@@ -33,6 +33,8 @@ def main() -> None:
 
     import jax
     jax.config.update("jax_platforms", "cpu")
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from eval_uplift_real import load_policy
 

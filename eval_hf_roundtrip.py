@@ -1,6 +1,6 @@
 """HF-layout checkpoint + tokenizer dir → cold load → engine decode parity.
 
-VERDICT r4 missing #4: ``models/load.py`` and ``HFTokenizer`` existed
+Round-4 review: ``models/load.py`` and ``HFTokenizer`` existed
 but no artifact drove the PRODUCTION loading posture end to end — an
 HF-layout model dir plus an HF tokenizer dir, cold-loaded, served by
 the engine (the reference serves real checkpoints,
@@ -144,6 +144,8 @@ def main() -> None:
 
     import jax
     jax.config.update("jax_platforms", "cpu")
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from senweaver_ide_tpu.models import get_config
     from senweaver_ide_tpu.models.transformer import init_params

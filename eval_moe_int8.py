@@ -113,6 +113,8 @@ def main() -> None:
 
     import jax
     jax.config.update("jax_platforms", "cpu")
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from senweaver_ide_tpu.models import get_config, init_params
     from senweaver_ide_tpu.models.tokenizer import ByteTokenizer

@@ -1,6 +1,6 @@
 """OnlineImprovementLoop end to end on REAL weights (no scripted policy).
 
-VERDICT r3 missing #2: weight-learning (LEARNING_r03) and
+Round-3 review: weight-learning (LEARNING_r03) and
 prompt-conditioning (LEARNING_CONTEXTUAL_*) each existed in isolation;
 this eval runs them TOGETHER through ``training/online.py`` — the
 reference's coupled cycle (``apoService.ts:435-472`` auto-analysis timer
@@ -296,6 +296,8 @@ def main() -> None:
 
     import jax
     jax.config.update("jax_platforms", "cpu")   # tiny-model CPU work
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     report = run_online_eval(rounds=args.rounds, ckpt=args.ckpt,
                              seed=args.seed, group_size=args.group_size,

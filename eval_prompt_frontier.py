@@ -1,4 +1,4 @@
-"""Prompt-length conditioning frontier (VERDICT r3 missing #4).
+"""Prompt-length conditioning frontier (round-3 review).
 
 The product premise is that injected '# APO Optimized Rules' steer the
 policy from inside a LONG assembled system message
@@ -95,6 +95,8 @@ def main() -> None:
 
     import jax
     jax.config.update("jax_platforms", "cpu")
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     lengths = [int(x) for x in args.lengths.split(",") if x.strip()]
     report = run_frontier(lengths, rounds=args.rounds,

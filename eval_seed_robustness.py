@@ -1,6 +1,6 @@
 """De-lottery the flagship pretrain: config sweep on the HARD seeds.
 
-VERDICT r4 weak #2 / next-round #7: the rule-following pretrain behind
+Round-4 review: the rule-following pretrain behind
 the 2.06x headline converges in ~2 of 9 seeds at the proven recipe
 (2 groups x 16, lr 0.02, 80-round cap), and a seed-10/11/12 attempt
 found NONE — best-of-N retries handle it honestly but the pipeline is a
@@ -70,6 +70,8 @@ def main() -> None:
 
     import jax
     jax.config.update("jax_platforms", "cpu")
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     seeds = [int(s) for s in args.seeds.split(",") if s.strip()]
     names = [c for c in args.configs.split(",") if c.strip()]
@@ -96,9 +98,8 @@ def main() -> None:
     print(json.dumps({
         "metric": "pretrain_seed_robustness",
         "seeds": seeds,
-        "note": "seeds 10/11/12 all FAILED the r4 baseline recipe "
-                "(ROUND4_NOTES engineering notes) — any convergence "
-                "here is a config effect, not seed luck",
+        "note": "seeds 10/11/12 all FAILED the r4 baseline recipe — any "
+                "convergence here is a config effect, not seed luck",
         "cells": cells,
         "by_config": by_cfg,
         "best_config": best,

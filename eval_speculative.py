@@ -87,7 +87,9 @@ def main() -> None:
     args = ap.parse_args()
 
     import jax
-    jax.config.update("jax_platforms", "cpu")   # tiny models; wedge-proof
+    jax.config.update("jax_platforms", "cpu")   # tiny models
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print(json.dumps(run_speculative_eval(
         n_prompts=args.prompts, max_new_tokens=args.max_new_tokens,

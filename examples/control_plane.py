@@ -12,6 +12,8 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 import jax
 jax.config.update("jax_platforms", "cpu")
+from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+enable_compile_cache()
 from senweaver_ide_tpu.apo.eval import GOOD_RULESET, RuleSensitivePolicy
 from senweaver_ide_tpu.models import get_config
 from senweaver_ide_tpu.models.tokenizer import ByteTokenizer

@@ -20,6 +20,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+enable_compile_cache()
 
 from senweaver_ide_tpu.apo.eval import RuleSensitivePolicy, SIX_PATTERN_TASKS
 from senweaver_ide_tpu.apo.local import make_local_apo

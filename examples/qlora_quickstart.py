@@ -23,6 +23,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+enable_compile_cache()
 
 from senweaver_ide_tpu.models import (get_config, init_params,
                                       quantize_weights_int8, quantized_bytes)

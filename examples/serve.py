@@ -1,10 +1,13 @@
 """Quickstart: serve a policy with continuous batching.
 
-Runs the tiny test model by default so it works anywhere (CPU included);
-point --model-dir at a local HF-layout checkpoint (e.g. a downloaded
-Qwen/Qwen2.5-Coder-1.5B snapshot) to serve the real thing on a TPU chip.
+Serves seeded random weights of any preset on whatever device JAX has —
+the tiny test model by default, so it works anywhere; ``--model
+qwen2.5-coder-1.5b`` serves the flagship's published shape (on a TPU
+chip: run it through the builder's chip tool). Point --model-dir at a
+local HF-layout checkpoint (e.g. a downloaded Qwen/Qwen2.5-Coder-1.5B
+snapshot) to serve real weights.
 
-    python examples/serve.py [--model-dir DIR] [--prompt "def main():"]
+    python examples/serve.py [--model NAME] [--model-dir DIR] [--prompt "def main():"]
 """
 import argparse
 import sys
@@ -15,16 +18,24 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model-dir", default=None)
+    ap.add_argument("--model", default="tiny-test",
+                    help="preset to serve from seeded random weights")
+    ap.add_argument("--model-dir", default=None,
+                    help="HF-layout qwen2.5-coder-1.5b checkpoint to load "
+                         "instead")
     ap.add_argument("--prompt", default="def fibonacci(n):")
     ap.add_argument("--max-new-tokens", type=int, default=32)
     ap.add_argument("--cpu", action="store_true",
-                    help="force the CPU backend (tiny demo / wedged TPU)")
+                    help="run on the CPU backend even where JAX has an "
+                         "accelerator")
     args = ap.parse_args()
 
     import jax
-    if args.cpu or args.model_dir is None:
+    if args.cpu:
         jax.config.update("jax_platforms", "cpu")
+
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from senweaver_ide_tpu.models import get_config, init_params
     from senweaver_ide_tpu.models.tokenizer import ByteTokenizer
@@ -36,7 +47,7 @@ def main() -> None:
         params = load_hf_params(args.model_dir, config)
         tok = load_tokenizer(args.model_dir)
     else:
-        config = get_config("tiny-test")
+        config = get_config(args.model)
         params = init_params(config, jax.random.PRNGKey(0))
         tok = ByteTokenizer()
 
@@ -46,7 +57,8 @@ def main() -> None:
     rid = engine.submit(tok.encode(args.prompt, add_bos=True),
                         max_new_tokens=args.max_new_tokens)
     out = engine.run()[rid]
-    print(f"[{config.name}] {len(out)} tokens:")
+    print(f"[{config.name}] on {jax.devices()[0].platform}: "
+          f"{len(out)} tokens:")
     print(tok.decode(out))
 
 

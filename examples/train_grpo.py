@@ -20,6 +20,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 def main() -> None:
     import jax
     jax.config.update("jax_platforms", "cpu")   # hermetic demo
+    from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from senweaver_ide_tpu.apo.eval import (SIX_PATTERN_TASKS,
                                             RuleSensitivePolicy)

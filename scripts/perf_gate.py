@@ -29,9 +29,8 @@ Comparator semantics: the committed ``PERF_BASELINE.json`` carries a
 per-metric steady-state value and a noise band (default 2.0x — CPU CI
 runners are noisy; a genuine algorithmic regression is typically well
 past 2x on these microscopic cases). ``current > value * band`` fails
-the gate. Entries stamped ``"cached": true`` — e.g. a BENCH_CACHE
-replay — are REFUSED as evidence on either side: a cached number
-proves nothing about this commit.
+the gate. Entries stamped ``"cached": true`` are REFUSED as evidence
+on either side: a cached number proves nothing about this commit.
 
 Usage:
   python scripts/perf_gate.py                   # measure + compare

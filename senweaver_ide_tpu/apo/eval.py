@@ -1,6 +1,6 @@
 """Prompt-conditioned candidate scoring + the north-star uplift eval.
 
-VERDICT r1's core APO gap: beam candidates were scored by a
+The round-1 review's core APO gap: beam candidates were scored by a
 prompt-INDEPENDENT corpus baseline, so the search could never rank them.
 This module supplies the real scorer the reference keeps on its backend
 (``POST /api/apo/optimize`` scores candidates against rollouts,
@@ -50,7 +50,7 @@ SIX_PATTERN_TASKS: List[str] = [
 # them unverified; only BOTH yield fully careful behavior. A real
 # policy has the same structure statistically; the markers make it
 # exact for tests — and graded, so beam search must COMPOSE the right
-# pair, not merely hit any one marker (VERDICT r3 weak #3).
+# pair, not merely hit any one marker (round-3 review).
 VERIFY_MARKERS = ("verify", "read the file before")
 EFFICIENCY_MARKERS = ("minimal tool", "minimum number of tool calls",
                       "never retry")
@@ -61,7 +61,7 @@ GOOD_RULESET = [
     "Use the minimum number of tool calls needed; never retry blindly.",
 ]
 
-# Hold-out proposal bank (VERDICT r3 weak #3): rule phrasings the
+# Hold-out proposal bank (round-3 review): rule phrasings the
 # OPTIMIZER can propose, of which only SOME satisfy the policy's behavior
 # contract (CAREFUL_MARKERS) — and nothing in the proposer encodes which.
 # With this bank the beam must discover the steering subset by scored
@@ -181,7 +181,7 @@ class RuleSensitivePolicy:
     # Hold-out mode: apply-edit calls SAMPLE 2-rule subsets from this
     # bank (seeded) instead of returning improved_rules outright — the
     # optimizer no longer knows the answer, so the beam has to find the
-    # steering subset by scoring (VERDICT r3 weak #3).
+    # steering subset by scoring (round-3 review).
     proposal_bank: Optional[Sequence[str]] = None
     proposal_seed: int = 0
 
@@ -369,7 +369,7 @@ def run_uplift_eval(workdir: str, *, client=None,
 
     # holdout: the scripted optimizer proposes sampled subsets from the
     # hold-out bank instead of handing over GOOD_RULESET — beam search
-    # must FIND the steering rules by score (VERDICT r3 weak #3). The
+    # must FIND the steering rules by score (round-3 review). The
     # bank only wires into the SCRIPTED client; a caller-supplied client
     # (real policy) keeps its own optimizer behavior, and the report's
     # holdout flag must say what actually ran.

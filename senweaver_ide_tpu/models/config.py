@@ -266,8 +266,8 @@ def small_test() -> ModelConfig:
     """Between tiny-test and the real presets: enough capacity for
     prompt-CONDITIONAL behavior (the contextual learning eval needs the
     task tokens, buried in an ~1.8k-token prompt, to actually route the
-    output distribution — tiny-test's 2×d64 could not; see
-    ROUND3_NOTES.md §16), still seconds-per-round on one chip."""
+    output distribution — tiny-test's 2×d64 could not), still
+    seconds-per-round on one chip."""
     return ModelConfig(
         name="small-test", vocab_size=512, hidden_size=128,
         intermediate_size=384, num_layers=4, num_heads=8, num_kv_heads=4,

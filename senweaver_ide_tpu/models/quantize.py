@@ -1,7 +1,8 @@
 """Weight-only int8 quantization for serving.
 
-Decode on one v5e chip is weight-HBM-bound (BENCH_NOTES.md: 2116
-tok/s/chip for the 1.5B ≈ the bf16 roofline 819 GB/s ÷ 3.1 GB). Storing
+Small-batch decode streams every weight byte once per step: 3.1 GB of
+bf16 at a v5e chip's 819 GB/s bounds the 1.5B at ~264 steps/s (device
+time on today's code: not measured). Storing
 the dense matmul weights as int8 with one fp32 scale per OUTPUT channel
 (absmax over the contraction axis) halves the bytes every decode step
 must stream, raising the bandwidth ceiling ~2× at <1% relative logit

@@ -202,10 +202,10 @@ def _dense(h: jax.Array, lp: Dict[str, jax.Array], name: str,
 
     When the stored weight is int8 (see ``models.quantize``), the matmul
     upcasts it in-compute and applies the per-output-channel scale to the
-    (much smaller) output. Decode is weight-HBM-bound (BENCH_NOTES.md
-    roofline: 2116 tok/s ≈ the bf16 bandwidth ceiling), so halving the
-    bytes each step streams is the one remaining 2×-class lever; the
-    scale multiply is an elementwise epilogue XLA fuses into the dot."""
+    (much smaller) output. Small-batch decode streams every weight byte
+    once per step, so halving those bytes halves that bound (see
+    ``models.quantize``); the scale multiply is an elementwise epilogue
+    XLA fuses into the dot."""
     w = lp[name]
     if w.dtype == jnp.int8:
         out = jnp.einsum(spec, h, w.astype(h.dtype))

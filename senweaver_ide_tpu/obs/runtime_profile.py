@@ -95,16 +95,9 @@ def _install_compile_listener() -> None:
     with _listener_lock:
         if _listener_installed:
             return
-        # Mark installed even on failure: an older jax without the
-        # monitoring hook should not re-raise on every profiler build
-        # (the ledger then falls back to signature-novelty timing).
         _listener_installed = True
-        try:
-            from jax import monitoring
-            monitoring.register_event_duration_secs_listener(
-                _on_event_duration)
-        except Exception:
-            pass
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_event_duration)
 
 
 # -- abstract signatures -------------------------------------------------

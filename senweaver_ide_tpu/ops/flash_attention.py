@@ -37,13 +37,6 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import MASKED_THRESHOLD as _MASKED
 from .attention import NEG_INF
 
-# JAX 0.4.37 renamed the Pallas-TPU compiler-params dataclass
-# (``CompilerParams`` → ``TPUCompilerParams``); newer JAX releases are
-# renaming it back. Resolve whichever spelling this JAX ships so the
-# kernels compile across the supported version range.
-_TPUCompilerParams = getattr(pltpu, "TPUCompilerParams", None) \
-    or getattr(pltpu, "CompilerParams")
-
 
 def _fa_kernel(offsets_ref, q_ref, k_ref, v_ref, bias_ref, out_ref, lse_ref,
                acc_ref, m_ref, l_ref, *, causal: bool,
@@ -162,7 +155,7 @@ def _fa_forward(q, k, v, bias, offsets, *, causal, window, block_q,
             jax.ShapeDtypeStruct((b, hq, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, hq, 1, sq), jnp.float32),
         ],
-        compiler_params=_TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         cost_estimate=pl.CostEstimate(

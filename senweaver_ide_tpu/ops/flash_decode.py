@@ -34,12 +34,6 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import MASKED_THRESHOLD as _MASKED
 from .attention import NEG_INF
 
-# Same version shim as ops/flash_attention.py: JAX 0.4.37 spells the
-# Pallas-TPU compiler params ``TPUCompilerParams``; other releases spell
-# it ``CompilerParams``. Accept either.
-_TPUCompilerParams = getattr(pltpu, "TPUCompilerParams", None) \
-    or getattr(pltpu, "CompilerParams")
-
 
 def _fd_kernel(lengths_ref, q_ref, k_ref, v_ref, out_ref,
                acc_ref, m_ref, l_ref, *, scale: float, block_kv: int,
@@ -184,7 +178,7 @@ def flash_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rows, d), q.dtype),
-        compiler_params=_TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * hq * smax * d,

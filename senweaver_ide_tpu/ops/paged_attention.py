@@ -44,11 +44,6 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import MASKED_THRESHOLD as _MASKED
 from .attention import NEG_INF
 
-# Version shim shared with the other Pallas kernels: JAX 0.4.37 spells
-# the compiler params ``TPUCompilerParams``; later ``CompilerParams``.
-_TPUCompilerParams = getattr(pltpu, "TPUCompilerParams", None) \
-    or getattr(pltpu, "CompilerParams")
-
 
 def _pfd_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
                 scale: float, block_size: int, hkv: int, rep_pad: int,
@@ -202,7 +197,7 @@ def paged_flash_decode(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, rows, d), q.dtype),
-        compiler_params=_TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=4 * t * hq * mb * bs * d,

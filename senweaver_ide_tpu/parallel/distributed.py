@@ -41,13 +41,9 @@ def initialize(cfg: DistributedConfig = DistributedConfig()) -> None:
     global _initialized
     if _initialized:
         return
-    try:
-        from jax._src import distributed as _dist
-        if getattr(_dist.global_state, "client", None) is not None:
-            _initialized = True
-            return
-    except (ImportError, AttributeError):
-        pass   # private API moved: fall through and let init itself decide
+    if jax.distributed.is_initialized():
+        _initialized = True
+        return
     addr = cfg.coordinator_address or os.environ.get(
         "JAX_COORDINATOR_ADDRESS")
     nproc = cfg.num_processes if cfg.num_processes is not None else (

@@ -24,7 +24,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from .ring_attention import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -208,5 +208,5 @@ def moe_ffn_sharded(params: MoEParams, cfg: MoEConfig, x: jax.Array, *,
     out, aux = shard_map(
         fn, mesh=mesh,
         in_specs=tuple(in_specs),
-        out_specs=(P("ep"), P()), check_rep=False)(*args)
+        out_specs=(P("ep"), P()), check_vma=False)(*args)
     return out, aux

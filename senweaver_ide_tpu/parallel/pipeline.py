@@ -21,7 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from .ring_attention import shard_map
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -153,7 +153,7 @@ def pipeline_forward(params: Params, config: ModelConfig,
     else:
         fn = pp_fn
     outs = shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                     check_rep=False)(*args)
+                     check_vma=False)(*args)
     x = outs.reshape(b, s, c.hidden_size)
 
     x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
@@ -356,7 +356,7 @@ def pipeline_train_grads_1f1b(params: Params, config: ModelConfig,
         in_specs=(lp_specs, P(), P(), P(), P(), P(), P(), P()),
         out_specs=(jax.tree_util.tree_map(lambda _: P("pp"), lp_specs),
                    P(), P(), P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(params["layers"], mb_x, mb_tok, mb_tgt, mb_tmask, mb_adv,
       head_w, norm_w)
     g_lp, g_embed, g_head, g_norm, loss = outs

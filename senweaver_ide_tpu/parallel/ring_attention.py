@@ -29,37 +29,11 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-import inspect
-
-try:
-    from jax import shard_map as _shard_map
-    _REP_KWARG = ("check_vma" if "check_vma"
-                  in inspect.signature(_shard_map).parameters
-                  else "check_rep")
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KWARG = "check_rep"
-
-
-def shard_map(f, **kwargs):
-    """jax.shard_map across the check_rep→check_vma API rename."""
-    if "check_rep" in kwargs:
-        kwargs[_REP_KWARG] = kwargs.pop("check_rep")
-    return _shard_map(f, **kwargs)
 
 from ..ops.attention import MASKED_THRESHOLD as _MASKED
 from ..ops.attention import NEG_INF, repeat_kv
-
-
-def _axis_size(axis_name) -> int:
-    """``jax.lax.axis_size`` across JAX versions: absent in 0.4.x, where
-    ``psum(1, axis)`` is the canonical spelling (it constant-folds to
-    the bound axis size, so Python-level shape checks still work)."""
-    size = getattr(jax.lax, "axis_size", None)
-    if size is not None:
-        return size(axis_name)
-    return jax.lax.psum(1, axis_name)
 
 
 def chunk_attention_lse(
@@ -133,7 +107,7 @@ def ring_attention(
     """Ring attention over the ``axis_name`` mesh axis. Must run inside
     ``shard_map`` with the sequence axis sharded on that axis. Device i's
     queries live at absolute positions [i·S_local, (i+1)·S_local)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     s_local = q.shape[1]
     q_off = idx * s_local
@@ -173,7 +147,7 @@ def ulysses_attention(
     Hq/sp heads, reshard back. Head counts must divide by the axis size."""
     from ..ops.attention import attention
 
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if q.shape[2] % n or k.shape[2] % n:
         raise ValueError(
             f"ulysses needs head counts divisible by |{axis_name}|={n}; "
@@ -203,10 +177,10 @@ def make_ring_attention(mesh: Mesh, *, axis_name: str = "sp",
         return shard_map(lambda q, k, v, m: fn(q, k, v, kv_mask=m),
                          mesh=mesh,
                          in_specs=(spec, spec, spec, P(None, axis_name)),
-                         out_specs=out_spec, check_rep=False)
+                         out_specs=out_spec, check_vma=False)
     return shard_map(lambda q, k, v: fn(q, k, v), mesh=mesh,
                      in_specs=(spec, spec, spec), out_specs=out_spec,
-                     check_rep=False)
+                     check_vma=False)
 
 
 def make_ulysses_attention(mesh: Mesh, *, axis_name: str = "sp",
@@ -216,4 +190,4 @@ def make_ulysses_attention(mesh: Mesh, *, axis_name: str = "sp",
                            causal=causal)
     return shard_map(lambda q, k, v: fn(q, k, v), mesh=mesh,
                      in_specs=(spec, spec, spec), out_specs=out_spec,
-                     check_rep=False)
+                     check_vma=False)

@@ -93,8 +93,11 @@ KV_CACHE_SPEC = P(None, ("dp", "fsdp"), None, "tp", None)
 
 def restrict_spec(spec: P, mesh: Mesh) -> P:
     """Drop axes the mesh does not have (a tp-only serving mesh must not
-    reject the canonical specs that also name dp/fsdp/sp)."""
-    names = set(mesh.axis_names)
+    reject the canonical specs that also name dp/fsdp/sp) or has at size
+    1, and trailing Nones — the form jit hands back for its outputs, so
+    a state placed with this spec and the state a step returns are one
+    jit cache entry, not two."""
+    names = {a for a, n in mesh.shape.items() if n > 1}
 
     def keep(entry):
         if entry is None:
@@ -104,7 +107,10 @@ def restrict_spec(spec: P, mesh: Mesh) -> P:
             return kept if len(kept) > 1 else (kept[0] if kept else None)
         return entry if entry in names else None
 
-    return P(*(keep(e) for e in spec))
+    kept = [keep(e) for e in spec]
+    while kept and kept[-1] is None:
+        kept.pop()
+    return P(*kept)
 
 
 def spec_for_path(path: str, ndim: int = -1) -> P:

@@ -29,6 +29,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs.runtime_profile import ProfiledFunction
 from ..traces import features as F
@@ -59,17 +60,25 @@ DIM_NAMES = (
     "conversation_efficiency",
 )
 
+# Module constants are NUMPY on purpose: a jnp.array here would initialise
+# the JAX backend at import, and on a TPU host the first process to do that
+# owns the chip — a control server or report script that merely imports
+# this package must not take it from the trainer.
+
 # finalReward weights (traceCollectorService.ts:766-776).
-WEIGHTS = jnp.array([0.25, 0.18, 0.12, 0.08, 0.05, 0.05, 0.08, 0.08, 0.11],
-                    dtype=jnp.float32)
+WEIGHTS = np.array([0.25, 0.18, 0.12, 0.08, 0.05, 0.05, 0.08, 0.08, 0.11],
+                   dtype=np.float32)
 
 # Threshold tables, row 0 = normal, row 1 = agent.
-_FAIL_T = jnp.array([[3.0, 2.0, 1.0], [5.0, 3.0, 2.0]])      # severe/moderate/minor
-_COUNT_T = jnp.array([[3.0, 6.0, 10.0], [8.0, 15.0, 25.0]])  # excellent/good/fair
-_TOKEN_T = jnp.array([[2000.0, 5000.0, 10000.0],
-                      [5000.0, 15000.0, 30000.0]])           # excellent/good/fair
-_LLM_T = jnp.array([1.0, 3.0])
-_TURN_T = jnp.array([2.0, 3.0])
+_FAIL_T = np.array([[3.0, 2.0, 1.0], [5.0, 3.0, 2.0]],
+                   np.float32)                   # severe/moderate/minor
+_COUNT_T = np.array([[3.0, 6.0, 10.0], [8.0, 15.0, 25.0]],
+                    np.float32)                  # excellent/good/fair
+_TOKEN_T = np.array([[2000.0, 5000.0, 10000.0],
+                     [5000.0, 15000.0, 30000.0]],
+                    np.float32)                  # excellent/good/fair
+_LLM_T = np.array([1.0, 3.0], np.float32)
+_TURN_T = np.array([2.0, 3.0], np.float32)
 
 
 class RewardOutput(NamedTuple):
@@ -114,14 +123,14 @@ def reward_head(feat: jax.Array) -> RewardOutput:
     d_success = (tool_ok / safe_calls) * 2.0 - 1.0
 
     # Dim 4: tool-call reliability, adaptive fail thresholds (ref :701-708).
-    ft = _FAIL_T[agent]
+    ft = jnp.asarray(_FAIL_T)[agent]
     d_reliability = jnp.where(
         tool_fail >= ft[0], -1.0,
         jnp.where(tool_fail >= ft[1], -0.5,
                   jnp.where(tool_fail >= ft[2], -0.2, 1.0)))
 
     # Dim 5: tool-call count efficiency (ref :710-718).
-    ct = _COUNT_T[agent]
+    ct = jnp.asarray(_COUNT_T)[agent]
     d_count = jnp.where(
         tool_calls > ct[2], -0.8,
         jnp.where(tool_calls > ct[1], -0.3,
@@ -135,19 +144,19 @@ def reward_head(feat: jax.Array) -> RewardOutput:
                   jnp.where(avg_dur > 1000.0, 0.5, 1.0)))
 
     # Dim 6: response efficiency (ref :733-737).
-    llm_t = _LLM_T[agent]
+    llm_t = jnp.asarray(_LLM_T)[agent]
     d_response = jnp.maximum(
         -1.0, 1.0 - jnp.maximum(0.0, llm_calls - llm_t) * 0.4)
 
     # Dim 7: token efficiency (ref :740-749).
-    tt = _TOKEN_T[agent]
+    tt = jnp.asarray(_TOKEN_T)[agent]
     d_token = jnp.where(
         tokens > tt[2], -0.5,
         jnp.where(tokens > tt[1], 0.0,
                   jnp.where(tokens > tt[0], 0.5, 1.0)))
 
     # Dim 8: conversation efficiency, turn bands (ref :752-763).
-    turn_t = _TURN_T[agent]
+    turn_t = jnp.asarray(_TURN_T)[agent]
     d_turns = jnp.where(
         turns > turn_t * 3.0, -0.8,
         jnp.where(turns > turn_t * 2.0, -0.3,

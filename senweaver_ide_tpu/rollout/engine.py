@@ -601,8 +601,20 @@ class RolloutEngine:
         # (SURVEY.md §2.7 'continuous-batching sampler with TP-sharded
         # KV cache'); jit then compiles collectives from the shardings.
         self.mesh = mesh
+        # One-chip replicas of a fleet: params the caller COMMITTED to
+        # one device (jax.device_put(params, dev)) name this engine's
+        # chip. Its own state — PRNG key, block pool, published params —
+        # is then created on and committed to that device; left
+        # uncommitted it would be allocated on the default device
+        # (every replica's pool on chip 0) and each step's key split
+        # would run there.
+        self._device = None
+        if mesh is None:
+            leaf = jax.tree_util.tree_leaves(params)[0]
+            if getattr(leaf, "committed", False) and len(leaf.devices()) == 1:
+                (self._device,) = leaf.devices()
         self.params = self._place_params(params)
-        self._key = jax.random.PRNGKey(seed)
+        self._key = self._on_device(lambda: jax.random.PRNGKey(seed))
         # KV layout: paged block pool by default; the layouts the pool
         # has no equivalent for yet fall back to the slot cache.
         self.engine_config = engine_config or EngineConfig()
@@ -680,10 +692,10 @@ class RolloutEngine:
             # Pool before allocator: the allocator's byte ledger
             # (senweaver_kv_bytes_{device,host}) needs the pool's
             # per-block footprint, which the kv_dtype ladder shrinks.
-            self.pool = init_paged_pool(
+            self.pool = self._on_device(lambda: init_paged_pool(
                 config, nb, bs,
                 kv_dtype=self.engine_config.kv_dtype,
-                kv_dtype_per_layer=self.engine_config.kv_dtype_per_layer)
+                kv_dtype_per_layer=self.engine_config.kv_dtype_per_layer))
             self._alloc = BlockAllocator(
                 nb, bs, registry=get_registry(),
                 bytes_per_block=pool_bytes_per_block(self.pool))
@@ -796,9 +808,20 @@ class RolloutEngine:
 
     def _place_params(self, params: Params) -> Params:
         if self.mesh is None:
-            return params
+            if self._device is None:
+                return params
+            return jax.device_put(params, self._device)
         from ..parallel.sharding import shard_params
         return shard_params(params, self.mesh)
+
+    def _on_device(self, make):
+        """Build engine-owned arrays: directly on this replica's chip
+        and committed there when it has one, else wherever JAX puts
+        them."""
+        if self._device is None:
+            return make()
+        with jax.default_device(self._device):
+            return jax.device_put(make(), self._device)
 
     def update_params(self, params: Params) -> None:
         """On-policy weight sync: the trainer hands over fresh params

@@ -69,9 +69,7 @@ from ..obs.runtime_profile import ProfiledFunction
 # per-(token, head) f32 absmax scales.
 KV_DTYPES = ("bf16", "int8", "fp8")
 
-# fp8 support rides the jax build; gate on availability instead of
-# importing unconditionally so older jaxlibs still serve int8/bf16.
-_FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
+_FP8_DTYPE = jnp.float8_e4m3fn
 
 
 def kv_payload_dtype(name: str):
@@ -79,10 +77,6 @@ def kv_payload_dtype(name: str):
     if name == "int8":
         return jnp.int8
     if name == "fp8":
-        if _FP8_DTYPE is None:
-            raise ValueError(
-                "kv_dtype='fp8' requires a jax build with "
-                "float8_e4m3fn; this one has none — use int8 or bf16")
         return _FP8_DTYPE
     raise ValueError(f"unknown quantized kv_dtype {name!r}; "
                      f"expected one of {KV_DTYPES}")

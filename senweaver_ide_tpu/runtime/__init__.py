@@ -8,6 +8,7 @@ via ctypes) and the senweaver-ctl CLI (native/senweaver_ctl.cpp) speaking
 JSON-RPC over a unix socket to ControlServer.
 """
 
+from .compile_cache import enable_compile_cache
 from .control import (DEFAULT_SOCKET, ControlClient, ControlError,
                       ControlServer, Job)
 from .jobs import JobRunner
@@ -16,5 +17,6 @@ from .native import (TraceRing, build_native, byte_tokenize_batch,
 
 __all__ = [
     "DEFAULT_SOCKET", "ControlClient", "ControlError", "ControlServer", "Job", "JobRunner", "TraceRing", "build_native",
-    "byte_tokenize_batch", "ctl_binary_path", "native_available",
+    "byte_tokenize_batch", "ctl_binary_path", "enable_compile_cache",
+    "native_available",
 ]

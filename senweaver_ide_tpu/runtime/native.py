@@ -26,11 +26,11 @@ _build_attempted = False
 
 
 def build_native(force: bool = False) -> bool:
-    """Run the Makefile; returns True when the shared library exists."""
+    """Run the Makefile once per process; returns True when the shared
+    library exists. ``make`` decides freshness, not the presence of
+    ``_build/``: the directory is git-ignored, so a tree copied as it
+    stands can carry a binary built from another commit's sources."""
     global _build_attempted
-    if (os.path.exists(_LIB_PATH) and os.path.exists(_CTL_PATH)
-            and not force):
-        return True
     if _build_attempted and not force:
         return os.path.exists(_LIB_PATH)
     _build_attempted = True
@@ -44,8 +44,7 @@ def build_native(force: bool = False) -> bool:
 
 def ctl_binary_path() -> Optional[str]:
     """Path to the senweaver-ctl CLI, building if needed."""
-    if not os.path.exists(_CTL_PATH):
-        build_native()
+    build_native()
     return _CTL_PATH if os.path.exists(_CTL_PATH) else None
 
 

@@ -149,9 +149,9 @@ class AsyncGRPOTrainer:
         self.lora_base = lora_base
         # Frozen/rolling reference for the k3-KL term (grpo_round's
         # ref_params analogue): a FULL policy tree; combined with
-        # grpo_config.kl_coef > 0 it anchors long runs against drift
-        # (ROUND3_NOTES.md §24). Swap via set_ref_params at round
-        # boundaries for a rolling anchor.
+        # grpo_config.kl_coef > 0 it anchors long runs against drift.
+        # Swap via set_ref_params at round boundaries for a rolling
+        # anchor.
         self.ref_params = ref_params
 
         self._queue: "queue.Queue[_Collected]" = queue.Queue(

@@ -209,7 +209,7 @@ def place_batch_for_mesh(mesh, tokens, mask, rewards, group_ids,
 
     Explicit placement matters: feeding host numpy through jit relies on
     GSPMD propagation, which broadcasts the batch to every device before
-    resharding (VERDICT r1 weak #5). The sequence axis keeps S = k·sp+1
+    resharding (round-1 review). The sequence axis keeps S = k·sp+1
     (the TRAINING length S−1 shards over sp after the next-token shift
     inside the step), so grids place batch-axis-only here.
     Returns jnp/global arrays ready for train_step."""

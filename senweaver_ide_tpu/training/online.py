@@ -152,7 +152,7 @@ class OnlineImprovementLoop:
         # anchor_every > 0 (with grpo_config.kl_coef > 0): keep a
         # rolling snapshot of the policy as the k3-KL reference,
         # refreshed every anchor_every rounds — the drift stabilizer
-        # proven by the contextual runs (ROUND3_NOTES.md §24).
+        # proven by the contextual runs.
         self.anchor_every = anchor_every
         self._anchor = (state.params
                         if anchor_every > 0 and grpo_config.kl_coef > 0

@@ -625,9 +625,9 @@ def _grpo_round_impl(state, model_config, mesh, make_session, tasks, *,
     # Anchored training: a frozen REFERENCE policy (e.g. a rolling
     # snapshot of the serving params a few rounds back) supplies
     # ref_logp for the k3 KL term — the stabilizer against the observed
-    # conditioning collapse under long unanchored runs
-    # (ROUND3_NOTES.md §23). ref_params must be a FULL policy tree
-    # (callers using LoRA pass the materialized/merged view).
+    # conditioning collapse under long unanchored runs. ref_params
+    # must be a FULL policy tree (callers using LoRA pass the
+    # materialized/merged view).
     ref = None
     if ref_params is not None and grpo_config.kl_coef > 0.0:
         from .async_loop import behavior_logp_batched

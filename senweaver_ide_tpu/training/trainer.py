@@ -87,7 +87,16 @@ def make_train_state(config: ModelConfig, key: jax.Array,
         jax.jit(opt.init,
                 out_shardings=_opt_state_shardings(opt, params, mesh))(params)
     return TrainState(params=params, opt_state=opt_state,
-                      step=jnp.zeros((), jnp.int32), opt=opt)
+                      step=_zero_step(mesh), opt=opt)
+
+
+def _zero_step(mesh: Optional[Mesh]) -> jax.Array:
+    """Step counter placed like the step a train step returns
+    (replicated on the mesh), so step 2 reuses step 1's compile."""
+    step = jnp.zeros((), jnp.int32)
+    if mesh is None:
+        return step
+    return jax.device_put(step, NamedSharding(mesh, P()))
 
 
 def make_lora_train_state(config: ModelConfig, base_params: Params,
@@ -119,26 +128,33 @@ def make_lora_train_state(config: ModelConfig, base_params: Params,
     opt = optimizer or make_optimizer(learning_rate)
     opt_state = jax.jit(opt.init)(lora)
     return TrainState(params=lora, opt_state=opt_state,
-                      step=jnp.zeros((), jnp.int32), opt=opt)
+                      step=_zero_step(mesh), opt=opt)
 
 
 def _opt_state_shardings(opt, params, mesh):
-    """Shardings for the optimizer state: any leaf whose (shape, dtype)
-    matches a param leaf (Adam moments are param-shaped) inherits that param's
-    spec; everything else (counts, scalars) replicates."""
-    shapes = jax.eval_shape(opt.init, params)
-    pspecs = param_specs(params)
-    shape_to_spec = {}
-    for leaf, spec in zip(jax.tree_util.tree_leaves(params),
-                          jax.tree_util.tree_leaves(
-                              pspecs, is_leaf=lambda x: isinstance(x, P))):
-        shape_to_spec.setdefault((leaf.shape, leaf.dtype), spec)
+    """Shardings for the optimizer state: a leaf that sits under the same
+    dict path as a param and has its shape (Adam moments mirror the param
+    tree) inherits that param's spec; everything else (counts, scalars)
+    replicates. By path, not by shape: wq and wo are both (L, D, D)
+    wherever q_dim == hidden_size — qwen2.5-coder-1.5b for one — with
+    transposed specs."""
+    def dict_path(path):
+        return tuple(k.key for k in path
+                     if isinstance(k, jax.tree_util.DictKey))
 
-    def leaf_sharding(leaf):
-        spec = shape_to_spec.get((leaf.shape, leaf.dtype), P())
+    specs = jax.tree_util.tree_leaves(
+        param_specs(params), is_leaf=lambda x: isinstance(x, P))
+    by_path = {dict_path(path): (leaf.shape, spec) for (path, leaf), spec
+               in zip(jax.tree_util.tree_flatten_with_path(params)[0], specs)}
+
+    def leaf_sharding(path, leaf):
+        shape, spec = by_path.get(dict_path(path), (None, P()))
+        if shape != leaf.shape:
+            spec = P()
         return NamedSharding(mesh, restrict_spec(spec, mesh))
 
-    return jax.tree_util.tree_map(leaf_sharding, shapes)
+    return jax.tree_util.tree_map_with_path(
+        leaf_sharding, jax.eval_shape(opt.init, params))
 
 
 @functools.partial(jax.jit,
@@ -259,6 +275,14 @@ def _grpo_step(state: TrainState, config: ModelConfig,
 
     updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
     params = optax.apply_updates(state.params, updates)
+    if mesh is not None and lora_base is None:
+        # The new state keeps the layout the old one was given: left to
+        # the compiler, step 1 returns its own choice, step 2 retraces
+        # on it, and a checkpoint restore sees a third.
+        params = jax.lax.with_sharding_constraint(
+            params, param_shardings(params, mesh))
+        opt_state = jax.lax.with_sharding_constraint(
+            opt_state, _opt_state_shardings(optimizer, params, mesh))
     metrics = dict(metr)
     if config.num_experts == 0:
         del metrics["moe_aux"]

@@ -1,6 +1,7 @@
 """Prompt-conditioned beam scoring (apo/eval.py): a known-better rule-set
-must actually WIN the beam search — the capability VERDICT r1 found missing
-(the corpus scorer tied all candidates and the seed always won)."""
+must actually WIN the beam search — the capability the round-1 review
+found missing (the corpus scorer tied all candidates and the seed always
+won)."""
 
 import pytest
 
@@ -113,7 +114,7 @@ def test_real_policy_uplift_path_end_to_end(tmp_path):
 
 
 def test_graded_contract_single_class_is_partial(harness):
-    """The behavior contract is GRADED (VERDICT r3 weak #3): one rule
+    """The behavior contract is GRADED (round-3 review): one rule
     class alone lands strictly between sloppy and fully careful, so the
     beam must COMPOSE a verify+efficiency pair rather than hit any
     single marker."""

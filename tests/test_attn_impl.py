@@ -1,7 +1,7 @@
 """Attention-impl switch (ModelConfig.attn_impl): flash / ring / ulysses
 wired into the MODEL and TRAINER paths must match the einsum reference —
-this is the integration VERDICT r1 flagged as missing (flash/SP were dead
-code outside their own unit tests)."""
+this is the integration the round-1 review flagged as missing (flash/SP
+were dead code outside their own unit tests)."""
 
 import dataclasses
 

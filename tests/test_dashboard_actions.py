@@ -1,6 +1,6 @@
 """Dashboard operator actions: click-path → control RPC → state change.
 
-VERDICT r3 weak #8: the dashboard was a GET-only viewer while the
+Round-3 review: the dashboard was a GET-only viewer while the
 reference UI *drives* the system (suggestion apply/reject, job control —
 apoService.ts:1375-1458 segment lifecycle, browser/react/src). These
 tests run the full round trip over real transports: HTTP POST

@@ -1,6 +1,6 @@
 """HF-layout round trip through the serve path (eval_hf_roundtrip.py).
 
-VERDICT r4 missing #4: the production loading posture — an HF model dir
+Round-4 review: the production loading posture — an HF model dir
 plus an HF tokenizer dir, cold-loaded and served — executed end to end
 (ref ``sendLLMMessage.impl.ts:927``: the reference serves real
 checkpoints; zero egress here, so the checkpoint is our own export and

@@ -47,7 +47,7 @@ def test_lora_learning_curve_rises():
 
 
 def test_lora_converged_artifact():
-    """VERDICT r3 weak #2 demanded adapters CONVERGING, not just rising:
+    """Round-3 review demanded adapters CONVERGING, not just rising:
     the committed 40-round anchored artifact must show full-FT parity
     (sustained ~1.0), and the QLoRA variant the same over an int8 base.
     Pinning the artifacts keeps the regression margin at convergence

@@ -1,6 +1,6 @@
 """OnlineImprovementLoop on REAL weights (eval_online_real.py).
 
-VERDICT r3 missing #2 asked for an online-loop test with no
+Round-3 review asked for an online-loop test with no
 RuleSensitivePolicy anywhere: every episode here is sampled by a real
 (random-init) transformer through the engine, judged from its own token
 ids, trained on the reward head's finalReward, with the APO half wired

@@ -1,4 +1,4 @@
-"""Parallel rollout collection (VERDICT r1 weak #6): episodes must drive
+"""Parallel rollout collection (round-1 review): episodes must drive
 the engine's slot pool CONCURRENTLY, not one session at a time."""
 
 import threading
@@ -62,7 +62,7 @@ def test_collection_overlaps_and_orders_deterministically(tmp_path):
 
 
 def test_shared_engine_keeps_multiple_slots_busy(tmp_path):
-    """The VERDICT done-criterion: ≥2 engine slots concurrently active
+    """The done-criterion: ≥2 engine slots concurrently active
     while collecting over ONE shared continuous-batching engine."""
     config = get_config("tiny-test")
     params = init_params(config, jax.random.PRNGKey(0))

@@ -1,5 +1,5 @@
 """PerformanceMonitor thresholds + jax.profiler capture + grpo_round
-wiring (VERDICT r1 missing #8 / SURVEY §5 tracing)."""
+wiring (round-1 review / SURVEY §5 tracing)."""
 
 import os
 

@@ -1,4 +1,4 @@
-"""Pipeline-parallel TRAINING (VERDICT r1 weak #9: pp was forward-biased —
+"""Pipeline-parallel TRAINING (round-1 review: pp was forward-biased —
 no test ran a training step through the pipelined path)."""
 
 import jax

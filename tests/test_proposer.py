@@ -1,6 +1,6 @@
 """Generative APO proposer (apo/proposer.py): corpus, training, serving.
 
-The optimizer-role LM that closes VERDICT r4 missing #3 — the beam's
+The optimizer-role LM that closes a round-4 review gap — the beam's
 critique and apply-edit calls answered by REAL sampled model text
 (ref ``apoService.ts:992-1215``: the reference keeps this role on a
 backend LLM; SURVEY.md §3.3 in-trees it)."""

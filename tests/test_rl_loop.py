@@ -359,7 +359,7 @@ def test_grpo_round_anchored_reference(tmp_path, tiny_stack):
     """ref_params + kl_coef engage the k3-KL term inside the round: on
     the FIRST update the policy equals the anchor, so kl must be ~0 and
     the update must still be finite (the stabilizer for long contextual
-    runs, ROUND3_NOTES.md §23)."""
+    runs)."""
     from senweaver_ide_tpu.training.grpo import GRPOConfig
     config, state = tiny_stack
     tok = ByteTokenizer()

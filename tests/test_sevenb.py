@@ -1,4 +1,4 @@
-"""6.7B feasibility machinery (eval_sevenb.py / VERDICT r3 missing #5).
+"""6.7B feasibility machinery (eval_sevenb.py / round-3 review).
 
 The full-size run is SEVENB_r04.json; these tests pin the arithmetic
 and run the streamed int8 loader + real decode at a shrunken

@@ -1,5 +1,5 @@
 """Anthropic-messages and Gemini native transports against a local
-http.server emulating both wire formats (VERDICT r1 missing #5: the
+http.server emulating both wire formats (round-1 review: the
 registry listed the styles but no client spoke them)."""
 
 import json

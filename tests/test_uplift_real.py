@@ -1,4 +1,4 @@
-"""Real-policy APO uplift harness (eval_uplift_real.py / VERDICT r3 #1).
+"""Real-policy APO uplift harness (eval_uplift_real.py / round-3 review).
 
 Unit coverage for the pieces (multi-turn single-trace conversations, the
 bank proposer, prompt rendering) plus a shrunken end-to-end cycle on a

@@ -1,6 +1,6 @@
 """TraceUploader over a REAL HTTP peer (loopback http.server).
 
-VERDICT r4 weak #8: the upload path had wire-format tests but never
+Round-4 review: the upload path had wire-format tests but never
 faced a real socket peer. Zero egress makes a remote `/api/traces`
 unreachable, so the peer is a loopback HTTP server speaking the same
 contract — real sockets, real POST bodies, real status codes
