@@ -738,58 +738,78 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     slot path (`kv_pos < pos+1`, causal with per-row ``q_offset``), so
     paged and slot decode agree to numerical identity of the masking
     and matmul shapes' element-wise dot products.
+
+    The ``jax.named_scope`` names (``attn.qkv``, ``attn.kv_write``,
+    ``attn.kv_gather``, ``attn.scores``, ``attn.out``, ``mlp``) are the
+    stable device-side names of docs/observability.md: they ride each
+    HLO instruction's ``op_name`` metadata and change no computation.
     """
     t = x.shape[0]
     quantized = k_scale_pool is not None
-    h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
-    q, k, v = _qkv(c, lp, h, cos, sin, adapters, adapter_ids)
+    with jax.named_scope("attn.qkv"):
+        h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+        q, k, v = _qkv(c, lp, h, cos, sin, adapters, adapter_ids)
     # q (T,1,Hq,Dh), k/v (T,1,Hkv,Dh)
-    if quantized:
-        # Quantize-at-write: payload and scale scatter through the SAME
-        # (write_block, write_off) indices with the same mode="drop"
-        # out-of-range sentinel, so dropped writes (padding / rescore
-        # entries) leave both tensors untouched and quantization
-        # commutes with the sentinel, fork refcounts, and COW — those
-        # act on whole blocks via the pool movers, never element-wise.
-        kq, ks = quantize_pool_kv(k[:, 0], k_pool.dtype)
-        vq, vs = quantize_pool_kv(v[:, 0], v_pool.dtype)
-        k_pool = k_pool.at[write_block, write_off].set(kq, mode="drop")
-        v_pool = v_pool.at[write_block, write_off].set(vq, mode="drop")
-        k_scale_pool = k_scale_pool.at[write_block, write_off].set(
-            ks, mode="drop")
-        v_scale_pool = v_scale_pool.at[write_block, write_off].set(
-            vs, mode="drop")
-    else:
-        k_pool = k_pool.at[write_block, write_off].set(
-            k[:, 0].astype(k_pool.dtype), mode="drop")
-        v_pool = v_pool.at[write_block, write_off].set(
-            v[:, 0].astype(v_pool.dtype), mode="drop")
+    with jax.named_scope("attn.kv_write"):
+        if quantized:
+            # Quantize-at-write: payload and scale scatter through the
+            # SAME (write_block, write_off) indices with the same
+            # mode="drop" out-of-range sentinel, so dropped writes
+            # (padding / rescore entries) leave both tensors untouched
+            # and quantization commutes with the sentinel, fork
+            # refcounts, and COW — those act on whole blocks via the
+            # pool movers, never element-wise.
+            kq, ks = quantize_pool_kv(k[:, 0], k_pool.dtype)
+            vq, vs = quantize_pool_kv(v[:, 0], v_pool.dtype)
+            k_pool = k_pool.at[write_block, write_off].set(
+                kq, mode="drop")
+            v_pool = v_pool.at[write_block, write_off].set(
+                vq, mode="drop")
+            k_scale_pool = k_scale_pool.at[write_block, write_off].set(
+                ks, mode="drop")
+            v_scale_pool = v_scale_pool.at[write_block, write_off].set(
+                vs, mode="drop")
+        else:
+            k_pool = k_pool.at[write_block, write_off].set(
+                k[:, 0].astype(k_pool.dtype), mode="drop")
+            v_pool = v_pool.at[write_block, write_off].set(
+                v[:, 0].astype(v_pool.dtype), mode="drop")
     if use_kernel:
         from ..ops.paged_attention import paged_flash_decode
-        out = paged_flash_decode(q[:, 0], k_pool, v_pool,
-                                 tables[seq_row], positions + 1,
-                                 k_scale=k_scale_pool,
-                                 v_scale=v_scale_pool)[:, None]
+        # the kernel gathers through the table itself
+        with jax.named_scope("attn.scores"):
+            out = paged_flash_decode(q[:, 0], k_pool, v_pool,
+                                     tables[seq_row], positions + 1,
+                                     k_scale=k_scale_pool,
+                                     v_scale=v_scale_pool)[:, None]
     else:
-        nb, bs, hkv, dh = k_pool.shape
-        tbl = tables[seq_row]                              # (T, MB)
-        mb = tbl.shape[1]
-        k_seq = k_pool[tbl].reshape(t, mb * bs, hkv, dh)
-        v_seq = v_pool[tbl].reshape(t, mb * bs, hkv, dh)
-        if quantized:
-            k_seq = dequantize_pool_kv(
-                k_seq, k_scale_pool[tbl].reshape(t, mb * bs, hkv), x.dtype)
-            v_seq = dequantize_pool_kv(
-                v_seq, v_scale_pool[tbl].reshape(t, mb * bs, hkv), x.dtype)
-        kv_pos = jnp.arange(mb * bs)[None, :]
-        valid = kv_pos < positions[:, None] + 1
-        out = attention(q, k_seq.astype(x.dtype), v_seq.astype(x.dtype),
-                        q_offset=positions, kv_mask=valid, causal=True)
-    attn_in = out.reshape(t, 1, c.q_dim)
-    attn_out = _dense(attn_in, lp, "wo", "bse,ed->bsd")
-    attn_out = _with_adapter(attn_out, attn_in, adapters, adapter_ids, "wo")
-    x = x + attn_out
-    x, aux = _mlp(c, lp, x)
+        with jax.named_scope("attn.kv_gather"):
+            nb, bs, hkv, dh = k_pool.shape
+            tbl = tables[seq_row]                              # (T, MB)
+            mb = tbl.shape[1]
+            k_seq = k_pool[tbl].reshape(t, mb * bs, hkv, dh)
+            v_seq = v_pool[tbl].reshape(t, mb * bs, hkv, dh)
+            if quantized:
+                k_seq = dequantize_pool_kv(
+                    k_seq, k_scale_pool[tbl].reshape(t, mb * bs, hkv),
+                    x.dtype)
+                v_seq = dequantize_pool_kv(
+                    v_seq, v_scale_pool[tbl].reshape(t, mb * bs, hkv),
+                    x.dtype)
+        with jax.named_scope("attn.scores"):
+            kv_pos = jnp.arange(mb * bs)[None, :]
+            valid = kv_pos < positions[:, None] + 1
+            out = attention(q, k_seq.astype(x.dtype),
+                            v_seq.astype(x.dtype), q_offset=positions,
+                            kv_mask=valid, causal=True)
+    with jax.named_scope("attn.out"):
+        attn_in = out.reshape(t, 1, c.q_dim)
+        attn_out = _dense(attn_in, lp, "wo", "bse,ed->bsd")
+        attn_out = _with_adapter(attn_out, attn_in, adapters, adapter_ids,
+                                 "wo")
+        x = x + attn_out
+    with jax.named_scope("mlp"):
+        x, aux = _mlp(c, lp, x)
     if quantized:
         return x, (k_pool, v_pool, k_scale_pool, v_scale_pool), aux
     return x, (k_pool, v_pool), aux
@@ -849,9 +869,10 @@ def forward_paged(
 def _forward_paged_impl(params, c, tokens, *, pool, tables,
                         seq_row, positions, write_block, write_off,
                         use_kernel, adapters=None, adapter_ids=None):
-    x = params["embed"][tokens][:, None, :]            # (T, 1, D)
-    cos, sin = rope_cos_sin(positions[:, None], c.head_dim, c.rope_theta,
-                            scaling=c.rope_scaling)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens][:, None, :]        # (T, 1, D)
+        cos, sin = rope_cos_sin(positions[:, None], c.head_dim,
+                                c.rope_theta, scaling=c.rope_scaling)
     aux0 = jnp.zeros((), jnp.float32)
     # Both are STATIC under jit: derived from pytree structure (None-ness
     # and shapes), so the precision ladder never adds a trace argument.
@@ -910,16 +931,18 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         upd.update(k=k_upd, v=v_upd)
     x, _aux = carry
 
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:  # tied embeddings
-        if "tied_head_q8" in params:
-            logits = _dense(x, params, "tied_head_q8", "bsd,vd->bsv")
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        head = params.get("lm_head")
+        if head is None:  # tied embeddings
+            if "tied_head_q8" in params:
+                logits = _dense(x, params, "tied_head_q8", "bsd,vd->bsv")
+            else:
+                logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
         else:
-            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
-    return logits[:, 0].astype(jnp.float32), pool._replace(**upd)
+            logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
+        logits = logits[:, 0].astype(jnp.float32)
+    return logits, pool._replace(**upd)
 
 
 def count_params(params: Params) -> int:

@@ -12,17 +12,19 @@ Three pieces (docs/observability.md):
 Instrumented hot paths (rl_loop, trainer, engine, agent loop, beam
 search, trace collector) fetch the PROCESS-GLOBAL tracer/registry via
 :func:`get_tracer`/:func:`get_registry` at call time. Tracing defaults
-OFF — a disabled tracer's ``span()`` returns a shared no-op context
-manager, so instrumentation sites cost one branch. Enable with::
+OFF — ``span()`` then returns a shared no-op context manager, so
+instrumentation sites cost one branch. A span is on under
+:func:`enable` or inside a ``jax.profiler`` session, and is then also a
+``TraceAnnotation`` on the device trace's clock. Enable with::
 
     from senweaver_ide_tpu import obs
     obs.enable(span_jsonl="spans.jsonl")     # spans stream as they finish
     ... run a round ...
     obs.get_tracer().write_chrome_trace("trace.json")   # Perfetto-loadable
 
-The registry is always live (per-round telemetry is a handful of dict
-writes); only span recording and per-token engine counters gate on
-:func:`is_enabled`.
+The registry is always live (per-round telemetry and the engine's
+per-step counters are a handful of dict writes); only span recording
+gates on tracing being on.
 """
 
 from __future__ import annotations
@@ -49,7 +51,8 @@ from .training_health import (TrainingHealthConfig, TrainingHealthMonitor,
                               evaluate_health, get_health_monitor,
                               set_health_monitor)
 from .timeline import RequestTimeline, TimelineRecorder
-from .tracing import SpanRecord, Tracer, load_span_jsonl, stitch_summary
+from .tracing import (SpanRecord, Tracer, _set_tracer, get_tracer,
+                      load_span_jsonl, stitch_summary)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
@@ -73,12 +76,7 @@ __all__ = [
 ]
 
 _lock = threading.Lock()
-_tracer = Tracer(enabled=False)
 _registry = MetricsRegistry()
-
-
-def get_tracer() -> Tracer:
-    return _tracer
 
 
 def get_registry() -> MetricsRegistry:
@@ -88,16 +86,17 @@ def get_registry() -> MetricsRegistry:
 def enable(span_jsonl: Optional[str] = None) -> Tracer:
     """Turn on span tracing process-wide (optionally streaming every
     finished span to ``span_jsonl``); returns the global tracer."""
-    _tracer.enable(span_jsonl)
-    return _tracer
+    tracer = get_tracer()
+    tracer.enable(span_jsonl)
+    return tracer
 
 
 def disable() -> None:
-    _tracer.disable()
+    get_tracer().disable()
 
 
 def is_enabled() -> bool:
-    return _tracer.enabled
+    return get_tracer().enabled
 
 
 def traced(name: Optional[str] = None):
@@ -114,10 +113,7 @@ def traced(name: Optional[str] = None):
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            t = _tracer
-            if not t.enabled:
-                return fn(*args, **kwargs)
-            with t.span(span_name):
+            with get_tracer().span(span_name):
                 return fn(*args, **kwargs)
         return wrapper
     return deco
@@ -131,10 +127,9 @@ def _reset_for_tests() -> None:
     MetricsService/PerformanceMonitor built with an explicit registry)
     keep their own references by design.
     """
-    global _tracer, _registry
+    global _registry
     with _lock:
-        old = _tracer
-        _tracer = Tracer(enabled=False)
+        old = _set_tracer(Tracer(enabled=False))
         _registry = MetricsRegistry()
     set_health_monitor(None)   # next get_health_monitor() rebuilds
     set_profiler(None)         # next get_profiler() rebuilds

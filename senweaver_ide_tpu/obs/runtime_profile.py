@@ -16,6 +16,11 @@ maintains, per wrapped function:
   outputs immediately after the call anyway (the engine's single
   batched ``device_get`` per step), so blocking here moves the existing
   sync, it does not add one;
+- two **spans** a call, ``<name>.dispatch`` (the wrapped call: trace,
+  compile, enqueue) and, when blocking, ``<name>.wait`` (its
+  ``block_until_ready``), under whatever span is open around the call —
+  so the caller's span minus these two is the wrapper's own
+  bookkeeping plus whatever else the caller did;
 - **host→device transfer accounting**: bytes of host-resident (numpy)
   leaves fed per call — PR 10 showed the host feed is where wins hide.
   ``profiled_device_get`` is the device→host counterpart;
@@ -57,8 +62,16 @@ import jax
 import numpy as np
 
 from .metrics import DEFAULT_MS_BUCKETS
+from .tracing import get_tracer
 
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/"
+
+# The wrapper's two spans are children: on under an enabled tracer or a
+# span that is open around the call, and never ask the profiler
+# themselves — a caller that found tracing off (the engine asks once a
+# step) pays one context lookup each here.
+def _child_span(name: str):
+    return get_tracer().child_span(name)
 
 # Compile-time buckets: compiles run seconds, not microseconds.
 COMPILE_MS_BUCKETS: Tuple[float, ...] = (
@@ -592,6 +605,8 @@ class ProfiledFunction:
         self.storm_threshold = int(storm_threshold)
         self.mem_every = int(mem_every)
         self._mem_countdown = int(mem_every)
+        self._span_dispatch = name + ".dispatch"
+        self._span_wait = name + ".wait"
 
     @property
     def wrapped(self) -> Callable:
@@ -631,9 +646,11 @@ class ProfiledFunction:
         st.append(frame)
         t0 = time.perf_counter()
         try:
-            out = self._fn(*args, **kwargs)
+            with _child_span(self._span_dispatch):
+                out = self._fn(*args, **kwargs)
             if self.block:
-                out = jax.block_until_ready(out)
+                with _child_span(self._span_wait):
+                    out = jax.block_until_ready(out)
         finally:
             st.pop()
         step_ms = (time.perf_counter() - t0) * 1_000.0

@@ -11,11 +11,23 @@ exporters for JSONL and the Chrome trace-event format — the latter loads
 directly into Perfetto / ``chrome://tracing`` and is the repo's first
 cross-component flamegraph of a full GRPO round.
 
+A span is ON when the tracer is enabled (``obs.enable()``) or a JAX
+profiler session is running (``jax.profiler.start_trace`` ..
+``stop_trace``). A span that is on is also a
+``jax.profiler.TraceAnnotation``: inside a session it is an event of the
+trace's ``/host:CPU`` plane, on the clock of the device's ``XLA Ops``, so
+a device gap can be put to the host phase that covers it. Its record
+carries ``start_ns``/``end_ns`` from ``time.perf_counter_ns()`` — the
+clock a driving loop stamps its steps and tokens with — beside the epoch
+``start_s`` the exporters use.
+
 Design constraints, in order:
-1. Disabled tracing must be free: ``span()`` on a disabled tracer
-   returns one shared no-op context manager (a bool check + two empty
-   method calls on the hot path — RLAX/Podracer-style always-on
-   instrumentation sites stay in the code, the cost does not).
+1. Tracing that is off must be free: ``span()`` then returns one shared
+   no-op context manager (a bool check, one ``is_enabled()`` call of
+   ~0.02 us, two empty method calls — RLAX/Podracer-style always-on
+   instrumentation sites stay in the code, the cost does not). A hot
+   loop asks :meth:`Tracer.active` once and picks ``span`` or
+   :func:`noop_span` for all its sites.
 2. Recording never raises into the instrumented caller.
 3. Thread-safe: rollout episodes record from a thread pool.
 """
@@ -25,25 +37,49 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import itertools
 import json
 import os
 import threading
 import time
-import uuid
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # (trace_id, span_id) of the active span in this execution context.
 _Ctx = Tuple[str, str]
 
+# Ids: a counter on a random 64-bit base — unique in this process,
+# and as unlikely as a uuid to meet another process's in a stitched
+# trace, at a tenth of uuid4's cost (two ids a span, ten spans a step).
+_ID_BASE = int.from_bytes(os.urandom(8), "big")
+_ID_SEQ = itertools.count(1)
+
 
 def _new_id() -> str:
-    return uuid.uuid4().hex[:16]
+    return f"{(_ID_BASE + next(_ID_SEQ)) & 0xFFFFFFFFFFFFFFFF:016x}"
+
+
+def _annotation(name: str, attrs: Dict[str, Any]) -> TraceAnnotation:
+    """The profiler-trace twin of a span: scalar attrs ride as the
+    event's stats, anything else stays in the SpanRecord only."""
+    if not attrs:
+        return TraceAnnotation(name)
+    return TraceAnnotation(name, **{
+        k: v for k, v in attrs.items()
+        if isinstance(v, (bool, int, float, str))})
+
+
+# Epoch seconds of perf_counter_ns() == 0: a span reads one clock and
+# its ``start_s`` follows from it.
+_EPOCH_S = time.time() - time.perf_counter_ns() / 1e9
 
 
 @dataclasses.dataclass
 class SpanRecord:
-    """One finished span. ``start_s`` is epoch seconds; durations are ms."""
+    """One finished span. ``start_s`` is epoch seconds; durations are ms;
+    ``start_ns``/``end_ns`` are ``time.perf_counter_ns()`` readings."""
     name: str
     trace_id: str
     span_id: str
@@ -53,6 +89,8 @@ class SpanRecord:
     thread: str
     tid: int
     attrs: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    start_ns: int = 0
+    end_ns: int = 0
 
     def to_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -72,10 +110,16 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def noop_span(name: str, **attrs: Any) -> _NoopSpan:
+    """``Tracer.span``'s stand-in for a loop that asked
+    :meth:`Tracer.active` once and got False."""
+    return _NOOP
+
+
 class _ActiveSpan:
-    """Context manager for one live span on an enabled tracer."""
+    """Context manager for one live span on a tracer that is on."""
     __slots__ = ("_tracer", "_name", "_attrs", "_token", "_ctx", "_t0",
-                 "_start_s")
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -90,26 +134,28 @@ class _ActiveSpan:
         self._ctx = (trace_id, span_id,
                      parent[1] if parent else None)
         self._token = tracer._ctx.set((trace_id, span_id))
-        self._start_s = time.time()
-        self._t0 = time.perf_counter()
+        self._ann = _annotation(self._name, self._attrs)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def set_attr(self, key: str, value: Any) -> None:
         self._attrs[key] = value
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        duration_ms = (time.perf_counter() - self._t0) * 1000.0
+        end_ns = time.perf_counter_ns()
+        self._ann.__exit__(exc_type, exc, tb)
         tracer = self._tracer
         tracer._ctx.reset(self._token)
         if exc_type is not None:
             self._attrs["error"] = f"{exc_type.__name__}: {exc}"
         trace_id, span_id, parent_id = self._ctx
         cur = threading.current_thread()
+        t0 = self._t0
         tracer._record(SpanRecord(
-            name=self._name, trace_id=trace_id, span_id=span_id,
-            parent_id=parent_id, start_s=self._start_s,
-            duration_ms=duration_ms, thread=cur.name, tid=cur.ident or 0,
-            attrs=self._attrs))
+            self._name, trace_id, span_id, parent_id, _EPOCH_S + t0 / 1e9,
+            (end_ns - t0) / 1e6, cur.name, cur.ident or 0, self._attrs,
+            t0, end_ns))
         return False
 
 
@@ -136,22 +182,48 @@ class Tracer:
 
     # -- recording ----------------------------------------------------------
 
+    def active(self) -> bool:
+        """Would a span opened now record? True when the tracer is
+        enabled or a JAX profiler session is running."""
+        return self.enabled or TraceAnnotation.is_enabled()
+
     def span(self, name: str, **attrs: Any):
-        """``with tracer.span("collect", tasks=3):`` — no-op when disabled."""
-        if not self.enabled:
+        """``with tracer.span("collect", tasks=3):`` — no-op when off."""
+        if not self.active():
             return _NOOP
         return _ActiveSpan(self, name, attrs)
 
+    def child_span(self, name: str):
+        """A span that is on only under an enabled tracer or inside a
+        span that is open around the caller; never asks the profiler. For
+        a callee of a loop that asked :meth:`active` once: where the loop
+        found tracing off the callee pays one attribute read and one
+        context lookup."""
+        if self.enabled or self._ctx.get() is not None:
+            return _ActiveSpan(self, name, {})
+        return _NOOP
+
+    def record_span(self, name: str, start_ns: int, end_ns: int, *,
+                    trace_id: str, parent_id: Optional[str] = None,
+                    **attrs: Any) -> None:
+        """Record a span whose ends (``time.perf_counter_ns()`` readings)
+        are known only afterwards — a request's phases. In memory and in
+        the exporters only: the profiler's trace takes no event after
+        the fact. The caller decides whether tracing is on."""
+        cur = threading.current_thread()
+        self._record(SpanRecord(
+            name, trace_id, _new_id(), parent_id,
+            _EPOCH_S + start_ns / 1e9, (end_ns - start_ns) / 1e6,
+            cur.name, cur.ident or 0, attrs, start_ns, end_ns))
+
     def traced(self, name: Optional[str] = None) -> Callable:
-        """Decorator form of :meth:`span`; enabled-check happens per call."""
+        """Decorator form of :meth:`span`; the on-check happens per call."""
         def deco(fn: Callable) -> Callable:
             import functools
             span_name = name or fn.__qualname__
 
             @functools.wraps(fn)
             def wrapper(*args, **kwargs):
-                if not self.enabled:
-                    return fn(*args, **kwargs)
                 with self.span(span_name):
                     return fn(*args, **kwargs)
             return wrapper
@@ -198,7 +270,7 @@ class Tracer:
             ctx = tracer.capture()
             pool.submit(lambda: run_under(tracer, ctx))
         """
-        if not self.enabled or ctx is None:
+        if ctx is None or not self.active():
             return _NOOP
         return self._attach_cm(ctx)
 
@@ -370,3 +442,18 @@ def load_span_jsonl(path: str) -> List[SpanRecord]:
             except (json.JSONDecodeError, TypeError):
                 pass
     return out
+
+
+# The process's tracer: ``obs.get_tracer`` is this function.
+_TRACER = Tracer(enabled=False)
+
+
+def get_tracer() -> Tracer:
+    return _TRACER
+
+
+def _set_tracer(tracer: Tracer) -> Tracer:
+    """Swap the process's tracer (test isolation); returns the old one."""
+    global _TRACER
+    old, _TRACER = _TRACER, tracer
+    return old

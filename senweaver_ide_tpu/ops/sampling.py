@@ -70,14 +70,15 @@ def sample_token(
     uniform sampling — and paid a full-vocab sort on every decode step).
     ``top_p_cutoff`` selects the bounded-candidate nucleus path (see
     apply_top_p); pass None for the exact full-sort."""
-    if temperature == 0.0:
-        return jnp.argmax(logits, axis=-1)
-    x = apply_temperature(logits, temperature)
-    if top_k > 0:
-        x = apply_top_k(x, top_k)
-    if 0.0 < top_p < 1.0:
-        x = apply_top_p(x, top_p, cutoff=top_p_cutoff)
-    return jax.random.categorical(key, x, axis=-1)
+    with jax.named_scope("sample"):
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1)
+        x = apply_temperature(logits, temperature)
+        if top_k > 0:
+            x = apply_top_k(x, top_k)
+        if 0.0 < top_p < 1.0:
+            x = apply_top_p(x, top_p, cutoff=top_p_cutoff)
+        return jax.random.categorical(key, x, axis=-1)
 
 
 def sampled_logprob(logits: jnp.ndarray, token: jnp.ndarray) -> jnp.ndarray:
@@ -88,5 +89,6 @@ def sampled_logprob(logits: jnp.ndarray, token: jnp.ndarray) -> jnp.ndarray:
     network's own log p(token), NOT the temperature/top-k/top-p-shaped
     sampling distribution — it must match ``token_logprobs`` computed
     by the trainer over the same network (training/grpo.py)."""
-    logz = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    return jnp.take_along_axis(logz, token[..., None], axis=-1)[..., 0]
+    with jax.named_scope("sample"):
+        logz = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        return jnp.take_along_axis(logz, token[..., None], axis=-1)[..., 0]

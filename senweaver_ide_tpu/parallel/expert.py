@@ -142,12 +142,14 @@ def moe_ffn(params: MoEParams, cfg: MoEConfig,
     b, s, d = x.shape
     x_flat = x.reshape(b * s, d)
     C = _capacity(cfg, b * s)
-    dispatch, combine, aux = _route(cfg, params["router"], x_flat, C)
-    expert_in = jnp.einsum("tec,td->ecd", dispatch,
-                           x_flat.astype(jnp.float32)).astype(x.dtype)
-    expert_out = _run_experts(params, expert_in)
-    y = jnp.einsum("tec,ecd->td", combine,
-                   expert_out.astype(jnp.float32))
+    with jax.named_scope("moe.route"):
+        dispatch, combine, aux = _route(cfg, params["router"], x_flat, C)
+    with jax.named_scope("moe.experts"):
+        expert_in = jnp.einsum("tec,td->ecd", dispatch,
+                               x_flat.astype(jnp.float32)).astype(x.dtype)
+        expert_out = _run_experts(params, expert_in)
+        y = jnp.einsum("tec,ecd->td", combine,
+                       expert_out.astype(jnp.float32))
     return y.reshape(b, s, d).astype(x.dtype), aux
 
 

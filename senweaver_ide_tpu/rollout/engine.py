@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
+import time
 from collections import deque
 from typing import Deque, Dict, List, Optional
 
@@ -38,6 +39,7 @@ from ..models.transformer import (KVCache, Params, forward, forward_paged,
                                   init_kv_cache)
 from ..obs import get_registry, get_tracer
 from ..obs.runtime_profile import ProfiledFunction, profiled_device_get
+from ..obs.tracing import noop_span
 from ..ops.sampling import sample_token, sampled_logprob
 from .kv_pressure import (HostPrefix, PrefixCandidate, dequantize_host,
                           pick_victim, should_tier)
@@ -540,6 +542,18 @@ class _Request:
     parent_rid: Optional[int] = None
     branch_pos: Optional[int] = None
     branch_depth: int = 0
+    # The request's life on time.perf_counter_ns(), stamped whether or
+    # not tracing is on (a request submitted before a trace begins still
+    # has to know its submit time when its first token falls inside it):
+    # made, first placed in a row (kept across preemption), first token
+    # in hand, finished; `row` is the row it last ran in (`slot` is
+    # cleared at the finish). Read by the request.* spans.
+    t_submit_ns: int = dataclasses.field(
+        default_factory=time.perf_counter_ns)
+    t_scheduled_ns: Optional[int] = None
+    t_first_token_ns: Optional[int] = None
+    t_done_ns: Optional[int] = None
+    row: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -802,6 +816,19 @@ class RolloutEngine:
         # fleet load signal (remaining decode tokens) pushed by the
         # serving replica for the depth controller; None = standalone
         self._spec_fleet_tokens: Optional[float] = None  # guarded-by: _lock
+        # Per-step counters, published whether or not span tracing is on
+        # (instruments fetched once, as the speculative counters are).
+        reg = get_registry()
+        self._steps_total = reg.counter(
+            "senweaver_engine_decode_steps_total",
+            "Pool decode steps executed.")
+        self._tokens_total = reg.counter(
+            "senweaver_engine_tokens_total",
+            "Tokens emitted by the rollout engine.")
+        # Is span tracing on? Asked once at the top of a step and true
+        # only inside it: the step's span sites and the request.* spans
+        # of phases that end in the step read it instead of asking again.
+        self._trace_on = False                  # guarded-by: _lock
         # Many agent loops (subagent threads) drive one engine: all state
         # mutation is serialized; concurrency = slots, not host threads.
         self._lock = threading.RLock()
@@ -1279,6 +1306,7 @@ class RolloutEngine:
                 plen = self._row_len[row]
                 nblk = self._alloc.blocks_for(plen)
                 child.slot = crow
+                self._mark_scheduled(child, crow)
                 self._slot_req[crow] = child
                 self._tables[crow] = self._alloc.fork(
                     self._tables[row][:nblk])
@@ -1318,54 +1346,52 @@ class RolloutEngine:
 
     def _step(self) -> Dict[int, List[int]]:
         # guarded-by: caller
-        if self.kv_layout == "paged":
-            return self._step_paged()
-        self._schedule()
-        emitted = self._pending_emits
-        self._pending_emits = {}
-        active_list = [r is not None for r in self._slot_req]
-        if not any(active_list):
+        self._trace_on = get_tracer().active()
+        span = get_tracer().span if self._trace_on else noop_span
+        try:
+            if self.kv_layout == "paged":
+                return self._step_paged(span)
+            self._schedule()
+            emitted = self._pending_emits
+            self._pending_emits = {}
+            active_list = [r is not None for r in self._slot_req]
+            if not any(active_list):
+                return emitted
+            with span("engine.decode_step", active=sum(active_list)):
+                active = jnp.asarray(active_list)
+                self._key, step_key = jax.random.split(self._key)
+                next_tok, logp, self.cache = _pool_decode_step(
+                    self.params, self.config, self.cur_tok, active, self.cache,
+                    step_key, self.sample)
+                self.cur_tok = next_tok
+                self._stats["decode_steps"] += 1
+                # ONE batched device→host transfer per decode step (the
+                # analysis JIT110 budget): three separate np.asarray calls
+                # were three blocking roundtrips. device_get still blocks on
+                # the device step, so the span spans the actual decode, not
+                # just its dispatch.
+                toks, logps, lengths = profiled_device_get(
+                    (next_tok, logp, self.cache.length),
+                    fn="engine.decode_step")
+            self._steps_total.inc()
+            self._tokens_total.inc(sum(active_list))
+            for slot, req in enumerate(self._slot_req):
+                if req is None:
+                    continue
+                tok = int(toks[slot])
+                req.tokens.append(tok)
+                req.logps.append(float(logps[slot]))
+                self._stats["tokens_emitted"] += 1
+                emitted.setdefault(req.rid, []).append(tok)
+                hit_eos = req.eos_id is not None and tok == req.eos_id
+                out_of_budget = len(req.tokens) >= req.max_new_tokens
+                out_of_cache = int(lengths[slot]) >= self.context_bound - 1
+                if hit_eos or out_of_budget or out_of_cache:
+                    self._finish_request(req, slot)
+            self._schedule()
             return emitted
-        tracer = get_tracer()
-        with tracer.span("engine.decode_step",
-                         active=sum(active_list)):
-            active = jnp.asarray(active_list)
-            self._key, step_key = jax.random.split(self._key)
-            next_tok, logp, self.cache = _pool_decode_step(
-                self.params, self.config, self.cur_tok, active, self.cache,
-                step_key, self.sample)
-            self.cur_tok = next_tok
-            self._stats["decode_steps"] += 1
-            # ONE batched device→host transfer per decode step (the
-            # analysis JIT110 budget): three separate np.asarray calls
-            # were three blocking roundtrips. device_get still blocks on
-            # the device step, so the span spans the actual decode, not
-            # just its dispatch.
-            toks, logps, lengths = profiled_device_get(
-                (next_tok, logp, self.cache.length),
-                fn="engine.decode_step")
-        if tracer.enabled:
-            reg = get_registry()
-            reg.counter("senweaver_engine_decode_steps_total",
-                        "Pool decode steps executed.").inc()
-            reg.counter("senweaver_engine_tokens_total",
-                        "Tokens emitted by the rollout engine."
-                        ).inc(sum(active_list))
-        for slot, req in enumerate(self._slot_req):
-            if req is None:
-                continue
-            tok = int(toks[slot])
-            req.tokens.append(tok)
-            req.logps.append(float(logps[slot]))
-            self._stats["tokens_emitted"] += 1
-            emitted.setdefault(req.rid, []).append(tok)
-            hit_eos = req.eos_id is not None and tok == req.eos_id
-            out_of_budget = len(req.tokens) >= req.max_new_tokens
-            out_of_cache = int(lengths[slot]) >= self.context_bound - 1
-            if hit_eos or out_of_budget or out_of_cache:
-                self._finish_request(req, slot)
-        self._schedule()
-        return emitted
+        finally:
+            self._trace_on = False
 
     def run(self) -> Dict[int, List[int]]:
         """Drive until all submitted requests finish."""
@@ -1480,6 +1506,7 @@ class RolloutEngine:
         req.adapter_binding = prev.adapter_binding
         prev.adapter_binding = None
         self._requests[rid] = req
+        self._mark_scheduled(req, slot)
         self._slot_held[slot] = None
         self._slot_req[slot] = req
         if self.kv_layout == "paged":
@@ -1877,6 +1904,8 @@ class RolloutEngine:
         req.tokens.append(tok0_i)
         req.logps.append(float(logp0_h))
         self._stats["tokens_emitted"] += 1
+        self._tokens_total.inc()
+        self._mark_first_token(req)
         self._pending_emits.setdefault(req.rid, []).append(tok0_i)
         if self.kv_layout == "paged":
             self._cur_tok_host[slot] = tok0_i
@@ -1885,6 +1914,48 @@ class RolloutEngine:
         if ((req.eos_id is not None and tok0_i == req.eos_id)
                 or req.max_new_tokens <= 1):
             self._finish_request(req, slot)
+
+    def _mark_scheduled(self, req: "_Request", row: int) -> None:
+        # guarded-by: caller
+        """A request was placed in a row. The first placement is the end
+        of its queue phase (a preempted request keeps it); one that
+        arrives with tokens in hand (a fork's adopted token, a migrated
+        request) has no prefill phase."""
+        req.row = row
+        if req.t_scheduled_ns is not None:
+            return
+        req.t_scheduled_ns = time.perf_counter_ns()
+        if req.tokens:
+            req.t_first_token_ns = req.t_scheduled_ns
+        if self._trace_on:
+            self._record_phase(req, "request.queue", req.t_submit_ns,
+                               req.t_scheduled_ns)
+
+    def _mark_first_token(self, req: "_Request") -> None:
+        # guarded-by: caller
+        if req.t_first_token_ns is not None:
+            return
+        req.t_first_token_ns = time.perf_counter_ns()
+        if self._trace_on and req.t_scheduled_ns is not None:
+            self._record_phase(req, "request.prefill", req.t_scheduled_ns,
+                               req.t_first_token_ns)
+
+    def _record_phase(self, req: "_Request", name: str, start_ns: int,
+                      end_ns: int) -> None:
+        # guarded-by: caller
+        """One phase of a request's life as a span; the three of one
+        request share ``trace_id``. Only phases that end inside a step
+        that found tracing on are recorded."""
+        g = req.group
+        get_tracer().record_span(
+            name, start_ns, end_ns, trace_id=f"req-{req.rid}",
+            rid=req.rid, row=req.row, prompt_tokens=len(req.prompt),
+            output_tokens=len(req.tokens),
+            # a submit_group follower that took the donor's spine
+            grafted=(g is not None and req.rid != g.donor_rid
+                     and not g.degraded),
+            prefix_hit=req.prefix_id is not None,
+            preempts=req.preempt_count)
 
     def _group_degrade_if_uncaptured(self, req: "_Request") -> None:
         # guarded-by: caller
@@ -1928,6 +1999,10 @@ class RolloutEngine:
         # guarded-by: caller
         """Mark a request done and either hold or free its slot."""
         req.done = True
+        req.t_done_ns = time.perf_counter_ns()
+        if self._trace_on and req.t_first_token_ns is not None:
+            self._record_phase(req, "request.decode",
+                               req.t_first_token_ns, req.t_done_ns)
         self._group_degrade_if_uncaptured(req)
         self._group_forget_follower(req)
         self._slot_req[slot] = None
@@ -2057,6 +2132,7 @@ class RolloutEngine:
     def _schedule_single_impl(self, req: "_Request", slot: int) -> None:
         # guarded-by: caller
         req.slot = slot
+        self._mark_scheduled(req, slot)
         self._slot_req[slot] = req
         true_len = len(req.prompt)
         self._stats["prefills"] += 1
@@ -2130,6 +2206,7 @@ class RolloutEngine:
         rows, lens, slot_ids = [], [], []
         for req, slot in zip(group, slots):
             req.slot = slot
+            self._mark_scheduled(req, slot)
             self._slot_req[slot] = req
             rows.append(req.prompt + [0] * (bucket - len(req.prompt)))
             lens.append(len(req.prompt))
@@ -2763,6 +2840,7 @@ class RolloutEngine:
         # guarded-by: caller
         req.slot = row
         self._slot_req[row] = req
+        self._mark_scheduled(req, row)
         g = req.group
         group_graft = (g is not None and g.spine is not None
                        and not g.degraded and req.rid != g.donor_rid
@@ -2995,47 +3073,125 @@ class RolloutEngine:
         return (toks_l, rows_l, pos_l, wb_l, wo_l, decode_rows,
                 spec_rows, job_rows, aid)
 
-    def _step_paged(self) -> Dict[int, List[int]]:
+    def _step_paged(self, span) -> Dict[int, List[int]]:
         # guarded-by: caller
-        self._schedule()
-        emitted = self._pending_emits
-        self._pending_emits = {}
-        depth, spec_plan = self._spec_begin_step()
-        plan = self._assemble_paged_plan(spec_plan, depth)
-        if plan is None:
-            return emitted
-        (toks_l, rows_l, pos_l, wb_l, wo_l, decode_rows, spec_rows,
-         job_rows, aid) = plan
-        adapters = adapter_ids = None
-        if aid is not None:
-            # Fixed-shape banks + (T,)-ladder id vectors ride every
-            # call — the only adapter-dependent state the jit sees.
-            adapters = self.adapter_pool.banks()
-            adapter_ids = tuple(np.asarray(g, np.int32) for g in aid)
-        tracer = get_tracer()
-        n_active = len(decode_rows) + len(spec_rows) + len(job_rows)
-        with tracer.span("engine.decode_step", active=n_active):
-            self._key, step_key = jax.random.split(self._key)
-            # host numpy in, device out: the five plan vectors enter
-            # the jit as numpy (single C++ ingest each); jnp.asarray
-            # here would cost a full dispatch per vector per step —
-            # profiled at ~half the paged step's host time
-            next_tok, logp, self.pool = _paged_fused_step(
-                self.params, self.config,
-                np.asarray(toks_l, np.int32), self._tables_device(),
-                np.asarray(rows_l, np.int32),
-                np.asarray(pos_l, np.int32),
-                np.asarray(wb_l, np.int32),
-                np.asarray(wo_l, np.int32),
-                self.pool, step_key, self.sample,
-                self._use_paged_kernel,
-                adapters=adapters, adapter_ids=adapter_ids)
+        """One fused step in four host phases, each a span where the
+        device may wait for the host (docs/observability.md): plan,
+        launch, fetch, emit. ``span`` is the tracer's ``span`` when the
+        step found tracing on, else the no-op."""
+        with span("engine.step", step=self._stats["decode_steps"]) as st:
+            with span("engine.plan") as sp:
+                rows0 = list(self._slot_req) if sp is not None else ()
+                with span("engine.schedule"):
+                    self._schedule()
+                emitted = self._pending_emits
+                self._pending_emits = {}
+                depth, spec_plan = self._spec_begin_step()
+                with span("engine.assemble_plan"):
+                    plan = self._assemble_paged_plan(spec_plan, depth)
+                if sp is not None:
+                    sp.set_attr("admitted", self._placed_since(rows0))
+                if plan is None:
+                    return emitted
+                (toks_l, rows_l, pos_l, wb_l, wo_l, decode_rows, spec_rows,
+                 job_rows, adapter_ids) = plan
+                adapters = None
+                if adapter_ids is not None:
+                    # Fixed-shape banks + (T,)-ladder id vectors ride
+                    # every call — the only adapter-dependent state the
+                    # jit sees.
+                    adapters = self.adapter_pool.banks()
+                    adapter_ids = tuple(np.asarray(g, np.int32)
+                                        for g in adapter_ids)
+                with span("engine.tables"):
+                    tables = self._tables_device()
+                # host numpy in, device out: the five plan vectors enter
+                # the jit as numpy (single C++ ingest each); jnp.asarray
+                # here would cost a full dispatch per vector per step —
+                # profiled at ~half the paged step's host time
+                vectors = tuple(
+                    np.asarray(v, np.int32)
+                    for v in (toks_l, rows_l, pos_l, wb_l, wo_l))
+            if st is not None:
+                prefill = sum(j[3] for j in job_rows)
+                st.set_attr("entries", len(toks_l))
+                st.set_attr("used", len(decode_rows) + prefill
+                            + sum(len(r[3]) for r in spec_rows))
+                st.set_attr("decode_rows", len(decode_rows))
+                st.set_attr("prefill_tokens", prefill)
+                st.set_attr("table_width", int(tables.shape[1]))
+                st.set_attr("queue_depth", len(self._queue))
+                st.set_attr("rows_active", len(decode_rows)
+                            + len(spec_rows) + len(job_rows))
+            toks, logps = self._launch_paged(span, vectors, tables,
+                                             adapters, adapter_ids)
             self._stats["decode_steps"] += 1
-            # ONE batched device→host transfer per fused step (the
-            # analysis JIT110 budget), covering decode tokens AND the
-            # first tokens of completing prefills.
-            toks, logps = profiled_device_get((next_tok, logp),
-                                              fn="engine.fused_step")
+            with span("engine.fetch") as sp:
+                # ONE batched device→host transfer per fused step (the
+                # analysis JIT110 budget), covering decode tokens AND the
+                # first tokens of completing prefills.
+                toks, logps = profiled_device_get((toks, logps),
+                                                  fn="engine.fused_step")
+                if sp is not None:
+                    sp.set_attr("bytes", int(toks.nbytes + logps.nbytes))
+            with span("engine.emit") as sp:
+                rows0 = list(self._slot_req) if sp is not None else ()
+                n_emitted = self._emit_paged(toks, logps, decode_rows,
+                                             spec_rows, job_rows, emitted)
+                self._steps_total.inc()
+                self._tokens_total.inc(n_emitted)
+                self._publish_fragmentation()
+                with span("engine.schedule"):
+                    self._schedule()
+                if sp is not None:
+                    sp.set_attr("emitted", n_emitted)
+                    sp.set_attr("finished",
+                                sum(r is not None and r.done for r in rows0))
+                    sp.set_attr("admitted", self._placed_since(rows0))
+            return emitted
+
+    def _launch_paged(self, span, vectors, tables, adapters, adapter_ids):
+        # guarded-by: caller
+        """Enqueue the fused step on the plan's five vectors. The span's
+        self time (less the wrapper's ``.dispatch`` and ``.wait``
+        children) is the key split plus the wrapper's own bookkeeping.
+        On the v5e host a new shape's lowering time follows the summed
+        frame sizes from ``step()`` down to this call (PERF.md §6, PR 24):
+        a change to the locals here, in ``_step``/``_step_paged`` or in
+        ``ProfiledFunction.__call__`` can move set-up by seconds."""
+        tokens, seq_row, positions, write_block, write_off = vectors
+        with span("engine.launch"):
+            self._key, step_key = jax.random.split(self._key)
+            next_tok, logp, self.pool = _paged_fused_step(
+                self.params, self.config, tokens, tables, seq_row,
+                positions, write_block, write_off, self.pool, step_key,
+                self.sample, self._use_paged_kernel,
+                adapters=adapters, adapter_ids=adapter_ids)
+        return next_tok, logp
+
+    def _publish_fragmentation(self) -> None:
+        # guarded-by: caller
+        used_tokens = sum(self._row_len[s] for s in range(self.num_slots)
+                          if self._tables[s])
+        for _p_tokens, p_blocks, _last in self._prefixes.values():
+            if p_blocks is not None:  # host-tiered entries hold no pool
+                used_tokens += len(_p_tokens)
+        self._alloc.publish_fragmentation(used_tokens)
+
+    def _placed_since(self, rows0) -> int:
+        # guarded-by: caller
+        """Rows that hold a request they did not hold in ``rows0``, a copy
+        of ``_slot_req``: the placements since (a span's attr; worked
+        out only where tracing is on)."""
+        return sum(r is not None and r is not r0
+                   for r0, r in zip(rows0, self._slot_req))
+
+    def _emit_paged(self, toks, logps, decode_rows, spec_rows, job_rows,
+                    emitted: Dict[int, List[int]]) -> int:
+        # guarded-by: caller
+        """Hand the step's tokens to their requests: decode rows,
+        speculative windows, then completing prefill jobs. Returns the
+        number of tokens emitted."""
         n_emitted = 0
         for idx, row, req in decode_rows:
             tok = int(toks[idx])
@@ -3145,24 +3301,11 @@ class RolloutEngine:
                 self._stats["tokens_emitted"] += 1
                 n_emitted += 1
                 emitted.setdefault(req.rid, []).append(tok)
+                self._mark_first_token(req)
                 self._cur_tok_host[row] = tok
                 if ((req.eos_id is not None and tok == req.eos_id)
                         or req.max_new_tokens <= 1):
                     self._finish_request(req, row)
             else:
                 self._cur_tok_host[row] = job.after_tok
-        if tracer.enabled:
-            reg = get_registry()
-            reg.counter("senweaver_engine_decode_steps_total",
-                        "Pool decode steps executed.").inc()
-            reg.counter("senweaver_engine_tokens_total",
-                        "Tokens emitted by the rollout engine."
-                        ).inc(n_emitted)
-        used_tokens = sum(self._row_len[s] for s in range(self.num_slots)
-                          if self._tables[s])
-        for _p_tokens, p_blocks, _last in self._prefixes.values():
-            if p_blocks is not None:  # host-tiered entries hold no pool
-                used_tokens += len(_p_tokens)
-        self._alloc.publish_fragmentation(used_tokens)
-        self._schedule()
-        return emitted
+        return n_emitted

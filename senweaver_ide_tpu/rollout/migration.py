@@ -370,6 +370,7 @@ def restore_into_engine(engine, ckpt: DecodeCheckpoint) -> int:
                 engine._alloc.count_install_copy(nblk)
                 row = free[0]
                 req.slot = row
+                engine._mark_scheduled(req, row)
                 engine._slot_req[row] = req
                 engine._tables[row] = list(blocks)
                 engine._row_len[row] = int(ckpt.kv_len)

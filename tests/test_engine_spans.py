@@ -1,0 +1,360 @@
+"""Spans inside ``RolloutEngine.step()`` (paged path) and over a request's
+life: names, nesting, the counts their attrs carry, the profiler-session
+path onto the device trace's clock, and what the off path costs."""
+
+import glob
+import os
+
+import jax
+import numpy as np
+import pytest
+
+from senweaver_ide_tpu import obs
+from senweaver_ide_tpu.models import init_params, tiny_test
+from senweaver_ide_tpu.models.config import tiny_moe_test
+from senweaver_ide_tpu.rollout import EngineConfig, RolloutEngine
+from senweaver_ide_tpu.rollout import engine as engine_mod
+from senweaver_ide_tpu.rollout.sampler import SampleParams
+
+SAMPLED = SampleParams(temperature=1.0, top_k=0, top_p=0.9)
+STEP_CHILDREN = ("engine.plan", "engine.launch", "engine.fetch",
+                 "engine.emit")
+PLAN_CHILDREN = ("engine.schedule", "engine.assemble_plan", "engine.tables")
+LAUNCH_CHILDREN = ("engine.fused_step.dispatch", "engine.fused_step.wait")
+PROMPTS = ([5, 9, 2, 7, 1, 3, 8, 4, 6, 2, 9, 1, 7, 3, 5, 8, 2, 4, 6, 1,
+            3, 5], [11, 3, 8, 1, 4], [2, 6, 4, 9, 9, 1, 2])
+
+
+@pytest.fixture(autouse=True)
+def _fresh_obs():
+    obs._reset_for_tests()
+    yield
+    obs._reset_for_tests()
+
+
+@pytest.fixture(scope="module")
+def model():
+    config = tiny_test()
+    return init_params(config, jax.random.PRNGKey(0)), config
+
+
+def make_engine(model, num_slots=4, seed=3, **cfg):
+    params, config = model
+    cfg.setdefault("block_size", 4)
+    cfg.setdefault("step_tokens", 16)
+    return RolloutEngine(params, config, num_slots=num_slots, max_len=64,
+                         sample=SAMPLED, seed=seed,
+                         engine_config=EngineConfig(kv_layout="paged",
+                                                    **cfg))
+
+
+def drive(eng, group=True):
+    """Three plain requests (one longer than a step's budget) and a group
+    of three, driven until the engine is idle."""
+    rids = [eng.submit(p, max_new_tokens=6) for p in PROMPTS]
+    if group:
+        rids += eng.submit_group([3, 4, 5, 6, 7, 8], 3, max_new_tokens=5)
+    while eng.has_work:
+        eng.step()
+    return rids
+
+
+def by_name(spans):
+    out = {}
+    for s in spans:
+        out.setdefault(s.name, []).append(s)
+    return out
+
+
+def test_every_step_has_the_named_phases_nested_in_it(model):
+    eng = make_engine(model)
+    obs.enable()
+    drive(eng)
+    spans = obs.get_tracer().spans()
+    byid = {s.span_id: s for s in spans}
+    names = by_name(spans)
+    steps = names["engine.step"]
+    assert len(steps) == eng.stats()["decode_steps"]
+    assert "engine.decode_step" not in names
+    for step in steps:
+        kids = [s for s in spans if s.parent_id == step.span_id]
+        assert [k.name for k in kids] == list(STEP_CHILDREN)
+        plan, launch, _fetch, emit = kids
+        sub = lambda p: [s.name for s in spans if s.parent_id == p.span_id]
+        assert [n for n in sub(plan) if n in PLAN_CHILDREN] == \
+            list(PLAN_CHILDREN)
+        assert sub(launch) == list(LAUNCH_CHILDREN)
+        assert sub(emit) == ["engine.schedule"]
+    # every child lies inside its parent, on the perf_counter_ns clock
+    for s in spans:
+        if s.name.startswith("engine."):
+            assert s.end_ns >= s.start_ns > 0
+            assert s.duration_ms == pytest.approx(
+                (s.end_ns - s.start_ns) / 1e6)
+            if s.parent_id is not None:
+                p = byid[s.parent_id]
+                assert p.start_ns <= s.start_ns and s.end_ns <= p.end_ns
+                assert p.trace_id == s.trace_id
+
+
+def test_step_attrs_add_up_to_the_engines_own_counts(model):
+    eng = make_engine(model)
+    obs.enable()
+    drive(eng)
+    st = eng.stats()
+    steps = by_name(obs.get_tracer().spans())["engine.step"]
+    a = [s.attrs for s in steps]
+    assert [x["step"] for x in a] == list(range(len(a)))
+    assert sum(x["prefill_tokens"] for x in a) == st["prefill_tokens"]
+    # every request's first token came from a prefill; the rest from
+    # decode rows
+    n_requests = len(PROMPTS) + 3
+    assert sum(x["decode_rows"] for x in a) == \
+        st["tokens_emitted"] - n_requests
+    for x in a:
+        assert x["entries"] in (eng.num_slots, 16)     # the compiled widths
+        assert x["used"] == x["decode_rows"] + x["prefill_tokens"]
+        assert 0 < x["used"] <= x["entries"]
+        assert 0 < x["rows_active"] <= eng.num_slots
+        assert x["table_width"] in (1, 2, 4, 8, 16)
+    assert any(x["entries"] == 16 for x in a)
+    assert any(x["entries"] == eng.num_slots for x in a)
+    spans = by_name(obs.get_tracer().spans())
+    # a request is placed by a step's leading or its trailing schedule
+    assert sum(s.attrs["admitted"] for s in spans["engine.plan"]
+               + spans["engine.emit"]) == n_requests
+    assert spans["engine.plan"][0].attrs["admitted"] == len(PROMPTS) + 1
+    assert sum(s.attrs["emitted"] for s in spans["engine.emit"]) == \
+        st["tokens_emitted"]
+    assert sum(s.attrs["finished"] for s in spans["engine.emit"]) == \
+        n_requests
+    assert all(s.attrs["bytes"] > 0 for s in spans["engine.fetch"])
+
+
+def test_a_requests_three_phases_share_an_id_and_abut(model):
+    eng = make_engine(model)
+    obs.enable()
+    rids = drive(eng)
+    spans = obs.get_tracer().spans()
+    for rid in rids:
+        mine = {s.name: s for s in spans if s.trace_id == f"req-{rid}"}
+        assert set(mine) == {"request.queue", "request.prefill",
+                             "request.decode"}
+        q, p, d = (mine[n] for n in ("request.queue", "request.prefill",
+                                     "request.decode"))
+        assert q.end_ns == p.start_ns and p.end_ns == d.start_ns
+        assert q.start_ns <= q.end_ns <= p.end_ns <= d.end_ns
+        req = eng._requests[rid]
+        assert (q.start_ns, q.end_ns, p.end_ns, d.end_ns) == (
+            req.t_submit_ns, req.t_scheduled_ns, req.t_first_token_ns,
+            req.t_done_ns)
+        assert d.attrs["rid"] == rid and d.attrs["row"] == req.row
+        assert d.attrs["output_tokens"] == len(req.tokens)
+        assert d.attrs["prompt_tokens"] == len(req.prompt)
+        assert d.attrs["preempts"] == 0 and not d.attrs["prefix_hit"]
+    # the group's followers took the donor's spine; nobody else did
+    grafted = {rid: next(s for s in spans if s.trace_id == f"req-{rid}"
+                         and s.name == "request.prefill").attrs["grafted"]
+               for rid in rids}
+    assert [grafted[r] for r in rids] == [False] * 4 + [True, True]
+
+
+def test_request_stamps_are_set_with_tracing_off(model):
+    eng = make_engine(model)
+    rids = drive(eng)
+    assert obs.get_tracer().spans() == []
+    for rid in rids:
+        r = eng._requests[rid]
+        assert r.t_submit_ns <= r.t_scheduled_ns <= r.t_first_token_ns \
+            <= r.t_done_ns
+        assert r.row is not None and r.slot is None
+
+
+def test_a_preempted_request_keeps_its_first_scheduled_stamp(model):
+    # 6 blocks cannot hold two 16-token rollouts: one is preempted
+    eng = make_engine(model, num_slots=2, num_blocks=6)
+    obs.enable()
+    rids = [eng.submit(p, max_new_tokens=12)
+            for p in ([5, 9, 2, 7], [11, 3, 8, 1])]
+    first = {}
+    while eng.has_work:
+        eng.step()
+        for rid in rids:
+            t = eng._requests[rid].t_scheduled_ns
+            if t is not None:
+                assert first.setdefault(rid, t) == t
+    assert eng.stats()["kv_preemptions"] >= 1
+    hit = [r for r in rids if eng._requests[r].preempt_count]
+    assert hit
+    spans = obs.get_tracer().spans()
+    for rid in hit:
+        mine = [s for s in spans if s.trace_id == f"req-{rid}"]
+        assert sorted(s.name for s in mine) == [
+            "request.decode", "request.prefill", "request.queue"]
+        dec = next(s for s in mine if s.name == "request.decode")
+        assert dec.attrs["preempts"] == eng._requests[rid].preempt_count
+
+
+def test_profiler_session_alone_turns_spans_on_and_lands_them_in_the_trace(
+        model, tmp_path):
+    """The shared-clock path, without a chip: the tracer stays disabled, a
+    ``jax.profiler`` session runs, and the engine's spans are both in
+    memory and events of the trace's host plane."""
+    from jax.profiler import ProfileData
+    eng = make_engine(model)
+    eng.submit(PROMPTS[1], max_new_tokens=2)
+    while eng.has_work:                 # compile outside the session
+        eng.step()
+    assert obs.get_tracer().spans() == [] and not obs.is_enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.submit(PROMPTS[1], max_new_tokens=3)
+        while eng.has_work:
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    names = by_name(obs.get_tracer().spans())
+    assert len(names["engine.step"]) >= 3
+    assert {"engine.plan", "engine.fused_step.dispatch",
+            "request.decode"} <= set(names)
+    eng.submit(PROMPTS[1], max_new_tokens=2)      # session over: off again
+    n = len(obs.get_tracer().spans())
+    while eng.has_work:
+        eng.step()
+    assert len(obs.get_tracer().spans()) == n
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("engine."):
+                        found.setdefault(e.name.split("#")[0], []).append(e)
+    assert len(found["engine.step"]) == len(names["engine.step"])
+    assert len(found["engine.plan"]) == len(names["engine.plan"])
+    step, plan = found["engine.step"][0], found["engine.plan"][0]
+    assert step.start_ns <= plan.start_ns
+    assert plan.start_ns + plan.duration_ns <= \
+        step.start_ns + step.duration_ns
+
+
+def test_off_path_asks_the_profiler_once_a_step_and_records_nothing(
+        model, monkeypatch):
+    from jax.profiler import TraceAnnotation
+    eng = make_engine(model)
+    calls = []
+    monkeypatch.setattr(TraceAnnotation, "is_enabled",
+                        staticmethod(lambda: calls.append(1) or False))
+    rids = [eng.submit(p, max_new_tokens=4) for p in PROMPTS]
+    rids += eng.submit_group([3, 4, 5, 6, 7, 8], 2, max_new_tokens=3)
+    steps = 0
+    while eng.has_work:
+        before = len(calls)
+        eng.step()
+        steps += 1
+        assert len(calls) - before <= 1
+    assert steps > 3 and len(calls) == steps
+    assert obs.get_tracer().spans() == []
+    assert all(eng.is_done(r) for r in rids)
+
+
+def test_same_seed_same_tokens_and_logps_with_tracing_on_and_off(model):
+    outs = []
+    for on in (False, True):
+        obs._reset_for_tests()
+        if on:
+            obs.enable()
+        eng = make_engine(model, seed=11)
+        rids = drive(eng)
+        outs.append([(eng.result(r), eng.result_logps(r)) for r in rids])
+    assert outs[0] == outs[1]
+    assert any(len(set(t)) > 1 for t, _ in outs[0])
+
+
+def test_engine_counters_are_on_metrics_with_span_tracing_off(model):
+    eng = make_engine(model)
+    drive(eng)
+    assert not obs.is_enabled() and obs.get_tracer().spans() == []
+    st = eng.stats()
+    text = obs.get_registry().render()
+    assert f"senweaver_engine_tokens_total {st['tokens_emitted']}" in text
+    assert f"senweaver_engine_decode_steps_total {st['decode_steps']}" \
+        in text
+
+
+def test_slot_layout_keeps_its_decode_step_span(model):
+    params, config = model
+    eng = RolloutEngine(params, config, num_slots=2, max_len=64,
+                        sample=SAMPLED,
+                        engine_config=EngineConfig(kv_layout="slots"))
+    obs.enable()
+    rid = eng.submit(PROMPTS[1], max_new_tokens=3)
+    eng.run()
+    names = by_name(obs.get_tracer().spans())
+    assert "engine.decode_step" in names and "engine.step" not in names
+    assert {"request.queue", "request.prefill", "request.decode"} <= \
+        set(names)
+    assert names["request.decode"][0].trace_id == f"req-{rid}"
+    text = obs.get_registry().render()
+    assert "senweaver_engine_tokens_total 3" in text
+
+
+def test_record_span_keeps_the_given_ends_and_ids():
+    t = obs.get_tracer()
+    t.record_span("request.queue", 1_000, 3_500_000, trace_id="req-7",
+                  parent_id="abc", rid=7)
+    (s,) = t.spans()
+    assert (s.name, s.trace_id, s.parent_id) == ("request.queue", "req-7",
+                                                 "abc")
+    assert (s.start_ns, s.end_ns) == (1_000, 3_500_000)
+    assert s.duration_ms == pytest.approx(3.499)
+    assert s.attrs == {"rid": 7} and len(s.span_id) == 16
+    assert "start_ns" in s.to_dict()
+
+
+def test_child_span_follows_an_open_span_or_an_enabled_tracer(monkeypatch):
+    """``child_span`` is for a callee of a loop that asked ``active()``
+    once: it never asks the profiler, and is on only under a span that is
+    open around it or an enabled tracer."""
+    from jax.profiler import TraceAnnotation
+    asked = []
+    monkeypatch.setattr(TraceAnnotation, "is_enabled",
+                        staticmethod(lambda: asked.append(1) or True))
+    t = obs.get_tracer()
+    with t.child_span("alone"):
+        pass
+    assert asked == [] and t.spans() == []
+    with t.span("outer"):               # on: a session "runs"
+        with t.child_span("inner"):
+            pass
+    inner, outer = t.spans()
+    assert (inner.name, outer.name) == ("inner", "outer")
+    assert inner.parent_id == outer.span_id and len(asked) == 1
+    obs.enable()
+    with t.child_span("top"):
+        pass
+    assert t.spans()[-1].name == "top" and len(asked) == 1
+
+
+def _lowered_fused_step(config, seed=0):
+    params = init_params(config, jax.random.PRNGKey(seed))
+    eng = RolloutEngine(params, config, num_slots=4, max_len=64,
+                        sample=SAMPLED,
+                        engine_config=EngineConfig(kv_layout="paged",
+                                                   block_size=4))
+    z = np.zeros((4,), np.int32)
+    return engine_mod._paged_fused_step.lower(
+        params, config, z, np.zeros((4, 2), np.int32), z, z, z, z, eng.pool,
+        jax.random.PRNGKey(0), SAMPLED, False).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("make,scopes", [
+    (tiny_test, ("embed", "attn.qkv", "attn.kv_write", "attn.kv_gather",
+                 "attn.scores", "attn.out", "mlp", "lm_head", "sample")),
+    (tiny_moe_test, ("mlp", "moe.route", "moe.experts"))],
+    ids=["dense", "moe"])
+def test_fused_step_carries_the_stable_device_side_names(make, scopes):
+    text = _lowered_fused_step(make())
+    for scope in scopes:
+        assert f'"{scope}/' in text or f"/{scope}/" in text, scope

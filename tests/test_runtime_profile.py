@@ -395,3 +395,59 @@ def test_speculation_depth_sweep_compiles_once_per_bucket():
             f"repeat depth sweep recompiled {name}: "
             f"{after[name]['signatures']}")
         assert after[name]["storms"] == 0
+
+
+# ---------------------------------------------------------------------------
+# spans: <name>.dispatch and <name>.wait around the wrapped call
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("block", [True, False], ids=["block", "async"])
+def test_wrapper_emits_dispatch_and_wait_spans(block):
+    """An enabled tracer sees the wrapped call as ``.dispatch`` and, when
+    the wrapper blocks, its ``block_until_ready`` as ``.wait`` — children
+    of whatever span is open around the call."""
+    f = wrap(jax.jit(lambda x: x * 2), "t.spans", block=block)
+    obs.enable()
+    with obs.get_tracer().span("caller"):
+        f(jnp.ones((4,)))
+    spans = obs.get_tracer().spans()
+    caller = next(s for s in spans if s.name == "caller")
+    kids = [s for s in spans if s.parent_id == caller.span_id]
+    assert [s.name for s in kids] == (
+        ["t.spans.dispatch", "t.spans.wait"] if block
+        else ["t.spans.dispatch"])
+    for s in kids:
+        assert caller.start_ns <= s.start_ns <= s.end_ns <= caller.end_ns
+    if block:
+        assert kids[0].end_ns <= kids[1].start_ns
+
+
+def test_wrapper_spans_follow_the_caller_not_the_profiler(monkeypatch):
+    """The wrapper never asks the profiler whether a session runs: with
+    the tracer disabled its spans are on under an open span only, so a
+    caller that found tracing off pays nothing here."""
+    from jax.profiler import TraceAnnotation
+    f = wrap(jax.jit(lambda x: x + 1), "t.quiet")
+    f(jnp.ones((4,)))
+    asked = []
+    monkeypatch.setattr(TraceAnnotation, "is_enabled",
+                        staticmethod(lambda: asked.append(1) or False))
+    f(jnp.ones((4,)))
+    assert asked == [] and obs.get_tracer().spans() == []
+    # inside a session (is_enabled true) a span opened by the caller is
+    # on, and the wrapper's two ride under it
+    monkeypatch.setattr(TraceAnnotation, "is_enabled",
+                        staticmethod(lambda: True))
+    with obs.get_tracer().span("caller"):
+        f(jnp.ones((4,)))
+    assert [s.name for s in obs.get_tracer().spans()] == [
+        "t.quiet.dispatch", "t.quiet.wait", "caller"]
+
+
+def test_disabled_profiler_is_a_plain_pass_through_with_no_spans():
+    f = wrap(jax.jit(lambda x: x + 1), "t.off")
+    get_profiler().set_enabled(False)
+    obs.enable()
+    with obs.get_tracer().span("caller"):
+        f(jnp.ones((4,)))
+    assert [s.name for s in obs.get_tracer().spans()] == ["caller"]
