@@ -6,8 +6,15 @@ TPU-first design decisions (vs a PyTorch-style module port):
   giving O(1) compile time in depth and a natural pipeline-parallel axis.
 - All matmuls are einsums in bf16 with fp32 softmax/norm accumulation — the
   shapes XLA tiles directly onto the MXU.
-- KV cache is a pre-allocated (L, B, Smax, Hkv, Dh) pair updated with
-  ``dynamic_update_slice`` — static shapes, no reallocation during decode.
+- KV is pre-allocated at static shapes. The paged pool
+  (``forward_paged``; what the engine serves from) is stacked
+  (L, num_blocks, block_size, Hkv, Dh) and rides the layer scan's CARRY:
+  each layer scatters into and gathers from the stacked arrays at its own
+  index, so a donated pool is updated in place — nothing of the pool's or
+  of a layer's size is copied, sliced out or written back in a step. The
+  legacy slot cache (``forward(..., cache=...)``, (L, B, Smax, Hkv, Dh),
+  ``dynamic_update_slice``) still scans its layers as xs/ys, which XLA
+  cannot alias: it moves the whole cache every step.
 - Sharding lives entirely in ``parallel/sharding.py`` PartitionSpecs; the
   model code is sharding-agnostic (GSPMD propagates).
 
@@ -713,25 +720,31 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
 
 def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                  cos: jax.Array, sin: jax.Array,
-                 k_pool: jax.Array, v_pool: jax.Array,
+                 leaves: Tuple[jax.Array, ...], layer: jax.Array,
                  tables: jax.Array, seq_row: jax.Array,
                  positions: jax.Array, write_block: jax.Array,
                  write_off: jax.Array, use_kernel: bool = False,
-                 adapters=None, adapter_ids=None,
-                 k_scale_pool=None, v_scale_pool=None):
+                 adapters=None, adapter_ids=None):
     """One transformer block over a paged KV pool (rollout/paged_kv.py).
 
     ``x`` is a flat token batch ``(T, 1, D)`` — T independent
     (sequence, position) pairs, decode steps and chunked-prefill
-    segments mixed freely. This layer's pool is
-    ``k_pool``/``v_pool`` ``(num_blocks, block_size, Hkv, Dh)``; each
-    token first scatters its new k/v at
-    ``(write_block[t], write_off[t])`` (``write_block == num_blocks``
-    drops the write — padding and rescore entries), then attends over
-    its own sequence through the block-table indirection
-    ``tables[seq_row[t]]``. The scatter lands before the gather, so a
-    chunk's later tokens see its earlier ones at the same layer —
-    flat-batch chunked prefill is exactly block prefill.
+    segments mixed freely. ``leaves`` are the pool's arrays STACKED over
+    layers as they are stored — ``(k, v)``
+    ``(L, num_blocks, block_size, Hkv, Dh)``, plus
+    ``(k_scale, v_scale)`` ``(L, num_blocks, block_size, Hkv)`` on a
+    quantized pool — and ``layer`` is this block's index into them.
+    Nothing layer-sized is sliced out: each token first scatters its
+    new k/v at ``(layer, write_block[t], write_off[t], head)``
+    (``write_block == num_blocks`` is out of range on the block axis, so
+    ``mode="drop"`` drops the write in every layer — padding and rescore
+    entries), then attends over its own sequence through the block-table
+    indirection ``(layer, tables[seq_row[t]])``. The scatter lands
+    before the gather, so a chunk's later tokens see its earlier ones at
+    the same layer — flat-batch chunked prefill is exactly block
+    prefill. Returns ``(x, leaves')``: the caller carries the
+    leaves through its layer scan, so a donated pool is updated in
+    place.
 
     The gathered view is a contiguous ``(T, MB*BS, Hkv, Dh)`` cache
     per token, attended with the SAME mask and attention call as the
@@ -745,7 +758,7 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     HLO instruction's ``op_name`` metadata and change no computation.
     """
     t = x.shape[0]
-    quantized = k_scale_pool is not None
+    quantized = len(leaves) == 4
     with jax.named_scope("attn.qkv"):
         h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
         q, k, v = _qkv(c, lp, h, cos, sin, adapters, adapter_ids)
@@ -753,49 +766,49 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     with jax.named_scope("attn.kv_write"):
         if quantized:
             # Quantize-at-write: payload and scale scatter through the
-            # SAME (write_block, write_off) indices with the same
+            # SAME (layer, block, offset, head) indices with the same
             # mode="drop" out-of-range sentinel, so dropped writes
             # (padding / rescore entries) leave both tensors untouched
             # and quantization commutes with the sentinel, fork
             # refcounts, and COW — those act on whole blocks via the
             # pool movers, never element-wise.
-            kq, ks = quantize_pool_kv(k[:, 0], k_pool.dtype)
-            vq, vs = quantize_pool_kv(v[:, 0], v_pool.dtype)
-            k_pool = k_pool.at[write_block, write_off].set(
-                kq, mode="drop")
-            v_pool = v_pool.at[write_block, write_off].set(
-                vq, mode="drop")
-            k_scale_pool = k_scale_pool.at[write_block, write_off].set(
-                ks, mode="drop")
-            v_scale_pool = v_scale_pool.at[write_block, write_off].set(
-                vs, mode="drop")
+            kq, ks = quantize_pool_kv(k[:, 0], leaves[0].dtype)
+            vq, vs = quantize_pool_kv(v[:, 0], leaves[1].dtype)
+            new = (kq, vq, ks, vs)
         else:
-            k_pool = k_pool.at[write_block, write_off].set(
-                k[:, 0].astype(k_pool.dtype), mode="drop")
-            v_pool = v_pool.at[write_block, write_off].set(
-                v[:, 0].astype(v_pool.dtype), mode="drop")
+            new = (k[:, 0].astype(leaves[0].dtype),
+                   v[:, 0].astype(leaves[1].dtype))
+        # One update a (token, head): its window is the minor axis alone,
+        # so the scatter runs in whatever layout the pool is stored in.
+        # With a whole (Hkv, Dh) window, XLA:TPU gave a 1-byte pool two
+        # layouts and converted the whole pool between them in every
+        # layer.
+        head = jnp.arange(leaves[0].shape[3])
+        leaves = tuple(
+            leaf.at[layer, write_block[:, None], write_off[:, None],
+                    head].set(val, mode="drop")
+            for leaf, val in zip(leaves, new))
     if use_kernel:
         from ..ops.paged_attention import paged_flash_decode
-        # the kernel gathers through the table itself
+        # the kernel takes ONE layer's pool and gathers through the
+        # table itself
+        k_pool, v_pool, *scales = (leaf[layer] for leaf in leaves)
+        k_scale, v_scale = scales or (None, None)
         with jax.named_scope("attn.scores"):
             out = paged_flash_decode(q[:, 0], k_pool, v_pool,
                                      tables[seq_row], positions + 1,
-                                     k_scale=k_scale_pool,
-                                     v_scale=v_scale_pool)[:, None]
+                                     k_scale=k_scale,
+                                     v_scale=v_scale)[:, None]
     else:
         with jax.named_scope("attn.kv_gather"):
-            nb, bs, hkv, dh = k_pool.shape
             tbl = tables[seq_row]                              # (T, MB)
-            mb = tbl.shape[1]
-            k_seq = k_pool[tbl].reshape(t, mb * bs, hkv, dh)
-            v_seq = v_pool[tbl].reshape(t, mb * bs, hkv, dh)
+            mb, bs = tbl.shape[1], leaves[0].shape[2]
+            k_seq, v_seq, *scales = (
+                leaf[layer, tbl].reshape((t, mb * bs) + leaf.shape[3:])
+                for leaf in leaves)
             if quantized:
-                k_seq = dequantize_pool_kv(
-                    k_seq, k_scale_pool[tbl].reshape(t, mb * bs, hkv),
-                    x.dtype)
-                v_seq = dequantize_pool_kv(
-                    v_seq, v_scale_pool[tbl].reshape(t, mb * bs, hkv),
-                    x.dtype)
+                k_seq = dequantize_pool_kv(k_seq, scales[0], x.dtype)
+                v_seq = dequantize_pool_kv(v_seq, scales[1], x.dtype)
         with jax.named_scope("attn.scores"):
             kv_pos = jnp.arange(mb * bs)[None, :]
             valid = kv_pos < positions[:, None] + 1
@@ -809,10 +822,8 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                                  "wo")
         x = x + attn_out
     with jax.named_scope("mlp"):
-        x, aux = _mlp(c, lp, x)
-    if quantized:
-        return x, (k_pool, v_pool, k_scale_pool, v_scale_pool), aux
-    return x, (k_pool, v_pool), aux
+        x, _ = _mlp(c, lp, x)
+    return x, leaves
 
 
 def forward_paged(
@@ -843,7 +854,12 @@ def forward_paged(
     entries and final prompt tokens) and ignores the rest.
 
     ``pool`` is the whole ``PagedKVPool`` pytree (accepted duck-typed
-    to avoid a models → rollout import cycle). A quantized pool
+    to avoid a models → rollout import cycle). Its leaves travel in the
+    layer scan's carry and are written and read in place at
+    ``(layer, block, offset)``: jit the caller with the pool donated
+    (``donate_argnames=("pool",)``, as every caller in rollout/ does)
+    and the step holds no second pool; without the donation XLA copies
+    the pool once on entry. A quantized pool
     (``k_scale is not None``) stores int8/fp8 payloads with per-token
     per-head f32 absmax scales, quantized AT WRITE TIME inside this one
     traced function — no extra device round-trips. An optional
@@ -873,37 +889,38 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         x = params["embed"][tokens][:, None, :]        # (T, 1, D)
         cos, sin = rope_cos_sin(positions[:, None], c.head_dim,
                                 c.rope_theta, scaling=c.rope_scaling)
-    aux0 = jnp.zeros((), jnp.float32)
-    # Both are STATIC under jit: derived from pytree structure (None-ness
-    # and shapes), so the precision ladder never adds a trace argument.
+    # STATIC under jit: derived from pytree structure (None-ness and
+    # shapes), so the precision ladder never adds a trace argument.
     n_hi = 0 if pool.k_hi is None else pool.k_hi.shape[0]
-    quantized = pool.k_scale is not None
 
-    def full_body(carry, inputs):
-        x, aux = carry
-        # Adapter banks carry a leading L axis (rollout/adapter_pool),
-        # so they ride the layer scan as xs; ``adapters is None`` scans
-        # as an empty pytree and unpacks back to None here.
-        lp, k_l, v_l, ad = inputs
-        x, (k_l, v_l), layer_aux = _paged_layer(
-            c, lp, x, cos, sin, k_l, v_l, tables, seq_row, positions,
-            write_block, write_off, use_kernel=use_kernel,
-            adapters=ad, adapter_ids=adapter_ids)
-        return (x, aux + layer_aux), (k_l, v_l)
+    def scan_layers(x, layers, ad, leaves):
+        """The layer scan over one group of pool leaves. The leaves ride
+        the CARRY, stacked as stored, and each layer scatters into and
+        gathers from them at its own index: XLA aliases a while loop's
+        carry in place (it cannot alias xs with ys), so with the pool
+        donated no layer is sliced out, written back or copied. The xs
+        are the layer params, the adapter banks (leading L axis,
+        rollout/adapter_pool; ``None`` scans as an empty pytree and
+        unpacks back to None) and the layer index."""
+        def body(carry, inputs):
+            x, leaves = carry
+            lp, ad_l, layer = inputs
+            x, leaves = _paged_layer(
+                c, lp, x, cos, sin, leaves, layer, tables, seq_row,
+                positions, write_block, write_off, use_kernel=use_kernel,
+                adapters=ad_l, adapter_ids=adapter_ids)
+            return (x, leaves), None
 
-    def quant_body(carry, inputs):
-        x, aux = carry
-        lp, k_l, v_l, ks_l, vs_l, ad = inputs
-        x, (k_l, v_l, ks_l, vs_l), layer_aux = _paged_layer(
-            c, lp, x, cos, sin, k_l, v_l, tables, seq_row, positions,
-            write_block, write_off, use_kernel=use_kernel,
-            adapters=ad, adapter_ids=adapter_ids,
-            k_scale_pool=ks_l, v_scale_pool=vs_l)
-        return (x, aux + layer_aux), (k_l, v_l, ks_l, vs_l)
+        index = jnp.arange(leaves[0].shape[0], dtype=jnp.int32)
+        (x, leaves), _ = jax.lax.scan(body, (x, leaves),
+                                      (layers, ad, index),
+                                      unroll=c.scan_unroll)
+        return x, leaves
 
     layers, lo_ad = params["layers"], adapters
+    names = ("k", "v") if pool.k_scale is None else (
+        "k", "v", "k_scale", "v_scale")
     upd = {}
-    carry = (x, aux0)
     if n_hi:
         # Full-width prefix layers scan first, then the quantized tail:
         # two scans over layer slices instead of one (the per-layer
@@ -912,24 +929,12 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                                   lambda a: a[:n_hi])
         sl_lo = functools.partial(jax.tree_util.tree_map,
                                   lambda a: a[n_hi:])
-        carry, (k_hi, v_hi) = jax.lax.scan(
-            full_body, carry,
-            (sl_hi(layers), pool.k_hi, pool.v_hi, sl_hi(adapters)),
-            unroll=c.scan_unroll)
-        upd["k_hi"], upd["v_hi"] = k_hi, v_hi
+        x, (upd["k_hi"], upd["v_hi"]) = scan_layers(
+            x, sl_hi(layers), sl_hi(adapters), (pool.k_hi, pool.v_hi))
         layers, lo_ad = sl_lo(layers), sl_lo(adapters)
-    if quantized:
-        carry, (k_upd, v_upd, ks_upd, vs_upd) = jax.lax.scan(
-            quant_body, carry,
-            (layers, pool.k, pool.v, pool.k_scale, pool.v_scale, lo_ad),
-            unroll=c.scan_unroll)
-        upd.update(k=k_upd, v=v_upd, k_scale=ks_upd, v_scale=vs_upd)
-    else:
-        carry, (k_upd, v_upd) = jax.lax.scan(
-            full_body, carry, (layers, pool.k, pool.v, lo_ad),
-            unroll=c.scan_unroll)
-        upd.update(k=k_upd, v=v_upd)
-    x, _aux = carry
+    x, leaves = scan_layers(x, layers, lo_ad,
+                            tuple(getattr(pool, n) for n in names))
+    upd.update(zip(names, leaves))
 
     with jax.named_scope("lm_head"):
         x = rms_norm(x, params["final_norm"], c.rms_norm_eps)

@@ -212,8 +212,9 @@ def test_reconnect_exhaustion_falls_back_to_polling():
     coord.stop()
     with s._conn_lock:
         dead = s._conn
-        dead.close()
-    s._handle_disconnect(dead)
+        if dead is not None:    # None: the read loop saw the drop first
+            dead.close()
+    s._handle_disconnect(dead)  # then waits for it on the reconnect lock
     assert s.polling and s.reconnects_used == 2
     h.close()
     s.close()

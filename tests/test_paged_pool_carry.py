@@ -1,0 +1,267 @@
+"""The paged forward carries the KV pool through its layer scan.
+
+``forward_paged`` scatters into and gathers from the pool's leaves
+STACKED over layers, addressed ``(layer, block, offset)``, with the
+leaves in the layer scan's carry. Two things hold it there:
+
+- a plain per-layer reference written here (a Python loop over layers on
+  ``pool.k[l]``: slice the layer out, scatter, gather, stack the layers
+  back) gives the same bits, logits and every pool leaf, for every pool
+  variant, with a share of the entries on the drop sentinel;
+- the compiled step keeps no second pool: its temporaries stay under a
+  quarter of the pool's bytes (the xs/ys form of the scan needed more
+  than a whole pool).
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from senweaver_ide_tpu.models import transformer as tf
+from senweaver_ide_tpu.models.config import get_config
+from senweaver_ide_tpu.ops.attention import attention
+from senweaver_ide_tpu.ops.norms import rms_norm
+from senweaver_ide_tpu.ops.rotary import rope_cos_sin
+from senweaver_ide_tpu.rollout.sampler import SampleParams
+from senweaver_ide_tpu.rollout import engine as eng
+from senweaver_ide_tpu.rollout.paged_kv import PagedKVPool, init_paged_pool
+
+NUM_BLOCKS, BLOCK_SIZE, ROWS, TABLE_WIDTH = 12, 4, 3, 4
+
+
+def _config():
+    # three layers: a prefix of one and a tail of two for the ladder, and
+    # a "next layer" after each of the first two for the sentinel
+    return dataclasses.replace(get_config("tiny-test"), num_layers=3)
+
+
+def _random_pool(config, key, kv_dtype, per_layer):
+    """A pool whose every element is random, so a write that lands in the
+    wrong place, or a gather that reads the wrong layer, changes bits."""
+    pool = init_paged_pool(config, NUM_BLOCKS, BLOCK_SIZE, kv_dtype,
+                           per_layer)
+    leaves, treedef = jax.tree_util.tree_flatten(pool)
+    out = []
+    for i, leaf in enumerate(leaves):
+        k = jax.random.fold_in(key, i)
+        if leaf.dtype == jnp.int8:
+            out.append(jax.random.randint(k, leaf.shape, -127, 128,
+                                          jnp.int32).astype(jnp.int8))
+        elif leaf.ndim == 4:                     # absmax scales: positive
+            out.append(jax.random.uniform(k, leaf.shape, jnp.float32,
+                                          1e-3, 2e-2))
+        else:
+            out.append(jax.random.normal(k, leaf.shape, jnp.float32)
+                       .astype(leaf.dtype))
+    return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def _random_banks(config, key, slots=2, rank=4):
+    """One rung of a LoRA bank as rollout/adapter_pool lays it out:
+    leaves (L, slots + 1, d_in, r) / (L, slots + 1, r, d_out), slot 0
+    the null adapter."""
+    c = config
+    dims = {"wq": (c.hidden_size, c.q_dim), "wv": (c.hidden_size, c.kv_dim),
+            "wo": (c.q_dim, c.hidden_size)}
+    bank = {}
+    for i, (name, (d_in, d_out)) in enumerate(dims.items()):
+        ka, kb = jax.random.split(jax.random.fold_in(key, i))
+        a = 0.1 * jax.random.normal(
+            ka, (c.num_layers, slots + 1, d_in, rank), c.dtype)
+        b = 0.1 * jax.random.normal(
+            kb, (c.num_layers, slots + 1, rank, d_out), c.dtype)
+        bank[name + "_lora_a"] = a.at[:, 0].set(0)
+        bank[name + "_lora_b"] = b.at[:, 0].set(0)
+    return (bank,)
+
+
+def _batch(decode_only):
+    """A flat token batch over three rows. Row 0 prefills a five-token
+    chunk (or decodes), rows 1 and 2 decode deep into their tables; the
+    entries marked dropped address the sentinel block ``NUM_BLOCKS``,
+    each at an offset and beside a block whose neighbours a wrong write
+    would hit."""
+    tables = np.array([[3, 7, 0, 0], [5, 1, 9, 0], [11, 2, 6, 10]], np.int32)
+    if decode_only:                    # the kernel: one entry a row
+        seq_row = np.array([0, 1, 2], np.int32)
+        positions = np.array([4, 9, 14], np.int32)
+        dropped = np.array([False, True, False])
+    else:
+        seq_row = np.array([0, 0, 0, 0, 0, 1, 2, 1, 2, 0], np.int32)
+        positions = np.array([0, 1, 2, 3, 4, 9, 14, 10, 15, 5], np.int32)
+        dropped = np.array([False, False, True, False, False, False, False,
+                            True, True, True])
+    block = tables[seq_row, positions // BLOCK_SIZE]
+    write_block = np.where(dropped, NUM_BLOCKS, block).astype(np.int32)
+    write_off = (positions % BLOCK_SIZE).astype(np.int32)
+    tokens = (np.arange(len(seq_row), dtype=np.int32) * 37 + 11) % 512
+    return dict(tokens=tokens, tables=tables, seq_row=seq_row,
+                positions=positions, write_block=write_block,
+                write_off=write_off), dropped
+
+
+def _reference_layer(c, lp, x, cos, sin, leaves, b, use_kernel, ad, ad_ids):
+    """One block on ONE layer's pool ``(num_blocks, block_size, ...)``:
+    the semantics the stacked form has to keep."""
+    t = x.shape[0]
+    h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+    q, k, v = tf._qkv(c, lp, h, cos, sin, ad, ad_ids)
+    if len(leaves) == 4:
+        kq, ks = tf.quantize_pool_kv(k[:, 0], leaves[0].dtype)
+        vq, vs = tf.quantize_pool_kv(v[:, 0], leaves[1].dtype)
+        new = (kq, vq, ks, vs)
+    else:
+        new = (k[:, 0].astype(leaves[0].dtype),
+               v[:, 0].astype(leaves[1].dtype))
+    leaves = tuple(
+        leaf.at[b["write_block"], b["write_off"]].set(val, mode="drop")
+        for leaf, val in zip(leaves, new))
+    tbl = b["tables"][b["seq_row"]]
+    if use_kernel:
+        from senweaver_ide_tpu.ops.paged_attention import paged_flash_decode
+        k_scale, v_scale = leaves[2:] or (None, None)
+        out = paged_flash_decode(q[:, 0], leaves[0], leaves[1], tbl,
+                                 b["positions"] + 1, k_scale=k_scale,
+                                 v_scale=v_scale)[:, None]
+    else:
+        width = tbl.shape[1] * BLOCK_SIZE
+        seqs = [leaf[tbl].reshape((t, width) + leaf.shape[2:])
+                for leaf in leaves]
+        k_seq, v_seq = seqs[:2]
+        if len(leaves) == 4:
+            k_seq = tf.dequantize_pool_kv(k_seq, seqs[2], x.dtype)
+            v_seq = tf.dequantize_pool_kv(v_seq, seqs[3], x.dtype)
+        valid = jnp.arange(width)[None, :] < b["positions"][:, None] + 1
+        out = attention(q, k_seq.astype(x.dtype), v_seq.astype(x.dtype),
+                        q_offset=b["positions"], kv_mask=valid, causal=True)
+    attn_in = out.reshape(t, 1, c.q_dim)
+    attn_out = tf._dense(attn_in, lp, "wo", "bse,ed->bsd")
+    x = x + tf._with_adapter(attn_out, attn_in, ad, ad_ids, "wo")
+    x, _ = tf._mlp(c, lp, x)
+    return x, leaves
+
+
+def _reference_forward_paged(params, c, pool, b, use_kernel, adapters,
+                             adapter_ids):
+    """A Python loop over layers, each on its own slice of the pool."""
+    take = lambda tree, l: jax.tree_util.tree_map(lambda a: a[l], tree)
+    with jax.default_matmul_precision(c.matmul_precision):
+        x = params["embed"][b["tokens"]][:, None, :]
+        cos, sin = rope_cos_sin(b["positions"][:, None], c.head_dim,
+                                c.rope_theta, scaling=c.rope_scaling)
+        names = ("k", "v") if pool.k_scale is None else (
+            "k", "v", "k_scale", "v_scale")
+        done = {}
+        for l in range(c.num_layers):
+            group, at = ((("k_hi", "v_hi"), l) if l < pool.hi_layers
+                         else (names, l - pool.hi_layers))
+            x, leaves = _reference_layer(
+                c, take(params["layers"], l), x, cos, sin,
+                tuple(getattr(pool, n)[at] for n in group), b, use_kernel,
+                take(adapters, l), adapter_ids)
+            for n, leaf in zip(group, leaves):
+                done.setdefault(n, []).append(leaf)
+        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"]) \
+            if params.get("lm_head") is None else tf._dense(
+                x, params, "lm_head", "bsd,dv->bsv")
+    return (logits[:, 0].astype(jnp.float32),
+            pool._replace(**{n: jnp.stack(v) for n, v in done.items()}))
+
+
+VARIANTS = {
+    "bf16": dict(kv_dtype="bf16"),
+    "int8": dict(kv_dtype="int8"),
+    "fp8": dict(kv_dtype="fp8"),
+    "prefix-ladder": dict(kv_dtype="int8",
+                          per_layer=("bf16", "int8", "int8")),
+    "lora-banks": dict(kv_dtype="bf16", lora=True),
+    "kernel": dict(kv_dtype="bf16", use_kernel=True),
+    "kernel-int8": dict(kv_dtype="int8", use_kernel=True),
+}
+
+
+@pytest.mark.parametrize("variant", list(VARIANTS))
+def test_forward_paged_matches_per_layer_reference_bit_for_bit(variant):
+    v = VARIANTS[variant]
+    use_kernel = v.get("use_kernel", False)
+    c = _config()
+    key = jax.random.PRNGKey(7)
+    params = tf.init_params(c, jax.random.fold_in(key, 1))
+    pool = _random_pool(c, jax.random.fold_in(key, 2), v["kv_dtype"],
+                        v.get("per_layer"))
+    batch, dropped = _batch(decode_only=use_kernel)
+    b = {k: jnp.asarray(a) for k, a in batch.items()}
+    adapters = adapter_ids = None
+    if v.get("lora"):
+        adapters = _random_banks(c, jax.random.fold_in(key, 3))
+        adapter_ids = (jnp.asarray(np.arange(len(dropped)) % 3, jnp.int32),)
+
+    ref = jax.jit(_reference_forward_paged, static_argnums=(1, 4))
+    want_logits, want_pool = ref(params, c, pool, b, use_kernel, adapters,
+                                 adapter_ids)
+    run = jax.jit(tf.forward_paged, static_argnames=("config", "use_kernel"))
+    got_logits, got_pool = run(
+        params, config=c, pool=pool, use_kernel=use_kernel,
+        adapters=adapters, adapter_ids=adapter_ids, **b)
+
+    np.testing.assert_array_equal(np.asarray(got_logits),
+                                  np.asarray(want_logits))
+    assert got_pool._fields == want_pool._fields
+    for name, got, want, before in zip(got_pool._fields, got_pool,
+                                       want_pool, pool):
+        if want is None:
+            assert got is None, name
+            continue
+        got, before = np.asarray(got), np.asarray(before)
+        assert got.dtype == np.asarray(want).dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got, np.asarray(want), err_msg=name)
+        # A dropped entry changes no block of any layer: every (layer,
+        # block, offset) the batch did not address holds its old bits —
+        # block 0 of the next layer among them, where a flattened
+        # (layer * num_blocks + sentinel) index would have landed.
+        written = np.zeros(got.shape[:3], bool)
+        written[:, batch["write_block"][~dropped],
+                batch["write_off"][~dropped]] = True
+        np.testing.assert_array_equal(got[~written], before[~written],
+                                      err_msg=name)
+        assert not written[:, 0].any()        # no entry writes block 0
+        assert (got[written] != before[written]).any(), name
+
+
+def _temp_bytes(lowered):
+    analysis = lowered.compile().memory_analysis()
+    if analysis is None or not hasattr(analysis, "temp_size_in_bytes"):
+        pytest.skip("this backend gives no memory_analysis")
+    return analysis.temp_size_in_bytes
+
+
+def _pool_bytes(pool):
+    return sum(a.size * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(pool))
+
+
+@pytest.mark.parametrize("program", ["fused_step", "draft_propose_scan"])
+def test_compiled_step_keeps_no_second_pool(program):
+    """The pool is donated and carried: the program's temporaries are a
+    fraction of it. With the pool as the scan's xs and ys they held a
+    whole second pool and a layer's slice (6.5 MB beside 4.2 MB)."""
+    c = get_config("tiny-test")
+    params = jax.eval_shape(lambda: tf.init_params(c, jax.random.PRNGKey(0)))
+    pool = jax.eval_shape(lambda: init_paged_pool(c, 2048, 16))
+    rows, width, entries = 8, 8, 16
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "fused_step":
+        lowered = eng._paged_fused_step.lower(
+            params, c, i32(entries), i32(rows, width), i32(entries),
+            i32(entries), i32(entries), i32(entries), pool,
+            jax.ShapeDtypeStruct((2,), jnp.uint32), SampleParams(), False)
+    else:
+        lowered = eng._draft_propose_scan.lower(
+            params, c, i32(rows), i32(rows),
+            jax.ShapeDtypeStruct((rows,), jnp.bool_), i32(rows, width),
+            pool, 3, False)
+    assert _temp_bytes(lowered) < _pool_bytes(pool) / 4
