@@ -16,6 +16,21 @@ from typing import Optional
 import jax.numpy as jnp
 
 
+class LatentCacheUnsupported(NotImplementedError):
+    """A mechanism that has no form yet for a latent-attention (MLA)
+    configuration, whose cache row is one ``[c_kv | k_rope]`` vector a token
+    and not (k, v) by kv-head. Raised where the mechanism is asked for —
+    engine construction, ``init_kv_cache``, ``enable_speculation`` — and
+    never replaced by a silent fallback. ``mechanism`` names it."""
+
+    def __init__(self, mechanism: str, config_name: str):
+        super().__init__(
+            f"{mechanism} is not implemented for the latent-attention "
+            f"configuration {config_name!r}: its paged cache holds one "
+            f"latent vector a token, not (k, v) by kv-head")
+        self.mechanism = mechanism
+
+
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
     """Llama-3-style NTK-by-parts RoPE scaling (HF ``rope_type: llama3``).
@@ -90,16 +105,72 @@ class ModelConfig:
     # default (bf16 MXU passes — the fast path for real models). The fp32
     # test config pins "highest" so cache-vs-full decode parity is exact.
     matmul_precision: Optional[str] = None
-    # Mixture-of-experts FFN: 0 = dense. When > 0, every layer's MLP is a
-    # top-k routed expert bank (parallel/expert.py semantics) and the
-    # expert axis shards over 'ep'.
+    # Mixture-of-experts FFN: 0 = dense. When > 0, every layer after the
+    # ``first_dense_layers`` has a top-k routed expert bank that drops
+    # nothing (models/moe.py) and the expert axis shards over 'ep'.
     num_experts: int = 0
     num_experts_per_tok: int = 2
-    expert_capacity_factor: float = 1.25
     # HF checkpoint layout for the expert banks on EXPORT ("mixtral":
     # block_sparse_moe w1/w3/w2; "qwen3": mlp.experts gate/up/down_proj).
     # The loader autodetects from the checkpoint keys.
     moe_layout: str = "mixtral"
+    # Width of one routed (and one shared) expert; None = intermediate_size
+    # (Mixtral/Qwen3-MoE presets, where every layer is an expert layer).
+    moe_intermediate_size: Optional[int] = None
+    # Shared experts: a dense SwiGLU of width n x moe width that every
+    # token passes beside its routed experts (DeepSeek-V2/V3, GLM-4.x MoE).
+    num_shared_experts: int = 0
+    # Leading layers that keep a dense FFN of ``intermediate_size``
+    # (HF ``first_k_dense_replace``); the rest are expert layers. Their
+    # params live in ``params["dense_layers"]``, the expert stack in
+    # ``params["layers"]``; both stacks are scanned one after the other.
+    first_dense_layers: int = 0
+    # Router form: "softmax" = softmax over experts, top-k, weights
+    # renormalised over the chosen (Mixtral, Qwen3-MoE); "sigmoid_bias" =
+    # sigmoid scores, choice by score + a per-expert correction bias,
+    # weights = the chosen scores WITHOUT the bias, normalised, times
+    # ``routed_scaling_factor`` (DeepSeek-V3 ``noaux_tc`` with one group).
+    router_type: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # Multi-head latent attention (DeepSeek-V2; GLM-4.7-Flash): q through a
+    # rank-``q_lora_rank`` bottleneck, keys and values through one shared
+    # ``kv_lora_rank`` latent plus one decoupled rotary key of
+    # ``qk_rope_head_dim`` a token. 0 = classic q/k/v projections. With MLA
+    # ``head_dim`` is the query/key width (nope + rope), ``num_kv_heads``
+    # equals ``num_heads`` and the paged cache holds ``latent_dim`` values
+    # a token a layer instead of (k, v) by kv-head.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Cached values a token a layer under MLA: [c_kv | k_rope]."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row_dim(self) -> int:
+        """Width of a row of the paged latent pool: ``latent_dim`` rounded
+        up to whole 128-lane tiles, the tail zero. At its own 576 GLM-4.7's
+        pool has no layout without padding that keeps a row's values
+        adjacent: XLA:TPU then stores it blocks-minor and converts the whole
+        pool on entry to and exit from every step (compile only, v5e: two
+        pool-sized copies, temp 1.9 GB; at 640 none, temp 0.25 GB)."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def expert_size(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def num_expert_layers(self) -> int:
+        return self.num_layers - self.first_dense_layers
 
     @property
     def q_dim(self) -> int:
@@ -203,6 +274,23 @@ def tiny_moe_test() -> ModelConfig:
         num_experts=4, num_experts_per_tok=2)
 
 
+def tiny_glm_moe_test() -> ModelConfig:
+    """GLM-4.7-Flash's layer (``glm4_moe_lite``) at test size: latent
+    attention, one leading dense layer, 8 routed experts top-2 + 1 shared,
+    sigmoid router with a correction bias; q/k width (8 + 4) differs from
+    the value width on purpose."""
+    return ModelConfig(
+        name="tiny-glm-moe-test", vocab_size=512, hidden_size=64,
+        intermediate_size=160, num_layers=3, num_heads=4, num_kv_heads=4,
+        head_dim=12, max_seq_len=128, rope_theta=1_000_000.0,
+        rms_norm_eps=1e-5, dtype=jnp.float32, matmul_precision="highest",
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=48,
+        num_shared_experts=1, first_dense_layers=1,
+        router_type="sigmoid_bias", routed_scaling_factor=1.8,
+        kv_lora_rank=24, q_lora_rank=32, qk_nope_head_dim=8,
+        qk_rope_head_dim=4, v_head_dim=16)
+
+
 def tiny_test() -> ModelConfig:
     """Small config for unit tests and CPU-mesh dry runs."""
     return ModelConfig(
@@ -290,6 +378,7 @@ PRESETS = {
     "qwen3-30b-a3b": qwen3_30b_a3b,
     "tiny-test": tiny_test,
     "tiny-moe-test": tiny_moe_test,
+    "tiny-glm-moe-test": tiny_glm_moe_test,
     "small-test": small_test,
 }
 

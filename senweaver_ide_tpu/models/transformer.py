@@ -31,10 +31,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import attention
+from ..ops.attention import NEG_INF, attention
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
-from .config import ModelConfig
+from .config import LatentCacheUnsupported, ModelConfig
+from .moe import BANKS, MoEStats, expert_ffn
 
 Params = Dict[str, Any]
 
@@ -85,6 +86,8 @@ def _is_ring(c: ModelConfig, cap: int) -> bool:
 
 def init_kv_cache(config: ModelConfig, batch: int, max_len: int,
                   dtype=None, *, quantized: Optional[bool] = None) -> KVCache:
+    if config.mla:
+        raise LatentCacheUnsupported("the slot KVCache layout", config.name)
     quantized = config.kv_quant if quantized is None else quantized
     max_len = ring_capacity(config, max_len)
     shape = (config.num_layers, batch, max_len, config.num_kv_heads,
@@ -151,11 +154,12 @@ def dequantize_pool_kv(q: jnp.ndarray, scale: jnp.ndarray,
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
-def init_params(config: ModelConfig, key: jax.Array) -> Params:
-    """Random init (normal / sqrt(fan_in)); layer params stacked on axis 0."""
-    c = config
-    k_embed, k_layers, k_head = jax.random.split(key, 3)
-
+def _init_layer_stack(c: ModelConfig, key: jax.Array, L: int,
+                      expert: bool) -> Dict[str, jax.Array]:
+    """One stack of ``L`` layers of the same structure, every leaf with a
+    leading L axis. ``expert``: routed experts (+ shared expert) in place
+    of the dense SwiGLU. Every matrix is stored ``(..., fan_in, fan_out)``.
+    """
     def dense(key, shape, fan_in):
         # Generate directly in the target dtype: the fp32-then-cast
         # pattern materializes an fp32 transient of every stacked tensor
@@ -164,42 +168,94 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
         scale = jnp.asarray(1.0 / float(fan_in) ** 0.5, c.dtype)
         return jax.random.normal(key, shape, c.dtype) * scale
 
-    L, D, F = c.num_layers, c.hidden_size, c.intermediate_size
-    ks = jax.random.split(k_layers, 8)
-    layers = {
-        "attn_norm": jnp.ones((L, D), c.dtype),
-        "wq": dense(ks[0], (L, D, c.q_dim), D),
-        "wk": dense(ks[1], (L, D, c.kv_dim), D),
-        "wv": dense(ks[2], (L, D, c.kv_dim), D),
-        "wo": dense(ks[3], (L, c.q_dim, D), c.q_dim),
-        "mlp_norm": jnp.ones((L, D), c.dtype),
-    }
-    if c.num_experts > 0:
-        E = c.num_experts
+    D, F = c.hidden_size, c.intermediate_size
+    ks = jax.random.split(key, 8)
+    layers = {"attn_norm": jnp.ones((L, D), c.dtype),
+              "mlp_norm": jnp.ones((L, D), c.dtype)}
+    if c.mla:
+        if c.q_lora_rank <= 0 or c.head_dim != (c.qk_nope_head_dim
+                                                + c.qk_rope_head_dim):
+            raise ValueError(
+                f"{c.name}: latent attention needs q_lora_rank > 0 and "
+                f"head_dim == qk_nope_head_dim + qk_rope_head_dim")
+        ka = jax.random.split(ks[0], 4)
+        H, rq, rkv = c.num_heads, c.q_lora_rank, c.kv_lora_rank
+        layers.update(
+            wq_a=dense(ka[0], (L, D, rq), D),
+            q_a_norm=jnp.ones((L, rq), c.dtype),
+            wq_b=dense(ka[1], (L, rq, H * c.head_dim), rq),
+            wkv_a=dense(ka[2], (L, D, c.latent_dim), D),
+            kv_a_norm=jnp.ones((L, rkv), c.dtype),
+            wkv_b=dense(ka[3], (L, rkv, H * (c.qk_nope_head_dim
+                                             + c.v_head_dim)), rkv),
+            wo=dense(ks[3], (L, H * c.v_head_dim, D), H * c.v_head_dim))
+    else:
+        layers.update(
+            wq=dense(ks[0], (L, D, c.q_dim), D),
+            wk=dense(ks[1], (L, D, c.kv_dim), D),
+            wv=dense(ks[2], (L, D, c.kv_dim), D),
+            wo=dense(ks[3], (L, c.q_dim, D), c.q_dim))
+    if expert:
+        E, Fe = c.num_experts, c.expert_size
         layers["router"] = dense(ks[7], (L, D, E), D)
-        layers["w_gate"] = dense(ks[4], (L, E, D, F), D)
-        layers["w_up"] = dense(ks[5], (L, E, D, F), D)
-        layers["w_down"] = dense(ks[6], (L, E, F, D), F)
+        layers["w_gate"] = dense(ks[4], (L, E, D, Fe), D)
+        layers["w_up"] = dense(ks[5], (L, E, D, Fe), D)
+        layers["w_down"] = dense(ks[6], (L, E, Fe, D), Fe)
+        if c.router_type == "sigmoid_bias":
+            # The per-expert correction bias added to the scores for the
+            # CHOICE only. A trained model's bias evens the load; training
+            # starts it at 0. Named ``*_norm`` because, like a norm's gain,
+            # a seeded-weights filler has to leave it a constant: drawn at
+            # random it would decide the choice in place of the scores.
+            layers["router_bias_norm"] = jnp.zeros((L, E), jnp.float32)
+        if c.num_shared_experts:
+            Fs = c.num_shared_experts * Fe
+            kk = jax.random.split(ks[1], 3)
+            layers["ws_gate"] = dense(kk[0], (L, D, Fs), D)
+            layers["ws_up"] = dense(kk[1], (L, D, Fs), D)
+            layers["ws_down"] = dense(kk[2], (L, Fs, D), Fs)
     else:
         layers["w_gate"] = dense(ks[4], (L, D, F), D)
         layers["w_up"] = dense(ks[5], (L, D, F), D)
         layers["w_down"] = dense(ks[6], (L, F, D), F)
-    if c.qkv_bias:
+    if c.qkv_bias and not c.mla:
         layers["bq"] = jnp.zeros((L, c.q_dim), c.dtype)
         layers["bk"] = jnp.zeros((L, c.kv_dim), c.dtype)
         layers["bv"] = jnp.zeros((L, c.kv_dim), c.dtype)
-    if c.qk_norm:
+    if c.qk_norm and not c.mla:
         layers["q_norm"] = jnp.ones((L, c.head_dim), c.dtype)
         layers["k_norm"] = jnp.ones((L, c.head_dim), c.dtype)
+    return layers
 
+
+def init_params(config: ModelConfig, key: jax.Array) -> Params:
+    """Random init (normal / sqrt(fan_in)); layer params stacked on axis 0.
+
+    ``params["layers"]`` is the model's main stack. A configuration with
+    ``first_dense_layers`` leading dense-FFN layers before its expert
+    layers has those in a second stack, ``params["dense_layers"]``, of the
+    same attention structure; the forward scans it first."""
+    c = config
+    k_embed, k_layers, k_head = jax.random.split(key, 3)
+    D = c.hidden_size
+    n_dense = c.first_dense_layers if c.num_experts > 0 else 0
+    if not 0 <= n_dense < c.num_layers:
+        raise ValueError(f"{c.name}: first_dense_layers {n_dense} of "
+                         f"{c.num_layers} layers")
     params: Params = {
         "embed": (jax.random.normal(k_embed, (c.vocab_size, D), c.dtype)
                   * jnp.asarray(0.02, c.dtype)),
-        "layers": layers,
+        "layers": _init_layer_stack(c, k_layers, c.num_layers - n_dense,
+                                    expert=c.num_experts > 0),
         "final_norm": jnp.ones((D,), c.dtype),
     }
+    if n_dense:
+        params["dense_layers"] = _init_layer_stack(
+            c, jax.random.fold_in(k_layers, 1), n_dense, expert=False)
     if not c.tie_word_embeddings:
-        params["lm_head"] = dense(k_head, (D, c.vocab_size), D)
+        params["lm_head"] = (
+            jax.random.normal(k_head, (D, c.vocab_size), c.dtype)
+            * jnp.asarray(1.0 / float(D) ** 0.5, c.dtype))
     return params
 
 
@@ -290,6 +346,71 @@ def _qkv(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array,
     return q, k, v
 
 
+def _mla_project(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array,
+                 cos: jax.Array, sin: jax.Array):
+    """Latent-attention projections. h (B, S, D) -> q_nope (B, S, H, nope),
+    q_rope (B, S, H, rope) rotated, and the row the cache holds for each
+    token, ``latent`` (B, S, kv_lora_rank + rope) = [RMSNorm(c) | RoPE(k_r)]:
+    one compressed vector for all heads' keys and values, and one rotary
+    key shared by all heads."""
+    b, s, _ = h.shape
+    nope, r = c.qk_nope_head_dim, c.kv_lora_rank
+    with jax.named_scope("attn.q_latent"):
+        cq = rms_norm(_dense(h, lp, "wq_a", "bsd,dr->bsr"), lp["q_a_norm"],
+                      c.rms_norm_eps)
+        q = _dense(cq, lp, "wq_b", "bsr,re->bse").reshape(
+            b, s, c.num_heads, c.head_dim)
+        q_nope = q[..., :nope]
+        q_rope = apply_rope(q[..., nope:], cos, sin)
+    with jax.named_scope("attn.kv_latent"):
+        ckr = _dense(h, lp, "wkv_a", "bsd,dr->bsr")
+        c_kv = rms_norm(ckr[..., :r], lp["kv_a_norm"], c.rms_norm_eps)
+        k_rope = apply_rope(ckr[..., None, r:], cos, sin)[..., 0, :]
+        latent = jnp.concatenate([c_kv, k_rope], axis=-1)
+    return q_nope, q_rope, latent
+
+
+def _mla_up_weights(c: ModelConfig, lp: Dict[str, jax.Array]):
+    """``wkv_b`` (r, H * (nope + v)) by head: W_kb (r, H, nope), W_vb
+    (r, H, v)."""
+    w = lp["wkv_b"].reshape(c.kv_lora_rank, c.num_heads,
+                            c.qk_nope_head_dim + c.v_head_dim)
+    return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
+
+
+def _mla_self_attention(c: ModelConfig, lp: Dict[str, jax.Array],
+                        h: jax.Array, cos, sin, kv_mask):
+    """No-cache latent attention in the EXPANDED form: every position's
+    per-head keys and values are made from its latent, then plain causal
+    attention with q/k width nope + rope and value width ``v_head_dim``.
+    h (B, S, D) -> (B, S, H * v). The trainer's and the scorer's path; the
+    paged engine reads its cache in the absorbed form (``_paged_mla_layer``)."""
+    b, s, _ = h.shape
+    r = c.kv_lora_rank
+    q_nope, q_rope, latent = _mla_project(c, lp, h, cos, sin)
+    w_kb, w_vb = _mla_up_weights(c, lp)
+    c_kv, k_rope = latent[..., :r], latent[..., r:]
+    exact = h.dtype == jnp.float32
+    prec = jax.lax.Precision.HIGHEST if exact else None
+    k_nope = jnp.einsum("bsr,rhn->bshn", c_kv, w_kb, precision=prec)
+    v = jnp.einsum("bsr,rhv->bshv", c_kv, w_vb, precision=prec)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_rope[:, :, None, :],
+                                  (b, s, c.num_heads, k_rope.shape[-1]))],
+        axis=-1)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=prec,
+                        preferred_element_type=jnp.float32)
+    scores = scores * (1.0 / float(c.head_dim) ** 0.5)
+    mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
+    if kv_mask is not None:
+        mask = mask & kv_mask[:, None, None, :]
+    probs = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
+    out = jnp.einsum("bhqk,bkhv->bqhv", probs.astype(v.dtype), v,
+                     precision=prec, preferred_element_type=jnp.float32)
+    return out.reshape(b, s, c.num_heads * c.v_head_dim).astype(h.dtype)
+
+
 def _self_attention(c: ModelConfig, q, k, v, kv_mask, mesh):
     """No-cache attention dispatch per ``c.attn_impl`` (training/scoring
     path). q (B,S,Hq,Dh), k/v (B,S,Hkv,Dh) → (B,S,Hq,Dh)."""
@@ -366,6 +487,15 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     """
     b, s, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+    if c.mla:       # no cache here: _forward_impl refuses one
+        if c.attn_impl != "einsum" or c.sliding_window is not None:
+            raise LatentCacheUnsupported(
+                f"attn_impl={c.attn_impl!r} / sliding_window="
+                f"{c.sliding_window} in the no-cache forward", c.name)
+        out = _mla_self_attention(c, lp, h, cos, sin, kv_mask)
+        x = x + _dense(out, lp, "wo", "bse,ed->bsd")
+        x, aux, _ = _mlp(c, lp, x)
+        return x, (None, None), aux
     q, k, v = _qkv(c, lp, h, cos, sin)
 
     if cache_kv is not None and len(cache_kv) == 5:
@@ -466,35 +596,57 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
         kv_out = (k, v)
 
     x = x + _dense(out.reshape(b, s, c.q_dim), lp, "wo", "bse,ed->bsd")
-    x, aux = _mlp(c, lp, x)
+    x, aux, _ = _mlp(c, lp, x)
     return x, kv_out, aux
 
 
-def _mlp(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array):
-    """Post-attention FFN block (dense silu-gate or MoE), shared by the
-    contiguous-cache and paged layer bodies. Returns
-    (x + ffn(norm(x)), moe aux loss — 0 for dense layers)."""
+def _swiglu(h: jax.Array, lp: Dict[str, jax.Array], gate: str, up: str,
+            down: str) -> jax.Array:
+    g = _dense(h, lp, gate, "bsd,df->bsf")
+    u = _dense(h, lp, up, "bsd,df->bsf")
+    act = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * u
+    return _dense(act, lp, down, "bsf,fd->bsd")
+
+
+def _mlp(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+         count: Optional[jax.Array] = None,
+         stack_layer: Optional[jax.Array] = None):
+    """Post-attention FFN block, shared by the contiguous-cache and paged
+    layer bodies: a dense SwiGLU, or where the layer's params hold a
+    ``router`` the dropless expert layer of ``models/moe.py`` plus the
+    shared expert (a layer stack is all of one kind; a configuration with
+    leading dense layers has two stacks). Returns (x + ffn(norm(x)), moe
+    aux loss — 0 for dense layers and for a ``sigmoid_bias`` router,
+    ``MoEStats`` over the entries ``count`` marks — None for dense).
+    ``stack_layer``: the expert banks in ``lp`` are the whole stack's and
+    this is the layer's index in it (``moe._grouped``)."""
     h = rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
-    if c.num_experts > 0:
-        from ..parallel.expert import MoEConfig, moe_ffn
-        moe_cfg = MoEConfig(hidden_size=c.hidden_size,
-                            intermediate_size=c.intermediate_size,
-                            num_experts=c.num_experts,
-                            top_k=c.num_experts_per_tok,
-                            capacity_factor=c.expert_capacity_factor,
-                            dtype=c.dtype)
-        moe_params = {"router": lp["router"], "w_gate": lp["w_gate"],
-                      "w_up": lp["w_up"], "w_down": lp["w_down"]}
-        for _n in ("w_gate_scale", "w_up_scale", "w_down_scale"):
-            if _n in lp:       # int8 expert banks (models/quantize.py)
-                moe_params[_n] = lp[_n]
-        ffn_out, aux = moe_ffn(moe_params, moe_cfg, h)
-        return x + ffn_out, aux
-    gate = _dense(h, lp, "w_gate", "bsd,df->bsf")
-    up = _dense(h, lp, "w_up", "bsd,df->bsf")
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype) * up
-    return (x + _dense(act, lp, "w_down", "bsf,fd->bsd"),
-            jnp.zeros((), jnp.float32))
+    if "router" not in lp:
+        return (x + _swiglu(h, lp, "w_gate", "w_up", "w_down"),
+                jnp.zeros((), jnp.float32), None)
+    b, s, d = h.shape
+    y, aux, stats = expert_ffn(c, lp, h.reshape(b * s, d), count,
+                               stack_layer)
+    y = y.reshape(b, s, d)
+    if "ws_gate" in lp:
+        with jax.named_scope("moe.shared"):
+            y = y + _swiglu(h, lp, "ws_gate", "ws_up", "ws_down").astype(
+                jnp.float32)
+    return x + y.astype(x.dtype), aux, stats
+
+
+def _rope_dim(c: ModelConfig) -> int:
+    """Width the rotary tables are made for: the whole head, or under
+    latent attention the decoupled rotary part alone."""
+    return c.qk_rope_head_dim if c.mla else c.head_dim
+
+
+def _layer_stacks(params: Params) -> Tuple[Dict[str, jax.Array], ...]:
+    """The model's layer stacks in the order they run: the leading
+    dense-FFN stack where the configuration has one, then the main one."""
+    if "dense_layers" in params:
+        return params["dense_layers"], params["layers"]
+    return (params["layers"],)
 
 
 def forward(
@@ -548,9 +700,12 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
             base = base[:, None]                       # per-slot lengths
         positions = base + jnp.arange(s, dtype=jnp.int32)[None, :]
         positions = jnp.broadcast_to(positions, (b, s))
-    cos, sin = rope_cos_sin(positions, c.head_dim, c.rope_theta,
+    cos, sin = rope_cos_sin(positions, _rope_dim(c), c.rope_theta,
                             scaling=c.rope_scaling)
 
+    if cache is not None and c.mla:
+        raise LatentCacheUnsupported(
+            "forward(cache=...) over the slot KVCache", c.name)
     if cache is None:
         def one_layer(x, lp, cos, sin):
             x, _, layer_aux = _layer(c, lp, x, cos, sin, None, attn_mask,
@@ -577,9 +732,11 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
             x, layer_aux = one_layer(x, lp, cos, sin)
             return (x, aux + layer_aux), None
 
-        (x, aux_total), _ = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), params["layers"],
-            unroll=c.scan_unroll)
+        carry = (x, jnp.zeros((), jnp.float32))
+        for stack in _layer_stacks(params):
+            carry, _ = jax.lax.scan(body, carry, stack,
+                                    unroll=c.scan_unroll)
+        x, aux_total = carry
         new_cache = None
     else:
         max_len = cache.k.shape[2]
@@ -724,7 +881,7 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                  tables: jax.Array, seq_row: jax.Array,
                  positions: jax.Array, write_block: jax.Array,
                  write_off: jax.Array, use_kernel: bool = False,
-                 adapters=None, adapter_ids=None):
+                 adapters=None, adapter_ids=None, stack_layer=None):
     """One transformer block over a paged KV pool (rollout/paged_kv.py).
 
     ``x`` is a flat token batch ``(T, 1, D)`` — T independent
@@ -757,6 +914,10 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     stable device-side names of docs/observability.md: they ride each
     HLO instruction's ``op_name`` metadata and change no computation.
     """
+    if c.mla:
+        return _paged_mla_layer(c, lp, x, cos, sin, leaves, layer, tables,
+                                seq_row, positions, write_block, write_off,
+                                stack_layer)
     t = x.shape[0]
     quantized = len(leaves) == 4
     with jax.named_scope("attn.qkv"):
@@ -822,8 +983,85 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                                  "wo")
         x = x + attn_out
     with jax.named_scope("mlp"):
-        x, _ = _mlp(c, lp, x)
-    return x, leaves
+        x, _, stats = _mlp(c, lp, x, _writes(lp, write_block, leaves[0]),
+                           stack_layer)
+    return x, leaves, stats
+
+
+def _writes(lp, write_block: jax.Array, leaf: jax.Array):
+    """For an expert layer, the entries of the flat batch that write a
+    cache row (the ones ``MoEStats`` counts); None for a dense layer."""
+    return write_block < leaf.shape[1] if "router" in lp else None
+
+
+def _paged_mla_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+                     cos: jax.Array, sin: jax.Array,
+                     leaves: Tuple[jax.Array, ...], layer: jax.Array,
+                     tables: jax.Array, seq_row: jax.Array,
+                     positions: jax.Array, write_block: jax.Array,
+                     write_off: jax.Array, stack_layer=None):
+    """``_paged_layer`` for latent attention: the pool's one payload leaf
+    ``(L, num_blocks, block_size, 1, latent_row_dim)`` holds a token's
+    ``[c_kv | k_rope | 0...]`` row (zero-padded to whole lane tiles, see
+    ``ModelConfig.latent_row_dim``), written in place like a kv-head's row,
+    and every entry of the flat batch — decode rows and prefill-chunk tokens
+    alike — reads its sequence's rows through the table and attends in the
+    ABSORBED form: with ``wkv_b`` split by head into W_kb and W_vb,
+
+        score_i = (q_nope_i W_kb_i^T) . c_kv + q_rope_i . k_rope
+        out_i   = (sum_s a_is c_kv_s) W_vb_i
+
+    so nothing of the cached context is expanded to heads: the gathered
+    rows are read twice (scores, weighted sum) at their stored width. The
+    same mathematics as ``_mla_self_attention``'s expanded form."""
+    t = x.shape[0]
+    (leaf,) = leaves
+    r = c.kv_lora_rank
+    h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+    q_nope, q_rope, latent = _mla_project(c, lp, h, cos, sin)
+    exact = x.dtype == jnp.float32
+    prec = jax.lax.Precision.HIGHEST if exact else None
+    with jax.named_scope("attn.kv_write"):
+        # one update a token, its window the minor axis alone (see
+        # _paged_layer): the "head" axis of a latent pool has length 1
+        pad = ((0, 0), (0, 0), (0, leaf.shape[-1] - latent.shape[-1]))
+        leaf = leaf.at[layer, write_block[:, None], write_off[:, None],
+                       jnp.arange(1)].set(
+                           jnp.pad(latent.astype(leaf.dtype), pad),
+                           mode="drop")
+    with jax.named_scope("attn.absorb"):
+        w_kb, w_vb = _mla_up_weights(c, lp)
+        q_abs = jnp.einsum("thn,rhn->thr", q_nope[:, 0], w_kb,
+                           precision=prec)
+        # the row's zero tail meets a zero tail of the query: the product
+        # runs over whole tiles and no slice of the gathered rows is made
+        q_cat = jnp.pad(jnp.concatenate([q_abs, q_rope[:, 0]], axis=-1),
+                        pad)
+    with jax.named_scope("attn.kv_gather"):
+        tbl = tables[seq_row]                                  # (T, MB)
+        mb, bs = tbl.shape[1], leaf.shape[2]
+        seq = leaf[layer, tbl].reshape(t, mb * bs, leaf.shape[-1])
+    with jax.named_scope("attn.scores"):
+        scores = jnp.einsum("thc,tsc->ths", q_cat, seq, precision=prec,
+                            preferred_element_type=jnp.float32)
+        scores = scores * (1.0 / float(c.head_dim) ** 0.5)
+        valid = jnp.arange(mb * bs)[None, :] < positions[:, None] + 1
+        probs = jax.nn.softmax(
+            jnp.where(valid[:, None, :], scores, NEG_INF), axis=-1)
+        # over the whole row: a slice of the gathered rows would be a copy
+        # of them; the rotary and zero columns of the small result are cut
+        ctx = jnp.einsum("ths,tsc->thc", probs.astype(x.dtype), seq,
+                         precision=prec,
+                         preferred_element_type=jnp.float32)[..., :r]
+    with jax.named_scope("attn.out"):
+        out = jnp.einsum("thr,rhv->thv", ctx.astype(x.dtype), w_vb,
+                         precision=prec)
+        x = x + _dense(out.reshape(t, 1, c.num_heads * c.v_head_dim), lp,
+                       "wo", "bse,ed->bsd")
+    with jax.named_scope("mlp"):
+        x, _, stats = _mlp(c, lp, x, _writes(lp, write_block, leaf),
+                           stack_layer)
+    return x, (leaf,), stats
 
 
 def forward_paged(
@@ -844,6 +1082,7 @@ def forward_paged(
     use_kernel: bool = False,     # static: Pallas paged-decode kernel
     adapters=None,                # per-rung LoRA bank dicts, leading L
     adapter_ids=None,             # per-rung (T,) int32 slot ids
+    with_moe_stats: bool = False,  # static: also return MoEStats
 ):
     """Run the model over a paged KV pool: every entry of the flat
     ``(T,)`` token batch is one (sequence, position) pair — a decode
@@ -865,21 +1104,34 @@ def forward_paged(
     traced function — no extra device round-trips. An optional
     ``k_hi``/``v_hi`` full-width prefix holds the first
     ``pool.hi_layers`` layers (``kv_dtype_per_layer`` ladder: early
-    layers, where divergence concentrates, stay bf16)."""
+    layers, where divergence concentrates, stay bf16).
+
+    A latent-attention configuration's pool has one payload leaf, ``k``
+    ``(L, num_blocks, block_size, 1, latent_row_dim)``, and a zero-width ``v``
+    (``rollout.paged_kv.init_paged_pool``). A configuration with leading
+    dense layers scans its two stacks one after the other over the same
+    carried leaves, each layer at its absolute index.
+
+    ``with_moe_stats=True`` (an expert configuration) returns a third
+    value, ``MoEStats`` summed over the expert layers (``experts_touched``)
+    and their largest (``expert_load_max``), counted over the entries that
+    write a cache row: padding is routed, and is not work."""
     c = config
     if c.matmul_precision is not None:
         with jax.default_matmul_precision(c.matmul_precision):
-            return _forward_paged_impl(
+            out = _forward_paged_impl(
                 params, c, tokens, pool=pool,
                 tables=tables, seq_row=seq_row, positions=positions,
                 write_block=write_block, write_off=write_off,
                 use_kernel=use_kernel, adapters=adapters,
                 adapter_ids=adapter_ids)
-    return _forward_paged_impl(
-        params, c, tokens, pool=pool, tables=tables,
-        seq_row=seq_row, positions=positions, write_block=write_block,
-        write_off=write_off, use_kernel=use_kernel, adapters=adapters,
-        adapter_ids=adapter_ids)
+    else:
+        out = _forward_paged_impl(
+            params, c, tokens, pool=pool, tables=tables,
+            seq_row=seq_row, positions=positions, write_block=write_block,
+            write_off=write_off, use_kernel=use_kernel, adapters=adapters,
+            adapter_ids=adapter_ids)
+    return out if with_moe_stats else out[:2]
 
 
 def _forward_paged_impl(params, c, tokens, *, pool, tables,
@@ -887,13 +1139,18 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                         use_kernel, adapters=None, adapter_ids=None):
     with jax.named_scope("embed"):
         x = params["embed"][tokens][:, None, :]        # (T, 1, D)
-        cos, sin = rope_cos_sin(positions[:, None], c.head_dim,
+        cos, sin = rope_cos_sin(positions[:, None], _rope_dim(c),
                                 c.rope_theta, scaling=c.rope_scaling)
+    if c.mla and (use_kernel or adapters is not None
+                  or pool.k_scale is not None):
+        raise LatentCacheUnsupported(
+            "the Pallas paged-decode kernel / adapter banks / a quantized "
+            "pool in forward_paged", c.name)
     # STATIC under jit: derived from pytree structure (None-ness and
     # shapes), so the precision ladder never adds a trace argument.
     n_hi = 0 if pool.k_hi is None else pool.k_hi.shape[0]
 
-    def scan_layers(x, layers, ad, leaves):
+    def scan_layers(x, layers, ad, leaves, first=0):
         """The layer scan over one group of pool leaves. The leaves ride
         the CARRY, stacked as stored, and each layer scatters into and
         gathers from them at its own index: XLA aliases a while loop's
@@ -901,21 +1158,42 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         donated no layer is sliced out, written back or copied. The xs
         are the layer params, the adapter banks (leading L axis,
         rollout/adapter_pool; ``None`` scans as an empty pytree and
-        unpacks back to None) and the layer index."""
+        unpacks back to None) and the layer index, counted from
+        ``first``: a stack that is not the leaves' first indexes them by
+        its layers' absolute numbers. An expert stack also carries and
+        returns its ``MoEStats`` (None for a dense stack)."""
+        n = jax.tree_util.tree_leaves(layers)[0].shape[0]
+        counts = banks = None
+        if "router" in layers:
+            counts = MoEStats(jnp.zeros((), jnp.int32),
+                              jnp.zeros((), jnp.int32))
+            # The expert banks stay whole outside the xs: each layer's
+            # grouped products address its experts inside them
+            # (moe._grouped) instead of taking a copy of its slice.
+            banks = {k: v for k, v in layers.items() if k in BANKS}
+            layers = {k: v for k, v in layers.items() if k not in BANKS}
+
         def body(carry, inputs):
-            x, leaves = carry
+            x, leaves, acc = carry
             lp, ad_l, layer = inputs
-            x, leaves = _paged_layer(
+            if banks is not None:
+                lp = {**lp, **banks}
+            x, leaves, stats = _paged_layer(
                 c, lp, x, cos, sin, leaves, layer, tables, seq_row,
                 positions, write_block, write_off, use_kernel=use_kernel,
-                adapters=ad_l, adapter_ids=adapter_ids)
-            return (x, leaves), None
+                adapters=ad_l, adapter_ids=adapter_ids,
+                stack_layer=None if banks is None else layer - first)
+            if stats is not None:
+                acc = MoEStats(
+                    acc.experts_touched + stats.experts_touched,
+                    jnp.maximum(acc.expert_load_max, stats.expert_load_max))
+            return (x, leaves, acc), None
 
-        index = jnp.arange(leaves[0].shape[0], dtype=jnp.int32)
-        (x, leaves), _ = jax.lax.scan(body, (x, leaves),
-                                      (layers, ad, index),
-                                      unroll=c.scan_unroll)
-        return x, leaves
+        index = jnp.arange(first, first + n, dtype=jnp.int32)
+        (x, leaves, counts), _ = jax.lax.scan(body, (x, leaves, counts),
+                                              (layers, ad, index),
+                                              unroll=c.scan_unroll)
+        return x, leaves, counts
 
     layers, lo_ad = params["layers"], adapters
     names = ("k", "v") if pool.k_scale is None else (
@@ -929,11 +1207,27 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                                   lambda a: a[:n_hi])
         sl_lo = functools.partial(jax.tree_util.tree_map,
                                   lambda a: a[n_hi:])
-        x, (upd["k_hi"], upd["v_hi"]) = scan_layers(
+        x, (upd["k_hi"], upd["v_hi"]), hi_moe = scan_layers(
             x, sl_hi(layers), sl_hi(adapters), (pool.k_hi, pool.v_hi))
         layers, lo_ad = sl_lo(layers), sl_lo(adapters)
-    x, leaves = scan_layers(x, layers, lo_ad,
-                            tuple(getattr(pool, n) for n in names))
+    if c.mla:
+        names = ("k",)      # the latent rows; ``v`` has no width
+    leaves = tuple(getattr(pool, n) for n in names)
+    first = 0
+    if "dense_layers" in params:
+        if n_hi or adapters is not None:
+            raise NotImplementedError(
+                "a kv_dtype_per_layer prefix or adapter banks over a "
+                "configuration with leading dense layers")
+        # the leading dense-FFN stack, over the same carried leaves
+        x, leaves, _ = scan_layers(x, params["dense_layers"], None, leaves)
+        first = c.first_dense_layers
+    x, leaves, moe = scan_layers(x, layers, lo_ad, leaves, first)
+    if n_hi and moe is not None:
+        # the full-width prefix layers are expert layers of the same model
+        moe = MoEStats(moe.experts_touched + hi_moe.experts_touched,
+                       jnp.maximum(moe.expert_load_max,
+                                   hi_moe.expert_load_max))
     upd.update(zip(names, leaves))
 
     with jax.named_scope("lm_head"):
@@ -947,7 +1241,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         else:
             logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
         logits = logits[:, 0].astype(jnp.float32)
-    return logits, pool._replace(**upd)
+    return logits, pool._replace(**upd), moe
 
 
 def count_params(params: Params) -> int:

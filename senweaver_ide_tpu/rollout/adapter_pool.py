@@ -52,7 +52,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import ModelConfig
+from ..models.config import LatentCacheUnsupported, ModelConfig
 from ..obs import get_registry
 
 # (in_dim, out_dim) per supported target. Attention-only by design:
@@ -124,6 +124,10 @@ class AdapterPool:
 
     def __init__(self, config: ModelConfig,
                  pool_config: Optional[AdapterPoolConfig] = None):
+        if config.mla:
+            raise LatentCacheUnsupported(
+                "the multi-LoRA adapter pool (its targets are wq/wk/wv/wo)",
+                config.name)
         self.config = config
         self.pool_config = pool_config or AdapterPoolConfig()
         pc = self.pool_config

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import ModelConfig
+from ..models.config import LatentCacheUnsupported, ModelConfig
 from ..models.transformer import (KVCache, Params, forward, forward_paged,
                                   init_kv_cache)
 from ..obs import get_registry, get_tracer
@@ -265,14 +265,23 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     absmax scales through the SAME sentinel-guarded indices — no extra
     device round-trips, no new compile per occupancy bucket (the scale
     tensors are shape-static alongside the payloads)."""
-    logits, pool = forward_paged(
+    logits, pool, *moe = forward_paged(
         params, config, tokens, pool=pool,
         tables=tables, seq_row=seq_row, positions=positions,
         write_block=write_block, write_off=write_off,
-        use_kernel=use_kernel, adapters=adapters, adapter_ids=adapter_ids)
+        use_kernel=use_kernel, adapters=adapters, adapter_ids=adapter_ids,
+        with_moe_stats=config.num_experts > 0)
     next_tok = sample_token(logits, key, temperature=sample.temperature,
                             top_k=sample.top_k, top_p=sample.top_p)
     logp = sampled_logprob(logits, next_tok)
+    if moe:
+        # An expert model's step also says what its routing did: the two
+        # counts of ``MoEStats`` ride BEHIND the step's tokens in the same
+        # array, so the one fetch brings them and nothing is dispatched or
+        # waited for on their account (``_note_moe_step`` reads them).
+        # Every consumer of the tokens indexes entries below ``T``.
+        next_tok = jnp.concatenate(
+            [next_tok, jnp.stack(moe[0]).astype(next_tok.dtype)])
     return next_tok, logp, pool
 
 
@@ -608,6 +617,26 @@ class RolloutEngine:
         # ring pools (chunked prefill), the pool size on absolute ones.
         self.context_bound = (config.max_seq_len
                               if self._ring else max_len)
+        if config.mla:
+            ec = engine_config or EngineConfig()
+            # Latent attention serves from the paged latent pool alone.
+            # What has no latent form is refused here, by name, instead
+            # of falling back to a layout that cannot hold the cache.
+            for asked, mechanism in (
+                    (ec.kv_layout == "slots", "the slot KVCache layout "
+                     "(EngineConfig.kv_layout='slots')"),
+                    (config.kv_quant, "the slot int8 cache (kv_quant)"),
+                    (self._ring, "the sliding-window ring cache"),
+                    (mesh is not None, "tensor-parallel KV sharding "
+                     "(mesh=...)"),
+                    (adapter_pool is not None, "the multi-LoRA adapter "
+                     "pool"),
+                    (ec.paged_kernel
+                     or config.decode_attn_impl == "flash",
+                     "the Pallas paged-decode kernel (paged_kernel / "
+                     "decode_attn_impl='flash')")):
+                if asked:
+                    raise LatentCacheUnsupported(mechanism, config.name)
         self.sample = sample
         self.eos_id = eos_id
         # Optional tensor-parallel serving: params take the Megatron
@@ -732,6 +761,30 @@ class RolloutEngine:
                 pk = (config.decode_attn_impl == "flash"
                       and jax.devices()[0].platform == "tpu")
             self._use_paged_kernel = bool(pk)
+            # An expert model's fused step reports its routing behind the
+            # step's tokens (``_paged_fused_step``); counters whether or
+            # not span tracing is on, like the step counters below.
+            self._moe_counters = None
+            if config.num_experts > 0:
+                reg = get_registry()
+                self._moe_counters = (
+                    reg.counter(
+                        "senweaver_moe_assignments_total",
+                        "(token, choice) pairs the fused step's expert "
+                        "layers were given, a layer: entries in use x "
+                        "experts per token."),
+                    reg.counter(
+                        "senweaver_moe_experts_touched_total",
+                        "Expert banks with at least one token, summed "
+                        "over the expert layers and the fused steps."),
+                    reg.counter(
+                        "senweaver_moe_expert_banks_total",
+                        "Expert banks the fused steps could have touched: "
+                        "expert layers x experts a step."),
+                    reg.gauge(
+                        "senweaver_moe_expert_load_max",
+                        "Largest number of tokens on one expert in any "
+                        "layer of the last fused step."))
         self._slot_req: List[Optional[_Request]] = [None] * num_slots  # guarded-by: _lock
         # rid holding each slot's KV across turns (hold_slot), or None
         self._slot_held: List[Optional[int]] = [None] * num_slots  # guarded-by: _lock
@@ -908,6 +961,9 @@ class RolloutEngine:
         (``num_blocks``; default sized like the target's) whose
         gauges publish under ``senweaver_spec_draft_kv_*``."""
         from .spec_controller import FixedDepth, SpecController
+        if self.config.mla or draft_config.mla:
+            raise LatentCacheUnsupported("fused draft/verify speculation",
+                                         self.config.name)
         if self.kv_layout != "paged":
             raise ValueError(
                 "fused speculation needs the paged KV layout (engine "
@@ -1557,6 +1613,10 @@ class RolloutEngine:
         buffer; ``update_params`` invalidates all prefixes (their KV
         belongs to the old policy) and auto_prefix clients re-register.
         """
+        if self.config.mla:
+            raise LatentCacheUnsupported(
+                "registered prefixes (their prefill runs over the slot "
+                "KVCache)", self.config.name)
         with self._lock:
             if not tokens:
                 raise ValueError("empty prefix")
@@ -3134,6 +3194,9 @@ class RolloutEngine:
                                                   fn="engine.fused_step")
                 if sp is not None:
                     sp.set_attr("bytes", int(toks.nbytes + logps.nbytes))
+            if self._moe_counters is not None:
+                self._note_moe_step(st, toks, decode_rows, spec_rows,
+                                    job_rows)
             with span("engine.emit") as sp:
                 rows0 = list(self._slot_req) if sp is not None else ()
                 n_emitted = self._emit_paged(toks, logps, decode_rows,
@@ -3168,6 +3231,29 @@ class RolloutEngine:
                 self.sample, self._use_paged_kernel,
                 adapters=adapters, adapter_ids=adapter_ids)
         return next_tok, logp
+
+    def _note_moe_step(self, st, toks, decode_rows, spec_rows,
+                       job_rows) -> None:
+        # guarded-by: caller
+        """An expert model's step: publish what its routing did. The
+        device's two counts arrive behind the step's tokens in the fetched
+        array (``_paged_fused_step``); the pairs offered are the host's
+        own count, entries in use x experts per token."""
+        touched, load_max = int(toks[-2]), int(toks[-1])
+        used = (len(decode_rows) + sum(j[3] for j in job_rows)
+                + sum(len(r[3]) for r in spec_rows))
+        assignments = used * self.config.num_experts_per_tok
+        n_banks = self.config.num_expert_layers * self.config.num_experts
+        pairs, banks, banks_total, peak = self._moe_counters
+        pairs.inc(assignments)
+        banks.inc(touched)
+        banks_total.inc(n_banks)
+        peak.set(load_max)
+        if st is not None:
+            st.set_attr("expert_assignments", assignments)
+            st.set_attr("experts_touched", touched)
+            st.set_attr("expert_banks", n_banks)
+            st.set_attr("expert_load_max", load_max)
 
     def _publish_fragmentation(self) -> None:
         # guarded-by: caller

@@ -59,6 +59,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..models.config import LatentCacheUnsupported
 from ..models.transformer import (ModelConfig, dequantize_pool_kv,
                                   quantize_pool_kv)
 from ..obs.runtime_profile import ProfiledFunction
@@ -214,11 +215,30 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
     keeps a bf16 prefix of layers full-width (see
     :func:`resolve_kv_dtypes`). The legacy slot-cache int8 switch
     (``config.kv_quant``) is a different mechanism — the engine still
-    falls back to the slot layout there."""
+    falls back to the slot layout there.
+
+    A latent-attention configuration (``config.mla``) caches ONE vector a
+    token a layer, ``[c_kv | k_rope]``: its payload is the single leaf
+    ``k`` ``(L, num_blocks, block_size, 1, latent_row_dim)`` (the latent
+    padded to whole 128-lane tiles, ``ModelConfig.latent_row_dim``) — no
+    kv-head axis to speak of and no separate values — and ``v`` keeps the
+    block axes
+    with a last axis of width 0, so that every mover below stays one
+    ``tree_map`` (or one indexed update a leaf) over the same leaves and
+    moves no byte for it. The quantized ladder has no latent form yet."""
     hkv, dh = config.num_kv_heads, config.head_dim
     num_layers = config.num_layers
     payload, n_hi = resolve_kv_dtypes(num_layers, kv_dtype,
                                       kv_dtype_per_layer)
+    if config.mla:
+        if payload is not None:
+            raise LatentCacheUnsupported(
+                "the quantized KV ladder (EngineConfig.kv_dtype int8/fp8)",
+                config.name)
+        shape = (num_layers, num_blocks, block_size, 1)
+        return PagedKVPool(
+            k=jnp.zeros(shape + (config.latent_row_dim,), dtype=config.dtype),
+            v=jnp.zeros(shape + (0,), dtype=config.dtype))
     if payload is None:
         shape = (num_layers, num_blocks, block_size, hkv, dh)
         return PagedKVPool(k=jnp.zeros(shape, dtype=config.dtype),

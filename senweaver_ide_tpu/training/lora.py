@@ -37,7 +37,7 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import ModelConfig
+from ..models.config import LatentCacheUnsupported, ModelConfig
 from ..models.quantize import _quantize_matrix, is_quantized
 
 # (in_dim, out_dim) resolvers per supported target matrix.
@@ -58,6 +58,10 @@ def init_lora(config: ModelConfig, key: jax.Array, *, rank: int = 16,
               alpha: float = None, targets: Sequence[str] = DEFAULT_TARGETS,
               ) -> Dict:
     """Adapter pytree; zero function delta at init (B = 0)."""
+    if config.mla:
+        raise LatentCacheUnsupported(
+            "LoRA on the latent projections (targets are wq/wk/wv/wo)",
+            config.name)
     if config.num_experts > 0:
         bad = {"w_gate", "w_up", "w_down"} & set(targets)
         if bad:

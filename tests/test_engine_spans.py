@@ -11,7 +11,8 @@ import pytest
 
 from senweaver_ide_tpu import obs
 from senweaver_ide_tpu.models import init_params, tiny_test
-from senweaver_ide_tpu.models.config import tiny_moe_test
+from senweaver_ide_tpu.models.config import (tiny_glm_moe_test,
+                                             tiny_moe_test)
 from senweaver_ide_tpu.rollout import EngineConfig, RolloutEngine
 from senweaver_ide_tpu.rollout import engine as engine_mod
 from senweaver_ide_tpu.rollout.sampler import SampleParams
@@ -352,8 +353,14 @@ def _lowered_fused_step(config, seed=0):
 @pytest.mark.parametrize("make,scopes", [
     (tiny_test, ("embed", "attn.qkv", "attn.kv_write", "attn.kv_gather",
                  "attn.scores", "attn.out", "mlp", "lm_head", "sample")),
-    (tiny_moe_test, ("mlp", "moe.route", "moe.experts"))],
-    ids=["dense", "moe"])
+    (tiny_moe_test, ("mlp", "moe.router", "moe.sort", "moe.experts",
+                     "moe.combine")),
+    (tiny_glm_moe_test, ("embed", "attn.q_latent", "attn.kv_latent",
+                         "attn.kv_write", "attn.absorb", "attn.kv_gather",
+                         "attn.scores", "attn.out", "mlp", "moe.router",
+                         "moe.sort", "moe.experts", "moe.shared",
+                         "moe.combine", "lm_head", "sample"))],
+    ids=["dense", "moe", "latent-moe"])
 def test_fused_step_carries_the_stable_device_side_names(make, scopes):
     text = _lowered_fused_step(make())
     for scope in scopes:

@@ -262,9 +262,9 @@ def test_moe_roundtrip_mixtral_layout(tmp_path, rng):
 
 def test_parity_vs_transformers_qwen3_moe(tmp_path):
     """Qwen3-MoE: QK-norm + softmax-top-k-renormalized routing + the
-    qwen3 expert layout, parity vs Qwen3MoeForCausalLM. Capacity is set
-    high so our capacity-bounded dispatch drops nothing (HF has no
-    capacity limit); routing weights must then match exactly."""
+    qwen3 expert layout, parity vs Qwen3MoeForCausalLM. The expert layer
+    drops nothing, as HF's has no capacity limit; routing weights must
+    then match exactly."""
     transformers = pytest.importorskip("transformers")
     if not hasattr(transformers, "Qwen3MoeForCausalLM"):
         pytest.skip("transformers too old for Qwen3-MoE")
@@ -284,8 +284,7 @@ def test_parity_vs_transformers_qwen3_moe(tmp_path):
         intermediate_size=48, num_layers=2, num_heads=4, num_kv_heads=2,
         head_dim=16, max_seq_len=128, rope_theta=1_000_000.0,
         qkv_bias=False, qk_norm=True, num_experts=4,
-        num_experts_per_tok=2, expert_capacity_factor=8.0,
-        moe_layout="qwen3",
+        num_experts_per_tok=2, moe_layout="qwen3",
         dtype=jnp.float32, matmul_precision="highest")
     _hf_parity(tmp_path, model, our_cfg, 512)
 

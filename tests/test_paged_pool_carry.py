@@ -140,7 +140,7 @@ def _reference_layer(c, lp, x, cos, sin, leaves, b, use_kernel, ad, ad_ids):
     attn_in = out.reshape(t, 1, c.q_dim)
     attn_out = tf._dense(attn_in, lp, "wo", "bse,ed->bsd")
     x = x + tf._with_adapter(attn_out, attn_in, ad, ad_ids, "wo")
-    x, _ = tf._mlp(c, lp, x)
+    x, *_ = tf._mlp(c, lp, x)      # (x, aux, MoEStats | None)
     return x, leaves
 
 
