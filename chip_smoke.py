@@ -49,7 +49,9 @@ from senweaver_ide_tpu.obs.runtime_profile import get_profiler
 from senweaver_ide_tpu.ops.attention import attention
 from senweaver_ide_tpu.ops.flash_attention import flash_attention
 from senweaver_ide_tpu.ops.flash_decode import flash_decode
-from senweaver_ide_tpu.ops.paged_attention import paged_flash_decode
+from senweaver_ide_tpu.ops.paged_attention import (paged_attention_rows,
+                                                   paged_flash_decode,
+                                                   plan_rows, query_tile)
 from senweaver_ide_tpu.parallel import MeshConfig, make_mesh
 from senweaver_ide_tpu.rollout import RolloutEngine
 from senweaver_ide_tpu.rollout import engine as engine_mod
@@ -408,8 +410,9 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
     out["flash_decode_max_err"] = err
     check(err <= KERNEL_TOL, f"flash_decode off by {err}")
 
-    # paged_flash_decode at the engine's default block_size, bf16 pool
-    # and the int8 pool with fused dequant
+    # paged_flash_decode at the engine's default block_size: one query a
+    # row through paged_attention_rows, and the quantized pools with
+    # fused dequant
     bs = EngineConfig().block_size
     t, mb = 16, s // bs
     nb = 2 * mb
@@ -431,7 +434,7 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
                                         interpret=interpret))
     if not interpret:
         lowered_with_kernel(pfd_jit.lower(qp, k_pool, v_pool, tables, plens),
-                            "_pfd_kernel")
+                            "paged_attention_rows")
     err = max_err(timed("paged_flash_decode", pfd_jit, qp, k_pool, v_pool,
                         tables, plens), gathered(k_pool, v_pool))
     out["paged_flash_decode_max_err"] = err
@@ -455,6 +458,88 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
                      dequantize_pool_kv(vq, vscale, dtype)))
         out[f"paged_flash_decode_{name}_max_err"] = err
         check(err <= KERNEL_TOL, f"paged_flash_decode {name} off by {err}")
+    d = config.head_dim
+    out.update(paged_rows_checks(config.num_heads, config.num_kv_heads, d,
+                                 "", interpret, seed))
+    # a second head shape (mistral-7b's, llama-3.1-8b's, qwen3-8b's):
+    # four times the bytes a block, a quarter of the blocks a chunk
+    out.update(paged_rows_checks(32, 8, d, "_32x8", interpret, seed))
+    return out
+
+
+def paged_rows_checks(hq: int, hkv: int, d: int, tag: str, interpret: bool,
+                      seed: int) -> dict:
+    """``paged_attention_rows`` against the XLA gather it replaces in the
+    fused step, over a stacked pool at the benchmark cells' shapes: 48
+    rows with a table 64 blocks wide, contexts 128-900, as a narrow step
+    (48 decode entries) and a wide one (47 decode rows, a prefill chunk
+    of 137 tokens on the last row, 8 entries of padding). Reports each
+    path's largest error against the other and its time a call (the
+    median of 20, after the first) as ``paged_rows{tag}_{entries}_...``:
+    a bring-up reading of one layer's attention, not the benchmark's."""
+    if interpret:
+        dtype, rows, mb, layers, lo, hi, chunk, pad, reps = (
+            jnp.float32, 6, 8, 2, 20, 100, 21, 3, 1)
+    else:
+        dtype, rows, mb, layers, lo, hi, chunk, pad, reps = (
+            jnp.bfloat16, 48, 64, 2, 128, 900, 137, 8, 20)
+    bs = EngineConfig().block_size
+    nb = rows * mb + 4
+    rng = np.random.default_rng(seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    k_leaf = jax.random.normal(ks[0], (layers, nb, bs, hkv, d), dtype)
+    v_leaf = jax.random.normal(ks[1], (layers, nb, bs, hkv, d), dtype)
+    tables = jnp.asarray(
+        rng.permutation(nb)[:rows * mb].reshape(rows, mb).astype(np.int32))
+    ctx = rng.integers(lo, hi + 1, rows).astype(np.int32)
+    layer = jnp.asarray(layers - 1, jnp.int32)
+
+    # the pool and the tables are arguments: closed over, they would be
+    # constants of the program
+    def gather(k_leaf, v_leaf, tables, q, seq_row, positions):
+        tbl = tables[seq_row]
+        k_seq, v_seq = (leaf[layer, tbl].reshape(-1, mb * bs, hkv, d)
+                        for leaf in (k_leaf, v_leaf))
+        valid = jnp.arange(mb * bs)[None, :] < positions[:, None] + 1
+        return attention(q[:, None], k_seq, v_seq, q_offset=positions,
+                         kv_mask=valid, causal=True)[:, 0]
+
+    def kernel(k_leaf, v_leaf, tables, q, seq_row, positions):
+        plan = plan_rows(seq_row, positions, block_size=bs, table_width=mb,
+                         q_tile=query_tile(hq))
+        return paged_attention_rows(q, k_leaf, v_leaf, layer, tables,
+                                    positions, plan, interpret=interpret)
+
+    def ms_a_call(fn, *args):
+        jax.block_until_ready(fn(*args))
+        times = []
+        for _ in range(reps):
+            t0 = time.monotonic()
+            jax.block_until_ready(fn(*args))
+            times.append(time.monotonic() - t0)
+        return round(1e3 * float(np.median(times)), 4)
+
+    narrow = (np.arange(rows, dtype=np.int32), ctx - 1)
+    wide = (np.concatenate([np.arange(rows - 1), np.full(chunk, rows - 1),
+                            np.zeros(pad)]).astype(np.int32),
+            np.concatenate([ctx[:-1] - 1, ctx[-1] + np.arange(chunk),
+                            np.zeros(pad)]).astype(np.int32))
+    out = {}
+    gather_jit, kernel_jit = jax.jit(gather), jax.jit(kernel)
+    for seq_row, positions in (narrow, wide):
+        t = len(seq_row)
+        q = jax.random.normal(jax.random.fold_in(ks[2], t), (t, hq, d), dtype)
+        args = (k_leaf, v_leaf, tables, q, jnp.asarray(seq_row),
+                jnp.asarray(positions))
+        if not interpret:
+            lowered_with_kernel(kernel_jit.lower(*args),
+                                "paged_attention_rows")
+        err = max_err(kernel_jit(*args), gather_jit(*args))
+        out[f"paged_rows{tag}_{t}_max_err"] = err
+        check(err <= KERNEL_TOL,
+              f"paged_attention_rows{tag} over {t} entries off by {err}")
+        out[f"paged_rows{tag}_{t}_ms"] = ms_a_call(kernel_jit, *args)
+        out[f"paged_gather{tag}_{t}_ms"] = ms_a_call(gather_jit, *args)
     return out
 
 
@@ -670,19 +755,19 @@ def main() -> None:
         flash_config = dataclasses.replace(config, decode_attn_impl="flash")
         flash_config_checks(served, flash_config, sz, interpret)
         # On the chip the engine must choose the kernel by itself
-        # (paged_kernel=None: flash decode on a TPU). The rehearsal
-        # forces it, so the interpreted kernel rides the fused step too.
+        # (paged_kernel=None: an unquantized dense pool on a TPU is read
+        # by paged_attention_rows, as in every engine above). The
+        # rehearsal forces it, so the interpreted kernel rides the fused
+        # step too.
         flash = RolloutEngine(
             served, flash_config, num_slots=sz.num_slots,
             max_len=sz.max_len, seed=args.seed,
             sample=SampleParams(temperature=0.0),
             engine_config=EngineConfig(
                 paged_kernel=True if interpret else None))
-        check(flash._use_paged_kernel,
-              f"decode_attn_impl='flash' on {dev.platform} did not select "
-              f"the paged kernel")
         if not interpret:
-            lowered_with_kernel(fused_step_lowering(flash), "_pfd_kernel")
+            lowered_with_kernel(fused_step_lowering(flash),
+                                "paged_attention_rows")
         rec.update(greedy_agreement(
             flash, scorer, served,
             draw_prompts(rng, sz.score_rows, sz.prompt_lo,

@@ -881,7 +881,8 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                  tables: jax.Array, seq_row: jax.Array,
                  positions: jax.Array, write_block: jax.Array,
                  write_off: jax.Array, use_kernel: bool = False,
-                 adapters=None, adapter_ids=None, stack_layer=None):
+                 adapters=None, adapter_ids=None, stack_layer=None,
+                 row_plan=None):
     """One transformer block over a paged KV pool (rollout/paged_kv.py).
 
     ``x`` is a flat token batch ``(T, 1, D)`` — T independent
@@ -903,16 +904,26 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     leaves through its layer scan, so a donated pool is updated in
     place.
 
-    The gathered view is a contiguous ``(T, MB*BS, Hkv, Dh)`` cache
+    With a ``row_plan`` (``ops.paged_attention.plan_rows`` of this
+    batch; ``forward_paged`` makes one where the kernel runs) an
+    unquantized pool is read by ``paged_attention_rows``: each run of
+    entries of one row attends together over that row's live blocks,
+    streamed from the stacked leaves where they lie. Without one, the
+    XLA gather: a contiguous ``(T, MB*BS, Hkv, Dh)`` cache
     per token, attended with the SAME mask and attention call as the
     slot path (`kv_pos < pos+1`, causal with per-row ``q_offset``), so
     paged and slot decode agree to numerical identity of the masking
-    and matmul shapes' element-wise dot products.
+    and matmul shapes' element-wise dot products. It is the plain
+    reference the kernel is tested against, and the path off the TPU.
+    ``use_kernel`` on a QUANTIZED pool is the dequant-fused
+    ``paged_flash_decode`` over this layer's slice.
 
     The ``jax.named_scope`` names (``attn.qkv``, ``attn.kv_write``,
     ``attn.kv_gather``, ``attn.scores``, ``attn.out``, ``mlp``) are the
     stable device-side names of docs/observability.md: they ride each
     HLO instruction's ``op_name`` metadata and change no computation.
+    ``attn.kv_gather`` exists on the gather path alone; the kernel is
+    ``paged_attention_rows`` in a trace.
     """
     if c.mla:
         return _paged_mla_layer(c, lp, x, cos, sin, leaves, layer, tables,
@@ -949,12 +960,16 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
             leaf.at[layer, write_block[:, None], write_off[:, None],
                     head].set(val, mode="drop")
             for leaf, val in zip(leaves, new))
-    if use_kernel:
+    if row_plan is not None and not quantized:
+        from ..ops.paged_attention import paged_attention_rows
+        with jax.named_scope("attn.scores"):
+            out = paged_attention_rows(q[:, 0], *leaves, layer, tables,
+                                       positions, row_plan)[:, None]
+    elif use_kernel and quantized:
         from ..ops.paged_attention import paged_flash_decode
-        # the kernel takes ONE layer's pool and gathers through the
-        # table itself
-        k_pool, v_pool, *scales = (leaf[layer] for leaf in leaves)
-        k_scale, v_scale = scales or (None, None)
+        # the dequant-fused kernel takes ONE layer's pool and gathers
+        # through the table itself
+        k_pool, v_pool, k_scale, v_scale = (leaf[layer] for leaf in leaves)
         with jax.named_scope("attn.scores"):
             out = paged_flash_decode(q[:, 0], k_pool, v_pool,
                                      tables[seq_row], positions + 1,
@@ -1079,7 +1094,7 @@ def forward_paged(
     write_block: jax.Array,       # (T,) int32 — pool block to write
                                   # (num_blocks = drop)
     write_off: jax.Array,         # (T,) int32 — offset within block
-    use_kernel: bool = False,     # static: Pallas paged-decode kernel
+    use_kernel: Optional[bool] = None,  # static: None = by what runs
     adapters=None,                # per-rung LoRA bank dicts, leading L
     adapter_ids=None,             # per-rung (T,) int32 slot ids
     with_moe_stats: bool = False,  # static: also return MoEStats
@@ -1112,6 +1127,16 @@ def forward_paged(
     dense layers scans its two stacks one after the other over the same
     carried leaves, each layer at its absolute index.
 
+    ``use_kernel=None`` chooses by what the code sees
+    (``reads_pool_in_place``): on a TPU an unquantized dense pool (and
+    the full-width prefix layers of a ``kv_dtype_per_layer`` ladder)
+    whose ``head_dim`` is a multiple of 128 is read by
+    ``ops.paged_attention.paged_attention_rows``; off the TPU, and for a
+    quantized or a latent pool or narrower heads, the XLA gather. ``True`` forces the
+    kernels (interpreted off the TPU; on a quantized pool the
+    dequant-fused ``paged_flash_decode``), ``False`` the gather: both
+    are for tests.
+
     ``with_moe_stats=True`` (an expert configuration) returns a third
     value, ``MoEStats`` summed over the expert layers (``experts_touched``)
     and their largest (``expert_load_max``), counted over the entries that
@@ -1134,6 +1159,24 @@ def forward_paged(
     return out if with_moe_stats else out[:2]
 
 
+def reads_pool_in_place(c: ModelConfig, use_kernel: Optional[bool]) -> bool:
+    """``forward_paged``'s choice for the unquantized leaves of a dense
+    pool: True where ``paged_attention_rows`` reads them where they lie,
+    False where the XLA gather copies each entry's table width. By what
+    the code sees (``use_kernel=None``): a TPU takes the kernel where
+    Mosaic can cut a block's heads out of the pool, which needs head
+    rows of whole 128-lane tiles (at a ``head_dim`` of 64 it refuses the
+    block's window: those models keep the gather). The engine asks too:
+    where the kernel reads, a step's cost does not follow the table's
+    width, so its table keeps one width."""
+    if c.mla:
+        return False
+    if use_kernel is not None:
+        return bool(use_kernel)
+    from ..ops.paged_attention import on_tpu
+    return on_tpu() and c.head_dim % 128 == 0
+
+
 def _forward_paged_impl(params, c, tokens, *, pool, tables,
                         seq_row, positions, write_block, write_off,
                         use_kernel, adapters=None, adapter_ids=None):
@@ -1149,6 +1192,17 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
     # STATIC under jit: derived from pytree structure (None-ness and
     # shapes), so the precision ladder never adds a trace argument.
     n_hi = 0 if pool.k_hi is None else pool.k_hi.shape[0]
+    row_plan = None
+    if (pool.k_scale is None or n_hi) and reads_pool_in_place(c,
+                                                              use_kernel):
+        # once a step, outside the layer scans: the flat batch cut into
+        # the runs of one row that paged_attention_rows attends together
+        from ..ops.paged_attention import plan_rows, query_tile
+        with jax.named_scope("attn.row_plan"):
+            row_plan = plan_rows(seq_row, positions,
+                                 block_size=pool.k.shape[2],
+                                 table_width=tables.shape[1],
+                                 q_tile=query_tile(c.num_heads))
 
     def scan_layers(x, layers, ad, leaves, first=0):
         """The layer scan over one group of pool leaves. The leaves ride
@@ -1182,7 +1236,8 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                 c, lp, x, cos, sin, leaves, layer, tables, seq_row,
                 positions, write_block, write_off, use_kernel=use_kernel,
                 adapters=ad_l, adapter_ids=adapter_ids,
-                stack_layer=None if banks is None else layer - first)
+                stack_layer=None if banks is None else layer - first,
+                row_plan=row_plan)
             if stats is not None:
                 acc = MoEStats(
                     acc.experts_touched + stats.experts_touched,

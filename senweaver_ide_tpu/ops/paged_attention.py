@@ -1,38 +1,72 @@
-"""Paged flash-decode: Pallas TPU kernel reading KV through block tables.
+"""Paged attention: Pallas TPU kernels reading KV through block tables.
 
 The paged engine (rollout/paged_kv.py) stores KV in a fixed pool of
-``(block_size, Hkv, D)`` blocks; each token's sequence is a list of
-physical block ids. The XLA gather path
-(``models.transformer._paged_layer``) materializes a contiguous
-``(T, MB*BS, Hkv, D)`` copy of every token's blocks in HBM each step;
-this kernel instead DMAs each block straight from the pool into VMEM
-using the **scalar-prefetched block table in the BlockSpec index maps**
-— the `(token, logical_block) -> physical_block` translation happens at
-DMA-issue time, so per-step HBM traffic is one streamed read of the
-referenced blocks and no gathered intermediate.
+``(block_size, Hkv, D)`` blocks; each sequence is a list of physical
+block ids. The XLA gather path (``models.transformer._paged_layer``)
+materializes, for EVERY entry of the flat batch, a contiguous copy of its
+sequence's whole table width. The kernels here read the blocks where
+they lie.
 
-Everything else is ``ops/flash_decode.py``: online-softmax scratch
-(acc/m/l in VMEM), the GQA ``(kv_head, group)`` sublane layout, block
-skipping past each token's fill level, interpret mode off-TPU.
+``paged_attention_rows`` is the one the fused step runs on an
+unquantized dense pool. It works **per sequence row, not per entry**: a
+*segment* is a maximal run of entries with the same ``seq_row`` (a decode
+row is a segment of one, a prefill chunk or a verify window a segment of
+n), and a segment's queries attend together over that row's live blocks,
+``ceil((max position + 1) / block_size)`` of them, streamed from the
+pool leaf AS STORED — stacked over layers, the layer a prefetched scalar —
+into VMEM by the kernel's own double-buffered DMAs, a chunk of blocks a
+compute step, with an online softmax; each query is masked by
+its own position. Nothing of size ``T x table_width x block_size`` is
+written anywhere, and no layer is sliced out of the pool.
+
+How it is laid out:
+
+- ``plan_rows`` (plain XLA, once a step, outside the layer scan) finds
+  the segments from ``seq_row`` and cuts them into *items*: a segment of
+  one query is one item, a longer one ``ceil(n / q_tile)`` items of up
+  to ``q_tile`` queries. A row that appears in two runs is two segments:
+  slower, never wrong. Tail padding (row 0, position 0) is one segment of
+  one block.
+- The kernel is ONE program (grid of 1) that loops over every (item,
+  chunk of its blocks) in order. While chunk g is computed, chunk g+1
+  (the same item's next, or the next item's first) is in flight into
+  the other half of the buffers. A block's DMA is issued only if the
+  item covers it.
+- A block arrives as the pool holds it, ``(block_size, Hkv, D)`` with
+  the kv heads interleaved position by position, and is used like that:
+  the chunk is read as ``(blocks * block_size * Hkv, D)`` rows, every
+  query head takes its scores against all of them in one product, and
+  the mask keeps the columns of a head's own kv head (``col % Hkv``).
+  The other columns get probability 0, so the PV product over the same
+  interleaved rows is the grouped one. No strided load, no per-head
+  loop. A decode row streams K and V through the MXU as weights either
+  way, so the other heads' columns cost it mask and exp work alone; a
+  tile of queries pays ``Hkv`` times the products.
+- The score tile is bounded whatever the heads (``query_tile``,
+  ``blocks_per_chunk``): with many kv heads a compute step takes fewer
+  blocks. Mosaic needs head rows of whole 128-lane tiles to cut a
+  block out of the pool: ``forward_paged`` keeps the gather for a
+  ``head_dim`` that is no multiple of 128.
+- Numerics are ``ops/attention.py``'s: low-precision operands on the MXU
+  with f32 accumulation, scores, softmax and the output accumulator in
+  f32, probabilities cast to the value dtype for the PV product; f32
+  inputs take the exact path (``Precision.HIGHEST``).
+
+``paged_flash_decode`` is the one-query-a-row form over ONE layer's
+pool: unquantized, it is ``paged_attention_rows`` with every entry its
+own row. With ``k_scale``/``v_scale`` it is the **dequant-fused**
+kernel for int8/fp8 pools (rollout/paged_kv.py quantized ladder): one
+(token, logical block) program with the physical block id resolved in
+the BlockSpec index maps, the scales riding their own specs through the
+same indirection and the rescale fused after the payload's upcast.
 
 ``lengths[t]`` counts valid positions including the freshly-written
 current token (write-then-attend, same contract as flash_decode).
-
-**Dequant-fused variant** (``k_scale``/``v_scale`` passed): the pool
-holds int8/fp8 payloads plus per-(block, position, head) f32 absmax
-scales (rollout/paged_kv.py quantized ladder). The scales ride their
-own scalar-prefetched block specs through the SAME table indirection,
-and the rescale happens inside the per-block loop right after the
-payload's f32 upcast — a quantized block is never materialized at full
-width anywhere but the (BS, D) tile being consumed in VMEM, so HBM
-traffic per step drops with the payload width. Note Mosaic's int8
-min-tile is (32, 128) on the last two dims; sub-tile block_size/D
-configs rely on relayout padding (and the interpret path, used by the
-CPU test fleet, has no tiling constraint at all).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional
 
@@ -44,22 +78,335 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import MASKED_THRESHOLD as _MASKED
 from .attention import NEG_INF
 
+# The score tile of a compute step: the head rows of an item's queries by
+# the (position, kv head) columns of a chunk of blocks, f32, with its
+# mask and probabilities beside it in VMEM. Both sides follow the head
+# shape (``query_tile``, ``blocks_per_chunk``): 12/2 heads and blocks of
+# 16 give 32 queries x 16 blocks, 32/8 give 16 x 4, 32/32 give 16 x 1.
+# Unbounded, a tile of 32 queries x 16 blocks ran out of VMEM from 8 kv
+# heads on. On a v5e, 28 layers at the cells' shapes (12/2 x 128; my chip
+# runs, PR 27): 6.1 ms a 48-row step at 16 blocks a chunk, 6.5 at 8, 9.0
+# at 4 (the gather: 14.6); the 192-entry step 7.6 ms at (32, 16), 8.5 at
+# (32, 8) (the gather: 55.6).
+TILE_ROWS = 512
+TILE_COLS = 512
+# head rows a query takes in the tile: whole bf16 sublane tiles, so a
+# tile of queries folds into the rows of one product without a relayout
+_HEAD_ROWS = 16
 
-def _pfd_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
-                scale: float, block_size: int, hkv: int, rep_pad: int,
-                quantized: bool):
-    """One (token, logical block) program. The K/V refs already hold the
-    PHYSICAL block — the index maps resolved ``tables_ref`` before the
-    DMA — so the body only needs the logical position ``bi * block_size``
-    for masking. KV heads loop inside (Mosaic tiling: the head axis must
-    stay whole in the block specs for Hkv < 8). With ``quantized`` the
-    ref list carries per-block scale tiles and the upcast to f32 is
-    immediately rescaled — dequant fused into the block loop."""
-    if quantized:
-        ks_ref, vs_ref, out_ref, acc_ref, m_ref, l_ref = refs
-    else:
-        ks_ref = vs_ref = None
-        out_ref, acc_ref, m_ref, l_ref = refs
+
+def _head_rows(num_q_heads: int) -> int:
+    return -(-num_q_heads // _HEAD_ROWS) * _HEAD_ROWS
+
+
+def query_tile(num_q_heads: int) -> int:
+    """Queries an item of a multi-query segment holds, for ``plan_rows``:
+    as many as fill the score tile's rows."""
+    return max(1, TILE_ROWS // _head_rows(num_q_heads))
+
+
+def blocks_per_chunk(block_size: int, num_kv_heads: int) -> int:
+    """Pool blocks a compute step streams: as many as fill the score
+    tile's columns."""
+    return max(1, TILE_COLS // (block_size * num_kv_heads))
+
+
+def on_tpu() -> bool:
+    """Whether programs traced now run on a TPU: what chooses the kernel
+    over the XLA gather in ``forward_paged``, and Mosaic over interpret
+    mode here."""
+    return jax.default_backend() == "tpu"
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["row", "q0", "count", "blocks", "num_items"],
+                   meta_fields=["q_tile"])
+@dataclasses.dataclass(frozen=True)
+class RowPlan:
+    """The flat batch cut into the kernel's items, all ``(T,)`` int32
+    but the count: item i attends queries ``[q0, q0 + count)`` of the
+    flat batch, all of table row ``row``, over its first ``blocks``
+    blocks. Items lie in the order of the batch; the entries past
+    ``num_items`` hold no work. ``q_tile`` (static) is the most queries
+    an item holds: the kernel sizes its tiles by it."""
+    row: jax.Array
+    q0: jax.Array
+    count: jax.Array
+    blocks: jax.Array
+    num_items: jax.Array      # (1,)
+    q_tile: int
+
+
+def plan_rows(seq_row: jax.Array, positions: jax.Array, *,
+              block_size: int, table_width: int, q_tile: int) -> RowPlan:
+    """Find the segments of a flat batch on the device and cut them into
+    items of up to ``q_tile`` queries (``query_tile`` of the model's
+    heads; see the module docstring). A boundary is where
+    ``seq_row[t] != seq_row[t-1]``; an item covers the blocks up to the
+    largest position among ITS queries, so a chunk's early tiles read
+    less than its last. A handful of small ops (the fused step lowers
+    them for every shape it compiles): two scans and one segment
+    reduction."""
+    t = seq_row.shape[0]
+    seq_row = seq_row.astype(jnp.int32)
+    idx = jnp.arange(t, dtype=jnp.int32)
+    start = jnp.concatenate(
+        [jnp.ones((1,), bool), seq_row[1:] != seq_row[:-1]])
+    # an entry opens an item where its segment starts and every q_tile
+    # entries after
+    seg_q0 = jax.lax.cummax(jnp.where(start, idx, 0))
+    opens = (idx - seg_q0) % q_tile == 0
+    item = jnp.cumsum(opens.astype(jnp.int32)) - 1        # entry -> item
+    # per item: its last position, its row, its last and (negated) first
+    # entry; an item past the last gets the reduction's identity (< 0)
+    last, row, hi, lo = jax.ops.segment_max(
+        jnp.stack([positions.astype(jnp.int32), seq_row, idx, -idx], 1),
+        item, num_segments=t, indices_are_sorted=True).T
+    live = hi >= 0
+    return RowPlan(
+        row=jnp.where(live, row, 0),
+        q0=jnp.where(live, -lo, 0),
+        count=jnp.where(live, hi + lo + 1, 0),
+        blocks=jnp.where(live, jnp.clip(
+            (last + block_size) // block_size, 1, table_width), 0),
+        num_items=item[-1:] + 1, q_tile=q_tile)
+
+
+def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
+                 blocks_ref, num_ref, pos_ref,           # scalars (SMEM)
+                 q_ref, k_hbm, v_hbm,                    # inputs
+                 out_ref,                                # output
+                 k_buf, v_buf, sems, acc_ref, m_ref, l_ref, qpos_ref,
+                 *, scale: float, block_size: int, hkv: int, rep: int,
+                 hq_pad: int, q_tile: int, chunk: int, exact: bool):
+    """The whole flat batch in one program; see the module docstring.
+
+    ``k_buf``/``v_buf`` are ``(2, chunk) + a block's shape``: two
+    slots of one chunk each. One loop runs over every (item, chunk) in
+    order; step g computes out of slot ``g % 2`` what step g-1 started
+    into it, after starting its own successor into the other. Every
+    started DMA is waited for by the step that computes it, which
+    rebuilds the same descriptors from the same scalars. The DMA code is
+    traced three times and the compute twice (one query, a tile of
+    them) whatever the sizes: the step's lowering time is part of
+    ``setup_s``."""
+    n_cols = chunk * block_size * hkv
+    layer = layer_ref[0]
+    num_items = num_ref[0]
+    precision = jax.lax.Precision.HIGHEST if exact else None
+
+    def for_blocks(item, c, slot, act):
+        """``act`` on the (k copy, v copy) of each block of chunk ``c`` of
+        ``item`` that the item covers, into ``slot``: a loop, so the
+        program's size does not grow with the chunk."""
+        row = row_ref[item]
+        first = c * chunk
+
+        def body(b, _):
+            phys = tables_ref[row, first + b]
+            act(pltpu.make_async_copy(k_hbm.at[layer, phys],
+                                      k_buf.at[slot, b], sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, phys],
+                                      v_buf.at[slot, b], sems.at[1, slot]))
+            return 0
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(chunk, blocks_ref[item] - first), body, 0)
+
+    def start(kc, vc):
+        kc.start()
+        vc.start()
+
+    def wait(kc, vc):
+        kc.wait()
+        vc.wait()
+
+    # A slot's rows that no DMA has filled yet must hold numbers: their
+    # columns are masked, and 0 x stale is 0 only if stale is finite.
+    k_buf[...] = jnp.zeros_like(k_buf)
+    v_buf[...] = jnp.zeros_like(v_buf)
+
+    @pl.when(num_items > 0)
+    def _first():
+        for_blocks(0, 0, 0, start)
+
+    def attend(item, c, slot, last, queries: int):
+        """Chunk ``c`` of an item of ``queries`` (static) query slots,
+        ``hq_pad`` head rows each. Rows past the item's count, and the
+        padded heads, compute and are not kept: what the last chunk
+        stores of them is overwritten by the items that own those
+        entries, which come later."""
+        rows = queries * hq_pad
+        q0 = q0_ref[item]
+
+        @pl.when(c == 0)
+        def _init():
+            m_ref[:rows] = jnp.full((rows, 1), NEG_INF, jnp.float32)
+            l_ref[:rows] = jnp.zeros((rows, 1), jnp.float32)
+            acc_ref[:rows] = jnp.zeros((rows, acc_ref.shape[-1]),
+                                       jnp.float32)
+            if queries > 1:
+                # each row's own position, kept for the item's chunks
+                which = jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, 1), 0) // hq_pad
+                qpos_ref[...] = jax.lax.fori_loop(
+                    0, queries,
+                    lambda i, acc: jnp.where(which == i, pos_ref[q0 + i],
+                                             acc),
+                    jnp.zeros((rows, 1), jnp.int32))
+
+        if queries == 1:
+            q = q_ref[q0]                                  # (hq_pad, D)
+            q_pos = pos_ref[q0]
+        else:
+            q = q_ref[pl.ds(q0, queries)].reshape(rows, q_ref.shape[-1])
+            q_pos = qpos_ref[...]
+        # a column is (position, kv head), heads innermost; a row's kv
+        # head is its head's group
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % hq_pad
+        own = (col % hkv) == (head // rep)          # (rows, n_cols)
+        seen = col // hkv + c * (chunk * block_size) <= q_pos
+        k = k_buf.at[slot].reshape(n_cols, k_buf.shape[-1])[...]
+        v = v_buf.at[slot].reshape(n_cols, v_buf.shape[-1])[...]
+        s = jax.lax.dot_general(
+            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=precision) * scale                   # (rows, n_cols)
+        s = jnp.where(jnp.logical_and(own, seen), s, NEG_INF)
+        m_prev = m_ref[:rows]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(s > _MASKED, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l = corr * l_ref[:rows] + jnp.sum(p, axis=-1, keepdims=True)
+        acc = corr * acc_ref[:rows] + jax.lax.dot_general(
+            p if exact else p.astype(v.dtype), v,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        m_ref[:rows] = m_new
+        l_ref[:rows] = l
+        acc_ref[:rows] = acc
+
+        @pl.when(last)
+        def _store():
+            res = (acc / jnp.where(l > 0.0, l, 1.0)).astype(out_ref.dtype)
+            if queries == 1:
+                out_ref[q0] = res
+            else:
+                out_ref[pl.ds(q0, queries)] = res.reshape(
+                    queries, hq_pad, res.shape[-1])
+
+    def step(carry):
+        g, item, c = carry
+        slot = g % 2
+        last = (c + 1) * chunk >= blocks_ref[item]
+        nxt_item = jnp.where(last, item + 1, item)
+        nxt_c = jnp.where(last, 0, c + 1)
+
+        @pl.when(nxt_item < num_items)
+        def _prefetch():
+            for_blocks(nxt_item, nxt_c, 1 - slot, start)
+
+        for_blocks(item, c, slot, wait)
+        if q_tile == 1:
+            attend(item, c, slot, last, 1)
+        else:
+            single = count_ref[item] == 1
+            pl.when(single)(lambda: attend(item, c, slot, last, 1))
+            pl.when(jnp.logical_not(single))(
+                lambda: attend(item, c, slot, last, q_tile))
+        return g + 1, nxt_item, nxt_c
+
+    jax.lax.while_loop(lambda carry: carry[1] < num_items, step,
+                       (jnp.int32(0), jnp.int32(0), jnp.int32(0)))
+
+
+def paged_attention_rows(
+    q: jax.Array,              # (T, Hq, D) — one query per entry
+    k_leaf: jax.Array,         # (L, NB, BS, Hkv, D) — the pool leaf as
+    v_leaf: jax.Array,         #   stored, every layer
+    layer: jax.Array,          # () int32 — this block's index into them
+    tables: jax.Array,         # (R, MB) int32 — physical block per
+                               # (row, logical block)
+    positions: jax.Array,      # (T,) int32 — each query's own position
+    plan: RowPlan,             # plan_rows(seq_row, positions, ...)
+    *,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Attention of a flat paged batch over its rows' blocks, read in
+    place (module docstring). Query t sees positions ``<= positions[t]``
+    of its row. Returns ``(T, Hq, D)``."""
+    t, hq, d = q.shape
+    _, _, bs, hkv, _ = k_leaf.shape
+    rep = hq // hkv
+    exact = q.dtype == jnp.float32
+    hq_pad = _head_rows(hq)
+    q_tile = plan.q_tile
+    chunk = min(blocks_per_chunk(bs, hkv), tables.shape[1])
+    if interpret is None:
+        interpret = not on_tpu()
+    # q_tile rows of slack: a tile that starts near the end reads and
+    # writes past T
+    qp = jnp.pad(q, ((0, q_tile), (0, hq_pad - hq), (0, 0)))
+    pos = jnp.pad(positions.astype(jnp.int32), (0, q_tile))
+    rows = q_tile * hq_pad
+    kernel = functools.partial(
+        _rows_kernel, scale=1.0 / (d ** 0.5), block_size=bs, hkv=hkv,
+        rep=rep, hq_pad=hq_pad, q_tile=q_tile, chunk=chunk, exact=exact)
+    if hkv == 1:
+        # a lone kv head is no axis of a block: Mosaic cannot cut a
+        # packed (block_size, 1, D) window out of the pool
+        k_leaf, v_leaf = (leaf.reshape(leaf.shape[:3] + (d,))
+                          for leaf in (k_leaf, v_leaf))
+    block = k_leaf.shape[2:]
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    live_bytes = 2 * t * tables.shape[1] * bs * hkv * d * k_leaf.dtype.itemsize
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=8,
+            grid=(1,),
+            in_specs=[vmem, hbm, hbm],
+            out_specs=vmem,
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk) + block, k_leaf.dtype),
+                pltpu.VMEM((2, chunk) + block, v_leaf.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((rows, d), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # q and the output are whole in VMEM, beside 16 MiB for the
+            # rest: past the default limit for a long flat batch
+            vmem_limit_bytes=min(2 * qp.size * qp.dtype.itemsize
+                                 + (16 << 20), 100 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * t * hq * tables.shape[1] * bs * d // 2,
+            bytes_accessed=live_bytes // 2,
+            transcendentals=t * hq * tables.shape[1] * bs // 2),
+        name="paged_attention_rows",
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+      jnp.asarray(tables, jnp.int32), plan.row, plan.q0, plan.count,
+      plan.blocks, plan.num_items, pos, qp, k_leaf, v_leaf)
+    return out[:t, :hq]
+
+
+def _pfd_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, ks_ref,
+                vs_ref, out_ref, acc_ref, m_ref, l_ref, *,
+                scale: float, block_size: int, hkv: int, rep_pad: int):
+    """One (token, logical block) program over a QUANTIZED pool. The K/V
+    refs already hold the PHYSICAL block — the index maps resolved
+    ``tables_ref`` before the DMA — so the body only needs the logical
+    position ``bi * block_size`` for masking. KV heads loop inside
+    (Mosaic tiling: the head axis must stay whole in the block specs for
+    Hkv < 8). The per-block scale tiles rescale the payload right after
+    its f32 upcast — dequant fused into the block loop."""
     ti = pl.program_id(0)
     bi = pl.program_id(1)
     n_blk = pl.num_programs(1)
@@ -79,8 +426,7 @@ def _pfd_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
         for h in range(hkv):
             qh = q[h * rep_pad:(h + 1) * rep_pad]            # (rep_pad, D)
             kh = k_ref[0, :, h, :].astype(jnp.float32)       # (BS, D)
-            if quantized:
-                kh = kh * ks_ref[0, :, h][:, None]
+            kh = kh * ks_ref[0, :, h][:, None]
             s_heads.append(jax.lax.dot_general(
                 qh, kh, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32))         # (rep_pad, BS)
@@ -99,8 +445,7 @@ def _pfd_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, *refs,
         for h in range(hkv):
             ph = p[h * rep_pad:(h + 1) * rep_pad]
             vh = v_ref[0, :, h, :].astype(jnp.float32)       # (BS, D)
-            if quantized:
-                vh = vh * vs_ref[0, :, h][:, None]
+            vh = vh * vs_ref[0, :, h][:, None]
             pv_heads.append(jax.lax.dot_general(
                 ph, vh, dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))         # (rep_pad, D)
@@ -131,24 +476,35 @@ def paged_flash_decode(
     v_scale: Optional[jax.Array] = None,   # scales for int8/fp8 pools
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Block-table cache attention for the flat paged token batch.
-    Returns (T, Hq, D). The KV block size IS the kernel block size —
-    the pool was allocated block-aligned, so there is never a pad-copy
-    path here (the flash_decode ``Smax % block_kv`` failure mode cannot
-    arise by construction). Passing ``k_scale``/``v_scale`` selects the
-    dequant-fused variant for quantized pools."""
+    """Block-table cache attention with one query a table row. Returns
+    (T, Hq, D). The KV block size IS the kernel block size — the pool
+    was allocated block-aligned, so there is never a pad-copy path here
+    (the flash_decode ``Smax % block_kv`` failure mode cannot arise by
+    construction). Unquantized, it is ``paged_attention_rows`` with
+    every entry a row of its own; passing ``k_scale``/``v_scale``
+    selects the dequant-fused kernel for quantized pools. Note Mosaic's
+    int8 min-tile is (32, 128) on the last two dims; sub-tile
+    block_size/D configs rely on relayout padding (and the interpret
+    path, used by the CPU test fleet, has no tiling constraint at
+    all)."""
     t, hq, d = q.shape
     nb, bs, hkv, _ = k_pool.shape
     mb = tables.shape[1]
-    rep = hq // hkv
-    rep_pad = max(8, -(-rep // 8) * 8)
-    quantized = k_scale is not None
-    if quantized and v_scale is None:
-        raise ValueError("k_scale passed without v_scale")
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (t,))
     tables = jnp.asarray(tables, jnp.int32)
+    if k_scale is None:
+        positions = lengths - 1
+        plan = plan_rows(jnp.arange(t, dtype=jnp.int32), positions,
+                         block_size=bs, table_width=mb, q_tile=1)
+        return paged_attention_rows(
+            q, k_pool[None], v_pool[None], jnp.zeros((), jnp.int32),
+            tables, positions, plan, interpret=interpret)
+    if v_scale is None:
+        raise ValueError("k_scale passed without v_scale")
+    rep = hq // hkv
+    rep_pad = max(8, -(-rep // 8) * 8)
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = not on_tpu()
 
     # (T, Hq, D) → (T, Hkv*rep_pad, D): flattened (kv-head, group) pairs
     # on the sublane axis, same layout as flash_decode.
@@ -158,32 +514,23 @@ def paged_flash_decode(
     qg = qg.reshape(t, hkv * rep_pad, d)
 
     kernel = functools.partial(_pfd_kernel, scale=1.0 / (d ** 0.5),
-                               block_size=bs, hkv=hkv, rep_pad=rep_pad,
-                               quantized=quantized)
+                               block_size=bs, hkv=hkv, rep_pad=rep_pad)
     rows = hkv * rep_pad
     # The paged trick: the physical block id comes from the scalar-
     # prefetched table at DMA-issue time. Full head axis per block
-    # (Mosaic last-two-dims tiling rule). Scale tiles (quantized pools)
-    # ride the same indirection.
+    # (Mosaic last-two-dims tiling rule). The scale tiles ride the same
+    # indirection.
     pool_spec = pl.BlockSpec(
         (1, bs, hkv, d), lambda ti, bi, tbl, lens: (tbl[ti, bi], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, rows, d),
-                     lambda ti, bi, tbl, lens: (ti, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    operands = [qg, k_pool, v_pool]
-    if quantized:
-        scale_spec = pl.BlockSpec(
-            (1, bs, hkv), lambda ti, bi, tbl, lens: (tbl[ti, bi], 0, 0))
-        in_specs += [scale_spec, scale_spec]
-        operands += [jnp.asarray(k_scale, jnp.float32),
-                     jnp.asarray(v_scale, jnp.float32)]
+    scale_spec = pl.BlockSpec(
+        (1, bs, hkv), lambda ti, bi, tbl, lens: (tbl[ti, bi], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # tables, lengths
         grid=(t, mb),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((1, rows, d),
+                         lambda ti, bi, tbl, lens: (ti, 0, 0)),
+            pool_spec, pool_spec, scale_spec, scale_spec],
         out_specs=pl.BlockSpec((1, rows, d),
                                lambda ti, bi, tbl, lens: (ti, 0, 0)),
         scratch_shapes=[
@@ -192,7 +539,7 @@ def paged_flash_decode(
             pltpu.VMEM((rows, 1), jnp.float32),
         ],
     )
-    kv_bytes = d * k_pool.dtype.itemsize + (4 if quantized else 0)
+    kv_bytes = d * k_pool.dtype.itemsize + 4
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -204,6 +551,7 @@ def paged_flash_decode(
             bytes_accessed=2 * t * mb * bs * hkv * kv_bytes,
             transcendentals=t * hq * mb * bs),
         interpret=interpret,
-    )(tables, lengths, *operands)
+    )(tables, lengths, qg, k_pool, v_pool,
+      jnp.asarray(k_scale, jnp.float32), jnp.asarray(v_scale, jnp.float32))
 
     return out.reshape(t, hkv, rep_pad, d)[:, :, :rep, :].reshape(t, hq, d)

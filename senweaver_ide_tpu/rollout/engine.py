@@ -36,7 +36,7 @@ import numpy as np
 
 from ..models.config import LatentCacheUnsupported, ModelConfig
 from ..models.transformer import (KVCache, Params, forward, forward_paged,
-                                  init_kv_cache)
+                                  init_kv_cache, reads_pool_in_place)
 from ..obs import get_registry, get_tracer
 from ..obs.runtime_profile import ProfiledFunction, profiled_device_get
 from ..obs.tracing import noop_span
@@ -245,7 +245,7 @@ def _paged_fused_step(params: Params, config: ModelConfig,
                       write_block: jax.Array, write_off: jax.Array,
                       pool: PagedKVPool,
                       key: jax.Array, sample: SampleParams,
-                      use_kernel: bool,
+                      use_kernel: Optional[bool],
                       adapters=None, adapter_ids=None):
     """One fused paged step over a flat token batch: decode rows and
     exact-size chunked-prefill segments share the same forward under a
@@ -291,7 +291,7 @@ def _draft_propose_scan(params: Params, config: ModelConfig,
                         cur_tok: jax.Array, base_pos: jax.Array,
                         spec_mask: jax.Array, tables: jax.Array,
                         pool: PagedKVPool,
-                        k: int, use_kernel: bool):
+                        k: int, use_kernel: Optional[bool]):
     """Greedy draft proposal loop, entirely on device: ``k`` sequential
     draft-model decode steps over every speculating row at once
     (``spec_mask``), each feeding its own argmax back in. One device
@@ -332,7 +332,7 @@ def _draft_feed_step(params: Params, config: ModelConfig,
                      seq_row: jax.Array, positions: jax.Array,
                      write_block: jax.Array, write_off: jax.Array,
                      pool: PagedKVPool,
-                     use_kernel: bool):
+                     use_kernel: Optional[bool]):
     """Draft-cache catch-up: run the draft model over a flat token
     batch purely for its KV writes (logits discarded, no transfer).
     This is how the draft reaches lockstep with the target after
@@ -395,8 +395,12 @@ class EngineConfig:
     # budget cannot starve resident requests); the remainder fills
     # with exact-size prefill segments.
     step_tokens: Optional[int] = None
-    # None = auto: use the Pallas paged-attention kernel on TPU when
-    # the model already opted into flash decode; True/False forces.
+    # None = by what the code sees (models.transformer.forward_paged):
+    # on a TPU an unquantized dense pool of 128-wide heads is read in
+    # place by the Pallas kernel ops.paged_attention.paged_attention_rows,
+    # everything else by the XLA gather. True / False force the kernels
+    # on or off (interpreted off the TPU): a test override, not a
+    # serving knob.
     paged_kernel: Optional[bool] = None
     # Host-RAM tier for warm prefixes (rollout/kv_pressure.py): under
     # pool pressure, warm/shared prefixes swap to host numpy buffers
@@ -756,11 +760,19 @@ class RolloutEngine:
             st = self.engine_config.step_tokens
             self._step_tokens = max(
                 num_slots, int(st) if st else max(4 * num_slots, 64))
-            pk = self.engine_config.paged_kernel
-            if pk is None:
-                pk = (config.decode_attn_impl == "flash"
-                      and jax.devices()[0].platform == "tpu")
-            self._use_paged_kernel = bool(pk)
+            # None: forward_paged chooses by what it sees (the platform
+            # and the pool's leaves); True / False force it, for tests
+            self._use_paged_kernel = self.engine_config.paged_kernel
+            # The gather copies every entry's whole table width, so the
+            # table is trimmed to a ladder of widths, one compiled
+            # program each. The kernel reads a row's live blocks
+            # whatever the table's width: there the table keeps its full
+            # width, and the ladder's programs are never built (each
+            # would lower the Mosaic kernel again: 0.3 s a shape of
+            # set-up on a v5e host, PERF.md §6 PR 27).
+            self._table_ladder = not (
+                self.pool.k_scale is None
+                and reads_pool_in_place(config, self._use_paged_kernel))
             # An expert model's fused step reports its routing behind the
             # step's tokens (``_paged_fused_step``); counters whether or
             # not span tracing is on, like the step counters below.
@@ -878,6 +890,15 @@ class RolloutEngine:
         self._tokens_total = reg.counter(
             "senweaver_engine_tokens_total",
             "Tokens emitted by the rollout engine.")
+        self._kv_blocks_total = reg.counter(
+            "senweaver_engine_kv_blocks_read_total",
+            "KV pool blocks the fused steps' attention had to cover: for "
+            "each run of one row's entries in a step, the blocks up to "
+            "its last position. The lower bound of what is read: the "
+            "kernel reads a long run's blocks once a tile of queries.")
+        # the last assembled plan's share of that counter, for the
+        # engine.step span's attr kv_blocks
+        self._kv_blocks_step = 0                # guarded-by: _lock
         # Is span tracing on? Asked once at the top of a step and true
         # only inside it: the step's span sites and the request.* spans
         # of phases that end in the step read it instead of asking again.
@@ -2834,18 +2855,22 @@ class RolloutEngine:
     def _tables_device(self) -> jnp.ndarray:
         # guarded-by: caller
         """Dense (num_slots, mb) int32 block-table array for the fused
-        step, trimmed to the widest resident table and bucketed to a
-        power of two (a bounded compile ladder, like _chunk_sizes, so
-        at most log2(blocks_per_row) shapes compile). Attention cost
-        then tracks the LONGEST live sequence instead of always paying
-        the full blocks_per_row width; unused entries hold 0 and are
+        step. On the gather path it is trimmed to the widest resident
+        table and bucketed to a power of two (a bounded compile ladder,
+        like _chunk_sizes, so at most log2(blocks_per_row) shapes
+        compile): attention cost then tracks the LONGEST live sequence
+        instead of always paying the full blocks_per_row width. Where
+        the kernel reads the pool in place the width costs nothing and
+        stays blocks_per_row: one shape. Unused entries hold 0 and are
         never read past each row's fill level (the validity mask in
-        the gather path, the block skip in the kernel)."""
-        widest = max((len(t) for t in self._tables), default=0)
-        mb = 1
-        while mb < widest:
-            mb *= 2
-        mb = min(self._blocks_per_row, mb)
+        the gather path, the blocks an item covers in the kernel)."""
+        mb = self._blocks_per_row
+        if self._table_ladder:
+            widest = max((len(t) for t in self._tables), default=0)
+            mb = 1
+            while mb < widest:
+                mb *= 2
+            mb = min(self._blocks_per_row, mb)
         arr = np.zeros((self.num_slots, mb), np.int32)
         for s, tbl in enumerate(self._tables):
             if tbl:
@@ -3022,6 +3047,7 @@ class RolloutEngine:
         spec_rows = []             # (entry_idx, row, req, proposals, start)
         job_rows = []              # (row, req, job, n, last_idx, wrote)
         committed: set = set()
+        kv_blocks = 0              # blocks the step's attention covers
         for row in range(self.num_slots):
             req = self._slot_req[row]
             if req is None or req.paused or req.rid in self._prefill_jobs:
@@ -3046,12 +3072,14 @@ class RolloutEngine:
                     pos_l.append(fp)
                     wb_l.append(wb)
                     wo_l.append(wo)
+                kv_blocks += (p + len(feed) - 1) // bs + 1
                 committed.add(row)
                 continue
             try:
                 wb = self._ensure_block(row, p, committed)
             except _RowPreempted:
                 continue
+            kv_blocks += p // bs + 1
             decode_rows.append((len(toks_l), row, req))
             toks_l.append(self._cur_tok_host[row])
             rows_l.append(row)
@@ -3089,6 +3117,7 @@ class RolloutEngine:
                 wo_l.append(wo)
             wrote = 0 if job.drop_writes else n
             job_rows.append((row, req, job, n, base + n - 1, wrote))
+            kv_blocks += (job.pos + n - 1) // bs + 1
             committed.add(row)
             budget -= n
         if not toks_l:
@@ -3109,6 +3138,9 @@ class RolloutEngine:
         else:
             t = self.num_slots if not job_rows else self._step_tokens
         n_real = len(toks_l)
+        # the tail padding is one more run, of row 0's first block
+        self._kv_blocks_step = kv_blocks + (n_real < t)
+        self._kv_blocks_total.inc(self._kv_blocks_step)
         while len(toks_l) < t:
             toks_l.append(0)
             rows_l.append(0)
@@ -3180,6 +3212,7 @@ class RolloutEngine:
                 st.set_attr("decode_rows", len(decode_rows))
                 st.set_attr("prefill_tokens", prefill)
                 st.set_attr("table_width", int(tables.shape[1]))
+                st.set_attr("kv_blocks", self._kv_blocks_step)
                 st.set_attr("queue_depth", len(self._queue))
                 st.set_attr("rows_active", len(decode_rows)
                             + len(spec_rows) + len(job_rows))

@@ -132,6 +132,28 @@ def test_step_attrs_add_up_to_the_engines_own_counts(model):
     assert all(s.attrs["bytes"] > 0 for s in spans["engine.fetch"])
 
 
+def test_kv_blocks_counts_each_runs_blocks_and_feeds_the_counter(model):
+    """``kv_blocks``: for each run of one row's entries in a step, the
+    blocks up to its last position; the always-on counter sums it."""
+    eng = make_engine(model)
+    obs.enable()
+    rid = eng.submit(list(range(1, 23)), max_new_tokens=4)   # 22 tokens
+    while eng.has_work:
+        eng.step()
+    a = [s.attrs for s in by_name(obs.get_tracer().spans())["engine.step"]]
+    # block_size 4, step_tokens 16: a chunk of 16 (4 blocks), then 6 (up
+    # to position 21: 6 blocks), then decode rows at positions 22, 23, 24
+    # (6, 6, 7 blocks); each step has tail padding, one block more
+    assert [x["kv_blocks"] for x in a] == [4, 7, 7, 7, 8]
+    assert [x["entries"] for x in a] == [16, 16, 4, 4, 4]
+    for x in a:
+        assert x["kv_blocks"] <= x["entries"] * x["table_width"]
+    assert (f"senweaver_engine_kv_blocks_read_total "
+            f"{sum(x['kv_blocks'] for x in a)}"
+            in obs.get_registry().render())
+    assert eng.is_done(rid)
+
+
 def test_a_requests_three_phases_share_an_id_and_abut(model):
     eng = make_engine(model)
     obs.enable()

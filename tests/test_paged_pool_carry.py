@@ -10,7 +10,11 @@ leaves in the layer scan's carry. Two things hold it there:
   variant, with a share of the entries on the drop sentinel;
 - the compiled step keeps no second pool: its temporaries stay under a
   quarter of the pool's bytes (the xs/ys form of the scan needed more
-  than a whole pool).
+  than a whole pool);
+- compiled for a v5e (described, not attached) with the Pallas kernel
+  reading the pool, the step at the benchmark cells' shapes holds no
+  temporary of a pool's or a layer slice's size, hands the kernel the
+  stacked leaves themselves, and copies no array of a leaf's shape.
 """
 
 import dataclasses
@@ -265,3 +269,138 @@ def test_compiled_step_keeps_no_second_pool(program):
             jax.ShapeDtypeStruct((rows,), jnp.bool_), i32(rows, width),
             pool, 3, False)
     assert _temp_bytes(lowered) < _pool_bytes(pool) / 4
+
+
+# ---- the kernel path, compiled for the chip it runs on -------------------
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """One device of a described v5e 2x2: the TPU compiler with no chip.
+    Made inside the fixture, so importing this file loads no libtpu."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("model,layers,entries", [
+    ("qwen2.5-coder-1.5b", None, 48), ("qwen2.5-coder-1.5b", None, 192),
+    # other head shapes, cut to four layers (a scan: the program is the
+    # same) so the weights fit the described chip: 32/8 and 32/32 x 128
+    ("qwen3-8b", 4, 48), ("deepseek-coder-6.7b", 4, 192)])
+def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
+                                                     entries, monkeypatch):
+    """``_paged_fused_step`` at a preset's widths, 48 rows of 64 blocks,
+    bf16, with ``paged_attention_rows`` compiled by Mosaic (the test
+    says "on a TPU": the backend here is the CPU). The qwen cases are the
+    benchmark cells' shapes."""
+    from senweaver_ide_tpu.ops import paged_attention
+    monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
+    c = get_config(model)
+    if layers:
+        c = dataclasses.replace(c, num_layers=layers)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: tf.init_params(c, jax.random.PRNGKey(0))))
+    pool = on_chip(jax.eval_shape(
+        lambda: init_paged_pool(c, 3328 if layers is None else 832, 16)))
+    rows, width = 48, 64
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_v5e)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = eng._paged_fused_step.lower(
+            params, c, i32(entries), i32(rows, width), i32(entries),
+            i32(entries), i32(entries), i32(entries), pool,
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_v5e),
+            SampleParams(temperature=1.0), None).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = compiled.as_text()
+    leaf = ",".join(map(str, pool.k.shape))
+    layer_bytes = pool.k.size * pool.k.dtype.itemsize // pool.k.shape[0]
+    # the sampler's (entries, vocabulary) f32 logits are the step's
+    # largest temporary; a layer's slice of one leaf is 27 MB at qwen's
+    # widths
+    logits_bytes = entries * c.vocab_size * 4
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < logits_bytes + layer_bytes / 2)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "paged_attention_rows" in line]
+    assert calls, "the kernel is not in the compiled step"
+    for line in calls:
+        # the whole stacked leaf is the kernel's operand, twice (k, v)
+        assert line.count(f"bf16[{leaf}]") == 2, line
+    for line in text.splitlines():
+        if f"bf16[{leaf}]" in line.split("=")[0] or \
+                f"= bf16[{leaf}]" in line:
+            assert " copy(" not in line and "copy-start" not in line, line
+    # nothing of the gather's size is left: (entries x width, 16, Hkv, Dh)
+    assert (f"bf16[{entries * width},16,{c.num_kv_heads},{c.head_dim}]"
+            not in text)
+
+
+def _preset_head_shapes():
+    """Every (Hq, Hkv, Dh) a full-size dense preset has, once, under the
+    first preset's name."""
+    from senweaver_ide_tpu.models.config import PRESETS
+    shapes = {}
+    for name in sorted(PRESETS):
+        c = PRESETS[name]()
+        if not c.mla and "test" not in name:
+            shapes.setdefault((c.num_heads, c.num_kv_heads, c.head_dim),
+                              name)
+    return [pytest.param(name, id=f"{name}-{hq}-{hkv}x{d}")
+            for (hq, hkv, d), name in shapes.items()]
+
+
+@pytest.mark.parametrize("model", _preset_head_shapes())
+def test_every_preset_takes_a_path_that_compiles_for_v5e(one_v5e, model,
+                                                         monkeypatch):
+    """On a TPU ``forward_paged`` sends a dense unquantized pool to the
+    kernel only where Mosaic compiles it: at each preset's head shape the
+    kernel alone, over a table as wide as the engine hands it (64 blocks:
+    the kernel's table keeps ``blocks_per_row``), at a narrow and a wide
+    step's entries, with the pool's leaves not copied on the way in. A
+    ``head_dim`` of 64 keeps the gather."""
+    from senweaver_ide_tpu.ops import paged_attention as pa
+    monkeypatch.setattr(pa, "on_tpu", lambda: True)
+    c = get_config(model)
+    hq, hkv, d = c.num_heads, c.num_kv_heads, c.head_dim
+    if d % 128:
+        assert not tf.reads_pool_in_place(c, None)
+        return
+    assert tf.reads_pool_in_place(c, None)
+    bs, width, rows, nb, layers = 16, 64, 48, 832, 2
+    struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                       sharding=one_v5e)
+
+    def attend(q, k_leaf, v_leaf, tables, seq_row, positions):
+        plan = pa.plan_rows(seq_row, positions, block_size=bs,
+                            table_width=width, q_tile=pa.query_tile(hq))
+        return pa.paged_attention_rows(q, k_leaf, v_leaf, jnp.int32(1),
+                                       tables, positions, plan)
+
+    leaf = struct((layers, nb, bs, hkv, d), jnp.bfloat16)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        for entries in (48, 192):
+            text = jax.jit(attend).lower(
+                struct((entries, hq, d), jnp.bfloat16), leaf, leaf,
+                struct((rows, width), jnp.int32),
+                struct((entries,), jnp.int32),
+                struct((entries,), jnp.int32)).compile().as_text()
+            assert "paged_attention_rows" in text
+            shape = ",".join(map(str, leaf.shape))
+            assert not [line for line in text.splitlines()
+                        if f"= bf16[{shape}]" in line and " copy(" in line]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
