@@ -7,7 +7,7 @@ This eval executes it end to end at the ``qwen2.5-coder-1.5b`` config
 trajectories → ``train_step`` (the same jit step the tiny evals and the
 chip MFU bench use) → a SECOND step so the loss can move. Wall-time per
 phase, peak RSS, and losses are recorded; throughput/MFU on silicon
-stays the chip queue's job (bench.py ``_measure_train``) — this artifact
+stays the chip's job (a training cell of ``benchmark/``) — this artifact
 proves the path is executed code at the real shape, with real memory.
 
 Modes:

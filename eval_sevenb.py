@@ -21,8 +21,7 @@ This eval executes the plan as far as a CPU host allows:
    6.7B tree resolves a PartitionSpec (parallel/sharding.py) and the
    fsdp=8 per-device byte split fits a v5e chip.
 
-Device time for this shape: not measured (bench.py's 6.7B rows need a
-chip).
+Device time for this shape: not measured (no benchmark cell has it).
 
     python eval_sevenb.py [--skip-decode]
 

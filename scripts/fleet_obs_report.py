@@ -1,40 +1,14 @@
 #!/usr/bin/env python3
-"""Fleet observability report: federation summary or a hermetic
-selftest of the metrics-federation + alerting + incident plane
-(ISSUE 16 acceptance).
+"""Fleet observability report: summarize an incident JSONL
+(``IncidentCorrelator.export_jsonl``) — alerts, top causes, peers.
 
 Usage::
 
-    python scripts/fleet_obs_report.py --selftest
     python scripts/fleet_obs_report.py incidents.jsonl
 
-Two modes:
-
-- **JSONL**: scans an incident JSONL (``IncidentCorrelator.
-  export_jsonl``) and summarizes alerts, top causes, and peers.
-- **--selftest**: hermetic CPU proof of the whole plane — a
-  multi-process-shaped loopback fleet (per-peer registries + event
-  journals behind real rpc handlers) under ``NetworkFaultPlan`` /
-  ``MemoryPressurePlan`` chaos, on a fake clock. Three scenarios, each
-  with a KNOWN injected cause the correlator must rank:
-
-  1. *Partition*: one peer is partitioned mid-scrape. Its series must
-     be marked STALE with a gap (never interpolated), the
-     ``fleet_peer_stale`` alert must fire exactly once (no flap across
-     the heal), and the incident's top cause must be
-     ``peer_unreachable`` on that peer.
-  2. *KV squat*: chaos squats real blocks on the serving peer's pool
-     under an over-capacity workload. Fleet KV pressure sustains above
-     the watermark, ``kv_pressure_high`` fires once (hysteresis across
-     the release boundary — no flap), and the top cause is in the
-     ``kv_*`` reaction family, SYNTHESIZED from federated counter
-     movement (the chaos counters themselves are excluded).
-  3. *Eager publish under load*: an eager weight publish lands during
-     interactive traffic; TTFT blows the SLO, the multi-window burn
-     alert fires, and the top cause names the publish event.
-
-  The injected cause must be top-ranked in >= 2 of 3 scenarios (it is
-  asserted per scenario below at exactly that bar).
+The plane itself (metrics federation, alerting, incident correlation
+under partition / KV-squat / eager-publish chaos) is held by
+``tests/test_fleet_obs.py``.
 """
 
 from __future__ import annotations
@@ -44,7 +18,7 @@ import collections
 import json
 import os
 import sys
-from typing import Any, Dict, List
+from typing import Any, Dict
 
 # Allow running from a source checkout without installation.
 sys.path.insert(0, os.path.dirname(os.path.dirname(
@@ -74,359 +48,10 @@ def summarize_jsonl(path: str) -> Dict[str, Any]:
             "worst_peers": dict(peers)}
 
 
-class _FakeClock:
-    def __init__(self, t: float = 0.0):
-        self.t = float(t)
-
-    def __call__(self) -> float:
-        return self.t
-
-    def advance(self, dt: float) -> float:
-        self.t += dt
-        return self.t
-
-
-def _fresh_plane(clock):
-    """(store, journal) on a fresh global registry + fake-clock
-    journal — the serve-side half every scenario starts from."""
-    from senweaver_ide_tpu import obs
-    obs._reset_for_tests()
-    journal = obs.EventJournal(clock=clock)
-    obs.set_event_journal(journal)
-    store = obs.FleetMetricsStore(clock=clock)
-    return store, journal
-
-
-def _scrape_handler(peer, registry, journal, clock):
-    """A real rpc handler whose only job is the ``scrape`` method —
-    the shape of a peer process that serves nothing else."""
-    from senweaver_ide_tpu.obs import MetricsScrapeMixin
-    from senweaver_ide_tpu.serve.remote_server import RpcHandlerBase
-
-    class ObsScrapeHandler(MetricsScrapeMixin, RpcHandlerBase):
-        mutating_methods = frozenset({"scrape"})
-        span_service = "obs"
-
-    h = ObsScrapeHandler()
-    h.scrape_peer = peer
-    h.scrape_registry = registry
-    h.scrape_journal = journal
-    h.scrape_clock = clock
-    return h
-
-
-# -- scenario 1: partition mid-scrape ----------------------------------------
-def scenario_partition() -> Dict[str, Any]:
-    from senweaver_ide_tpu import obs
-    from senweaver_ide_tpu.resilience import NetworkFaultPlan
-    from senweaver_ide_tpu.serve.rpc import LoopbackTransport
-
-    clock = _FakeClock()
-    store, journal = _fresh_plane(clock)
-    reg = obs.get_registry()
-
-    # The learner peer: its OWN registry + journal, a genuinely
-    # separate vantage point behind a real handler.
-    learner_reg = obs.MetricsRegistry()
-    learner_journal = obs.EventJournal(clock=clock, registry=learner_reg)
-    idle = learner_reg.gauge("senweaver_learner_idle_fraction", "")
-    steps = learner_reg.counter("senweaver_learner_steps_total", "")
-
-    netplan = NetworkFaultPlan()
-    peers = {
-        "serve-1": LoopbackTransport(
-            _scrape_handler("serve-1", reg, journal, clock),
-            target="serve-1", fault_plan=netplan),
-        "learner-1": LoopbackTransport(
-            _scrape_handler("learner-1", learner_reg, learner_journal,
-                            clock),
-            target="learner-1", fault_plan=netplan),
-    }
-    fed = obs.MetricsFederator(store, peers, clock=clock,
-                               journal=journal, interval_s=0.0)
-    corr = obs.IncidentCorrelator(store, journal=journal, clock=clock)
-    mgr = obs.AlertManager(store, obs.default_alert_rules(),
-                           clock=clock, journal=journal, correlator=corr)
-
-    # Healthy scrapes: the learner ticks, the store follows.
-    for i in range(4):
-        idle.set(0.2 + 0.01 * i)
-        steps.inc()
-        fed.scrape_once(clock.advance(1.0))
-        mgr.evaluate(clock.t)
-    assert not mgr.active(), "no alert should fire on a healthy fleet"
-    pre = store.series("senweaver_learner_steps_total", peer="learner-1")
-    assert len(pre) == 4, f"healthy rings should grow, got {len(pre)}"
-
-    # Partition the learner mid-scrape. Its instruments KEEP MOVING —
-    # the store must not see any of it.
-    netplan.partition("learner-1")
-    for _ in range(5):
-        idle.set(0.4)            # unobservable movement behind the wall
-        steps.inc()
-        fed.scrape_once(clock.advance(1.0))
-        mgr.evaluate(clock.t)
-    during = store.series("senweaver_learner_steps_total",
-                          peer="learner-1")
-    assert len(during) == len(pre), \
-        "a partitioned peer's ring grew — points were fabricated"
-    assert store.is_stale("learner-1"), "partitioned peer not stale"
-    assert during[-1] == pre[-1], "a stale series was rewritten"
-    assert mgr.active() == ["fleet_peer_stale"], \
-        f"expected fleet_peer_stale, got {mgr.active()}"
-    assert mgr.transitions("fleet_peer_stale") == 1, "alert flapped"
-
-    incident = corr.incidents(1)[0]
-    top = incident.top_cause
-    assert top is not None and top["cause"] == "peer_unreachable", \
-        f"top cause should be peer_unreachable, got {top}"
-    assert top["event"].get("peer") == "learner-1", \
-        f"cause should name the partitioned peer, got {top}"
-
-    # Heal: the peer recovers, series resume (full resync), the alert
-    # clears exactly once after its hold — 2 transitions total.
-    netplan.heal("learner-1")
-    for _ in range(8):
-        steps.inc()
-        fed.scrape_once(clock.advance(1.0))
-        mgr.evaluate(clock.t)
-    after = store.series("senweaver_learner_steps_total",
-                         peer="learner-1")
-    assert len(after) > len(pre), "healed peer's series never resumed"
-    assert not store.is_stale("learner-1"), "healed peer still stale"
-    assert not mgr.active(), "alert failed to clear after heal + hold"
-    assert mgr.transitions("fleet_peer_stale") == 2, \
-        "alert flapped across the heal boundary"
-    kinds = [e["kind"] for e in journal.recent(64)]
-    assert "peer_unreachable" in kinds and "peer_recovered" in kinds
-
-    return {"rings_frozen_under_partition": True,
-            "stale_not_fabricated": True,
-            "alert_transitions": mgr.transitions("fleet_peer_stale"),
-            "top_cause": top["cause"],
-            "cause_ok": True}
-
-
-# -- scenario 2: KV squat on the serving peer --------------------------------
-def scenario_kv_squat() -> Dict[str, Any]:
-    import jax
-
-    from senweaver_ide_tpu import obs
-    from senweaver_ide_tpu.models import init_params, tiny_test
-    from senweaver_ide_tpu.resilience import (MemoryPressureFault,
-                                              MemoryPressurePlan)
-    from senweaver_ide_tpu.rollout import EngineConfig, RolloutEngine
-    from senweaver_ide_tpu.rollout.sampler import SampleParams
-    from senweaver_ide_tpu.serve import ServingFleet
-    from senweaver_ide_tpu.serve.admission import AdmissionConfig
-    from senweaver_ide_tpu.serve.rpc import LoopbackTransport
-
-    clock = _FakeClock()
-    store, journal = _fresh_plane(clock)
-    reg = obs.get_registry()
-
-    greedy = SampleParams(temperature=0.0, top_k=0, top_p=1.0)
-    config = tiny_test()
-    params = init_params(config, jax.random.PRNGKey(0))
-    eng = RolloutEngine(
-        params, config, num_slots=2, max_len=64, sample=greedy,
-        engine_config=EngineConfig(kv_layout="paged", block_size=4,
-                                   num_blocks=10))
-    # The squat fires on the FIRST engine step, while only the 1-block
-    # warmup prompt occupies the pool — so it really grabs 9 of 10
-    # blocks (``on_step`` clamps to free_blocks; squatting later, after
-    # requests place, would only get the leftovers and the pressure
-    # floor would dip below the watermark between preemptions). With
-    # 9 squatted the floor is 0.9 > 0.85 for the whole hold, so the
-    # sustain window is genuinely continuous. No release_step: the
-    # schedule is indexed on engine steps, which stall when nothing is
-    # placeable — the mitigation below is an explicit release_all().
-    plan = MemoryPressurePlan([MemoryPressureFault(at_step=0,
-                                                   hold_blocks=9)])
-    fleet = ServingFleet([plan.wrap_engine(eng)], clock=clock,
-                         peer_id="serve-1",
-                         admission=AdmissionConfig(kv_pressure_high=0.97,
-                                                   kv_pressure_low=0.9))
-    fed = obs.MetricsFederator(
-        store,
-        {"serve-1": LoopbackTransport(
-            _scrape_handler("serve-1", reg, journal, clock),
-            target="serve-1")},
-        clock=clock, journal=journal, interval_s=0.0)
-    corr = obs.IncidentCorrelator(store, clock=clock)
-    mgr = obs.AlertManager(store, obs.default_alert_rules(),
-                           clock=clock, journal=journal, correlator=corr)
-    fleet.attach_federation(fed, alert_manager=mgr)
-
-    # Warmup: one tiny request placed BEFORE the squat (1 block), so
-    # the first engine step both fires the fault and leaves a live
-    # decode fighting the squeezed pool (exhaustion → preemptions →
-    # the counter movement the correlator synthesizes causes from).
-    warmup = fleet.submit([5, 9, 2], max_new_tokens=6)
-    clock.advance(0.5)
-    fleet.step()
-    hot = [5, 9, 2, 7, 4, 4, 8, 1]
-    tickets = [warmup] + [fleet.submit(hot + [i + 1, 3],
-                                       max_new_tokens=8)
-                          for i in range(4)]
-    # Phase A: hold the squeeze for a fixed window — pressure sits at
-    # the 0.9 floor, the sustain clock runs uninterrupted, the fast
-    # alert must fire.
-    for _ in range(30):
-        clock.advance(0.5)
-        fleet.step()           # pumps federation + alerts too
-
-    assert "kv_pressure_high" in [
-        r for r in mgr.summary()
-        if mgr.transitions(r) >= 1], "kv_pressure_high never fired"
-    peak = max((v for (_t, v) in store.series(
-        "senweaver_kv_pressure", peer="serve-1")), default=0.0)
-    assert peak >= 0.85, f"squeeze never crossed the watermark ({peak})"
-
-    incidents = [i for i in corr.incidents(8)
-                 if i.alert == "kv_pressure_high"]
-    assert incidents, "no incident opened for kv_pressure_high"
-    top = incidents[-1].top_cause   # earliest firing = the onset
-    kv_family = {"kv_evictions", "kv_swaps_out", "kv_exhaustion",
-                 "kv_preemption_storm", "admission_sheds"}
-    cause_ok = top is not None and top["cause"] in kv_family
-    assert cause_ok, f"top cause not in the kv reaction family: {top}"
-    assert top["event"].get("synthesized"), \
-        "kv cause should be synthesized from counter movement"
-    assert not str(top["event"].get("metric", "")).startswith(
-        "senweaver_chaos_"), "correlator read the chaos plan's counters"
-
-    # Mitigation boundary: release the squat, drain the backlog, and
-    # the alert must clear once (after hold) and never re-fire — no
-    # flap across the recovery.
-    plan.release_all(eng)
-    steps = 0
-    while fleet.pending() and steps < 300:
-        clock.advance(0.5)
-        fleet.step()
-        steps += 1
-    assert not fleet.pending(), f"fleet did not drain in {steps} steps"
-    for _ in range(14):
-        clock.advance(5.0)
-        fleet.step()
-    assert "kv_pressure_high" not in mgr.active(), \
-        "alert failed to clear after the squeeze released"
-    assert mgr.transitions("kv_pressure_high") == 2, \
-        "kv_pressure_high flapped across the mitigation boundary"
-
-    assert all(fleet.outcome(t) is not None for t in tickets), \
-        "a request was lost (no outcome under the squeeze)"
-    return {"peak_kv_pressure": round(peak, 3),
-            "alert_transitions": mgr.transitions("kv_pressure_high"),
-            "top_cause": top["cause"] if top else None,
-            "synthesized": True,
-            "cause_ok": cause_ok}
-
-
-# -- scenario 3: eager publish during interactive load -----------------------
-def scenario_eager_publish() -> Dict[str, Any]:
-    import jax
-
-    from senweaver_ide_tpu import obs
-    from senweaver_ide_tpu.models import init_params, tiny_test
-    from senweaver_ide_tpu.rollout import RolloutEngine
-    from senweaver_ide_tpu.rollout.sampler import SampleParams
-    from senweaver_ide_tpu.serve import ServingFleet
-    from senweaver_ide_tpu.serve.rpc import LoopbackTransport
-
-    clock = _FakeClock()
-    store, journal = _fresh_plane(clock)
-    reg = obs.get_registry()
-
-    greedy = SampleParams(temperature=0.0, top_k=0, top_p=1.0)
-    config = tiny_test()
-    params = init_params(config, jax.random.PRNGKey(0))
-    eng = RolloutEngine(params, config, num_slots=4, max_len=64,
-                        sample=greedy)
-    fleet = ServingFleet([eng], clock=clock, peer_id="serve-1")
-    fed = obs.MetricsFederator(
-        store,
-        {"serve-1": LoopbackTransport(
-            _scrape_handler("serve-1", reg, journal, clock),
-            target="serve-1")},
-        clock=clock, journal=journal, interval_s=0.0)
-    corr = obs.IncidentCorrelator(store, clock=clock)
-    mgr = obs.AlertManager(store, obs.default_alert_rules(),
-                           clock=clock, journal=journal, correlator=corr)
-    fleet.attach_federation(fed, alert_manager=mgr)
-
-    tickets = [fleet.submit([5, 9, i + 2], max_new_tokens=6,
-                            priority="interactive") for i in range(4)]
-    # The injected cause: an EAGER publish lands right as the batch is
-    # admitted, and the fake clock charges its stall to TTFT.
-    params2 = init_params(config, jax.random.PRNGKey(1))
-    fleet.begin_publish(params2, eager=True)
-    clock.advance(1.2)          # > interactive ttft_s target (0.5)
-    steps = 0
-    while fleet.pending() and steps < 300:
-        clock.advance(0.01)
-        fleet.step()
-        steps += 1
-    assert not fleet.pending(), "fleet did not drain"
-    clock.advance(0.5)
-    fleet.step()                # one more pump: scrape + evaluate
-
-    assert mgr.transitions("slo_burn_fast") >= 1, \
-        "fast-window burn alert never fired"
-    incidents = [i for i in corr.incidents(8)
-                 if i.alert == "slo_burn_fast"]
-    assert incidents, "no incident opened for slo_burn_fast"
-    top = incidents[0].top_cause
-    cause_ok = top is not None and top["cause"] in (
-        "publish_begin", "publish_end")
-    assert cause_ok, f"top cause should name the publish, got {top}"
-    assert top["event"].get("version") is not None, \
-        "publish cause should carry the version"
-    burn = mgr.state("slo_burn_fast").value
-    out = fleet.run()
-    assert all(t in out for t in tickets), "a request was lost"
-    return {"burn_ratio_at_fire": round(burn, 2),
-            "top_cause": top["cause"] if top else None,
-            "incident_summary": incidents[0].summary,
-            "cause_ok": cause_ok}
-
-
-def selftest() -> Dict[str, Any]:
-    """Hermetic proof of the fleet observability plane; raises on any
-    violated invariant (non-zero exit for CI)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    from senweaver_ide_tpu import obs
-
-    partition = scenario_partition()
-    kv_squat = scenario_kv_squat()
-    eager = scenario_eager_publish()
-
-    causes_ok = sum(int(s.get("cause_ok", False))
-                    for s in (partition, kv_squat, eager))
-    # Acceptance bar: injected cause top-ranked in >= 2 of 3 (each
-    # scenario above asserts individually, so in practice 3 of 3).
-    assert causes_ok >= 2, f"only {causes_ok}/3 causes top-ranked"
-
-    obs._reset_for_tests()
-    return {"mode": "selftest",
-            "partition": partition,
-            "kv_squat": kv_squat,
-            "eager_publish": eager,
-            "causes_top_ranked": f"{causes_ok}/3",
-            "ok": True}
-
-
 def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("path", nargs="?", help="incident JSONL to scan")
-    parser.add_argument("--selftest", action="store_true",
-                        help="run the hermetic fleet-obs selftest")
+    parser.add_argument("path", help="incident JSONL to scan")
     args = parser.parse_args()
-    if args.selftest:
-        print(json.dumps(selftest(), indent=2))
-        return
-    if not args.path:
-        parser.error("need an incident JSONL path (or --selftest)")
     print(json.dumps(summarize_jsonl(args.path), indent=2))
 
 

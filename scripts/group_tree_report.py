@@ -1,33 +1,17 @@
 #!/usr/bin/env python3
-"""Group-shared rollout / tree-branching report: JSONL summary or a
-hermetic selftest of the shared-KV rollout plane (ISSUE 18
-acceptance).
+"""Group-shared rollout / tree-branching report: summarize a metrics
+JSONL.
 
 Usage::
 
     python scripts/group_tree_report.py metrics.jsonl
-    python scripts/group_tree_report.py --selftest
 
 Companion to ``scripts/kv_pressure_report.py`` (memory plane) — this
 one answers "what did GROUP SHARING do?": prefills paid vs avoided,
-forks and COW splits, branch events, and degrade counts.
-
-Two modes:
-
-- **JSONL**: scans a metrics JSONL for engine group/fork counter
-  fields and emits the last observed values.
-- **--selftest**: hermetic CPU proof, zero infrastructure (CI runs it
-  after the group-rollout test job):
-
-  1. *One prefill per group*: a G=8 group decodes bitwise-identical
-     to 8 independent submits while the engine's prefill counter
-     reads exactly 1, and the pool drains leak-free.
-  2. *Tree exactness*: a BranchPolicy-driven rollout tree (sampled +
-     forced branches, depth 2) where every leaf's suffix equals an
-     independent decode of its full stream.
-  3. *Degrade honesty*: donor death before spine capture falls back
-     to unshared prefills — same outputs, ``group_degrades`` counted,
-     still leak-free.
+forks and COW splits, branch events, and degrade counts, as the last
+values the engine's group/fork counter fields held. The invariants
+(one prefill a group, leaf exactness, honest degrades) are held by
+``tests/test_group_tree.py``.
 """
 
 from __future__ import annotations
@@ -66,128 +50,10 @@ def summarize_jsonl(path: str) -> Dict[str, Any]:
             **{f: last.get(f) for f in GROUP_FIELDS}}
 
 
-def selftest() -> Dict[str, Any]:
-    """Hermetic proof of the shared-rollout invariants; raises on any
-    violation (non-zero exit for CI)."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    from senweaver_ide_tpu import obs
-    from senweaver_ide_tpu.models import init_params, tiny_test
-    from senweaver_ide_tpu.rollout import (BranchPolicy, EngineConfig,
-                                           GroupRollout, RolloutEngine)
-    from senweaver_ide_tpu.rollout.sampler import SampleParams
-
-    greedy = SampleParams(temperature=0.0, top_k=0, top_p=1.0)
-    config = tiny_test()
-    params = init_params(config, jax.random.PRNGKey(0))
-    prompt = [5, 9, 2, 7, 1, 3]
-
-    def engine(num_slots=8):
-        return RolloutEngine(params, config, num_slots=num_slots,
-                             max_len=96, sample=greedy,
-                             engine_config=EngineConfig(
-                                 kv_layout="paged", block_size=4))
-
-    def independent(stream, max_new):
-        eng = engine(num_slots=2)
-        rid = eng.submit(list(stream), max_new_tokens=max_new)
-        return eng.run()[rid]
-
-    # -- 1. one prefill per group, bitwise-exact ---------------------------
-    obs._reset_for_tests()
-    ref = independent(prompt, 12)
-    eng = engine()
-    rids = eng.submit_group(prompt, 8, max_new_tokens=12)
-    out = eng.run()
-    for r in rids:
-        assert out[r] == ref, "group member diverged from the reference"
-    st = eng.stats()
-    assert st["prefills"] == 1, \
-        f"G=8 group paid {st['prefills']} prefills, wanted exactly 1"
-    assert st["group_prefills"] == 1 and st["group_forks"] == 7
-    assert st["group_degrades"] == 0
-    eng._alloc.check_leaks()
-
-    group = {
-        "group_size": 8,
-        "prefills": st["prefills"],
-        "group_forks": st["group_forks"],
-        "prefill_tokens_avoided": st["group_prefill_tokens_avoided"],
-        "cow_copies": st["kv_cow_copies"],
-        "bitwise_exact": True,
-        "leaks_clean": True,
-    }
-
-    # -- 2. tree exactness at depth, sampled + forced ----------------------
-    obs._reset_for_tests()
-    eng = engine()
-    trigger = int(ref[2])
-    planner = GroupRollout(eng, policy=BranchPolicy(
-        max_leaves=6, max_depth=2, branch_width=2,
-        min_tokens_between=1, branch_tokens=(trigger,)))
-    gid = planner.submit_group(prompt, 2, max_new_tokens=12)
-    planner.run()
-    recs = planner.collect(gid)
-    assert len(recs) > 2, "branch policy never fired"
-    assert any(r["depth"] > 0 for r in recs)
-    for rec in recs:
-        leaf = planner._leaves[rec["rid"]]
-        stream = list(prompt) + list(leaf.inherited)
-        own = eng.result(rec["rid"])
-        assert own == independent(stream, len(own)), \
-            f"leaf rid={rec['rid']} depth={rec['depth']} diverged"
-    stats = planner.branch_stats()
-    eng._alloc.check_leaks()
-
-    tree = {
-        "leaves": stats["leaves"],
-        "branched_leaves": stats["branched_leaves"],
-        "max_depth": stats["max_depth"],
-        "branch_events": stats["branch_events"],
-        "every_leaf_exact": True,
-        "leaks_clean": True,
-    }
-
-    # -- 3. donor death degrades honestly ----------------------------------
-    obs._reset_for_tests()
-    eng = engine()
-    rids = eng.submit_group(prompt, 3, max_new_tokens=8)
-    assert eng.release_request(rids[0])      # donor dies pre-capture
-    out = eng.run()
-    ref8 = independent(prompt, 8)
-    for r in rids[1:]:
-        assert out[r] == ref8, "degraded follower diverged"
-    st3 = eng.stats()
-    assert st3["group_degrades"] == 1 and st3["group_prefills"] == 0
-    eng._alloc.check_leaks()
-
-    degrade = {
-        "group_degrades": st3["group_degrades"],
-        "followers_exact": True,
-        "leaks_clean": True,
-    }
-
-    return {
-        "mode": "selftest",
-        "group_shared_prefill": group,
-        "tree_branching": tree,
-        "donor_death_degrade": degrade,
-        "ok": True,
-    }
-
-
 def main() -> None:
     parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("path", nargs="?", help="metrics JSONL to scan")
-    parser.add_argument("--selftest", action="store_true",
-                        help="run the hermetic group-rollout selftest")
+    parser.add_argument("path", help="metrics JSONL to scan")
     args = parser.parse_args()
-    if args.selftest:
-        print(json.dumps(selftest(), indent=2))
-        return
-    if not args.path:
-        parser.error("need a metrics JSONL path (or --selftest)")
     print(json.dumps(summarize_jsonl(args.path), indent=2))
 
 

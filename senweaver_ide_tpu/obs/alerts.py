@@ -18,7 +18,7 @@ Three rule kinds, all evaluated host-side against a
 Hysteresis is mandatory — the chaos plans flap inputs by design. A
 firing alert clears only when the value drops below ``clear_threshold``
 AND ``hold_s`` has elapsed since it fired; `transitions` counts
-fire/clear edges so the selftest can assert an alert fired exactly once
+fire/clear edges so that a test can assert an alert fired exactly once
 across a mitigation boundary.
 
 Each rule carries ``causes`` — (event kind, weight) priors handed to the

@@ -393,7 +393,7 @@ class AdapterPool:
     # introspection
 
     def note_gather_overhead(self, ratio: float) -> None:
-        """Bench/perf-gate hook: gathered-step time over base-only."""
+        """Measurement hook: gathered-step time over base-only."""
         self._m_overhead.set(float(ratio))
 
     def _refresh_gauges_locked(self) -> None:

@@ -7,8 +7,8 @@ SINGLE forward, and the standard rejection rule (Leviathan et al. 2023)
 keeps the longest valid prefix — so the target's cost per emitted token
 drops toward 1/k of a per-token loop while the output distribution is
 exactly the target's. On this repo's dispatch-bound serving path (each
-host→TPU step costs fixed overhead; see bench.py _measure_steps) the
-verify-k-at-once shape is also what amortizes dispatches.
+host→TPU step costs fixed overhead) the verify-k-at-once shape is also
+what amortizes dispatches.
 
 Greedy (temperature 0) acceptance is ``proposal == target argmax``,
 which makes the output IDENTICAL to vanilla greedy decoding of the

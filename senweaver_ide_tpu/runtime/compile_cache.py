@@ -1,7 +1,7 @@
 """Where the persistent XLA compile cache lives.
 
-Entry points (``chip_smoke.py``, ``bench.py``, ``__graft_entry__.py``,
-``examples/*``, ``eval_*.py``) call :func:`enable_compile_cache` before
+Entry points (``chip_smoke.py``, ``__graft_entry__.py``, ``examples/*``,
+``eval_*.py``) call :func:`enable_compile_cache` before
 their first compile. Never called at package import: tests and library
 users keep whatever JAX is configured with.
 """

@@ -14,7 +14,6 @@ The load-bearing invariants:
 """
 
 import json
-import os
 
 import jax
 import pytest
@@ -443,37 +442,3 @@ def test_record_round_publishes_advantage_gauges():
     assert reg.get(
         "senweaver_grpo_zero_advantage_group_fraction").value() == 0.25
     assert reg.get("senweaver_grpo_advantage_std").value() == 0.7
-
-
-# ---- bench failure paths: no replayed value, non-zero exit ----------------
-
-@pytest.fixture
-def bench_module(monkeypatch):
-    import bench
-    from senweaver_ide_tpu.runtime import compile_cache
-    # tests keep JAX's own cache settings
-    monkeypatch.setattr(compile_cache, "enable_compile_cache", lambda: "")
-    return bench
-
-
-def test_bench_without_accelerator_prints_nothing_and_fails(
-        bench_module, monkeypatch, capsys):
-    monkeypatch.delenv("BENCH_FORCE_CPU", raising=False)
-    with pytest.raises(SystemExit) as exc:     # tests run on CPU: no chip
-        bench_module.main()
-    assert exc.value.code not in (0, None)
-    assert "no accelerator" in str(exc.value.code)
-    assert capsys.readouterr().out.strip() == ""   # no line, cached or not
-
-
-def test_bench_measurement_failure_propagates(bench_module, monkeypatch,
-                                              capsys):
-    monkeypatch.setenv("BENCH_FORCE_CPU", "1")
-
-    def broken(*a, **kw):
-        raise RuntimeError("regression in decode")
-
-    monkeypatch.setattr(bench_module, "_measure", broken)
-    with pytest.raises(RuntimeError, match="regression in decode"):
-        bench_module.main()
-    assert capsys.readouterr().out.strip() == ""

@@ -453,3 +453,244 @@ def test_slo_exemplars_carry_peer_id():
     rec.finish_completed(1, tokens=1)  # feeds tracker.observe
     exemplars = tracker.exemplars()
     assert exemplars and exemplars[0]["peer_id"] == "serve-7"
+
+
+# ---- the whole plane under chaos: a loopback fleet (per-peer registries
+# and journals behind real rpc handlers) under NetworkFaultPlan /
+# MemoryPressurePlan chaos on a fake clock; each test has a KNOWN injected
+# cause that the correlator must rank first ----
+
+def _serving_plane(clock):
+    """(store, journal, registry): the global registry and a fake-clock
+    global journal, as a serving process has them."""
+    journal = EventJournal(clock=clock)
+    obs.set_event_journal(journal)
+    return FleetMetricsStore(clock=clock), journal, obs.get_registry()
+
+
+def _alerting(store, peers, clock, journal, *, correlator_journal=None):
+    fed = MetricsFederator(store, peers, clock=clock, journal=journal,
+                           interval_s=0.0)
+    corr = IncidentCorrelator(store, journal=correlator_journal,
+                              clock=clock)
+    mgr = AlertManager(store, obs.default_alert_rules(), clock=clock,
+                       journal=journal, correlator=corr)
+    return fed, corr, mgr
+
+
+def _tiny_engine(**kw):
+    import jax
+
+    from senweaver_ide_tpu.models import init_params, tiny_test
+    from senweaver_ide_tpu.rollout import RolloutEngine
+    from senweaver_ide_tpu.rollout.sampler import SampleParams
+
+    config = tiny_test()
+    params = init_params(config, jax.random.PRNGKey(0))
+    return RolloutEngine(
+        params, config, max_len=64,
+        sample=SampleParams(temperature=0.0, top_k=0, top_p=1.0), **kw)
+
+
+def test_injected_cause_top_ranked_partition():
+    """One peer is partitioned mid-scrape: its series go STALE with a
+    gap (never interpolated), ``fleet_peer_stale`` fires exactly once
+    and clears exactly once after the heal, and the incident's top
+    cause is ``peer_unreachable`` on that peer."""
+    from senweaver_ide_tpu.resilience import NetworkFaultPlan
+    from senweaver_ide_tpu.serve.rpc import LoopbackTransport
+
+    clock = FakeClock()
+    store, journal, reg = _serving_plane(clock)
+    # the learner peer: its OWN registry + journal behind a real handler
+    learner_reg = MetricsRegistry()
+    learner_journal = EventJournal(clock=clock, registry=learner_reg)
+    idle = learner_reg.gauge("senweaver_learner_idle_fraction", "")
+    steps = learner_reg.counter("senweaver_learner_steps_total", "")
+    netplan = NetworkFaultPlan()
+    peers = {
+        "serve-1": LoopbackTransport(
+            _rpc_handler(reg, journal, clock, "serve-1"),
+            target="serve-1", fault_plan=netplan),
+        "learner-1": LoopbackTransport(
+            _rpc_handler(learner_reg, learner_journal, clock,
+                         "learner-1"),
+            target="learner-1", fault_plan=netplan),
+    }
+    fed, corr, mgr = _alerting(store, peers, clock, journal,
+                               correlator_journal=journal)
+
+    def learner_steps():
+        return store.series("senweaver_learner_steps_total",
+                            peer="learner-1")
+
+    for i in range(4):
+        idle.set(0.2 + 0.01 * i)
+        steps.inc()
+        fed.scrape_once(clock.advance(1.0))
+        mgr.evaluate(clock.t)
+    assert not mgr.active()
+    pre = learner_steps()
+    assert len(pre) == 4
+
+    # Its instruments KEEP MOVING — the store must see none of it.
+    netplan.partition("learner-1")
+    for _ in range(5):
+        idle.set(0.4)
+        steps.inc()
+        fed.scrape_once(clock.advance(1.0))
+        mgr.evaluate(clock.t)
+    during = learner_steps()
+    assert len(during) == len(pre), "points were fabricated"
+    assert store.is_stale("learner-1")
+    assert during[-1] == pre[-1], "a stale series was rewritten"
+    assert mgr.active() == ["fleet_peer_stale"]
+    assert mgr.transitions("fleet_peer_stale") == 1
+
+    top = corr.incidents(1)[0].top_cause
+    assert top is not None and top["cause"] == "peer_unreachable", top
+    assert top["event"].get("peer") == "learner-1", top
+
+    netplan.heal("learner-1")
+    for _ in range(8):
+        steps.inc()
+        fed.scrape_once(clock.advance(1.0))
+        mgr.evaluate(clock.t)
+    assert len(learner_steps()) > len(pre), "series never resumed"
+    assert not store.is_stale("learner-1")
+    assert not mgr.active()
+    assert mgr.transitions("fleet_peer_stale") == 2, "alert flapped"
+    kinds = [e["kind"] for e in journal.recent(64)]
+    assert "peer_unreachable" in kinds and "peer_recovered" in kinds
+
+
+def test_injected_cause_top_ranked_kv_squat():
+    """Chaos squats real blocks on the serving peer's pool under an
+    over-capacity workload: ``kv_pressure_high`` fires once (hysteresis
+    across the release — no flap) and the top cause is in the ``kv_*``
+    reaction family, SYNTHESIZED from federated counter movement."""
+    from senweaver_ide_tpu.resilience import (MemoryPressureFault,
+                                              MemoryPressurePlan)
+    from senweaver_ide_tpu.rollout import EngineConfig
+    from senweaver_ide_tpu.serve import ServingFleet
+    from senweaver_ide_tpu.serve.admission import AdmissionConfig
+    from senweaver_ide_tpu.serve.rpc import LoopbackTransport
+
+    clock = FakeClock()
+    store, journal, reg = _serving_plane(clock)
+    eng = _tiny_engine(num_slots=2, engine_config=EngineConfig(
+        kv_layout="paged", block_size=4, num_blocks=10))
+    # The squat fires on the FIRST engine step, while only the 1-block
+    # warmup prompt occupies the pool, so it grabs 9 of 10 blocks
+    # (``on_step`` clamps to free_blocks): the floor is 0.9 > 0.85 for
+    # the whole hold and the sustain window is continuous. No
+    # release_step: the schedule is indexed on engine steps, which
+    # stall when nothing is placeable; the mitigation is release_all().
+    plan = MemoryPressurePlan([MemoryPressureFault(at_step=0,
+                                                   hold_blocks=9)])
+    fleet = ServingFleet([plan.wrap_engine(eng)], clock=clock,
+                         peer_id="serve-1",
+                         admission=AdmissionConfig(kv_pressure_high=0.97,
+                                                   kv_pressure_low=0.9))
+    fed, corr, mgr = _alerting(
+        store,
+        {"serve-1": LoopbackTransport(
+            _rpc_handler(reg, journal, clock, "serve-1"),
+            target="serve-1")},
+        clock, journal)
+    fleet.attach_federation(fed, alert_manager=mgr)
+
+    # One tiny request placed BEFORE the squat, so the first step both
+    # fires the fault and leaves a live decode fighting the squeezed
+    # pool (exhaustion → preemptions → the counter movement the
+    # correlator synthesizes causes from).
+    warmup = fleet.submit([5, 9, 2], max_new_tokens=6)
+    clock.advance(0.5)
+    fleet.step()
+    hot = [5, 9, 2, 7, 4, 4, 8, 1]
+    tickets = [warmup] + [fleet.submit(hot + [i + 1, 3],
+                                       max_new_tokens=8)
+                          for i in range(4)]
+    for _ in range(30):
+        clock.advance(0.5)
+        fleet.step()           # pumps federation + alerts too
+
+    assert mgr.transitions("kv_pressure_high") >= 1
+    peak = max(v for (_t, v) in store.series(
+        "senweaver_kv_pressure", peer="serve-1"))
+    assert peak >= 0.85
+
+    incidents = [i for i in corr.incidents(8)
+                 if i.alert == "kv_pressure_high"]
+    assert incidents
+    top = incidents[-1].top_cause   # earliest firing = the onset
+    assert top is not None and top["cause"] in {
+        "kv_evictions", "kv_swaps_out", "kv_exhaustion",
+        "kv_preemption_storm", "admission_sheds"}, top
+    assert top["event"].get("synthesized")
+    assert not str(top["event"].get("metric", "")).startswith(
+        "senweaver_chaos_"), "correlator read the chaos plan's counters"
+
+    plan.release_all(eng)
+    for _ in range(300):
+        if not fleet.pending():
+            break
+        clock.advance(0.5)
+        fleet.step()
+    assert not fleet.pending()
+    for _ in range(14):
+        clock.advance(5.0)
+        fleet.step()
+    assert "kv_pressure_high" not in mgr.active()
+    assert mgr.transitions("kv_pressure_high") == 2, "alert flapped"
+    assert all(fleet.outcome(t) is not None for t in tickets)
+
+
+def test_injected_cause_top_ranked_eager_publish():
+    """An eager weight publish lands during interactive traffic; TTFT
+    blows the SLO, the multi-window burn alert fires, and the top
+    cause names the publish event with its version."""
+    import jax
+
+    from senweaver_ide_tpu.models import init_params
+    from senweaver_ide_tpu.serve import ServingFleet
+    from senweaver_ide_tpu.serve.rpc import LoopbackTransport
+
+    clock = FakeClock()
+    store, journal, reg = _serving_plane(clock)
+    eng = _tiny_engine(num_slots=4)
+    fleet = ServingFleet([eng], clock=clock, peer_id="serve-1")
+    fed, corr, mgr = _alerting(
+        store,
+        {"serve-1": LoopbackTransport(
+            _rpc_handler(reg, journal, clock, "serve-1"),
+            target="serve-1")},
+        clock, journal)
+    fleet.attach_federation(fed, alert_manager=mgr)
+
+    tickets = [fleet.submit([5, 9, i + 2], max_new_tokens=6,
+                            priority="interactive") for i in range(4)]
+    # The EAGER publish lands right as the batch is admitted, and the
+    # fake clock charges its stall to TTFT.
+    fleet.begin_publish(init_params(eng.config, jax.random.PRNGKey(1)),
+                        eager=True)
+    clock.advance(1.2)          # > interactive ttft_s target (0.5)
+    for _ in range(300):
+        if not fleet.pending():
+            break
+        clock.advance(0.01)
+        fleet.step()
+    assert not fleet.pending()
+    clock.advance(0.5)
+    fleet.step()                # one more pump: scrape + evaluate
+
+    assert mgr.transitions("slo_burn_fast") >= 1
+    incidents = [i for i in corr.incidents(8)
+                 if i.alert == "slo_burn_fast"]
+    assert incidents
+    top = incidents[0].top_cause
+    assert top is not None and top["cause"] in (
+        "publish_begin", "publish_end"), top
+    assert top["event"].get("version") is not None
+    out = fleet.run()
+    assert all(t in out for t in tickets)

@@ -201,3 +201,58 @@ def test_preemption_storm_cap_bounds_rework(model):
     assert registry_value("senweaver_kv_preemption_storms_total") \
         == st["kv_preemption_storms"]
     eng._alloc.check_leaks()
+
+
+# ---- the whole ladder at 2x over capacity --------------------------------
+
+def test_pressure_ladder_at_2x_over_capacity(model):
+    """A prefix-sharing workload whose working set (~6 requests x ~5
+    blocks + 2 prefixes) is ~2x a 10-block pool, squeezed by chaos
+    mid-run. Every ticket completes with tokens that are an exact
+    prefix of the unpressured run (swap/restore and preemption are
+    invisible to outputs; a storm-capped request may truncate-finish
+    short, a wrong token may never appear), the cold single-use prefix
+    is evicted while the hot shared one survives through the host tier,
+    and the pool drains leak-free."""
+    prompts = [HOT + [i + 1, 3] for i in range(6)]
+
+    ref_eng = make(model, num_blocks=64)
+    ref_pid = ref_eng.register_prefix(HOT)
+    ref_rids = [ref_eng.submit(p, max_new_tokens=8, prefix_id=ref_pid)
+                for p in prompts]
+    ref_raw = ref_eng.run()
+    reference = [ref_raw[r] for r in ref_rids]
+
+    eng = make(model, num_blocks=10)
+    cold_pid = eng.register_prefix(COLD)     # decoy the evictor must take
+    plan = MemoryPressurePlan([MemoryPressureFault(at_step=3,
+                                                   hold_blocks=4,
+                                                   release_step=60)])
+    fleet = ServingFleet([plan.wrap_engine(eng)],
+                         admission=AdmissionConfig(kv_pressure_high=0.95,
+                                                   kv_pressure_low=0.7))
+    pid = fleet.register_prefix(HOT)
+    tickets = [fleet.submit(p, max_new_tokens=8, prefix_id=pid)
+               for p in prompts]
+    for _ in range(800):
+        if not fleet.pending():
+            break
+        fleet.step()
+    assert not fleet.pending()
+    plan.release_all(eng)
+    out = fleet.run()
+    completed = [out.get(t) for t in tickets]
+    assert all(c is not None for c in completed)   # zero lost
+    for got, ref in zip(completed, reference):
+        assert got == ref[:len(got)]
+    st = eng.stats()
+    full = sum(got == ref for got, ref in zip(completed, reference))
+    assert full >= 4
+    assert len(tickets) - full <= st["kv_preemption_storms"]
+    assert st["prefix_evictions"] >= 1
+    assert st["prefix_swap_outs"] >= 1 and st["prefix_swap_ins"] >= 1
+    assert cold_pid not in eng._prefixes
+    hot_pid = eng._prefix_by_tokens.get(tuple(HOT))
+    assert hot_pid is not None and hot_pid in eng._prefixes
+    eng.release_prefix(hot_pid)
+    eng._alloc.check_leaks()

@@ -437,3 +437,60 @@ def test_prefix_store_cross_ladder_import(paged_model):
         total += len(ref)
         match += sum(int(a == b) for a, b in zip(out[t], ref))
     assert match / max(1, total) >= MATCH_BUDGET
+
+
+# ---- what the int8 rung buys at one device byte budget -------------------
+
+def test_int8_at_one_byte_budget(paged_model):
+    """In the bytes of a 10-block bf16 pool the int8 pool holds >= 2x
+    the blocks (f32 scales included), and the 2x-over-capacity
+    shared-prefix workload records strictly fewer evictions +
+    preemptions there and finishes no fewer requests in full. A
+    swapped-out int8 prefix stays quantized in host RAM, exports
+    quantized, and restores token-exact within the rung."""
+    _, config = paged_model
+    bpb_full = pool_bytes_per_block(init_paged_pool(config, 8, 4))
+    bpb_q8 = pool_bytes_per_block(
+        init_paged_pool(config, 8, 4, kv_dtype="int8"))
+    blocks_full = 10
+    blocks_q8 = blocks_full * bpb_full // bpb_q8
+    assert blocks_q8 >= 2 * blocks_full
+
+    hot = [(j * 11) % 200 + 2 for j in range(16)]   # 4 blocks @ bs 4
+    prompts = [hot + [i + 1, 3] for i in range(6)]
+
+    def pressured(kv_dtype, num_blocks):
+        eng = _mk(paged_model, kv_dtype, num_blocks=int(num_blocks),
+                  host_tier=False)
+        pid = eng.register_prefix(hot)
+        rids = [eng.submit(p, max_new_tokens=12, prefix_id=pid)
+                for p in prompts]
+        out = eng.run()
+        st = eng.stats()
+        if pid in eng._prefixes:
+            eng.release_prefix(pid)
+        eng._alloc.check_leaks()
+        # the storm cap may truncate-finish a ticket; none may be LOST
+        assert all(r in out for r in rids)
+        return (st["prefix_evictions"] + st["kv_preemptions"],
+                sum(len(out[r]) == 12 for r in rids))
+
+    press_full, done_full = pressured("bf16", blocks_full)
+    press_q8, done_q8 = pressured("int8", blocks_q8)
+    assert press_full >= 1          # the workload IS over capacity
+    assert press_q8 < press_full
+    assert done_q8 >= done_full
+
+    eng = _mk(paged_model, "int8")
+    pid = eng.register_prefix(hot)
+    r0 = eng.submit(hot + [1, 3], max_new_tokens=8, prefix_id=pid)
+    ref = eng.run()[r0]
+    eng._swap_out_prefix(pid)
+    hp = eng._prefix_host[pid]
+    assert hp.quantized and hp.k.dtype == np.int8
+    _toks, kv, _ = eng.export_prefix(pid)
+    assert kv.quantized and isinstance(kv.k, np.ndarray)
+    r1 = eng.submit(hot + [1, 3], max_new_tokens=8, prefix_id=pid)
+    assert eng.run()[r1] == ref
+    eng.release_prefix(pid)
+    eng._alloc.check_leaks()

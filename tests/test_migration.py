@@ -773,6 +773,55 @@ def test_remote_checkpoint_retry_replays_snapshot(model):
     assert isinstance(fleet.outcome(t), Completed)
 
 
+def test_chaos_evacuation_exactly_once(model):
+    """Three remote replicas under mixed decode load: every in-flight
+    decode is evacuated off replica-0 while the first install on the
+    wire is dropped (the idempotency-keyed retry lands it, or the
+    source finishes the decode), then one migration target is
+    partitioned before its first post-handoff token can ack. Every
+    admitted ticket completes EXACTLY once at its full length, no
+    handoff stays unacked, and every allocator balances."""
+    clock = FakeClock()
+    plan = NetworkFaultPlan([
+        NetworkFault(kind="drop", method="restore_checkpoint",
+                     call_idx=0)])
+    fleet, handlers, _ = make_remote_fleet(model, 3, clock=clock,
+                                           plan=plan)
+    mig = fleet.attach_migration()
+    tickets = [fleet.submit([3 + i, 9, 2, 7, 1], max_new_tokens=8)
+               for i in range(8)]
+    for _ in range(2):
+        clock.advance(1.0)
+        fleet.step()
+    source = fleet._replica_by_id("replica-0")
+    moved = mig.evacuate(source, reason="test", now=clock())
+    assert moved == len(mig.pending) == 3
+    # death triage must rescue its decodes back onto their frozen sources
+    plan.partition(next(iter(mig.pending.values())).target.replica_id)
+    run_fleet(fleet, clock, max_steps=300)
+
+    outcomes = [fleet.outcome(t) for t in tickets]
+    assert all(isinstance(o, Completed) for o in outcomes)   # none lost
+    assert all(len(o.tokens) == 8 for o in outcomes)    # none truncated
+    assert len(fleet._outcomes) == len(fleet._requests) == len(tickets)
+    assert all(o.weight_version == o.weight_version_at_finish
+               for o in outcomes)
+    assert not mig.pending                              # every handoff acked
+    assert (migrations_value("test", "completed")
+            + migrations_value("test", "rescued")) == moved
+    assert migrations_value("test", "rescued") >= 1
+    assert plan.injected_counts() == {"drop": 1, "partition": 1}
+
+    # Heal, release what is stranded on the zombie (its janitor's job
+    # in production), then balance every allocator.
+    plan.heal()
+    for h in handlers:
+        for rid, r in list(h.engine._requests.items()):
+            if not r.done:
+                h.engine.release_request(rid)
+        h.engine._alloc.check_leaks()
+
+
 # ---- forked-row checkpoints (group-shared rollout, ISSUE 18) -------------
 
 def test_forked_row_checkpoint_is_unshared_deep_copy(model):

@@ -399,23 +399,59 @@ def test_healthy_round_populates_health(tiny_rl):
     assert out.health["groups"] == 2.0
 
 
-# ---- jit purity + selftest smoke ----
+# ---- jit purity + the observatory end to end ----
 
 def test_jit_lint_no_new_findings():
     lint = analysis.run_package()
     assert not lint.new, [f.format() for f in lint.new]
 
 
-def test_training_health_report_selftest(capsys):
+def test_degenerate_rounds_enable_loo_and_the_report_reads_the_ring(
+        tmp_path, capsys):
+    """One healthy round, then three degenerate ones through monitor,
+    mitigator and scheduler together: round 1 observes, round 2 turns
+    leave-one-out on, and ``scripts/training_health_report.py`` reads
+    the exported ring back."""
     import importlib.util
     import pathlib
+
+    monitor = obs.get_health_monitor()
+    assert monitor.observe(_health(*_healthy_batch()),
+                           round_index=0) == []
+
+    grpo_config = GRPOConfig()
+    mitigator = HealthMitigator(enabled=True,
+                                allow={MITIGATION_LEAVE_ONE_OUT: True},
+                                trigger_rounds=2)
+    scheduler = GroupSizeScheduler(4, max_size=16)
+    rounds = []
+    for r in range(1, 4):
+        health = _health(*_degenerate_batch(),
+                         config=DiagnosticsConfig.from_grpo(
+                             mitigator.effective(grpo_config)))
+        triggers = obs.evaluate_health(health, monitor.config)
+        effective, events = mitigator.apply(grpo_config, triggers)
+        monitor.observe(health, round_index=r, triggers=triggers,
+                        events=events)
+        scheduler.update(mitigator.group_size_active())
+        rounds.append((health, triggers, events))
+    assert {"rank_collapse", "zero_groups"} <= set(rounds[0][1])
+    assert rounds[0][2] == []
+    assert "mitigation_enabled:leave_one_out" in rounds[1][2]
+    assert effective.leave_one_out
+    assert obs.get_registry().get(
+        "senweaver_grpo_health_rank_fraction").value() \
+        == rounds[-1][0]["rank_fraction"]
+
+    ring = monitor.export_jsonl(str(tmp_path / "ring.jsonl"))
     path = (pathlib.Path(__file__).resolve().parents[1] / "scripts"
             / "training_health_report.py")
-    spec = importlib.util.spec_from_file_location("thr_selftest", path)
+    spec = importlib.util.spec_from_file_location("thr_report", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    assert mod.main(["--selftest"]) == 0
+    assert mod.main([ring]) == 0
     report = json.loads(capsys.readouterr().out)
-    assert report["mode"] == "selftest"
-    assert report["healthy"]["triggers"] == []
-    assert report["trigger_totals"]["rank_collapse"] >= 3
+    assert report["rounds"] == 4
+    assert report["trigger_counts"]["rank_collapse"] >= 3
+    assert report["worst_rounds"][0]["triggers"]
+    assert mod.main([str(tmp_path / "absent.jsonl")]) == 2
