@@ -49,9 +49,9 @@ from senweaver_ide_tpu.obs.runtime_profile import get_profiler
 from senweaver_ide_tpu.ops.attention import attention
 from senweaver_ide_tpu.ops.flash_attention import flash_attention
 from senweaver_ide_tpu.ops.flash_decode import flash_decode
-from senweaver_ide_tpu.ops.paged_attention import (paged_attention_rows,
-                                                   paged_flash_decode,
-                                                   plan_rows, query_tile)
+from senweaver_ide_tpu.ops.paged_attention import (
+    paged_attention_rows, paged_flash_decode, paged_latent_attention_rows,
+    plan_rows, query_tile)
 from senweaver_ide_tpu.parallel import MeshConfig, make_mesh
 from senweaver_ide_tpu.rollout import RolloutEngine
 from senweaver_ide_tpu.rollout import engine as engine_mod
@@ -464,22 +464,33 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
     # a second head shape (mistral-7b's, llama-3.1-8b's, qwen3-8b's):
     # four times the bytes a block, a quarter of the blocks a chunk
     out.update(paged_rows_checks(32, 8, d, "_32x8", interpret, seed))
+    # the latent pool's one-leaf form at GLM-4.7-Flash's shapes: 20 heads
+    # over rows of 576 values stored 640 wide, the value their first 512
+    out.update(paged_rows_checks(20, 1, 640, "_latent", interpret, seed,
+                                 latent_rank=512))
     return out
 
 
 def paged_rows_checks(hq: int, hkv: int, d: int, tag: str, interpret: bool,
-                      seed: int) -> dict:
+                      seed: int, latent_rank: int = 0) -> dict:
     """``paged_attention_rows`` against the XLA gather it replaces in the
     fused step, over a stacked pool at the benchmark cells' shapes: 48
     rows with a table 64 blocks wide, contexts 128-900, as a narrow step
     (48 decode entries) and a wide one (47 decode rows, a prefill chunk
-    of 137 tokens on the last row, 8 entries of padding). Reports each
+    of 137 tokens on the last row, 8 entries of padding). With a
+    ``latent_rank`` it is ``paged_latent_attention_rows`` over a latent
+    pool's one leaf of ``d``-wide rows, the value their first
+    ``latent_rank`` columns, at the glm cell's shapes: a table 256 blocks
+    wide, contexts 1024-3840, the scale of 256-wide heads. Reports each
     path's largest error against the other and its time a call (the
     median of 20, after the first) as ``paged_rows{tag}_{entries}_...``:
     a bring-up reading of one layer's attention, not the benchmark's."""
     if interpret:
         dtype, rows, mb, layers, lo, hi, chunk, pad, reps = (
             jnp.float32, 6, 8, 2, 20, 100, 21, 3, 1)
+    elif latent_rank:
+        dtype, rows, mb, layers, lo, hi, chunk, pad, reps = (
+            jnp.bfloat16, 48, 256, 2, 1024, 3840, 137, 8, 20)
     else:
         dtype, rows, mb, layers, lo, hi, chunk, pad, reps = (
             jnp.bfloat16, 48, 64, 2, 128, 900, 137, 8, 20)
@@ -487,28 +498,46 @@ def paged_rows_checks(hq: int, hkv: int, d: int, tag: str, interpret: bool,
     nb = rows * mb + 4
     rng = np.random.default_rng(seed)
     ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
-    k_leaf = jax.random.normal(ks[0], (layers, nb, bs, hkv, d), dtype)
-    v_leaf = jax.random.normal(ks[1], (layers, nb, bs, hkv, d), dtype)
+    # the pool's payload leaves: k and v, or the one latent leaf
+    leaves = tuple(jax.random.normal(k, (layers, nb, bs, hkv, d), dtype)
+                   for k in ks[:1 if latent_rank else 2])
     tables = jnp.asarray(
         rng.permutation(nb)[:rows * mb].reshape(rows, mb).astype(np.int32))
     ctx = rng.integers(lo, hi + 1, rows).astype(np.int32)
     layer = jnp.asarray(layers - 1, jnp.int32)
+    scale = 1.0 / 16.0
+    name = ("paged_latent_attention_rows" if latent_rank
+            else "paged_attention_rows")
 
     # the pool and the tables are arguments: closed over, they would be
     # constants of the program
-    def gather(k_leaf, v_leaf, tables, q, seq_row, positions):
+    def gather(leaves, tables, q, seq_row, positions):
         tbl = tables[seq_row]
-        k_seq, v_seq = (leaf[layer, tbl].reshape(-1, mb * bs, hkv, d)
-                        for leaf in (k_leaf, v_leaf))
         valid = jnp.arange(mb * bs)[None, :] < positions[:, None] + 1
+        if latent_rank:
+            # _paged_mla_layer's gather path
+            seq = leaves[0][layer, tbl].reshape(-1, mb * bs, d)
+            scores = jnp.einsum("thc,tsc->ths", q, seq,
+                                preferred_element_type=jnp.float32) * scale
+            probs = jax.nn.softmax(
+                jnp.where(valid[:, None, :], scores, -1e30), axis=-1)
+            return jnp.einsum(
+                "ths,tsc->thc", probs.astype(dtype), seq,
+                preferred_element_type=jnp.float32)[..., :latent_rank]
+        k_seq, v_seq = (leaf[layer, tbl].reshape(-1, mb * bs, hkv, d)
+                        for leaf in leaves)
         return attention(q[:, None], k_seq, v_seq, q_offset=positions,
                          kv_mask=valid, causal=True)[:, 0]
 
-    def kernel(k_leaf, v_leaf, tables, q, seq_row, positions):
+    def kernel(leaves, tables, q, seq_row, positions):
         plan = plan_rows(seq_row, positions, block_size=bs, table_width=mb,
                          q_tile=query_tile(hq))
-        return paged_attention_rows(q, k_leaf, v_leaf, layer, tables,
-                                    positions, plan, interpret=interpret)
+        if latent_rank:
+            return paged_latent_attention_rows(
+                q, *leaves, layer, tables, positions, plan, scale=scale,
+                value_dim=latent_rank, interpret=interpret)
+        return paged_attention_rows(q, *leaves, layer, tables, positions,
+                                    plan, interpret=interpret)
 
     def ms_a_call(fn, *args):
         jax.block_until_ready(fn(*args))
@@ -529,15 +558,14 @@ def paged_rows_checks(hq: int, hkv: int, d: int, tag: str, interpret: bool,
     for seq_row, positions in (narrow, wide):
         t = len(seq_row)
         q = jax.random.normal(jax.random.fold_in(ks[2], t), (t, hq, d), dtype)
-        args = (k_leaf, v_leaf, tables, q, jnp.asarray(seq_row),
+        args = (leaves, tables, q, jnp.asarray(seq_row),
                 jnp.asarray(positions))
         if not interpret:
-            lowered_with_kernel(kernel_jit.lower(*args),
-                                "paged_attention_rows")
+            lowered_with_kernel(kernel_jit.lower(*args), name)
         err = max_err(kernel_jit(*args), gather_jit(*args))
         out[f"paged_rows{tag}_{t}_max_err"] = err
         check(err <= KERNEL_TOL,
-              f"paged_attention_rows{tag} over {t} entries off by {err}")
+              f"{name}{tag} over {t} entries off by {err}")
         out[f"paged_rows{tag}_{t}_ms"] = ms_a_call(kernel_jit, *args)
         out[f"paged_gather{tag}_{t}_ms"] = ms_a_call(gather_jit, *args)
     return out

@@ -928,7 +928,7 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     if c.mla:
         return _paged_mla_layer(c, lp, x, cos, sin, leaves, layer, tables,
                                 seq_row, positions, write_block, write_off,
-                                stack_layer)
+                                stack_layer, row_plan)
     t = x.shape[0]
     quantized = len(leaves) == 4
     with jax.named_scope("attn.qkv"):
@@ -1014,7 +1014,7 @@ def _paged_mla_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                      leaves: Tuple[jax.Array, ...], layer: jax.Array,
                      tables: jax.Array, seq_row: jax.Array,
                      positions: jax.Array, write_block: jax.Array,
-                     write_off: jax.Array, stack_layer=None):
+                     write_off: jax.Array, stack_layer=None, row_plan=None):
     """``_paged_layer`` for latent attention: the pool's one payload leaf
     ``(L, num_blocks, block_size, 1, latent_row_dim)`` holds a token's
     ``[c_kv | k_rope | 0...]`` row (zero-padded to whole lane tiles, see
@@ -1026,9 +1026,16 @@ def _paged_mla_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
         score_i = (q_nope_i W_kb_i^T) . c_kv + q_rope_i . k_rope
         out_i   = (sum_s a_is c_kv_s) W_vb_i
 
-    so nothing of the cached context is expanded to heads: the gathered
-    rows are read twice (scores, weighted sum) at their stored width. The
-    same mathematics as ``_mla_self_attention``'s expanded form."""
+    so nothing of the cached context is expanded to heads: the rows are
+    read at their stored width, for the scores and for the weighted sum.
+    The same mathematics as ``_mla_self_attention``'s expanded form.
+
+    With a ``row_plan`` the rows are read where they lie by
+    ``ops.paged_attention.paged_latent_attention_rows``: each run of one
+    row's entries streams that row's live blocks once, and the one leaf
+    serves both products. Without one, the XLA gather: every entry's whole
+    table width copied, then read twice. It is the plain reference the
+    kernel is tested against, and the path off the TPU."""
     t = x.shape[0]
     (leaf,) = leaves
     r = c.kv_lora_rank
@@ -1052,22 +1059,31 @@ def _paged_mla_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
         # runs over whole tiles and no slice of the gathered rows is made
         q_cat = jnp.pad(jnp.concatenate([q_abs, q_rope[:, 0]], axis=-1),
                         pad)
-    with jax.named_scope("attn.kv_gather"):
-        tbl = tables[seq_row]                                  # (T, MB)
-        mb, bs = tbl.shape[1], leaf.shape[2]
-        seq = leaf[layer, tbl].reshape(t, mb * bs, leaf.shape[-1])
-    with jax.named_scope("attn.scores"):
-        scores = jnp.einsum("thc,tsc->ths", q_cat, seq, precision=prec,
-                            preferred_element_type=jnp.float32)
-        scores = scores * (1.0 / float(c.head_dim) ** 0.5)
-        valid = jnp.arange(mb * bs)[None, :] < positions[:, None] + 1
-        probs = jax.nn.softmax(
-            jnp.where(valid[:, None, :], scores, NEG_INF), axis=-1)
-        # over the whole row: a slice of the gathered rows would be a copy
-        # of them; the rotary and zero columns of the small result are cut
-        ctx = jnp.einsum("ths,tsc->thc", probs.astype(x.dtype), seq,
-                         precision=prec,
-                         preferred_element_type=jnp.float32)[..., :r]
+    # the scale is the model's (its q/k head width), not the row's
+    scale = 1.0 / float(c.head_dim) ** 0.5
+    if row_plan is not None:
+        from ..ops.paged_attention import paged_latent_attention_rows
+        with jax.named_scope("attn.scores"):
+            ctx = paged_latent_attention_rows(
+                q_cat, leaf, layer, tables, positions, row_plan,
+                scale=scale, value_dim=r)
+    else:
+        with jax.named_scope("attn.kv_gather"):
+            tbl = tables[seq_row]                              # (T, MB)
+            mb, bs = tbl.shape[1], leaf.shape[2]
+            seq = leaf[layer, tbl].reshape(t, mb * bs, leaf.shape[-1])
+        with jax.named_scope("attn.scores"):
+            scores = jnp.einsum("thc,tsc->ths", q_cat, seq, precision=prec,
+                                preferred_element_type=jnp.float32) * scale
+            valid = jnp.arange(mb * bs)[None, :] < positions[:, None] + 1
+            probs = jax.nn.softmax(
+                jnp.where(valid[:, None, :], scores, NEG_INF), axis=-1)
+            # over the whole row: a slice of the gathered rows would be a
+            # copy of them; the rotary and zero columns of the small
+            # result are cut
+            ctx = jnp.einsum("ths,tsc->thc", probs.astype(x.dtype), seq,
+                             precision=prec,
+                             preferred_element_type=jnp.float32)[..., :r]
     with jax.named_scope("attn.out"):
         out = jnp.einsum("thr,rhv->thv", ctx.astype(x.dtype), w_vb,
                          precision=prec)
@@ -1131,9 +1147,10 @@ def forward_paged(
     (``reads_pool_in_place``): on a TPU an unquantized dense pool (and
     the full-width prefix layers of a ``kv_dtype_per_layer`` ladder)
     whose ``head_dim`` is a multiple of 128 is read by
-    ``ops.paged_attention.paged_attention_rows``; off the TPU, and for a
-    quantized or a latent pool or narrower heads, the XLA gather. ``True`` forces the
-    kernels (interpreted off the TPU; on a quantized pool the
+    ``ops.paged_attention.paged_attention_rows`` and a latent pool by
+    its one-leaf form ``paged_latent_attention_rows``; off the TPU, and
+    for a quantized pool or narrower heads, the XLA gather. ``True``
+    forces the kernels (interpreted off the TPU; on a quantized pool the
     dequant-fused ``paged_flash_decode``), ``False`` the gather: both
     are for tests.
 
@@ -1160,21 +1177,21 @@ def forward_paged(
 
 
 def reads_pool_in_place(c: ModelConfig, use_kernel: Optional[bool]) -> bool:
-    """``forward_paged``'s choice for the unquantized leaves of a dense
-    pool: True where ``paged_attention_rows`` reads them where they lie,
-    False where the XLA gather copies each entry's table width. By what
-    the code sees (``use_kernel=None``): a TPU takes the kernel where
-    Mosaic can cut a block's heads out of the pool, which needs head
-    rows of whole 128-lane tiles (at a ``head_dim`` of 64 it refuses the
-    block's window: those models keep the gather). The engine asks too:
-    where the kernel reads, a step's cost does not follow the table's
-    width, so its table keeps one width."""
-    if c.mla:
-        return False
+    """``forward_paged``'s choice for the unquantized leaves of a pool:
+    True where ``paged_attention_rows`` (a latent pool:
+    ``paged_latent_attention_rows``) reads them where they lie, False
+    where the XLA gather copies each entry's table width. By what the
+    code sees (``use_kernel=None``): a TPU takes the kernel where Mosaic
+    can cut a block's rows out of the pool, which needs rows of whole
+    128-lane tiles: a latent row is padded to them
+    (``ModelConfig.latent_row_dim``); at a ``head_dim`` of 64 Mosaic
+    refuses the block's window, and those models keep the gather. The
+    engine asks too: where the kernel reads, a step's cost does not
+    follow the table's width, so its table keeps one width."""
     if use_kernel is not None:
         return bool(use_kernel)
     from ..ops.paged_attention import on_tpu
-    return on_tpu() and c.head_dim % 128 == 0
+    return on_tpu() and (c.mla or c.head_dim % 128 == 0)
 
 
 def _forward_paged_impl(params, c, tokens, *, pool, tables,
@@ -1184,11 +1201,9 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         x = params["embed"][tokens][:, None, :]        # (T, 1, D)
         cos, sin = rope_cos_sin(positions[:, None], _rope_dim(c),
                                 c.rope_theta, scaling=c.rope_scaling)
-    if c.mla and (use_kernel or adapters is not None
-                  or pool.k_scale is not None):
+    if c.mla and (adapters is not None or pool.k_scale is not None):
         raise LatentCacheUnsupported(
-            "the Pallas paged-decode kernel / adapter banks / a quantized "
-            "pool in forward_paged", c.name)
+            "adapter banks / a quantized pool in forward_paged", c.name)
     # STATIC under jit: derived from pytree structure (None-ness and
     # shapes), so the precision ladder never adds a trace argument.
     n_hi = 0 if pool.k_hi is None else pool.k_hi.shape[0]
@@ -1196,7 +1211,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
     if (pool.k_scale is None or n_hi) and reads_pool_in_place(c,
                                                               use_kernel):
         # once a step, outside the layer scans: the flat batch cut into
-        # the runs of one row that paged_attention_rows attends together
+        # the runs of one row that the kernel attends together
         from ..ops.paged_attention import plan_rows, query_tile
         with jax.named_scope("attn.row_plan"):
             row_plan = plan_rows(seq_row, positions,

@@ -8,7 +8,8 @@ sequence's whole table width. The kernels here read the blocks where
 they lie.
 
 ``paged_attention_rows`` is the one the fused step runs on an
-unquantized dense pool. It works **per sequence row, not per entry**: a
+unquantized dense pool, ``paged_latent_attention_rows`` its form for a
+latent pool (below). It works **per sequence row, not per entry**: a
 *segment* is a maximal run of entries with the same ``seq_row`` (a decode
 row is a segment of one, a prefill chunk or a verify window a segment of
 n), and a segment's queries attend together over that row's live blocks,
@@ -51,6 +52,22 @@ How it is laid out:
   with f32 accumulation, scores, softmax and the output accumulator in
   f32, probabilities cast to the value dtype for the PV product; f32
   inputs take the exact path (``Precision.HIGHEST``).
+
+*The latent pool* (multi-head latent attention, ``_paged_mla_layer``) has
+ONE payload leaf, ``(L, num_blocks, block_size, 1, row)``: a token's
+``[c_kv | k_rope | 0...]`` row is the key of every head and, in its
+first ``kv_lora_rank`` columns, the value too. The same program with
+these parameters: one DMA a block into one buffer (handing the dense
+form the leaf twice would stream every block twice), the scores against
+the whole row, the weighted sum over the lane-aligned window of the
+same VMEM rows that holds the value, and the scale the model's
+(``1 / sqrt`` of its q/k head width, not of the row's). One "kv head"
+under all the query heads, so an item is ``query_tile(Hq)`` queries and
+a compute step ``blocks_per_chunk(block_size, 1)`` blocks. On a v5e at
+GLM-4.7-Flash's shapes (20 heads, rows 640 wide, rank 512, 48 rows at
+contexts 1-3.8k; my chip runs, PR 29): 0.54 ms a layer for a 48-entry
+step, 65 cycles a 20 KB block (the gather and its two reads: 1.72 ms),
+0.80 ms for a 192-entry one (6.88).
 
 ``paged_flash_decode`` is the one-query-a-row form over ONE layer's
 pool: unquantized, it is ``paged_attention_rows`` with every entry its
@@ -174,14 +191,16 @@ def plan_rows(seq_row: jax.Array, positions: jax.Array, *,
 
 def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
                  blocks_ref, num_ref, pos_ref,           # scalars (SMEM)
-                 q_ref, k_hbm, v_hbm,                    # inputs
-                 out_ref,                                # output
-                 k_buf, v_buf, sems, acc_ref, m_ref, l_ref, qpos_ref,
-                 *, scale: float, block_size: int, hkv: int, rep: int,
-                 hq_pad: int, q_tile: int, chunk: int, exact: bool):
+                 q_ref, *refs,
+                 scale: float, block_size: int, hkv: int, rep: int,
+                 hq_pad: int, q_tile: int, chunk: int, exact: bool,
+                 leaves: int):
     """The whole flat batch in one program; see the module docstring.
 
-    ``k_buf``/``v_buf`` are ``(2, chunk) + a block's shape``: two
+    ``refs`` are the pool's ``leaves`` payload leaves in HBM (k and v, or
+    the one latent leaf whose rows hold both), the output, a buffer a
+    leaf, then ``sems, acc_ref, m_ref, l_ref, qpos_ref``. A buffer is
+    ``(2, chunk) + a block's shape``: two
     slots of one chunk each. One loop runs over every (item, chunk) in
     order; step g computes out of slot ``g % 2`` what step g-1 started
     into it, after starting its own successor into the other. Every
@@ -190,41 +209,42 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
     traced three times and the compute twice (one query, a tile of
     them) whatever the sizes: the step's lowering time is part of
     ``setup_s``."""
+    hbm, out_ref, bufs = refs[:leaves], refs[leaves], refs[leaves + 1:-5]
+    sems, acc_ref, m_ref, l_ref, qpos_ref = refs[-5:]
     n_cols = chunk * block_size * hkv
     layer = layer_ref[0]
     num_items = num_ref[0]
     precision = jax.lax.Precision.HIGHEST if exact else None
 
     def for_blocks(item, c, slot, act):
-        """``act`` on the (k copy, v copy) of each block of chunk ``c`` of
-        ``item`` that the item covers, into ``slot``: a loop, so the
+        """``act`` on the copies (one a leaf) of each block of chunk ``c``
+        of ``item`` that the item covers, into ``slot``: a loop, so the
         program's size does not grow with the chunk."""
         row = row_ref[item]
         first = c * chunk
 
         def body(b, _):
             phys = tables_ref[row, first + b]
-            act(pltpu.make_async_copy(k_hbm.at[layer, phys],
-                                      k_buf.at[slot, b], sems.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[layer, phys],
-                                      v_buf.at[slot, b], sems.at[1, slot]))
+            act([pltpu.make_async_copy(leaf.at[layer, phys],
+                                       buf.at[slot, b], sems.at[i, slot])
+                 for i, (leaf, buf) in enumerate(zip(hbm, bufs))])
             return 0
 
         jax.lax.fori_loop(
             0, jnp.minimum(chunk, blocks_ref[item] - first), body, 0)
 
-    def start(kc, vc):
-        kc.start()
-        vc.start()
+    def start(copies):
+        for copy in copies:
+            copy.start()
 
-    def wait(kc, vc):
-        kc.wait()
-        vc.wait()
+    def wait(copies):
+        for copy in copies:
+            copy.wait()
 
     # A slot's rows that no DMA has filled yet must hold numbers: their
     # columns are masked, and 0 x stale is 0 only if stale is finite.
-    k_buf[...] = jnp.zeros_like(k_buf)
-    v_buf[...] = jnp.zeros_like(v_buf)
+    for buf in bufs:
+        buf[...] = jnp.zeros_like(buf)
 
     @pl.when(num_items > 0)
     def _first():
@@ -267,8 +287,11 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
         head = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % hq_pad
         own = (col % hkv) == (head // rep)          # (rows, n_cols)
         seen = col // hkv + c * (chunk * block_size) <= q_pos
-        k = k_buf.at[slot].reshape(n_cols, k_buf.shape[-1])[...]
-        v = v_buf.at[slot].reshape(n_cols, v_buf.shape[-1])[...]
+        k, *v = (buf.at[slot].reshape(n_cols, buf.shape[-1])[...]
+                 for buf in bufs)
+        # a latent row's leading columns are its value: the window of the
+        # same VMEM rows, as wide as the accumulator
+        v = v[0] if v else k[:, :acc_ref.shape[-1]]
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -336,8 +359,43 @@ def paged_attention_rows(
     """Attention of a flat paged batch over its rows' blocks, read in
     place (module docstring). Query t sees positions ``<= positions[t]``
     of its row. Returns ``(T, Hq, D)``."""
+    d = q.shape[-1]
+    return _attend_rows(q, (k_leaf, v_leaf), layer, tables, positions, plan,
+                        scale=1.0 / (d ** 0.5), value_dim=d,
+                        name="paged_attention_rows", interpret=interpret)
+
+
+def paged_latent_attention_rows(
+    q: jax.Array,              # (T, Hq, W) — the absorbed query, its tail
+                               # zero where the row's is
+    leaf: jax.Array,           # (L, NB, BS, 1, W) — the latent pool's one
+                               # payload leaf as stored, every layer
+    layer: jax.Array,          # () int32
+    tables: jax.Array,         # (R, MB) int32
+    positions: jax.Array,      # (T,) int32
+    plan: RowPlan,             # plan_rows(seq_row, positions, ...)
+    *,
+    scale: float,              # the model's: 1 / sqrt(its q/k head width)
+    value_dim: int,            # leading columns of a row that are its value
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``paged_attention_rows`` over a latent pool (module docstring):
+    the one leaf serves both products, a block streamed once. Returns the
+    weighted sums of the rows' first ``value_dim`` columns,
+    ``(T, Hq, value_dim)``."""
+    return _attend_rows(q, (leaf,), layer, tables, positions, plan,
+                        scale=scale, value_dim=value_dim,
+                        name="paged_latent_attention_rows",
+                        interpret=interpret)
+
+
+def _attend_rows(q, leaves, layer, tables, positions, plan, *, scale,
+                 value_dim, name, interpret):
+    """The one program behind ``paged_attention_rows`` (``leaves`` k and
+    v) and ``paged_latent_attention_rows`` (one leaf, the value a row's
+    leading ``value_dim`` columns)."""
     t, hq, d = q.shape
-    _, _, bs, hkv, _ = k_leaf.shape
+    _, _, bs, hkv, _ = leaves[0].shape
     rep = hq // hkv
     exact = q.dtype == jnp.float32
     hq_pad = _head_rows(hq)
@@ -350,35 +408,40 @@ def paged_attention_rows(
     qp = jnp.pad(q, ((0, q_tile), (0, hq_pad - hq), (0, 0)))
     pos = jnp.pad(positions.astype(jnp.int32), (0, q_tile))
     rows = q_tile * hq_pad
+    # the accumulator's columns: the value's, in whole 128-lane tiles so
+    # the window of a latent row is cut on a tile's edge
+    acc_dim = min(-(-value_dim // 128) * 128, d)
     kernel = functools.partial(
-        _rows_kernel, scale=1.0 / (d ** 0.5), block_size=bs, hkv=hkv,
-        rep=rep, hq_pad=hq_pad, q_tile=q_tile, chunk=chunk, exact=exact)
+        _rows_kernel, scale=scale, block_size=bs, hkv=hkv,
+        rep=rep, hq_pad=hq_pad, q_tile=q_tile, chunk=chunk, exact=exact,
+        leaves=len(leaves))
     if hkv == 1:
         # a lone kv head is no axis of a block: Mosaic cannot cut a
         # packed (block_size, 1, D) window out of the pool
-        k_leaf, v_leaf = (leaf.reshape(leaf.shape[:3] + (d,))
-                          for leaf in (k_leaf, v_leaf))
-    block = k_leaf.shape[2:]
+        leaves = tuple(leaf.reshape(leaf.shape[:3] + (d,))
+                       for leaf in leaves)
+    block = leaves[0].shape[2:]
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    live_bytes = 2 * t * tables.shape[1] * bs * hkv * d * k_leaf.dtype.itemsize
+    live_bytes = (len(leaves) * t * tables.shape[1] * bs * hkv * d
+                  * leaves[0].dtype.itemsize)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=8,
             grid=(1,),
-            in_specs=[vmem, hbm, hbm],
+            in_specs=[vmem] + [hbm] * len(leaves),
             out_specs=vmem,
             scratch_shapes=[
-                pltpu.VMEM((2, chunk) + block, k_leaf.dtype),
-                pltpu.VMEM((2, chunk) + block, v_leaf.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((rows, d), jnp.float32),
+                *(pltpu.VMEM((2, chunk) + block, leaf.dtype)
+                  for leaf in leaves),
+                pltpu.SemaphoreType.DMA((len(leaves), 2)),
+                pltpu.VMEM((rows, acc_dim), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.int32),
             ]),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qp.shape[:2] + (acc_dim,), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # q and the output are whole in VMEM, beside 16 MiB for the
@@ -389,12 +452,12 @@ def paged_attention_rows(
             flops=4 * t * hq * tables.shape[1] * bs * d // 2,
             bytes_accessed=live_bytes // 2,
             transcendentals=t * hq * tables.shape[1] * bs // 2),
-        name="paged_attention_rows",
+        name=name,
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       jnp.asarray(tables, jnp.int32), plan.row, plan.q0, plan.count,
-      plan.blocks, plan.num_items, pos, qp, k_leaf, v_leaf)
-    return out[:t, :hq]
+      plan.blocks, plan.num_items, pos, qp, *leaves)
+    return out[:t, :hq, :value_dim]
 
 
 def _pfd_kernel(tables_ref, lengths_ref, q_ref, k_ref, v_ref, ks_ref,

@@ -397,10 +397,10 @@ class EngineConfig:
     step_tokens: Optional[int] = None
     # None = by what the code sees (models.transformer.forward_paged):
     # on a TPU an unquantized dense pool of 128-wide heads is read in
-    # place by the Pallas kernel ops.paged_attention.paged_attention_rows,
-    # everything else by the XLA gather. True / False force the kernels
-    # on or off (interpreted off the TPU): a test override, not a
-    # serving knob.
+    # place by the Pallas kernel ops.paged_attention.paged_attention_rows
+    # and a latent pool by its one-leaf form, everything else by the XLA
+    # gather. True / False force the kernels on or off (interpreted off
+    # the TPU): a test override, not a serving knob.
     paged_kernel: Optional[bool] = None
     # Host-RAM tier for warm prefixes (rollout/kv_pressure.py): under
     # pool pressure, warm/shared prefixes swap to host numpy buffers
@@ -635,10 +635,9 @@ class RolloutEngine:
                      "(mesh=...)"),
                     (adapter_pool is not None, "the multi-LoRA adapter "
                      "pool"),
-                    (ec.paged_kernel
-                     or config.decode_attn_impl == "flash",
-                     "the Pallas paged-decode kernel (paged_kernel / "
-                     "decode_attn_impl='flash')")):
+                    (config.decode_attn_impl == "flash",
+                     "the Pallas flash-decode kernel over the slot cache "
+                     "(decode_attn_impl='flash')")):
                 if asked:
                     raise LatentCacheUnsupported(mechanism, config.name)
         self.sample = sample

@@ -1,6 +1,8 @@
 """Paged-attention Pallas kernel (interpret mode) vs the gather+einsum
 reference the engine's default paged path uses: block-table indirection,
-GQA grouping, ragged lengths, block skipping."""
+GQA grouping, ragged lengths, block skipping; the latent pool's one-leaf
+form among the same cases. Through ``forward_paged`` and the engine:
+``tests/test_paged_attention_engine.py``."""
 
 import jax
 import jax.numpy as jnp
@@ -97,14 +99,9 @@ def test_length_one_skips_dead_blocks():
 
 from unittest import mock
 
-from senweaver_ide_tpu.models import transformer as tf
 from senweaver_ide_tpu.ops import paged_attention
-from senweaver_ide_tpu.models.config import get_config
-from senweaver_ide_tpu.ops.paged_attention import (paged_attention_rows,
-                                                   plan_rows)
-from senweaver_ide_tpu.rollout.engine import EngineConfig, RolloutEngine
-from senweaver_ide_tpu.rollout.paged_kv import init_paged_pool
-from senweaver_ide_tpu.rollout.sampler import SampleParams
+from senweaver_ide_tpu.ops.paged_attention import (
+    paged_attention_rows, paged_latent_attention_rows, plan_rows)
 
 LAYERS, NB, BS, MB, ROWS = 3, 80, 4, 12, 6
 
@@ -143,13 +140,36 @@ def _flat_batches():
     }
 
 
+# The latent pool's form, as ``_paged_mla_layer`` hands it over: ONE leaf
+# of rows 640 wide whose first 512 columns are the value too (GLM-4.7-
+# Flash's 20 heads, row and rank), and the model's scale, which is not
+# ``1 / sqrt(640)``. In the parametrisations below ``hkv == LATENT``
+# stands for it.
+LATENT = "latent"
+LATENT_ROW, LATENT_RANK, LATENT_SCALE = 640, 512, 1.0 / 16.0
+
+
 def _gather_reference(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
-    """``_paged_layer``'s gather path: every entry's whole table width."""
+    """``_paged_layer``'s gather path: every entry's whole table width.
+    Without a ``v_leaf``, ``_paged_mla_layer``'s: scores against the whole
+    row at the model's scale, the sum over the same rows cut to the rank."""
     t, width = q.shape[0], tables.shape[1] * k_leaf.shape[2]
     tbl = tables[seq_row]
+    valid = jnp.arange(width)[None, :] < positions[:, None] + 1
+    if v_leaf is None:
+        prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+                else None)
+        seq = k_leaf[layer, tbl].reshape(t, width, k_leaf.shape[-1])
+        scores = jnp.einsum("thc,tsc->ths", q, seq, precision=prec,
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(jnp.where(
+            valid[:, None, :], scores * LATENT_SCALE, -1e30), axis=-1)
+        return jnp.einsum(
+            "ths,tsc->thc", probs.astype(q.dtype), seq, precision=prec,
+            preferred_element_type=jnp.float32)[..., :LATENT_RANK].astype(
+                q.dtype)
     k_seq, v_seq = (leaf[layer, tbl].reshape((t, width) + leaf.shape[3:])
                     for leaf in (k_leaf, v_leaf))
-    valid = jnp.arange(width)[None, :] < positions[:, None] + 1
     return attention(q[:, None], k_seq, v_seq, q_offset=positions,
                      kv_mask=valid, causal=True)[:, 0]
 
@@ -158,14 +178,18 @@ def _rows_case(batch, hq, hkv, dtype, d=16):
     seq_row, positions, *tables = _flat_batches()[batch]
     tables = jnp.asarray(tables[0] if tables else _private_tables(),
                          jnp.int32)
+    latent = hkv == LATENT
+    if latent:
+        hkv, d = 1, LATENT_ROW
     ks = jax.random.split(jax.random.PRNGKey(hq * 10 + hkv), 3)
     k_leaf, v_leaf = (
         jax.random.normal(k, (LAYERS, NB, BS, hkv, d),
                           jnp.float32).astype(dtype) for k in ks[:2])
     q = jax.random.normal(ks[2], (len(seq_row), hq, d),
                           jnp.float32).astype(dtype)
-    return (q, k_leaf, v_leaf, jnp.asarray(1, jnp.int32), tables,
-            jnp.asarray(seq_row), jnp.asarray(positions))
+    return (q, k_leaf, None if latent else v_leaf,
+            jnp.asarray(1, jnp.int32), tables, jnp.asarray(seq_row),
+            jnp.asarray(positions))
 
 
 def _run_rows(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
@@ -174,11 +198,15 @@ def _run_rows(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
     # a score tile two blocks wide, so a run takes several compute steps
     with mock.patch.object(paged_attention, "TILE_COLS",
                            2 * BS * k_leaf.shape[3]):
+        if v_leaf is None:
+            return paged_latent_attention_rows(
+                q, k_leaf, layer, tables, positions, plan,
+                scale=LATENT_SCALE, value_dim=LATENT_RANK, interpret=True)
         return paged_attention_rows(q, k_leaf, v_leaf, layer, tables,
                                     positions, plan, interpret=True)
 
 
-HEADS = [(4, 4), (4, 2), (8, 1), (12, 2)]
+HEADS = [(4, 4), (4, 2), (8, 1), (12, 2), (20, LATENT)]
 
 
 @pytest.mark.parametrize("hq,hkv", HEADS)
@@ -251,120 +279,24 @@ def test_plan_rows_cuts_runs_into_items():
     assert not plan.count[n:].any() and not plan.blocks[n:].any()
 
 
-def test_rows_kernel_reads_no_dead_block():
-    """Blocks past a run's last position are not read: poison in them, and
-    any id in the dead table entries, cannot move the output."""
+@pytest.mark.parametrize("hq,hkv", [(4, 2), (20, LATENT)])
+def test_rows_kernel_reads_no_dead_block(hq, hkv):
+    """Blocks past a run's last position are not read: NaN in them (which
+    a masked column would still carry into the weighted sum), and any id
+    in the dead table entries, cannot move the output."""
     q, k_leaf, v_leaf, layer, tables, seq_row, positions = _rows_case(
-        "verify-window", 4, 2, jnp.float32)
+        "verify-window", hq, hkv, jnp.float32)
     want = _run_rows(q, k_leaf, v_leaf, layer, tables, seq_row, positions)
     live = np.zeros(k_leaf.shape[1], bool)
     for row, pos in zip(np.asarray(seq_row), np.asarray(positions)):
         live[np.asarray(tables)[row, :pos // BS + 1]] = True
-    poison = jnp.where(jnp.asarray(live)[None, :, None, None, None], 0, 1e4)
+    poison = jnp.where(jnp.asarray(live)[None, :, None, None, None], 0,
+                       jnp.nan)
     dead = np.arange(MB)[None, :] > np.asarray(
         jax.ops.segment_max(positions, seq_row, ROWS))[:, None] // BS
-    got = _run_rows(q, k_leaf + poison, v_leaf + poison, layer,
+    got = _run_rows(q, k_leaf + poison,
+                    None if v_leaf is None else v_leaf + poison, layer,
                     jnp.where(dead, 7, tables), seq_row, positions)
+    assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
-
-
-def _tiny():
-    return get_config("tiny-test")
-
-
-def test_forward_paged_kernel_matches_gather_prefill_then_decode():
-    """Chunked prefill of two rows, then a decode step at every position
-    up to the table's end (with a dropped write riding each step): the
-    kernel path's logits and pool equal the gather path's to f32
-    rounding."""
-    c = _tiny()
-    params = tf.init_params(c, jax.random.PRNGKey(0))
-    bs, mb = 4, 6
-    tables = jnp.asarray([[3, 8, 1, 10, 5, 7], [2, 9, 4, 11, 0, 6]],
-                         jnp.int32)
-    nb = 12
-    run = jax.jit(tf.forward_paged,
-                  static_argnames=("config", "use_kernel"))
-    pools = {uk: init_paged_pool(c, nb, bs) for uk in (False, True)}
-    toks = np.random.default_rng(1).integers(1, c.vocab_size, (2, bs * mb))
-
-    def step(seq_row, positions, drop):
-        seq_row, positions = np.asarray(seq_row), np.asarray(positions)
-        block = np.asarray(tables)[seq_row, positions // bs]
-        batch = dict(
-            tokens=jnp.asarray(toks[seq_row, positions], jnp.int32),
-            tables=tables, seq_row=jnp.asarray(seq_row, jnp.int32),
-            positions=jnp.asarray(positions, jnp.int32),
-            write_block=jnp.asarray(np.where(drop, nb, block), jnp.int32),
-            write_off=jnp.asarray(positions % bs, jnp.int32))
-        out = {}
-        for uk in (False, True):
-            out[uk], pools[uk] = run(params, config=c, pool=pools[uk],
-                                     use_kernel=uk, **batch)
-        live = ~np.asarray(drop)
-        np.testing.assert_allclose(np.asarray(out[True])[live],
-                                   np.asarray(out[False])[live],
-                                   atol=1e-5, rtol=1e-5)
-        for a, b in zip(pools[True], pools[False]):
-            if a is not None:
-                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                           atol=1e-5, rtol=1e-5)
-
-    # prefill: row 0 takes 7 then 4 tokens, row 1 takes 5 beside them
-    step([0] * 7 + [1] * 2, list(range(7)) + [0, 1], [False] * 9)
-    step([0] * 4 + [1] * 3, list(range(7, 11)) + [2, 3, 4], [False] * 7)
-    # decode: both rows a step, and a padding entry on the drop sentinel
-    for i in range(bs * mb - 11):
-        step([0, 1, 0], [11 + i, 5 + i, 0], [False, False, True])
-
-
-@pytest.mark.parametrize("sample", ["greedy", "sampled"])
-def test_engine_logps_with_the_kernel_equal_teacher_forcing(sample):
-    """``paged_kernel=True`` through the engine (interpreted here):
-    chunked prefill, decode rows and a forked group, each served token's
-    log p against the teacher-forced ``forward``."""
-    c = _tiny()
-    params = tf.init_params(c, jax.random.PRNGKey(2))
-    eng = RolloutEngine(
-        params, c, num_slots=4, max_len=64, seed=3,
-        sample=SampleParams(temperature=0.0 if sample == "greedy" else 1.0),
-        engine_config=EngineConfig(paged_kernel=True, step_tokens=8))
-    assert eng.kv_layout == "paged"
-    prompts = [list(range(1, 14)), [7, 7, 7]]
-    rids = [eng.submit(p, max_new_tokens=9) for p in prompts]
-    group_prompt = list(range(30, 51))
-    rids += eng.submit_group(group_prompt, 2, max_new_tokens=6)
-    prompts += [group_prompt] * 2
-    eng.run()
-    for p, rid in zip(prompts, rids):
-        out = eng.result(rid)
-        seq = jnp.asarray([p + out], jnp.int32)
-        logits = tf.forward(params, c, seq)[0]
-        logp = jax.nn.log_softmax(logits[0].astype(jnp.float32), axis=-1)
-        want = [float(logp[len(p) - 1 + i, tok])
-                for i, tok in enumerate(out)]
-        np.testing.assert_allclose(eng.result_logps(rid), want, atol=2e-4)
-    eng._alloc.check_leaks()
-
-
-@pytest.mark.parametrize("paged_kernel,widths", [(True, {8}),
-                                                 (None, {1, 2, 4, 8})])
-def test_table_keeps_one_width_where_the_kernel_reads_the_pool(
-        paged_kernel, widths):
-    """The gather's cost follows the table's width, so the table is cut
-    to a ladder of widths, a compiled program each; the kernel reads a
-    row's live blocks whatever the width, so there the table stays
-    ``blocks_per_row`` wide and the step has one shape a batch width."""
-    c = _tiny()
-    params = tf.init_params(c, jax.random.PRNGKey(2))
-    eng = RolloutEngine(
-        params, c, num_slots=2, max_len=32, seed=3,
-        engine_config=EngineConfig(paged_kernel=paged_kernel, block_size=4,
-                                   step_tokens=8))
-    eng.submit(list(range(1, 6)), max_new_tokens=24)
-    seen = set()
-    while eng.has_work:
-        seen.add(eng._tables_device().shape[1])
-        eng.step()
-    assert seen == widths

@@ -287,20 +287,32 @@ def one_v5e():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _benchmark_config(name):
+    """A configuration of the benchmark, from its own file."""
+    from benchmark import manifest
+    return manifest.model_config(manifest.load_json(
+        manifest.HERE, "configs", f"{name}.json"))
+
+
 @pytest.mark.parametrize("model,layers,entries", [
     ("qwen2.5-coder-1.5b", None, 48), ("qwen2.5-coder-1.5b", None, 192),
     # other head shapes, cut to four layers (a scan: the program is the
     # same) so the weights fit the described chip: 32/8 and 32/32 x 128
-    ("qwen3-8b", 4, 48), ("deepseek-coder-6.7b", 4, 192)])
+    ("qwen3-8b", 4, 48), ("deepseek-coder-6.7b", 4, 192),
+    # the latent pool: 20 heads over one leaf of rows 640 wide, a table of
+    # 256 blocks (max_len 4096), the dense layer and two expert layers
+    ("glm-4.7-flash", 3, 48), ("glm-4.7-flash", 3, 192)])
 def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
                                                      entries, monkeypatch):
     """``_paged_fused_step`` at a preset's widths, 48 rows of 64 blocks,
-    bf16, with ``paged_attention_rows`` compiled by Mosaic (the test
-    says "on a TPU": the backend here is the CPU). The qwen cases are the
-    benchmark cells' shapes."""
+    bf16, with ``paged_attention_rows`` (a latent pool:
+    ``paged_latent_attention_rows``) compiled by Mosaic (the test
+    says "on a TPU": the backend here is the CPU). The qwen and glm cases
+    are the benchmark cells' shapes."""
     from senweaver_ide_tpu.ops import paged_attention
     monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
-    c = get_config(model)
+    latent = model == "glm-4.7-flash"
+    c = _benchmark_config(model) if latent else get_config(model)
     if layers:
         c = dataclasses.replace(c, num_layers=layers)
     on_chip = lambda tree: jax.tree_util.tree_map(
@@ -308,9 +320,9 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
         tree)
     params = on_chip(jax.eval_shape(
         lambda: tf.init_params(c, jax.random.PRNGKey(0))))
-    pool = on_chip(jax.eval_shape(
-        lambda: init_paged_pool(c, 3328 if layers is None else 832, 16)))
-    rows, width = 48, 64
+    rows, width = 48, 256 if latent else 64
+    pool = on_chip(jax.eval_shape(lambda: init_paged_pool(
+        c, 52 * width if layers is None or latent else 832, 16)))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=one_v5e)
     cache = jax.config.jax_enable_compilation_cache
@@ -332,19 +344,31 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
     logits_bytes = entries * c.vocab_size * 4
     assert (compiled.memory_analysis().temp_size_in_bytes
             < logits_bytes + layer_bytes / 2)
+    name = "paged_latent_attention_rows" if latent else "paged_attention_rows"
     calls = [line for line in text.splitlines()
-             if "tpu_custom_call" in line and "paged_attention_rows" in line]
+             if "tpu_custom_call" in line and f"%{name}." in line]
     assert calls, "the kernel is not in the compiled step"
+    # the whole stacked leaf is the kernel's operand: twice (k, v); the
+    # latent pool's one leaf once, as the kernel takes it (a lone head is
+    # no axis of a block)
+    operand = leaf
+    if latent:
+        operand = ",".join(map(str, pool.k.shape[:3] + pool.k.shape[4:]))
     for line in calls:
-        # the whole stacked leaf is the kernel's operand, twice (k, v)
-        assert line.count(f"bf16[{leaf}]") == 2, line
+        assert line.count(f"bf16[{operand}]") == (1 if latent else 2), line
     for line in text.splitlines():
-        if f"bf16[{leaf}]" in line.split("=")[0] or \
-                f"= bf16[{leaf}]" in line:
-            assert " copy(" not in line and "copy-start" not in line, line
-    # nothing of the gather's size is left: (entries x width, 16, Hkv, Dh)
+        for shape in {leaf, operand}:
+            if f"bf16[{shape}]" in line.split("=")[0] or \
+                    f"= bf16[{shape}]" in line:
+                assert (" copy(" not in line
+                        and "copy-start" not in line), line
+    # nothing of the gather's size is left: (entries x width, 16, Hkv, Dh),
+    # the latent rows' (entries x width, 16, row) and the scores over them
     assert (f"bf16[{entries * width},16,{c.num_kv_heads},{c.head_dim}]"
             not in text)
+    if latent:
+        assert f"bf16[{entries * width},16,{c.latent_row_dim}]" not in text
+        assert f"f32[{entries},{c.num_heads},{width * 16}]" not in text
 
 
 def _preset_head_shapes():
