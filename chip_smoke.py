@@ -515,7 +515,7 @@ def paged_rows_checks(hq: int, hkv: int, d: int, tag: str, interpret: bool,
         tbl = tables[seq_row]
         valid = jnp.arange(mb * bs)[None, :] < positions[:, None] + 1
         if latent_rank:
-            # _paged_mla_layer's gather path
+            # _paged_mla_attend's gather path
             seq = leaves[0][layer, tbl].reshape(-1, mb * bs, d)
             scores = jnp.einsum("thc,tsc->ths", q, seq,
                                 preferred_element_type=jnp.float32) * scale

@@ -11,7 +11,8 @@ no attention biases).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+import math
+from typing import Optional, Union
 
 import jax.numpy as jnp
 
@@ -31,6 +32,20 @@ class LatentCacheUnsupported(NotImplementedError):
         self.mechanism = mechanism
 
 
+class ResidualStreamUnsupported(NotImplementedError):
+    """A mechanism that has no form yet for a configuration whose residual
+    path is ``hc_mult`` streams wide (manifold-constrained
+    hyper-connections, ``models.transformer._residual``). Raised where the
+    mechanism is asked for, never replaced by the plain residual add.
+    ``mechanism`` names it."""
+
+    def __init__(self, mechanism: str, config_name: str):
+        super().__init__(
+            f"{mechanism} is not implemented for the multi-stream residual "
+            f"(hc_mult > 0) of configuration {config_name!r}")
+        self.mechanism = mechanism
+
+
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
     """Llama-3-style NTK-by-parts RoPE scaling (HF ``rope_type: llama3``).
@@ -41,6 +56,30 @@ class RopeScaling:
     low_freq_factor: float = 1.0
     high_freq_factor: float = 4.0
     original_max_position: int = 8192
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (HF ``rope_scaling.type: yarn``, the DeepSeek-V2
+    form): frequencies whose wavelength fits the original window
+    ``beta_fast`` times or more are kept, those that fit it ``beta_slow``
+    times or less are divided by ``factor``, a linear ramp over the pair
+    index between (``ops.rotary.scale_frequencies_yarn``). cos and sin are
+    multiplied by m(``mscale``) / m(``mscale_all_dim``) and the softmax
+    scale of latent attention by m(``mscale_all_dim``)^2, with
+    m(k) = 0.1 k ln(factor) + 1 (``ModelConfig.attn_scale``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def magnitude(self, k: float) -> float:
+        """YaRN's m(k): 1 where nothing is stretched."""
+        if self.factor <= 1.0 or not k:
+            return 1.0
+        return 0.1 * k * math.log(self.factor) + 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,8 +94,10 @@ class ModelConfig:
     head_dim: int
     max_seq_len: int
     rope_theta: float = 10000.0
-    # Llama-3.1+ long-context frequency scaling; None = plain RoPE.
-    rope_scaling: Optional[RopeScaling] = None
+    # Long-context frequency scaling: Llama-3.1+'s NTK-by-parts
+    # (``RopeScaling``) or YaRN (``YarnScaling``, latent attention only);
+    # None = plain RoPE.
+    rope_scaling: Optional[Union[RopeScaling, YarnScaling]] = None
     rms_norm_eps: float = 1e-6
     tie_word_embeddings: bool = False
     qkv_bias: bool = False
@@ -144,10 +185,38 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Manifold-constrained hyper-connections (mHC): the residual stream is
+    # ``hc_mult`` rows wide and every sublayer reads and writes it through
+    # three maps computed from the token's own stream, one of them made
+    # doubly stochastic by ``hc_sinkhorn_iters`` rounds of column-then-row
+    # normalisation (``hc_eps`` in each divisor) of the exponential of its
+    # logits clipped to [``mhc_h_res_clamp_min``, ``mhc_h_res_clamp_max``]
+    # (``models.transformer._residual``). 0 = the plain ``x + f(x)``.
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
 
     @property
     def mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def attn_scale(self) -> float:
+        """Softmax scale of latent attention: 1/sqrt of the q/k head width,
+        times YaRN's m(``mscale_all_dim``)^2 where the rotary is YaRN's."""
+        scale = 1.0 / float(self.head_dim) ** 0.5
+        if isinstance(self.rope_scaling, YarnScaling):
+            scale *= self.rope_scaling.magnitude(
+                self.rope_scaling.mscale_all_dim) ** 2
+        return scale
+
+    @property
+    def hc_maps(self) -> int:
+        """Values the stream is projected to for one sublayer's maps:
+        H_pre (n), H_post (n), H_res (n x n)."""
+        return self.hc_mult * (self.hc_mult + 2)
 
     @property
     def latent_dim(self) -> int:
@@ -291,6 +360,24 @@ def tiny_glm_moe_test() -> ModelConfig:
         qk_rope_head_dim=4, v_head_dim=16)
 
 
+def tiny_xing_mhc_test() -> ModelConfig:
+    """Xing4.0's layer (``xing4_0``) at test size: ``tiny-glm-moe-test``'s
+    latent attention and experts with two leading dense layers, a residual
+    stream of 4 rows mixed by Sinkhorn maps of 4 rounds (unrolled, the
+    published 20 take XLA's CPU backend half a minute a program to
+    compile; ``sinkhorn`` itself is tested at 20), and YaRN rotary over 4
+    rotary pairs of which the first is kept, the second half stretched and
+    the last two stretched 8 times (lo 0, hi 2)."""
+    return dataclasses.replace(
+        tiny_glm_moe_test(), name="tiny-xing-mhc-test", num_layers=4,
+        first_dense_layers=2, rope_theta=10_000.0, rms_norm_eps=1e-6,
+        routed_scaling_factor=2.0, qk_rope_head_dim=8, head_dim=16,
+        rope_scaling=YarnScaling(factor=8.0, original_max_position=16,
+                                 beta_fast=2.0, beta_slow=0.25,
+                                 mscale=1.0, mscale_all_dim=1.0),
+        hc_mult=4, hc_sinkhorn_iters=4)
+
+
 def tiny_test() -> ModelConfig:
     """Small config for unit tests and CPU-mesh dry runs."""
     return ModelConfig(
@@ -379,6 +466,7 @@ PRESETS = {
     "tiny-test": tiny_test,
     "tiny-moe-test": tiny_moe_test,
     "tiny-glm-moe-test": tiny_glm_moe_test,
+    "tiny-xing-mhc-test": tiny_xing_mhc_test,
     "small-test": small_test,
 }
 

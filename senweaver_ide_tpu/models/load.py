@@ -31,7 +31,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .config import ModelConfig
+from .config import ModelConfig, ResidualStreamUnsupported
 from .transformer import Params
 
 __all__ = ["load_hf_params", "export_hf_params", "available_hf_keys"]
@@ -96,6 +96,9 @@ def load_hf_params(model_dir: str, config: ModelConfig, *,
     import jax.numpy as jnp
 
     c = config
+    if c.hc_mult:
+        # the checkpoint's names for the maps' leaves are not known here
+        raise ResidualStreamUnsupported("the HF loader", c.name)
     dtype = dtype or c.dtype
     raw = _load_raw(model_dir)
     D, F, L, V = c.hidden_size, c.intermediate_size, c.num_layers, c.vocab_size
@@ -189,6 +192,8 @@ def export_hf_params(params: Params, config: ModelConfig,
 
     from .quantize import is_quantized
 
+    if config.hc_mult:
+        raise ResidualStreamUnsupported("the HF exporter", config.name)
     if is_quantized(params):
         # transposing the +/-127 codes without their scales would write a
         # garbage checkpoint that loads cleanly elsewhere

@@ -25,7 +25,8 @@ Architectures covered: Qwen2.5-Coder (GQA + QKV bias, tied embeddings at
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, NamedTuple, Optional, Tuple
+import math
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +35,8 @@ import numpy as np
 from ..ops.attention import NEG_INF, attention
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
-from .config import LatentCacheUnsupported, ModelConfig
+from .config import (LatentCacheUnsupported, ModelConfig,
+                     ResidualStreamUnsupported, YarnScaling)
 from .moe import BANKS, MoEStats, expert_ffn
 
 Params = Dict[str, Any]
@@ -225,6 +227,27 @@ def _init_layer_stack(c: ModelConfig, key: jax.Array, L: int,
     if c.qk_norm and not c.mla:
         layers["q_norm"] = jnp.ones((L, c.head_dim), c.dtype)
         layers["k_norm"] = jnp.ones((L, c.head_dim), c.dtype)
+    if c.hc_mult:
+        # The residual operator's leaves (``_residual``), one set a
+        # sublayer, all float32: the projection phi like any matrix; the
+        # three gates alpha 0, so that the maps are their biases alone;
+        # biases that read the mean of the rows (sum H_pre = 1), write the
+        # sublayer's output to every row (H_post = 1) and mix the rows
+        # evenly. Rows that start equal then stay equal and a fresh model
+        # computes the plain model's function. The gates' name ends in
+        # ``norm`` for the reason ``router_bias_norm``'s does: a
+        # seeded-weights filler leaves them a constant.
+        n, m = c.hc_mult, c.hc_maps
+        b_pre = -math.log(n - 1.0) if n > 1 else 30.0
+        bias = jnp.concatenate([jnp.full((n,), b_pre, jnp.float32),
+                                jnp.zeros((m - n,), jnp.float32)])
+        for i, sub in enumerate(("attn", "mlp")):
+            kp = jax.random.fold_in(key, 100 + i)
+            layers[f"{sub}_hc_phi"] = (
+                jax.random.normal(kp, (L, n * D, m), jnp.float32)
+                / float(n * D) ** 0.5)
+            layers[f"{sub}_hc_gate_norm"] = jnp.zeros((L, 3), jnp.float32)
+            layers[f"{sub}_hc_bias"] = jnp.broadcast_to(bias, (L, 1, m))
     return layers
 
 
@@ -384,7 +407,7 @@ def _mla_self_attention(c: ModelConfig, lp: Dict[str, jax.Array],
     per-head keys and values are made from its latent, then plain causal
     attention with q/k width nope + rope and value width ``v_head_dim``.
     h (B, S, D) -> (B, S, H * v). The trainer's and the scorer's path; the
-    paged engine reads its cache in the absorbed form (``_paged_mla_layer``)."""
+    paged engine reads its cache in the absorbed form (``_paged_mla_attend``)."""
     b, s, _ = h.shape
     r = c.kv_lora_rank
     q_nope, q_rope, latent = _mla_project(c, lp, h, cos, sin)
@@ -401,7 +424,7 @@ def _mla_self_attention(c: ModelConfig, lp: Dict[str, jax.Array],
         axis=-1)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=prec,
                         preferred_element_type=jnp.float32)
-    scores = scores * (1.0 / float(c.head_dim) ** 0.5)
+    scores = scores * c.attn_scale
     mask = jnp.tril(jnp.ones((s, s), bool))[None, None]
     if kv_mask is not None:
         mask = mask & kv_mask[:, None, None, :]
@@ -471,11 +494,128 @@ def _cache_attention(c: ModelConfig, q, k_full, v_full, length, kv_mask,
                      causal=True, window=c.sliding_window)
 
 
+def sinkhorn(logits: jax.Array, c: ModelConfig) -> jax.Array:
+    """(..., n, n) float32 logits -> ``c.hc_sinkhorn_iters`` rounds of
+    Sinkhorn-Knopp on ``exp(clip(logits))``: each round divides every
+    column by its sum + ``hc_eps``, then every row by its sum + ``hc_eps``.
+
+    The matrix is taken apart into its n x n entries, each an array over
+    the tokens, and the rounds are unrolled over them: every sum is n - 1
+    adds of equal shapes, so the whole chain is elementwise and XLA:TPU
+    cuts it into about seven fusions. Written with ``sum(axis)`` each of
+    the 40 sums is a reduction, a fusion of its own: 80 launches a
+    sublayer (compiled for the v5e, PERF.md section 4). XLA's CPU backend
+    takes ~16 s to compile the 20 unrolled rounds: tests that compile a
+    model use fewer (``tiny_xing_mhc_test``)."""
+    n = logits.shape[-1]
+    e = jnp.exp(jnp.clip(logits, c.mhc_h_res_clamp_min,
+                         c.mhc_h_res_clamp_max))
+    m = [[e[..., i, j] for j in range(n)] for i in range(n)]
+    for _ in range(c.hc_sinkhorn_iters):
+        col = [sum(m[i][j] for i in range(n)) + c.hc_eps for j in range(n)]
+        m = [[m[i][j] / col[j] for j in range(n)] for i in range(n)]
+        row = [sum(m[i]) + c.hc_eps for i in range(n)]
+        m = [[m[i][j] / row[i] for j in range(n)] for i in range(n)]
+    return jnp.stack([jnp.stack(r, axis=-1) for r in m], axis=-2)
+
+
+def _stream_maps(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+                 sub: str):
+    """The three maps of one sublayer from the token's own stream x
+    (..., n, D), in float32 whatever the stream's dtype: H_pre (..., n) in
+    (0, 1), H_post (..., n) in (0, 2), H_res (..., n, n) doubly stochastic
+    (``sinkhorn``), and the largest distance of a row or column sum of any
+    H_res from 1. The gain-free RMSNorm over all n * D values is a scalar
+    a token, applied to the 24 projected values instead of the stream."""
+    n = c.hc_mult
+    flat = x.reshape(x.shape[:-2] + (n * x.shape[-1],)).astype(jnp.float32)
+    inv = jax.lax.rsqrt(jnp.mean(flat * flat, axis=-1, keepdims=True)
+                        + c.rms_norm_eps)
+    u = jnp.einsum("...k,km->...m", flat, lp[f"{sub}_hc_phi"],
+                   precision=jax.lax.Precision.HIGHEST) * inv
+    gate, bias = lp[f"{sub}_hc_gate_norm"], lp[f"{sub}_hc_bias"][0]
+    h_pre = jax.nn.sigmoid(u[..., :n] * gate[0] + bias[:n])
+    h_post = 2.0 * jax.nn.sigmoid(u[..., n:2 * n] * gate[1]
+                                  + bias[n:2 * n])
+    res = u[..., 2 * n:] * gate[2] + bias[2 * n:]
+    h_res = sinkhorn(res.reshape(res.shape[:-1] + (n, n)), c)
+    off = [jnp.abs(sum(h_res[..., i, j] for j in range(n)) - 1.0)
+           for i in range(n)]
+    off += [jnp.abs(sum(h_res[..., i, j] for i in range(n)) - 1.0)
+            for j in range(n)]
+    return h_pre, h_post, h_res, functools.reduce(jnp.maximum, off).max()
+
+
+def _residual(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+              sub: str, f: Callable):
+    """One sublayer through the residual path, the only place a layer
+    adds to it. ``f`` is the sublayer with its norm: its input (..., D) ->
+    (its output (..., D), whatever else it yields). Returns (x', that, the
+    Sinkhorn error of this sublayer's H_res — None on the plain path).
+
+    ``hc_mult == 0``: ``x + f(x)``.
+
+    Otherwise x is the stream (..., n, D) and ``lp[sub + "_hc_*"]`` the
+    sublayer's maps (manifold-constrained hyper-connections):
+
+        y  = f(sum_j H_pre[j] x[j])
+        x'[i] = sum_j H_res[i, j] x[j] + H_post[i] y
+
+    The maps are float32 (``_stream_maps``) and so are the two mixes,
+    written as n (n + 1) scaled adds over the rows so that each is one
+    elementwise pass over the stream, not a product the MXU would round to
+    bf16; the stream itself is held in x's dtype. The scope names
+    (``mhc.maps``, ``mhc.pre``, ``mhc.post``) are docs/observability.md's.
+    """
+    if not c.hc_mult:
+        y, extra = f(x)
+        return x + y, extra, None
+    n = c.hc_mult
+    with jax.named_scope("mhc.maps"):
+        h_pre, h_post, h_res, err = _stream_maps(c, lp, x, sub)
+    rows = [x[..., j, :].astype(jnp.float32) for j in range(n)]
+    with jax.named_scope("mhc.pre"):
+        x_in = sum(h_pre[..., j, None] * rows[j] for j in range(n))
+    y, extra = f(x_in.astype(x.dtype))
+    with jax.named_scope("mhc.post"):
+        y = y.astype(jnp.float32)
+        x = jnp.stack(
+            [sum(h_res[..., i, j, None] * rows[j] for j in range(n))
+             + h_post[..., i, None] * y for i in range(n)],
+            axis=-2).astype(x.dtype)
+    return x, extra, err
+
+
+def _stream_open(c: ModelConfig, x: jax.Array) -> jax.Array:
+    """The embedding (..., D) -> the residual stream the layers carry: the
+    same array, or with ``hc_mult`` streams the embedding in every row."""
+    if not c.hc_mult:
+        return x
+    return jnp.broadcast_to(x[..., None, :],
+                            x.shape[:-1] + (c.hc_mult, x.shape[-1]))
+
+
+def _stream_close(c: ModelConfig, x: jax.Array) -> jax.Array:
+    """The stream after the last layer -> (..., D) for the final norm: the
+    sum of its rows."""
+    if not c.hc_mult:
+        return x
+    return x.astype(jnp.float32).sum(-2).astype(x.dtype)
+
+
+def _worst(a, b):
+    """The larger of two Sinkhorn errors, either of which may be None."""
+    if a is None or b is None:
+        return a if b is None else b
+    return jnp.maximum(a, b)
+
+
 def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
            cos: jax.Array, sin: jax.Array,
            cache_kv: Optional[Tuple[jax.Array, jax.Array, jax.Array]],
            kv_mask, mesh=None, flash_decode_ok: bool = False):
-    """One transformer block. x: (B, S, D).
+    """One transformer block. x: (B, S, D), or the residual stream
+    (B, S, hc_mult, D) of a multi-stream configuration (``_residual``).
 
     Without cache_kv: full self-attention over the block's own k/v, via the
     ``c.attn_impl`` kernel (einsum / flash / ring / ulysses — the latter two
@@ -485,6 +625,19 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     — in the no-cache case the returned pair is the block's own (k, v);
     aux is the MoE load-balancing loss (0 for dense layers).
     """
+    x, kv_out, _ = _residual(
+        c, lp, x, "attn", lambda x_in: _attend(
+            c, lp, x_in, cos, sin, cache_kv, kv_mask, mesh, flash_decode_ok))
+    x, aux, _, _ = _mlp(c, lp, x)
+    return x, kv_out, aux
+
+
+def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+            cos: jax.Array, sin: jax.Array, cache_kv, kv_mask, mesh,
+            flash_decode_ok: bool):
+    """``_layer``'s attention sublayer, norm to output projection: x
+    (B, S, D), what the residual path hands it -> (attention's output
+    (B, S, D), the cache pair ``_layer`` returns)."""
     b, s, _ = x.shape
     h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
     if c.mla:       # no cache here: _forward_impl refuses one
@@ -493,9 +646,7 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                 f"attn_impl={c.attn_impl!r} / sliding_window="
                 f"{c.sliding_window} in the no-cache forward", c.name)
         out = _mla_self_attention(c, lp, h, cos, sin, kv_mask)
-        x = x + _dense(out, lp, "wo", "bse,ed->bsd")
-        x, aux, _ = _mlp(c, lp, x)
-        return x, (None, None), aux
+        return _dense(out, lp, "wo", "bse,ed->bsd"), (None, None)
     q, k, v = _qkv(c, lp, h, cos, sin)
 
     if cache_kv is not None and len(cache_kv) == 5:
@@ -595,9 +746,7 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
         out = _self_attention(c, q, k, v, kv_mask, mesh)
         kv_out = (k, v)
 
-    x = x + _dense(out.reshape(b, s, c.q_dim), lp, "wo", "bse,ed->bsd")
-    x, aux, _ = _mlp(c, lp, x)
-    return x, kv_out, aux
+    return _dense(out.reshape(b, s, c.q_dim), lp, "wo", "bse,ed->bsd"), kv_out
 
 
 def _swiglu(h: jax.Array, lp: Dict[str, jax.Array], gate: str, up: str,
@@ -615,30 +764,45 @@ def _mlp(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     layer bodies: a dense SwiGLU, or where the layer's params hold a
     ``router`` the dropless expert layer of ``models/moe.py`` plus the
     shared expert (a layer stack is all of one kind; a configuration with
-    leading dense layers has two stacks). Returns (x + ffn(norm(x)), moe
+    leading dense layers has two stacks). Returns (x + ffn(norm(x)) — or
+    what the multi-stream residual path makes of it, ``_residual`` — moe
     aux loss — 0 for dense layers and for a ``sigmoid_bias`` router,
-    ``MoEStats`` over the entries ``count`` marks — None for dense).
+    ``MoEStats`` over the entries ``count`` marks — None for dense,
+    ``_residual``'s Sinkhorn error — None for the plain path).
     ``stack_layer``: the expert banks in ``lp`` are the whole stack's and
     this is the layer's index in it (``moe._grouped``)."""
-    h = rms_norm(x, lp["mlp_norm"], c.rms_norm_eps)
-    if "router" not in lp:
-        return (x + _swiglu(h, lp, "w_gate", "w_up", "w_down"),
-                jnp.zeros((), jnp.float32), None)
-    b, s, d = h.shape
-    y, aux, stats = expert_ffn(c, lp, h.reshape(b * s, d), count,
-                               stack_layer)
-    y = y.reshape(b, s, d)
-    if "ws_gate" in lp:
-        with jax.named_scope("moe.shared"):
-            y = y + _swiglu(h, lp, "ws_gate", "ws_up", "ws_down").astype(
-                jnp.float32)
-    return x + y.astype(x.dtype), aux, stats
+    def ffn(x_in):
+        h = rms_norm(x_in, lp["mlp_norm"], c.rms_norm_eps)
+        if "router" not in lp:
+            return (_swiglu(h, lp, "w_gate", "w_up", "w_down"),
+                    (jnp.zeros((), jnp.float32), None))
+        b, s, d = h.shape
+        y, aux, stats = expert_ffn(c, lp, h.reshape(b * s, d), count,
+                                   stack_layer)
+        y = y.reshape(b, s, d)
+        if "ws_gate" in lp:
+            with jax.named_scope("moe.shared"):
+                y = y + _swiglu(h, lp, "ws_gate", "ws_up",
+                                "ws_down").astype(jnp.float32)
+        return y.astype(x_in.dtype), (aux, stats)
+
+    x, (aux, stats), err = _residual(c, lp, x, "mlp", ffn)
+    return x, aux, stats, err
 
 
-def _rope_dim(c: ModelConfig) -> int:
-    """Width the rotary tables are made for: the whole head, or under
-    latent attention the decoupled rotary part alone."""
-    return c.qk_rope_head_dim if c.mla else c.head_dim
+def _rope_tables(c: ModelConfig, positions: jax.Array):
+    """cos / sin for ``positions``, made for the whole head, or under
+    latent attention for the decoupled rotary part alone. YaRN scaling
+    also changes the softmax scale (``ModelConfig.attn_scale``), which only
+    the latent attention paths read: any other configuration with it is
+    refused."""
+    if isinstance(c.rope_scaling, YarnScaling) and not c.mla:
+        raise NotImplementedError(
+            f"{c.name}: YaRN rotary scaling is implemented for latent "
+            f"attention only (its softmax scale carries mscale_all_dim)")
+    return rope_cos_sin(positions,
+                        c.qk_rope_head_dim if c.mla else c.head_dim,
+                        c.rope_theta, scaling=c.rope_scaling)
 
 
 def _layer_stacks(params: Params) -> Tuple[Dict[str, jax.Array], ...]:
@@ -692,7 +856,12 @@ def forward(
 def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
                   mesh=None, fresh_cache=False):
     b, s = tokens.shape
-    x = params["embed"][tokens]  # gather; sharded vocab → XLA collective
+    if c.hc_mult and (cache is not None or mesh is not None):
+        raise ResidualStreamUnsupported(
+            "forward(cache=...) over the slot KVCache" if cache is not None
+            else "forward(mesh=...)", c.name)
+    # gather; sharded vocab → XLA collective
+    x = _stream_open(c, params["embed"][tokens])
 
     if positions is None:
         base = cache.length if cache is not None else jnp.zeros((), jnp.int32)
@@ -700,8 +869,7 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
             base = base[:, None]                       # per-slot lengths
         positions = base + jnp.arange(s, dtype=jnp.int32)[None, :]
         positions = jnp.broadcast_to(positions, (b, s))
-    cos, sin = rope_cos_sin(positions, _rope_dim(c), c.rope_theta,
-                            scaling=c.rope_scaling)
+    cos, sin = _rope_tables(c, positions)
 
     if cache is not None and c.mla:
         raise LatentCacheUnsupported(
@@ -860,7 +1028,7 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
                 (params["layers"], cache.k, cache.v), unroll=c.scan_unroll)
             new_cache = KVCache(k=k_upd, v=v_upd, length=cache.length + s)
 
-    x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+    x = rms_norm(_stream_close(c, x), params["final_norm"], c.rms_norm_eps)
     head = params.get("lm_head")
     if head is None:  # tied embeddings
         if "tied_head_q8" in params:
@@ -900,9 +1068,12 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     indirection ``(layer, tables[seq_row[t]])``. The scatter lands
     before the gather, so a chunk's later tokens see its earlier ones at
     the same layer — flat-batch chunked prefill is exactly block
-    prefill. Returns ``(x, leaves')``: the caller carries the
-    leaves through its layer scan, so a donated pool is updated in
-    place.
+    prefill. Returns ``(x, leaves', MoEStats or None, the residual
+    path's Sinkhorn error or None)``: the caller carries the leaves
+    through its layer scan, so a donated pool is updated in place. Both
+    sublayers go through ``_residual``: x is (T, 1, D), or the stream
+    (T, 1, hc_mult, D) of a multi-stream configuration. A latent
+    configuration's attention sublayer is ``_paged_mla_attend``.
 
     With a ``row_plan`` (``ops.paged_attention.plan_rows`` of this
     batch; ``forward_paged`` makes one where the kernel runs) an
@@ -925,10 +1096,28 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     ``attn.kv_gather`` exists on the gather path alone; the kernel is
     ``paged_attention_rows`` in a trace.
     """
-    if c.mla:
-        return _paged_mla_layer(c, lp, x, cos, sin, leaves, layer, tables,
-                                seq_row, positions, write_block, write_off,
-                                stack_layer, row_plan)
+    attend = _paged_mla_attend if c.mla else _paged_attend
+    x, leaves, err = _residual(
+        c, lp, x, "attn", lambda x_in: attend(
+            c, lp, x_in, cos, sin, leaves, layer, tables, seq_row,
+            positions, write_block, write_off, use_kernel=use_kernel,
+            adapters=adapters, adapter_ids=adapter_ids, row_plan=row_plan))
+    with jax.named_scope("mlp"):
+        x, _, stats, mlp_err = _mlp(
+            c, lp, x, _writes(lp, write_block, leaves[0]), stack_layer)
+    return x, leaves, stats, _worst(err, mlp_err)
+
+
+def _paged_attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+                  cos: jax.Array, sin: jax.Array,
+                  leaves: Tuple[jax.Array, ...], layer: jax.Array,
+                  tables: jax.Array, seq_row: jax.Array,
+                  positions: jax.Array, write_block: jax.Array,
+                  write_off: jax.Array, use_kernel: bool = False,
+                  adapters=None, adapter_ids=None, row_plan=None):
+    """``_paged_layer``'s attention sublayer over (k, v) leaves, norm to
+    output projection: x (T, 1, D), what the residual path hands it ->
+    (attention's output (T, 1, D), leaves')."""
     t = x.shape[0]
     quantized = len(leaves) == 4
     with jax.named_scope("attn.qkv"):
@@ -996,11 +1185,7 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
         attn_out = _dense(attn_in, lp, "wo", "bse,ed->bsd")
         attn_out = _with_adapter(attn_out, attn_in, adapters, adapter_ids,
                                  "wo")
-        x = x + attn_out
-    with jax.named_scope("mlp"):
-        x, _, stats = _mlp(c, lp, x, _writes(lp, write_block, leaves[0]),
-                           stack_layer)
-    return x, leaves, stats
+    return attn_out, leaves
 
 
 def _writes(lp, write_block: jax.Array, leaf: jax.Array):
@@ -1009,13 +1194,14 @@ def _writes(lp, write_block: jax.Array, leaf: jax.Array):
     return write_block < leaf.shape[1] if "router" in lp else None
 
 
-def _paged_mla_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
-                     cos: jax.Array, sin: jax.Array,
-                     leaves: Tuple[jax.Array, ...], layer: jax.Array,
-                     tables: jax.Array, seq_row: jax.Array,
-                     positions: jax.Array, write_block: jax.Array,
-                     write_off: jax.Array, stack_layer=None, row_plan=None):
-    """``_paged_layer`` for latent attention: the pool's one payload leaf
+def _paged_mla_attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+                      cos: jax.Array, sin: jax.Array,
+                      leaves: Tuple[jax.Array, ...], layer: jax.Array,
+                      tables: jax.Array, seq_row: jax.Array,
+                      positions: jax.Array, write_block: jax.Array,
+                      write_off: jax.Array, use_kernel=None, adapters=None,
+                      adapter_ids=None, row_plan=None):
+    """``_paged_attend`` for latent attention: the pool's one payload leaf
     ``(L, num_blocks, block_size, 1, latent_row_dim)`` holds a token's
     ``[c_kv | k_rope | 0...]`` row (zero-padded to whole lane tiles, see
     ``ModelConfig.latent_row_dim``), written in place like a kv-head's row,
@@ -1059,8 +1245,9 @@ def _paged_mla_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
         # runs over whole tiles and no slice of the gathered rows is made
         q_cat = jnp.pad(jnp.concatenate([q_abs, q_rope[:, 0]], axis=-1),
                         pad)
-    # the scale is the model's (its q/k head width), not the row's
-    scale = 1.0 / float(c.head_dim) ** 0.5
+    # the scale is the model's (its q/k head width, YaRN's magnitude),
+    # not the row's
+    scale = c.attn_scale
     if row_plan is not None:
         from ..ops.paged_attention import paged_latent_attention_rows
         with jax.named_scope("attn.scores"):
@@ -1087,12 +1274,9 @@ def _paged_mla_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     with jax.named_scope("attn.out"):
         out = jnp.einsum("thr,rhv->thv", ctx.astype(x.dtype), w_vb,
                          precision=prec)
-        x = x + _dense(out.reshape(t, 1, c.num_heads * c.v_head_dim), lp,
-                       "wo", "bse,ed->bsd")
-    with jax.named_scope("mlp"):
-        x, _, stats = _mlp(c, lp, x, _writes(lp, write_block, leaf),
-                           stack_layer)
-    return x, (leaf,), stats
+        out = _dense(out.reshape(t, 1, c.num_heads * c.v_head_dim), lp,
+                     "wo", "bse,ed->bsd")
+    return out, (leaf,)
 
 
 def forward_paged(
@@ -1114,6 +1298,7 @@ def forward_paged(
     adapters=None,                # per-rung LoRA bank dicts, leading L
     adapter_ids=None,             # per-rung (T,) int32 slot ids
     with_moe_stats: bool = False,  # static: also return MoEStats
+    with_mhc_stats: bool = False,  # static: also return the Sinkhorn error
 ):
     """Run the model over a paged KV pool: every entry of the flat
     ``(T,)`` token batch is one (sequence, position) pair — a decode
@@ -1157,7 +1342,12 @@ def forward_paged(
     ``with_moe_stats=True`` (an expert configuration) returns a third
     value, ``MoEStats`` summed over the expert layers (``experts_touched``)
     and their largest (``expert_load_max``), counted over the entries that
-    write a cache row: padding is routed, and is not work."""
+    write a cache row: padding is routed, and is not work.
+
+    ``with_mhc_stats=True`` (a multi-stream configuration, ``hc_mult``)
+    returns one more value after it: the largest ``|row sum - 1|`` or
+    ``|column sum - 1|`` of any H_res the call computed (``_residual``),
+    a float32 scalar."""
     c = config
     if c.matmul_precision is not None:
         with jax.default_matmul_precision(c.matmul_precision):
@@ -1173,7 +1363,9 @@ def forward_paged(
             seq_row=seq_row, positions=positions, write_block=write_block,
             write_off=write_off, use_kernel=use_kernel, adapters=adapters,
             adapter_ids=adapter_ids)
-    return out if with_moe_stats else out[:2]
+    logits, pool, moe, err = out
+    return ((logits, pool) + ((moe,) if with_moe_stats else ())
+            + ((err,) if with_mhc_stats else ()))
 
 
 def reads_pool_in_place(c: ModelConfig, use_kernel: Optional[bool]) -> bool:
@@ -1198,12 +1390,15 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                         seq_row, positions, write_block, write_off,
                         use_kernel, adapters=None, adapter_ids=None):
     with jax.named_scope("embed"):
-        x = params["embed"][tokens][:, None, :]        # (T, 1, D)
-        cos, sin = rope_cos_sin(positions[:, None], _rope_dim(c),
-                                c.rope_theta, scaling=c.rope_scaling)
+        # (T, 1, D), or the stream (T, 1, hc_mult, D)
+        x = _stream_open(c, params["embed"][tokens][:, None, :])
+        cos, sin = _rope_tables(c, positions[:, None])
     if c.mla and (adapters is not None or pool.k_scale is not None):
         raise LatentCacheUnsupported(
             "adapter banks / a quantized pool in forward_paged", c.name)
+    if c.hc_mult and adapters is not None:
+        raise ResidualStreamUnsupported("adapter banks in forward_paged",
+                                        c.name)
     # STATIC under jit: derived from pytree structure (None-ness and
     # shapes), so the precision ladder never adds a trace argument.
     n_hi = 0 if pool.k_hi is None else pool.k_hi.shape[0]
@@ -1230,9 +1425,12 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         unpacks back to None) and the layer index, counted from
         ``first``: a stack that is not the leaves' first indexes them by
         its layers' absolute numbers. An expert stack also carries and
-        returns its ``MoEStats`` (None for a dense stack)."""
+        returns its ``MoEStats`` (None for a dense stack), and a
+        multi-stream configuration's the largest Sinkhorn error of its
+        sublayers (None for the plain residual)."""
         n = jax.tree_util.tree_leaves(layers)[0].shape[0]
         counts = banks = None
+        worst = jnp.zeros((), jnp.float32) if c.hc_mult else None
         if "router" in layers:
             counts = MoEStats(jnp.zeros((), jnp.int32),
                               jnp.zeros((), jnp.int32))
@@ -1243,11 +1441,11 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
             layers = {k: v for k, v in layers.items() if k not in BANKS}
 
         def body(carry, inputs):
-            x, leaves, acc = carry
+            x, leaves, acc, worst = carry
             lp, ad_l, layer = inputs
             if banks is not None:
                 lp = {**lp, **banks}
-            x, leaves, stats = _paged_layer(
+            x, leaves, stats, err = _paged_layer(
                 c, lp, x, cos, sin, leaves, layer, tables, seq_row,
                 positions, write_block, write_off, use_kernel=use_kernel,
                 adapters=ad_l, adapter_ids=adapter_ids,
@@ -1257,15 +1455,15 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                 acc = MoEStats(
                     acc.experts_touched + stats.experts_touched,
                     jnp.maximum(acc.expert_load_max, stats.expert_load_max))
-            return (x, leaves, acc), None
+            return (x, leaves, acc, _worst(worst, err)), None
 
         index = jnp.arange(first, first + n, dtype=jnp.int32)
-        (x, leaves, counts), _ = jax.lax.scan(body, (x, leaves, counts),
-                                              (layers, ad, index),
-                                              unroll=c.scan_unroll)
-        return x, leaves, counts
+        (x, leaves, counts, worst), _ = jax.lax.scan(
+            body, (x, leaves, counts, worst), (layers, ad, index),
+            unroll=c.scan_unroll)
+        return x, leaves, counts, worst
 
-    layers, lo_ad = params["layers"], adapters
+    layers, lo_ad, err = params["layers"], adapters, None
     names = ("k", "v") if pool.k_scale is None else (
         "k", "v", "k_scale", "v_scale")
     upd = {}
@@ -1277,7 +1475,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                                   lambda a: a[:n_hi])
         sl_lo = functools.partial(jax.tree_util.tree_map,
                                   lambda a: a[n_hi:])
-        x, (upd["k_hi"], upd["v_hi"]), hi_moe = scan_layers(
+        x, (upd["k_hi"], upd["v_hi"]), hi_moe, err = scan_layers(
             x, sl_hi(layers), sl_hi(adapters), (pool.k_hi, pool.v_hi))
         layers, lo_ad = sl_lo(layers), sl_lo(adapters)
     if c.mla:
@@ -1290,9 +1488,11 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                 "a kv_dtype_per_layer prefix or adapter banks over a "
                 "configuration with leading dense layers")
         # the leading dense-FFN stack, over the same carried leaves
-        x, leaves, _ = scan_layers(x, params["dense_layers"], None, leaves)
+        x, leaves, _, err = scan_layers(x, params["dense_layers"], None,
+                                        leaves)
         first = c.first_dense_layers
-    x, leaves, moe = scan_layers(x, layers, lo_ad, leaves, first)
+    x, leaves, moe, last_err = scan_layers(x, layers, lo_ad, leaves, first)
+    err = _worst(err, last_err)
     if n_hi and moe is not None:
         # the full-width prefix layers are expert layers of the same model
         moe = MoEStats(moe.experts_touched + hi_moe.experts_touched,
@@ -1301,7 +1501,8 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
     upd.update(zip(names, leaves))
 
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], c.rms_norm_eps)
+        x = rms_norm(_stream_close(c, x), params["final_norm"],
+                     c.rms_norm_eps)
         head = params.get("lm_head")
         if head is None:  # tied embeddings
             if "tied_head_q8" in params:
@@ -1311,7 +1512,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         else:
             logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
         logits = logits[:, 0].astype(jnp.float32)
-    return logits, pool._replace(**upd), moe
+    return logits, pool._replace(**upd), moe, err
 
 
 def count_params(params: Params) -> int:

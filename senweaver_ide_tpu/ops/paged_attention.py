@@ -53,7 +53,7 @@ How it is laid out:
   f32, probabilities cast to the value dtype for the PV product; f32
   inputs take the exact path (``Precision.HIGHEST``).
 
-*The latent pool* (multi-head latent attention, ``_paged_mla_layer``) has
+*The latent pool* (multi-head latent attention, ``_paged_mla_attend``) has
 ONE payload leaf, ``(L, num_blocks, block_size, 1, row)``: a token's
 ``[c_kv | k_rope | 0...]`` row is the key of every head and, in its
 first ``kv_lora_rank`` columns, the value too. The same program with

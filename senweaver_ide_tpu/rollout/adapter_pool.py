@@ -52,7 +52,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import LatentCacheUnsupported, ModelConfig
+from ..models.config import (LatentCacheUnsupported, ModelConfig,
+                             ResidualStreamUnsupported)
 from ..obs import get_registry
 
 # (in_dim, out_dim) per supported target. Attention-only by design:
@@ -128,6 +129,9 @@ class AdapterPool:
             raise LatentCacheUnsupported(
                 "the multi-LoRA adapter pool (its targets are wq/wk/wv/wo)",
                 config.name)
+        if config.hc_mult:
+            raise ResidualStreamUnsupported("the multi-LoRA adapter pool",
+                                            config.name)
         self.config = config
         self.pool_config = pool_config or AdapterPoolConfig()
         pc = self.pool_config

@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import LatentCacheUnsupported, ModelConfig
+from ..models.config import (LatentCacheUnsupported, ModelConfig,
+                             ResidualStreamUnsupported)
 from ..models.transformer import (KVCache, Params, forward, forward_paged,
                                   init_kv_cache, reads_pool_in_place)
 from ..obs import get_registry, get_tracer
@@ -265,23 +266,30 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     absmax scales through the SAME sentinel-guarded indices — no extra
     device round-trips, no new compile per occupancy bucket (the scale
     tensors are shape-static alongside the payloads)."""
-    logits, pool, *moe = forward_paged(
+    logits, pool, *stats = forward_paged(
         params, config, tokens, pool=pool,
         tables=tables, seq_row=seq_row, positions=positions,
         write_block=write_block, write_off=write_off,
         use_kernel=use_kernel, adapters=adapters, adapter_ids=adapter_ids,
-        with_moe_stats=config.num_experts > 0)
+        with_moe_stats=config.num_experts > 0,
+        with_mhc_stats=config.hc_mult > 0)
     next_tok = sample_token(logits, key, temperature=sample.temperature,
                             top_k=sample.top_k, top_p=sample.top_p)
     logp = sampled_logprob(logits, next_tok)
-    if moe:
+    if config.hc_mult:
+        # A multi-stream model's step also says how far from doubly
+        # stochastic its worst H_res was: one float behind the step's
+        # log-probs, as the expert counts ride behind its tokens
+        # (``_note_mhc_step`` reads it).
+        logp = jnp.concatenate([logp, stats.pop()[None].astype(logp.dtype)])
+    if stats:
         # An expert model's step also says what its routing did: the two
         # counts of ``MoEStats`` ride BEHIND the step's tokens in the same
         # array, so the one fetch brings them and nothing is dispatched or
         # waited for on their account (``_note_moe_step`` reads them).
         # Every consumer of the tokens indexes entries below ``T``.
         next_tok = jnp.concatenate(
-            [next_tok, jnp.stack(moe[0]).astype(next_tok.dtype)])
+            [next_tok, jnp.stack(stats[0]).astype(next_tok.dtype)])
     return next_tok, logp, pool
 
 
@@ -640,6 +648,18 @@ class RolloutEngine:
                      "(decode_attn_impl='flash')")):
                 if asked:
                     raise LatentCacheUnsupported(mechanism, config.name)
+        if config.hc_mult:
+            ec = engine_config or EngineConfig()
+            # The multi-stream residual runs through forward_paged on one
+            # chip alone; the same refusals, for a model of any attention.
+            for asked, mechanism in (
+                    (ec.kv_layout == "slots" or config.kv_quant
+                     or self._ring, "the slot KVCache path"),
+                    (mesh is not None, "a mesh (mesh=...)"),
+                    (adapter_pool is not None, "the multi-LoRA adapter "
+                     "pool")):
+                if asked:
+                    raise ResidualStreamUnsupported(mechanism, config.name)
         self.sample = sample
         self.eos_id = eos_id
         # Optional tensor-parallel serving: params take the Megatron
@@ -796,6 +816,14 @@ class RolloutEngine:
                         "senweaver_moe_expert_load_max",
                         "Largest number of tokens on one expert in any "
                         "layer of the last fused step."))
+            # A multi-stream model's fused step reports its residual
+            # maps the same way, behind the step's log-probs.
+            self._mhc_gauge = None
+            if config.hc_mult:
+                self._mhc_gauge = get_registry().gauge(
+                    "senweaver_mhc_sinkhorn_err",
+                    "Largest |row sum - 1| or |column sum - 1| of any "
+                    "residual mixing map H_res in the last fused step.")
         self._slot_req: List[Optional[_Request]] = [None] * num_slots  # guarded-by: _lock
         # rid holding each slot's KV across turns (hold_slot), or None
         self._slot_held: List[Optional[int]] = [None] * num_slots  # guarded-by: _lock
@@ -3229,6 +3257,8 @@ class RolloutEngine:
             if self._moe_counters is not None:
                 self._note_moe_step(st, toks, decode_rows, spec_rows,
                                     job_rows)
+            if self._mhc_gauge is not None:
+                self._note_mhc_step(st, logps)
             with span("engine.emit") as sp:
                 rows0 = list(self._slot_req) if sp is not None else ()
                 n_emitted = self._emit_paged(toks, logps, decode_rows,
@@ -3286,6 +3316,15 @@ class RolloutEngine:
             st.set_attr("experts_touched", touched)
             st.set_attr("expert_banks", n_banks)
             st.set_attr("expert_load_max", load_max)
+
+    def _note_mhc_step(self, st, logps) -> None:
+        # guarded-by: caller
+        """A multi-stream model's step: publish the Sinkhorn error that
+        arrived behind the step's log-probs (``_paged_fused_step``)."""
+        err = float(logps[-1])
+        self._mhc_gauge.set(err)
+        if st is not None:
+            st.set_attr("mhc_ds_err", err)
 
     def _publish_fragmentation(self) -> None:
         # guarded-by: caller

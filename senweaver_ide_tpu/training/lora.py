@@ -37,7 +37,8 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import LatentCacheUnsupported, ModelConfig
+from ..models.config import (LatentCacheUnsupported, ModelConfig,
+                             ResidualStreamUnsupported)
 from ..models.quantize import _quantize_matrix, is_quantized
 
 # (in_dim, out_dim) resolvers per supported target matrix.
@@ -62,6 +63,8 @@ def init_lora(config: ModelConfig, key: jax.Array, *, rank: int = 16,
         raise LatentCacheUnsupported(
             "LoRA on the latent projections (targets are wq/wk/wv/wo)",
             config.name)
+    if config.hc_mult:
+        raise ResidualStreamUnsupported("LoRA adapters", config.name)
     if config.num_experts > 0:
         bad = {"w_gate", "w_up", "w_down"} & set(targets)
         if bad:

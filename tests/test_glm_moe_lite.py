@@ -209,7 +209,7 @@ def test_expert_layer_equals_the_reference(model, case):
     """Routed experts, weights, the 1.8 and the shared expert, whatever the
     load: every token on one pair of experts, an expert with none."""
     config, lp, x = _expert_layer_inputs(model, BIASES[case])
-    got, _, stats = _mlp(config, lp, x)
+    got, _, stats, _ = _mlp(config, lp, x)
     h = ref._rms_norm(x[0], lp["mlp_norm"], config.rms_norm_eps)
     want, _ = ref.expert_layer(TINY, None, h, lp)
     assert float(jnp.abs(got[0] - x[0] - want).max()) < TOL

@@ -140,7 +140,7 @@ def _flat_batches():
     }
 
 
-# The latent pool's form, as ``_paged_mla_layer`` hands it over: ONE leaf
+# The latent pool's form, as ``_paged_mla_attend`` hands it over: ONE leaf
 # of rows 640 wide whose first 512 columns are the value too (GLM-4.7-
 # Flash's 20 heads, row and rank), and the model's scale, which is not
 # ``1 / sqrt(640)``. In the parametrisations below ``hkv == LATENT``
@@ -151,7 +151,7 @@ LATENT_ROW, LATENT_RANK, LATENT_SCALE = 640, 512, 1.0 / 16.0
 
 def _gather_reference(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
     """``_paged_layer``'s gather path: every entry's whole table width.
-    Without a ``v_leaf``, ``_paged_mla_layer``'s: scores against the whole
+    Without a ``v_leaf``, ``_paged_mla_attend``'s: scores against the whole
     row at the model's scale, the sum over the same rows cut to the rank."""
     t, width = q.shape[0], tables.shape[1] * k_leaf.shape[2]
     tbl = tables[seq_row]

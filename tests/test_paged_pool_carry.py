@@ -144,7 +144,7 @@ def _reference_layer(c, lp, x, cos, sin, leaves, b, use_kernel, ad, ad_ids):
     attn_in = out.reshape(t, 1, c.q_dim)
     attn_out = tf._dense(attn_in, lp, "wo", "bse,ed->bsd")
     x = x + tf._with_adapter(attn_out, attn_in, ad, ad_ids, "wo")
-    x, *_ = tf._mlp(c, lp, x)      # (x, aux, MoEStats | None)
+    x, *_ = tf._mlp(c, lp, x)      # (x, aux, MoEStats | None, None)
     return x, leaves
 
 
@@ -301,17 +301,20 @@ def _benchmark_config(name):
     ("qwen3-8b", 4, 48), ("deepseek-coder-6.7b", 4, 192),
     # the latent pool: 20 heads over one leaf of rows 640 wide, a table of
     # 256 blocks (max_len 4096), the dense layer and two expert layers
-    ("glm-4.7-flash", 3, 48), ("glm-4.7-flash", 3, 192)])
+    ("glm-4.7-flash", 3, 48), ("glm-4.7-flash", 3, 192),
+    # the same pool under 32 heads and a 4-row residual stream: both dense
+    # layers and two expert layers
+    ("xing4.0-29b-a4b", 4, 48), ("xing4.0-29b-a4b", 4, 192)])
 def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
                                                      entries, monkeypatch):
     """``_paged_fused_step`` at a preset's widths, 48 rows of 64 blocks,
     bf16, with ``paged_attention_rows`` (a latent pool:
     ``paged_latent_attention_rows``) compiled by Mosaic (the test
-    says "on a TPU": the backend here is the CPU). The qwen and glm cases
-    are the benchmark cells' shapes."""
+    says "on a TPU": the backend here is the CPU). The qwen, glm and xing
+    cases are the benchmark cells' shapes."""
     from senweaver_ide_tpu.ops import paged_attention
     monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
-    latent = model == "glm-4.7-flash"
+    latent = model in ("glm-4.7-flash", "xing4.0-29b-a4b")
     c = _benchmark_config(model) if latent else get_config(model)
     if layers:
         c = dataclasses.replace(c, num_layers=layers)
