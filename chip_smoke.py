@@ -589,12 +589,10 @@ def fused_step_lowering(engine):
     """The engine's own jitted step, lowered with the engine's own state
     and flags (nothing is run or donated)."""
     n = engine.num_slots
-    zeros = np.zeros((n,), np.int32)
     return engine_mod._paged_fused_step.lower(
-        engine.params, engine.config, zeros,
-        np.zeros((n, 1), np.int32), zeros, zeros, zeros, zeros,
-        engine.pool, jax.random.PRNGKey(0), engine.sample,
-        engine._use_paged_kernel)
+        engine.params, engine.config, np.zeros((5, n), np.int32),
+        np.zeros((n, 1), np.int32), engine.pool, jax.random.PRNGKey(0),
+        engine.sample, engine._use_paged_kernel)
 
 
 def four_chips(report: Report, sz: Sizes, config, train_config, seed: int,

@@ -256,6 +256,8 @@ class RuntimeProfiler:
         self._registry = None                        # guarded-by: _lock
         self._instruments: Dict[str, Any] = {}       # guarded-by: _lock
         self._hbm_watermark: Dict[str, float] = {}   # guarded-by: _lock
+        # functions whose caller times the step itself (``begin_step``)
+        self._caller_steps: set = set()              # guarded-by: _lock
         self.storm_events: List[Dict[str, Any]] = []  # guarded-by: _lock
         _install_compile_listener()
 
@@ -402,6 +404,34 @@ class RuntimeProfiler:
         self._metrics()["transfer"].inc(
             int(nbytes), fn=name, direction=direction)
 
+    def begin_step(self, name: str) -> float:
+        """The caller of a function wrapped with ``block=False`` times
+        the step itself, from here to :meth:`end_step`: it knows when
+        the results are on the host, the wrapper sees the dispatch
+        alone and leaves ``name``'s step times to the caller from now
+        on. Returns the clock reading to hand to ``end_step``."""
+        if not self.enabled:
+            return 0.0
+        if name not in self._caller_steps:
+            with self._lock:
+                self._caller_steps.add(name)
+        return time.perf_counter()
+
+    def end_step(self, name: str, t_begin: float) -> None:
+        """The step begun at ``t_begin`` has its results on the host:
+        its time goes to ``name``'s ledger (``step_ms_sum``,
+        ``last_step_ms``) and the step histogram."""
+        if not self.enabled:
+            return
+        step_ms = (time.perf_counter() - t_begin) * 1_000.0
+        with self._lock:
+            led = self._ledgers.get(name)
+            if led is None:
+                return
+            led.step_ms_sum += step_ms
+            led.last_step_ms = step_ms
+        self._metrics()["step_ms"].observe(step_ms, fn=name)
+
     def maybe_cost_analysis(self, pf: "ProfiledFunction", sig: Tuple,
                             args: Tuple, kwargs: Dict[str, Any]
                             ) -> Optional[Tuple[float, float]]:
@@ -437,8 +467,10 @@ class RuntimeProfiler:
         storm = False
         with self._lock:
             led.calls += 1
-            led.step_ms_sum += step_ms
-            led.last_step_ms = step_ms
+            timed = pf.block or name not in self._caller_steps
+            if timed:
+                led.step_ms_sum += step_ms
+                led.last_step_ms = step_ms
             led.h2d_bytes += h2d_bytes
             entry = led.signatures.get(sig)
             if entry is None:
@@ -463,7 +495,8 @@ class RuntimeProfiler:
             n_sigs = len(led.signatures)
         ins = self._metrics()
         ins["calls"].inc(fn=name)
-        ins["step_ms"].observe(step_ms, fn=name)
+        if timed:
+            ins["step_ms"].observe(step_ms, fn=name)
         ins["signatures"].set(n_sigs, fn=name)
         if h2d_bytes > 0:
             ins["transfer"].inc(h2d_bytes, fn=name, direction="h2d")

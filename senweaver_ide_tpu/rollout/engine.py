@@ -39,7 +39,8 @@ from ..models.config import (LatentCacheUnsupported, ModelConfig,
 from ..models.transformer import (KVCache, Params, forward, forward_paged,
                                   init_kv_cache, reads_pool_in_place)
 from ..obs import get_registry, get_tracer
-from ..obs.runtime_profile import ProfiledFunction, profiled_device_get
+from ..obs.runtime_profile import (ProfiledFunction, get_profiler,
+                                   profiled_device_get)
 from ..obs.tracing import noop_span
 from ..ops.sampling import sample_token, sampled_logprob
 from .kv_pressure import (HostPrefix, PrefixCandidate, dequantize_host,
@@ -241,19 +242,24 @@ def _pool_decode_step(params: Params, config: ModelConfig, cur_tok: jax.Array,
                    static_argnames=("config", "sample", "use_kernel"),
                    donate_argnames=("pool",))
 def _paged_fused_step(params: Params, config: ModelConfig,
-                      tokens: jax.Array, tables: jax.Array,
-                      seq_row: jax.Array, positions: jax.Array,
-                      write_block: jax.Array, write_off: jax.Array,
+                      plan: jax.Array, tables: jax.Array,
                       pool: PagedKVPool,
                       key: jax.Array, sample: SampleParams,
                       use_kernel: Optional[bool],
                       adapters=None, adapter_ids=None):
     """One fused paged step over a flat token batch: decode rows and
     exact-size chunked-prefill segments share the same forward under a
-    static token budget (``tokens.shape[0]``). Each entry writes its
+    static token budget (``plan.shape[1]``). ``plan`` is the host's
+    five int32 vectors as the rows of ONE ``(5, T)`` array — tokens,
+    seq_row, positions, write_block, write_off — so the call ingests
+    one host array for them, not five. Each entry writes its
     k/v through ``(write_block, write_off)`` — padding/rescore entries
     address the out-of-range sentinel block and are dropped by the
-    scatter. Sampling happens in-jit for EVERY row; the host keeps only
+    scatter. ``key`` is the engine's key: it is split HERE, as the
+    host used to split it before every call (the same threefry split
+    in the same order, so a seed's tokens are what they were), and the
+    next key is the fourth result — no program of its own between two
+    steps. Sampling happens in-jit for EVERY row; the host keeps only
     the rows it marked as samplers (decode rows, the final token of a
     completing prefill), so ONE batched device_get per step covers
     first tokens and decode tokens alike. With an adapter pool
@@ -266,6 +272,8 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     absmax scales through the SAME sentinel-guarded indices — no extra
     device round-trips, no new compile per occupancy bucket (the scale
     tensors are shape-static alongside the payloads)."""
+    key, step_key = jax.random.split(key)
+    tokens, seq_row, positions, write_block, write_off = plan
     logits, pool, *stats = forward_paged(
         params, config, tokens, pool=pool,
         tables=tables, seq_row=seq_row, positions=positions,
@@ -273,7 +281,7 @@ def _paged_fused_step(params: Params, config: ModelConfig,
         use_kernel=use_kernel, adapters=adapters, adapter_ids=adapter_ids,
         with_moe_stats=config.num_experts > 0,
         with_mhc_stats=config.hc_mult > 0)
-    next_tok = sample_token(logits, key, temperature=sample.temperature,
+    next_tok = sample_token(logits, step_key, temperature=sample.temperature,
                             top_k=sample.top_k, top_p=sample.top_p)
     logp = sampled_logprob(logits, next_tok)
     if config.hc_mult:
@@ -290,7 +298,7 @@ def _paged_fused_step(params: Params, config: ModelConfig,
         # Every consumer of the tokens indexes entries below ``T``.
         next_tok = jnp.concatenate(
             [next_tok, jnp.stack(stats[0]).astype(next_tok.dtype)])
-    return next_tok, logp, pool
+    return next_tok, logp, pool, key
 
 
 @functools.partial(jax.jit, static_argnames=("config", "k", "use_kernel"),
@@ -362,12 +370,15 @@ def _draft_feed_step(params: Params, config: ModelConfig,
 # widths x token-batch widths x speculation depths) so only unbounded
 # retraces trip it. The draft propose/feed steps get the same
 # treatment: their ladders are (table-bucket x depth) and
-# (table-bucket x feed-width bucket) respectively.
+# (table-bucket x feed-width bucket) respectively. The fused step is
+# the one wrap that does not block: its pool and key (args 4-5) are
+# shape-stable too, and ``_step_paged``, which knows when the step's
+# tokens are on the host, reports the step's time to this ledger.
 _pool_decode_step = ProfiledFunction(
     _pool_decode_step, "engine.decode_step", skip_args=(0, 1))
 _paged_fused_step = ProfiledFunction(
-    _paged_fused_step, "engine.fused_step", skip_args=(0, 1),
-    storm_threshold=64)
+    _paged_fused_step, "engine.fused_step", skip_args=(0, 1, 4, 5),
+    block=False, storm_threshold=64)
 _draft_propose_scan = ProfiledFunction(
     _draft_propose_scan, "engine.spec_propose", skip_args=(0, 1),
     storm_threshold=32)
@@ -923,6 +934,11 @@ class RolloutEngine:
             "each run of one row's entries in a step, the blocks up to "
             "its last position. The lower bound of what is read: the "
             "kernel reads a long run's blocks once a tile of queries.")
+        self._host_syncs_total = reg.counter(
+            "senweaver_engine_step_host_syncs_total",
+            "Points of a paged step at which the host blocked on the "
+            "device: the fetch of the step's tokens (one a step), and the "
+            "proposals' fetch where speculation runs.")
         # the last assembled plan's share of that counter, for the
         # engine.step span's attr kv_blocks
         self._kv_blocks_step = 0                # guarded-by: _lock
@@ -2513,6 +2529,7 @@ class RolloutEngine:
             self._draft_tables_device(), self._draft_pool,
             k, self._use_paged_kernel)
         props = profiled_device_get(props_dev, fn="engine.spec_propose")
+        self._host_syncs_total.inc()
         plan = {}
         for row in rows:
             plan[row] = [int(x) for x in props[row]]
@@ -3225,12 +3242,10 @@ class RolloutEngine:
                 with span("engine.tables"):
                     tables = self._tables_device()
                 # host numpy in, device out: the five plan vectors enter
-                # the jit as numpy (single C++ ingest each); jnp.asarray
-                # here would cost a full dispatch per vector per step —
-                # profiled at ~half the paged step's host time
-                vectors = tuple(
-                    np.asarray(v, np.int32)
-                    for v in (toks_l, rows_l, pos_l, wb_l, wo_l))
+                # the jit as the rows of ONE numpy array (one C++ ingest;
+                # jnp.asarray here would cost a full dispatch a step)
+                vectors = np.asarray(
+                    (toks_l, rows_l, pos_l, wb_l, wo_l), np.int32)
             if st is not None:
                 prefill = sum(j[3] for j in job_rows)
                 st.set_attr("entries", len(toks_l))
@@ -3243,17 +3258,24 @@ class RolloutEngine:
                 st.set_attr("queue_depth", len(self._queue))
                 st.set_attr("rows_active", len(decode_rows)
                             + len(spec_rows) + len(job_rows))
+            t_launch = get_profiler().begin_step("engine.fused_step")
             toks, logps = self._launch_paged(span, vectors, tables,
                                              adapters, adapter_ids)
             self._stats["decode_steps"] += 1
             with span("engine.fetch") as sp:
                 # ONE batched device→host transfer per fused step (the
                 # analysis JIT110 budget), covering decode tokens AND the
-                # first tokens of completing prefills.
+                # first tokens of completing prefills: the step's one
+                # blocking point, on a copy that was asked for at launch.
+                t_wait = time.perf_counter()
                 toks, logps = profiled_device_get((toks, logps),
                                                   fn="engine.fused_step")
                 if sp is not None:
+                    sp.set_attr("wait_ms",
+                                (time.perf_counter() - t_wait) * 1_000.0)
                     sp.set_attr("bytes", int(toks.nbytes + logps.nbytes))
+                self._host_syncs_total.inc()
+            get_profiler().end_step("engine.fused_step", t_launch)
             if self._moe_counters is not None:
                 self._note_moe_step(st, toks, decode_rows, spec_rows,
                                     job_rows)
@@ -3277,21 +3299,29 @@ class RolloutEngine:
 
     def _launch_paged(self, span, vectors, tables, adapters, adapter_ids):
         # guarded-by: caller
-        """Enqueue the fused step on the plan's five vectors. The span's
-        self time (less the wrapper's ``.dispatch`` and ``.wait``
-        children) is the key split plus the wrapper's own bookkeeping.
-        On the v5e host a new shape's lowering time follows the summed
-        frame sizes from ``step()`` down to this call (PERF.md §6, PR 24):
-        a change to the locals here, in ``_step``/``_step_paged`` or in
-        ``ProfiledFunction.__call__`` can move set-up by seconds."""
-        tokens, seq_row, positions, write_block, write_off = vectors
-        with span("engine.launch"):
-            self._key, step_key = jax.random.split(self._key)
-            next_tok, logp, self.pool = _paged_fused_step(
-                self.params, self.config, tokens, tables, seq_row,
-                positions, write_block, write_off, self.pool, step_key,
-                self.sample, self._use_paged_kernel,
+        """Enqueue the fused step on the plan's ``(5, T)`` array and ask
+        for its tokens' copy to the host: ONE program for the runtime,
+        which also splits the engine's key, and one transfer queued
+        behind it on the device. Nothing here waits: the pool is
+        ordered by the next step's donation, the tokens by
+        ``engine.fetch``. The span's self time (less the wrapper's
+        ``.dispatch`` child) is the wrapper's bookkeeping and the two
+        transfer requests. On the v5e host a new shape's lowering time
+        follows the summed frame sizes from ``step()`` down to this
+        call (PERF.md §6, PR 24 and 31): the seven unused locals below
+        keep this frame and ``_step_paged``'s at the 67 slots they had
+        together before PR 31, measured on the chip to be worth 0.5 s
+        of a qwen cell's warm-up and 0.9 s of glm's (ROADMAP D10)."""
+        b0 = b1 = b2 = b3 = b4 = b5 = b6 = None      # frame ballast
+        with span("engine.launch") as sp:
+            next_tok, logp, self.pool, self._key = _paged_fused_step(
+                self.params, self.config, vectors, tables, self.pool,
+                self._key, self.sample, self._use_paged_kernel,
                 adapters=adapters, adapter_ids=adapter_ids)
+            next_tok.copy_to_host_async()
+            logp.copy_to_host_async()
+            if sp is not None:
+                sp.set_attr("host_arrays", 2 + len(adapter_ids or ()))
         return next_tok, logp
 
     def _note_moe_step(self, st, toks, decode_rows, spec_rows,

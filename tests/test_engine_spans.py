@@ -21,7 +21,9 @@ SAMPLED = SampleParams(temperature=1.0, top_k=0, top_p=0.9)
 STEP_CHILDREN = ("engine.plan", "engine.launch", "engine.fetch",
                  "engine.emit")
 PLAN_CHILDREN = ("engine.schedule", "engine.assemble_plan", "engine.tables")
-LAUNCH_CHILDREN = ("engine.fused_step.dispatch", "engine.fused_step.wait")
+# the wrapped jit call alone: nothing under launch waits (the step's one
+# wait is engine.fetch's own time, attr wait_ms)
+LAUNCH_CHILDREN = ("engine.fused_step.dispatch",)
 PROMPTS = ([5, 9, 2, 7, 1, 3, 8, 4, 6, 2, 9, 1, 7, 3, 5, 8, 2, 4, 6, 1,
             3, 5], [11, 3, 8, 1, 4], [2, 6, 4, 9, 9, 1, 2])
 
@@ -80,12 +82,18 @@ def test_every_step_has_the_named_phases_nested_in_it(model):
     for step in steps:
         kids = [s for s in spans if s.parent_id == step.span_id]
         assert [k.name for k in kids] == list(STEP_CHILDREN)
-        plan, launch, _fetch, emit = kids
+        plan, launch, fetch, emit = kids
         sub = lambda p: [s.name for s in spans if s.parent_id == p.span_id]
         assert [n for n in sub(plan) if n in PLAN_CHILDREN] == \
             list(PLAN_CHILDREN)
         assert sub(launch) == list(LAUNCH_CHILDREN)
+        assert sub(fetch) == []
         assert sub(emit) == ["engine.schedule"]
+        # two host arrays through the dispatch (the packed plan, the
+        # table); the wait is inside the fetch
+        assert launch.attrs["host_arrays"] == 2
+        assert 0.0 <= fetch.attrs["wait_ms"] <= fetch.duration_ms
+    assert "engine.fused_step.wait" not in names
     # every child lies inside its parent, on the perf_counter_ns clock
     for s in spans:
         if s.name.startswith("engine."):
@@ -366,10 +374,10 @@ def _lowered_fused_step(config, seed=0):
                         sample=SAMPLED,
                         engine_config=EngineConfig(kv_layout="paged",
                                                    block_size=4))
-    z = np.zeros((4,), np.int32)
     return engine_mod._paged_fused_step.lower(
-        params, config, z, np.zeros((4, 2), np.int32), z, z, z, z, eng.pool,
-        jax.random.PRNGKey(0), SAMPLED, False).as_text(debug_info=True)
+        params, config, np.zeros((5, 4), np.int32),
+        np.zeros((4, 2), np.int32), eng.pool, jax.random.PRNGKey(0),
+        SAMPLED, False).as_text(debug_info=True)
 
 
 @pytest.mark.parametrize("make,scopes", [

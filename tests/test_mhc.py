@@ -510,10 +510,10 @@ def test_step_reports_its_worst_map_in_the_one_fetch(model, served):
     assert served["gauge"] == steps[-1]["mhc_ds_err"]
     assert all(len(logps) == 9 for _, _, logps in served["requests"])
     # the step itself: T log-probs and one float, T tokens and two counts
-    z = np.zeros((4,), np.int32)
-    toks, logp, _ = engine_mod._paged_fused_step(
-        model[0], model[1], z, np.zeros((4, 16), np.int32), z, z,
-        np.full((4,), served["pool"].num_blocks, np.int32), z,
+    plan = np.zeros((5, 4), np.int32)
+    plan[3] = served["pool"].num_blocks
+    toks, logp, _, _ = engine_mod._paged_fused_step(
+        model[0], model[1], plan, np.zeros((4, 16), np.int32),
         served["pool"], jax.random.PRNGKey(0), SAMPLED, None)
     assert toks.shape == (6,) and logp.shape == (5,)
     assert 1e-6 < float(logp[-1]) < 0.5

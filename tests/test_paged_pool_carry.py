@@ -260,8 +260,7 @@ def test_compiled_step_keeps_no_second_pool(program):
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     if program == "fused_step":
         lowered = eng._paged_fused_step.lower(
-            params, c, i32(entries), i32(rows, width), i32(entries),
-            i32(entries), i32(entries), i32(entries), pool,
+            params, c, i32(5, entries), i32(rows, width), pool,
             jax.ShapeDtypeStruct((2,), jnp.uint32), SampleParams(), False)
     else:
         lowered = eng._draft_propose_scan.lower(
@@ -332,8 +331,7 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = eng._paged_fused_step.lower(
-            params, c, i32(entries), i32(rows, width), i32(entries),
-            i32(entries), i32(entries), i32(entries), pool,
+            params, c, i32(5, entries), i32(rows, width), pool,
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_v5e),
             SampleParams(temperature=1.0), None).compile()
     finally:

@@ -78,6 +78,66 @@ def test_step_time_recorded_for_blocking_wrap():
     assert snap["last_step_ms"] > 0.0
 
 
+def test_a_caller_times_the_step_of_a_non_blocking_wrap():
+    """A non-blocking wrapper sees the dispatch alone. A caller that knows
+    when the results reached the host times the step (``begin_step`` /
+    ``end_step``, as ``RolloutEngine._step_paged`` does for
+    ``engine.fused_step``): the ledger's step times and the histogram are
+    then the caller's, one observation a call."""
+    import time
+    f = wrap(jax.jit(lambda x: x + 1), "t.report", block=False)
+    prof = get_profiler()
+    for pause in (0.02, 0.03):
+        t0 = prof.begin_step("t.report")
+        out = f(jnp.ones((4,)))
+        time.sleep(pause)
+        np.asarray(out)
+        prof.end_step("t.report", t0)
+    snap = prof.ledger()["t.report"]
+    assert snap["blocking"] is False
+    assert snap["calls"] == 2 and snap["compiles"] == 1
+    assert snap["last_step_ms"] >= 30.0 and snap["step_ms_sum"] >= 50.0
+    hist = obs.get_registry().get("senweaver_runtime_step_ms")
+    got = hist.snapshot(fn="t.report")
+    assert got["count"] == 2 and got["sum"] >= 50.0
+    # the wrapper alone, as the trainer's wrap runs: it times the dispatch
+    g = wrap(jax.jit(lambda x: x * 3), "t.own", block=False)
+    g(jnp.ones((4,)))
+    assert prof.ledger()["t.own"]["last_step_ms"] > 0.0
+    assert hist.snapshot(fn="t.own")["count"] == 1
+    # a blocking wrap keeps its own times whoever claims them
+    h = wrap(jax.jit(lambda x: x - 3), "t.blocks")
+    prof.end_step("t.blocks", prof.begin_step("t.blocks"))   # no ledger yet
+    h(jnp.ones((4,)))
+    assert hist.snapshot(fn="t.blocks")["count"] == 1
+    get_profiler().set_enabled(False)
+    assert prof.begin_step("t.report") == 0.0
+    prof.end_step("t.report", 0.0)
+    assert prof.ledger()["t.report"]["calls"] == 2
+
+
+def test_the_engines_step_ms_is_launch_to_fetch_by_its_own_report():
+    """``engine.fused_step`` does not block in its wrapper: ``step_ms`` is
+    what ``_step_paged`` reports, launch to tokens on the host, so it
+    covers ``engine.fetch``'s wait."""
+    from senweaver_ide_tpu.models import init_params, tiny_test
+    from senweaver_ide_tpu.rollout import EngineConfig, RolloutEngine
+    config = tiny_test()
+    eng = RolloutEngine(init_params(config, jax.random.PRNGKey(0)), config,
+                        num_slots=2, max_len=32,
+                        engine_config=EngineConfig(kv_layout="paged"))
+    obs.enable()
+    eng.submit([3, 1, 4, 1, 5], max_new_tokens=4)
+    eng.run()
+    snap = get_profiler().ledger()["engine.fused_step"]
+    spans = obs.get_tracer().spans()
+    waits = [s.attrs["wait_ms"] for s in spans if s.name == "engine.fetch"]
+    assert snap["blocking"] is False and snap["calls"] == len(waits) == 4
+    assert snap["step_ms_sum"] >= sum(waits) > 0.0
+    assert snap["last_step_ms"] >= waits[-1]
+    assert not any(s.name == "engine.fused_step.wait" for s in spans)
+
+
 # ---------------------------------------------------------------------------
 # retrace storms
 # ---------------------------------------------------------------------------
