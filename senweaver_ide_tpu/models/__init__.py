@@ -1,4 +1,5 @@
-from .config import (ModelConfig, PRESETS, RopeScaling, YarnScaling,
+from .config import (ModelConfig, PRESETS, RecurrentStateUnsupported,
+                     RopeScaling, YarnScaling,
                      get_config,
                      qwen2_5_coder_0_5b, qwen2_5_coder_1_5b, qwen2_5_coder_7b,
                      deepseek_coder_1_3b, deepseek_coder_6_7b, llama_3_1_8b,

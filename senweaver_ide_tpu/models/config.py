@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Union
+from typing import Optional, Tuple, Union
 
 import jax.numpy as jnp
 
@@ -43,6 +43,23 @@ class ResidualStreamUnsupported(NotImplementedError):
         super().__init__(
             f"{mechanism} is not implemented for the multi-stream residual "
             f"(hc_mult > 0) of configuration {config_name!r}")
+        self.mechanism = mechanism
+
+
+class RecurrentStateUnsupported(NotImplementedError):
+    """A mechanism that has no form yet for a configuration whose layers
+    hold recurrent state (a state-space mixer, ``mamba_d_ssm > 0``): one
+    fixed-size array a row, overwritten every token, that has no position
+    to re-read and cannot be shared by refcount, only copied
+    (``rollout.paged_kv.StateRows``). Raised where the mechanism is asked
+    for, never replaced by a path that would drop or reuse the state.
+    ``mechanism`` names it."""
+
+    def __init__(self, mechanism: str, config_name: str):
+        super().__init__(
+            f"{mechanism} is not implemented for the recurrent state "
+            f"(mamba_d_ssm > 0) of configuration {config_name!r}: a row's "
+            f"state is overwritten every token and has no snapshot there")
         self.mechanism = mechanism
 
 
@@ -197,10 +214,54 @@ class ModelConfig:
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
     mhc_h_res_clamp_max: float = 30.0
+    # A Mamba-2 state-space mixer beside attention in every block
+    # (Falcon-H1): both read the block's one normed input and their scaled
+    # outputs are summed into the residual (``models.transformer._mixers``,
+    # ``ops/ssm.py``). ``mamba_d_ssm`` = ``mamba_n_heads`` x
+    # ``mamba_d_head`` inner values, each head a (d_head, d_state) state;
+    # ``mamba_n_groups`` groups of heads share one B and one C;
+    # a causal depthwise conv of ``mamba_d_conv`` taps (with bias) over
+    # [x | B | C]. 0 = no mixer. The names are the published keys.
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    # muP multipliers (Falcon-H1's published keys), constants of the
+    # forward: on the embedding, the logits, the attention sublayer's input
+    # and output, the keys, the mixer's input and output, the five parts
+    # of the mixer's input projection (z, x, B, C, dt), and the MLP's gate
+    # and output. At 1.0 no operation is added.
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
 
     @property
     def mla(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def ssm(self) -> bool:
+        """The blocks hold a state-space mixer, and the cache a recurrent
+        state a row beside the KV blocks."""
+        return self.mamba_d_ssm > 0
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the mixer's conv runs over: [x | B | C]."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def ssm_proj_dim(self) -> int:
+        """Width of the mixer's input projection: [z | x B C | dt]."""
+        return self.mamba_d_ssm + self.ssm_conv_dim + self.mamba_n_heads
 
     @property
     def attn_scale(self) -> float:
@@ -378,6 +439,25 @@ def tiny_xing_mhc_test() -> ModelConfig:
         hc_mult=4, hc_sinkhorn_iters=4)
 
 
+def tiny_falcon_h1_test() -> ModelConfig:
+    """Falcon-H1's block (``falcon_h1``) at test size: a Mamba-2 mixer of
+    4 heads x 8 with a state of 16 in 2 groups and a 4-tap conv beside GQA
+    attention (4 query / 2 kv heads) on one normed input, no biases, and
+    all twelve multipliers away from 1 so that dropping one shows."""
+    return ModelConfig(
+        name="tiny-falcon-h1-test", vocab_size=512, hidden_size=64,
+        intermediate_size=128, num_layers=2, num_heads=4, num_kv_heads=2,
+        head_dim=16, max_seq_len=128, rope_theta=1e11, rms_norm_eps=1e-5,
+        dtype=jnp.float32, matmul_precision="highest",
+        mamba_d_ssm=32, mamba_n_heads=4, mamba_d_head=8, mamba_d_state=16,
+        mamba_n_groups=2, mamba_d_conv=4,
+        embedding_multiplier=5.0, lm_head_multiplier=0.25,
+        attention_in_multiplier=0.9, attention_out_multiplier=0.5,
+        key_multiplier=0.7, ssm_in_multiplier=0.8, ssm_out_multiplier=0.6,
+        ssm_multipliers=(0.9, 0.8, 0.7, 1.2, 1.1),
+        mlp_multipliers=(0.75, 0.4))
+
+
 def tiny_test() -> ModelConfig:
     """Small config for unit tests and CPU-mesh dry runs."""
     return ModelConfig(
@@ -467,6 +547,7 @@ PRESETS = {
     "tiny-moe-test": tiny_moe_test,
     "tiny-glm-moe-test": tiny_glm_moe_test,
     "tiny-xing-mhc-test": tiny_xing_mhc_test,
+    "tiny-falcon-h1-test": tiny_falcon_h1_test,
     "small-test": small_test,
 }
 

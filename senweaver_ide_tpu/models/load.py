@@ -31,7 +31,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .config import ModelConfig, ResidualStreamUnsupported
+from .config import (ModelConfig, RecurrentStateUnsupported,
+                     ResidualStreamUnsupported)
 from .transformer import Params
 
 __all__ = ["load_hf_params", "export_hf_params", "available_hf_keys"]
@@ -99,6 +100,9 @@ def load_hf_params(model_dir: str, config: ModelConfig, *,
     if c.hc_mult:
         # the checkpoint's names for the maps' leaves are not known here
         raise ResidualStreamUnsupported("the HF loader", c.name)
+    if c.ssm:
+        # nor are its names for the mixer's leaves
+        raise RecurrentStateUnsupported("the HF loader", c.name)
     dtype = dtype or c.dtype
     raw = _load_raw(model_dir)
     D, F, L, V = c.hidden_size, c.intermediate_size, c.num_layers, c.vocab_size
@@ -194,6 +198,8 @@ def export_hf_params(params: Params, config: ModelConfig,
 
     if config.hc_mult:
         raise ResidualStreamUnsupported("the HF exporter", config.name)
+    if config.ssm:
+        raise RecurrentStateUnsupported("the HF exporter", config.name)
     if is_quantized(params):
         # transposing the +/-127 codes without their scales would write a
         # garbage checkpoint that loads cleanly elsewhere

@@ -35,8 +35,10 @@ import numpy as np
 from ..ops.attention import NEG_INF, attention
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
+from ..ops import ssm as ssm_ops
 from .config import (LatentCacheUnsupported, ModelConfig,
-                     ResidualStreamUnsupported, YarnScaling)
+                     RecurrentStateUnsupported, ResidualStreamUnsupported,
+                     YarnScaling)
 from .moe import BANKS, MoEStats, expert_ffn
 
 Params = Dict[str, Any]
@@ -90,6 +92,9 @@ def init_kv_cache(config: ModelConfig, batch: int, max_len: int,
                   dtype=None, *, quantized: Optional[bool] = None) -> KVCache:
     if config.mla:
         raise LatentCacheUnsupported("the slot KVCache layout", config.name)
+    if config.ssm:
+        raise RecurrentStateUnsupported("the slot KVCache layout",
+                                        config.name)
     quantized = config.kv_quant if quantized is None else quantized
     max_len = ring_capacity(config, max_len)
     shape = (config.num_layers, batch, max_len, config.num_kv_heads,
@@ -227,6 +232,35 @@ def _init_layer_stack(c: ModelConfig, key: jax.Array, L: int,
     if c.qk_norm and not c.mla:
         layers["q_norm"] = jnp.ones((L, c.head_dim), c.dtype)
         layers["k_norm"] = jnp.ones((L, c.head_dim), c.dtype)
+    if c.ssm:
+        # The state-space mixer's leaves (``_ssm_project`` .. ``_ssm_out``),
+        # Mamba-2's own start: a step size dt log-uniform on [1e-3, 1e-1]
+        # behind the softplus, A = -exp(A_log) uniform on [-16, -1], so a
+        # token decays a head's state by exp(dt A) in [0.2, 0.999]; the
+        # skip D 1; the conv as a depthwise Conv1d starts. dt_bias, A_log
+        # and D are float32 whatever the serving dtype: they set decays.
+        if c.mamba_d_ssm != c.mamba_n_heads * c.mamba_d_head or (
+                c.mamba_n_heads % c.mamba_n_groups):
+            raise ValueError(
+                f"{c.name}: mamba_d_ssm {c.mamba_d_ssm} != mamba_n_heads x "
+                f"mamba_d_head, or heads not divisible by mamba_n_groups")
+        km = [jax.random.fold_in(key, 200 + i) for i in range(6)]
+        I, H, K = c.mamba_d_ssm, c.mamba_n_heads, c.mamba_d_conv
+        bound = 1.0 / float(K) ** 0.5
+        dt = jnp.exp(jax.random.uniform(
+            km[4], (L, H), jnp.float32, math.log(1e-3), math.log(1e-1)))
+        layers.update(
+            ssm_in=dense(km[0], (L, D, c.ssm_proj_dim), D),
+            ssm_out=dense(km[1], (L, I, D), I),
+            ssm_conv_w=jax.random.uniform(
+                km[2], (L, K, c.ssm_conv_dim), c.dtype, -bound, bound),
+            ssm_conv_b=jax.random.uniform(
+                km[3], (L, c.ssm_conv_dim), c.dtype, -bound, bound),
+            ssm_dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+            ssm_A_log=jnp.log(jax.random.uniform(
+                km[5], (L, H), jnp.float32, 1.0, 16.0)),
+            ssm_D=jnp.ones((L, H), jnp.float32),
+            ssm_norm=jnp.ones((L, I), c.dtype))
     if c.hc_mult:
         # The residual operator's leaves (``_residual``), one set a
         # sublayer, all float32: the projection phi like any matrix; the
@@ -355,6 +389,7 @@ def _qkv(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array,
     q = _with_adapter(q, h, adapters, adapter_ids, "wq")
     k = _with_adapter(k, h, adapters, adapter_ids, "wk")
     v = _with_adapter(v, h, adapters, adapter_ids, "wv")
+    k = _times(k, c.key_multiplier)
     if c.qkv_bias:
         q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
     q = q.reshape(b, s, c.num_heads, c.head_dim)
@@ -494,6 +529,109 @@ def _cache_attention(c: ModelConfig, q, k_full, v_full, length, kv_mask,
                      causal=True, window=c.sliding_window)
 
 
+def _times(x: jax.Array, m: float) -> jax.Array:
+    """x times a published multiplier: a constant of the forward. At 1
+    nothing is added to the program."""
+    return x if m == 1.0 else x * m
+
+
+def _ssm_project(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array):
+    """The mixer's input projection of the block's normed input h
+    (..., D): ``[z | xBC | dt] = (ssm_in_multiplier h) W_in`` with
+    ``ssm_multipliers`` on its five parts (z, x, B, C, dt). -> z (..., I)
+    the gate, xbc (..., I + 2 G N) the conv's input, dt (..., H) float32
+    before the bias and the softplus."""
+    i, gn = c.mamba_d_ssm, c.mamba_n_groups * c.mamba_d_state
+    with jax.named_scope("ssm.in_proj"):
+        p = _dense(_times(h, c.ssm_in_multiplier), lp, "ssm_in",
+                   "bsd,de->bse")
+        if any(m != 1.0 for m in c.ssm_multipliers):
+            mz, mx, mb, mc, mdt = c.ssm_multipliers
+            vec = np.concatenate([
+                np.full(i, mz), np.full(i, mx), np.full(gn, mb),
+                np.full(gn, mc), np.full(c.mamba_n_heads, mdt)])
+            p = (p.astype(jnp.float32)
+                 * jnp.asarray(vec, jnp.float32)).astype(p.dtype)
+    return (p[..., :i], p[..., i:i + c.ssm_conv_dim],
+            p[..., i + c.ssm_conv_dim:].astype(jnp.float32))
+
+
+def _ssm_split(c: ModelConfig, lp: Dict[str, jax.Array], conv_out: jax.Array,
+               dt: jax.Array, dtype):
+    """The conv's output (..., I + 2 G N) f32 and the raw dt (..., H) ->
+    what the scan reads: x (..., H, P), B and C (..., G, N) in ``dtype``
+    after the silu; dt = softplus(dt + dt_bias) and A = -exp(A_log) in
+    float32 (no clamp on dt: the configuration publishes none)."""
+    i, g, n = c.mamba_d_ssm, c.mamba_n_groups, c.mamba_d_state
+    xbc = jax.nn.silu(conv_out).astype(dtype)
+    lead = xbc.shape[:-1]
+    x = xbc[..., :i].reshape(lead + (c.mamba_n_heads, c.mamba_d_head))
+    b = xbc[..., i:i + g * n].reshape(lead + (g, n))
+    cc = xbc[..., i + g * n:].reshape(lead + (g, n))
+    dt = jax.nn.softplus(dt + lp["ssm_dt_bias"])
+    return x, b, cc, dt, -jnp.exp(lp["ssm_A_log"])
+
+
+def _ssm_out(c: ModelConfig, lp: Dict[str, jax.Array], y: jax.Array,
+             z: jax.Array) -> jax.Array:
+    """The scan's output y (..., H, P) f32 and the gate z (..., I) -> the
+    mixer's output (..., D): gated grouped RMSNorm, output projection."""
+    with jax.named_scope("ssm.gate_norm"):
+        g = ssm_ops.gated_norm(y.reshape(z.shape), z, lp["ssm_norm"],
+                               c.mamba_n_groups, c.rms_norm_eps, z.dtype)
+    with jax.named_scope("ssm.out_proj"):
+        return _dense(g, lp, "ssm_out", "bse,ed->bsd")
+
+
+def _ssm_mix(c: ModelConfig, lp: Dict[str, jax.Array],
+             h: jax.Array) -> jax.Array:
+    """The state-space mixer over whole sequences from a zero state: h
+    (B, S, D), the block's normed input -> (B, S, D)."""
+    z, xbc, dt = _ssm_project(c, lp, h)
+    with jax.named_scope("ssm.conv"):
+        conv = ssm_ops.conv_dense(xbc, lp["ssm_conv_w"], lp["ssm_conv_b"])
+        x, b, cc, dt, a = _ssm_split(c, lp, conv, dt, h.dtype)
+    with jax.named_scope("ssm.scan"):
+        y = ssm_ops.scan_dense(x, dt, a, b, cc, lp["ssm_D"])
+    return _ssm_out(c, lp, y, z)
+
+
+def _paged_ssm_mix(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array,
+                   rows: Tuple[jax.Array, jax.Array], layer: jax.Array,
+                   seq_row: jax.Array, run_plan):
+    """``_ssm_mix`` over the flat batch and the pool's row-addressed
+    leaves: h (T, 1, D); ``rows`` = (ssm (L, rows, H, P, N) f32, conv
+    (L, rows, K-1, C)), read and written at ``(layer, row)`` once a run
+    (``ops/ssm.py``); ``run_plan`` is ``ops.ssm.plan_runs`` of this batch.
+    -> ((T, 1, D), rows')."""
+    state, window = rows
+    z, xbc, dt = _ssm_project(c, lp, h)
+    with jax.named_scope("ssm.conv"):
+        conv, window = ssm_ops.conv_flat(
+            xbc[:, 0], lp["ssm_conv_w"], lp["ssm_conv_b"], window, layer,
+            seq_row, run_plan)
+        x, b, cc, dt, a = _ssm_split(c, lp, conv, dt[:, 0], h.dtype)
+    with jax.named_scope("ssm.scan"):
+        y, state = ssm_ops.scan_flat(x, dt, a, b, cc, lp["ssm_D"], state,
+                                     layer, seq_row, run_plan)
+    return _ssm_out(c, lp, y[:, None], z), (state, window)
+
+
+def _mixers(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+            attend: Callable, mix: Callable):
+    """The first sublayer of a block that has a state-space mixer beside
+    attention: both read ONE normed input and their scaled outputs are
+    summed, ``ssm_out_multiplier SSM(h) + attention_out_multiplier
+    Attn(attention_in_multiplier h)``. ``attend(h)`` and ``mix(h)`` each
+    return (output, what else it yields). -> (the sum, (attention's,
+    the mixer's))."""
+    h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+    attn, kv = attend(_times(h, c.attention_in_multiplier))
+    mixed, state = mix(h)
+    return (_times(attn, c.attention_out_multiplier)
+            + _times(mixed, c.ssm_out_multiplier)), (kv, state)
+
+
 def sinkhorn(logits: jax.Array, c: ModelConfig) -> jax.Array:
     """(..., n, n) float32 logits -> ``c.hc_sinkhorn_iters`` rounds of
     Sinkhorn-Knopp on ``exp(clip(logits))``: each round divides every
@@ -625,21 +763,34 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     — in the no-cache case the returned pair is the block's own (k, v);
     aux is the MoE load-balancing loss (0 for dense layers).
     """
-    x, kv_out, _ = _residual(
-        c, lp, x, "attn", lambda x_in: _attend(
-            c, lp, x_in, cos, sin, cache_kv, kv_mask, mesh, flash_decode_ok))
+    if c.ssm:
+        # two mixers on one normed input (``_mixers``); the scan is causal,
+        # so a mask of a right-padded tail changes nothing before it
+        x, (kv_out, _), _ = _residual(
+            c, lp, x, "attn", lambda x_in: _mixers(
+                c, lp, x_in,
+                lambda h: _attend(c, lp, None, cos, sin, cache_kv, kv_mask,
+                                  mesh, flash_decode_ok, h=h),
+                lambda h: (_ssm_mix(c, lp, h), None)))
+    else:
+        x, kv_out, _ = _residual(
+            c, lp, x, "attn", lambda x_in: _attend(
+                c, lp, x_in, cos, sin, cache_kv, kv_mask, mesh,
+                flash_decode_ok))
     x, aux, _, _ = _mlp(c, lp, x)
     return x, kv_out, aux
 
 
 def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
             cos: jax.Array, sin: jax.Array, cache_kv, kv_mask, mesh,
-            flash_decode_ok: bool):
+            flash_decode_ok: bool, h: Optional[jax.Array] = None):
     """``_layer``'s attention sublayer, norm to output projection: x
     (B, S, D), what the residual path hands it -> (attention's output
-    (B, S, D), the cache pair ``_layer`` returns)."""
-    b, s, _ = x.shape
-    h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+    (B, S, D), the cache pair ``_layer`` returns). With ``h`` the caller
+    has normed the input already (``_mixers``) and x is not read."""
+    if h is None:
+        h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+    b, s, _ = h.shape
     if c.mla:       # no cache here: _forward_impl refuses one
         if c.attn_impl != "einsum" or c.sliding_window is not None:
             raise LatentCacheUnsupported(
@@ -669,9 +820,9 @@ def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                 out = attention(q, k, v, kv_mask=kv_mask, causal=False)
             else:
                 k_all = jnp.concatenate(
-                    [_dequantize_kv(k_cache, k_scale, x.dtype), k], axis=1)
+                    [_dequantize_kv(k_cache, k_scale, h.dtype), k], axis=1)
                 v_all = jnp.concatenate(
-                    [_dequantize_kv(v_cache, v_scale, x.dtype), v], axis=1)
+                    [_dequantize_kv(v_cache, v_scale, h.dtype), v], axis=1)
                 out = attention(q, k_all, v_all, kv_mask=kv_mask,
                                 causal=False)
         if length.ndim == 0 and not ring:
@@ -700,8 +851,8 @@ def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
             v_scale = v_scale.at[slot, pos].set(vs, mode="drop")
         if out is None:
             out = _cache_attention(c, q,
-                                   _dequantize_kv(k_cache, k_scale, x.dtype),
-                                   _dequantize_kv(v_cache, v_scale, x.dtype),
+                                   _dequantize_kv(k_cache, k_scale, h.dtype),
+                                   _dequantize_kv(v_cache, v_scale, h.dtype),
                                    length, kv_mask, flash_decode_ok)
         kv_out = (k_cache, v_cache, k_scale, v_scale)
     elif cache_kv is not None:
@@ -714,8 +865,8 @@ def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
             if kv_mask.shape[-1] == s:
                 out = attention(q, k, v, kv_mask=kv_mask, causal=False)
             else:
-                k_all = jnp.concatenate([k_cache.astype(x.dtype), k], axis=1)
-                v_all = jnp.concatenate([v_cache.astype(x.dtype), v], axis=1)
+                k_all = jnp.concatenate([k_cache.astype(h.dtype), k], axis=1)
+                v_all = jnp.concatenate([v_cache.astype(h.dtype), v], axis=1)
                 out = attention(q, k_all, v_all, kv_mask=kv_mask,
                                 causal=False)
         if length.ndim == 0 and not ring:
@@ -750,11 +901,12 @@ def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
 
 
 def _swiglu(h: jax.Array, lp: Dict[str, jax.Array], gate: str, up: str,
-            down: str) -> jax.Array:
-    g = _dense(h, lp, gate, "bsd,df->bsf")
+            down: str, mults: Tuple[float, float] = (1.0, 1.0)) -> jax.Array:
+    """``(up(h) * silu(mults[0] gate(h))) down * mults[1]``."""
+    g = _times(_dense(h, lp, gate, "bsd,df->bsf"), mults[0])
     u = _dense(h, lp, up, "bsd,df->bsf")
     act = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * u
-    return _dense(act, lp, down, "bsf,fd->bsd")
+    return _times(_dense(act, lp, down, "bsf,fd->bsd"), mults[1])
 
 
 def _mlp(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
@@ -774,7 +926,8 @@ def _mlp(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     def ffn(x_in):
         h = rms_norm(x_in, lp["mlp_norm"], c.rms_norm_eps)
         if "router" not in lp:
-            return (_swiglu(h, lp, "w_gate", "w_up", "w_down"),
+            return (_swiglu(h, lp, "w_gate", "w_up", "w_down",
+                            c.mlp_multipliers),
                     (jnp.zeros((), jnp.float32), None))
         b, s, d = h.shape
         y, aux, stats = expert_ffn(c, lp, h.reshape(b * s, d), count,
@@ -860,8 +1013,13 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
         raise ResidualStreamUnsupported(
             "forward(cache=...) over the slot KVCache" if cache is not None
             else "forward(mesh=...)", c.name)
+    if c.ssm and (cache is not None or mesh is not None):
+        raise RecurrentStateUnsupported(
+            "forward(cache=...) over the slot KVCache" if cache is not None
+            else "forward(mesh=...)", c.name)
     # gather; sharded vocab → XLA collective
-    x = _stream_open(c, params["embed"][tokens])
+    x = _stream_open(c, _times(params["embed"][tokens],
+                               c.embedding_multiplier))
 
     if positions is None:
         base = cache.length if cache is not None else jnp.zeros((), jnp.int32)
@@ -1040,7 +1198,8 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
             logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
     else:
         logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
-    return logits.astype(jnp.float32), new_cache, aux_total
+    return (_times(logits.astype(jnp.float32), c.lm_head_multiplier),
+            new_cache, aux_total)
 
 
 def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
@@ -1050,7 +1209,7 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                  positions: jax.Array, write_block: jax.Array,
                  write_off: jax.Array, use_kernel: bool = False,
                  adapters=None, adapter_ids=None, stack_layer=None,
-                 row_plan=None):
+                 row_plan=None, run_plan=None, state_layer=None):
     """One transformer block over a paged KV pool (rollout/paged_kv.py).
 
     ``x`` is a flat token batch ``(T, 1, D)`` — T independent
@@ -1095,13 +1254,36 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     HLO instruction's ``op_name`` metadata and change no computation.
     ``attn.kv_gather`` exists on the gather path alone; the kernel is
     ``paged_attention_rows`` in a trace.
+
+    A configuration with a state-space mixer (``c.ssm``) carries the
+    pool's row-addressed leaves (ssm, conv) as the LAST two of ``leaves``,
+    indexed by ``state_layer`` (the block's absolute number) and cut by
+    ``run_plan`` (``ops.ssm.plan_runs``); its first sublayer is the two
+    mixers on one normed input (``_mixers``, ``_paged_ssm_mix``; scopes
+    ``ssm.in_proj``, ``ssm.conv``, ``ssm.scan``, ``ssm.gate_norm``,
+    ``ssm.out_proj``).
     """
     attend = _paged_mla_attend if c.mla else _paged_attend
-    x, leaves, err = _residual(
-        c, lp, x, "attn", lambda x_in: attend(
-            c, lp, x_in, cos, sin, leaves, layer, tables, seq_row,
-            positions, write_block, write_off, use_kernel=use_kernel,
-            adapters=adapters, adapter_ids=adapter_ids, row_plan=row_plan))
+    if c.ssm:
+        def both(x_in):
+            out, (kv, rows) = _mixers(
+                c, lp, x_in,
+                lambda h: _paged_attend(
+                    c, lp, None, cos, sin, leaves[:-2], layer, tables,
+                    seq_row, positions, write_block, write_off,
+                    use_kernel=use_kernel, adapters=adapters,
+                    adapter_ids=adapter_ids, row_plan=row_plan, h=h),
+                lambda h: _paged_ssm_mix(c, lp, h, leaves[-2:], state_layer,
+                                         seq_row, run_plan))
+            return out, kv + rows
+        x, leaves, err = _residual(c, lp, x, "attn", both)
+    else:
+        x, leaves, err = _residual(
+            c, lp, x, "attn", lambda x_in: attend(
+                c, lp, x_in, cos, sin, leaves, layer, tables, seq_row,
+                positions, write_block, write_off, use_kernel=use_kernel,
+                adapters=adapters, adapter_ids=adapter_ids,
+                row_plan=row_plan))
     with jax.named_scope("mlp"):
         x, _, stats, mlp_err = _mlp(
             c, lp, x, _writes(lp, write_block, leaves[0]), stack_layer)
@@ -1114,14 +1296,17 @@ def _paged_attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                   tables: jax.Array, seq_row: jax.Array,
                   positions: jax.Array, write_block: jax.Array,
                   write_off: jax.Array, use_kernel: bool = False,
-                  adapters=None, adapter_ids=None, row_plan=None):
+                  adapters=None, adapter_ids=None, row_plan=None,
+                  h: Optional[jax.Array] = None):
     """``_paged_layer``'s attention sublayer over (k, v) leaves, norm to
     output projection: x (T, 1, D), what the residual path hands it ->
-    (attention's output (T, 1, D), leaves')."""
-    t = x.shape[0]
+    (attention's output (T, 1, D), leaves'). With ``h`` the caller has
+    normed the input already (``_mixers``) and x is not read."""
     quantized = len(leaves) == 4
     with jax.named_scope("attn.qkv"):
-        h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+        if h is None:
+            h = rms_norm(x, lp["attn_norm"], c.rms_norm_eps)
+        t = h.shape[0]
         q, k, v = _qkv(c, lp, h, cos, sin, adapters, adapter_ids)
     # q (T,1,Hq,Dh), k/v (T,1,Hkv,Dh)
     with jax.named_scope("attn.kv_write"):
@@ -1172,13 +1357,13 @@ def _paged_attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
                 leaf[layer, tbl].reshape((t, mb * bs) + leaf.shape[3:])
                 for leaf in leaves)
             if quantized:
-                k_seq = dequantize_pool_kv(k_seq, scales[0], x.dtype)
-                v_seq = dequantize_pool_kv(v_seq, scales[1], x.dtype)
+                k_seq = dequantize_pool_kv(k_seq, scales[0], h.dtype)
+                v_seq = dequantize_pool_kv(v_seq, scales[1], h.dtype)
         with jax.named_scope("attn.scores"):
             kv_pos = jnp.arange(mb * bs)[None, :]
             valid = kv_pos < positions[:, None] + 1
-            out = attention(q, k_seq.astype(x.dtype),
-                            v_seq.astype(x.dtype), q_offset=positions,
+            out = attention(q, k_seq.astype(h.dtype),
+                            v_seq.astype(h.dtype), q_offset=positions,
                             kv_mask=valid, causal=True)
     with jax.named_scope("attn.out"):
         attn_in = out.reshape(t, 1, c.q_dim)
@@ -1391,8 +1576,12 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                         use_kernel, adapters=None, adapter_ids=None):
     with jax.named_scope("embed"):
         # (T, 1, D), or the stream (T, 1, hc_mult, D)
-        x = _stream_open(c, params["embed"][tokens][:, None, :])
+        x = _stream_open(c, _times(params["embed"][tokens][:, None, :],
+                                   c.embedding_multiplier))
         cos, sin = _rope_tables(c, positions[:, None])
+    if c.ssm and adapters is not None:
+        raise RecurrentStateUnsupported("adapter banks in forward_paged",
+                                        c.name)
     if c.mla and (adapters is not None or pool.k_scale is not None):
         raise LatentCacheUnsupported(
             "adapter banks / a quantized pool in forward_paged", c.name)
@@ -1413,8 +1602,20 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                                  block_size=pool.k.shape[2],
                                  table_width=tables.shape[1],
                                  q_tile=query_tile(c.num_heads))
+    run_plan = None
+    rows = ()
+    if c.ssm:
+        # likewise once a step: the kept entries of each row are one run
+        # of the state-space mixer (padding and dropped writes advance
+        # nothing). The row-addressed leaves ride the carry behind the
+        # block-addressed ones.
+        rows = tuple(pool.rows)
+        with jax.named_scope("ssm.run_plan"):
+            run_plan = ssm_ops.plan_runs(
+                seq_row, positions, write_block < pool.k.shape[1],
+                num_rows=tables.shape[0])
 
-    def scan_layers(x, layers, ad, leaves, first=0):
+    def scan_layers(x, layers, ad, leaves, first=0, state_first=None):
         """The layer scan over one group of pool leaves. The leaves ride
         the CARRY, stacked as stored, and each layer scatters into and
         gathers from them at its own index: XLA aliases a while loop's
@@ -1427,7 +1628,11 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         its layers' absolute numbers. An expert stack also carries and
         returns its ``MoEStats`` (None for a dense stack), and a
         multi-stream configuration's the largest Sinkhorn error of its
-        sublayers (None for the plain residual)."""
+        sublayers (None for the plain residual). ``state_first``: the
+        absolute number of the stack's first layer, which indexes the
+        row-addressed state leaves (``first`` where the block leaves are
+        indexed by it too)."""
+        state_first = first if state_first is None else state_first
         n = jax.tree_util.tree_leaves(layers)[0].shape[0]
         counts = banks = None
         worst = jnp.zeros((), jnp.float32) if c.hc_mult else None
@@ -1450,7 +1655,9 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                 positions, write_block, write_off, use_kernel=use_kernel,
                 adapters=ad_l, adapter_ids=adapter_ids,
                 stack_layer=None if banks is None else layer - first,
-                row_plan=row_plan)
+                row_plan=row_plan, run_plan=run_plan,
+                state_layer=(layer + (state_first - first) if c.ssm
+                             else None))
             if stats is not None:
                 acc = MoEStats(
                     acc.experts_touched + stats.experts_touched,
@@ -1475,12 +1682,13 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                                   lambda a: a[:n_hi])
         sl_lo = functools.partial(jax.tree_util.tree_map,
                                   lambda a: a[n_hi:])
-        x, (upd["k_hi"], upd["v_hi"]), hi_moe, err = scan_layers(
-            x, sl_hi(layers), sl_hi(adapters), (pool.k_hi, pool.v_hi))
+        x, (upd["k_hi"], upd["v_hi"], *rows), hi_moe, err = scan_layers(
+            x, sl_hi(layers), sl_hi(adapters),
+            (pool.k_hi, pool.v_hi) + rows)
         layers, lo_ad = sl_lo(layers), sl_lo(adapters)
     if c.mla:
         names = ("k",)      # the latent rows; ``v`` has no width
-    leaves = tuple(getattr(pool, n) for n in names)
+    leaves = tuple(getattr(pool, n) for n in names) + tuple(rows)
     first = 0
     if "dense_layers" in params:
         if n_hi or adapters is not None:
@@ -1491,7 +1699,8 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         x, leaves, _, err = scan_layers(x, params["dense_layers"], None,
                                         leaves)
         first = c.first_dense_layers
-    x, leaves, moe, last_err = scan_layers(x, layers, lo_ad, leaves, first)
+    x, leaves, moe, last_err = scan_layers(x, layers, lo_ad, leaves, first,
+                                           state_first=first + n_hi)
     err = _worst(err, last_err)
     if n_hi and moe is not None:
         # the full-width prefix layers are expert layers of the same model
@@ -1499,6 +1708,8 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                        jnp.maximum(moe.expert_load_max,
                                    hi_moe.expert_load_max))
     upd.update(zip(names, leaves))
+    if c.ssm:
+        upd["rows"] = type(pool.rows)(*leaves[-2:])
 
     with jax.named_scope("lm_head"):
         x = rms_norm(_stream_close(c, x), params["final_norm"],
@@ -1511,7 +1722,8 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                 logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
         else:
             logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
-        logits = logits[:, 0].astype(jnp.float32)
+        logits = _times(logits[:, 0].astype(jnp.float32),
+                        c.lm_head_multiplier)
     return logits, pool._replace(**upd), moe, err
 
 

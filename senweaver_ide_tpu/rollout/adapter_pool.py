@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.config import (LatentCacheUnsupported, ModelConfig,
+                             RecurrentStateUnsupported,
                              ResidualStreamUnsupported)
 from ..obs import get_registry
 
@@ -131,6 +132,9 @@ class AdapterPool:
                 config.name)
         if config.hc_mult:
             raise ResidualStreamUnsupported("the multi-LoRA adapter pool",
+                                            config.name)
+        if config.ssm:
+            raise RecurrentStateUnsupported("the multi-LoRA adapter pool",
                                             config.name)
         self.config = config
         self.pool_config = pool_config or AdapterPoolConfig()

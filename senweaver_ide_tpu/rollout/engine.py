@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.config import (LatentCacheUnsupported, ModelConfig,
+                             RecurrentStateUnsupported,
                              ResidualStreamUnsupported)
 from ..models.transformer import (KVCache, Params, forward, forward_paged,
                                   init_kv_cache, reads_pool_in_place)
@@ -46,10 +47,10 @@ from ..ops.sampling import sample_token, sampled_logprob
 from .kv_pressure import (HostPrefix, PrefixCandidate, dequantize_host,
                           pick_victim, should_tier)
 from .paged_kv import (BlockAllocator, BlockPayload, BlocksExhausted,
-                       PagedKVPool, copy_blocks, gather_blocks,
-                       gather_blocks_quant, init_paged_pool, install_blocks,
-                       install_blocks_quant, pool_bytes_per_block,
-                       resolve_kv_dtypes)
+                       PagedKVPool, copy_blocks, copy_state_rows,
+                       gather_blocks, gather_blocks_quant, init_paged_pool,
+                       install_blocks, install_blocks_quant,
+                       pool_bytes_per_block, resolve_kv_dtypes)
 from .sampler import SampleParams
 
 
@@ -467,6 +468,11 @@ class _PrefillJob:
     # when not sampling (preemption resume), restore this token as the
     # row's decode cursor instead of emitting anything
     after_tok: Optional[int] = None
+    # a group donor's prefill over prompt[:-1] for a model with recurrent
+    # state: when it completes the engine captures the group's fork (the
+    # table and a snapshot of the state rows) and the donor goes on with
+    # this token, the prompt's last, as a one-token job of its own
+    fork_then: Optional[int] = None
 
 
 class _RowPreempted(Exception):
@@ -599,7 +605,13 @@ class _GroupShare:
     with a refcount bump (zero KV bytes moved) and rescores only the
     last prompt token. ``degraded`` flips if the donor dies before
     capture (preemption storm, migration release): followers fall back
-    to plain unshared prefills — slower, never inexact."""
+    to plain unshared prefills — slower, never inexact.
+
+    A model with recurrent state has no position to rescore: its donor's
+    prefill is cut at ``prompt[:-1]``, the capture there also copies the
+    donor's state rows into snapshot row ``state_row``, and donor and
+    followers alike feed ``prompt[-1]`` as a one-token job with its
+    write kept, each follower from its own copy of the snapshot."""
 
     gid: int
     prompt_len: int
@@ -609,6 +621,7 @@ class _GroupShare:
     waiters: List["_Request"] = dataclasses.field(default_factory=list)
     pending: int = 0                     # followers not yet grafted
     degraded: bool = False
+    state_row: Optional[int] = None      # snapshot row (recurrent state)
 
 
 class RolloutEngine:
@@ -671,6 +684,21 @@ class RolloutEngine:
                      "pool")):
                 if asked:
                     raise ResidualStreamUnsupported(mechanism, config.name)
+        if config.ssm:
+            ec = engine_config or EngineConfig()
+            # Recurrent state lives in the paged pool's row-addressed
+            # leaves on one chip alone: a layout that has no place for it
+            # is refused, never fallen back to.
+            for asked, mechanism in (
+                    (ec.kv_layout == "slots", "the slot KVCache layout "
+                     "(EngineConfig.kv_layout='slots')"),
+                    (config.kv_quant, "the slot int8 cache (kv_quant)"),
+                    (self._ring, "the sliding-window ring cache"),
+                    (mesh is not None, "a mesh (mesh=...)"),
+                    (adapter_pool is not None, "the multi-LoRA adapter "
+                     "pool")):
+                if asked:
+                    raise RecurrentStateUnsupported(mechanism, config.name)
         self.sample = sample
         self.eos_id = eos_id
         # Optional tensor-parallel serving: params take the Megatron
@@ -769,10 +797,34 @@ class RolloutEngine:
             # Pool before allocator: the allocator's byte ledger
             # (senweaver_kv_bytes_{device,host}) needs the pool's
             # per-block footprint, which the kv_dtype ladder shrinks.
+            # Recurrent state: one row of it for each engine row (state
+            # row r goes with table row r) and the snapshot rows behind:
+            # a group's state at its fork waits in one until its last
+            # follower has copied it; with none free a group degrades to
+            # unshared prefills.
+            n_snap = max(2, num_slots // 6) if config.ssm else 0
             self.pool = self._on_device(lambda: init_paged_pool(
                 config, nb, bs,
                 kv_dtype=self.engine_config.kv_dtype,
-                kv_dtype_per_layer=self.engine_config.kv_dtype_per_layer))
+                kv_dtype_per_layer=self.engine_config.kv_dtype_per_layer,
+                state_rows=num_slots + n_snap if config.ssm else 0))
+            self._state_snap_free: List[int] = list(  # guarded-by: _lock
+                range(num_slots + n_snap - 1, num_slots - 1, -1))
+            # (src, dst) row copies asked for since the last fused step:
+            # dispatched together in the next step's plan phase
+            self._state_copies: List[tuple] = []    # guarded-by: _lock
+            self._state_copies_total = None
+            if config.ssm:
+                reg = get_registry()
+                self._state_copies_total = reg.counter(
+                    "senweaver_ssm_state_copies_total",
+                    "Recurrent-state rows copied: a group's snapshot at "
+                    "its fork and each follower's install of it.")
+                reg.gauge(
+                    "senweaver_ssm_state_bytes",
+                    "Device bytes of the pool's row-addressed recurrent "
+                    "state (engine rows and snapshot rows, all layers)."
+                ).set(self.pool.rows.nbytes)
             self._alloc = BlockAllocator(
                 nb, bs, registry=get_registry(),
                 bytes_per_block=pool_bytes_per_block(self.pool))
@@ -1025,6 +1077,10 @@ class RolloutEngine:
         (``num_blocks``; default sized like the target's) whose
         gauges publish under ``senweaver_spec_draft_kv_*``."""
         from .spec_controller import FixedDepth, SpecController
+        if self.config.ssm or draft_config.ssm:
+            # a rejected draft cannot roll a state back
+            raise RecurrentStateUnsupported("fused draft/verify speculation",
+                                            self.config.name)
         if self.config.mla or draft_config.mla:
             raise LatentCacheUnsupported("fused draft/verify speculation",
                                          self.config.name)
@@ -1378,6 +1434,8 @@ class RolloutEngine:
         for requests that are done, paused, or still prefilling."""
         if self.kv_layout != "paged":
             raise ValueError("fork_request requires the paged KV layout")
+        self._refuse_state("fork_request (a branch shares KV blocks by "
+                           "refcount; the state has no fork yet)")
         with self._lock:
             parent = self._requests.get(rid)
             if parent is None:
@@ -1543,6 +1601,8 @@ class RolloutEngine:
                 out["kv_bytes_per_block"] = self._alloc.bytes_per_block
                 out["kv_bytes_device"] = self._alloc.used_bytes
                 out["kv_bytes_host"] = self._alloc.swapped_bytes
+                if self.pool is not None and self.pool.rows is not None:
+                    out["state_bytes_device"] = self.pool.rows.nbytes
             if self.adapter_pool is not None:
                 ap = self.adapter_pool.stats()
                 out["adapters_published"] = len(ap["adapters"])
@@ -1681,6 +1741,9 @@ class RolloutEngine:
             raise LatentCacheUnsupported(
                 "registered prefixes (their prefill runs over the slot "
                 "KVCache)", self.config.name)
+        self._refuse_state("registered prefixes (register_prefix: a "
+                           "prefix's blocks are grafted, its state has no "
+                           "snapshot)")
         with self._lock:
             if not tokens:
                 raise ValueError("empty prefix")
@@ -1744,6 +1807,7 @@ class RolloutEngine:
         are immutable and the jitted paths donate only the POOL cache,
         never a prefix buffer. Raises KeyError if the prefix was evicted
         or invalidated (callers re-register, same as submit())."""
+        self._refuse_state("prefix export (export_prefix)")
         with self._lock:
             if prefix_id not in self._prefixes:
                 raise KeyError(f"unknown prefix_id {prefix_id}")
@@ -1791,6 +1855,8 @@ class RolloutEngine:
         buffer would be silent garbage). ``last_logits`` is the donor's
         final-token logits; without it, a zero-suffix submit recomputes
         the last position (one-token prefill) on first use."""
+        self._refuse_state("prefix import (import_prefix: the peer's KV "
+                           "comes without the state behind it)")
         with self._lock:
             if not tokens:
                 raise ValueError("empty prefix")
@@ -1947,6 +2013,9 @@ class RolloutEngine:
         request is left PAUSED so its state cannot advance between
         snapshot and the coordinator's release/resume). The freeze +
         snapshot happen atomically under the engine lock."""
+        self._refuse_state("request checkpoints and migration "
+                           "(checkpoint_request: a DecodeCheckpoint holds "
+                           "KV blocks, no state)")
         from .migration import checkpoint_from_engine
         with self._lock:
             return checkpoint_from_engine(self, rid, pause=pause)
@@ -1957,6 +2026,8 @@ class RolloutEngine:
         layout exist, otherwise a front-of-queue requeue that resumes
         through the preemption-recompute replay. Either way the
         resumed output is token-exact versus never migrating."""
+        self._refuse_state("request checkpoints and migration "
+                           "(restore_request)")
         from .migration import restore_into_engine
         with self._lock:
             rid = restore_into_engine(self, ckpt)
@@ -2009,6 +2080,63 @@ class RolloutEngine:
             return out
 
     # -- internals ----------------------------------------------------------
+
+    def _refuse_state(self, mechanism: str) -> None:
+        """What has no state-snapshot counterpart yet is refused by name
+        for a model with recurrent state, never fallen back from."""
+        if self.config.ssm:
+            raise RecurrentStateUnsupported(mechanism, self.config.name)
+
+    def _flush_state_copies(self, span) -> int:
+        # guarded-by: caller
+        """Dispatch the row copies asked for since the last fused step
+        (a group's snapshot, its followers' installs), in order, each ONE
+        shape-static program over all layers: before the step that reads
+        or overwrites any of the rows. Returns how many."""
+        copies, self._state_copies = self._state_copies, []
+        for src, dst in copies:
+            with span("engine.state_copy", src=src, dst=dst):
+                self.pool = copy_state_rows(
+                    self.pool, np.asarray([src], np.int32),
+                    np.asarray([dst], np.int32))
+        if copies:
+            self._state_copies_total.inc(len(copies))
+        return len(copies)
+
+    def _group_release_fork(self, g: "_GroupShare") -> None:
+        # guarded-by: caller
+        """The last follower has its fork: drop the engine's retained
+        spine (the followers' own forks keep the blocks alive) and free
+        the snapshot row, whose copies are already queued in order."""
+        if g.spine is not None:
+            self._alloc.release(g.spine)
+            g.spine = None
+        if g.state_row is not None:
+            self._state_snap_free.append(g.state_row)
+            g.state_row = None
+        self._groups.pop(g.gid, None)
+
+    def _group_capture(self, req: "_Request", row: int) -> None:
+        # guarded-by: caller
+        """The donor's row holds exactly what the group shares: capture
+        an engine-retained fork of its table (released when the last
+        follower grafts) and wake the waiters. A model with recurrent
+        state also snapshots the row's state; with no snapshot row free
+        the group degrades to unshared prefills, as with a dead donor."""
+        g = req.group
+        if self.config.ssm:
+            if not self._state_snap_free:
+                self._group_degrade_if_uncaptured(req)
+                return
+            g.state_row = self._state_snap_free.pop()
+            self._state_copies.append((row, g.state_row))
+        g.spine = self._alloc.fork(self._tables[row])
+        g.spine_len = self._row_len[row]
+        self._stats["group_prefills"] += 1
+        for w in g.waiters:
+            if not w.done:
+                self._queue.append(w)
+        g.waiters = []
 
     def _emit_first_token(self, req: "_Request", slot: int,
                           last_logits) -> None:
@@ -2114,10 +2242,7 @@ class RolloutEngine:
         g.waiters = [w for w in g.waiters if w.rid != req.rid]
         g.pending -= 1
         if g.pending <= 0:
-            if g.spine is not None:
-                self._alloc.release(g.spine)
-                g.spine = None
-            self._groups.pop(g.gid, None)
+            self._group_release_fork(g)
 
     def _finish_request(self, req: "_Request", slot: int) -> None:
         # guarded-by: caller
@@ -2995,23 +3120,32 @@ class RolloutEngine:
             self._tables[row] = self._alloc.fork(g.spine)
             self._row_len[row] = g.spine_len
             self._stats["group_forks"] += 1
-            self._stats["group_prefill_tokens_avoided"] += g.spine_len - 1
             self._stats["prefill_tokens"] += 1
-            self._prefill_jobs[req.rid] = _PrefillJob(
-                toks=[req.prompt[-1]], pos=g.spine_len - 1,
-                sample_last=True, drop_writes=True)
+            if g.state_row is None:
+                self._stats["group_prefill_tokens_avoided"] += (
+                    g.spine_len - 1)
+                self._prefill_jobs[req.rid] = _PrefillJob(
+                    toks=[req.prompt[-1]], pos=g.spine_len - 1,
+                    sample_last=True, drop_writes=True)
+            else:
+                # Recurrent state: the spine ends BEFORE the prompt's
+                # last token. Install a copy of the state there and feed
+                # that token with its write kept (a shared boundary block
+                # COW-splits now, one token earlier than above): the
+                # donor's own flow, from a bit-equal state.
+                self._state_copies.append((g.state_row, row))
+                self._stats["group_prefill_tokens_avoided"] += g.spine_len
+                self._prefill_jobs[req.rid] = _PrefillJob(
+                    toks=[req.prompt[-1]], pos=g.spine_len,
+                    sample_last=True)
             if not req.group_grafted:
                 # a preempted-then-rescheduled follower re-grafts but
                 # must not double-decrement the pending count
                 req.group_grafted = True
                 g.pending -= 1
                 if g.pending <= 0 and g.spine is not None:
-                    # last follower grafted: drop the engine's retained
-                    # spine fork — the followers' own forks keep the
-                    # blocks alive until each finishes
-                    self._alloc.release(g.spine)
-                    g.spine = None
-                    self._groups.pop(g.gid, None)
+                    # last follower grafted
+                    self._group_release_fork(g)
             return
         if req.tokens:
             # preemption resume: recompute prompt + everything emitted
@@ -3069,6 +3203,18 @@ class RolloutEngine:
                     sample_last=True, drop_writes=True)
             return
         self._stats["prefill_tokens"] += len(req.prompt)
+        if (self.config.ssm and g is not None and req.rid == g.donor_rid
+                and g.spine is None and not g.degraded and g.waiters):
+            if len(req.prompt) < 2:
+                # nothing before the last token to share
+                self._group_degrade_if_uncaptured(req)
+            else:
+                # the donor of a group over recurrent state: prefill up
+                # to the last token, fork there, then feed it
+                self._prefill_jobs[req.rid] = _PrefillJob(
+                    toks=list(req.prompt[:-1]), pos=0, sample_last=False,
+                    fork_then=req.prompt[-1])
+                return
         self._prefill_jobs[req.rid] = _PrefillJob(
             toks=list(req.prompt), pos=0, sample_last=True)
 
@@ -3227,6 +3373,8 @@ class RolloutEngine:
                     plan = self._assemble_paged_plan(spec_plan, depth)
                 if sp is not None:
                     sp.set_attr("admitted", self._placed_since(rows0))
+                n_copies = (self._flush_state_copies(span)
+                            if self._state_copies else 0)
                 if plan is None:
                     return emitted
                 (toks_l, rows_l, pos_l, wb_l, wo_l, decode_rows, spec_rows,
@@ -3258,6 +3406,11 @@ class RolloutEngine:
                 st.set_attr("queue_depth", len(self._queue))
                 st.set_attr("rows_active", len(decode_rows)
                             + len(spec_rows) + len(job_rows))
+                if self.config.ssm:
+                    # rows whose recurrent state the step reads and writes
+                    st.set_attr("ssm_rows", len(decode_rows)
+                                + sum(1 for j in job_rows if j[5]))
+                    st.set_attr("ssm_state_copies", n_copies)
             t_launch = get_profiler().begin_step("engine.fused_step")
             toks, logps = self._launch_paged(span, vectors, tables,
                                              adapters, adapter_ids)
@@ -3308,11 +3461,12 @@ class RolloutEngine:
         ``.dispatch`` child) is the wrapper's bookkeeping and the two
         transfer requests. On the v5e host a new shape's lowering time
         follows the summed frame sizes from ``step()`` down to this
-        call (PERF.md §6, PR 24 and 31): the seven unused locals below
+        call (PERF.md §6, PR 24 and 31): the six unused locals below
+        (seven until ``_step_paged`` gained one of its own in PR 32)
         keep this frame and ``_step_paged``'s at the 67 slots they had
         together before PR 31, measured on the chip to be worth 0.5 s
         of a qwen cell's warm-up and 0.9 s of glm's (ROADMAP D10)."""
-        b0 = b1 = b2 = b3 = b4 = b5 = b6 = None      # frame ballast
+        b0 = b1 = b2 = b3 = b4 = b5 = None           # frame ballast
         with span("engine.launch") as sp:
             next_tok, logp, self.pool, self._key = _paged_fused_step(
                 self.params, self.config, vectors, tables, self.pool,
@@ -3465,22 +3619,26 @@ class RolloutEngine:
                 continue
             self._prefill_jobs.pop(req.rid, None)
             g = req.group
-            if (g is not None and req.rid == g.donor_rid
-                    and g.spine is None and not g.degraded
-                    and job.sample_last and not req.tokens):
+            uncaptured = (g is not None and req.rid == g.donor_rid
+                          and g.spine is None and not g.degraded
+                          and not req.tokens)
+            if job.fork_then is not None:
+                # Recurrent state: the donor stands before the prompt's
+                # last token, table and state alike. Fork here, then go
+                # on with that token as a job of one (as each follower
+                # will, from its copy of the state).
+                if uncaptured:
+                    self._group_capture(req, row)
+                self._prefill_jobs[req.rid] = _PrefillJob(
+                    toks=[job.fork_then], pos=job.pos, sample_last=True)
+                continue
+            if uncaptured and job.sample_last and not self.config.ssm:
                 # Donor prefill just completed and its first sampled
                 # token is NOT yet written (tokens are fed the step
                 # after sampling): the table is the pure prompt spine.
-                # Capture an engine-retained fork (released when the
-                # last follower grafts) and wake the waiters — the
-                # donor's own next write COW-splits the boundary block.
-                g.spine = self._alloc.fork(self._tables[row])
-                g.spine_len = self._row_len[row]
-                self._stats["group_prefills"] += 1
-                for w in g.waiters:
-                    if not w.done:
-                        self._queue.append(w)
-                g.waiters = []
+                # The donor's own next write COW-splits the boundary
+                # block.
+                self._group_capture(req, row)
             if job.sample_last:
                 tok = int(toks[last_idx])
                 req.tokens.append(tok)

@@ -52,6 +52,7 @@ the raw bytes + scales.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import threading
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence
@@ -141,6 +142,37 @@ class BlocksExhausted(RuntimeError):
         self.num_blocks = num_blocks
 
 
+class StateRows(NamedTuple):
+    """Row-addressed recurrent state, the pool's second kind of state
+    beside the block-addressed KV: one fixed-size array a row a layer,
+    overwritten every token, so it is never shared by refcount
+    (``BlockAllocator.fork``) and never split on write (``cow_target``) —
+    only copied, whole, row to row (:func:`copy_state_rows`). Rows
+    ``0..num_slots-1`` belong to the engine's rows (state row r goes with
+    table row r); the rows behind them hold snapshots (a group's state at
+    its fork). A descriptor of its own, not a case of the block leaves:
+    the block movers (``copy_blocks``, ``install_blocks*``,
+    ``gather_blocks*``) never touch it, and a later kind of per-row or
+    per-window state can sit beside it the same way.
+
+    ``ssm`` ``(L, rows, H, P, N)`` float32: a state-space mixer's state;
+    ``conv`` ``(L, rows, K-1, C)``: the last K-1 inputs of its conv, oldest
+    first (``ops/ssm.py``)."""
+
+    ssm: jnp.ndarray
+    conv: jnp.ndarray
+
+    @property
+    def num_rows(self) -> int:
+        return self.ssm.shape[1]
+
+    @property
+    def nbytes(self) -> int:
+        """Device bytes of all rows, all layers: held whole, whatever the
+        rows do."""
+        return sum(int(a.size) * jnp.dtype(a.dtype).itemsize for a in self)
+
+
 class PagedKVPool(NamedTuple):
     """The device-side block pool. ``k``/``v`` are
     ``(L, num_blocks, block_size, Hkv, Dh)``; block 0..num_blocks-1 are
@@ -154,7 +186,10 @@ class PagedKVPool(NamedTuple):
     ``kv_dtype_per_layer`` override the first ``hi_layers`` layers
     live full-width in ``k_hi``/``v_hi`` and the payload tensors hold
     only the quantized tail (``Lq = L - hi_layers``). All shape- and
-    None-derived properties are static under jit."""
+    None-derived properties are static under jit.
+
+    ``rows`` holds what is addressed by row and not by block
+    (:class:`StateRows`); None for a model whose only state is KV."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -162,6 +197,7 @@ class PagedKVPool(NamedTuple):
     v_scale: Optional[jnp.ndarray] = None
     k_hi: Optional[jnp.ndarray] = None
     v_hi: Optional[jnp.ndarray] = None
+    rows: Optional[StateRows] = None
 
     @property
     def num_blocks(self) -> int:
@@ -207,9 +243,23 @@ class BlockPayload(NamedTuple):
     v_hi: Any = None
 
 
+def init_state_rows(config: ModelConfig, num_rows: int) -> StateRows:
+    """Zeroed row-addressed state for ``config``'s state-space mixer:
+    the state in float32 (it is a sum over thousands of steps), the conv's
+    window in the serving dtype (it holds projection outputs as they
+    are)."""
+    c = config
+    return StateRows(
+        ssm=jnp.zeros((c.num_layers, num_rows, c.mamba_n_heads,
+                       c.mamba_d_head, c.mamba_d_state), jnp.float32),
+        conv=jnp.zeros((c.num_layers, num_rows, c.mamba_d_conv - 1,
+                        c.ssm_conv_dim), c.dtype))
+
+
 def init_paged_pool(config: ModelConfig, num_blocks: int,
                     block_size: int, kv_dtype: str = "bf16",
-                    kv_dtype_per_layer=None) -> PagedKVPool:
+                    kv_dtype_per_layer=None,
+                    state_rows: int = 0) -> PagedKVPool:
     """Zeroed pool sized for ``config``. ``kv_dtype`` selects the
     serving precision ladder rung; ``kv_dtype_per_layer`` optionally
     keeps a bf16 prefix of layers full-width (see
@@ -225,7 +275,20 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
     block axes
     with a last axis of width 0, so that every mover below stays one
     ``tree_map`` (or one indexed update a leaf) over the same leaves and
-    moves no byte for it. The quantized ladder has no latent form yet."""
+    moves no byte for it. The quantized ladder has no latent form yet.
+
+    A configuration with recurrent state (``config.ssm``) gets
+    ``state_rows`` rows of it beside the blocks (``PagedKVPool.rows``);
+    every other configuration's pool is what it was."""
+    if config.ssm:
+        if state_rows <= 0:
+            raise ValueError(
+                f"{config.name}: a pool for a model with recurrent state "
+                f"needs state_rows > 0")
+        return init_paged_pool(
+            dataclasses.replace(config, mamba_d_ssm=0), num_blocks,
+            block_size, kv_dtype, kv_dtype_per_layer)._replace(
+                rows=init_state_rows(config, state_rows))
     hkv, dh = config.num_kv_heads, config.head_dim
     num_layers = config.num_layers
     payload, n_hi = resolve_kv_dtypes(num_layers, kv_dtype,
@@ -261,7 +324,7 @@ def pool_bytes_per_block(pool: PagedKVPool) -> int:
     (payload + scales + full-width prefix) — the unit the allocator's
     byte gauges multiply by."""
     total = 0
-    for a in pool:
+    for a in pool._replace(rows=None):
         if a is not None:
             total += int(a.size) * jnp.dtype(a.dtype).itemsize
     return total // pool.num_blocks
@@ -620,9 +683,22 @@ def copy_blocks(pool: PagedKVPool, src: jnp.ndarray,
     one gather+scatter per tensor — the COW copy. tree_map covers every
     pool tensor (payload, scales, full-width prefix), so quantize-at-
     write commutes with COW: a copied block carries its scales with
-    it."""
+    it. Row-addressed state (``pool.rows``) has no blocks and passes
+    through."""
     return jax.tree_util.tree_map(
-        lambda a: a.at[:, dst].set(a[:, src]), pool)
+        lambda a: a.at[:, dst].set(a[:, src]),
+        pool._replace(rows=None))._replace(rows=pool.rows)
+
+
+@functools.partial(jax.jit, donate_argnames=("pool",))
+def copy_state_rows(pool: PagedKVPool, src: jnp.ndarray,
+                    dst: jnp.ndarray) -> PagedKVPool:
+    """Copy the row-addressed state ``src[i] -> dst[i]`` (both ``(n,)``
+    int32) over all layers: the snapshot of a group's state at its fork
+    and its install into a follower's row are both this one program (one
+    shape for ``n`` = 1). The block leaves pass through."""
+    return pool._replace(rows=jax.tree_util.tree_map(
+        lambda a: a.at[:, dst].set(a[:, src]), pool.rows))
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -731,6 +807,11 @@ def gather_blocks_quant(pool: PagedKVPool,
 # signatures per pool shape legitimate; only unbounded growth storms.
 copy_blocks = ProfiledFunction(copy_blocks, "paged_kv.copy",
                                storm_threshold=32)
+# the pool is out of the signature scan and nothing waits: the copy is
+# ordered on the device by the next step's donation of the pool
+copy_state_rows = ProfiledFunction(copy_state_rows, "paged_kv.copy_state",
+                                   skip_args=(0,), block=False,
+                                   storm_threshold=32)
 install_blocks = ProfiledFunction(install_blocks, "paged_kv.install",
                                   storm_threshold=32)
 install_blocks_quant = ProfiledFunction(

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.config import (LatentCacheUnsupported, ModelConfig,
+                             RecurrentStateUnsupported,
                              ResidualStreamUnsupported)
 from ..models.quantize import _quantize_matrix, is_quantized
 
@@ -65,6 +66,11 @@ def init_lora(config: ModelConfig, key: jax.Array, *, rank: int = 16,
             config.name)
     if config.hc_mult:
         raise ResidualStreamUnsupported("LoRA adapters", config.name)
+    if config.ssm:
+        raise RecurrentStateUnsupported(
+            "LoRA adapters (init_lora: the mixer's projections are no "
+            "target, and the trainer has no backward of the chunked scan)",
+            config.name)
     if config.num_experts > 0:
         bad = {"w_gate", "w_up", "w_down"} & set(targets)
         if bad:

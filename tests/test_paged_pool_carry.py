@@ -372,6 +372,54 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
         assert f"f32[{entries},{c.num_heads},{width * 16}]" not in text
 
 
+@pytest.mark.parametrize("entries", [48, 192])
+def test_hybrid_step_compiled_for_v5e_copies_no_pool_and_no_state(
+        one_v5e, entries, monkeypatch):
+    """``_paged_fused_step`` at Falcon-H1-34B's widths (two of its layers;
+    the falcon cell's shapes: 48 rows of 256 blocks, 56 state rows), compiled
+    for the v5e: attention at 20/4 heads goes through ``paged_attention_rows``
+    (Mosaic compiles it; no gather), and the rows' float32 state — 4 MB a
+    row a layer — is updated where it lies: no copy of the stacked leaf or
+    of a step's 48-row slab, no temporary of a slab's size."""
+    from senweaver_ide_tpu.ops import paged_attention
+    monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
+    c = dataclasses.replace(_benchmark_config("falcon-h1-34b-instruct"),
+                            num_layers=2)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: tf.init_params(c, jax.random.PRNGKey(0))))
+    rows, width = 48, 256
+    pool = on_chip(jax.eval_shape(lambda: init_paged_pool(
+        c, 52 * width, 16, state_rows=56)))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_v5e)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = eng._paged_fused_step.lower(
+            params, c, i32(5, entries), i32(rows, width), pool,
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_v5e),
+            SampleParams(temperature=1.0), None).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = compiled.as_text()
+    slab_bytes = rows * 32 * 128 * 256 * 4
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < entries * c.vocab_size * 4 + slab_bytes / 2)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "%paged_attention_rows." in line]
+    assert calls, "the kernel is not in the compiled step"
+    assert f"bf16[{entries * width},16,4,128]" not in text
+    for shape in ("f32[2,56,32,128,256]", "f32[48,32,128,256]",
+                  "f32[1,48,32,128,256]", "bf16[2,13312,16,4,128]"):
+        for line in text.splitlines():
+            if f"= {shape}" in line:
+                assert (" copy(" not in line
+                        and "copy-start" not in line), line
+
+
 def _preset_head_shapes():
     """Every (Hq, Hkv, Dh) a full-size dense preset has, once, under the
     first preset's name."""

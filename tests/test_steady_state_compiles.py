@@ -227,6 +227,32 @@ def _group_rollout(config, params):
     return run, FUSED, 3
 
 
+def _state_group_rollout(config, params):
+    """The same group on a model with recurrent state (a state-space
+    mixer in every block): the donor's prefill stops before the prompt's
+    last token, one snapshot and seven installs of its state rows are ONE
+    program (``paged_kv.copy_state``), and every member feeds the last
+    token through the fused step's two widths."""
+    from senweaver_ide_tpu.models.config import tiny_falcon_h1_test
+    hybrid = tiny_falcon_h1_test()
+    weights = jax.block_until_ready(
+        init_params(hybrid, jax.random.PRNGKey(0)))
+    prompt = [(j * 11) % 200 + 2 for j in range(24)]
+
+    def run():
+        eng = RolloutEngine(weights, hybrid, num_slots=8, max_len=128,
+                            sample=GREEDY,
+                            engine_config=_paged(block_size=4))
+        eng.submit_group(prompt, 8, max_new_tokens=16)
+        out = eng.run()
+        assert [len(t) for t in out.values()] == [16] * 8
+        st = eng.stats()
+        assert (st["prefills"], st["group_forks"]) == (1, 7)
+        eng._alloc.check_leaks()
+        return out
+    return run, FUSED + ("paged_kv.copy_state",), 3
+
+
 def _train_step(config, params):
     """One GRPO update via training.trainer.train_step."""
     from senweaver_ide_tpu.training.trainer import train_step
@@ -359,6 +385,7 @@ CASES = {
     "migration": _migration,
     "multi_lora": _multi_lora,
     "group_rollout": _group_rollout,
+    "state_group_rollout": _state_group_rollout,
     "train_step": _train_step,
     "streaming_grpo": _streaming_grpo,
     "reward_head": _reward_head,
