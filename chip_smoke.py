@@ -56,7 +56,8 @@ from senweaver_ide_tpu.parallel import MeshConfig, make_mesh
 from senweaver_ide_tpu.rollout import RolloutEngine
 from senweaver_ide_tpu.rollout import engine as engine_mod
 from senweaver_ide_tpu.rollout.engine import EngineConfig
-from senweaver_ide_tpu.rollout.paged_kv import kv_payload_dtype
+from senweaver_ide_tpu.rollout.paged_kv import (kv_payload_dtype,
+                                              resolve_block_size)
 from senweaver_ide_tpu.rollout.sampler import SampleParams
 from senweaver_ide_tpu.runtime.compile_cache import enable_compile_cache
 from senweaver_ide_tpu.serve import ServingFleet
@@ -410,10 +411,9 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
     out["flash_decode_max_err"] = err
     check(err <= KERNEL_TOL, f"flash_decode off by {err}")
 
-    # paged_flash_decode at the engine's default block_size: one query a
-    # row through paged_attention_rows, and the quantized pools with
-    # fused dequant
-    bs = EngineConfig().block_size
+    # paged_flash_decode at blocks of 16: one query a row through
+    # paged_attention_rows, and the quantized pools with fused dequant
+    bs = 16
     t, mb = 16, s // bs
     nb = 2 * mb
     rng = np.random.default_rng(seed)
@@ -464,80 +464,58 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
     # a second head shape (mistral-7b's, llama-3.1-8b's, qwen3-8b's):
     # four times the bytes a block, a quarter of the blocks a chunk
     out.update(paged_rows_checks(32, 8, d, "_32x8", interpret, seed))
+    # Falcon-H1-34B's heads over its cell's contexts (a row of 4096)
+    out.update(paged_rows_checks(20, 4, d, "_20x4", interpret, seed,
+                                 long_rows=True))
     # the latent pool's one-leaf form at GLM-4.7-Flash's shapes: 20 heads
     # over rows of 576 values stored 640 wide, the value their first 512
     out.update(paged_rows_checks(20, 1, 640, "_latent", interpret, seed,
-                                 latent_rank=512))
+                                 latent_rank=512, long_rows=True))
     return out
 
 
 def paged_rows_checks(hq: int, hkv: int, d: int, tag: str, interpret: bool,
-                      seed: int, latent_rank: int = 0) -> dict:
+                      seed: int, latent_rank: int = 0,
+                      long_rows: bool = False) -> dict:
     """``paged_attention_rows`` against the XLA gather it replaces in the
     fused step, over a stacked pool at the benchmark cells' shapes: 48
-    rows with a table 64 blocks wide, contexts 128-900, as a narrow step
-    (48 decode entries) and a wide one (47 decode rows, a prefill chunk
-    of 137 tokens on the last row, 8 entries of padding). With a
-    ``latent_rank`` it is ``paged_latent_attention_rows`` over a latent
-    pool's one leaf of ``d``-wide rows, the value their first
-    ``latent_rank`` columns, at the glm cell's shapes: a table 256 blocks
-    wide, contexts 1024-3840, the scale of 256-wide heads. Reports each
-    path's largest error against the other and its time a call (the
-    median of 20, after the first) as ``paged_rows{tag}_{entries}_...``:
-    a bring-up reading of one layer's attention, not the benchmark's."""
+    rows of 1024 tokens, contexts 128-900 (``long_rows``: the ctx4k cells'
+    rows of 4096, contexts 1024-3840), as a narrow step (48 decode
+    entries) and a wide one (47 decode rows, a prefill chunk of 137 tokens
+    on the last row, 8 entries of padding). With a ``latent_rank`` it is
+    ``paged_latent_attention_rows`` over a latent pool's one leaf of
+    ``d``-wide rows, the value their first ``latent_rank`` columns, at the
+    scale of 256-wide heads. Twice: at blocks of 16 (``..._bs16_...``) and
+    at the block the engine resolves for this row (``..._resolved_...``,
+    ``paged_kv.resolve_block_size``; ``paged_rows{tag}_block`` says which),
+    the same tokens in a table a fraction as wide: the kernel pays for each
+    block's copy, so the pair is what a copy costs (PERF.md §6, PR 33).
+    Reports each path's largest error against the other
+    (``paged_rows{tag}_{entries}_{size}_max_err``), the kernel's time a
+    layer with 16 layers looped inside ONE program
+    (``..._{size}_ms``: a call's dispatch, ~0.6 ms, would hide a kernel of
+    0.1) and the gather's time a call (``paged_gather{tag}_{entries}_ms``,
+    blocks of 16, the median of 20 after the first): a bring-up reading of
+    one layer's attention, not the benchmark's."""
+    looped = 2 if interpret else 16
     if interpret:
-        dtype, rows, mb, layers, lo, hi, chunk, pad, reps = (
-            jnp.float32, 6, 8, 2, 20, 100, 21, 3, 1)
-    elif latent_rank:
-        dtype, rows, mb, layers, lo, hi, chunk, pad, reps = (
-            jnp.bfloat16, 48, 256, 2, 1024, 3840, 137, 8, 20)
+        dtype, rows, max_len, layers, lo, hi, chunk, pad, reps = (
+            jnp.float32, 6, 128, 2, 20, 100, 21, 3, 1)
+    elif long_rows:
+        dtype, rows, max_len, layers, lo, hi, chunk, pad, reps = (
+            jnp.bfloat16, 48, 4096, 2, 1024, 3840, 137, 8, 20)
     else:
-        dtype, rows, mb, layers, lo, hi, chunk, pad, reps = (
-            jnp.bfloat16, 48, 64, 2, 128, 900, 137, 8, 20)
-    bs = EngineConfig().block_size
-    nb = rows * mb + 4
+        dtype, rows, max_len, layers, lo, hi, chunk, pad, reps = (
+            jnp.bfloat16, 48, 1024, 2, 128, 900, 137, 8, 20)
     rng = np.random.default_rng(seed)
-    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
-    # the pool's payload leaves: k and v, or the one latent leaf
-    leaves = tuple(jax.random.normal(k, (layers, nb, bs, hkv, d), dtype)
-                   for k in ks[:1 if latent_rank else 2])
-    tables = jnp.asarray(
-        rng.permutation(nb)[:rows * mb].reshape(rows, mb).astype(np.int32))
     ctx = rng.integers(lo, hi + 1, rows).astype(np.int32)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
     layer = jnp.asarray(layers - 1, jnp.int32)
     scale = 1.0 / 16.0
     name = ("paged_latent_attention_rows" if latent_rank
             else "paged_attention_rows")
-
-    # the pool and the tables are arguments: closed over, they would be
-    # constants of the program
-    def gather(leaves, tables, q, seq_row, positions):
-        tbl = tables[seq_row]
-        valid = jnp.arange(mb * bs)[None, :] < positions[:, None] + 1
-        if latent_rank:
-            # _paged_mla_attend's gather path
-            seq = leaves[0][layer, tbl].reshape(-1, mb * bs, d)
-            scores = jnp.einsum("thc,tsc->ths", q, seq,
-                                preferred_element_type=jnp.float32) * scale
-            probs = jax.nn.softmax(
-                jnp.where(valid[:, None, :], scores, -1e30), axis=-1)
-            return jnp.einsum(
-                "ths,tsc->thc", probs.astype(dtype), seq,
-                preferred_element_type=jnp.float32)[..., :latent_rank]
-        k_seq, v_seq = (leaf[layer, tbl].reshape(-1, mb * bs, hkv, d)
-                        for leaf in leaves)
-        return attention(q[:, None], k_seq, v_seq, q_offset=positions,
-                         kv_mask=valid, causal=True)[:, 0]
-
-    def kernel(leaves, tables, q, seq_row, positions):
-        plan = plan_rows(seq_row, positions, block_size=bs, table_width=mb,
-                         q_tile=query_tile(hq))
-        if latent_rank:
-            return paged_latent_attention_rows(
-                q, *leaves, layer, tables, positions, plan, scale=scale,
-                value_dim=latent_rank, interpret=interpret)
-        return paged_attention_rows(q, *leaves, layer, tables, positions,
-                                    plan, interpret=interpret)
+    resolved = resolve_block_size(
+        hkv * d * jnp.dtype(dtype).itemsize, max_len)
 
     def ms_a_call(fn, *args):
         jax.block_until_ready(fn(*args))
@@ -553,21 +531,85 @@ def paged_rows_checks(hq: int, hkv: int, d: int, tag: str, interpret: bool,
                             np.zeros(pad)]).astype(np.int32),
             np.concatenate([ctx[:-1] - 1, ctx[-1] + np.arange(chunk),
                             np.zeros(pad)]).astype(np.int32))
-    out = {}
-    gather_jit, kernel_jit = jax.jit(gather), jax.jit(kernel)
-    for seq_row, positions in (narrow, wide):
-        t = len(seq_row)
-        q = jax.random.normal(jax.random.fold_in(ks[2], t), (t, hq, d), dtype)
-        args = (leaves, tables, q, jnp.asarray(seq_row),
-                jnp.asarray(positions))
-        if not interpret:
-            lowered_with_kernel(kernel_jit.lower(*args), name)
-        err = max_err(kernel_jit(*args), gather_jit(*args))
-        out[f"paged_rows{tag}_{t}_max_err"] = err
-        check(err <= KERNEL_TOL,
-              f"{name}{tag} over {t} entries off by {err}")
-        out[f"paged_rows{tag}_{t}_ms"] = ms_a_call(kernel_jit, *args)
-        out[f"paged_gather{tag}_{t}_ms"] = ms_a_call(gather_jit, *args)
+    out = {f"paged_rows{tag}_block": resolved}
+    # (a row whose block of 16 is large enough already is timed once)
+    sizes = {"bs16": 16, "resolved": resolved}
+    if resolved == 16:
+        del sizes["resolved"]
+    for size, bs in sizes.items():
+        mb = max_len // bs
+        nb = rows * mb + 4
+        # the pool's payload leaves: k and v, or the one latent leaf
+        leaves = tuple(jax.random.normal(k, (layers, nb, bs, hkv, d), dtype)
+                       for k in ks[:1 if latent_rank else 2])
+        tables = jnp.asarray(rng.permutation(nb)[:rows * mb].reshape(
+            rows, mb).astype(np.int32))
+
+        # the pool and the tables are arguments: closed over, they would
+        # be constants of the program
+        def gather(leaves, tables, q, seq_row, positions):
+            tbl = tables[seq_row]
+            valid = jnp.arange(mb * bs)[None, :] < positions[:, None] + 1
+            if latent_rank:
+                # _paged_mla_attend's gather path
+                seq = leaves[0][layer, tbl].reshape(-1, mb * bs, d)
+                scores = jnp.einsum(
+                    "thc,tsc->ths", q, seq,
+                    preferred_element_type=jnp.float32) * scale
+                probs = jax.nn.softmax(
+                    jnp.where(valid[:, None, :], scores, -1e30), axis=-1)
+                return jnp.einsum(
+                    "ths,tsc->thc", probs.astype(dtype), seq,
+                    preferred_element_type=jnp.float32)[..., :latent_rank]
+            k_seq, v_seq = (leaf[layer, tbl].reshape(-1, mb * bs, hkv, d)
+                            for leaf in leaves)
+            return attention(q[:, None], k_seq, v_seq, q_offset=positions,
+                             kv_mask=valid, causal=True)[:, 0]
+
+        def kernel(leaves, tables, q, seq_row, positions, trips=0):
+            plan = plan_rows(seq_row, positions, block_size=bs,
+                             table_width=mb, q_tile=query_tile(hq))
+
+            def one(at):
+                if latent_rank:
+                    return paged_latent_attention_rows(
+                        q, *leaves, at, tables, positions, plan,
+                        scale=scale, value_dim=latent_rank,
+                        interpret=interpret)
+                return paged_attention_rows(q, *leaves, at, tables,
+                                            positions, plan,
+                                            interpret=interpret)
+
+            if not trips:
+                return one(layer)
+            # the layers in turn, as the fused step's scan runs them
+            return jax.lax.fori_loop(
+                0, trips, lambda i, acc: acc + one(i % layers)[..., :1],
+                jnp.zeros(q.shape[:2] + (1,), dtype))
+
+        gather_jit, kernel_jit = jax.jit(gather), jax.jit(kernel)
+        looped_jit = jax.jit(functools.partial(kernel, trips=looped))
+        for seq_row, positions in (narrow, wide):
+            t = len(seq_row)
+            q = jax.random.normal(jax.random.fold_in(ks[2], t), (t, hq, d),
+                                  dtype)
+            args = (leaves, tables, q, jnp.asarray(seq_row),
+                    jnp.asarray(positions))
+            if not interpret:
+                lowered_with_kernel(kernel_jit.lower(*args), name)
+            err = max_err(kernel_jit(*args), gather_jit(*args))
+            out[f"paged_rows{tag}_{t}_{size}_max_err"] = err
+            check(err <= KERNEL_TOL,
+                  f"{name}{tag} over {t} entries, blocks of {bs}, off by "
+                  f"{err}")
+            out[f"paged_rows{tag}_{t}_{size}_ms"] = round(
+                ms_a_call(looped_jit, *args) / looped, 4)
+            if size == "bs16":
+                out[f"paged_gather{tag}_{t}_ms"] = ms_a_call(gather_jit,
+                                                             *args)
+    if resolved == 16:
+        out.update({k.replace("_bs16_", "_resolved_"): v
+                    for k, v in out.items() if "_bs16_" in k})
     return out
 
 

@@ -40,9 +40,9 @@ How it is laid out:
   the mask keeps the columns of a head's own kv head (``col % Hkv``).
   The other columns get probability 0, so the PV product over the same
   interleaved rows is the grouped one. No strided load, no per-head
-  loop. A decode row streams K and V through the MXU as weights either
-  way, so the other heads' columns cost it mask and exp work alone; a
-  tile of queries pays ``Hkv`` times the products.
+  loop; a tile of queries pays ``Hkv`` times the products. On a v5e a
+  copy costs ~25 ns (latent ~37) whatever it carries and a (position,
+  kv head) column 1.7-3.0 ns: hence ``paged_kv.resolve_block_size``.
 - The score tile is bounded whatever the heads (``query_tile``,
   ``blocks_per_chunk``): with many kv heads a compute step takes fewer
   blocks. Mosaic needs head rows of whole 128-lane tiles to cut a
@@ -65,9 +65,9 @@ same VMEM rows that holds the value, and the scale the model's
 under all the query heads, so an item is ``query_tile(Hq)`` queries and
 a compute step ``blocks_per_chunk(block_size, 1)`` blocks. On a v5e at
 GLM-4.7-Flash's shapes (20 heads, rows 640 wide, rank 512, 48 rows at
-contexts 1-3.8k; my chip runs, PR 29): 0.54 ms a layer for a 48-entry
-step, 65 cycles a 20 KB block (the gather and its two reads: 1.72 ms),
-0.80 ms for a 192-entry one (6.88).
+contexts 1-3.8k; my chip runs, PR 29 and 33): 0.59 ms a layer for a
+48-entry step at blocks of 16, 0.38 at 64 (the gather and its two reads:
+1.72 ms), 0.77 / 0.53 ms for a 192-entry one (6.88).
 
 ``paged_flash_decode`` is the one-query-a-row form over ONE layer's
 pool: unquantized, it is ``paged_attention_rows`` with every entry its
@@ -101,10 +101,10 @@ from .attention import NEG_INF
 # shape (``query_tile``, ``blocks_per_chunk``): 12/2 heads and blocks of
 # 16 give 32 queries x 16 blocks, 32/8 give 16 x 4, 32/32 give 16 x 1.
 # Unbounded, a tile of 32 queries x 16 blocks ran out of VMEM from 8 kv
-# heads on. On a v5e, 28 layers at the cells' shapes (12/2 x 128; my chip
-# runs, PR 27): 6.1 ms a 48-row step at 16 blocks a chunk, 6.5 at 8, 9.0
-# at 4 (the gather: 14.6); the 192-entry step 7.6 ms at (32, 16), 8.5 at
-# (32, 8) (the gather: 55.6).
+# heads on. On a v5e, 28 layers at the cells' shapes (12/2 x 128, blocks
+# of 16; my chip runs, PR 27): 6.1 ms a 48-row step at 16 blocks a chunk,
+# 6.5 at 8, 9.0 at 4 (the gather: 14.6); the 192-entry step 7.6 ms at
+# (32, 16), 8.5 at (32, 8) (the gather: 55.6). Blocks of 128: 4.2 (PR 33).
 TILE_ROWS = 512
 TILE_COLS = 512
 # head rows a query takes in the tile: whole bf16 sublane tiles, so a

@@ -49,8 +49,9 @@ from .kv_pressure import (HostPrefix, PrefixCandidate, dequantize_host,
 from .paged_kv import (BlockAllocator, BlockPayload, BlocksExhausted,
                        PagedKVPool, copy_blocks, copy_state_rows,
                        gather_blocks, gather_blocks_quant, init_paged_pool,
-                       install_blocks, install_blocks_quant,
-                       pool_bytes_per_block, resolve_kv_dtypes)
+                       install_blocks, install_blocks_quant, kv_row_bytes,
+                       pool_bytes_per_block, resolve_block_size,
+                       resolve_kv_dtypes)
 from .sampler import SampleParams
 
 
@@ -400,12 +401,25 @@ class EngineConfig:
     falls back to slots where the block pool has no equivalent yet —
     int8 KV (``kv_quant``), sliding-window ring caches, TP-sharded
     meshes — ``engine.kv_layout`` reports the effective layout and
-    ``engine.kv_layout_fallback`` the reason."""
+    ``engine.kv_layout_fallback`` the reason.
+
+    ``block_size`` left None is resolved when the engine is built, from
+    what the engine can see: the bytes of the pool's KV row and
+    ``max_len`` (``paged_kv.resolve_block_size``; docs/serving.md "Paged
+    KV cache"). ``engine.engine_config.block_size`` holds the resolved
+    int."""
 
     kv_layout: str = "paged"
     # tokens per KV block; the partial last block of each sequence is
-    # the only internal fragmentation (senweaver_kv_fragmentation)
-    block_size: int = 16
+    # the only internal fragmentation (senweaver_kv_fragmentation). None
+    # = by what the pool stores (paged_kv.resolve_block_size): a block's
+    # copy costs the attention kernels about the same whatever it
+    # carries, so a block holds as many tokens as make one payload
+    # leaf's copy paged_kv.COPY_TARGET_BYTES, 16 to 128, fewer where
+    # max_len is short. ``engine.engine_config`` holds the resolved int.
+    # The block is also the unit of prefix grafts, of copy-on-write and
+    # of the host tier. An explicit value is taken as it is.
+    block_size: Optional[int] = None
     # pool capacity in blocks; None = (num_slots + 4) rows' worth —
     # slot-cache parity plus headroom for shared prefixes, which live
     # in the same pool here instead of separate slot-shaped buffers
@@ -723,6 +737,12 @@ class RolloutEngine:
         # KV layout: paged block pool by default; the layouts the pool
         # has no equivalent for yet fall back to the slot cache.
         self.engine_config = engine_config or EngineConfig()
+        if self.engine_config.block_size is None:
+            self.engine_config = dataclasses.replace(
+                self.engine_config, block_size=resolve_block_size(
+                    kv_row_bytes(config, self.engine_config.kv_dtype,
+                                 self.engine_config.kv_dtype_per_layer),
+                    max_len))
         requested = self.engine_config.kv_layout
         if requested not in ("paged", "slots"):
             raise ValueError(f"unknown kv_layout {requested!r}")
@@ -828,6 +848,16 @@ class RolloutEngine:
             self._alloc = BlockAllocator(
                 nb, bs, registry=get_registry(),
                 bytes_per_block=pool_bytes_per_block(self.pool))
+            # What one copy of the attention kernels carries: a block of
+            # one payload leaf in one layer, as the pool stores it. With
+            # the step's kv_blocks x payload leaves a trace reader has
+            # copies a step and bytes a copy (engine.step attrs).
+            self._kv_copy_bytes = (int(np.prod(self.pool.k.shape[2:]))
+                                   * self.pool.k.dtype.itemsize)
+            get_registry().gauge(
+                "senweaver_kv_block_size",
+                "Tokens a KV pool block holds: EngineConfig.block_size as "
+                "resolved at construction.").set(bs)
             self._storm_total = get_registry().counter(
                 "senweaver_kv_preemption_storms_total",
                 "Requests preempted EngineConfig.max_preempts times and "
@@ -3403,6 +3433,8 @@ class RolloutEngine:
                 st.set_attr("prefill_tokens", prefill)
                 st.set_attr("table_width", int(tables.shape[1]))
                 st.set_attr("kv_blocks", self._kv_blocks_step)
+                st.set_attr("block_size", self._alloc.block_size)
+                st.set_attr("kv_copy_bytes", self._kv_copy_bytes)
                 st.set_attr("queue_depth", len(self._queue))
                 st.set_attr("rows_active", len(decode_rows)
                             + len(spec_rows) + len(job_rows))

@@ -319,6 +319,54 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
         v_hi=jnp.zeros(hi_shape, config.dtype) if n_hi else None)
 
 
+# What one DMA of the attention kernels should carry, in bytes: a block of
+# ONE payload leaf in ONE layer, the unit ``ops/paged_attention.py``'s
+# ``_rows_kernel`` copies. The kernel starts and waits for each copy, and a
+# copy costs it about as much whatever it carries; the rest of its time
+# follows the tokens it reads. The kernel alone on a v5e, 48 decode rows,
+# ms a layer by tokens a block (my chip runs, PR 33; PERF.md §6):
+#
+#   tokens a block              16      32      64      128     256
+#   12/2 x 128, rows of 1024    0.2138  0.1777  0.1601  0.1515  0.1458
+#   20/4 x 128, rows of 4096    1.1568  0.9746  0.8822  0.8467  (0.6649)
+#   latent row of 640, 4096     0.5924  0.4554  0.3836  0.3680  0.3724
+#
+# (in brackets: one block fills two score tiles there, another program).
+# The smallest target after which no shape gains 5% more: copies of 64
+# KiB are 128 / 64 / 64 tokens (-5.4 / -9.5 / -15.8% on the step before,
+# -3.8 / -4.0 / -4.1% on the step after).
+COPY_TARGET_BYTES = 64 << 10
+
+
+def kv_row_bytes(config: ModelConfig, kv_dtype: str = "bf16",
+                 kv_dtype_per_layer=None) -> int:
+    """Bytes one token takes in one payload leaf of one layer, AS STORED:
+    ``Hkv x head_dim`` of the pool's dtype (a quantized rung stores one
+    byte a value), or the latent pool's one padded row."""
+    payload, _ = resolve_kv_dtypes(config.num_layers, kv_dtype,
+                                   kv_dtype_per_layer)
+    if config.mla:
+        return config.latent_row_dim * jnp.dtype(config.dtype).itemsize
+    return (config.num_kv_heads * config.head_dim
+            * jnp.dtype(payload or config.dtype).itemsize)
+
+
+def resolve_block_size(row_bytes: int, max_len: int) -> int:
+    """Tokens a block holds when nobody said (``EngineConfig.block_size``
+    None): the smallest power of two, from 16 to 128, whose copy
+    (``block_size x row_bytes``, see :func:`kv_row_bytes`) reaches
+    ``COPY_TARGET_BYTES``, halved while a row of ``max_len`` tokens would
+    hold fewer than 8 blocks (a short row's last block is most of its
+    waste), never under 16. One rule of what the pool stores and how long a
+    row is; no model is named."""
+    bs = 16
+    while bs < 128 and bs * row_bytes < COPY_TARGET_BYTES:
+        bs *= 2
+    while bs > 16 and max_len // bs < 8:
+        bs //= 2
+    return bs
+
+
 def pool_bytes_per_block(pool: PagedKVPool) -> int:
     """Device bytes one block occupies across every pool tensor
     (payload + scales + full-width prefix) — the unit the allocator's

@@ -162,6 +162,35 @@ def test_kv_blocks_counts_each_runs_blocks_and_feeds_the_counter(model):
     assert eng.is_done(rid)
 
 
+@pytest.mark.parametrize("block_size,resolved", [(4, 4), (8, 8),
+                                                 (None, 16)])
+def test_step_says_the_blocks_size_and_a_copys_bytes(model, block_size,
+                                                     resolved):
+    """``block_size`` and ``kv_copy_bytes`` ride every ``engine.step``
+    beside ``kv_blocks``: the resolved block's tokens and the bytes of
+    one payload leaf's block (what one DMA of the attention kernels
+    carries), so a trace has copies a step and bytes a copy; the gauge
+    ``senweaver_kv_block_size`` says the first with tracing off."""
+    eng = make_engine(model, block_size=block_size)
+    assert f"senweaver_kv_block_size {resolved}" in \
+        obs.get_registry().render()
+    obs.enable()
+    eng.submit(list(range(1, 23)), max_new_tokens=4)         # 22 tokens
+    while eng.has_work:
+        eng.step()
+    a = [s.attrs for s in by_name(obs.get_tracer().spans())["engine.step"]]
+    config = model[1]
+    row_bytes = (config.num_kv_heads * config.head_dim
+                 * np.dtype(config.dtype).itemsize)
+    assert {x["block_size"] for x in a} == {resolved}
+    assert {x["kv_copy_bytes"] for x in a} == {resolved * row_bytes}
+    # kv_blocks counts blocks of that size: a full chunk of 16 tokens,
+    # then one of 6 and decode rows at 22, 23, 24, each of those steps
+    # with tail padding, one block more
+    assert [x["kv_blocks"] for x in a] == [15 // resolved + 1] + [
+        (last // resolved + 1) + 1 for last in (21, 22, 23, 24)]
+
+
 def test_a_requests_three_phases_share_an_id_and_abut(model):
     eng = make_engine(model)
     obs.enable()

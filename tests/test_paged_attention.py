@@ -104,39 +104,48 @@ from senweaver_ide_tpu.ops.paged_attention import (
     paged_attention_rows, paged_latent_attention_rows, plan_rows)
 
 LAYERS, NB, BS, MB, ROWS = 3, 80, 4, 12, 6
+# The block-size axis: the batches below at blocks of 4 with a table 12
+# wide, and at a block the engine resolves for a narrow KV row
+# (``paged_kv.resolve_block_size``) with a table 4 wide, where every run
+# starts at twice its position: it still begins inside a block and ends in
+# a later one. block size -> (table width, position scale)
+LAYOUTS = {BS: (MB, 1), 32: (4, 2)}
 
 
-def _private_tables():
-    """Six rows of twelve blocks, no block twice."""
-    return np.random.default_rng(0).permutation(NB)[:ROWS * MB].reshape(
-        ROWS, MB)
+def _private_tables(mb=MB):
+    """Six rows of ``mb`` blocks, no block twice."""
+    return np.random.default_rng(0).permutation(NB)[:ROWS * mb].reshape(
+        ROWS, mb)
 
 
 # name -> (seq_row, positions[, tables]); a row's entries are in order.
-def _flat_batches():
-    chunk = lambda row, lo, n: ([row] * n, list(range(lo, lo + n)))
+def _flat_batches(bs=BS):
+    mb, at = LAYOUTS[bs]
+    chunk = lambda row, lo, n: ([row] * n, list(range(at * lo, at * lo + n)))
+    rows = lambda row, pos: (row, [at * p for p in pos])
     cat = lambda *parts: tuple(np.asarray(sum((p[i] for p in parts), []),
                                           np.int32) for i in (0, 1))
-    tables = _private_tables()
+    tables = _private_tables(mb)
     forked = tables.copy()
-    forked[1, :5] = forked[0, :5]       # rows 1, 2 share row 0's first five
-    forked[2, :5] = forked[0, :5]       # blocks: a forked group's prompt
+    shared = -(-20 * at // bs)          # the blocks of a prompt of 20 (40):
+    forked[1, :shared] = forked[0, :shared]   # rows 1, 2 share row 0's, a
+    forked[2, :shared] = forked[0, :shared]   # forked group's prompt
     return {
-        "decode-rows": cat(([0, 1, 2, 3, 4, 5], [5, 0, 40, 17, 47, 16])),
+        "decode-rows": cat(rows([0, 1, 2, 3, 4, 5], [5, 0, 40, 17, 47, 16])),
         # 19 and 7 queries: tiles of 4 with a remainder, chunks of 2 blocks
-        "two-chunks": cat(([0], [9]), chunk(1, 7, 19), chunk(3, 0, 7),
-                          ([5], [30])),
-        "verify-window": cat(([0, 1], [12, 3]), chunk(2, 30, 5),
+        "two-chunks": cat(rows([0], [9]), chunk(1, 7, 19), chunk(3, 0, 7),
+                          rows([5], [30])),
+        "verify-window": cat(rows([0, 1], [12, 3]), chunk(2, 30, 5),
                              chunk(4, 8, 4)),
-        "tail-padding": cat(([2, 3], [21, 6]), chunk(5, 3, 6),
-                            ([0] * 5, [0] * 5)),
-        "forked-blocks": cat(([0, 1, 2], [19, 21, 23]), chunk(3, 0, 5))
+        "tail-padding": cat(rows([2, 3], [21, 6]), chunk(5, 3, 6),
+                            rows([0] * 5, [0] * 5)),
+        "forked-blocks": cat(rows([0, 1, 2], [19, 21, 23]), chunk(3, 0, 5))
         + (forked,),
         # row 1 decodes, row 4 has a chunk, then row 1's verify tail comes
         # in a second run: two segments of one row
         "row-in-two-runs": cat(chunk(1, 10, 3), chunk(4, 2, 6),
-                               chunk(1, 13, 2)),
-        "rows-of-length-one": cat(([3, 1, 0], [0, 0, 0])),
+                               ([1, 1], [at * 10 + 3, at * 10 + 4])),
+        "rows-of-length-one": cat(rows([3, 1, 0], [0, 0, 0])),
     }
 
 
@@ -174,16 +183,16 @@ def _gather_reference(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
                      kv_mask=valid, causal=True)[:, 0]
 
 
-def _rows_case(batch, hq, hkv, dtype, d=16):
-    seq_row, positions, *tables = _flat_batches()[batch]
-    tables = jnp.asarray(tables[0] if tables else _private_tables(),
-                         jnp.int32)
+def _rows_case(batch, hq, hkv, dtype, d=16, bs=BS):
+    seq_row, positions, *tables = _flat_batches(bs)[batch]
+    tables = jnp.asarray(
+        tables[0] if tables else _private_tables(LAYOUTS[bs][0]), jnp.int32)
     latent = hkv == LATENT
     if latent:
         hkv, d = 1, LATENT_ROW
     ks = jax.random.split(jax.random.PRNGKey(hq * 10 + hkv), 3)
     k_leaf, v_leaf = (
-        jax.random.normal(k, (LAYERS, NB, BS, hkv, d),
+        jax.random.normal(k, (LAYERS, NB, bs, hkv, d),
                           jnp.float32).astype(dtype) for k in ks[:2])
     q = jax.random.normal(ks[2], (len(seq_row), hq, d),
                           jnp.float32).astype(dtype)
@@ -193,11 +202,12 @@ def _rows_case(batch, hq, hkv, dtype, d=16):
 
 
 def _run_rows(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
-    plan = plan_rows(seq_row, positions, block_size=BS,
+    bs = k_leaf.shape[2]
+    plan = plan_rows(seq_row, positions, block_size=bs,
                      table_width=tables.shape[1], q_tile=4)
     # a score tile two blocks wide, so a run takes several compute steps
     with mock.patch.object(paged_attention, "TILE_COLS",
-                           2 * BS * k_leaf.shape[3]):
+                           2 * bs * k_leaf.shape[3]):
         if v_leaf is None:
             return paged_latent_attention_rows(
                 q, k_leaf, layer, tables, positions, plan,
@@ -209,22 +219,31 @@ def _run_rows(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
 HEADS = [(4, 4), (4, 2), (8, 1), (12, 2), (20, LATENT)]
 
 
+def _with_a_larger_block(batches, larger):
+    """(batch, block size): every batch at blocks of 4, and the ``larger``
+    ones again at the larger block, for each head shape."""
+    return ([(b, BS) for b in batches]
+            + [(b, bs) for b in larger for bs in LAYOUTS if bs != BS])
+
+
 @pytest.mark.parametrize("hq,hkv", HEADS)
-@pytest.mark.parametrize("batch", list(_flat_batches()))
-def test_rows_kernel_matches_gather_f32(batch, hq, hkv):
-    args = _rows_case(batch, hq, hkv, jnp.float32)
+@pytest.mark.parametrize("batch,bs", _with_a_larger_block(
+    list(_flat_batches()), ["two-chunks", "forked-blocks"]))
+def test_rows_kernel_matches_gather_f32(batch, bs, hq, hkv):
+    args = _rows_case(batch, hq, hkv, jnp.float32, bs=bs)
     np.testing.assert_allclose(np.asarray(_run_rows(*args)),
                                np.asarray(_gather_reference(*args)),
                                atol=2e-5, rtol=2e-5)
 
 
 @pytest.mark.parametrize("hq,hkv", HEADS)
-@pytest.mark.parametrize("batch", ["two-chunks", "forked-blocks",
-                                   "row-in-two-runs"])
-def test_rows_kernel_matches_gather_bf16(batch, hq, hkv):
+@pytest.mark.parametrize("batch,bs", _with_a_larger_block(
+    ["two-chunks", "forked-blocks", "row-in-two-runs"],
+    ["row-in-two-runs"]))
+def test_rows_kernel_matches_gather_bf16(batch, bs, hq, hkv):
     """bf16 operands, f32 scores and accumulator, like ops/attention: a
     bf16 ulp or two of an O(1) output."""
-    args = _rows_case(batch, hq, hkv, jnp.bfloat16)
+    args = _rows_case(batch, hq, hkv, jnp.bfloat16, bs=bs)
     got, want = _run_rows(*args), _gather_reference(*args)
     assert got.dtype == jnp.bfloat16
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -279,24 +298,107 @@ def test_plan_rows_cuts_runs_into_items():
     assert not plan.count[n:].any() and not plan.blocks[n:].any()
 
 
+@pytest.mark.parametrize("bs", list(LAYOUTS))
 @pytest.mark.parametrize("hq,hkv", [(4, 2), (20, LATENT)])
-def test_rows_kernel_reads_no_dead_block(hq, hkv):
+def test_rows_kernel_reads_no_dead_block(hq, hkv, bs):
     """Blocks past a run's last position are not read: NaN in them (which
     a masked column would still carry into the weighted sum), and any id
     in the dead table entries, cannot move the output."""
     q, k_leaf, v_leaf, layer, tables, seq_row, positions = _rows_case(
-        "verify-window", hq, hkv, jnp.float32)
+        "verify-window", hq, hkv, jnp.float32, bs=bs)
     want = _run_rows(q, k_leaf, v_leaf, layer, tables, seq_row, positions)
     live = np.zeros(k_leaf.shape[1], bool)
     for row, pos in zip(np.asarray(seq_row), np.asarray(positions)):
-        live[np.asarray(tables)[row, :pos // BS + 1]] = True
+        live[np.asarray(tables)[row, :pos // bs + 1]] = True
     poison = jnp.where(jnp.asarray(live)[None, :, None, None, None], 0,
                        jnp.nan)
-    dead = np.arange(MB)[None, :] > np.asarray(
-        jax.ops.segment_max(positions, seq_row, ROWS))[:, None] // BS
+    dead = np.arange(tables.shape[1])[None, :] > np.asarray(
+        jax.ops.segment_max(positions, seq_row, ROWS))[:, None] // bs
     got = _run_rows(q, k_leaf + poison,
                     None if v_leaf is None else v_leaf + poison, layer,
                     jnp.where(dead, 7, tables), seq_row, positions)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+# ---- the block's size: one rule of the KV row's bytes and the row's length
+
+
+def _kv_shape(name):
+    """A configuration's KV shape: a benchmark cell's from its own file,
+    else a preset's."""
+    from senweaver_ide_tpu.models.config import PRESETS, get_config
+    if name in PRESETS:
+        return get_config(name)
+    from benchmark import manifest
+    return manifest.model_config(manifest.load_json(
+        manifest.HERE, "configs", f"{name}.json"))
+
+
+@pytest.mark.parametrize("config,kv_dtype,max_len,row_bytes,want", [
+    # the four cells: 12/2 x 128 bf16; 20/4 x 128; the latent row, 576
+    # values stored 640 wide, under 20 and under 32 heads
+    ("qwen2.5-coder-1.5b", "bf16", 1024, 512, 128),
+    ("falcon-h1-34b-instruct", "bf16", 4096, 1024, 64),
+    ("glm-4.7-flash", "bf16", 4096, 1280, 64),
+    ("xing4.0-29b-a4b", "bf16", 4096, 1280, 64),
+    # a one-byte pool stores twice the tokens in the same copy, up to 128
+    ("qwen2.5-coder-1.5b", "fp8", 1024, 256, 128),
+    ("falcon-h1-34b-instruct", "int8", 4096, 512, 128),
+    # 8 kv heads: 32 tokens are a copy of 64 KiB; 32 kv heads: a block of
+    # 16 is one of 128 KiB already
+    ("qwen3-8b", "bf16", 4096, 2048, 32),
+    ("deepseek-coder-6.7b", "bf16", 4096, 8192, 16),
+    # a short row keeps 8 blocks; never under 16 tokens
+    ("qwen2.5-coder-1.5b", "bf16", 4096, 512, 128),
+    ("qwen2.5-coder-1.5b", "bf16", 512, 512, 64),
+    ("qwen2.5-coder-1.5b", "bf16", 256, 512, 32),
+    ("qwen2.5-coder-1.5b", "bf16", 64, 512, 16),
+    # the tiny presets' float32 rows are narrow: their size is the row's
+    # length alone, so most tests' engines (max_len <= 128) keep 16
+    ("tiny-test", "bf16", 128, None, 16),
+    ("tiny-test", "bf16", 1024, None, 128)])
+def test_block_size_follows_the_row(config, kv_dtype, max_len, row_bytes,
+                                    want):
+    """``EngineConfig.block_size`` None: the smallest power of two of
+    tokens, 16 to 128, whose copy of one payload leaf reaches
+    ``COPY_TARGET_BYTES``, halved while the row would hold under 8
+    blocks."""
+    from senweaver_ide_tpu.rollout import paged_kv
+    c = _kv_shape(config)
+    got_bytes = paged_kv.kv_row_bytes(c, kv_dtype)
+    if row_bytes is not None:
+        assert got_bytes == row_bytes
+    bs = paged_kv.resolve_block_size(got_bytes, max_len)
+    assert bs == want and isinstance(bs, int)
+    # what the rule promises, whatever the target's value
+    assert 16 <= bs <= 128 and bs & (bs - 1) == 0
+    assert bs == 16 or max_len // bs >= 8
+    assert (bs == 16 or bs // 2 * got_bytes < paged_kv.COPY_TARGET_BYTES)
+    # the pool's own leaf says the same bytes a token
+    pool = jax.eval_shape(lambda: paged_kv.init_paged_pool(
+        c, 2, bs, kv_dtype, state_rows=2 if c.ssm else 0))
+    assert (np.prod(pool.k.shape[3:]) * pool.k.dtype.itemsize == got_bytes)
+
+
+@pytest.mark.parametrize("block_size,max_len,want", [
+    (None, 64, 16), (None, 1024, 128), (8, 64, 8), (64, 1024, 64)])
+def test_engine_config_reads_back_the_resolved_block(block_size, max_len,
+                                                     want):
+    """An explicit ``block_size`` is taken as it is; None is resolved at
+    construction, and ``engine.engine_config`` holds the int the pool was
+    built with (the benchmark's warm-up reads it there)."""
+    from senweaver_ide_tpu.models.config import get_config
+    from senweaver_ide_tpu.models.transformer import init_params
+    from senweaver_ide_tpu.rollout.engine import EngineConfig, RolloutEngine
+    c = get_config("tiny-test")
+    eng = RolloutEngine(
+        init_params(c, jax.random.PRNGKey(0)), c, num_slots=2,
+        max_len=max_len, engine_config=EngineConfig(block_size=block_size))
+    bs = eng.engine_config.block_size
+    assert type(bs) is int and bs == want
+    assert eng.pool.block_size == eng._alloc.block_size == bs
+    # the same tokens in the pool whatever the block: (slots + 4) rows
+    assert eng.pool.num_blocks * bs == 6 * max_len
+    assert EngineConfig().block_size is None

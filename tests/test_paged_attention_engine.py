@@ -4,6 +4,8 @@ teacher forcing, for a dense GQA pool and a latent one, and the table's
 width where the kernel reads the pool. The kernels alone:
 ``tests/test_paged_attention.py`` (one file a worker: split for time)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -140,3 +142,51 @@ def test_table_keeps_one_width_where_the_kernel_reads_the_pool(
         p // 4 + 2 for p in range(5, 28)]
     assert all(x["kv_blocks"] <= x["entries"] * x["table_width"]
                for x in steps)
+
+
+# ---- the block's size changes where a token's KV lies, not one value -----
+
+@functools.lru_cache(maxsize=None)
+def _served_at(model, block_size):
+    """What a greedy engine serves at one block size: two lone prompts
+    through 8-entry prefill chunks, a group of three whose 22-token prompt
+    ends inside a block at every size (5 blocks and 2 tokens at 4, 1 and 6
+    at 16, 22 into the first at 64; a state-space model forks a token
+    earlier, inside a block too), so each follower's first write copies
+    the boundary block, then a follower and a lone request preempted in
+    mid-decode and recomputed. ``(tokens, log-probs)`` a request."""
+    c = get_config(model)
+    params = tf.init_params(c, jax.random.PRNGKey(2))
+    eng = RolloutEngine(
+        params, c, num_slots=6, max_len=128, seed=3,
+        sample=SampleParams(temperature=0.0),
+        engine_config=EngineConfig(block_size=block_size, step_tokens=8))
+    assert eng.kv_layout == "paged"
+    assert eng.engine_config.block_size == eng.pool.block_size == block_size
+    rids = [eng.submit(p, max_new_tokens=9)
+            for p in (list(range(1, 14)), [7, 7, 7])]
+    rids += eng.submit_group(list(range(30, 52)), 3, max_new_tokens=8)
+    while len(eng._requests[rids[-1]].tokens) < 3:
+        eng.step()
+    with eng._lock:
+        eng._preempt_row(eng._requests[rids[-1]].slot)
+        eng._preempt_row(eng._requests[rids[0]].slot)
+    eng.run()
+    st = eng.stats()
+    assert st["kv_preemptions"] == 2 and st["group_forks"] >= 1
+    assert st["kv_cow_copies"] >= 2
+    eng._alloc.check_leaks()
+    return [(eng.result(r), eng.result_logps(r)) for r in rids]
+
+
+@pytest.mark.parametrize("block_size", [16, 64])
+@pytest.mark.parametrize("model", MODELS + ["tiny-falcon-h1-test"])
+def test_a_larger_block_serves_the_same_tokens(model, block_size):
+    """The dense, the latent and the hybrid model at blocks of 16 and 64
+    against blocks of 4 (off the TPU: the gather path): the same greedy
+    tokens, the same log-probs to rounding, for the lone requests, the
+    forked group and the preempted rows."""
+    want, got = _served_at(model, 4), _served_at(model, block_size)
+    assert [t for t, _ in got] == [t for t, _ in want]
+    for (_, a), (_, b) in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-5)

@@ -31,7 +31,9 @@ from senweaver_ide_tpu.ops.norms import rms_norm
 from senweaver_ide_tpu.ops.rotary import rope_cos_sin
 from senweaver_ide_tpu.rollout.sampler import SampleParams
 from senweaver_ide_tpu.rollout import engine as eng
-from senweaver_ide_tpu.rollout.paged_kv import PagedKVPool, init_paged_pool
+from senweaver_ide_tpu.rollout.paged_kv import (PagedKVPool, init_paged_pool,
+                                                kv_row_bytes,
+                                                resolve_block_size)
 
 NUM_BLOCKS, BLOCK_SIZE, ROWS, TABLE_WIDTH = 12, 4, 3, 4
 
@@ -293,24 +295,31 @@ def _benchmark_config(name):
         manifest.HERE, "configs", f"{name}.json"))
 
 
-@pytest.mark.parametrize("model,layers,entries", [
-    ("qwen2.5-coder-1.5b", None, 48), ("qwen2.5-coder-1.5b", None, 192),
+@pytest.mark.parametrize("model,layers,entries,block", [
+    ("qwen2.5-coder-1.5b", None, 48, None),
+    ("qwen2.5-coder-1.5b", None, 192, None),
     # other head shapes, cut to four layers (a scan: the program is the
     # same) so the weights fit the described chip: 32/8 and 32/32 x 128
-    ("qwen3-8b", 4, 48), ("deepseek-coder-6.7b", 4, 192),
-    # the latent pool: 20 heads over one leaf of rows 640 wide, a table of
-    # 256 blocks (max_len 4096), the dense layer and two expert layers
-    ("glm-4.7-flash", 3, 48), ("glm-4.7-flash", 3, 192),
+    ("qwen3-8b", 4, 48, None), ("deepseek-coder-6.7b", 4, 192, None),
+    # the latent pool: 20 heads over one leaf of rows 640 wide, rows of
+    # 4096 tokens, the dense layer and two expert layers
+    ("glm-4.7-flash", 3, 48, None), ("glm-4.7-flash", 3, 192, None),
     # the same pool under 32 heads and a 4-row residual stream: both dense
     # layers and two expert layers
-    ("xing4.0-29b-a4b", 4, 48), ("xing4.0-29b-a4b", 4, 192)])
-def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
-                                                     entries, monkeypatch):
-    """``_paged_fused_step`` at a preset's widths, 48 rows of 64 blocks,
-    bf16, with ``paged_attention_rows`` (a latent pool:
-    ``paged_latent_attention_rows``) compiled by Mosaic (the test
-    says "on a TPU": the backend here is the CPU). The qwen, glm and xing
-    cases are the benchmark cells' shapes."""
+    ("xing4.0-29b-a4b", 4, 48, None), ("xing4.0-29b-a4b", 4, 192, None),
+    # an explicit block of 16, the cells' size until PR 33: tables 64 and
+    # 256 wide
+    ("qwen2.5-coder-1.5b", None, 48, 16), ("glm-4.7-flash", 3, 48, 16)])
+def test_kernel_step_compiled_for_v5e_copies_no_pool(
+        one_v5e, model, layers, entries, block, monkeypatch):
+    """``_paged_fused_step`` at a preset's widths, 48 rows of 1024 tokens
+    (the latent cells: 4096), bf16, the block the engine resolves for
+    the pool (``paged_kv.resolve_block_size``: 128 tokens and a table 8
+    wide for qwen, 64 and 64 for the latent cells, 32 and 32 at 8 kv
+    heads, 16 and 64 at 32) unless one is given, with ``paged_attention_rows`` (a latent
+    pool: ``paged_latent_attention_rows``) compiled by Mosaic (the test
+    says "on a TPU": the backend here is the CPU), the table whole in
+    SMEM. The qwen, glm and xing cases are the benchmark cells' shapes."""
     from senweaver_ide_tpu.ops import paged_attention
     monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
     latent = model in ("glm-4.7-flash", "xing4.0-29b-a4b")
@@ -322,9 +331,15 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
         tree)
     params = on_chip(jax.eval_shape(
         lambda: tf.init_params(c, jax.random.PRNGKey(0))))
-    rows, width = 48, 256 if latent else 64
+    max_len = 4096 if latent else 1024
+    bs = block or resolve_block_size(kv_row_bytes(c), max_len)
+    if block is None:
+        assert bs == {"qwen2.5-coder-1.5b": 128, "qwen3-8b": 32,
+                      "glm-4.7-flash": 64, "xing4.0-29b-a4b": 64}.get(
+                          model, 16)
+    rows, width = 48, max_len // bs
     pool = on_chip(jax.eval_shape(lambda: init_paged_pool(
-        c, 52 * width if layers is None or latent else 832, 16)))
+        c, 52 * width if layers is None or latent else 13 * width, bs)))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=one_v5e)
     cache = jax.config.jax_enable_compilation_cache
@@ -363,20 +378,23 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(one_v5e, model, layers,
                     f"= bf16[{shape}]" in line:
                 assert (" copy(" not in line
                         and "copy-start" not in line), line
-    # nothing of the gather's size is left: (entries x width, 16, Hkv, Dh),
-    # the latent rows' (entries x width, 16, row) and the scores over them
-    assert (f"bf16[{entries * width},16,{c.num_kv_heads},{c.head_dim}]"
+    # nothing of the gather's size is left: (entries x width, block, Hkv,
+    # Dh), the latent rows' (entries x width, block, row) and the scores
+    # over them
+    assert (f"bf16[{entries * width},{bs},{c.num_kv_heads},{c.head_dim}]"
             not in text)
     if latent:
-        assert f"bf16[{entries * width},16,{c.latent_row_dim}]" not in text
-        assert f"f32[{entries},{c.num_heads},{width * 16}]" not in text
+        assert (f"bf16[{entries * width},{bs},{c.latent_row_dim}]"
+                not in text)
+        assert f"f32[{entries},{c.num_heads},{width * bs}]" not in text
 
 
 @pytest.mark.parametrize("entries", [48, 192])
 def test_hybrid_step_compiled_for_v5e_copies_no_pool_and_no_state(
         one_v5e, entries, monkeypatch):
     """``_paged_fused_step`` at Falcon-H1-34B's widths (two of its layers;
-    the falcon cell's shapes: 48 rows of 256 blocks, 56 state rows), compiled
+    the falcon cell's shapes: 48 rows of 4096 tokens in the blocks of 64
+    the engine resolves for 20/4 heads, 56 state rows), compiled
     for the v5e: attention at 20/4 heads goes through ``paged_attention_rows``
     (Mosaic compiles it; no gather), and the rows' float32 state — 4 MB a
     row a layer — is updated where it lies: no copy of the stacked leaf or
@@ -390,9 +408,11 @@ def test_hybrid_step_compiled_for_v5e_copies_no_pool_and_no_state(
         tree)
     params = on_chip(jax.eval_shape(
         lambda: tf.init_params(c, jax.random.PRNGKey(0))))
-    rows, width = 48, 256
+    bs = resolve_block_size(kv_row_bytes(c), 4096)
+    assert bs == 64
+    rows, width = 48, 4096 // bs
     pool = on_chip(jax.eval_shape(lambda: init_paged_pool(
-        c, 52 * width, 16, state_rows=56)))
+        c, 52 * width, bs, state_rows=56)))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=one_v5e)
     cache = jax.config.jax_enable_compilation_cache
@@ -411,9 +431,10 @@ def test_hybrid_step_compiled_for_v5e_copies_no_pool_and_no_state(
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "%paged_attention_rows." in line]
     assert calls, "the kernel is not in the compiled step"
-    assert f"bf16[{entries * width},16,4,128]" not in text
+    assert f"bf16[{entries * width},{bs},4,128]" not in text
     for shape in ("f32[2,56,32,128,256]", "f32[48,32,128,256]",
-                  "f32[1,48,32,128,256]", "bf16[2,13312,16,4,128]"):
+                  "f32[1,48,32,128,256]",
+                  f"bf16[2,{52 * width},{bs},4,128]"):
         for line in text.splitlines():
             if f"= {shape}" in line:
                 assert (" copy(" not in line
@@ -439,10 +460,11 @@ def test_every_preset_takes_a_path_that_compiles_for_v5e(one_v5e, model,
                                                          monkeypatch):
     """On a TPU ``forward_paged`` sends a dense unquantized pool to the
     kernel only where Mosaic compiles it: at each preset's head shape the
-    kernel alone, over a table as wide as the engine hands it (64 blocks:
-    the kernel's table keeps ``blocks_per_row``), at a narrow and a wide
-    step's entries, with the pool's leaves not copied on the way in. A
-    ``head_dim`` of 64 keeps the gather."""
+    kernel alone, over a table as wide as the engine hands it (a row of
+    1024 tokens in blocks of 16 and in the blocks the engine resolves for
+    these heads: the kernel's table keeps ``blocks_per_row``), at a narrow
+    and a wide step's entries, with the pool's leaves not copied on the
+    way in. A ``head_dim`` of 64 keeps the gather."""
     from senweaver_ide_tpu.ops import paged_attention as pa
     monkeypatch.setattr(pa, "on_tpu", lambda: True)
     c = get_config(model)
@@ -451,21 +473,26 @@ def test_every_preset_takes_a_path_that_compiles_for_v5e(one_v5e, model,
         assert not tf.reads_pool_in_place(c, None)
         return
     assert tf.reads_pool_in_place(c, None)
-    bs, width, rows, nb, layers = 16, 64, 48, 832, 2
+    rows, layers = 48, 2
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                        sharding=one_v5e)
 
     def attend(q, k_leaf, v_leaf, tables, seq_row, positions):
-        plan = pa.plan_rows(seq_row, positions, block_size=bs,
-                            table_width=width, q_tile=pa.query_tile(hq))
+        plan = pa.plan_rows(seq_row, positions, block_size=k_leaf.shape[2],
+                            table_width=tables.shape[1],
+                            q_tile=pa.query_tile(hq))
         return pa.paged_attention_rows(q, k_leaf, v_leaf, jnp.int32(1),
                                        tables, positions, plan)
 
-    leaf = struct((layers, nb, bs, hkv, d), jnp.bfloat16)
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        for entries in (48, 192):
+        for bs, entries in sorted(
+                {(b, e) for b in (16, resolve_block_size(kv_row_bytes(c),
+                                                         1024))
+                 for e in (48, 192)}):
+            width = 1024 // bs
+            leaf = struct((layers, 13 * width, bs, hkv, d), jnp.bfloat16)
             text = jax.jit(attend).lower(
                 struct((entries, hq, d), jnp.bfloat16), leaf, leaf,
                 struct((rows, width), jnp.int32),
