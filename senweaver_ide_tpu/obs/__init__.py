@@ -15,7 +15,9 @@ search, trace collector) fetch the PROCESS-GLOBAL tracer/registry via
 OFF — ``span()`` then returns a shared no-op context manager, so
 instrumentation sites cost one branch. A span is on under
 :func:`enable` or inside a ``jax.profiler`` session, and is then also a
-``TraceAnnotation`` on the device trace's clock. Enable with::
+``TraceAnnotation`` in the trace's host plane (that plane's clock, not
+the device's: ``benchmark/readers/idle_ledger.py`` joins the two step
+by step). Enable with::
 
     from senweaver_ide_tpu import obs
     obs.enable(span_jsonl="spans.jsonl")     # spans stream as they finish

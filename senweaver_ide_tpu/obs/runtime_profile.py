@@ -26,11 +26,20 @@ maintains, per wrapped function:
   ``profiled_device_get`` is the device→host counterpart;
 - **XLA cost analysis** (``lowered.compile().cost_analysis()``): FLOPs
   and bytes touched per compiled signature, turned into
-  achieved-vs-roofline utilization gauges against
-  ``SENWEAVER_PEAK_FLOPS`` / ``SENWEAVER_PEAK_BYTES_PER_SEC``. OFF by
+  achieved-vs-roofline utilization gauges against the device's
+  published peaks (:data:`DEVICE_PEAKS`, by ``device_kind``; a device
+  that is not in the table gets no utilization gauge). OFF by
   default (it costs one extra trace+compile per new signature) — enable
   with ``get_profiler().set_cost_analysis(True)`` or
   ``SENWEAVER_RUNTIME_COST_ANALYSIS=1``;
+- **unqueued time** for a function whose caller times the step itself
+  (``begin_step`` / ``end_step``): the time between one step's results
+  reaching the host and the same caller's next launch, in which that
+  caller had no such step in flight (``unqueued_ms_sum``,
+  ``senweaver_runtime_unqueued_ms``; a wait for work counts too) —
+  with no profiler attached and one busy engine on the device,
+  ``unqueued / (unqueued + step)`` is a lower bound of the share of
+  time the device waited for the host;
 - **HBM/live-buffer watermark sampling** (:func:`sample_memory`):
   ``device.memory_stats()`` where the backend provides it (TPU/GPU),
   degrading to live-array byte accounting on CPU — the gauges carry a
@@ -65,6 +74,28 @@ from .metrics import DEFAULT_MS_BUCKETS
 from .tracing import get_tracer
 
 _COMPILE_EVENT_PREFIX = "/jax/core/compile/"
+
+# Published peaks of one chip, by ``device_kind`` (the v5e's are Google
+# Cloud's "TPU v5e" page: 197 TFLOP/s bf16, 819 GB/s of HBM; the
+# benchmark's ``peaks.json`` holds the same two). A device that is not
+# here has no utilization: nothing is guessed.
+DEVICE_PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops_per_s": 197e12, "bytes_per_s": 819e9},
+}
+
+
+def device_kind() -> Optional[str]:
+    """``device_kind`` of the first device of the backend in use."""
+    try:
+        return jax.devices()[0].device_kind
+    except Exception:
+        return None
+
+
+def device_peaks() -> Dict[str, float]:
+    """This process's row of :data:`DEVICE_PEAKS`; empty for a device
+    the table does not have."""
+    return DEVICE_PEAKS.get(device_kind() or "", {})
 
 # The wrapper's two spans are children: on under an enabled tracer or a
 # span that is open around the call, and never ask the profiler
@@ -206,6 +237,9 @@ class _FnLedger:
         self.d2h_bytes = 0
         self.step_ms_sum = 0.0
         self.last_step_ms = 0.0
+        # caller-timed steps (begin_step / end_step): the time between
+        # a caller's end_step and its next begin_step
+        self.unqueued_ms_sum = 0.0
         self.signatures: Dict[Tuple, _SigEntry] = {}
         # cost analysis per signature: sig -> (flops, bytes) or None
         self.cost: Dict[Tuple, Optional[Tuple[float, float]]] = {}
@@ -228,6 +262,7 @@ class _FnLedger:
             "h2d_bytes": self.h2d_bytes, "d2h_bytes": self.d2h_bytes,
             "step_ms_sum": round(self.step_ms_sum, 3),
             "last_step_ms": round(self.last_step_ms, 3),
+            "unqueued_ms_sum": round(self.unqueued_ms_sum, 3),
             "blocking": self.blocking,
             "flops_per_call": flops, "cost_bytes_per_call": cbytes,
             "signatures": sigs,
@@ -300,6 +335,12 @@ class RuntimeProfiler:
                     "Per-call wall time (device window when the "
                     "wrapper blocks on outputs, dispatch otherwise).",
                     labelnames=("fn",), buckets=DEFAULT_MS_BUCKETS),
+                "unqueued_ms": reg.histogram(
+                    "senweaver_runtime_unqueued_ms",
+                    "Time between a caller-timed step's results "
+                    "reaching the host and that caller's next launch: "
+                    "it had no such step in flight.",
+                    labelnames=("fn",), buckets=DEFAULT_MS_BUCKETS),
                 "storms": reg.counter(
                     "senweaver_runtime_retrace_storms_total",
                     "Retrace-storm detector trips: compiles exceeded "
@@ -329,9 +370,9 @@ class RuntimeProfiler:
                     "the last profiled call.", labelnames=("fn",)),
                 "roofline": reg.gauge(
                     "senweaver_runtime_roofline_utilization",
-                    "Achieved / peak per resource (peaks from "
-                    "SENWEAVER_PEAK_FLOPS and "
-                    "SENWEAVER_PEAK_BYTES_PER_SEC).",
+                    "Achieved / peak per resource (published peaks of "
+                    "the device kind; absent for a kind the program's "
+                    "table does not have).",
                     labelnames=("fn", "resource")),
             }
             self._registry = reg
@@ -374,8 +415,9 @@ class RuntimeProfiler:
             return max(costs) if costs else None
 
     def utilization(self, name: str) -> Optional[Dict[str, float]]:
-        """Achieved FLOP/s (and utilization vs SENWEAVER_PEAK_FLOPS)
-        from the last blocking call's device window."""
+        """Achieved FLOP/s (and utilization against the device kind's
+        published peak, where :data:`DEVICE_PEAKS` has the kind) from
+        the last blocking call's device window."""
         with self._lock:
             led = self._ledgers.get(name)
             if led is None or not led.blocking or led.last_step_ms <= 0:
@@ -385,7 +427,7 @@ class RuntimeProfiler:
                 return None
             achieved = max(costs) / (led.last_step_ms / 1_000.0)
         out = {"achieved_flops_per_sec": achieved}
-        peak = _env_float("SENWEAVER_PEAK_FLOPS")
+        peak = device_peaks().get("flops_per_s")
         if peak:
             out["utilization"] = achieved / peak
         return out
@@ -417,20 +459,37 @@ class RuntimeProfiler:
                 self._caller_steps.add(name)
         return time.perf_counter()
 
-    def end_step(self, name: str, t_begin: float) -> None:
+    def end_step(self, name: str, t_begin: float,
+                 last_end: float = 0.0) -> float:
         """The step begun at ``t_begin`` has its results on the host:
         its time goes to ``name``'s ledger (``step_ms_sum``,
-        ``last_step_ms``) and the step histogram."""
+        ``last_step_ms``) and the step histogram. Returns the clock
+        reading it took (0.0 where it recorded nothing), for the caller
+        to keep and hand back as ``last_end`` at its next step: the time
+        from there to ``t_begin`` — the caller had no step of ``name``
+        in flight — goes to ``unqueued_ms_sum`` and its histogram. The
+        reading is the CALLER's, not the ledger's: several engines step
+        in one process under one name, and one's launch may precede
+        another's fetch."""
         if not self.enabled:
-            return
-        step_ms = (time.perf_counter() - t_begin) * 1_000.0
+            return 0.0
+        t_end = time.perf_counter()
+        step_ms = (t_end - t_begin) * 1_000.0
+        unqueued_ms = None
         with self._lock:
             led = self._ledgers.get(name)
             if led is None:
-                return
+                return 0.0
             led.step_ms_sum += step_ms
             led.last_step_ms = step_ms
-        self._metrics()["step_ms"].observe(step_ms, fn=name)
+            if 0.0 < last_end <= t_begin:
+                unqueued_ms = (t_begin - last_end) * 1_000.0
+                led.unqueued_ms_sum += unqueued_ms
+        ins = self._metrics()
+        ins["step_ms"].observe(step_ms, fn=name)
+        if unqueued_ms is not None:
+            ins["unqueued_ms"].observe(unqueued_ms, fn=name)
+        return t_end
 
     def maybe_cost_analysis(self, pf: "ProfiledFunction", sig: Tuple,
                             args: Tuple, kwargs: Dict[str, Any]
@@ -512,11 +571,12 @@ class RuntimeProfiler:
             if pf.block and step_ms > 0:
                 step_s = step_ms / 1_000.0
                 ins["achieved"].set(flops / step_s, fn=name)
-                peak = _env_float("SENWEAVER_PEAK_FLOPS")
+                peaks = device_peaks()
+                peak = peaks.get("flops_per_s")
                 if peak:
                     ins["roofline"].set(flops / step_s / peak,
                                         fn=name, resource="flops")
-                peak_bw = _env_float("SENWEAVER_PEAK_BYTES_PER_SEC")
+                peak_bw = peaks.get("bytes_per_s")
                 if peak_bw and cbytes:
                     ins["roofline"].set(cbytes / step_s / peak_bw,
                                         fn=name, resource="bytes")
@@ -594,16 +654,6 @@ class RuntimeProfiler:
             if agg["bytes_limit"]:
                 limit_g.set(agg["bytes_limit"], backend=platform)
         return by_backend
-
-
-def _env_float(name: str) -> Optional[float]:
-    raw = os.environ.get(name)
-    if not raw:
-        return None
-    try:
-        return float(raw)
-    except ValueError:
-        return None
 
 
 # -- the wrapper ---------------------------------------------------------

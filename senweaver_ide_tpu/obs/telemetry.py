@@ -22,14 +22,14 @@ XLA ``cost_analysis()`` FLOPs figure for the profiled GRPO step, the
 FLOPs per update over the round's wall time — instead of the analytic
 ``6 * params * tokens`` estimate (fwd 2x + bwd 4x), which remains the
 fallback when cost analysis is off. ``mfu_source`` in the returned dict
-says which one you got. Peak FLOPs comes from the constructor or the
-``SENWEAVER_PEAK_FLOPS`` env var (e.g. 1.97e14 for a v5e chip in bf16);
-without it the absolute achieved FLOP/s gauge still publishes.
+says which one you got. Peak FLOPs comes from the constructor or from
+the device kind's published peak (``runtime_profile.DEVICE_PEAKS``:
+1.97e14 for a v5e chip in bf16); for a device that table does not have
+the absolute achieved FLOP/s gauge still publishes and no MFU does.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Any, Dict, Optional
 
 from .metrics import MetricsRegistry
@@ -88,8 +88,8 @@ class StepTelemetry:
         self.registry = registry
         self.param_count = param_count
         if peak_flops is None:
-            env = os.environ.get("SENWEAVER_PEAK_FLOPS")
-            peak_flops = float(env) if env else None
+            from .runtime_profile import device_peaks
+            peak_flops = device_peaks().get("flops_per_s")
         self.peak_flops = peak_flops
         r = registry
         self._tps = r.gauge(

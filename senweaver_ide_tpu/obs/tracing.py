@@ -15,10 +15,15 @@ A span is ON when the tracer is enabled (``obs.enable()``) or a JAX
 profiler session is running (``jax.profiler.start_trace`` ..
 ``stop_trace``). A span that is on is also a
 ``jax.profiler.TraceAnnotation``: inside a session it is an event of the
-trace's ``/host:CPU`` plane, on the clock of the device's ``XLA Ops``, so
-a device gap can be put to the host phase that covers it. Its record
-carries ``start_ns``/``end_ns`` from ``time.perf_counter_ns()`` — the
-clock a driving loop stamps its steps and tokens with — beside the epoch
+trace's ``/host:CPU`` plane, on the HOST plane's clock. The device's
+lines (``XLA Modules``, ``XLA Ops``) are offset from that clock by a few
+tenths of a millisecond or more, so a device gap is not put to a host phase by
+comparing timestamps: the benchmark's ``readers/idle_ledger.py`` joins
+the spans to the device's runs step by step and attributes a gap from
+each clock alone.
+A span's record carries ``start_ns``/``end_ns`` from
+``time.perf_counter_ns()`` — the clock a driving loop stamps its steps
+and tokens with, and the one the idle ledger reads — beside the epoch
 ``start_s`` the exporters use.
 
 Design constraints, in order:

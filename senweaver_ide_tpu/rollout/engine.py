@@ -1028,6 +1028,9 @@ class RolloutEngine:
         # only inside it: the step's span sites and the request.* spans
         # of phases that end in the step read it instead of asking again.
         self._trace_on = False                  # guarded-by: _lock
+        # the profiler's clock when the last fused step's results were
+        # on the host (0.0: none yet), for the next step's unqueued time
+        self._fetched_at = 0.0                  # guarded-by: _lock
         # Many agent loops (subagent threads) drive one engine: all state
         # mutation is serialized; concurrency = slots, not host threads.
         self._lock = threading.RLock()
@@ -2938,11 +2941,16 @@ class RolloutEngine:
                     tgt = self._alloc.cow_target(table[lb])
                     if tgt is not None:
                         # the donor's refcount keeps the source block
-                        # alive; ours moved to `tgt` inside cow_target
-                        self.pool = copy_blocks(
-                            self.pool,
-                            jnp.asarray([table[lb]], jnp.int32),
-                            jnp.asarray([tgt], jnp.int32))
+                        # alive; ours moved to `tgt` inside cow_target.
+                        # The copy blocks and the two index arrays are
+                        # dispatches of their own: the span holds all
+                        # three (`idle_gap_copies_ms` reads it)
+                        with (get_tracer().span if self._trace_on
+                              else noop_span)("engine.cow_copy"):
+                            self.pool = copy_blocks(
+                                self.pool,
+                                jnp.asarray([table[lb]], jnp.int32),
+                                jnp.asarray([tgt], jnp.int32))
                         table[lb] = tgt
                 else:
                     raise AssertionError(
@@ -3460,7 +3468,15 @@ class RolloutEngine:
                                 (time.perf_counter() - t_wait) * 1_000.0)
                     sp.set_attr("bytes", int(toks.nbytes + logps.nbytes))
                 self._host_syncs_total.inc()
-            get_profiler().end_step("engine.fused_step", t_launch)
+            # no fused step of THIS engine was in flight from its last
+            # step's fetch to this one's launch: emit, the caller, plan,
+            # copies (and the wait for work, where there was none)
+            fetched = get_profiler().end_step(
+                "engine.fused_step", t_launch, self._fetched_at)
+            if st is not None and fetched and self._fetched_at:
+                st.set_attr("unqueued_ms",
+                            (t_launch - self._fetched_at) * 1_000.0)
+            self._fetched_at = fetched
             if self._moe_counters is not None:
                 self._note_moe_step(st, toks, decode_rows, spec_rows,
                                     job_rows)
@@ -3493,12 +3509,13 @@ class RolloutEngine:
         ``.dispatch`` child) is the wrapper's bookkeeping and the two
         transfer requests. On the v5e host a new shape's lowering time
         follows the summed frame sizes from ``step()`` down to this
-        call (PERF.md §6, PR 24 and 31): the six unused locals below
-        (seven until ``_step_paged`` gained one of its own in PR 32)
+        call (PERF.md §6, PR 24 and 31): the five unused locals below
+        (seven until ``_step_paged`` gained one of its own in PR 32 and
+        another in PR 35: ``fetched``)
         keep this frame and ``_step_paged``'s at the 67 slots they had
         together before PR 31, measured on the chip to be worth 0.5 s
         of a qwen cell's warm-up and 0.9 s of glm's (ROADMAP D10)."""
-        b0 = b1 = b2 = b3 = b4 = b5 = None           # frame ballast
+        b0 = b1 = b2 = b3 = b4 = None                # frame ballast
         with span("engine.launch") as sp:
             next_tok, logp, self.pool, self._key = _paged_fused_step(
                 self.params, self.config, vectors, tables, self.pool,

@@ -1,6 +1,6 @@
 """Spans inside ``RolloutEngine.step()`` (paged path) and over a request's
 life: names, nesting, the counts their attrs carry, the profiler-session
-path onto the device trace's clock, and what the off path costs."""
+path into the trace's host plane, and what the off path costs."""
 
 import glob
 import os
@@ -257,9 +257,11 @@ def test_a_preempted_request_keeps_its_first_scheduled_stamp(model):
 
 def test_profiler_session_alone_turns_spans_on_and_lands_them_in_the_trace(
         model, tmp_path):
-    """The shared-clock path, without a chip: the tracer stays disabled, a
-    ``jax.profiler`` session runs, and the engine's spans are both in
-    memory and events of the trace's host plane."""
+    """The profiler-session path, without a chip: the tracer stays
+    disabled, a ``jax.profiler`` session runs, and the engine's spans are
+    both in memory and events of the trace's host plane (on that plane's
+    clock; the device's lines have their own,
+    ``benchmark/readers/idle_ledger.py``)."""
     from jax.profiler import ProfileData
     eng = make_engine(model)
     eng.submit(PROMPTS[1], max_new_tokens=2)
@@ -317,6 +319,13 @@ def test_off_path_asks_the_profiler_once_a_step_and_records_nothing(
     assert steps > 3 and len(calls) == steps
     assert obs.get_tracer().spans() == []
     assert all(eng.is_done(r) for r in rids)
+    # the unqueued counter needs no tracing: one reading between every
+    # two fused steps, in the ledger and on /metrics
+    from senweaver_ide_tpu.obs.runtime_profile import get_profiler
+    assert get_profiler().ledger()["engine.fused_step"][
+        "unqueued_ms_sum"] > 0.0
+    assert (f'senweaver_runtime_unqueued_ms_count{{fn="engine.fused_step"}}'
+            f' {steps - 1}' in obs.get_registry().render())
 
 
 def test_same_seed_same_tokens_and_logps_with_tracing_on_and_off(model):
@@ -328,8 +337,104 @@ def test_same_seed_same_tokens_and_logps_with_tracing_on_and_off(model):
         eng = make_engine(model, seed=11)
         rids = drive(eng)
         outs.append([(eng.result(r), eng.result_logps(r)) for r in rids])
+        # the group's followers split the block they share: the on run
+        # went through ``engine.cow_copy``, the off run through no span
+        names = by_name(obs.get_tracer().spans())
+        assert ("engine.cow_copy" in names) == on
+        assert eng._alloc.counters()["cow_copies"] == 2
+        assert bool(names) == on
     assert outs[0] == outs[1]
     assert any(len(set(t)) > 1 for t, _ in outs[0])
+
+
+def test_a_followers_first_write_is_a_cow_copy_span_under_the_plan(model):
+    """A group's prompt ends inside a block: each follower's first write
+    splits the block it shares (``_ensure_block``), a blocking
+    ``paged_kv.copy`` and two index arrays, all inside ``engine.cow_copy``
+    under ``engine.assemble_plan``; the spans add up to the allocator's
+    counter (a step's count of copies is its ``engine.cow_copy`` spans)."""
+    eng = make_engine(model)
+    obs.enable()
+    drive(eng)
+    spans = obs.get_tracer().spans()
+    byid = {s.span_id: s for s in spans}
+    names = by_name(spans)
+    copies = names["engine.cow_copy"]
+    n = eng._alloc.counters()["cow_copies"]
+    assert len(copies) == n == 2
+    for c in copies:
+        assert byid[c.parent_id].name == "engine.assemble_plan"
+        assert c.attrs == {}
+        # the copy's own dispatch and wait are inside it, not beside it
+        kids = [s.name for s in spans if s.parent_id == c.span_id]
+        assert kids == ["paged_kv.copy.dispatch", "paged_kv.copy.wait"]
+    for c in copies:
+        (st,) = [s for s in names["engine.step"]
+                 if s.start_ns <= c.start_ns and c.end_ns <= s.end_ns]
+        assert "cow_copies" not in st.attrs
+    assert (f"senweaver_kv_cow_copies_total {n}"
+            in obs.get_registry().render())
+
+
+def test_unqueued_ms_rides_the_step_and_is_the_profilers_reading(model):
+    """From the second fused step on ``engine.step`` carries
+    ``unqueued_ms``: the time from the last step's tokens on the host to
+    this step's launch (emit, the caller, plan, copies), as
+    ``RuntimeProfiler.end_step`` counted it. It lies between the spans
+    that bound it."""
+    from senweaver_ide_tpu.obs.runtime_profile import get_profiler
+    eng = make_engine(model)
+    obs.enable()
+    drive(eng)
+    spans = obs.get_tracer().spans()
+    steps = by_name(spans)["engine.step"]
+    kid = lambda st, name: next(s for s in spans if s.name == name
+                                and s.parent_id == st.span_id)
+    assert "unqueued_ms" not in steps[0].attrs
+    for prev, cur in zip(steps, steps[1:]):
+        got = cur.attrs["unqueued_ms"]
+        inner = (kid(cur, "engine.plan").end_ns
+                 - kid(prev, "engine.emit").start_ns) / 1e6
+        outer = (kid(cur, "engine.launch").start_ns
+                 - kid(prev, "engine.fetch").end_ns) / 1e6
+        assert 0.0 < inner <= got <= outer
+    led = get_profiler().ledger()["engine.fused_step"]
+    assert led["unqueued_ms_sum"] == pytest.approx(
+        sum(s.attrs["unqueued_ms"] for s in steps[1:]), abs=0.01)
+    hist = obs.get_registry().get("senweaver_runtime_unqueued_ms")
+    assert hist.snapshot(fn="engine.fused_step")["count"] == len(steps) - 1
+
+
+def test_two_engines_in_two_threads_each_read_their_own_unqueued_time(
+        model):
+    """``serve/replica.py`` steps several engines in one process, each in a
+    thread of its own and all under ``engine.fused_step``: one's launch
+    precedes another's fetch. Each engine keeps its own last fetch, so no
+    ``unqueued_ms`` is negative, an engine's first step carries none
+    (whatever the other did before), and the name's sum is the sum of the
+    attrs."""
+    import threading
+    from senweaver_ide_tpu.obs.runtime_profile import get_profiler
+    engines = [make_engine(model, seed=s) for s in (3, 4)]
+    obs.enable()
+    threads = [threading.Thread(target=drive, args=(e,)) for e in engines]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    steps = by_name(obs.get_tracer().spans())["engine.step"]
+    assert len(steps) == sum(e.stats()["decode_steps"] for e in engines)
+    firsts = [s for s in steps if s.attrs["step"] == 0]
+    assert len(firsts) == 2
+    assert all("unqueued_ms" not in s.attrs for s in firsts)
+    got = [s.attrs["unqueued_ms"] for s in steps if s.attrs["step"] > 0]
+    assert len(got) == len(steps) - 2 and min(got) > 0.0
+    led = get_profiler().ledger()["engine.fused_step"]
+    assert led["unqueued_ms_sum"] == pytest.approx(sum(got), abs=0.01)
+    hist = obs.get_registry().get("senweaver_runtime_unqueued_ms")
+    snap = hist.snapshot(fn="engine.fused_step")
+    assert snap["count"] == len(got)
+    assert snap["sum"] == pytest.approx(sum(got), abs=0.01)
 
 
 def test_engine_counters_are_on_metrics_with_span_tracing_off(model):

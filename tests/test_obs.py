@@ -314,7 +314,7 @@ def test_estimate_mfu():
 
 
 def test_step_telemetry_publishes_round(monkeypatch):
-    monkeypatch.delenv("SENWEAVER_PEAK_FLOPS", raising=False)
+    monkeypatch.setenv("SENWEAVER_PEAK_FLOPS", "7.0")     # read by nothing
     r = MetricsRegistry()
     tele = StepTelemetry(r, param_count=1000, peak_flops=1e9)
     out = tele.record_round(collect_s=2.0, batch_build_s=0.5,
@@ -339,10 +339,21 @@ def test_step_telemetry_publishes_round(monkeypatch):
     assert r.get("senweaver_rounds_total").value() == 2
 
 
-def test_step_telemetry_peak_flops_env(monkeypatch):
+@pytest.mark.parametrize("kind,peak", [("TPU v5 lite", 197e12),
+                                       ("TPU v9 unheard of", None)])
+def test_step_telemetry_peak_flops_from_the_device_kind(monkeypatch, kind,
+                                                        peak):
+    """No constructor argument: the device kind's published peak
+    (``runtime_profile.DEVICE_PEAKS``), and none for a kind the table
+    lacks — the environment is not asked."""
+    from senweaver_ide_tpu.obs import runtime_profile
     monkeypatch.setenv("SENWEAVER_PEAK_FLOPS", "2e9")
+    monkeypatch.setattr(runtime_profile, "device_kind", lambda: kind)
     tele = StepTelemetry(MetricsRegistry(), param_count=10)
-    assert tele.peak_flops == 2e9
+    assert tele.peak_flops == peak
+    out = tele.record_round(collect_s=1.0, batch_build_s=0.1, train_s=0.5,
+                            batch_tokens=64)
+    assert ("mfu" in out) == (peak is not None)
 
 
 # ---- legacy bridges ----

@@ -116,6 +116,113 @@ def test_a_caller_times_the_step_of_a_non_blocking_wrap():
     assert prof.ledger()["t.report"]["calls"] == 2
 
 
+def test_unqueued_and_step_time_tile_the_span_of_the_steps():
+    """Between one ``end_step`` and its caller's next ``begin_step`` the
+    caller had no step in flight: that time is ``unqueued_ms_sum`` and one
+    histogram observation, from the second step on, with no clock read of
+    its own — so the two sums tile first begin to last end exactly."""
+    import time
+    wrap(jax.jit(lambda x: x + 1), "t.tile", block=False)(jnp.ones((4,)))
+    prof = get_profiler()
+    own = prof.ledger()["t.tile"]["step_ms_sum"]    # the wrapper's dispatch
+    begins, ends = [], [0.0]
+    for pause in (0.0, 0.02, 0.005, 0.03):
+        time.sleep(pause)                       # the host between steps
+        begins.append(prof.begin_step("t.tile"))
+        time.sleep(0.01)                        # the step in flight
+        ends.append(prof.end_step("t.tile", begins[-1], ends[-1]))
+    between = [(b - e) * 1e3 for b, e in zip(begins[1:], ends[1:])]
+    assert between[0] >= 20.0 and between[2] >= 30.0 > between[1] >= 5.0
+    snap = prof.ledger()["t.tile"]
+    assert snap["unqueued_ms_sum"] == pytest.approx(sum(between), abs=0.002)
+    assert snap["unqueued_ms_sum"] + snap["step_ms_sum"] - own == \
+        pytest.approx((ends[-1] - begins[0]) * 1e3, abs=0.005)
+    hist = obs.get_registry().get("senweaver_runtime_unqueued_ms")
+    got = hist.snapshot(fn="t.tile")
+    assert got["count"] == 3
+    assert got["sum"] == pytest.approx(snap["unqueued_ms_sum"], abs=0.002)
+    text = obs.get_registry().render()
+    assert 'senweaver_runtime_unqueued_ms_count{fn="t.tile"} 3' in text
+    # a reading from after the launch is no unqueued time: nothing negative
+    # is ever observed
+    t = prof.begin_step("t.tile")
+    assert prof.end_step("t.tile", t, t + 1.0) > t
+    assert hist.snapshot(fn="t.tile")["count"] == 3
+    # off: nothing is read, nothing returned
+    prof.set_enabled(False)
+    assert prof.end_step("t.tile", prof.begin_step("t.tile"), t) == 0.0
+    assert prof.ledger()["t.tile"]["unqueued_ms_sum"] == \
+        snap["unqueued_ms_sum"]
+
+
+def test_two_callers_of_one_name_each_count_their_own_unqueued_time():
+    """Several engines step in one process (``serve/replica.py``'s stepper
+    threads), all under ``engine.fused_step``: one's launch precedes
+    another's fetch. Each hands ``end_step`` its OWN last reading, so the
+    name's sum is the sum of the callers' sums and no observation is
+    negative, however the threads interleave."""
+    import threading
+    import time
+    wrap(jax.jit(lambda x: x + 1), "t.two", block=False)(jnp.ones((4,)))
+    prof = get_profiler()
+    hist = obs.get_registry().get("senweaver_runtime_unqueued_ms")
+    mine = {}
+
+    def engine(tag, host_s, step_s, steps):
+        last, total = 0.0, 0.0
+        for _ in range(steps):
+            time.sleep(host_s)
+            t = prof.begin_step("t.two")
+            time.sleep(step_s)                  # the other one ends in here
+            if last:
+                total += (t - last) * 1e3
+            last = prof.end_step("t.two", t, last)
+        mine[tag] = total
+
+    threads = [threading.Thread(target=engine, args=a) for a in
+               (("a", 0.002, 0.011, 9), ("b", 0.003, 0.007, 12))]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    snap = prof.ledger()["t.two"]
+    got = hist.snapshot(fn="t.two")
+    assert got["count"] == (9 - 1) + (12 - 1)
+    assert mine["a"] >= 8 * 2.0 and mine["b"] >= 11 * 3.0
+    assert snap["unqueued_ms_sum"] == pytest.approx(
+        mine["a"] + mine["b"], abs=0.01)
+    assert got["sum"] == pytest.approx(snap["unqueued_ms_sum"], abs=0.01)
+
+
+def test_the_engines_unqueued_time_is_live_with_tracing_off():
+    """``RolloutEngine._step_paged`` brackets every fused step, tracing on
+    or off: the ledger's two sums tile the loop from the first launch to
+    the last fetch, and nothing was recorded."""
+    from senweaver_ide_tpu.models import init_params, tiny_test
+    from senweaver_ide_tpu.rollout import EngineConfig, RolloutEngine
+    config = tiny_test()
+    eng = RolloutEngine(init_params(config, jax.random.PRNGKey(0)), config,
+                        num_slots=2, max_len=32,
+                        engine_config=EngineConfig(kv_layout="paged",
+                                                   block_size=4))
+    prof = get_profiler()
+    begins = []
+    begin = prof.begin_step
+    prof.begin_step = lambda name: begins.append(begin(name)) or begins[-1]
+    eng.submit([5, 9, 2, 7], max_new_tokens=6)
+    while eng.has_work:
+        eng.step()
+    assert not obs.is_enabled() and obs.get_tracer().spans() == []
+    snap = prof.ledger()["engine.fused_step"]
+    steps = eng.stats()["decode_steps"]
+    assert len(begins) == steps > 3
+    assert snap["unqueued_ms_sum"] > 0.0
+    assert snap["unqueued_ms_sum"] + snap["step_ms_sum"] == pytest.approx(
+        (eng._fetched_at - begins[0]) * 1e3, abs=0.01)
+    hist = obs.get_registry().get("senweaver_runtime_unqueued_ms")
+    assert hist.snapshot(fn="engine.fused_step")["count"] == steps - 1
+
+
 def test_the_engines_step_ms_is_launch_to_fetch_by_its_own_report():
     """``engine.fused_step`` does not block in its wrapper: ``step_ms`` is
     what ``_step_paged`` reports, launch to tokens on the host, so it
@@ -255,6 +362,51 @@ def test_cost_analysis_records_flops_when_enabled():
     assert snap["flops_per_call"] == fpc
     util = get_profiler().utilization("t.cost")
     assert util is not None and util["achieved_flops_per_sec"] > 0
+
+
+@pytest.mark.parametrize("kind,peaks", [
+    ("TPU v5 lite", {"flops_per_s": 197e12, "bytes_per_s": 819e9}),
+    ("TPU v9 unheard of", {}), (None, {})],
+    ids=["v5e", "unknown-kind", "no-device"])
+def test_utilization_comes_from_the_device_kinds_published_peaks(
+        monkeypatch, kind, peaks):
+    """One table in the program, keyed by ``device_kind``: the v5e's
+    peaks as ``benchmark/peaks.json`` has them; a kind the table lacks
+    publishes the achieved rate and no utilization, never a guess. No
+    environment variable is read."""
+    from senweaver_ide_tpu.obs import runtime_profile as rp
+    monkeypatch.setenv("SENWEAVER_PEAK_FLOPS", "1.0")
+    monkeypatch.setenv("SENWEAVER_PEAK_BYTES_PER_SEC", "1.0")
+    monkeypatch.setattr(rp, "device_kind", lambda: kind)
+    assert rp.device_peaks() == peaks
+    get_profiler().set_cost_analysis(True)
+    f = wrap(jax.jit(lambda a, b: a @ b), "t.peaks")
+    f(jnp.ones((16, 16)), jnp.ones((16, 16)))
+    util = get_profiler().utilization("t.peaks")
+    assert util["achieved_flops_per_sec"] > 0
+    gauge = obs.get_registry().get("senweaver_runtime_roofline_utilization")
+    if peaks:
+        assert util["utilization"] == pytest.approx(
+            util["achieved_flops_per_sec"] / 197e12)
+        assert 0.0 < gauge.value(fn="t.peaks", resource="flops") < 1.0
+        assert 0.0 < gauge.value(fn="t.peaks", resource="bytes") < 1.0
+    else:
+        assert "utilization" not in util
+        assert "senweaver_runtime_roofline_utilization{" not in \
+            obs.get_registry().render()
+
+
+def test_the_programs_peaks_are_the_benchmarks():
+    import json
+    import os
+    from senweaver_ide_tpu.obs.runtime_profile import DEVICE_PEAKS
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "peaks.json")) as f:
+        bench = json.load(f)
+    assert set(DEVICE_PEAKS) == set(bench)
+    for kind, row in DEVICE_PEAKS.items():
+        assert row == {"flops_per_s": bench[kind]["bf16_flops_per_s"],
+                       "bytes_per_s": bench[kind]["hbm_bytes_per_s"]}
 
 
 def test_cost_analysis_off_by_default():
