@@ -632,9 +632,9 @@ def fused_step_lowering(engine):
     and flags (nothing is run or donated)."""
     n = engine.num_slots
     return engine_mod._paged_fused_step.lower(
-        engine.params, engine.config, np.zeros((5, n), np.int32),
+        engine.params, engine.config, np.zeros((6, n), np.int32),
         np.zeros((n, 1), np.int32), engine.pool, jax.random.PRNGKey(0),
-        engine.sample, engine._use_paged_kernel)
+        engine._cur_tok_dev, engine.sample, engine._use_paged_kernel)
 
 
 def four_chips(report: Report, sz: Sizes, config, train_config, seed: int,

@@ -467,7 +467,9 @@ class RuntimeProfiler:
         reading it took (0.0 where it recorded nothing), for the caller
         to keep and hand back as ``last_end`` at its next step: the time
         from there to ``t_begin`` — the caller had no step of ``name``
-        in flight — goes to ``unqueued_ms_sum`` and its histogram. The
+        in flight — goes to ``unqueued_ms_sum`` and its histogram, 0
+        where this step was begun before the last one ended (a caller
+        that runs a step ahead: one was in flight all the time). The
         reading is the CALLER's, not the ledger's: several engines step
         in one process under one name, and one's launch may precede
         another's fetch."""
@@ -482,8 +484,8 @@ class RuntimeProfiler:
                 return 0.0
             led.step_ms_sum += step_ms
             led.last_step_ms = step_ms
-            if 0.0 < last_end <= t_begin:
-                unqueued_ms = (t_begin - last_end) * 1_000.0
+            if last_end > 0.0:
+                unqueued_ms = max(0.0, t_begin - last_end) * 1_000.0
                 led.unqueued_ms_sum += unqueued_ms
         ins = self._metrics()
         ins["step_ms"].observe(step_ms, fn=name)

@@ -28,7 +28,7 @@ import functools
 import threading
 import time
 from collections import deque
-from typing import Deque, Dict, List, Optional
+from typing import Any, Deque, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -240,31 +240,43 @@ def _pool_decode_step(params: Params, config: ModelConfig, cur_tok: jax.Array,
                                    v_scale=new_cache.v_scale)
 
 
+# bits of the plan's sixth row (``_paged_fused_step``)
+FEED_TAKE, FEED_PUT = 1, 2
+
+
 @functools.partial(jax.jit,
                    static_argnames=("config", "sample", "use_kernel"),
                    donate_argnames=("pool",))
 def _paged_fused_step(params: Params, config: ModelConfig,
                       plan: jax.Array, tables: jax.Array,
                       pool: PagedKVPool,
-                      key: jax.Array, sample: SampleParams,
+                      key: jax.Array, cur: jax.Array, sample: SampleParams,
                       use_kernel: Optional[bool],
                       adapters=None, adapter_ids=None):
     """One fused paged step over a flat token batch: decode rows and
     exact-size chunked-prefill segments share the same forward under a
     static token budget (``plan.shape[1]``). ``plan`` is the host's
-    five int32 vectors as the rows of ONE ``(5, T)`` array — tokens,
-    seq_row, positions, write_block, write_off — so the call ingests
-    one host array for them, not five. Each entry writes its
+    six int32 vectors as the rows of ONE ``(6, T)`` array — tokens,
+    seq_row, positions, write_block, write_off, feed — so the call
+    ingests one host array for them, not six. Each entry writes its
     k/v through ``(write_block, write_off)`` — padding/rescore entries
     address the out-of-range sentinel block and are dropped by the
     scatter. ``key`` is the engine's key: it is split HERE, as the
     host used to split it before every call (the same threefry split
     in the same order, so a seed's tokens are what they were), and the
     next key is the fourth result — no program of its own between two
-    steps. Sampling happens in-jit for EVERY row; the host keeps only
-    the rows it marked as samplers (decode rows, the final token of a
-    completing prefill), so ONE batched device_get per step covers
-    first tokens and decode tokens alike. With an adapter pool
+    steps. ``cur`` is the rows' current tokens, ``(num_slots,)`` int32,
+    carried from step to step as the key is (the fifth result): an
+    entry whose ``feed`` has ``FEED_TAKE`` set is fed ``cur`` of its
+    row in place of its ``tokens`` (a decode row whose last sample is
+    not on the host yet: the step before this one is still running),
+    and one with ``FEED_PUT`` leaves its sample there (decode rows,
+    the last entry of a completing prefill). So a step can be launched
+    before the last one's tokens are home, and it is the same program
+    when it is not. Sampling happens in-jit for EVERY row; the host
+    keeps only the rows it marked as samplers (decode rows, the final
+    token of a completing prefill), so ONE batched device_get per step
+    covers first tokens and decode tokens alike. With an adapter pool
     attached, ``adapters`` (fixed-shape rank-ladder banks) and
     ``adapter_ids`` (per-rung (T,) slot vectors, null slot 0 for base
     rows) ride every call, so tenant churn reuses the same compiled
@@ -275,7 +287,8 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     device round-trips, no new compile per occupancy bucket (the scale
     tensors are shape-static alongside the payloads)."""
     key, step_key = jax.random.split(key)
-    tokens, seq_row, positions, write_block, write_off = plan
+    tokens, seq_row, positions, write_block, write_off, feed = plan
+    tokens = jnp.where((feed & FEED_TAKE) > 0, cur[seq_row], tokens)
     logits, pool, *stats = forward_paged(
         params, config, tokens, pool=pool,
         tables=tables, seq_row=seq_row, positions=positions,
@@ -286,6 +299,10 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     next_tok = sample_token(logits, step_key, temperature=sample.temperature,
                             top_k=sample.top_k, top_p=sample.top_p)
     logp = sampled_logprob(logits, next_tok)
+    # a row has at most one entry that puts: the others' index is out of
+    # range and the scatter drops them
+    cur = cur.at[jnp.where((feed & FEED_PUT) > 0, seq_row, cur.shape[0])
+                 ].set(next_tok.astype(cur.dtype), mode="drop")
     if config.hc_mult:
         # A multi-stream model's step also says how far from doubly
         # stochastic its worst H_res was: one float behind the step's
@@ -300,7 +317,7 @@ def _paged_fused_step(params: Params, config: ModelConfig,
         # Every consumer of the tokens indexes entries below ``T``.
         next_tok = jnp.concatenate(
             [next_tok, jnp.stack(stats[0]).astype(next_tok.dtype)])
-    return next_tok, logp, pool, key
+    return next_tok, logp, pool, key, cur
 
 
 @functools.partial(jax.jit, static_argnames=("config", "k", "use_kernel"),
@@ -373,13 +390,14 @@ def _draft_feed_step(params: Params, config: ModelConfig,
 # retraces trip it. The draft propose/feed steps get the same
 # treatment: their ladders are (table-bucket x depth) and
 # (table-bucket x feed-width bucket) respectively. The fused step is
-# the one wrap that does not block: its pool and key (args 4-5) are
-# shape-stable too, and ``_step_paged``, which knows when the step's
-# tokens are on the host, reports the step's time to this ledger.
+# the one wrap that does not block: its pool, key and rows' current
+# tokens (args 4-6) are shape-stable too, and the engine, which knows
+# when a step's tokens are on the host (``_collect``), reports the
+# step's time to this ledger.
 _pool_decode_step = ProfiledFunction(
     _pool_decode_step, "engine.decode_step", skip_args=(0, 1))
 _paged_fused_step = ProfiledFunction(
-    _paged_fused_step, "engine.fused_step", skip_args=(0, 1, 4, 5),
+    _paged_fused_step, "engine.fused_step", skip_args=(0, 1, 4, 5, 6),
     block=False, storm_threshold=64)
 _draft_propose_scan = ProfiledFunction(
     _draft_propose_scan, "engine.spec_propose", skip_args=(0, 1),
@@ -494,6 +512,35 @@ class _RowPreempted(Exception):
     reclamation and was requeued — skip it for this step."""
 
 
+class _PlanWaits(Exception):
+    """Internal: the plan being assembled ran out of blocks while a step
+    is in flight. Reclaiming may preempt, and a preempted request is
+    rebuilt from its tokens: the step in flight is collected first, then
+    the plan is assembled again (what it allocated so far stays in the
+    tables)."""
+
+
+@dataclasses.dataclass
+class _FlyingStep:
+    """A fused step that was launched and whose tokens are not on the
+    host yet: what :meth:`RolloutEngine._collect` needs to fetch them
+    and hand them to their requests."""
+
+    step: int                   # its number (``decode_steps`` at launch)
+    toks: jax.Array
+    logps: jax.Array
+    # (entry, request, is its first token): the decode rows and the
+    # completing prefills that sample, in entry order
+    samplers: list
+    spec_rows: list
+    used: int                   # entries in use (an expert model's count)
+    t_launch: float             # the profiler's clock at launch
+    # the ``engine.step`` span that launched it (None: tracing off): the
+    # step's value attrs are set on it when the tokens are home, beside
+    # its ``used`` and ``entries``, so a reader joins them by step
+    span: Any = None
+
+
 class _DraftMetricsView:
     """Registry adapter for the draft block allocator: re-prefixes the
     ``senweaver_kv_*`` series to ``senweaver_spec_draft_kv_*`` so the
@@ -559,6 +606,13 @@ class _Request:
     # policy logp for GRPO importance ratios), parallel to `tokens`
     logps: List[float] = dataclasses.field(default_factory=list)
     done: bool = False
+    # sampled entries launched for this request whose tokens are not on
+    # the host yet (0 between serial steps, up to 2 while a step runs
+    # ahead), and whether the last token it may have is among the
+    # launched: budget and context bound are counts of LAUNCHED tokens,
+    # so no entry is planned for a request that is closing
+    inflight: int = 0
+    closing: bool = False
     slot: Optional[int] = None
     prefix_id: Optional[int] = None
     # hold_slot: keep the slot (and its KV) reserved after finishing so
@@ -606,6 +660,11 @@ class _Request:
     t_first_token_ns: Optional[int] = None
     t_done_ns: Optional[int] = None
     row: Optional[int] = None
+
+    @property
+    def launched(self) -> int:
+        """Tokens sampled for this request, delivered or in flight."""
+        return len(self.tokens) + self.inflight
 
 
 @dataclasses.dataclass
@@ -868,6 +927,11 @@ class RolloutEngine:
             self._tables: List[List[int]] = [[] for _ in range(num_slots)]  # guarded-by: _lock
             self._row_len: List[int] = [0] * num_slots  # guarded-by: _lock
             self._cur_tok_host: List[int] = [0] * num_slots  # guarded-by: _lock
+            # the same cursor on the device, carried through the fused
+            # step like the key: what a decode row is fed while its last
+            # sample is still on its way to the host
+            self._cur_tok_dev = self._on_device(
+                lambda: jnp.zeros((num_slots,), jnp.int32))
             self._prefill_jobs: Dict[int, _PrefillJob] = {}  # guarded-by: _lock
             st = self.engine_config.step_tokens
             self._step_tokens = max(
@@ -1031,6 +1095,14 @@ class RolloutEngine:
         # the profiler's clock when the last fused step's results were
         # on the host (0.0: none yet), for the next step's unqueued time
         self._fetched_at = 0.0                  # guarded-by: _lock
+        # the fused step that is launched and not collected, if any: set
+        # between two step() calls only while the engine runs ahead
+        self._flying: Optional[_FlyingStep] = None  # guarded-by: _lock
+        self._run_ahead_total = reg.counter(
+            "senweaver_engine_steps_run_ahead_total",
+            "Fused steps launched before the step before them had its "
+            "tokens on the host (the engine was saturated: a request "
+            "queued, or no row free).")
         # Many agent loops (subagent threads) drive one engine: all state
         # mutation is serialized; concurrency = slots, not host threads.
         self._lock = threading.RLock()
@@ -1071,6 +1143,7 @@ class RolloutEngine:
         if is_quantized(self.params) and not is_quantized(params):
             params = quantize_weights_int8(params)
         with self._lock:
+            self._drain()
             self.params = self._place_params(params)
             # release_prefix (not .clear()) so the paged layout also
             # drops the prefixes' block refcounts back to the pool.
@@ -1130,6 +1203,7 @@ class RolloutEngine:
                 f"draft vocab {draft_config.vocab_size} != target "
                 f"vocab {self.config.vocab_size}")
         with self._lock:
+            self._drain()      # a verify window needs the values
             if controller is None:
                 controller = (FixedDepth(int(depth)) if depth is not None
                               else SpecController())
@@ -1196,6 +1270,8 @@ class RolloutEngine:
         stale: the base policy is untouched."""
         if self.adapter_pool is None:
             raise RuntimeError("engine has no adapter_pool")
+        with self._lock:
+            self._drain()
         return self.adapter_pool.publish(adapter_id, lora, version=version)
 
     def has_adapter(self, adapter_id: Optional[str]) -> bool:
@@ -1470,6 +1546,7 @@ class RolloutEngine:
         self._refuse_state("fork_request (a branch shares KV blocks by "
                            "refcount; the state has no fork yet)")
         with self._lock:
+            self._drain()
             parent = self._requests.get(rid)
             if parent is None:
                 raise KeyError(f"unknown rid {rid}")
@@ -1544,14 +1621,32 @@ class RolloutEngine:
     @property
     def has_work(self) -> bool:
         with self._lock:
-            return bool(self._queue) or any(r is not None
-                                            for r in self._slot_req)
+            return (bool(self._queue) or self._flying is not None
+                    or any(r is not None for r in self._slot_req))
 
     def step(self) -> Dict[int, List[int]]:
         """Advance the pool by one decode step. Returns {rid: [tokens]} for
-        every token emitted since the previous step() — including tokens
-        sampled during prefill (a request can emit its first token and, if it
-        immediately hits eos, never appear in a later step)."""
+        every token that came home since the previous step() — including
+        tokens sampled during prefill (a request can emit its first token
+        and, if it immediately hits eos, never appear in a later step).
+
+        WHEN a token is returned. An under-loaded engine (a free row and
+        an empty queue after the step's admissions) returns a step's
+        tokens from the call that launched it. A saturated paged engine (a
+        request still queued, or no row free) runs ONE STEP AHEAD: the
+        call launches step k+1 and returns the tokens of step k, launched
+        by the call before it, so the device never waits for the host
+        between two steps; no admission is delayed by it, since a later
+        arrival would have waited for a row anyway. Either way a token is
+        in ``result()`` / ``result_logps()`` exactly when a ``step()``
+        has returned it, ``is_done(rid)`` turns true with the request's
+        LAST token (an EOS one step after it was sampled, where the next
+        entry was already launched: that sample is dropped), and
+        ``has_work`` stays true while a step is in flight. Entries that
+        read or move a request's tokens or rows (``fork_request``,
+        ``pause_request``, checkpoints, ``update_params``, ...) first
+        bring the step in flight home; its tokens are returned by the
+        next ``step()``."""
         with self._lock:
             return self._step()
 
@@ -1746,6 +1841,7 @@ class RolloutEngine:
     def release_slot(self, rid: int) -> None:
         """Free a slot held by a finished hold_slot request."""
         with self._lock:
+            self._drain()
             try:
                 slot = self._slot_held.index(rid)
             except ValueError:
@@ -1842,6 +1938,7 @@ class RolloutEngine:
         or invalidated (callers re-register, same as submit())."""
         self._refuse_state("prefix export (export_prefix)")
         with self._lock:
+            self._drain()      # the gather reads the pool
             if prefix_id not in self._prefixes:
                 raise KeyError(f"unknown prefix_id {prefix_id}")
             tokens, entry, last = self._prefixes[prefix_id]
@@ -2051,6 +2148,7 @@ class RolloutEngine:
                            "KV blocks, no state)")
         from .migration import checkpoint_from_engine
         with self._lock:
+            self._drain()
             return checkpoint_from_engine(self, rid, pause=pause)
 
     def restore_request(self, ckpt) -> int:
@@ -2063,6 +2161,7 @@ class RolloutEngine:
                            "(restore_request)")
         from .migration import restore_into_engine
         with self._lock:
+            self._drain()
             rid = restore_into_engine(self, ckpt)
             self._schedule()
             return rid
@@ -2073,6 +2172,7 @@ class RolloutEngine:
         Idempotent — unknown rids return False."""
         from .migration import release_from_engine
         with self._lock:
+            self._drain()
             req = self._requests.get(rid)
             if req is not None:
                 # a group donor migrated away before the spine capture
@@ -2090,6 +2190,7 @@ class RolloutEngine:
         assembler, the speculation planner, and the scheduler."""
         from .migration import set_paused
         with self._lock:
+            self._drain()
             set_paused(self, rid, True)
 
     def resume_request(self, rid: int) -> None:
@@ -2106,6 +2207,8 @@ class RolloutEngine:
         The fleet coordinator either migrates each or resumes it
         locally; a resumed request that caps out again truncates."""
         with self._lock:
+            if self._pressure_migrations:   # a fleet asks every tick
+                self._drain()
             out = [rid for rid in self._pressure_migrations
                    if rid in self._requests
                    and not self._requests[rid].done]
@@ -2279,12 +2382,18 @@ class RolloutEngine:
 
     def _finish_request(self, req: "_Request", slot: int) -> None:
         # guarded-by: caller
-        """Mark a request done and either hold or free its slot."""
-        req.done = True
-        req.t_done_ns = time.perf_counter_ns()
-        if self._trace_on and req.t_first_token_ns is not None:
-            self._record_phase(req, "request.decode",
-                               req.t_first_token_ns, req.t_done_ns)
+        """End a request whose last token is on the host: it leaves its
+        row (held or freed) and is done."""
+        self._close_request(req, slot)
+        self._mark_done(req)
+
+    def _close_request(self, req: "_Request", slot: int) -> None:
+        # guarded-by: caller
+        """The structural half of a request's end: its last token is
+        launched (or known), so no further entry is planned for it — it
+        leaves its row, which is held for a continuation or freed. It is
+        DONE only when that token is delivered (:meth:`_mark_done`)."""
+        req.closing = True
         self._group_degrade_if_uncaptured(req)
         self._group_forget_follower(req)
         self._slot_req[slot] = None
@@ -2297,11 +2406,6 @@ class RolloutEngine:
             self.adapter_pool.release(req.adapter_binding)
             req.adapter_binding = None
         if req.hold_slot:
-            # The LAST sampled token's k/v is not yet written (tokens
-            # are fed on the step AFTER they are sampled), so the
-            # resident history excludes it — a continuation's delta
-            # naturally begins with that token.
-            req.held_history = list(req.prompt) + req.tokens[:-1]
             self._slot_held[slot] = req.rid
             self._hold_seq += 1
             self._slot_hold_seq[slot] = self._hold_seq
@@ -2309,6 +2413,28 @@ class RolloutEngine:
             req.slot = None
             if self.kv_layout == "paged":
                 self._release_row(slot)
+
+    def _mark_done(self, req: "_Request") -> None:
+        # guarded-by: caller
+        """The value half: the request's last token has been delivered."""
+        req.done = True
+        req.t_done_ns = time.perf_counter_ns()
+        if self._trace_on and req.t_first_token_ns is not None:
+            self._record_phase(req, "request.decode",
+                               req.t_first_token_ns, req.t_done_ns)
+        if req.hold_slot and req.slot is not None:
+            # The LAST sampled token's k/v is not yet written (tokens
+            # are fed on the step AFTER they are sampled), so the
+            # resident history excludes it — a continuation's delta
+            # naturally begins with that token. (An EOS met while one
+            # more entry was in flight did write it: the row's length
+            # steps back over it, and the delta's first write lands on
+            # it. Only k/v can be written twice: where the row has
+            # recurrent state no such entry is ever launched,
+            # ``_state_needs_values``.)
+            req.held_history = list(req.prompt) + req.tokens[:-1]
+            if self.kv_layout == "paged":
+                self._row_len[req.slot] = len(req.held_history)
 
     def _drop_hold(self, slot: int) -> None:
         # guarded-by: caller
@@ -2942,15 +3068,17 @@ class RolloutEngine:
                     if tgt is not None:
                         # the donor's refcount keeps the source block
                         # alive; ours moved to `tgt` inside cow_target.
-                        # The copy blocks and the two index arrays are
-                        # dispatches of their own: the span holds all
-                        # three (`idle_gap_copies_ms` reads it)
+                        # Nothing waits for the copy: the pool's
+                        # donation orders it behind the step in flight
+                        # and before the next, and its two ids enter as
+                        # host arrays (`idle_gap_copies_ms` reads the
+                        # span)
                         with (get_tracer().span if self._trace_on
                               else noop_span)("engine.cow_copy"):
                             self.pool = copy_blocks(
                                 self.pool,
-                                jnp.asarray([table[lb]], jnp.int32),
-                                jnp.asarray([tgt], jnp.int32))
+                                np.asarray([table[lb]], np.int32),
+                                np.asarray([tgt], np.int32))
                         table[lb] = tgt
                 else:
                     raise AssertionError(
@@ -2958,6 +3086,8 @@ class RolloutEngine:
                         f"of {len(table)} block(s)")
                 return table[lb]
             except BlocksExhausted:
+                if self._flying is not None:
+                    raise _PlanWaits()
                 if not self._reclaim_blocks(row, committed):
                     raise _RowPreempted(row)
 
@@ -3271,6 +3401,7 @@ class RolloutEngine:
         pos_l: List[int] = []
         wb_l: List[int] = []
         wo_l: List[int] = []
+        feed_l: List[int] = []     # FEED_TAKE | FEED_PUT, an entry
         decode_rows = []           # (entry_idx, row, req)
         spec_rows = []             # (entry_idx, row, req, proposals, start)
         job_rows = []              # (row, req, job, n, last_idx, wrote)
@@ -3300,6 +3431,7 @@ class RolloutEngine:
                     pos_l.append(fp)
                     wb_l.append(wb)
                     wo_l.append(wo)
+                    feed_l.append(0)
                 kv_blocks += (p + len(feed) - 1) // bs + 1
                 committed.add(row)
                 continue
@@ -3309,7 +3441,14 @@ class RolloutEngine:
                 continue
             kv_blocks += p // bs + 1
             decode_rows.append((len(toks_l), row, req))
-            toks_l.append(self._cur_tok_host[row])
+            if req.inflight:
+                # its last sample is still on its way to the host: the
+                # device feeds the row its own copy
+                toks_l.append(0)
+                feed_l.append(FEED_TAKE | FEED_PUT)
+            else:
+                toks_l.append(self._cur_tok_host[row])
+                feed_l.append(FEED_PUT)
             rows_l.append(row)
             pos_l.append(p)
             wb_l.append(wb)
@@ -3343,6 +3482,9 @@ class RolloutEngine:
                 pos_l.append(p)
                 wb_l.append(wb)
                 wo_l.append(wo)
+                feed_l.append(0)
+            if job.sample_last and n == len(job.toks):
+                feed_l[-1] = FEED_PUT    # its first token: the row's current
             wrote = 0 if job.drop_writes else n
             job_rows.append((row, req, job, n, base + n - 1, wrote))
             kv_blocks += (job.pos + n - 1) // bs + 1
@@ -3375,6 +3517,7 @@ class RolloutEngine:
             pos_l.append(0)
             wb_l.append(nb)      # sentinel block: write dropped
             wo_l.append(0)
+            feed_l.append(0)
         # Per-rung adapter slot ids, parallel to the token batch: each
         # real entry gathers its request's bound slot (null slot 0 for
         # base rows and all padding). Built on EVERY step when a pool
@@ -3390,15 +3533,21 @@ class RolloutEngine:
                 if b is not None:
                     for j, s in enumerate(b.slot_ids):
                         aid[j][i] = s
-        return (toks_l, rows_l, pos_l, wb_l, wo_l, decode_rows,
+        return (toks_l, rows_l, pos_l, wb_l, wo_l, feed_l, decode_rows,
                 spec_rows, job_rows, aid)
 
     def _step_paged(self, span) -> Dict[int, List[int]]:
         # guarded-by: caller
-        """One fused step in four host phases, each a span where the
-        device may wait for the host (docs/observability.md): plan,
-        launch, fetch, emit. ``span`` is the tracer's ``span`` when the
-        step found tracing on, else the no-op."""
+        """One ``step()`` of the paged layout: plan and launch ONE fused
+        step, then collect (fetch and deliver) what has to be home before
+        the call returns. Serially that is the step just launched: plan,
+        launch, advance, fetch, emit. Where the engine is saturated
+        (:meth:`_saturated`) the step stays in flight and the call
+        collects the one launched by the call BEFORE it instead, so the
+        next plan and launch overlap the device: plan k+1, launch k+1,
+        advance k+1, fetch k, emit k. Each phase is a span
+        (docs/observability.md); ``span`` is the tracer's ``span`` when
+        the step found tracing on, else the no-op."""
         with span("engine.step", step=self._stats["decode_steps"]) as st:
             with span("engine.plan") as sp:
                 rows0 = list(self._slot_req) if sp is not None else ()
@@ -3407,38 +3556,51 @@ class RolloutEngine:
                 emitted = self._pending_emits
                 self._pending_emits = {}
                 depth, spec_plan = self._spec_begin_step()
-                with span("engine.assemble_plan"):
-                    plan = self._assemble_paged_plan(spec_plan, depth)
+                try:
+                    with span("engine.assemble_plan"):
+                        plan = self._assemble_paged_plan(spec_plan, depth)
+                except _PlanWaits:
+                    # the pool is exhausted: stand down, then reclaim
+                    self._collect(span, self._flying, emitted)
+                    with span("engine.assemble_plan"):
+                        plan = self._assemble_paged_plan(spec_plan, depth)
                 if sp is not None:
                     sp.set_attr("admitted", self._placed_since(rows0))
                 n_copies = (self._flush_state_copies(span)
                             if self._state_copies else 0)
-                if plan is None:
-                    return emitted
-                (toks_l, rows_l, pos_l, wb_l, wo_l, decode_rows, spec_rows,
-                 job_rows, adapter_ids) = plan
-                adapters = None
-                if adapter_ids is not None:
-                    # Fixed-shape banks + (T,)-ladder id vectors ride
-                    # every call — the only adapter-dependent state the
-                    # jit sees.
-                    adapters = self.adapter_pool.banks()
-                    adapter_ids = tuple(np.asarray(g, np.int32)
-                                        for g in adapter_ids)
-                with span("engine.tables"):
-                    tables = self._tables_device()
-                # host numpy in, device out: the five plan vectors enter
-                # the jit as the rows of ONE numpy array (one C++ ingest;
-                # jnp.asarray here would cost a full dispatch a step)
-                vectors = np.asarray(
-                    (toks_l, rows_l, pos_l, wb_l, wo_l), np.int32)
+                if plan is not None:
+                    (toks_l, rows_l, pos_l, wb_l, wo_l, feed_l, decode_rows,
+                     spec_rows, job_rows, adapter_ids) = plan
+                    adapters = None
+                    if adapter_ids is not None:
+                        # Fixed-shape banks + (T,)-ladder id vectors ride
+                        # every call — the only adapter-dependent state
+                        # the jit sees.
+                        adapters = self.adapter_pool.banks()
+                        adapter_ids = tuple(np.asarray(g, np.int32)
+                                            for g in adapter_ids)
+                    with span("engine.tables"):
+                        tables = self._tables_device()
+                    # host numpy in, device out: the six plan vectors
+                    # enter the jit as the rows of ONE numpy array (one
+                    # C++ ingest; jnp.asarray here would cost a full
+                    # dispatch a step)
+                    vectors = np.asarray(
+                        (toks_l, rows_l, pos_l, wb_l, wo_l, feed_l),
+                        np.int32)
+            prev = self._flying
+            if plan is None:
+                # nothing to launch; whatever is in flight comes home
+                if prev is not None:
+                    self._collect(span, prev, emitted)
+                return emitted
+            used = (len(decode_rows) + sum(j[3] for j in job_rows)
+                    + sum(len(r[3]) for r in spec_rows))
             if st is not None:
-                prefill = sum(j[3] for j in job_rows)
                 st.set_attr("entries", len(toks_l))
-                st.set_attr("used", len(decode_rows) + prefill
-                            + sum(len(r[3]) for r in spec_rows))
+                st.set_attr("used", used)
                 st.set_attr("decode_rows", len(decode_rows))
-                st.set_attr("prefill_tokens", prefill)
+                st.set_attr("prefill_tokens", sum(j[3] for j in job_rows))
                 st.set_attr("table_width", int(tables.shape[1]))
                 st.set_attr("kv_blocks", self._kv_blocks_step)
                 st.set_attr("block_size", self._alloc.block_size)
@@ -3446,80 +3608,102 @@ class RolloutEngine:
                 st.set_attr("queue_depth", len(self._queue))
                 st.set_attr("rows_active", len(decode_rows)
                             + len(spec_rows) + len(job_rows))
+                st.set_attr("launches", 1)
+                st.set_attr("ahead", int(prev is not None))
                 if self.config.ssm:
                     # rows whose recurrent state the step reads and writes
                     st.set_attr("ssm_rows", len(decode_rows)
                                 + sum(1 for j in job_rows if j[5]))
                     st.set_attr("ssm_state_copies", n_copies)
             t_launch = get_profiler().begin_step("engine.fused_step")
+            if st is not None and t_launch and (prev is not None
+                                                or self._fetched_at):
+                # no fused step of THIS engine was in flight from its
+                # last step's fetch to this launch: emit, the caller,
+                # plan, copies (and the wait for work, where there was
+                # none). None of it where the last step still runs.
+                st.set_attr("unqueued_ms", 0.0 if prev is not None else
+                            (t_launch - self._fetched_at) * 1_000.0)
             toks, logps = self._launch_paged(span, vectors, tables,
                                              adapters, adapter_ids)
-            self._stats["decode_steps"] += 1
-            with span("engine.fetch") as sp:
-                # ONE batched device→host transfer per fused step (the
-                # analysis JIT110 budget), covering decode tokens AND the
-                # first tokens of completing prefills: the step's one
-                # blocking point, on a copy that was asked for at launch.
-                t_wait = time.perf_counter()
-                toks, logps = profiled_device_get((toks, logps),
-                                                  fn="engine.fused_step")
-                if sp is not None:
-                    sp.set_attr("wait_ms",
-                                (time.perf_counter() - t_wait) * 1_000.0)
-                    sp.set_attr("bytes", int(toks.nbytes + logps.nbytes))
-                self._host_syncs_total.inc()
-            # no fused step of THIS engine was in flight from its last
-            # step's fetch to this one's launch: emit, the caller, plan,
-            # copies (and the wait for work, where there was none)
-            fetched = get_profiler().end_step(
-                "engine.fused_step", t_launch, self._fetched_at)
-            if st is not None and fetched and self._fetched_at:
-                st.set_attr("unqueued_ms",
-                            (t_launch - self._fetched_at) * 1_000.0)
-            self._fetched_at = fetched
-            if self._moe_counters is not None:
-                self._note_moe_step(st, toks, decode_rows, spec_rows,
-                                    job_rows)
-            if self._mhc_gauge is not None:
-                self._note_mhc_step(st, logps)
-            with span("engine.emit") as sp:
-                rows0 = list(self._slot_req) if sp is not None else ()
-                n_emitted = self._emit_paged(toks, logps, decode_rows,
-                                             spec_rows, job_rows, emitted)
-                self._steps_total.inc()
-                self._tokens_total.inc(n_emitted)
+            with span("engine.advance"):
+                samplers = self._advance_paged(decode_rows, job_rows)
                 self._publish_fragmentation()
-                with span("engine.schedule"):
-                    self._schedule()
-                if sp is not None:
-                    sp.set_attr("emitted", n_emitted)
-                    sp.set_attr("finished",
-                                sum(r is not None and r.done for r in rows0))
-                    sp.set_attr("admitted", self._placed_since(rows0))
+            self._flying = fly = _FlyingStep(
+                step=self._stats["decode_steps"], toks=toks, logps=logps,
+                samplers=samplers, spec_rows=spec_rows, used=used,
+                t_launch=t_launch, span=st)
+            self._stats["decode_steps"] += 1
+            if prev is not None:
+                self._run_ahead_total.inc()
+                self._collect(span, prev, emitted)
+            # the step's ONE trailing schedule: the rows its advance and
+            # the delivery above freed (what a serial collect below
+            # frees by EOS, the next plan's leading schedule fills)
+            self._schedule_traced(span)
+            if (self._spec is not None or not self._saturated()
+                    or self._state_needs_values(fly)):
+                self._collect(span, fly, emitted)
             return emitted
+
+    def _schedule_traced(self, span) -> None:
+        # guarded-by: caller
+        with span("engine.schedule") as sp:
+            rows0 = list(self._slot_req) if sp is not None else ()
+            self._schedule()
+            if sp is not None:
+                sp.set_attr("admitted", self._placed_since(rows0))
+
+    def _state_needs_values(self, fly: _FlyingStep) -> bool:
+        # guarded-by: caller
+        """Must this step's tokens be home before the next plan? A held
+        row that ends on EOS keeps its cache for a continuation. Launched
+        ahead, the next step would feed that EOS token to the row once
+        more: the k/v it writes is rewritten by the continuation's first
+        entry and comes out the same, but a recurrent state that has
+        consumed a token cannot step back over it. So a step in which a
+        held request of a model with such state may sample its EOS comes
+        home first (a request that is closing has no further entry)."""
+        return bool(self.config.ssm) and any(
+            req.hold_slot and req.eos_id is not None and not req.closing
+            for _idx, req, _first in fly.samplers)
+
+    def _saturated(self) -> bool:
+        # guarded-by: caller
+        """May the step just launched stay in flight while the next one
+        is planned? Only where that delays no admission: a step launched
+        ahead cannot admit a request that arrives after its plan was
+        made, so the engine runs ahead iff, after the step's
+        ``_schedule()``, a request is still queued or no row is free —
+        a later arrival would have waited behind the queue, or for a
+        row, in any case. An under-loaded engine (a free row, an empty
+        queue) keeps the serial order."""
+        return (not self._free_slots()
+                or any(not r.paused for r in self._queue))
 
     def _launch_paged(self, span, vectors, tables, adapters, adapter_ids):
         # guarded-by: caller
-        """Enqueue the fused step on the plan's ``(5, T)`` array and ask
+        """Enqueue the fused step on the plan's ``(6, T)`` array and ask
         for its tokens' copy to the host: ONE program for the runtime,
-        which also splits the engine's key, and one transfer queued
-        behind it on the device. Nothing here waits: the pool is
-        ordered by the next step's donation, the tokens by
-        ``engine.fetch``. The span's self time (less the wrapper's
-        ``.dispatch`` child) is the wrapper's bookkeeping and the two
-        transfer requests. On the v5e host a new shape's lowering time
-        follows the summed frame sizes from ``step()`` down to this
-        call (PERF.md §6, PR 24 and 31): the five unused locals below
-        (seven until ``_step_paged`` gained one of its own in PR 32 and
-        another in PR 35: ``fetched``)
-        keep this frame and ``_step_paged``'s at the 67 slots they had
-        together before PR 31, measured on the chip to be worth 0.5 s
-        of a qwen cell's warm-up and 0.9 s of glm's (ROADMAP D10)."""
-        b0 = b1 = b2 = b3 = b4 = None                # frame ballast
+        which also splits the engine's key and carries the rows' current
+        tokens, and one transfer queued behind it on the device. Nothing
+        here waits: the pool is ordered by the next step's donation, the
+        tokens by ``engine.fetch``. The span's self time (less the
+        wrapper's ``.dispatch`` child) is the wrapper's bookkeeping and
+        the two transfer requests. On the v5e host a new shape's
+        lowering time follows the summed frame sizes from ``step()``
+        down to this call (PERF.md §6, PR 24, 31 and 36): the unused
+        locals below keep this frame and ``_step_paged``'s at the size
+        the warm-up was last measured at on the chip
+        (``tests/test_engine_launch_path.py`` pins the sum; ROADMAP
+        D10)."""
+        b0 = b1 = None                               # frame ballast
         with span("engine.launch") as sp:
-            next_tok, logp, self.pool, self._key = _paged_fused_step(
+            (next_tok, logp, self.pool, self._key,
+             self._cur_tok_dev) = _paged_fused_step(
                 self.params, self.config, vectors, tables, self.pool,
-                self._key, self.sample, self._use_paged_kernel,
+                self._key, self._cur_tok_dev, self.sample,
+                self._use_paged_kernel,
                 adapters=adapters, adapter_ids=adapter_ids)
             next_tok.copy_to_host_async()
             logp.copy_to_host_async()
@@ -3527,16 +3711,67 @@ class RolloutEngine:
                 sp.set_attr("host_arrays", 2 + len(adapter_ids or ()))
         return next_tok, logp
 
-    def _note_moe_step(self, st, toks, decode_rows, spec_rows,
-                       job_rows) -> None:
+    def _drain(self) -> None:
+        # guarded-by: caller
+        """Bring the step in flight home, if there is one: every public
+        entry that reads or moves a request's tokens or rows between two
+        steps starts here, and then sees what a serial engine would
+        show. The tokens wait in ``_pending_emits`` for the next
+        ``step()`` to return them."""
+        if self._flying is None:
+            return
+        self._trace_on = get_tracer().active()
+        try:
+            self._collect(get_tracer().span if self._trace_on
+                          else noop_span, self._flying,
+                          self._pending_emits)
+        finally:
+            self._trace_on = False
+
+    def _collect(self, span, fly: _FlyingStep,
+                 emitted: Dict[int, List[int]]) -> None:
+        # guarded-by: caller
+        """Fetch a launched step's tokens and deliver them: the one
+        point where the host waits for the device. Scheduling into the
+        rows it frees is the caller's."""
+        if fly is self._flying:
+            self._flying = None
+        with span("engine.fetch", of_step=fly.step) as sp:
+            # ONE batched device→host transfer per fused step (the
+            # analysis JIT110 budget), covering decode tokens AND the
+            # first tokens of completing prefills, on a copy that was
+            # asked for at launch.
+            t_wait = time.perf_counter()
+            toks, logps = profiled_device_get((fly.toks, fly.logps),
+                                              fn="engine.fused_step")
+            if sp is not None:
+                sp.set_attr("wait_ms",
+                            (time.perf_counter() - t_wait) * 1_000.0)
+                sp.set_attr("bytes", int(toks.nbytes + logps.nbytes))
+            self._host_syncs_total.inc()
+        # launch to fetch; the time from the last fetch to this step's
+        # launch was unqueued, where the launch came after it
+        self._fetched_at = get_profiler().end_step(
+            "engine.fused_step", fly.t_launch, self._fetched_at)
+        if self._moe_counters is not None:
+            self._note_moe_step(fly.span, toks, fly.used)
+        if self._mhc_gauge is not None:
+            self._note_mhc_step(fly.span, logps)
+        with span("engine.emit") as sp:
+            n_emitted, n_finished = self._deliver(fly, toks, logps, emitted)
+            self._steps_total.inc()
+            self._tokens_total.inc(n_emitted)
+            if sp is not None:
+                sp.set_attr("emitted", n_emitted)
+                sp.set_attr("finished", n_finished)
+
+    def _note_moe_step(self, st, toks, used: int) -> None:
         # guarded-by: caller
         """An expert model's step: publish what its routing did. The
         device's two counts arrive behind the step's tokens in the fetched
         array (``_paged_fused_step``); the pairs offered are the host's
-        own count, entries in use x experts per token."""
+        own count, entries in use (``used``) x experts per token."""
         touched, load_max = int(toks[-2]), int(toks[-1])
-        used = (len(decode_rows) + sum(j[3] for j in job_rows)
-                + sum(len(r[3]) for r in spec_rows))
         assignments = used * self.config.num_experts_per_tok
         n_banks = self.config.num_expert_layers * self.config.num_experts
         pairs, banks, banks_total, peak = self._moe_counters
@@ -3576,27 +3811,106 @@ class RolloutEngine:
         return sum(r is not None and r is not r0
                    for r0, r in zip(rows0, self._slot_req))
 
-    def _emit_paged(self, toks, logps, decode_rows, spec_rows, job_rows,
-                    emitted: Dict[int, List[int]]) -> int:
+    def _advance_paged(self, decode_rows, job_rows) -> list:
         # guarded-by: caller
-        """Hand the step's tokens to their requests: decode rows,
-        speculative windows, then completing prefill jobs. Returns the
-        number of tokens emitted."""
-        n_emitted = 0
+        """The structural half of a step, right after its launch: what
+        needs only "the step was launched" — the rows' lengths, the
+        prefill jobs' progress and completion, a group's capture and its
+        followers, and every end that is a COUNT of launched tokens
+        (``max_new_tokens``, the context bound): such a request leaves
+        its row here, and the row is free for the queue's next request
+        as it was when tokens came home first. Returns the step's
+        samplers, ``(entry, request, is its first token)`` in entry
+        order, for :meth:`_deliver`."""
+        samplers = []
         for idx, row, req in decode_rows:
+            samplers.append((idx, req, False))
+            req.inflight += 1
+            self._row_len[row] += 1
+            if (req.launched >= req.max_new_tokens
+                    or self._row_len[row] >= self.context_bound - 1):
+                self._close_request(req, row)
+        for row, req, job, n, last_idx, wrote in job_rows:
+            self._row_len[row] += wrote
+            job.toks = job.toks[n:]
+            job.pos += n
+            if job.toks:
+                continue
+            self._prefill_jobs.pop(req.rid, None)
+            g = req.group
+            uncaptured = (g is not None and req.rid == g.donor_rid
+                          and g.spine is None and not g.degraded
+                          and not req.launched)
+            if job.fork_then is not None:
+                # Recurrent state: the donor stands before the prompt's
+                # last token, table and state alike. Fork here, then go
+                # on with that token as a job of one (as each follower
+                # will, from its copy of the state).
+                if uncaptured:
+                    self._group_capture(req, row)
+                self._prefill_jobs[req.rid] = _PrefillJob(
+                    toks=[job.fork_then], pos=job.pos, sample_last=True)
+                continue
+            if uncaptured and job.sample_last and not self.config.ssm:
+                # Donor prefill just completed and its first sampled
+                # token is NOT yet written (tokens are fed the step
+                # after sampling): the table is the pure prompt spine.
+                # The donor's own next write COW-splits the boundary
+                # block.
+                self._group_capture(req, row)
+            if job.sample_last:
+                samplers.append((last_idx, req, True))
+                req.inflight += 1
+                if req.max_new_tokens <= 1:
+                    self._close_request(req, row)
+            else:
+                self._cur_tok_host[row] = job.after_tok
+        return samplers
+
+    def _deliver(self, fly: _FlyingStep, toks, logps,
+                 emitted: Dict[int, List[int]]) -> tuple:
+        # guarded-by: caller
+        """The value half of a step, when its tokens are home: hand them
+        to their REQUESTS (a row may have changed hands since the
+        launch). EOS is a value: a request that meets it ends here, and
+        where a later step was launched ahead with one more entry for
+        its row, that sample is dropped when it arrives. A request is
+        done when its last token is delivered, not before. Speculative
+        windows (never launched ahead: accepting needs the values) roll
+        their rows back here too. Returns (tokens emitted, requests
+        finished)."""
+        n_emitted = n_finished = 0
+        for idx, req, first in fly.samplers:
+            req.inflight -= 1
+            if req.done:
+                continue                # ended by an earlier token
             tok = int(toks[idx])
             req.tokens.append(tok)
             req.logps.append(float(logps[idx]))
             self._stats["tokens_emitted"] += 1
             n_emitted += 1
             emitted.setdefault(req.rid, []).append(tok)
-            self._row_len[row] += 1
-            self._cur_tok_host[row] = tok
+            if first:
+                self._mark_first_token(req)
             hit_eos = req.eos_id is not None and tok == req.eos_id
-            out_of_budget = len(req.tokens) >= req.max_new_tokens
-            out_of_cache = self._row_len[row] >= self.context_bound - 1
-            if hit_eos or out_of_budget or out_of_cache:
-                self._finish_request(req, row)
+            if not req.closing:
+                self._cur_tok_host[req.slot] = tok
+                if hit_eos:
+                    self._close_request(req, req.slot)
+            if hit_eos or (req.closing and not req.inflight):
+                self._mark_done(req)
+                n_finished += 1
+        if fly.spec_rows:
+            n_emitted += self._deliver_spec(fly.spec_rows, toks, logps,
+                                            emitted)
+        return n_emitted, n_finished
+
+    def _deliver_spec(self, spec_rows, toks, logps,
+                      emitted: Dict[int, List[int]]) -> int:
+        # guarded-by: caller
+        """Accept each verify window's agreed prefix and roll both caches
+        back to it. Returns the number of tokens emitted."""
+        n_emitted = 0
         total_proposed = total_accepted = 0
         for base, row, req, props, start in spec_rows:
             k = len(props)
@@ -3660,46 +3974,4 @@ class RolloutEngine:
                       else 0.9 * sp.ema + 0.1 * rate)
             sp.ema_init = True
             sp.accept_gauge.set(sp.ema)
-        for row, req, job, n, last_idx, wrote in job_rows:
-            self._row_len[row] += wrote
-            job.toks = job.toks[n:]
-            job.pos += n
-            if job.toks:
-                continue
-            self._prefill_jobs.pop(req.rid, None)
-            g = req.group
-            uncaptured = (g is not None and req.rid == g.donor_rid
-                          and g.spine is None and not g.degraded
-                          and not req.tokens)
-            if job.fork_then is not None:
-                # Recurrent state: the donor stands before the prompt's
-                # last token, table and state alike. Fork here, then go
-                # on with that token as a job of one (as each follower
-                # will, from its copy of the state).
-                if uncaptured:
-                    self._group_capture(req, row)
-                self._prefill_jobs[req.rid] = _PrefillJob(
-                    toks=[job.fork_then], pos=job.pos, sample_last=True)
-                continue
-            if uncaptured and job.sample_last and not self.config.ssm:
-                # Donor prefill just completed and its first sampled
-                # token is NOT yet written (tokens are fed the step
-                # after sampling): the table is the pure prompt spine.
-                # The donor's own next write COW-splits the boundary
-                # block.
-                self._group_capture(req, row)
-            if job.sample_last:
-                tok = int(toks[last_idx])
-                req.tokens.append(tok)
-                req.logps.append(float(logps[last_idx]))
-                self._stats["tokens_emitted"] += 1
-                n_emitted += 1
-                emitted.setdefault(req.rid, []).append(tok)
-                self._mark_first_token(req)
-                self._cur_tok_host[row] = tok
-                if ((req.eos_id is not None and tok == req.eos_id)
-                        or req.max_new_tokens <= 1):
-                    self._finish_request(req, row)
-            else:
-                self._cur_tok_host[row] = job.after_tok
         return n_emitted

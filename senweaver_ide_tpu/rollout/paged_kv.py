@@ -853,10 +853,12 @@ def gather_blocks_quant(pool: PagedKVPool,
 # the prefix import/export + COW cost the KV-economics roadmap item
 # needs numbers for. The block-count ladder makes a handful of
 # signatures per pool shape legitimate; only unbounded growth storms.
+# the pool is out of the signature scan and nothing waits: a copy is
+# ordered on the device by the next step's donation of the pool, behind
+# a fused step that is still in flight
 copy_blocks = ProfiledFunction(copy_blocks, "paged_kv.copy",
+                               skip_args=(0,), block=False,
                                storm_threshold=32)
-# the pool is out of the signature scan and nothing waits: the copy is
-# ordered on the device by the next step's donation of the pool
 copy_state_rows = ProfiledFunction(copy_state_rows, "paged_kv.copy_state",
                                    skip_args=(0,), block=False,
                                    storm_threshold=32)

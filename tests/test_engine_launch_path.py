@@ -1,8 +1,8 @@
 """The paged step's launch path: between two fused steps the host enqueues
 ONE program and waits on ONE transfer. The key is split inside the jit
 (the parent's stream, to the bit), the wrapper neither blocks nor scans the
-pool, the tokens' copy is asked for at launch, and the plan's five vectors
-enter as one array. Counted here on the CPU; what it is worth in time only
+pool, the tokens' copy is asked for at launch, the plan's six vectors
+enter as one array, and a copy-on-write waits for nothing either. Counted here on the CPU; what it is worth in time only
 the chip says (PERF.md §6, PR 31)."""
 
 import dataclasses
@@ -66,9 +66,9 @@ def test_a_step_splits_no_key_on_the_host_and_blocks_once(model,
     """Over 20 steps: no ``jax.random.split`` of a concrete key (the one
     inside the jit runs on tracers, while a shape compiles), no
     ``jax.block_until_ready``, and one ``device_get`` a step, counted by
-    ``senweaver_engine_step_host_syncs_total`` too. Plain requests: a
-    group's follower pays one blocking ``paged_kv.copy`` when its first
-    write splits the block it shares (``_ensure_block``; PERF.md §7)."""
+    ``senweaver_engine_step_host_syncs_total`` too. Three requests on four
+    rows: an under-loaded engine fetches every step in the call that
+    launched it (tests/test_engine_run_ahead.py has the saturated one)."""
     host_splits, blocks, gets = [], [], []
     split, ready, get = (jax.random.split, jax.block_until_ready,
                          jax.device_get)
@@ -130,7 +130,8 @@ def test_a_steps_tokens_are_sampled_with_the_second_half_of_the_split(
     eng.step()
     params, config = model
     next_key, step_key = jax.random.split(jax.random.PRNGKey(SEED))
-    tokens, seq_row, positions, write_block, write_off = seen["plan"]
+    tokens, seq_row, positions, write_block, write_off, feed = seen["plan"]
+    assert not (feed & engine_mod.FEED_TAKE).any()      # host-known tokens
     logits, _pool, *_ = engine_mod.forward_paged(
         params, config, tokens, pool=ref.pool, tables=seen["tables"],
         seq_row=seq_row, positions=positions, write_block=write_block,
@@ -165,7 +166,10 @@ def test_launch_says_its_host_arrays_and_fetch_its_wait(model):
     spans = obs.get_tracer().spans()
     launches = [s for s in spans if s.name == "engine.launch"]
     fetches = [s for s in spans if s.name == "engine.fetch"]
-    assert len(launches) == len(fetches) == STEPS
+    # six requests on four rows: the last step launched is still in flight
+    assert eng._flying is not None
+    assert len(launches) == len(fetches) + 1 == STEPS
+    assert [s.attrs["of_step"] for s in fetches] == list(range(STEPS - 1))
     assert all(s.attrs["host_arrays"] == 2 for s in launches)
     for s in fetches:
         assert 0.0 <= s.attrs["wait_ms"] <= s.duration_ms
@@ -178,8 +182,9 @@ def test_launch_says_its_host_arrays_and_fetch_its_wait(model):
 
 
 def test_the_plan_enters_as_one_array_beside_the_table(model, monkeypatch):
-    """What the jit is handed: two host arrays (the ``(5, T)`` plan, the
-    table); params, pool and key are the device's."""
+    """What the jit is handed: two host arrays (the ``(6, T)`` plan, the
+    table); params, pool, key and the rows' current tokens are the
+    device's."""
     calls = []
     fn = engine_mod._paged_fused_step
 
@@ -196,9 +201,11 @@ def test_the_plan_enters_as_one_array_beside_the_table(model, monkeypatch):
                 if isinstance(a, np.ndarray)]
         assert len(host) == 2
         plan, tables = args[2], args[3]
-        assert plan.dtype == np.int32 and plan.shape[0] == 5
+        assert plan.dtype == np.int32 and plan.shape[0] == 6
         assert tables.shape[0] == eng.num_slots
         assert isinstance(args[5], jax.Array)           # the key
+        assert isinstance(args[6], jax.Array)           # the rows' tokens
+        assert args[6].shape == (eng.num_slots,)
         widths.add(plan.shape[1])
     assert widths == {eng.num_slots, 16}                 # the same two
 
@@ -212,7 +219,7 @@ def test_the_ledger_counts_one_compile_a_new_shape_without_blocking():
     config = dataclasses.replace(tiny_test(), vocab_size=101)
     model = (init_params(config, jax.random.PRNGKey(0)), config)
     assert engine_mod._paged_fused_step.block is False
-    assert set(engine_mod._paged_fused_step.skip_args) == {0, 1, 4, 5}
+    assert set(engine_mod._paged_fused_step.skip_args) == {0, 1, 4, 5, 6}
     prof = get_profiler()
     obs.enable()
     eng = make_engine(model)
@@ -226,21 +233,53 @@ def test_the_ledger_counts_one_compile_a_new_shape_without_blocking():
     assert snap["compiles"] == len(snap["signatures"]) == len(shapes)
     assert all(s["compiles"] == 1 for s in snap["signatures"])
     # the step as the host saw it: the engine's own launch-to-fetch time,
-    # one observation a step, nothing of the wrapper's dispatch beside it
+    # one observation a fetched step, nothing of the wrapper's dispatch
+    # beside it. A saturated engine has up to two steps in flight at a
+    # time, the one that runs and the one queued behind it.
     spans = obs.get_tracer().spans()
     lo = sum(s.duration_ms for s in spans
              if s.name in ("engine.launch", "engine.fetch"))
-    hi = sum(s.duration_ms for s in spans if s.name == "engine.step")
+    steps = [s for s in spans if s.name == "engine.step"]
+    hi = 2 * (steps[-1].end_ns - steps[0].start_ns) / 1e6
     assert lo <= snap["step_ms_sum"] <= hi
     hist = obs.get_registry().get("senweaver_runtime_step_ms").snapshot(
         fn="engine.fused_step")
-    assert hist["count"] == STEPS
+    assert eng._flying is not None
+    assert hist["count"] == STEPS - 1
     assert hist["sum"] == pytest.approx(snap["step_ms_sum"], abs=1e-2)
     drive(make_engine(model))                   # the same shapes again
     again = prof.ledger()["engine.fused_step"]
     assert again["compiles"] == snap["compiles"]
     assert again["calls"] == 2 * STEPS
 
+
+def test_a_copy_on_write_waits_for_nothing_and_hands_host_ids(model,
+                                                              monkeypatch):
+    """A follower's first write splits the block it shares
+    (``_ensure_block``): ``paged_kv.copy`` is wrapped ``block=False`` with
+    the pool out of the signature scan, as ``copy_state_rows`` is, and its
+    two block ids enter as numpy, not as two dispatches of their own. A
+    blocking copy on the pool would wait for the step in flight and turn
+    every forking step back into a serial one."""
+    assert engine_mod.copy_blocks.block is False
+    assert engine_mod.copy_blocks.skip_args == (0,)
+    calls, blocks, made = [], [], []
+    copy, ready, asarray = (engine_mod.copy_blocks, jax.block_until_ready,
+                            engine_mod.jnp.asarray)
+    monkeypatch.setattr(engine_mod, "copy_blocks",
+                        lambda *a: calls.append(a[1:]) or copy(*a))
+    eng = make_engine(model)
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: blocks.append(1) or ready(x))
+    monkeypatch.setattr(engine_mod.jnp, "asarray",
+                        lambda *a, **kw: made.append(a) or asarray(*a, **kw))
+    drive(eng)
+    assert len(calls) == eng._alloc.counters()["cow_copies"] > 0
+    for src, dst in calls:
+        for ids in (src, dst):
+            assert isinstance(ids, np.ndarray)
+            assert ids.dtype == np.int32 and ids.shape == (1,)
+    assert blocks == [] and made == []
 
 
 def test_the_hot_frames_keep_the_size_set_up_was_measured_at():
@@ -249,9 +288,18 @@ def test_the_hot_frames_keep_the_size_set_up_was_measured_at():
     jitted call leave a hot call astride a chunk's edge: at 60 slots for
     these two frames the warm-up took 2.75 s in the qwen cells and 4.77 s
     in glm, at the 67 they had before PR 31 it takes 2.23 and 3.88 (my
-    chip runs, PERF.md §6, PR 31). A change here is a new draw: measure
-    warm ``setup_s`` on the chip, parent against change, and move this
-    number with it."""
+    chip runs, PERF.md §6, PR 31). PR 36 rewrote both frames (the step
+    runs ahead: ``_step_paged`` 30 locals + 12 of stack, ``_launch_paged``
+    11 + 14 with two unused locals) and kept the sum: warm, 25 engine
+    steps of the qwen grpo cell took 2.36 / 2.38 / 2.54 s against the
+    parent's 2.23 / 2.37 / 2.28 / 2.27 / 2.29, chat-open's 13 steps 2.34
+    / 2.49 against 2.28 / 2.38, glm's 55 steps 3.51 against 3.59 / 3.67
+    (my chip runs, one call a cell, PERF.md §6, PR 36: the qwen cells
+    +0.1 s, inside the parent's own range of PR 35, 2.26-2.40; glm
+    none). The first run of every shape compiles anew (18-19.7 s at 65
+    to 73 slots, the least at 67).
+    A change here is a new draw: measure warm ``setup_s`` on the chip,
+    parent against change, and move this number with it."""
     slots = sum(f.__code__.co_nlocals + f.__code__.co_stacksize
                 for f in (RolloutEngine._step_paged,
                           RolloutEngine._launch_paged))

@@ -18,8 +18,10 @@ from senweaver_ide_tpu.rollout import engine as engine_mod
 from senweaver_ide_tpu.rollout.sampler import SampleParams
 
 SAMPLED = SampleParams(temperature=1.0, top_k=0, top_p=0.9)
-STEP_CHILDREN = ("engine.plan", "engine.launch", "engine.fetch",
-                 "engine.emit")
+# a step that launches and collects its own tokens (the serial order),
+# and the pieces of one that runs ahead (tests/test_engine_run_ahead.py)
+STEP_CHILDREN = ("engine.plan", "engine.launch", "engine.advance",
+                 "engine.schedule", "engine.fetch", "engine.emit")
 PLAN_CHILDREN = ("engine.schedule", "engine.assemble_plan", "engine.tables")
 # the wrapped jit call alone: nothing under launch waits (the step's one
 # wait is engine.fetch's own time, attr wait_ms)
@@ -79,20 +81,39 @@ def test_every_step_has_the_named_phases_nested_in_it(model):
     steps = names["engine.step"]
     assert len(steps) == eng.stats()["decode_steps"]
     assert "engine.decode_step" not in names
+    sub = lambda p: [s.name for s in spans if s.parent_id == p.span_id]
+    serial = 0
     for step in steps:
         kids = [s for s in spans if s.parent_id == step.span_id]
-        assert [k.name for k in kids] == list(STEP_CHILDREN)
-        plan, launch, fetch, emit = kids
-        sub = lambda p: [s.name for s in spans if s.parent_id == p.span_id]
+        got = [k.name for k in kids]
+        # plan, launch, advance; then the one schedule that decides whether
+        # the step stays in flight, and a fetch + emit for each step that
+        # comes home under this span: its own (serial), the one before it
+        # (ahead, collected before the schedule), both, or none yet
+        assert got[:3] == list(STEP_CHILDREN[:3])
+        assert got.count("engine.schedule") == 1
+        assert (got.count("engine.fetch") == got.count("engine.emit")
+                == step.attrs["ahead"] + (got[-1] == "engine.emit"))
+        for k, nxt in zip(kids, kids[1:]):
+            assert (k.name == "engine.fetch") == (nxt.name == "engine.emit")
+        if not step.attrs["ahead"] and got[-1] == "engine.emit":
+            assert got == list(STEP_CHILDREN)
+            serial += 1
+        plan, launch, advance = kids[:3]
         assert [n for n in sub(plan) if n in PLAN_CHILDREN] == \
             list(PLAN_CHILDREN)
         assert sub(launch) == list(LAUNCH_CHILDREN)
-        assert sub(fetch) == []
-        assert sub(emit) == ["engine.schedule"]
+        assert sub(advance) == []
         # two host arrays through the dispatch (the packed plan, the
         # table); the wait is inside the fetch
         assert launch.attrs["host_arrays"] == 2
-        assert 0.0 <= fetch.attrs["wait_ms"] <= fetch.duration_ms
+        for k in kids[3:]:
+            if k.name == "engine.fetch":
+                assert sub(k) == []
+                assert 0.0 <= k.attrs["wait_ms"] <= k.duration_ms
+            elif k.name == "engine.emit":
+                assert sub(k) == []         # scheduling is the step's
+    assert serial and any(s.attrs["ahead"] for s in steps)
     assert "engine.fused_step.wait" not in names
     # every child lies inside its parent, on the perf_counter_ns clock
     for s in spans:
@@ -130,8 +151,8 @@ def test_step_attrs_add_up_to_the_engines_own_counts(model):
     assert any(x["entries"] == eng.num_slots for x in a)
     spans = by_name(obs.get_tracer().spans())
     # a request is placed by a step's leading or its trailing schedule
-    assert sum(s.attrs["admitted"] for s in spans["engine.plan"]
-               + spans["engine.emit"]) == n_requests
+    assert sum(s.attrs.get("admitted", 0) for s in spans["engine.plan"]
+               + spans["engine.schedule"]) == n_requests
     assert spans["engine.plan"][0].attrs["admitted"] == len(PROMPTS) + 1
     assert sum(s.attrs["emitted"] for s in spans["engine.emit"]) == \
         st["tokens_emitted"]
@@ -320,12 +341,15 @@ def test_off_path_asks_the_profiler_once_a_step_and_records_nothing(
     assert obs.get_tracer().spans() == []
     assert all(eng.is_done(r) for r in rids)
     # the unqueued counter needs no tracing: one reading between every
-    # two fused steps, in the ledger and on /metrics
+    # two fused steps (0 where the second was launched ahead), in the
+    # ledger and on /metrics
     from senweaver_ide_tpu.obs.runtime_profile import get_profiler
     assert get_profiler().ledger()["engine.fused_step"][
         "unqueued_ms_sum"] > 0.0
+    launched = eng.stats()["decode_steps"]
+    assert steps - 1 <= launched <= steps
     assert (f'senweaver_runtime_unqueued_ms_count{{fn="engine.fused_step"}}'
-            f' {steps - 1}' in obs.get_registry().render())
+            f' {launched - 1}' in obs.get_registry().render())
 
 
 def test_same_seed_same_tokens_and_logps_with_tracing_on_and_off(model):
@@ -349,9 +373,9 @@ def test_same_seed_same_tokens_and_logps_with_tracing_on_and_off(model):
 
 def test_a_followers_first_write_is_a_cow_copy_span_under_the_plan(model):
     """A group's prompt ends inside a block: each follower's first write
-    splits the block it shares (``_ensure_block``), a blocking
-    ``paged_kv.copy`` and two index arrays, all inside ``engine.cow_copy``
-    under ``engine.assemble_plan``; the spans add up to the allocator's
+    splits the block it shares (``_ensure_block``): one ``paged_kv.copy``
+    that nothing waits for (the pool's donation orders it), inside
+    ``engine.cow_copy`` under ``engine.assemble_plan``; the spans add up to the allocator's
     counter (a step's count of copies is its ``engine.cow_copy`` spans)."""
     eng = make_engine(model)
     obs.enable()
@@ -365,9 +389,9 @@ def test_a_followers_first_write_is_a_cow_copy_span_under_the_plan(model):
     for c in copies:
         assert byid[c.parent_id].name == "engine.assemble_plan"
         assert c.attrs == {}
-        # the copy's own dispatch and wait are inside it, not beside it
+        # the copy's own dispatch is inside it, not beside it
         kids = [s.name for s in spans if s.parent_id == c.span_id]
-        assert kids == ["paged_kv.copy.dispatch", "paged_kv.copy.wait"]
+        assert kids == ["paged_kv.copy.dispatch"]
     for c in copies:
         (st,) = [s for s in names["engine.step"]
                  if s.start_ns <= c.start_ns and c.end_ns <= s.end_ns]
@@ -381,23 +405,34 @@ def test_unqueued_ms_rides_the_step_and_is_the_profilers_reading(model):
     ``unqueued_ms``: the time from the last step's tokens on the host to
     this step's launch (emit, the caller, plan, copies), as
     ``RuntimeProfiler.end_step`` counted it. It lies between the spans
-    that bound it."""
+    that bound it, and is 0 where the step was launched ahead: the one
+    before it was still in flight."""
     from senweaver_ide_tpu.obs.runtime_profile import get_profiler
     eng = make_engine(model)
     obs.enable()
     drive(eng)
     spans = obs.get_tracer().spans()
-    steps = by_name(spans)["engine.step"]
+    names = by_name(spans)
+    steps = [s for s in names["engine.step"] if "launches" in s.attrs]
     kid = lambda st, name: next(s for s in spans if s.name == name
                                 and s.parent_id == st.span_id)
     assert "unqueued_ms" not in steps[0].attrs
-    for prev, cur in zip(steps, steps[1:]):
+    serial = 0
+    for cur in steps[1:]:
         got = cur.attrs["unqueued_ms"]
-        inner = (kid(cur, "engine.plan").end_ns
-                 - kid(prev, "engine.emit").start_ns) / 1e6
-        outer = (kid(cur, "engine.launch").start_ns
-                 - kid(prev, "engine.fetch").end_ns) / 1e6
+        if cur.attrs["ahead"]:
+            assert got == 0.0
+            continue
+        serial += 1
+        launch = kid(cur, "engine.launch")
+        fetch = max((f for f in names["engine.fetch"]
+                     if f.end_ns <= launch.start_ns), key=lambda f: f.end_ns)
+        emit = min((e for e in names["engine.emit"]
+                    if e.start_ns >= fetch.end_ns), key=lambda e: e.start_ns)
+        inner = (kid(cur, "engine.plan").end_ns - emit.start_ns) / 1e6
+        outer = (launch.start_ns - fetch.end_ns) / 1e6
         assert 0.0 < inner <= got <= outer
+    assert serial and serial < len(steps) - 1
     led = get_profiler().ledger()["engine.fused_step"]
     assert led["unqueued_ms_sum"] == pytest.approx(
         sum(s.attrs["unqueued_ms"] for s in steps[1:]), abs=0.01)
@@ -411,8 +446,8 @@ def test_two_engines_in_two_threads_each_read_their_own_unqueued_time(
     thread of its own and all under ``engine.fused_step``: one's launch
     precedes another's fetch. Each engine keeps its own last fetch, so no
     ``unqueued_ms`` is negative, an engine's first step carries none
-    (whatever the other did before), and the name's sum is the sum of the
-    attrs."""
+    (whatever the other did before; 0 where it ran ahead of itself), and
+    the name's sum is the sum of the attrs."""
     import threading
     from senweaver_ide_tpu.obs.runtime_profile import get_profiler
     engines = [make_engine(model, seed=s) for s in (3, 4)]
@@ -428,7 +463,7 @@ def test_two_engines_in_two_threads_each_read_their_own_unqueued_time(
     assert len(firsts) == 2
     assert all("unqueued_ms" not in s.attrs for s in firsts)
     got = [s.attrs["unqueued_ms"] for s in steps if s.attrs["step"] > 0]
-    assert len(got) == len(steps) - 2 and min(got) > 0.0
+    assert len(got) == len(steps) - 2 and min(got) >= 0.0 < max(got)
     led = get_profiler().ledger()["engine.fused_step"]
     assert led["unqueued_ms_sum"] == pytest.approx(sum(got), abs=0.01)
     hist = obs.get_registry().get("senweaver_runtime_unqueued_ms")
@@ -509,9 +544,9 @@ def _lowered_fused_step(config, seed=0):
                         engine_config=EngineConfig(kv_layout="paged",
                                                    block_size=4))
     return engine_mod._paged_fused_step.lower(
-        params, config, np.zeros((5, 4), np.int32),
+        params, config, np.zeros((6, 4), np.int32),
         np.zeros((4, 2), np.int32), eng.pool, jax.random.PRNGKey(0),
-        SAMPLED, False).as_text(debug_info=True)
+        eng._cur_tok_dev, SAMPLED, False).as_text(debug_info=True)
 
 
 @pytest.mark.parametrize("make,scopes", [

@@ -529,10 +529,10 @@ def test_a_dense_models_step_fetches_what_it_did():
     eng = RolloutEngine(params, config, num_slots=4, max_len=64,
                         sample=GREEDY,
                         engine_config=EngineConfig(block_size=4))
-    toks, logp, _pool, _ = engine_mod._paged_fused_step(
-        params, config, np.zeros((5, 4), np.int32),
+    toks, logp, _pool, _, _ = engine_mod._paged_fused_step(
+        params, config, np.zeros((6, 4), np.int32),
         np.zeros((4, 2), np.int32), eng.pool, jax.random.PRNGKey(0),
-        GREEDY, False)
+        eng._cur_tok_dev, GREEDY, False)
     assert toks.shape == (4,) and logp.shape == (4,)
     eng.pool = _pool
     eng.submit(PROMPT, max_new_tokens=3)
@@ -547,11 +547,11 @@ def test_an_expert_models_step_appends_two_counts(model):
     params, config = model
     eng = make_engine(model, num_slots=4)
     drop = eng.pool.num_blocks          # the dropped-write sentinel
-    plan = np.zeros((5, 4), np.int32)
+    plan = np.zeros((6, 4), np.int32)
     plan[3] = [0, 0, drop, drop]
-    toks, logp, _, _ = engine_mod._paged_fused_step(
+    toks, logp, _, _, _ = engine_mod._paged_fused_step(
         params, config, plan, np.zeros((4, 2), np.int32), eng.pool,
-        jax.random.PRNGKey(0), GREEDY, False)
+        jax.random.PRNGKey(0), eng._cur_tok_dev, GREEDY, False)
     assert toks.shape == (6,) and logp.shape == (4,)
     # two identical entries write, two are dropped: 2 experts x 2 layers
     # touched, 2 pairs on each

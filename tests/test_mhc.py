@@ -202,8 +202,12 @@ def served(model):
     assert eng.kv_layout == "paged" and eng.kv_layout_fallback is None
     assert eng.stats()["group_forks"] == 2
     eng._alloc.check_leaks()
+    # four requests fill the four rows: the engine runs a step ahead, and
+    # a step's values are set on the span that LAUNCHED it when they come
+    # home, so every step has its own
     steps = [s.attrs for s in obs.get_tracer().spans()
              if s.name == "engine.step" and "entries" in s.attrs]
+    assert all("mhc_ds_err" in a for a in steps)
     gauge = obs.get_registry().get("senweaver_mhc_sinkhorn_err").value()
     obs._reset_for_tests()
     return {"requests": [(p, eng.result(r), eng.result_logps(r))
@@ -510,11 +514,12 @@ def test_step_reports_its_worst_map_in_the_one_fetch(model, served):
     assert served["gauge"] == steps[-1]["mhc_ds_err"]
     assert all(len(logps) == 9 for _, _, logps in served["requests"])
     # the step itself: T log-probs and one float, T tokens and two counts
-    plan = np.zeros((5, 4), np.int32)
+    plan = np.zeros((6, 4), np.int32)
     plan[3] = served["pool"].num_blocks
-    toks, logp, _, _ = engine_mod._paged_fused_step(
+    toks, logp, _, _, _ = engine_mod._paged_fused_step(
         model[0], model[1], plan, np.zeros((4, 16), np.int32),
-        served["pool"], jax.random.PRNGKey(0), SAMPLED, None)
+        served["pool"], jax.random.PRNGKey(0), np.zeros((4,), np.int32),
+        SAMPLED, None)
     assert toks.shape == (6,) and logp.shape == (5,)
     assert 1e-6 < float(logp[-1]) < 0.5
 

@@ -262,8 +262,9 @@ def test_compiled_step_keeps_no_second_pool(program):
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     if program == "fused_step":
         lowered = eng._paged_fused_step.lower(
-            params, c, i32(5, entries), i32(rows, width), pool,
-            jax.ShapeDtypeStruct((2,), jnp.uint32), SampleParams(), False)
+            params, c, i32(6, entries), i32(rows, width), pool,
+            jax.ShapeDtypeStruct((2,), jnp.uint32), i32(rows),
+            SampleParams(), False)
     else:
         lowered = eng._draft_propose_scan.lower(
             params, c, i32(rows), i32(rows),
@@ -346,9 +347,9 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = eng._paged_fused_step.lower(
-            params, c, i32(5, entries), i32(rows, width), pool,
+            params, c, i32(6, entries), i32(rows, width), pool,
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_v5e),
-            SampleParams(temperature=1.0), None).compile()
+            i32(rows), SampleParams(temperature=1.0), None).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
     text = compiled.as_text()
@@ -419,9 +420,9 @@ def test_hybrid_step_compiled_for_v5e_copies_no_pool_and_no_state(
     jax.config.update("jax_enable_compilation_cache", False)
     try:
         compiled = eng._paged_fused_step.lower(
-            params, c, i32(5, entries), i32(rows, width), pool,
+            params, c, i32(6, entries), i32(rows, width), pool,
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_v5e),
-            SampleParams(temperature=1.0), None).compile()
+            i32(rows), SampleParams(temperature=1.0), None).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cache)
     text = compiled.as_text()

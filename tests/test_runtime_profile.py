@@ -143,11 +143,13 @@ def test_unqueued_and_step_time_tile_the_span_of_the_steps():
     assert got["sum"] == pytest.approx(snap["unqueued_ms_sum"], abs=0.002)
     text = obs.get_registry().render()
     assert 'senweaver_runtime_unqueued_ms_count{fn="t.tile"} 3' in text
-    # a reading from after the launch is no unqueued time: nothing negative
-    # is ever observed
+    # a step begun before the last one ended (a caller that runs a step
+    # ahead) had one in flight all the time: 0 is observed, never a
+    # negative reading
     t = prof.begin_step("t.tile")
     assert prof.end_step("t.tile", t, t + 1.0) > t
-    assert hist.snapshot(fn="t.tile")["count"] == 3
+    assert hist.snapshot(fn="t.tile")["count"] == 4
+    assert hist.snapshot(fn="t.tile")["sum"] == pytest.approx(got["sum"])
     # off: nothing is read, nothing returned
     prof.set_enabled(False)
     assert prof.end_step("t.tile", prof.begin_step("t.tile"), t) == 0.0
