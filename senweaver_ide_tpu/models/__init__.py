@@ -1,4 +1,5 @@
-from .config import (ModelConfig, PRESETS, RecurrentStateUnsupported,
+from .config import (ExpertShareUnsupported, ModelConfig, PRESETS,
+                     RecurrentStateUnsupported,
                      RopeScaling, YarnScaling,
                      get_config,
                      qwen2_5_coder_0_5b, qwen2_5_coder_1_5b, qwen2_5_coder_7b,

@@ -97,10 +97,11 @@ _CAPABILITIES: Tuple[Tuple[str, ModelCapabilities], ...] = (
     ("llama-3", ModelCapabilities(context_window=131_072)),
     ("llama-4", ModelCapabilities(context_window=1_048_576)),
     ("llama", ModelCapabilities(context_window=131_072)),
-    # --- moonshot / zai / alibaba ---------------------------------------
+    # --- moonshot / meituan / zai / alibaba -----------------------------
     ("kimi-k2", ModelCapabilities(context_window=131_072)),
     ("kimi", ModelCapabilities(context_window=131_072)),
     ("moonshot", ModelCapabilities(context_window=131_072)),
+    ("longcat", ModelCapabilities(context_window=131_072)),
     ("glm-4", ModelCapabilities(context_window=131_072)),
     ("glm", ModelCapabilities(context_window=131_072)),
     # --- local test config ----------------------------------------------

@@ -63,6 +63,24 @@ class RecurrentStateUnsupported(NotImplementedError):
         self.mechanism = mechanism
 
 
+class ExpertShareUnsupported(NotImplementedError):
+    """A mechanism that has no form yet for a configuration whose expert
+    layer holds a SHARE of the experts its router addresses
+    (``moe_routed_experts`` > ``num_experts``), has identity experts
+    (``moe_zero_experts``) or sits on a shortcut beside a double block
+    (``shortcut_moe``). Raised where the mechanism is asked for, never
+    replaced by a layer that would drop the absent experts' pairs for
+    capacity or compute the identity experts as banks. ``mechanism``
+    names it."""
+
+    def __init__(self, mechanism: str, config_name: str):
+        super().__init__(
+            f"{mechanism} is not implemented for the expert share / "
+            f"identity experts / shortcut block of configuration "
+            f"{config_name!r}")
+        self.mechanism = mechanism
+
+
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
     """Llama-3-style NTK-by-parts RoPE scaling (HF ``rope_type: llama3``).
@@ -187,9 +205,31 @@ class ModelConfig:
     # renormalised over the chosen (Mixtral, Qwen3-MoE); "sigmoid_bias" =
     # sigmoid scores, choice by score + a per-expert correction bias,
     # weights = the chosen scores WITHOUT the bias, normalised, times
-    # ``routed_scaling_factor`` (DeepSeek-V3 ``noaux_tc`` with one group).
+    # ``routed_scaling_factor`` (DeepSeek-V3 ``noaux_tc`` with one group);
+    # "softmax_bias" = softmax scores over every router output, choice by
+    # score + correction bias, weights = the chosen scores as they are (NO
+    # renormalisation) times ``routed_scaling_factor`` (LongCat-Flash).
     router_type: str = "softmax"
     routed_scaling_factor: float = 1.0
+    # The chip's share of the experts. ``num_experts`` stays the banks held
+    # in ``w_gate/w_up/w_down``; the router addresses
+    # ``moe_routed_experts`` real experts (0 = ``num_experts``: every
+    # expert is held) of which this chip holds those numbered
+    # [``moe_first_expert``, ``moe_first_expert`` + ``num_experts``), and
+    # after them ``moe_zero_experts`` identity experts that return the
+    # token itself and hold no weights (LongCat-Flash's
+    # ``zero_expert_num``, ``zero_expert_type: identity``). A pair routed
+    # to a real expert that is not held adds nothing here (models/moe.py).
+    moe_routed_experts: int = 0
+    moe_first_expert: int = 0
+    moe_zero_experts: int = 0
+    # The shortcut-connected expert block (LongCat-Flash): a layer is TWO
+    # attention sublayers and TWO dense FFNs of ``intermediate_size``, and
+    # the expert layer reads the first FFN's normed input and rejoins the
+    # stream with the second FFN's output
+    # (``models.transformer._shortcut_block``). The cache then holds two
+    # attention layers a layer (``attn_layers``).
+    shortcut_moe: bool = False
     # Multi-head latent attention (DeepSeek-V2; GLM-4.7-Flash): q through a
     # rank-``q_lora_rank`` bottleneck, keys and values through one shared
     # ``kv_lora_rank`` latent plus one decoupled rotary key of
@@ -202,6 +242,11 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # LongCat-Flash's two latent scales: the queries times
+    # sqrt(hidden_size / q_lora_rank), the normed kv latent times
+    # sqrt(hidden_size / kv_lora_rank); the rotary key is not scaled.
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
     # Manifold-constrained hyper-connections (mHC): the residual stream is
     # ``hc_mult`` rows wide and every sublayer reads and writes it through
     # three maps computed from the token's own stream, one of them made
@@ -297,6 +342,38 @@ class ModelConfig:
     @property
     def expert_size(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def routed_experts(self) -> int:
+        """Real experts the router addresses; ``num_experts`` are held."""
+        return self.moe_routed_experts or self.num_experts
+
+    @property
+    def router_width(self) -> int:
+        """Outputs of the router: the real experts, then the identity
+        experts."""
+        return self.routed_experts + self.moe_zero_experts
+
+    @property
+    def expert_share(self) -> bool:
+        """The expert layer holds fewer experts than its router addresses,
+        or some of those are identity experts: a pair may have no bank."""
+        return self.router_width != self.num_experts
+
+    @property
+    def attn_layers(self) -> int:
+        """Attention layers the cache holds: sublayer i of layer l of a
+        shortcut block is pool layer 2 l + i."""
+        return self.num_layers * (2 if self.shortcut_moe else 1)
+
+    @property
+    def mla_scales(self) -> Tuple[float, float]:
+        """(s_q, s_kv) of latent attention; 1.0 where the flag is off."""
+        d = float(self.hidden_size)
+        return ((d / self.q_lora_rank) ** 0.5 if self.mla_scale_q_lora
+                else 1.0,
+                (d / self.kv_lora_rank) ** 0.5 if self.mla_scale_kv_lora
+                else 1.0)
 
     @property
     def num_expert_layers(self) -> int:
@@ -439,6 +516,87 @@ def tiny_xing_mhc_test() -> ModelConfig:
         hc_mult=4, hc_sinkhorn_iters=4)
 
 
+def tiny_longcat_flash_test() -> ModelConfig:
+    """LongCat-Flash's layer (``longcat_flash``) at test size: a shortcut
+    block of two latent-attention sublayers and two dense FFNs with the
+    expert branch beside them, a softmax router 16 + 8 wide (8 identity
+    experts) top-4 with a correction bias and no renormalisation, both
+    latent scales (2 and sqrt(8 / 3)), and this chip's share of the
+    experts: 8 of the 16, from the 4th."""
+    return ModelConfig(
+        name="tiny-longcat-flash-test", vocab_size=512, hidden_size=64,
+        intermediate_size=96, num_layers=2, num_heads=4, num_kv_heads=4,
+        head_dim=12, max_seq_len=128, rope_theta=10_000_000.0,
+        rms_norm_eps=1e-5, dtype=jnp.float32, matmul_precision="highest",
+        num_experts=8, num_experts_per_tok=4, moe_intermediate_size=32,
+        router_type="softmax_bias", routed_scaling_factor=6.0,
+        moe_routed_experts=16, moe_first_expert=4, moe_zero_experts=8,
+        shortcut_moe=True, kv_lora_rank=24, q_lora_rank=16,
+        qk_nope_head_dim=8, qk_rope_head_dim=4, v_head_dim=16,
+        mla_scale_q_lora=True, mla_scale_kv_lora=True)
+
+
+# LongCat-Flash's published ``config.json`` keys (``model_type:
+# longcat_flash``) that ``longcat_flash_config`` maps; any other key is
+# something the program would have to model and does not.
+_LONGCAT_FLASH_KEYS = frozenset((
+    "attention_bias", "vocab_size", "hidden_size", "ffn_hidden_size",
+    "expert_ffn_hidden_size", "num_layers", "num_attention_heads",
+    "kv_lora_rank", "q_lora_rank", "qk_rope_head_dim", "v_head_dim",
+    "qk_nope_head_dim", "mla_scale_q_lora", "mla_scale_kv_lora",
+    "routed_scaling_factor", "n_routed_experts", "max_position_embeddings",
+    "rms_norm_eps", "rope_theta", "attention_method", "zero_expert_num",
+    "zero_expert_type", "moe_topk"))
+
+
+def longcat_flash_config(published: dict, *, name: str,
+                         first_expert: int = 0,
+                         routed_experts: Optional[int] = None,
+                         dtype=jnp.bfloat16,
+                         matmul_precision: Optional[str] = None
+                         ) -> ModelConfig:
+    """LongCat-Flash's published keys -> ``ModelConfig``. ``num_layers``
+    counts its double layers (``shortcut_moe``); ``n_routed_experts`` is
+    the experts HELD, those from ``first_expert`` of the
+    ``routed_experts`` the router addresses (None: all are held). A key
+    this does not map, or a value with no form here (a ``zero_expert_type``
+    other than ``identity``, an attention bias, another
+    ``attention_method``), raises: a silent default under a real model's
+    name would be a guess."""
+    p = published
+    wrong = sorted(set(p) - _LONGCAT_FLASH_KEYS) + [
+        k for k, ok in (
+            ("zero_expert_type", p["zero_expert_type"] == "identity"),
+            ("attention_bias", p["attention_bias"] is False),
+            ("attention_method", p.get("attention_method", "MLA") == "MLA"))
+        if not ok]
+    if wrong:
+        raise ValueError(f"{name}: {wrong} as set are not mapped by "
+                         f"longcat_flash_config")
+    heads = p["num_attention_heads"]
+    return ModelConfig(
+        name=name, vocab_size=p["vocab_size"], hidden_size=p["hidden_size"],
+        intermediate_size=p["ffn_hidden_size"], num_layers=p["num_layers"],
+        num_heads=heads, num_kv_heads=heads,
+        head_dim=p["qk_nope_head_dim"] + p["qk_rope_head_dim"],
+        max_seq_len=p["max_position_embeddings"],
+        rope_theta=float(p["rope_theta"]),
+        rms_norm_eps=float(p["rms_norm_eps"]), dtype=dtype,
+        matmul_precision=matmul_precision,
+        num_experts=p["n_routed_experts"], num_experts_per_tok=p["moe_topk"],
+        moe_intermediate_size=p["expert_ffn_hidden_size"],
+        router_type="softmax_bias",
+        routed_scaling_factor=float(p["routed_scaling_factor"]),
+        moe_routed_experts=routed_experts or p["n_routed_experts"],
+        moe_first_expert=first_expert,
+        moe_zero_experts=p["zero_expert_num"], shortcut_moe=True,
+        kv_lora_rank=p["kv_lora_rank"], q_lora_rank=p["q_lora_rank"],
+        qk_nope_head_dim=p["qk_nope_head_dim"],
+        qk_rope_head_dim=p["qk_rope_head_dim"], v_head_dim=p["v_head_dim"],
+        mla_scale_q_lora=bool(p["mla_scale_q_lora"]),
+        mla_scale_kv_lora=bool(p["mla_scale_kv_lora"]))
+
+
 def tiny_falcon_h1_test() -> ModelConfig:
     """Falcon-H1's block (``falcon_h1``) at test size: a Mamba-2 mixer of
     4 heads x 8 with a state of 16 in 2 groups and a 4-tap conv beside GQA
@@ -548,6 +706,7 @@ PRESETS = {
     "tiny-glm-moe-test": tiny_glm_moe_test,
     "tiny-xing-mhc-test": tiny_xing_mhc_test,
     "tiny-falcon-h1-test": tiny_falcon_h1_test,
+    "tiny-longcat-flash-test": tiny_longcat_flash_test,
     "small-test": small_test,
 }
 

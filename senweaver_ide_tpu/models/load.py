@@ -31,8 +31,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .config import (ModelConfig, RecurrentStateUnsupported,
-                     ResidualStreamUnsupported)
+from .config import (ExpertShareUnsupported, ModelConfig,
+                     RecurrentStateUnsupported, ResidualStreamUnsupported)
 from .transformer import Params
 
 __all__ = ["load_hf_params", "export_hf_params", "available_hf_keys"]
@@ -103,6 +103,10 @@ def load_hf_params(model_dir: str, config: ModelConfig, *,
     if c.ssm:
         # nor are its names for the mixer's leaves
         raise RecurrentStateUnsupported("the HF loader", c.name)
+    if c.shortcut_moe or c.expert_share:
+        # nor for a double block's sublayers, or which experts a chip's
+        # share of a checkpoint would be cut from
+        raise ExpertShareUnsupported("the HF loader", c.name)
     dtype = dtype or c.dtype
     raw = _load_raw(model_dir)
     D, F, L, V = c.hidden_size, c.intermediate_size, c.num_layers, c.vocab_size
@@ -200,6 +204,8 @@ def export_hf_params(params: Params, config: ModelConfig,
         raise ResidualStreamUnsupported("the HF exporter", config.name)
     if config.ssm:
         raise RecurrentStateUnsupported("the HF exporter", config.name)
+    if config.shortcut_moe or config.expert_share:
+        raise ExpertShareUnsupported("the HF exporter", config.name)
     if is_quantized(params):
         # transposing the +/-127 codes without their scales would write a
         # garbage checkpoint that loads cleanly elsewhere

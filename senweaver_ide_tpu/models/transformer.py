@@ -24,6 +24,7 @@ Architectures covered: Qwen2.5-Coder (GQA + QKV bias, tied embeddings at
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
@@ -36,9 +37,9 @@ from ..ops.attention import NEG_INF, attention
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
 from ..ops import ssm as ssm_ops
-from .config import (LatentCacheUnsupported, ModelConfig,
-                     RecurrentStateUnsupported, ResidualStreamUnsupported,
-                     YarnScaling)
+from .config import (ExpertShareUnsupported, LatentCacheUnsupported,
+                     ModelConfig, RecurrentStateUnsupported,
+                     ResidualStreamUnsupported, YarnScaling)
 from .moe import BANKS, MoEStats, expert_ffn
 
 Params = Dict[str, Any]
@@ -166,7 +167,16 @@ def _init_layer_stack(c: ModelConfig, key: jax.Array, L: int,
     """One stack of ``L`` layers of the same structure, every leaf with a
     leading L axis. ``expert``: routed experts (+ shared expert) in place
     of the dense SwiGLU. Every matrix is stored ``(..., fan_in, fan_out)``.
-    """
+
+    A shortcut block (``c.shortcut_moe``) has two attention sublayers and
+    two dense FFNs a layer: ``sub0`` and ``sub1`` are each a dense layer's
+    leaves (norms, attention, ``w_gate / w_up / w_down`` of
+    ``intermediate_size``), and beside them sit the expert layer's
+    (``router`` as wide as the router, ``router_bias_norm``, the banks of
+    the experts held) (``_shortcut_block``). Leaves of their own, not one
+    leaf with a sublayer axis: a layer's slice of such a leaf has two
+    readers, and XLA:TPU then copies the slice out every layer (PERF.md
+    section 6, PR 37)."""
     def dense(key, shape, fan_in):
         # Generate directly in the target dtype: the fp32-then-cast
         # pattern materializes an fp32 transient of every stacked tensor
@@ -177,6 +187,45 @@ def _init_layer_stack(c: ModelConfig, key: jax.Array, L: int,
 
     D, F = c.hidden_size, c.intermediate_size
     ks = jax.random.split(key, 8)
+    if c.shortcut_moe and not (c.mla and expert and not c.hc_mult
+                               and not c.ssm and not c.num_shared_experts):
+        raise ValueError(
+            f"{c.name}: a shortcut block is two latent-attention sublayers, "
+            f"two dense FFNs and routed experts, on the plain residual")
+    if expert and c.moe_first_expert + c.num_experts > c.routed_experts:
+        raise ValueError(
+            f"{c.name}: experts [{c.moe_first_expert}, "
+            f"{c.moe_first_expert + c.num_experts}) held of "
+            f"{c.routed_experts} routed")
+
+    def experts():
+        E, Fe = c.num_experts, c.expert_size
+        out = {"router": dense(ks[7], (L, D, c.router_width), D),
+               "w_gate": dense(ks[4], (L, E, D, Fe), D),
+               "w_up": dense(ks[5], (L, E, D, Fe), D),
+               "w_down": dense(ks[6], (L, E, Fe, D), Fe)}
+        if c.router_type in ("sigmoid_bias", "softmax_bias"):
+            # The per-expert correction bias added to the scores for the
+            # CHOICE only. A trained model's bias evens the load; training
+            # starts it at 0. Named ``*_norm`` because, like a norm's gain,
+            # a seeded-weights filler has to leave it a constant: drawn at
+            # random it would decide the choice in place of the scores.
+            out["router_bias_norm"] = jnp.zeros((L, c.router_width),
+                                                jnp.float32)
+        if c.num_shared_experts:
+            Fs = c.num_shared_experts * Fe
+            kk = jax.random.split(ks[1], 3)
+            out["ws_gate"] = dense(kk[0], (L, D, Fs), D)
+            out["ws_up"] = dense(kk[1], (L, D, Fs), D)
+            out["ws_down"] = dense(kk[2], (L, Fs, D), Fs)
+        return out
+
+    if c.shortcut_moe:
+        plain = dataclasses.replace(c, shortcut_moe=False)
+        return {**experts(), **{
+            f"sub{i}": _init_layer_stack(
+                plain, jax.random.fold_in(key, 300 + i), L, expert=False)
+            for i in range(2)}}
     layers = {"attn_norm": jnp.ones((L, D), c.dtype),
               "mlp_norm": jnp.ones((L, D), c.dtype)}
     if c.mla:
@@ -203,24 +252,7 @@ def _init_layer_stack(c: ModelConfig, key: jax.Array, L: int,
             wv=dense(ks[2], (L, D, c.kv_dim), D),
             wo=dense(ks[3], (L, c.q_dim, D), c.q_dim))
     if expert:
-        E, Fe = c.num_experts, c.expert_size
-        layers["router"] = dense(ks[7], (L, D, E), D)
-        layers["w_gate"] = dense(ks[4], (L, E, D, Fe), D)
-        layers["w_up"] = dense(ks[5], (L, E, D, Fe), D)
-        layers["w_down"] = dense(ks[6], (L, E, Fe, D), Fe)
-        if c.router_type == "sigmoid_bias":
-            # The per-expert correction bias added to the scores for the
-            # CHOICE only. A trained model's bias evens the load; training
-            # starts it at 0. Named ``*_norm`` because, like a norm's gain,
-            # a seeded-weights filler has to leave it a constant: drawn at
-            # random it would decide the choice in place of the scores.
-            layers["router_bias_norm"] = jnp.zeros((L, E), jnp.float32)
-        if c.num_shared_experts:
-            Fs = c.num_shared_experts * Fe
-            kk = jax.random.split(ks[1], 3)
-            layers["ws_gate"] = dense(kk[0], (L, D, Fs), D)
-            layers["ws_up"] = dense(kk[1], (L, D, Fs), D)
-            layers["ws_down"] = dense(kk[2], (L, Fs, D), Fs)
+        layers.update(experts())
     else:
         layers["w_gate"] = dense(ks[4], (L, D, F), D)
         layers["w_up"] = dense(ks[5], (L, D, F), D)
@@ -410,19 +442,23 @@ def _mla_project(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array,
     q_rope (B, S, H, rope) rotated, and the row the cache holds for each
     token, ``latent`` (B, S, kv_lora_rank + rope) = [RMSNorm(c) | RoPE(k_r)]:
     one compressed vector for all heads' keys and values, and one rotary
-    key shared by all heads."""
+    key shared by all heads. Where the configuration sets them
+    (``ModelConfig.mla_scales``) the queries are multiplied by s_q and the
+    normed latent by s_kv; the rotary key is not scaled."""
     b, s, _ = h.shape
     nope, r = c.qk_nope_head_dim, c.kv_lora_rank
+    s_q, s_kv = c.mla_scales
     with jax.named_scope("attn.q_latent"):
         cq = rms_norm(_dense(h, lp, "wq_a", "bsd,dr->bsr"), lp["q_a_norm"],
                       c.rms_norm_eps)
-        q = _dense(cq, lp, "wq_b", "bsr,re->bse").reshape(
+        q = _times(_dense(cq, lp, "wq_b", "bsr,re->bse"), s_q).reshape(
             b, s, c.num_heads, c.head_dim)
         q_nope = q[..., :nope]
         q_rope = apply_rope(q[..., nope:], cos, sin)
     with jax.named_scope("attn.kv_latent"):
         ckr = _dense(h, lp, "wkv_a", "bsd,dr->bsr")
-        c_kv = rms_norm(ckr[..., :r], lp["kv_a_norm"], c.rms_norm_eps)
+        c_kv = _times(rms_norm(ckr[..., :r], lp["kv_a_norm"],
+                               c.rms_norm_eps), s_kv)
         k_rope = apply_rope(ckr[..., None, r:], cos, sin)[..., 0, :]
         latent = jnp.concatenate([c_kv, k_rope], axis=-1)
     return q_nope, q_rope, latent
@@ -763,6 +799,12 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     — in the no-cache case the returned pair is the block's own (k, v);
     aux is the MoE load-balancing loss (0 for dense layers).
     """
+    if c.shortcut_moe:
+        x, kv_out, aux, _ = _shortcut_block(
+            c, lp, x, lambda lp_i, i, x_in, kv: _attend(
+                c, lp_i, x_in, cos, sin, cache_kv, kv_mask, mesh,
+                flash_decode_ok), None)
+        return x, kv_out, aux
     if c.ssm:
         # two mixers on one normed input (``_mixers``); the scan is causal,
         # so a mask of a right-padded tail changes nothing before it
@@ -909,38 +951,89 @@ def _swiglu(h: jax.Array, lp: Dict[str, jax.Array], gate: str, up: str,
     return _times(_dense(act, lp, down, "bsf,fd->bsd"), mults[1])
 
 
+def _ffn(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array,
+         count: Optional[jax.Array] = None,
+         stack_layer: Optional[jax.Array] = None):
+    """The FFN of a block on its normed input h (B, S, D): a dense SwiGLU,
+    or where the layer's params hold a ``router`` the dropless expert
+    layer of ``models/moe.py`` plus the shared expert. -> (its output in
+    h's dtype, (moe aux loss — 0 for dense layers and for the bias
+    routers, ``MoEStats`` over the entries ``count`` marks — None for
+    dense)). ``stack_layer``: the expert banks in ``lp`` are the whole
+    stack's and this is the layer's index in it (``moe._grouped``)."""
+    if "router" not in lp:
+        return (_swiglu(h, lp, "w_gate", "w_up", "w_down",
+                        c.mlp_multipliers),
+                (jnp.zeros((), jnp.float32), None))
+    b, s, d = h.shape
+    y, aux, stats = expert_ffn(c, lp, h.reshape(b * s, d), count,
+                               stack_layer)
+    y = y.reshape(b, s, d)
+    if "ws_gate" in lp:
+        with jax.named_scope("moe.shared"):
+            y = y + _swiglu(h, lp, "ws_gate", "ws_up",
+                            "ws_down").astype(jnp.float32)
+    return y.astype(h.dtype), (aux, stats)
+
+
 def _mlp(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
          count: Optional[jax.Array] = None,
          stack_layer: Optional[jax.Array] = None):
     """Post-attention FFN block, shared by the contiguous-cache and paged
-    layer bodies: a dense SwiGLU, or where the layer's params hold a
-    ``router`` the dropless expert layer of ``models/moe.py`` plus the
-    shared expert (a layer stack is all of one kind; a configuration with
+    layer bodies (a layer stack is all of one kind; a configuration with
     leading dense layers has two stacks). Returns (x + ffn(norm(x)) — or
-    what the multi-stream residual path makes of it, ``_residual`` — moe
-    aux loss — 0 for dense layers and for a ``sigmoid_bias`` router,
-    ``MoEStats`` over the entries ``count`` marks — None for dense,
-    ``_residual``'s Sinkhorn error — None for the plain path).
-    ``stack_layer``: the expert banks in ``lp`` are the whole stack's and
-    this is the layer's index in it (``moe._grouped``)."""
-    def ffn(x_in):
-        h = rms_norm(x_in, lp["mlp_norm"], c.rms_norm_eps)
-        if "router" not in lp:
-            return (_swiglu(h, lp, "w_gate", "w_up", "w_down",
-                            c.mlp_multipliers),
-                    (jnp.zeros((), jnp.float32), None))
-        b, s, d = h.shape
-        y, aux, stats = expert_ffn(c, lp, h.reshape(b * s, d), count,
-                                   stack_layer)
-        y = y.reshape(b, s, d)
-        if "ws_gate" in lp:
-            with jax.named_scope("moe.shared"):
-                y = y + _swiglu(h, lp, "ws_gate", "ws_up",
-                                "ws_down").astype(jnp.float32)
-        return y.astype(x_in.dtype), (aux, stats)
-
-    x, (aux, stats), err = _residual(c, lp, x, "mlp", ffn)
+    what the multi-stream residual path makes of it, ``_residual`` —
+    ``_ffn``'s aux loss and ``MoEStats``, ``_residual``'s Sinkhorn error —
+    None for the plain path)."""
+    x, (aux, stats), err = _residual(
+        c, lp, x, "mlp", lambda x_in: _ffn(
+            c, lp, rms_norm(x_in, lp["mlp_norm"], c.rms_norm_eps), count,
+            stack_layer))
     return x, aux, stats, err
+
+
+def _shortcut_block(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
+                    attend: Callable, carry,
+                    count: Optional[jax.Array] = None,
+                    stack_layer: Optional[jax.Array] = None):
+    """The shortcut-connected expert block (LongCat-Flash), for the
+    no-cache and the paged layer alike:
+
+        a0 = x  + Attn_0(Norm(x))          u0 = Norm(a0)
+        m  = MoE(u0)                       the shortcut: read at the end
+        b0 = a0 + FFN_0(u0)
+        a1 = b0 + Attn_1(Norm(b0))
+        x' = a1 + FFN_1(Norm(a1)) + m
+
+    every addition through ``_residual``. ``attend(lp_i, i, x_in, carry)``
+    is attention sublayer i with its norm -> (its output, carry'): the
+    carry is what the two sublayers hand on (the pool's leaves). Nothing
+    reads ``m`` before the last line, so the compiler may order the
+    experts' grouped products beside FFN_0, Attn_1 and FFN_1: across chips
+    that is where the exchange hides, and none is modelled here. ->
+    (x', carry', aux, ``MoEStats``)."""
+    lp0, lp1 = lp["sub0"], lp["sub1"]     # each a dense layer's leaves
+    x, carry, _ = _residual(c, lp0, x, "attn",
+                            lambda x_in: attend(lp0, 0, x_in, carry))
+
+    def ffn_and_branch(x_in):
+        u = rms_norm(x_in, lp0["mlp_norm"], c.rms_norm_eps)
+        b, s, d = u.shape
+        with jax.named_scope("moe.shortcut"):
+            m, aux, stats = expert_ffn(c, lp, u.reshape(b * s, d), count,
+                                       stack_layer)
+        return _ffn(c, lp0, u)[0], (m.reshape(b, s, d), aux, stats)
+
+    with jax.named_scope("mlp"):
+        x, (m, aux, stats), _ = _residual(c, lp0, x, "mlp", ffn_and_branch)
+    x, carry, _ = _residual(c, lp1, x, "attn",
+                            lambda x_in: attend(lp1, 1, x_in, carry))
+    with jax.named_scope("mlp"):
+        x, _, _ = _residual(
+            c, lp1, x, "mlp", lambda x_in: (
+                (_ffn(c, lp1, rms_norm(x_in, lp1["mlp_norm"], c.rms_norm_eps)
+                      )[0].astype(jnp.float32) + m).astype(x_in.dtype), None))
+    return x, carry, aux, stats
 
 
 def _rope_tables(c: ModelConfig, positions: jax.Array):
@@ -1017,6 +1110,10 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
         raise RecurrentStateUnsupported(
             "forward(cache=...) over the slot KVCache" if cache is not None
             else "forward(mesh=...)", c.name)
+    if (c.expert_share or c.shortcut_moe) and mesh is not None:
+        # the share over an 'ep' axis needs its exchange; parallel/expert.py
+        # drops pairs for capacity and knows no identity expert
+        raise ExpertShareUnsupported("forward(mesh=...)", c.name)
     # gather; sharded vocab → XLA collective
     x = _stream_open(c, _times(params["embed"][tokens],
                                c.embedding_multiplier))
@@ -1264,6 +1361,19 @@ def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     ``ssm.out_proj``).
     """
     attend = _paged_mla_attend if c.mla else _paged_attend
+    if c.shortcut_moe:
+        def sublayer(lp_i, i, x_in, leaves):
+            # sublayer i of block ``layer`` is pool layer 2 layer + i
+            with jax.named_scope(f"attn.sub{i}"):
+                return attend(c, lp_i, x_in, cos, sin, leaves,
+                              2 * layer + i, tables, seq_row, positions,
+                              write_block, write_off, use_kernel=use_kernel,
+                              adapters=adapters, adapter_ids=adapter_ids,
+                              row_plan=row_plan)
+        x, leaves, _, stats = _shortcut_block(
+            c, lp, x, sublayer, leaves,
+            _writes(lp, write_block, leaves[0]), stack_layer)
+        return x, leaves, stats, None
     if c.ssm:
         def both(x_in):
             out, (kv, rows) = _mixers(
@@ -1637,8 +1747,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         counts = banks = None
         worst = jnp.zeros((), jnp.float32) if c.hc_mult else None
         if "router" in layers:
-            counts = MoEStats(jnp.zeros((), jnp.int32),
-                              jnp.zeros((), jnp.int32))
+            counts = MoEStats.zeros(c)
             # The expert banks stay whole outside the xs: each layer's
             # grouped products address its experts inside them
             # (moe._grouped) instead of taking a copy of its slice.
@@ -1659,9 +1768,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
                 state_layer=(layer + (state_first - first) if c.ssm
                              else None))
             if stats is not None:
-                acc = MoEStats(
-                    acc.experts_touched + stats.experts_touched,
-                    jnp.maximum(acc.expert_load_max, stats.expert_load_max))
+                acc = acc.merge(stats)
             return (x, leaves, acc, _worst(worst, err)), None
 
         index = jnp.arange(first, first + n, dtype=jnp.int32)
@@ -1704,9 +1811,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
     err = _worst(err, last_err)
     if n_hi and moe is not None:
         # the full-width prefix layers are expert layers of the same model
-        moe = MoEStats(moe.experts_touched + hi_moe.experts_touched,
-                       jnp.maximum(moe.expert_load_max,
-                                   hi_moe.expert_load_max))
+        moe = moe.merge(hi_moe)
     upd.update(zip(names, leaves))
     if c.ssm:
         upd["rows"] = type(pool.rows)(*leaves[-2:])

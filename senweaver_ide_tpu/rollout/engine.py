@@ -310,13 +310,16 @@ def _paged_fused_step(params: Params, config: ModelConfig,
         # (``_note_mhc_step`` reads it).
         logp = jnp.concatenate([logp, stats.pop()[None].astype(logp.dtype)])
     if stats:
-        # An expert model's step also says what its routing did: the two
-        # counts of ``MoEStats`` ride BEHIND the step's tokens in the same
-        # array, so the one fetch brings them and nothing is dispatched or
-        # waited for on their account (``_note_moe_step`` reads them).
-        # Every consumer of the tokens indexes entries below ``T``.
+        # An expert model's step also says what its routing did: the
+        # counts of ``MoEStats`` (two, or four where the layer holds a
+        # share of the router's experts) ride BEHIND the step's tokens in
+        # the same array, so the one fetch brings them and nothing is
+        # dispatched or waited for on their account (``_note_moe_step``
+        # reads them). Every consumer of the tokens indexes entries below
+        # ``T``.
         next_tok = jnp.concatenate(
-            [next_tok, jnp.stack(stats[0]).astype(next_tok.dtype)])
+            [next_tok, jnp.stack([n for n in stats[0] if n is not None]
+                                 ).astype(next_tok.dtype)])
     return next_tok, logp, pool, key, cur
 
 
@@ -820,7 +823,7 @@ class RolloutEngine:
         # of layout): a silently-ignored kv_dtype on a slots fallback
         # would serve at double the memory the operator budgeted for.
         self._kv_payload_dtype, self._kv_hi_layers = resolve_kv_dtypes(
-            config.num_layers, self.engine_config.kv_dtype,
+            config.attn_layers, self.engine_config.kv_dtype,
             self.engine_config.kv_dtype_per_layer)
         if (self._kv_payload_dtype is not None
                 and self.kv_layout != "paged"):
@@ -973,6 +976,18 @@ class RolloutEngine:
                         "senweaver_moe_expert_load_max",
                         "Largest number of tokens on one expert in any "
                         "layer of the last fused step."))
+                if config.expert_share:
+                    self._moe_counters += (
+                        reg.counter(
+                            "senweaver_moe_zero_picks_total",
+                            "Picks that fell on identity experts (no "
+                            "bank, no matmul), summed over the expert "
+                            "layers and the fused steps."),
+                        reg.counter(
+                            "senweaver_moe_local_pairs_total",
+                            "Picks that fell on an expert this chip "
+                            "holds: the pairs its grouped products "
+                            "computed."))
             # A multi-stream model's fused step reports its residual
             # maps the same way, behind the step's log-probs.
             self._mhc_gauge = None
@@ -3768,22 +3783,34 @@ class RolloutEngine:
     def _note_moe_step(self, st, toks, used: int) -> None:
         # guarded-by: caller
         """An expert model's step: publish what its routing did. The
-        device's two counts arrive behind the step's tokens in the fetched
-        array (``_paged_fused_step``); the pairs offered are the host's
-        own count, entries in use (``used``) x experts per token."""
-        touched, load_max = int(toks[-2]), int(toks[-1])
+        device's counts arrive behind the step's tokens in the fetched
+        array (``_paged_fused_step``: ``MoEStats``' two, or its four where
+        the layer holds a share of the router's experts); the pairs
+        offered are the host's own count, entries in use (``used``) x
+        experts per token, and the banks those HELD."""
+        touched, load_max, *share = (
+            int(n) for n in toks[-4 if self.config.expert_share else -2:])
         assignments = used * self.config.num_experts_per_tok
         n_banks = self.config.num_expert_layers * self.config.num_experts
-        pairs, banks, banks_total, peak = self._moe_counters
+        pairs, banks, banks_total, peak, *share_counters = self._moe_counters
         pairs.inc(assignments)
         banks.inc(touched)
         banks_total.inc(n_banks)
         peak.set(load_max)
+        for counter, n in zip(share_counters, share):
+            counter.inc(n)
         if st is not None:
             st.set_attr("expert_assignments", assignments)
             st.set_attr("experts_touched", touched)
             st.set_attr("expert_banks", n_banks)
             st.set_attr("expert_load_max", load_max)
+            if share:
+                # every pick of the step, all expert layers: what the two
+                # device counts are out of
+                st.set_attr("expert_picks", assignments
+                            * self.config.num_expert_layers)
+                st.set_attr("zero_picks", share[0])
+                st.set_attr("local_pairs", share[1])
 
     def _note_mhc_step(self, st, logps) -> None:
         # guarded-by: caller

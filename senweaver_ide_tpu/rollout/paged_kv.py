@@ -290,7 +290,8 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
             block_size, kv_dtype, kv_dtype_per_layer)._replace(
                 rows=init_state_rows(config, state_rows))
     hkv, dh = config.num_kv_heads, config.head_dim
-    num_layers = config.num_layers
+    # attention layers: two a layer in a shortcut block
+    num_layers = config.attn_layers
     payload, n_hi = resolve_kv_dtypes(num_layers, kv_dtype,
                                       kv_dtype_per_layer)
     if config.mla:
@@ -343,7 +344,7 @@ def kv_row_bytes(config: ModelConfig, kv_dtype: str = "bf16",
     """Bytes one token takes in one payload leaf of one layer, AS STORED:
     ``Hkv x head_dim`` of the pool's dtype (a quantized rung stores one
     byte a value), or the latent pool's one padded row."""
-    payload, _ = resolve_kv_dtypes(config.num_layers, kv_dtype,
+    payload, _ = resolve_kv_dtypes(config.attn_layers, kv_dtype,
                                    kv_dtype_per_layer)
     if config.mla:
         return config.latent_row_dim * jnp.dtype(config.dtype).itemsize

@@ -310,7 +310,10 @@ def _benchmark_config(name):
     ("xing4.0-29b-a4b", 4, 48, None), ("xing4.0-29b-a4b", 4, 192, None),
     # an explicit block of 16, the cells' size until PR 33: tables 64 and
     # 256 wide
-    ("qwen2.5-coder-1.5b", None, 48, 16), ("glm-4.7-flash", 3, 48, 16)])
+    ("qwen2.5-coder-1.5b", None, 48, 16), ("glm-4.7-flash", 3, 48, 16),
+    # 64 heads over the same latent rows, two attention sublayers a layer
+    # (four pool layers for two layers), 16 of 512 experts held
+    ("longcat-flash-chat", 2, 48, None), ("longcat-flash-chat", 2, 192, None)])
 def test_kernel_step_compiled_for_v5e_copies_no_pool(
         one_v5e, model, layers, entries, block, monkeypatch):
     """``_paged_fused_step`` at a preset's widths, 48 rows of 1024 tokens
@@ -320,10 +323,12 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(
     heads, 16 and 64 at 32) unless one is given, with ``paged_attention_rows`` (a latent
     pool: ``paged_latent_attention_rows``) compiled by Mosaic (the test
     says "on a TPU": the backend here is the CPU), the table whole in
-    SMEM. The qwen, glm and xing cases are the benchmark cells' shapes."""
+    SMEM. The qwen, glm, xing and longcat cases are the benchmark cells'
+    shapes."""
     from senweaver_ide_tpu.ops import paged_attention
     monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
-    latent = model in ("glm-4.7-flash", "xing4.0-29b-a4b")
+    latent = model in ("glm-4.7-flash", "xing4.0-29b-a4b",
+                       "longcat-flash-chat")
     c = _benchmark_config(model) if latent else get_config(model)
     if layers:
         c = dataclasses.replace(c, num_layers=layers)
@@ -336,8 +341,8 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(
     bs = block or resolve_block_size(kv_row_bytes(c), max_len)
     if block is None:
         assert bs == {"qwen2.5-coder-1.5b": 128, "qwen3-8b": 32,
-                      "glm-4.7-flash": 64, "xing4.0-29b-a4b": 64}.get(
-                          model, 16)
+                      "glm-4.7-flash": 64, "xing4.0-29b-a4b": 64,
+                      "longcat-flash-chat": 64}.get(model, 16)
     rows, width = 48, max_len // bs
     pool = on_chip(jax.eval_shape(lambda: init_paged_pool(
         c, 52 * width if layers is None or latent else 13 * width, bs)))

@@ -253,6 +253,32 @@ def _state_group_rollout(config, params):
     return run, FUSED + ("paged_kv.copy_state",), 3
 
 
+def _share_group_rollout(config, params):
+    """Two groups of four on four rows of a model that holds a share of
+    its router's experts on a shortcut beside a double block: the second
+    group waits in the queue, so the engine runs a step ahead, and every
+    step carries four routing counts behind its tokens, at both widths."""
+    from senweaver_ide_tpu.models.config import tiny_longcat_flash_test
+    share = tiny_longcat_flash_test()
+    weights = jax.block_until_ready(
+        init_params(share, jax.random.PRNGKey(0)))
+    prompt = [(j * 11) % 200 + 2 for j in range(24)]
+
+    def run():
+        eng = RolloutEngine(weights, share, num_slots=4, max_len=128,
+                            sample=GREEDY,
+                            engine_config=_paged(block_size=4))
+        for shift in (0, 1):
+            eng.submit_group(prompt[shift:], 4, max_new_tokens=16)
+        out = eng.run()
+        assert [len(t) for t in out.values()] == [16] * 8
+        st = eng.stats()
+        assert (st["prefills"], st["group_forks"]) == (2, 6)
+        eng._alloc.check_leaks()
+        return out
+    return run, FUSED, 3
+
+
 def _train_step(config, params):
     """One GRPO update via training.trainer.train_step."""
     from senweaver_ide_tpu.training.trainer import train_step
@@ -386,6 +412,7 @@ CASES = {
     "multi_lora": _multi_lora,
     "group_rollout": _group_rollout,
     "state_group_rollout": _state_group_rollout,
+    "share_group_rollout": _share_group_rollout,
     "train_step": _train_step,
     "streaming_grpo": _streaming_grpo,
     "reward_head": _reward_head,
