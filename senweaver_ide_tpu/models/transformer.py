@@ -1594,6 +1594,7 @@ def forward_paged(
     adapter_ids=None,             # per-rung (T,) int32 slot ids
     with_moe_stats: bool = False,  # static: also return MoEStats
     with_mhc_stats: bool = False,  # static: also return the Sinkhorn error
+    with_attn_stats: bool = False,  # static: also return the shared reads
 ):
     """Run the model over a paged KV pool: every entry of the flat
     ``(T,)`` token batch is one (sequence, position) pair — a decode
@@ -1642,7 +1643,14 @@ def forward_paged(
     ``with_mhc_stats=True`` (a multi-stream configuration, ``hc_mult``)
     returns one more value after it: the largest ``|row sum - 1|`` or
     ``|column sum - 1|`` of any H_res the call computed (``_residual``),
-    a float32 scalar."""
+    a float32 scalar.
+
+    ``with_attn_stats=True`` returns one more value, last: int32 ``(2,)``,
+    what the attention kernels' plan found among the decode rows' tables
+    (``ops.paged_attention.plan_rows``): the block reads the step did
+    not make because rows that hold the same physical blocks attended
+    them together (``kv_blocks_saved``), and the group items that did
+    (``attn_group_items``); zeros where the gather runs."""
     c = config
     if c.matmul_precision is not None:
         with jax.default_matmul_precision(c.matmul_precision):
@@ -1658,9 +1666,12 @@ def forward_paged(
             seq_row=seq_row, positions=positions, write_block=write_block,
             write_off=write_off, use_kernel=use_kernel, adapters=adapters,
             adapter_ids=adapter_ids)
-    logits, pool, moe, err = out
+    logits, pool, moe, err, shared = out
+    if shared is None and with_attn_stats:
+        shared = jnp.zeros((2,), jnp.int32)
     return ((logits, pool) + ((moe,) if with_moe_stats else ())
-            + ((err,) if with_mhc_stats else ()))
+            + ((err,) if with_mhc_stats else ())
+            + ((shared,) if with_attn_stats else ()))
 
 
 def reads_pool_in_place(c: ModelConfig, use_kernel: Optional[bool]) -> bool:
@@ -1701,17 +1712,22 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
     # STATIC under jit: derived from pytree structure (None-ness and
     # shapes), so the precision ladder never adds a trace argument.
     n_hi = 0 if pool.k_hi is None else pool.k_hi.shape[0]
-    row_plan = None
+    row_plan = shared = None
     if (pool.k_scale is None or n_hi) and reads_pool_in_place(c,
                                                               use_kernel):
         # once a step, outside the layer scans: the flat batch cut into
         # the runs of one row that the kernel attends together
-        from ..ops.paged_attention import plan_rows, query_tile
+        # and, by the tables, the rows that share their leading blocks
+        from ..ops.paged_attention import group_tile, plan_rows, query_tile
         with jax.named_scope("attn.row_plan"):
             row_plan = plan_rows(seq_row, positions,
                                  block_size=pool.k.shape[2],
                                  table_width=tables.shape[1],
-                                 q_tile=query_tile(c.num_heads))
+                                 q_tile=query_tile(c.num_heads),
+                                 tables=tables,
+                                 group_tile=group_tile(c.num_heads))
+            shared = jnp.stack([row_plan.kv_blocks_saved,
+                                row_plan.group_items])
     run_plan = None
     rows = ()
     if c.ssm:
@@ -1829,7 +1845,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
             logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
         logits = _times(logits[:, 0].astype(jnp.float32),
                         c.lm_head_multiplier)
-    return logits, pool._replace(**upd), moe, err
+    return logits, pool._replace(**upd), moe, err, shared
 
 
 def count_params(params: Params) -> int:

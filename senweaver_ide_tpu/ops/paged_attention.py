@@ -28,6 +28,27 @@ How it is laid out:
   to ``q_tile`` queries. A row that appears in two runs is two segments:
   slower, never wrong. Tail padding (row 0, position 0) is one segment of
   one block.
+- *The group item.* Decode rows whose tables begin with the same
+  physical blocks (a rollout group's forked prompt, a grafted prefix, a
+  ``fork_request`` tree) would each stream and multiply those blocks:
+  eight times a step for a group of eight. ``plan_rows``, given the
+  tables, finds such rows from the ids alone (no flag from the engine;
+  the rows need be neither adjacent nor in order) and plans, for up to
+  ``group_tile`` of them, ONE group item: their queries, folded into
+  the rows of one product as a tile of queries is, over the blocks they
+  share, read through one member's table. It leaves each member's
+  ``m``, ``l``, ``acc`` in scratch and stores nothing; each member's
+  *tail item* follows, takes up its member's state, goes on over the
+  row's own blocks from the first unshared one and stores: the same
+  online softmax over the same positions, reduced in another order. A
+  row's last live block is never shared (it is being written), so every
+  tail has a block. A chunk's K and V are the MXU's weights, loaded a
+  compute step whatever rows they serve: on a v5e an item of 8 queries
+  costs r x ONE single-query item over the same blocks, r = 1.12 at
+  12/2 heads (128 head rows), 1.63 at 20/4 (256), 1.88 at a latent
+  model's 20 heads (256) and 2.76 at 64 (512) (my chip run, PR 38, the
+  kernel before the change; 1.35 / 1.82 / 2.58 / 4.08 less a call's
+  floor), where the rows one by one cost 8 x.
 - The kernel is ONE program (grid of 1) that loops over every (item,
   chunk of its blocks) in order. While chunk g is computed, chunk g+1
   (the same item's next, or the next item's first) is in flight into
@@ -122,6 +143,13 @@ def query_tile(num_q_heads: int) -> int:
     return max(1, TILE_ROWS // _head_rows(num_q_heads))
 
 
+def group_tile(num_q_heads: int) -> int:
+    """Members of a share group that one group item attends together,
+    for ``plan_rows``: 8, a rollout group's size, or as many as fill the
+    score tile's rows where 8 do not fit (1: no group item)."""
+    return min(8, query_tile(num_q_heads))
+
+
 def blocks_per_chunk(block_size: int, num_kv_heads: int) -> int:
     """Pool blocks a compute step streams: as many as fill the score
     tile's columns."""
@@ -135,37 +163,79 @@ def on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+# a plan item's ``slot``: an item that starts its own softmax and stores
+# it, a group item (starts one a member, stores none); 0 and up is a tail
+# item, which takes up the group item's state of that member
+_FRESH, _GROUP = -1, -2
+
+
 @functools.partial(jax.tree_util.register_dataclass,
-                   data_fields=["row", "q0", "count", "blocks", "num_items"],
-                   meta_fields=["q_tile"])
+                   data_fields=["row", "q0", "count", "first", "blocks",
+                                "slot", "num_items", "kv_blocks_saved",
+                                "group_items"],
+                   meta_fields=["q_tile", "group_tile"])
 @dataclasses.dataclass(frozen=True)
 class RowPlan:
-    """The flat batch cut into the kernel's items, all ``(T,)`` int32
-    but the count: item i attends queries ``[q0, q0 + count)`` of the
-    flat batch, all of table row ``row``, over its first ``blocks``
-    blocks. Items lie in the order of the batch; the entries past
-    ``num_items`` hold no work. ``q_tile`` (static) is the most queries
-    an item holds: the kernel sizes its tiles by it."""
+    """The flat batch cut into the kernel's items, int32 vectors one
+    entry an item: item i attends queries ``[q0, q0 + count)`` of the
+    flat batch, all of table row ``row``, over its logical blocks
+    ``[first, blocks)``. Ungrouped items lie in the order of the batch
+    with ``first`` 0 and ``slot`` -1; the entries past ``num_items`` hold
+    no work. A *group item* (``slot`` -2) attends its ``count`` members'
+    queries over the ``blocks`` blocks they share, read through ``row``'s
+    table, and stores nothing; the ``count`` items after it are its
+    members' *tail items*, each one query (``q0``) over its own row from
+    block ``first`` (the group's ``blocks``) on, which takes up the group
+    item's softmax of member ``slot`` and stores the whole. A group item
+    and its tails lie where the last of its members lies in the batch.
+    ``kv_blocks_saved`` and ``group_items`` (scalars) count, over the
+    group items, blocks x (members - 1) and 1. ``q_tile`` and
+    ``group_tile`` (static) are the most queries an item and the most
+    members a group item hold: the kernel sizes its tiles by them."""
     row: jax.Array
     q0: jax.Array
     count: jax.Array
+    first: jax.Array
     blocks: jax.Array
+    slot: jax.Array
     num_items: jax.Array      # (1,)
+    kv_blocks_saved: jax.Array
+    group_items: jax.Array
     q_tile: int
+    group_tile: int = 0
 
 
 def plan_rows(seq_row: jax.Array, positions: jax.Array, *,
-              block_size: int, table_width: int, q_tile: int) -> RowPlan:
+              block_size: int, table_width: int, q_tile: int,
+              tables: Optional[jax.Array] = None,
+              group_tile: int = 0) -> RowPlan:
     """Find the segments of a flat batch on the device and cut them into
     items of up to ``q_tile`` queries (``query_tile`` of the model's
     heads; see the module docstring). A boundary is where
     ``seq_row[t] != seq_row[t-1]``; an item covers the blocks up to the
     largest position among ITS queries, so a chunk's early tiles read
-    less than its last. A handful of small ops (the fused step lowers
-    them for every shape it compiles): two scans and one segment
-    reduction."""
+    less than its last.
+
+    With the batch's ``tables`` and a ``group_tile`` of 2 or more
+    (``group_tile`` of the model's heads) the single-query segments whose
+    table rows begin with the same physical blocks are found too, and
+    attended as group items and tail items (``RowPlan``). Rows a and b
+    have ``common(a, b)`` leading blocks in common: the run of equal ids,
+    never past the block before either's last live one (a row's last
+    block is its own: it is being written). A row's *key* is the deepest
+    block it has in common with any other row; the rows of one key are a
+    share group, in the batch's order cut into group items of up to
+    ``group_tile``, and the group's ``shared`` is the least ``common``
+    among them — the same from whichever member it is taken, since a
+    common prefix is an ultrametric — so every member's first ``shared``
+    blocks ARE the first member's, whatever the tables hold. A batch in
+    which no two such rows share a block plans the items it did without
+    ``tables``. A dozen small ops over (rows x rows) and one over (rows
+    x rows x table width), once a step (the fused step lowers them for
+    every shape it compiles)."""
     t = seq_row.shape[0]
     seq_row = seq_row.astype(jnp.int32)
+    positions = positions.astype(jnp.int32)
     idx = jnp.arange(t, dtype=jnp.int32)
     start = jnp.concatenate(
         [jnp.ones((1,), bool), seq_row[1:] != seq_row[:-1]])
@@ -174,43 +244,116 @@ def plan_rows(seq_row: jax.Array, positions: jax.Array, *,
     seg_q0 = jax.lax.cummax(jnp.where(start, idx, 0))
     opens = (idx - seg_q0) % q_tile == 0
     item = jnp.cumsum(opens.astype(jnp.int32)) - 1        # entry -> item
-    # per item: its last position, its row, its last and (negated) first
-    # entry; an item past the last gets the reduction's identity (< 0)
-    last, row, hi, lo = jax.ops.segment_max(
-        jnp.stack([positions.astype(jnp.int32), seq_row, idx, -idx], 1),
-        item, num_segments=t, indices_are_sorted=True).T
-    live = hi >= 0
-    return RowPlan(
-        row=jnp.where(live, row, 0),
-        q0=jnp.where(live, -lo, 0),
-        count=jnp.where(live, hi + lo + 1, 0),
-        blocks=jnp.where(live, jnp.clip(
-            (last + block_size) // block_size, 1, table_width), 0),
-        num_items=item[-1:] + 1, q_tile=q_tile)
+    # per item its last position and last entry, read back at the entry
+    # that opens it
+    last, hi = jax.ops.segment_max(
+        jnp.stack([positions, idx], 1), item, num_segments=t,
+        indices_are_sorted=True)[item].T
+    live = lambda pos: jnp.clip((pos + block_size) // block_size, 1,
+                                table_width)
+    # every entry's own item (the one it opens, if it opens one), how many
+    # items it gives and where its own goes
+    count, first, blocks = hi - idx + 1, jnp.zeros_like(idx), live(last)
+    slot = jnp.full_like(idx, _FRESH)
+    gives = opens.astype(jnp.int32)
+    grouping = tables is not None and group_tile >= 2
+    if not grouping:
+        n = t
+        dest = jnp.where(opens, item, n)
+        saved = group_items = jnp.zeros((), jnp.int32)
+    else:
+        # a group item for every two entries at most, and ``group_tile``
+        # entries of slack: a group item reads its members' entries out
+        # of the items after it
+        n = t + t // 2 + group_tile
+        r = tables.shape[0]
+        rows = jnp.arange(r, dtype=jnp.int32)
+        # a row's single-query segment (its last, had it two), if any
+        single = start & jnp.concatenate([start[1:], jnp.ones((1,), bool)])
+        ent = jnp.full((r,), -1, jnp.int32).at[
+            jnp.where(single, seq_row, r)].max(idx, mode="drop")
+        row_live = live(positions[jnp.maximum(ent, 0)])
+        may_share = jnp.where(ent >= 0, row_live - 1, 0)
+        tables = tables.astype(jnp.int32)
+        run = jnp.min(jnp.where(
+            tables[:, None, :] == tables[None, :, :], table_width,
+            jnp.arange(table_width, dtype=jnp.int32)), axis=-1)
+        me = rows[:, None] == rows[None, :]
+        common = jnp.where(me, 0, jnp.minimum(
+            run, jnp.minimum(may_share[:, None], may_share[None, :])))
+        depth = jnp.max(common, axis=1)
+        key = jnp.where(depth > 0,
+                        tables[rows, jnp.maximum(depth - 1, 0)], -1 - rows)
+        same = key[:, None] == key[None, :]               # a row with itself
+        shared = jnp.min(jnp.where(same & ~me, common, table_width), axis=1)
+        rank = jnp.sum(same & (ent[None, :] < ent[:, None]), axis=1)
+        tile = rank // group_tile
+        mates = same & (tile[:, None] == tile[None, :])
+        members = jnp.sum(mates, axis=1)
+        last_ent = jnp.max(jnp.where(mates, ent[None, :], -1), axis=1)
+        grouped = (ent >= 0) & (shared > 0) & (members > 1)
+        # back to the entries: a grouped row's entry gives no item but at
+        # the last of its mates, which gives the group item and the tails
+        of_row = lambda x: x[seq_row]
+        mine = single & (of_row(ent) == idx) & of_row(grouped)
+        member = of_row(rank % group_tile)
+        leads = mine & (member == 0)
+        at = of_row(jnp.maximum(last_ent, 0))
+        gives = jnp.where(mine, jnp.where(at == idx, of_row(members) + 1, 0),
+                          gives)
+        off = jnp.cumsum(gives) - gives
+        dest = jnp.where(mine, off[at] + 1 + member,
+                         jnp.where(opens, off, n))
+        # a member's own item is its tail
+        count = jnp.where(mine, 1, count)
+        first = jnp.where(mine, of_row(shared), first)
+        blocks = jnp.where(mine, of_row(row_live), blocks)
+        slot = jnp.where(mine, member, slot)
+        group_items = jnp.sum(leads.astype(jnp.int32))
+        saved = jnp.sum(jnp.where(
+            leads, of_row(shared) * (of_row(members) - 1), 0))
+    items = jnp.zeros((n, 6), jnp.int32).at[dest].set(
+        jnp.stack([seq_row, idx, count, first, blocks, slot], 1),
+        mode="drop")
+    if grouping:
+        items = items.at[jnp.where(leads, off[at], n)].set(
+            jnp.stack([seq_row, idx, of_row(members), jnp.zeros_like(idx),
+                       of_row(shared), jnp.full_like(idx, _GROUP)], 1),
+            mode="drop")
+    row, q0, count, first, blocks, slot = items.T
+    return RowPlan(row=row, q0=q0, count=count, first=first, blocks=blocks,
+                   slot=slot, num_items=jnp.sum(gives)[None],
+                   kv_blocks_saved=saved, group_items=group_items,
+                   q_tile=q_tile, group_tile=group_tile if grouping else 0)
 
 
 def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
-                 blocks_ref, num_ref, pos_ref,           # scalars (SMEM)
+                 first_ref, blocks_ref, slot_ref, num_ref,
+                 pos_ref,                                # scalars (SMEM)
                  q_ref, *refs,
                  scale: float, block_size: int, hkv: int, rep: int,
-                 hq_pad: int, q_tile: int, chunk: int, exact: bool,
-                 leaves: int):
+                 hq_pad: int, q_tile: int, group_tile: int, chunk: int,
+                 exact: bool, leaves: int):
     """The whole flat batch in one program; see the module docstring.
 
     ``refs`` are the pool's ``leaves`` payload leaves in HBM (k and v, or
     the one latent leaf whose rows hold both), the output, a buffer a
-    leaf, then ``sems, acc_ref, m_ref, l_ref, qpos_ref``. A buffer is
-    ``(2, chunk) + a block's shape``: two
+    leaf, then ``sems, acc_ref, m_ref, l_ref, qpos_ref, qgrp_ref``. A
+    buffer is ``(2, chunk) + a block's shape``: two
     slots of one chunk each. One loop runs over every (item, chunk) in
     order; step g computes out of slot ``g % 2`` what step g-1 started
     into it, after starting its own successor into the other. Every
     started DMA is waited for by the step that computes it, which
-    rebuilds the same descriptors from the same scalars. The DMA code is
-    traced three times and the compute twice (one query, a tile of
-    them) whatever the sizes: the step's lowering time is part of
-    ``setup_s``."""
-    hbm, out_ref, bufs = refs[:leaves], refs[leaves], refs[leaves + 1:-5]
-    sems, acc_ref, m_ref, l_ref, qpos_ref = refs[-5:]
+    rebuilds the same descriptors from the same scalars. A group item
+    leaves its members' ``m``, ``l``, ``acc`` in the scratch rows, member
+    s at ``[s * hq_pad, (s + 1) * hq_pad)``, and stores nothing; the tail
+    items, which follow it, each move their member's rows to the front in
+    place of starting fresh, go on over their own blocks and store. The
+    DMA code is traced three times and the compute three (one query, a
+    group's tile, a tile of queries) whatever the sizes: the step's
+    lowering time is part of ``setup_s``."""
+    hbm, out_ref, bufs = refs[:leaves], refs[leaves], refs[leaves + 1:-6]
+    sems, acc_ref, m_ref, l_ref, qpos_ref, qgrp_ref = refs[-6:]
     n_cols = chunk * block_size * hkv
     layer = layer_ref[0]
     num_items = num_ref[0]
@@ -221,7 +364,7 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
         of ``item`` that the item covers, into ``slot``: a loop, so the
         program's size does not grow with the chunk."""
         row = row_ref[item]
-        first = c * chunk
+        first = first_ref[item] + c * chunk
 
         def body(b, _):
             phys = tables_ref[row, first + b]
@@ -250,22 +393,46 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
     def _first():
         for_blocks(0, 0, 0, start)
 
-    def attend(item, c, slot, last, queries: int):
+    def attend(item, c, slot, last, queries: int, group: bool = False):
         """Chunk ``c`` of an item of ``queries`` (static) query slots,
-        ``hq_pad`` head rows each. Rows past the item's count, and the
+        ``hq_pad`` head rows each; ``group``: a group item, whose queries
+        are its tail items'. Rows past the item's count, and the
         padded heads, compute and are not kept: what the last chunk
         stores of them is overwritten by the items that own those
-        entries, which come later."""
+        entries, which come later (a group item and its tails lie where
+        its last member does, and own single entries at or before it)."""
         rows = queries * hq_pad
         q0 = q0_ref[item]
 
         @pl.when(c == 0)
         def _init():
-            m_ref[:rows] = jnp.full((rows, 1), NEG_INF, jnp.float32)
-            l_ref[:rows] = jnp.zeros((rows, 1), jnp.float32)
-            acc_ref[:rows] = jnp.zeros((rows, acc_ref.shape[-1]),
-                                       jnp.float32)
-            if queries > 1:
+            def fresh():
+                m_ref[:rows] = jnp.full((rows, 1), NEG_INF, jnp.float32)
+                l_ref[:rows] = jnp.zeros((rows, 1), jnp.float32)
+                acc_ref[:rows] = jnp.zeros((rows, acc_ref.shape[-1]),
+                                           jnp.float32)
+
+            if queries > 1 or group_tile < 2:
+                fresh()
+            else:
+                member = slot_ref[item]
+                pl.when(member < 0)(fresh)
+
+                @pl.when(member >= 0)
+                def _take_up():
+                    # a tail item: its member's rows of the group item
+                    its = pl.ds(pl.multiple_of(member * hq_pad, hq_pad),
+                                hq_pad)
+                    m_ref[:rows] = m_ref[its]
+                    l_ref[:rows] = l_ref[its]
+                    acc_ref[:rows] = acc_ref[its]
+
+            if group:
+                # the members' queries: the tail items' entries
+                for s in range(queries):
+                    qgrp_ref[s * hq_pad:(s + 1) * hq_pad] = q_ref[
+                        q0_ref[item + 1 + s]]
+            elif queries > 1:
                 # each row's own position, kept for the item's chunks
                 which = jax.lax.broadcasted_iota(
                     jnp.int32, (rows, 1), 0) // hq_pad
@@ -275,7 +442,12 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
                                              acc),
                     jnp.zeros((rows, 1), jnp.int32))
 
-        if queries == 1:
+        if group:
+            # every member is past the shared blocks: the one limit is
+            # theirs, the last position they hold
+            q = qgrp_ref[...]
+            q_pos = blocks_ref[item] * block_size - 1
+        elif queries == 1:
             q = q_ref[q0]                                  # (hq_pad, D)
             q_pos = pos_ref[q0]
         else:
@@ -286,7 +458,8 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1)
         head = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % hq_pad
         own = (col % hkv) == (head // rep)          # (rows, n_cols)
-        seen = col // hkv + c * (chunk * block_size) <= q_pos
+        seen = (col // hkv + (first_ref[item] + c * chunk) * block_size
+                <= q_pos)
         k, *v = (buf.at[slot].reshape(n_cols, buf.shape[-1])[...]
                  for buf in bufs)
         # a latent row's leading columns are its value: the window of the
@@ -309,6 +482,8 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
         m_ref[:rows] = m_new
         l_ref[:rows] = l
         acc_ref[:rows] = acc
+        if group:
+            return
 
         @pl.when(last)
         def _store():
@@ -322,7 +497,7 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
     def step(carry):
         g, item, c = carry
         slot = g % 2
-        last = (c + 1) * chunk >= blocks_ref[item]
+        last = first_ref[item] + (c + 1) * chunk >= blocks_ref[item]
         nxt_item = jnp.where(last, item + 1, item)
         nxt_c = jnp.where(last, 0, c + 1)
 
@@ -331,13 +506,19 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
             for_blocks(nxt_item, nxt_c, 1 - slot, start)
 
         for_blocks(item, c, slot, wait)
-        if q_tile == 1:
+        if q_tile == 1 and group_tile < 2:
             attend(item, c, slot, last, 1)
         else:
             single = count_ref[item] == 1
             pl.when(single)(lambda: attend(item, c, slot, last, 1))
-            pl.when(jnp.logical_not(single))(
-                lambda: attend(item, c, slot, last, q_tile))
+            many = jnp.logical_not(single)
+            if group_tile > 1:
+                grouped = slot_ref[item] == _GROUP
+                pl.when(grouped)(lambda: attend(item, c, slot, last,
+                                                group_tile, group=True))
+                many = jnp.logical_and(many, jnp.logical_not(grouped))
+            if q_tile > 1:
+                pl.when(many)(lambda: attend(item, c, slot, last, q_tile))
         return g + 1, nxt_item, nxt_c
 
     jax.lax.while_loop(lambda carry: carry[1] < num_items, step,
@@ -399,7 +580,7 @@ def _attend_rows(q, leaves, layer, tables, positions, plan, *, scale,
     rep = hq // hkv
     exact = q.dtype == jnp.float32
     hq_pad = _head_rows(hq)
-    q_tile = plan.q_tile
+    q_tile, group = plan.q_tile, plan.group_tile
     chunk = min(blocks_per_chunk(bs, hkv), tables.shape[1])
     if interpret is None:
         interpret = not on_tpu()
@@ -407,14 +588,14 @@ def _attend_rows(q, leaves, layer, tables, positions, plan, *, scale,
     # writes past T
     qp = jnp.pad(q, ((0, q_tile), (0, hq_pad - hq), (0, 0)))
     pos = jnp.pad(positions.astype(jnp.int32), (0, q_tile))
-    rows = q_tile * hq_pad
+    rows = max(q_tile, group) * hq_pad
     # the accumulator's columns: the value's, in whole 128-lane tiles so
     # the window of a latent row is cut on a tile's edge
     acc_dim = min(-(-value_dim // 128) * 128, d)
     kernel = functools.partial(
         _rows_kernel, scale=scale, block_size=bs, hkv=hkv,
-        rep=rep, hq_pad=hq_pad, q_tile=q_tile, chunk=chunk, exact=exact,
-        leaves=len(leaves))
+        rep=rep, hq_pad=hq_pad, q_tile=q_tile, group_tile=group,
+        chunk=chunk, exact=exact, leaves=len(leaves))
     if hkv == 1:
         # a lone kv head is no axis of a block: Mosaic cannot cut a
         # packed (block_size, 1, D) window out of the pool
@@ -428,7 +609,7 @@ def _attend_rows(q, leaves, layer, tables, positions, plan, *, scale,
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=8,
+            num_scalar_prefetch=10,
             grid=(1,),
             in_specs=[vmem] + [hbm] * len(leaves),
             out_specs=vmem,
@@ -439,7 +620,8 @@ def _attend_rows(q, leaves, layer, tables, positions, plan, *, scale,
                 pltpu.VMEM((rows, acc_dim), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
                 pltpu.VMEM((rows, 1), jnp.float32),
-                pltpu.VMEM((rows, 1), jnp.int32),
+                pltpu.VMEM((q_tile * hq_pad, 1), jnp.int32),
+                pltpu.VMEM((max(group, 1) * hq_pad, d), q.dtype),
             ]),
         out_shape=jax.ShapeDtypeStruct(qp.shape[:2] + (acc_dim,), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -456,7 +638,7 @@ def _attend_rows(q, leaves, layer, tables, positions, plan, *, scale,
         interpret=interpret,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32),
       jnp.asarray(tables, jnp.int32), plan.row, plan.q0, plan.count,
-      plan.blocks, plan.num_items, pos, qp, *leaves)
+      plan.first, plan.blocks, plan.slot, plan.num_items, pos, qp, *leaves)
     return out[:t, :hq, :value_dim]
 
 

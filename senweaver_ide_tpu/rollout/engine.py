@@ -295,7 +295,8 @@ def _paged_fused_step(params: Params, config: ModelConfig,
         write_block=write_block, write_off=write_off,
         use_kernel=use_kernel, adapters=adapters, adapter_ids=adapter_ids,
         with_moe_stats=config.num_experts > 0,
-        with_mhc_stats=config.hc_mult > 0)
+        with_mhc_stats=config.hc_mult > 0, with_attn_stats=True)
+    shared = stats.pop()
     next_tok = sample_token(logits, step_key, temperature=sample.temperature,
                             top_k=sample.top_k, top_p=sample.top_p)
     logp = sampled_logprob(logits, next_tok)
@@ -320,6 +321,10 @@ def _paged_fused_step(params: Params, config: ModelConfig,
         next_tok = jnp.concatenate(
             [next_tok, jnp.stack([n for n in stats[0] if n is not None]
                                  ).astype(next_tok.dtype)])
+    # Last, for every model: what the attention kernels' plan found among
+    # the rows' tables (``forward_paged``: block reads not made, group
+    # items), the same way (``_collect`` cuts them off again).
+    next_tok = jnp.concatenate([next_tok, shared.astype(next_tok.dtype)])
     return next_tok, logp, pool, key, cur
 
 
@@ -1093,8 +1098,16 @@ class RolloutEngine:
             "senweaver_engine_kv_blocks_read_total",
             "KV pool blocks the fused steps' attention had to cover: for "
             "each run of one row's entries in a step, the blocks up to "
-            "its last position. The lower bound of what is read: the "
-            "kernel reads a long run's blocks once a tile of queries.")
+            "its last position. The kernel reads a long run's blocks once "
+            "a tile of queries, and the blocks that decode rows share "
+            "once for up to 8 of them "
+            "(senweaver_engine_kv_blocks_shared_total fewer).")
+        self._kv_blocks_shared_total = reg.counter(
+            "senweaver_engine_kv_blocks_shared_total",
+            "KV pool block reads the fused steps' attention did not make: "
+            "where decode rows hold the same physical blocks (a group's "
+            "prompt, a grafted prefix, a fork) the kernel reads them once "
+            "for up to 8 rows; for each such item, blocks x (rows - 1).")
         self._host_syncs_total = reg.counter(
             "senweaver_engine_step_host_syncs_total",
             "Points of a paged step at which the host blocked on the "
@@ -3768,6 +3781,13 @@ class RolloutEngine:
         # launch was unqueued, where the launch came after it
         self._fetched_at = get_profiler().end_step(
             "engine.fused_step", fly.t_launch, self._fetched_at)
+        # the step's last two: the block reads its attention did not make
+        # because rows that hold the same blocks attended them together
+        toks, (saved, group_items) = toks[:-2], toks[-2:]
+        self._kv_blocks_shared_total.inc(int(saved))
+        if fly.span is not None:
+            fly.span.set_attr("kv_blocks_saved", int(saved))
+            fly.span.set_attr("attn_group_items", int(group_items))
         if self._moe_counters is not None:
             self._note_moe_step(fly.span, toks, fly.used)
         if self._mhc_gauge is not None:

@@ -533,7 +533,8 @@ def test_a_dense_models_step_fetches_what_it_did():
         params, config, np.zeros((6, 4), np.int32),
         np.zeros((4, 2), np.int32), eng.pool, jax.random.PRNGKey(0),
         eng._cur_tok_dev, GREEDY, False)
-    assert toks.shape == (4,) and logp.shape == (4,)
+    # T tokens and the attention plan's two counts (every model's)
+    assert toks.shape == (6,) and logp.shape == (4,)
     eng.pool = _pool
     eng.submit(PROMPT, max_new_tokens=3)
     eng.run()
@@ -552,7 +553,8 @@ def test_an_expert_models_step_appends_two_counts(model):
     toks, logp, _, _, _ = engine_mod._paged_fused_step(
         params, config, plan, np.zeros((4, 2), np.int32), eng.pool,
         jax.random.PRNGKey(0), eng._cur_tok_dev, GREEDY, False)
-    assert toks.shape == (6,) and logp.shape == (4,)
+    assert toks.shape == (8,) and logp.shape == (4,)
     # two identical entries write, two are dropped: 2 experts x 2 layers
-    # touched, 2 pairs on each
-    assert (int(toks[-2]), int(toks[-1])) == (4, 2)
+    # touched, 2 pairs on each; behind them the attention plan's two
+    assert (int(toks[4]), int(toks[5])) == (4, 2)
+    assert toks[6:].tolist() == [0, 0]

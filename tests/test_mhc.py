@@ -513,14 +513,15 @@ def test_step_reports_its_worst_map_in_the_one_fetch(model, served):
     assert all("experts_touched" in a for a in steps)
     assert served["gauge"] == steps[-1]["mhc_ds_err"]
     assert all(len(logps) == 9 for _, _, logps in served["requests"])
-    # the step itself: T log-probs and one float, T tokens and two counts
+    # the step itself: T log-probs and one float, T tokens, the experts'
+    # two counts and the attention plan's two
     plan = np.zeros((6, 4), np.int32)
     plan[3] = served["pool"].num_blocks
     toks, logp, _, _, _ = engine_mod._paged_fused_step(
         model[0], model[1], plan, np.zeros((4, 16), np.int32),
         served["pool"], jax.random.PRNGKey(0), np.zeros((4,), np.int32),
         SAMPLED, None)
-    assert toks.shape == (6,) and logp.shape == (5,)
+    assert toks.shape == (8,) and logp.shape == (5,)
     assert 1e-6 < float(logp[-1]) < 0.5
 
 

@@ -184,7 +184,10 @@ def _gather_reference(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
 
 
 def _rows_case(batch, hq, hkv, dtype, d=16, bs=BS):
-    seq_row, positions, *tables = _flat_batches(bs)[batch]
+    """A batch of ``_flat_batches`` or of ``SHARE_GROUPS`` (below), its
+    queries and a pool that holds every block its tables name."""
+    seq_row, positions, *tables = (SHARE_GROUPS.get(batch)
+                                   or _flat_batches(bs)[batch])[:3]
     tables = jnp.asarray(
         tables[0] if tables else _private_tables(LAYOUTS[bs][0]), jnp.int32)
     latent = hkv == LATENT
@@ -192,8 +195,9 @@ def _rows_case(batch, hq, hkv, dtype, d=16, bs=BS):
         hkv, d = 1, LATENT_ROW
     ks = jax.random.split(jax.random.PRNGKey(hq * 10 + hkv), 3)
     k_leaf, v_leaf = (
-        jax.random.normal(k, (LAYERS, NB, bs, hkv, d),
-                          jnp.float32).astype(dtype) for k in ks[:2])
+        jax.random.normal(k, (LAYERS, max(NB, int(tables.max()) + 1), bs,
+                              hkv, d), jnp.float32).astype(dtype)
+        for k in ks[:2])
     q = jax.random.normal(ks[2], (len(seq_row), hq, d),
                           jnp.float32).astype(dtype)
     return (q, k_leaf, None if latent else v_leaf,
@@ -201,10 +205,12 @@ def _rows_case(batch, hq, hkv, dtype, d=16, bs=BS):
             jnp.asarray(positions))
 
 
-def _run_rows(q, k_leaf, v_leaf, layer, tables, seq_row, positions):
+def _run_rows(q, k_leaf, v_leaf, layer, tables, seq_row, positions,
+              plan=None):
     bs = k_leaf.shape[2]
-    plan = plan_rows(seq_row, positions, block_size=bs,
-                     table_width=tables.shape[1], q_tile=4)
+    if plan is None:
+        plan = plan_rows(seq_row, positions, block_size=bs,
+                         table_width=tables.shape[1], q_tile=4)
     # a score tile two blocks wide, so a run takes several compute steps
     with mock.patch.object(paged_attention, "TILE_COLS",
                            2 * bs * k_leaf.shape[3]):
@@ -319,6 +325,218 @@ def test_rows_kernel_reads_no_dead_block(hq, hkv, bs):
                     jnp.where(dead, 7, tables), seq_row, positions)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+# ---- share groups: rows whose tables hold the same blocks attend them once
+
+
+def _forked_tables(groups, mb=MB, rows=12):
+    """``rows`` rows of ``mb`` private blocks, then for each of ``groups``
+    (donor, followers, blocks) the followers' leading ``blocks`` made the
+    donor's: a rollout group's fork, a grafted prefix."""
+    tables = np.random.default_rng(1).permutation(200)[:rows * mb].reshape(
+        rows, mb)
+    for donor, followers, blocks in groups:
+        for f in followers:
+            tables[f, :blocks] = tables[donor, :blocks]
+    return tables
+
+
+def _decode(rows, positions):
+    return list(rows), list(positions)
+
+
+def _window(row, lo, n):
+    return [row] * n, list(range(lo, lo + n))
+
+
+def _batch(*parts):
+    return tuple(np.asarray(sum((p[i] for p in parts), []), np.int32)
+                 for i in (0, 1))
+
+
+# name -> (seq_row, positions, tables, group items, block reads saved);
+# blocks of 4, a table 12 wide, up to 8 members a group item. A row's last
+# live block is never shared: it is the one being written.
+SHARE_GROUPS = {
+    # a rollout group: 8 rows over a prompt of 5 blocks, each further on
+    "group-of-8": _batch(_decode(range(8), [21, 25, 30, 22, 41, 33, 27, 47]))
+    + (_forked_tables([(0, range(1, 8), 5)]), 1, 5 * 7),
+    "partial-group-of-3": _batch(_decode([4, 2, 7], [13, 19, 14]),
+                                 _decode([5], [9]))
+    + (_forked_tables([(2, [4, 7], 3)]), 1, 3 * 2),
+    # two groups whose members alternate in the batch, sharing 5 and 2
+    # blocks; the second's last member comes before the first's
+    "interleaved-groups": _batch(
+        _decode([0, 8, 1, 9, 2, 10, 3], [22, 9, 31, 12, 20, 8, 27]))
+    + (_forked_tables([(0, [1, 2, 3], 5), (8, [9, 10], 2)]), 2,
+       5 * 3 + 2 * 2),
+    # 11 members: a group item of 8 and one of 3 over the same blocks
+    "group-of-11": _batch(_decode(range(11), range(17, 39, 2)))
+    + (_forked_tables([(0, range(1, 11), 4)]), 2, 4 * 7 + 4 * 2),
+    # 9 members: the ninth is alone in its tile and stays a plain item
+    "group-of-9": _batch(_decode(range(9), range(17, 35, 2)))
+    + (_forked_tables([(0, range(1, 9), 4)]), 1, 4 * 7),
+    # a group beside a prefill chunk (19 queries: tiles of 4 and a
+    # remainder whose stores run over the next entries) and a verify window
+    "beside-chunk-and-window": _batch(
+        _decode([0], [22]), _window(5, 7, 19), _decode([1, 2], [29, 21]),
+        _window(6, 30, 5), _decode([3], [25]), _decode([0] * 3, [0] * 3))
+    + (_forked_tables([(0, [1, 2, 3], 5)]), 1, 5 * 3),
+    # row 2 copied its boundary block on write and diverges one block
+    # early: a row goes with the mates of its DEEPEST common block, so the
+    # other three share all five and row 2, left without one, reads alone
+    "diverges-a-block-early": _batch(_decode([0, 1, 2, 3], [22, 26, 24, 30]))
+    + ((lambda t: (t.__setitem__((2, 4), 199), t)[1])(
+        _forked_tables([(0, [1, 2, 3], 5)])), 1, 5 * 2),
+    # two of four diverge a block early, the boundary block their own: two
+    # groups, and each shares what its members do
+    "two-diverge-early": _batch(_decode([0, 1, 2, 3], [22, 26, 24, 30]))
+    + ((lambda t: (t.__setitem__((slice(2, 4), 4), t[4, 4]), t)[1])(
+        _forked_tables([(0, [1, 2, 3], 5)])), 2, 5 + 5),
+    # a member still inside the forked prompt's last block (a follower's
+    # first step) may share only what lies before it: one block less than
+    # its mates have in common, so for this step they go without it
+    "member-inside-the-prompt": _batch(_decode([0, 1, 2], [22, 18, 26]))
+    + (_forked_tables([(0, [1, 2], 5)]), 1, 5),
+    # all of them inside it: the blocks before it are the group's
+    "group-inside-the-prompt": _batch(_decode([0, 1, 2], [19, 18, 17]))
+    + (_forked_tables([(0, [1, 2], 5)]), 1, 4 * 2),
+    # a system prompt under every row and a group's prompt above it: a row
+    # groups with those it shares the MOST with; row 7 has no such mate
+    "prefix-under-groups": _batch(
+        _decode([0, 1, 2, 3, 4, 5, 7], [30, 33, 28, 31, 29, 35, 17]))
+    + (_forked_tables([(0, range(1, 8), 2), (0, [1, 2], 6),
+                       (3, [4, 5], 5)]), 2, 6 * 2 + 5 * 2),
+    # forks, but each row's only live block past the fork is its last
+    # (nothing before it is shared by two LIVE rows): today's items
+    "unshared": _batch(_decode([0, 1, 2, 3], [5, 17, 9, 30]),
+                       _window(4, 2, 6))
+    + (_forked_tables([]), 0, 0),
+    "forked-but-first-block-only": _batch(_decode([0, 1, 2], [1, 2, 3]))
+    + (_forked_tables([(0, [1, 2], 1)]), 0, 0),
+}
+
+
+def _host_recount(seq_row, positions, tables, bs, group_tile):
+    """(group items, block reads saved) from the tables, the slow way:
+    rows a and b have in common their leading equal ids, never past the
+    block before either's last; a row's mates share its deepest common
+    block; a group is cut in batch order into tiles of ``group_tile``."""
+    single = [t for t in range(len(seq_row))
+              if (t == 0 or seq_row[t - 1] != seq_row[t])
+              and (t + 1 == len(seq_row) or seq_row[t + 1] != seq_row[t])]
+    ent = {int(seq_row[t]): t for t in single}
+    may = {r: positions[t] // bs for r, t in ent.items()}
+
+    def common(a, b):
+        n = 0
+        while (n < min(may[a], may[b]) and tables[a, n] == tables[b, n]):
+            n += 1
+        return n
+
+    depth = {a: max([common(a, b) for b in ent if b != a], default=0)
+             for a in ent}
+    groups = {}
+    for a in sorted(ent, key=ent.get):
+        if depth[a]:
+            groups.setdefault(tables[a, depth[a] - 1], []).append(a)
+    items = saved = 0
+    for rows in groups.values():
+        shared = min(common(a, b) for a in rows for b in rows if a != b
+                     ) if len(rows) > 1 else 0
+        for i in range(0, len(rows), group_tile):
+            n = len(rows[i:i + group_tile])
+            if n > 1 and shared:
+                items, saved = items + 1, saved + shared * (n - 1)
+    return items, saved
+
+
+def _run_grouped(*args, group_tile=8):
+    """``_run_rows`` under the plan that has the tables: (output, plan)."""
+    *_, tables, seq_row, positions = args
+    plan = plan_rows(seq_row, positions, block_size=BS, table_width=MB,
+                     q_tile=4, tables=tables, group_tile=group_tile)
+    return _run_rows(*args, plan=plan), plan
+
+
+@pytest.mark.parametrize("hq,hkv", [(12, 2), (20, LATENT)])
+@pytest.mark.parametrize("name", list(SHARE_GROUPS))
+def test_share_groups_match_the_gather(name, hq, hkv):
+    """Rows whose tables begin with the same physical blocks attend them
+    in one group item and their own blocks after it (dense and latent
+    form): the gather's numbers, and the plan's counts are a host
+    recount's from the tables."""
+    args = _rows_case(name, hq, hkv, jnp.float32)
+    got, plan = _run_grouped(*args)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_gather_reference(*args)),
+                               atol=2e-5, rtol=2e-5)
+    seq_row, positions, tables, items, saved = SHARE_GROUPS[name]
+    assert (int(plan.group_items), int(plan.kv_blocks_saved)) == (
+        items, saved) == _host_recount(seq_row, positions, tables, BS, 8)
+    n = int(plan.num_items[0])
+    assert int((plan.slot[:n] == -2).sum()) == items
+    # every entry is stored by exactly one item
+    stores = plan.slot[:n] != -2
+    owned = np.concatenate([np.arange(q0, q0 + c) for q0, c, s in zip(
+        plan.q0[:n].tolist(), plan.count[:n].tolist(), stores.tolist())
+        if s])
+    assert sorted(owned.tolist()) == list(range(len(seq_row)))
+
+
+@pytest.mark.parametrize("name", ["group-of-8", "interleaved-groups",
+                                  "beside-chunk-and-window"])
+def test_share_groups_match_the_gather_bf16(name):
+    args = _rows_case(name, 12, 2, jnp.bfloat16)
+    got, _ = _run_grouped(*args)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(_gather_reference(*args), np.float32), atol=2e-2)
+
+
+@pytest.mark.parametrize("name", ["unshared", "forked-but-first-block-only"]
+                         + [b for b in _flat_batches()
+                            if b != "forked-blocks"])
+def test_an_unshared_batch_plans_the_items_it_did(name):
+    """No two decode rows hold a block in common: the plan with the tables
+    is the plan without them, item for item, and no group item."""
+    if name in SHARE_GROUPS:
+        seq_row, positions, tables, *_ = SHARE_GROUPS[name]
+    else:
+        (seq_row, positions), tables = (_flat_batches()[name],
+                                        _private_tables())
+    kw = dict(block_size=BS, table_width=MB, q_tile=4)
+    old = plan_rows(jnp.asarray(seq_row), jnp.asarray(positions), **kw)
+    new = plan_rows(jnp.asarray(seq_row), jnp.asarray(positions), **kw,
+                    tables=jnp.asarray(tables), group_tile=8)
+    t = len(seq_row)
+    for field in ("row", "q0", "count", "first", "blocks", "slot"):
+        assert getattr(new, field)[:t].tolist() == getattr(
+            old, field).tolist(), field
+        assert not getattr(new, field)[t:].any()
+    assert not old.first.any() and int(new.num_items[0]) == int(
+        old.num_items[0])
+    n = int(old.num_items[0])
+    assert (old.slot[:n] == -1).all()
+    assert int(new.group_items) == int(new.kv_blocks_saved) == 0
+    assert (new.group_tile, old.group_tile) == (8, 0)
+
+
+def test_group_tile_follows_the_head_shape():
+    """8 members a group item where 8 x the padded heads fit the score
+    tile's 512 rows (qwen 128 head rows, falcon / glm / xing 256, longcat
+    512), fewer above; a group tile of 2 cuts a group of 8 in four."""
+    from senweaver_ide_tpu.ops.paged_attention import group_tile
+    assert [group_tile(h) for h in (12, 20, 32, 64, 128, 512)] == [
+        8, 8, 8, 8, 4, 1]
+    args = _rows_case("group-of-8", 12, 2, jnp.float32)
+    got, plan = _run_grouped(*args, group_tile=2)
+    assert (int(plan.group_items), int(plan.kv_blocks_saved)) == (4, 5 * 4)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_gather_reference(*args)),
                                atol=2e-5, rtol=2e-5)
 
 

@@ -190,3 +190,63 @@ def test_a_larger_block_serves_the_same_tokens(model, block_size):
     assert [t for t, _ in got] == [t for t, _ in want]
     for (_, a), (_, b) in zip(got, want):
         np.testing.assert_allclose(a, b, atol=2e-5)
+
+
+# ---- a group's shared blocks are attended once (ops/paged_attention) -----
+
+def _group_of_8(model, paged_kernel):
+    """A ``submit_group`` of 8 over a 22-token prompt (5 full blocks of 4
+    and 2 tokens: every member's first write copies the boundary block) on
+    an engine of 8 rows, so it is saturated and runs ahead once the
+    followers hold their rows. Greedy. ``(tokens, log-probs)`` a member,
+    the ``engine.step`` spans' attrs and the counter."""
+    from senweaver_ide_tpu import obs
+    c = get_config(model)
+    params = tf.init_params(c, jax.random.PRNGKey(2))
+    obs._reset_for_tests()
+    obs.enable()
+    try:
+        eng = RolloutEngine(
+            params, c, num_slots=8, max_len=64, seed=3,
+            sample=SampleParams(temperature=0.0),
+            engine_config=EngineConfig(paged_kernel=paged_kernel,
+                                       block_size=4, step_tokens=16))
+        rids = eng.submit_group(list(range(30, 52)), 8, max_new_tokens=7)
+        eng.run()
+        steps = [dict(s.attrs) for s in obs.get_tracer().spans()
+                 if s.name == "engine.step" and "entries" in s.attrs]
+        counter = obs.get_registry().get(
+            "senweaver_engine_kv_blocks_shared_total").value()
+    finally:
+        obs._reset_for_tests()
+    assert eng.stats()["group_forks"] == 7
+    eng._alloc.check_leaks()
+    return ([(eng.result(r), eng.result_logps(r)) for r in rids], steps,
+            counter)
+
+
+@pytest.mark.parametrize("model", MODELS + ["tiny-falcon-h1-test"])
+def test_a_group_of_8_attends_its_prompt_once(model):
+    """The dense, the latent and the hybrid model: with the kernel on, the
+    group's eight decode rows attend the prompt's five shared blocks in
+    ONE group item (35 block reads a step not made) and serve the tokens
+    and log-probs of the gather path; the two counts are on the span that
+    LAUNCHED the step, under run-ahead too, and feed the counter."""
+    got, steps, counter = _group_of_8(model, True)
+    want, plain, none = _group_of_8(model, False)
+    assert [t for t, _ in got] == [t for t, _ in want]
+    for (_, a), (_, b) in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=2e-4)
+    # the gather path plans nothing and reports zeros
+    assert none == 0 and all(
+        (x["kv_blocks_saved"], x["attn_group_items"]) == (0, 0)
+        for x in plain)
+    assert len(steps) == len(plain)
+    shared = [x for x in steps if x["attn_group_items"]]
+    # all eight decoding: one group item over the prompt's five full
+    # blocks; never more saved than covered
+    assert {(x["attn_group_items"], x["kv_blocks_saved"])
+            for x in shared if x["decode_rows"] == 8} == {(1, 35)}
+    assert all(x["kv_blocks_saved"] < x["kv_blocks"] for x in steps)
+    assert any(x["ahead"] for x in shared)
+    assert counter == sum(x["kv_blocks_saved"] for x in steps) > 0
