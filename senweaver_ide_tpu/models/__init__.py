@@ -1,4 +1,5 @@
-from .config import (ExpertShareUnsupported, ModelConfig, PRESETS,
+from .config import (ExpertShareUnsupported, LayerPatternUnsupported,
+                     ModelConfig, PRESETS,
                      RecurrentStateUnsupported,
                      RopeScaling, YarnScaling,
                      get_config,

@@ -81,6 +81,37 @@ class ExpertShareUnsupported(NotImplementedError):
         self.mechanism = mechanism
 
 
+class LayerPatternUnsupported(NotImplementedError):
+    """A mechanism that has no form yet for a configuration whose layers
+    are of unlike kinds in a fixed pattern (``layer_types``: state-space
+    mixers, window and full attention, gated memory units, cross
+    attention), each kind with a cache of its own or none. Raised where
+    the mechanism is asked for, never replaced by a path that would treat
+    the layers as one kind or hold a window layer's cache at full length.
+    ``mechanism`` names it."""
+
+    def __init__(self, mechanism: str, config_name: str):
+        super().__init__(
+            f"{mechanism} is not implemented for the layer pattern "
+            f"(layer_types) of configuration {config_name!r}: its layers "
+            f"are of unlike kinds, each with a cache of its own or none")
+        self.mechanism = mechanism
+
+
+# The kinds of layer a ``layer_types`` pattern may name, each ``x + mix(
+# Norm(x))`` then ``x + MLP(Norm(x))`` (``models.transformer._pattern_layer``):
+#   "mamba"   a Mamba-1 mixer; holds row-addressed state; publishes its
+#             scan's output as the memory ``m`` of the layers after it
+#   "window"  self-attention over the trailing ``layer_window`` positions;
+#             holds a row-addressed ring of that many positions and a step
+#   "full"    causal self-attention; holds block-addressed KV
+#   "gmu"     a gated memory unit: a gate of the layer's input on ``m``;
+#             holds nothing
+#   "cross"   attention of the layer's queries over the last "full"
+#             layer's KV; holds nothing
+LAYER_KINDS = ("mamba", "window", "full", "gmu", "cross")
+
+
 @dataclasses.dataclass(frozen=True)
 class RopeScaling:
     """Llama-3-style NTK-by-parts RoPE scaling (HF ``rope_type: llama3``).
@@ -287,6 +318,50 @@ class ModelConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    # Layers of unlike kinds in a fixed pattern (Phi-4-mini-flash's SambaY
+    # decoder): segments ``(period, repeats)``, a period a tuple of
+    # ``LAYER_KINDS``, run in order; ``num_layers`` is their sum. Each
+    # segment is ONE scan over its repeats whose body runs the period's
+    # layers (``params["layers"]["seg<i>"]["<kind><j>"]``, stacked
+    # ``repeats`` deep). Such a model has no rotary or other positional
+    # term: causality, the window and the recurrence are its only sense of
+    # order. Empty: every layer is of one kind, as above.
+    layer_types: Tuple[Tuple[Tuple[str, ...], int], ...] = ()
+    # Positions a "window" layer attends (its own included).
+    layer_window: int = 0
+    # Mamba-1 (``layer_types`` "mamba"): ``mamba_d_ssm`` inner values each
+    # with a state of ``mamba_d_state``, a conv of ``mamba_d_conv`` taps,
+    # and the step size through a rank-``mamba_dt_rank`` bottleneck.
+    mamba_dt_rank: int = 0
+    # "rms": RMSNorm with a gain; "layer": LayerNorm with gain and bias
+    # (``rms_norm_eps`` is its epsilon too).
+    norm: str = "rms"
+    # Differential attention: the heads are two sets, each a softmax map
+    # over its own keys, both maps applied to all values, the second
+    # subtracted ``lambda`` times, a per-head RMSNorm behind
+    # (``models.transformer._diff_heads``). The cache then holds
+    # ``num_kv_heads / 2`` rows of ``2 head_dim`` a token: [k1 | k2] and
+    # [v1 | v2] of a pair of kv heads.
+    diff_attn: bool = False
+
+    @property
+    def pattern(self) -> bool:
+        """The layers are of unlike kinds (``layer_types``)."""
+        return bool(self.layer_types)
+
+    def kind_layers(self, kind: str) -> int:
+        """Layers of ``kind`` in the pattern."""
+        return sum(period.count(kind) * n for period, n in self.layer_types)
+
+    @property
+    def cache_kv_heads(self) -> int:
+        """Rows a token takes in one payload leaf of an attention layer."""
+        return self.num_kv_heads // 2 if self.diff_attn else self.num_kv_heads
+
+    @property
+    def cache_head_dim(self) -> int:
+        """Width of such a row."""
+        return self.head_dim * 2 if self.diff_attn else self.head_dim
 
     @property
     def mla(self) -> bool:
@@ -300,7 +375,10 @@ class ModelConfig:
 
     @property
     def ssm_conv_dim(self) -> int:
-        """Channels the mixer's conv runs over: [x | B | C]."""
+        """Channels the mixer's conv runs over: [x | B | C]; Mamba-1's
+        runs over x alone."""
+        if self.mamba_dt_rank:
+            return self.mamba_d_ssm
         return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
@@ -362,8 +440,11 @@ class ModelConfig:
 
     @property
     def attn_layers(self) -> int:
-        """Attention layers the cache holds: sublayer i of layer l of a
-        shortcut block is pool layer 2 l + i."""
+        """Attention layers the block pool holds: sublayer i of layer l of
+        a shortcut block is pool layer 2 l + i; of a layer pattern, its
+        "full" layers alone."""
+        if self.pattern:
+            return self.kind_layers("full")
         return self.num_layers * (2 if self.shortcut_moe else 1)
 
     @property
@@ -616,6 +697,54 @@ def tiny_falcon_h1_test() -> ModelConfig:
         mlp_multipliers=(0.75, 0.4))
 
 
+# Phi-4-mini-flash's pattern (``model_type: phi4flash``, arXiv:2507.06607):
+# a self-decoder of (Mamba-1, window attention) pairs, one (Mamba-1, full
+# attention) pair whose mixer's scan output is the memory and whose
+# attention's KV is the cache of the cross-decoder, (gated memory unit,
+# cross attention) pairs.
+def sambay_layer_types(num_layers: int):
+    """The SambaY pattern over ``num_layers`` layers (a multiple of 4):
+    the full-attention layer is the second of the middle pair."""
+    half = num_layers // 2
+    if num_layers % 4 or half < 2:
+        raise ValueError(f"a SambaY pattern has 4 n layers, not "
+                         f"{num_layers}")
+    return ((("mamba", "window"), half // 2), (("mamba", "full"), 1),
+            (("gmu", "cross"), half // 2 - 1))
+
+
+def phi4_mini_flash() -> ModelConfig:
+    """Phi-4-mini-flash-reasoning (3.8 B) at its published sizes: 32
+    layers, 9 Mamba-1 mixers, 8 window-512 and 1 full differential
+    attention layers of 40/20 heads x 64, 7 gated memory units, 7 cross
+    layers, LayerNorm, no positional term, the embedding tied."""
+    return ModelConfig(
+        name="phi-4-mini-flash-reasoning", vocab_size=200_064,
+        hidden_size=2560, intermediate_size=10_240, num_layers=32,
+        num_heads=40, num_kv_heads=20, head_dim=64, max_seq_len=262_144,
+        rms_norm_eps=1e-5, tie_word_embeddings=True,
+        layer_types=sambay_layer_types(32), layer_window=512,
+        mamba_d_ssm=5120, mamba_d_state=16, mamba_d_conv=4,
+        mamba_dt_rank=160, norm="layer", diff_attn=True)
+
+
+def tiny_phi4flash_test() -> ModelConfig:
+    """Phi-4-mini-flash's pattern at test size: 8 layers (two window
+    pairs, the middle pair, one cross pair), a window of 8 so that a
+    short sequence wraps its ring several times, 24/12 heads x 4 (6 cache
+    rows of 8 a token, stored folded 3 x 2 as the published 10 are 5 x
+    2), a mixer of 192 inner values x 8 with a step-size rank of 6."""
+    return ModelConfig(
+        name="tiny-phi4flash-test", vocab_size=512, hidden_size=96,
+        intermediate_size=128, num_layers=8, num_heads=24, num_kv_heads=12,
+        head_dim=4, max_seq_len=128, rms_norm_eps=1e-5,
+        tie_word_embeddings=True, dtype=jnp.float32,
+        matmul_precision="highest",
+        layer_types=sambay_layer_types(8), layer_window=8,
+        mamba_d_ssm=192, mamba_d_state=8, mamba_d_conv=4, mamba_dt_rank=6,
+        norm="layer", diff_attn=True)
+
+
 def tiny_test() -> ModelConfig:
     """Small config for unit tests and CPU-mesh dry runs."""
     return ModelConfig(
@@ -707,6 +836,8 @@ PRESETS = {
     "tiny-xing-mhc-test": tiny_xing_mhc_test,
     "tiny-falcon-h1-test": tiny_falcon_h1_test,
     "tiny-longcat-flash-test": tiny_longcat_flash_test,
+    "phi-4-mini-flash-reasoning": phi4_mini_flash,
+    "tiny-phi4flash-test": tiny_phi4flash_test,
     "small-test": small_test,
 }
 

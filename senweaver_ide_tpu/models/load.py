@@ -31,7 +31,8 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .config import (ExpertShareUnsupported, ModelConfig,
+from .config import (ExpertShareUnsupported, LayerPatternUnsupported,
+                     ModelConfig,
                      RecurrentStateUnsupported, ResidualStreamUnsupported)
 from .transformer import Params
 
@@ -100,6 +101,9 @@ def load_hf_params(model_dir: str, config: ModelConfig, *,
     if c.hc_mult:
         # the checkpoint's names for the maps' leaves are not known here
         raise ResidualStreamUnsupported("the HF loader", c.name)
+    if c.pattern:
+        # nor which of the checkpoint's heads form a differential set
+        raise LayerPatternUnsupported("the HF loader", c.name)
     if c.ssm:
         # nor are its names for the mixer's leaves
         raise RecurrentStateUnsupported("the HF loader", c.name)
@@ -202,6 +206,8 @@ def export_hf_params(params: Params, config: ModelConfig,
 
     if config.hc_mult:
         raise ResidualStreamUnsupported("the HF exporter", config.name)
+    if config.pattern:
+        raise LayerPatternUnsupported("the HF exporter", config.name)
     if config.ssm:
         raise RecurrentStateUnsupported("the HF exporter", config.name)
     if config.shortcut_moe or config.expert_share:

@@ -34,12 +34,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import NEG_INF, attention
-from ..ops.norms import rms_norm
+from ..ops.norms import layer_norm, rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
 from ..ops import ssm as ssm_ops
-from .config import (ExpertShareUnsupported, LatentCacheUnsupported,
-                     ModelConfig, RecurrentStateUnsupported,
-                     ResidualStreamUnsupported, YarnScaling)
+from .config import (LAYER_KINDS, ExpertShareUnsupported,
+                     LatentCacheUnsupported, LayerPatternUnsupported,
+                     ModelConfig,
+                     RecurrentStateUnsupported, ResidualStreamUnsupported,
+                     YarnScaling)
 from .moe import BANKS, MoEStats, expert_ffn
 
 Params = Dict[str, Any]
@@ -93,6 +95,8 @@ def init_kv_cache(config: ModelConfig, batch: int, max_len: int,
                   dtype=None, *, quantized: Optional[bool] = None) -> KVCache:
     if config.mla:
         raise LatentCacheUnsupported("the slot KVCache layout", config.name)
+    if config.pattern:
+        raise LayerPatternUnsupported("the slot KVCache layout", config.name)
     if config.ssm:
         raise RecurrentStateUnsupported("the slot KVCache layout",
                                         config.name)
@@ -327,6 +331,8 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     c = config
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     D = c.hidden_size
+    if c.pattern:
+        return _init_pattern_params(c, k_embed, k_layers)
     n_dense = c.first_dense_layers if c.num_experts > 0 else 0
     if not 0 <= n_dense < c.num_layers:
         raise ValueError(f"{c.name}: first_dense_layers {n_dense} of "
@@ -346,6 +352,112 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
             jax.random.normal(k_head, (D, c.vocab_size), c.dtype)
             * jnp.asarray(1.0 / float(D) ** 0.5, c.dtype))
     return params
+
+
+def _init_pattern_params(c: ModelConfig, k_embed: jax.Array,
+                         k_layers: jax.Array) -> Params:
+    """``init_params`` for a configuration of unlike layers
+    (``c.layer_types``): ``params["layers"]["seg<i>"][<kind>]`` holds the
+    leaves of that kind's layers in segment i, stacked ``repeats`` deep,
+    and each kind has only its own: every kind the two norms (with biases
+    under LayerNorm) and the SwiGLU MLP; "mamba" the mixer's seven
+    (``_mamba1_mix``), at Mamba-1's own start (A = -(1..N), a step size
+    log-uniform on [1e-3, 1e-1] behind the softplus, D 1); "window" and
+    "full" the q/k/v/o projections, the four lambda vectors as the columns
+    of one ``(head_dim, 4)`` leaf (normal * 0.1) and the sub-norm's gain;
+    "cross" a query and an output projection with its own lambda and
+    sub-norm; "gmu" two matrices. ``final_norm_bias`` is ``(1, D)``: a
+    seeded-weights filler that reads a leaf's fan-in off its second-to-last
+    axis (``benchmark/weights.py``) then has one to read."""
+    D, F, I = c.hidden_size, c.intermediate_size, c.mamba_d_ssm
+    if (sum(len(p) * n for p, n in c.layer_types) != c.num_layers
+            or any(len(set(p)) != len(p) or set(p) - set(LAYER_KINDS)
+                   for p, _ in c.layer_types)):
+        raise ValueError(f"{c.name}: layer_types {c.layer_types} is not "
+                         f"{c.num_layers} layers of distinct kinds a period, "
+                         f"each one of {LAYER_KINDS}")
+    if not (c.diff_attn and c.tie_word_embeddings and not c.hc_mult
+            and not c.num_experts and not c.mla):
+        raise ValueError(
+            f"{c.name}: a layer pattern is differential attention over a "
+            f"tied embedding on the plain residual, with dense MLPs")
+
+    def dense(key, shape, fan_in):
+        scale = jnp.asarray(1.0 / float(fan_in) ** 0.5, c.dtype)
+        return jax.random.normal(key, shape, c.dtype) * scale
+
+    def norm(lp, name, L):
+        lp[name] = jnp.ones((L, D), c.dtype)
+        if c.norm == "layer":
+            lp[name + "_bias"] = jnp.zeros((L, D), c.dtype)
+
+    def diff(lp, ks, L):
+        lp["attn_lambda"] = 0.1 * jax.random.normal(
+            ks, (L, c.head_dim, 4), jnp.float32)
+        lp["attn_sub_norm"] = jnp.ones((L, 2 * c.head_dim), c.dtype)
+
+    def one_kind(kind, key, L):
+        ks = jax.random.split(key, 12)
+        lp = {"w_gate": dense(ks[0], (L, D, F), D),
+              "w_up": dense(ks[1], (L, D, F), D),
+              "w_down": dense(ks[2], (L, F, D), F)}
+        norm(lp, "attn_norm", L)
+        norm(lp, "mlp_norm", L)
+        if kind == "mamba":
+            N, K, R = c.mamba_d_state, c.mamba_d_conv, c.mamba_dt_rank
+            bound = 1.0 / float(K) ** 0.5
+            dt = jnp.exp(jax.random.uniform(
+                ks[7], (L, I), jnp.float32, math.log(1e-3), math.log(1e-1)))
+            lp.update(
+                ssm_in=dense(ks[3], (L, D, 2 * I), D),
+                ssm_conv_w=jax.random.uniform(ks[4], (L, K, I), c.dtype,
+                                              -bound, bound),
+                ssm_conv_b=jax.random.uniform(ks[5], (L, I), c.dtype,
+                                              -bound, bound),
+                ssm_x=dense(ks[6], (L, I, R + 2 * N), I),
+                ssm_dt=dense(ks[8], (L, R, I), R),
+                ssm_dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+                ssm_A_log=jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)),
+                    (L, I, N)),
+                ssm_D=jnp.ones((L, I), jnp.float32),
+                ssm_out=dense(ks[9], (L, I, D), I))
+        elif kind in ("window", "full"):
+            lp.update(wq=dense(ks[3], (L, D, c.q_dim), D),
+                      wk=dense(ks[4], (L, D, c.kv_dim), D),
+                      wv=dense(ks[5], (L, D, c.kv_dim), D),
+                      wo=dense(ks[6], (L, c.q_dim, D), c.q_dim))
+            diff(lp, ks[7], L)
+        elif kind == "cross":
+            lp.update(wq=dense(ks[3], (L, D, c.q_dim), D),
+                      wo=dense(ks[6], (L, c.q_dim, D), c.q_dim))
+            diff(lp, ks[7], L)
+        else:       # "gmu"
+            lp.update(gmu_in=dense(ks[3], (L, D, I), D),
+                      gmu_out=dense(ks[4], (L, I, D), I))
+        return lp
+
+    params: Params = {
+        "embed": (jax.random.normal(k_embed, (c.vocab_size, D), c.dtype)
+                  * jnp.asarray(0.02, c.dtype)),
+        "layers": {
+            f"seg{i}": {kind: one_kind(
+                kind, jax.random.fold_in(k_layers, 16 * i + j), n)
+                for j, kind in enumerate(period)}
+            for i, (period, n) in enumerate(c.layer_types)},
+        "final_norm": jnp.ones((D,), c.dtype)}
+    if c.norm == "layer":
+        params["final_norm_bias"] = jnp.zeros((1, D), c.dtype)
+    return params
+
+
+def _norm(c: ModelConfig, x: jax.Array, lp: Dict[str, jax.Array],
+          name: str) -> jax.Array:
+    """The configuration's norm with leaf ``name`` (and, under LayerNorm,
+    ``name_bias``)."""
+    if c.norm == "layer":
+        return layer_norm(x, lp[name], lp[name + "_bias"], c.rms_norm_eps)
+    return rms_norm(x, lp[name], c.rms_norm_eps)
 
 
 def _dense(h: jax.Array, lp: Dict[str, jax.Array], name: str,
@@ -1036,6 +1148,219 @@ def _shortcut_block(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     return x, carry, aux, stats
 
 
+# -- layers of unlike kinds in a fixed pattern (``c.layer_types``) ----------
+
+def _mamba1_in(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array):
+    """Mamba-1's input projection of the layer's normed input h (B, S, D):
+    ``[u | z] = h W_in`` -> u (B, S, I) the conv's input, z the gate."""
+    with jax.named_scope("ssm.in_proj"):
+        p = _dense(h, lp, "ssm_in", "bsd,de->bse")
+    return p[..., :c.mamba_d_ssm], p[..., c.mamba_d_ssm:]
+
+
+def _mamba1_inputs(c: ModelConfig, lp: Dict[str, jax.Array],
+                   conv_out: jax.Array, dtype):
+    """The conv's output (B, S, I) f32 -> what the scan reads: u =
+    silu(conv) in ``dtype``; ``[r | B | C] = u W_x``; dt = softplus(r W_dt
+    + dt_bias) and A = -exp(A_log) (I, N) in float32."""
+    r, n = c.mamba_dt_rank, c.mamba_d_state
+    u = jax.nn.silu(conv_out).astype(dtype)
+    x = _dense(u, lp, "ssm_x", "bsi,ie->bse")
+    dt = jax.nn.softplus(
+        _dense(x[..., :r], lp, "ssm_dt", "bsr,ri->bsi").astype(jnp.float32)
+        + lp["ssm_dt_bias"])
+    return (u, dt, -jnp.exp(lp["ssm_A_log"].astype(jnp.float32)),
+            x[..., r:r + n], x[..., r + n:])
+
+
+def _mamba1_out(c: ModelConfig, lp: Dict[str, jax.Array], y: jax.Array,
+                z: jax.Array):
+    """The scan's output y (B, S, I) f32 and the gate z -> (the mixer's
+    output ``(y * silu(z)) W_out`` (B, S, D), the memory: y itself, before
+    the gate, in z's dtype)."""
+    with jax.named_scope("ssm.out_proj"):
+        g = (y * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+        return _dense(g, lp, "ssm_out", "bsi,id->bsd"), y.astype(z.dtype)
+
+
+def _gmu(lp: Dict[str, jax.Array], h: jax.Array, m: jax.Array) -> jax.Array:
+    """The gated memory unit: ``(silu(h W_in) * m) W_out``, m the same
+    token's memory."""
+    gate = jax.nn.silu(_dense(h, lp, "gmu_in", "bsd,di->bsi")
+                       .astype(jnp.float32)).astype(h.dtype)
+    return _dense(gate * m, lp, "gmu_out", "bsi,id->bsd")
+
+
+def _diff_queries(c: ModelConfig, lp: Dict[str, jax.Array],
+                  h: jax.Array) -> jax.Array:
+    """The queries of differential attention, h (B, S, D) -> (B, S, Hq,
+    2 head_dim). ``wq``'s columns are (pair p, set i, head j, head_dim):
+    the ``Hq / Hkv`` heads j of set i that read kv pair p. A cache row of
+    pair p is ``[k1_p | k2_p]``, so a query of set 1 is ``[q | 0]`` and one
+    of set 2 ``[0 | q]``: its product with the row is its own set's score,
+    and one attention call of ``Hq`` heads over ``Hkv / 2`` rows of
+    ``2 head_dim`` serves both maps; over values ``[v1_p | v2_p]`` it
+    yields ``[P v1 | P v2]`` a head, as the equations have it."""
+    b, s, _ = h.shape
+    rep = c.num_heads // c.num_kv_heads
+    q = _dense(h, lp, "wq", "bsd,de->bse").reshape(
+        b, s, c.cache_kv_heads, 2, rep, c.head_dim)
+    zero = jnp.zeros_like(q[:, :, :, 0])
+    q = jnp.stack([jnp.concatenate([q[:, :, :, 0], zero], axis=-1),
+                   jnp.concatenate([zero, q[:, :, :, 1]], axis=-1)], axis=3)
+    return q.reshape(b, s, c.num_heads, c.cache_head_dim)
+
+
+def _diff_kv(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array):
+    """h (B, S, D) -> the cache rows k, v (B, S, Hkv / 2, 2 head_dim):
+    ``wk``'s and ``wv``'s columns are (pair p, set i, head_dim)."""
+    b, s, _ = h.shape
+    shape = (b, s, c.cache_kv_heads, c.cache_head_dim)
+    return (_dense(h, lp, "wk", "bsd,de->bse").reshape(shape),
+            _dense(h, lp, "wv", "bsd,de->bse").reshape(shape))
+
+
+def _diff_out(c: ModelConfig, lp: Dict[str, jax.Array], out: jax.Array,
+              layer: jax.Array) -> jax.Array:
+    """The two maps' outputs (B, S, Hq, 2 head_dim), heads as
+    ``_diff_queries`` laid them, -> the attention sublayer's output
+    (B, S, D): ``RMSNorm(a_1 - lam a_2; g_sub) (1 - lam0)`` a head, in
+    float32, then ``W_o``. ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0``
+    from the columns of ``attn_lambda``, ``lam0 = 0.8 - 0.6 exp(-0.3
+    layer)`` with ``layer`` the layer's number in the model."""
+    b, s = out.shape[:2]
+    rep = c.num_heads // c.num_kv_heads
+    o = out.astype(jnp.float32).reshape(b, s, c.cache_kv_heads, 2, rep,
+                                        c.cache_head_dim)
+    v = lp["attn_lambda"].astype(jnp.float32)
+    lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * layer.astype(jnp.float32))
+    lam = (jnp.exp(jnp.sum(v[:, 0] * v[:, 1]))
+           - jnp.exp(jnp.sum(v[:, 2] * v[:, 3])) + lam0)
+    d = o[:, :, :, 0] - lam * o[:, :, :, 1]
+    d = d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True)
+                          + c.rms_norm_eps)
+    d = d * lp["attn_sub_norm"].astype(jnp.float32) * (1.0 - lam0)
+    with jax.named_scope("attn.out"):
+        return _dense(d.reshape(b, s, c.q_dim).astype(out.dtype), lp, "wo",
+                      "bse,ed->bsd")
+
+
+def _pattern_layer(c: ModelConfig, kind: str, lp: Dict[str, jax.Array],
+                   x: jax.Array, m: jax.Array, carry, mixer: Callable,
+                   at: Dict[str, Any]):
+    """One layer of a ``layer_types`` configuration, for the no-cache and
+    the paged forward alike: ``x + mix(Norm(x))`` then ``x + MLP(Norm(
+    x))``, both through ``_residual``. ``mixer(kind, lp, h, m, carry, at)``
+    is the layer's first sublayer on its normed input -> (its output, the
+    memory, carry'); ``at`` says where the layer is (``_pattern_scan``).
+    The scope names (``ssm.mamba``, ``attn.window``, ``attn.full``, ``gmu``,
+    ``attn.cross``, ``mlp``) are docs/observability.md's."""
+    scope = {"mamba": "ssm.mamba", "gmu": "gmu"}.get(kind, "attn." + kind)
+
+    def mix(x_in):
+        out, m2, carry2 = mixer(kind, lp, _norm(c, x_in, lp, "attn_norm"),
+                                m, carry, at)
+        return out, (m2, carry2)
+
+    with jax.named_scope(scope):
+        x, (m, carry), _ = _residual(c, lp, x, "attn", mix)
+    with jax.named_scope("mlp"):
+        x, _, _ = _residual(
+            c, lp, x, "mlp", lambda x_in: (_swiglu(
+                _norm(c, x_in, lp, "mlp_norm"), lp, "w_gate", "w_up",
+                "w_down"), None))
+    return x, m, carry
+
+
+def _pattern_scan(c: ModelConfig, params: Params, x: jax.Array, carry,
+                  mixer: Callable, remat: bool = False):
+    """The layers of a ``layer_types`` configuration: one scan a segment,
+    whose body runs the period's unlike layers in order, with the memory
+    ``m`` (the last "mamba" layer's scan output, read by the "gmu" layers)
+    and ``carry`` (the cache leaves, or the last "full" layer's k and v)
+    in the scan's carry. ``at`` tells a layer its number in the model
+    (``layer``), among the layers of its kind (``index``, which addresses
+    that kind's cache leaves), and the ``index`` of the last "full" layer
+    before it (``full``, whose KV a "cross" layer reads). -> (x, carry')."""
+    m = jnp.zeros(x.shape[:-1] + (c.mamba_d_ssm,), x.dtype)
+    seen = {kind: 0 for period, _ in c.layer_types for kind in period}
+    first = 0
+    layer_fn = _pattern_layer
+    if remat:
+        layer_fn = jax.checkpoint(
+            _pattern_layer, static_argnums=(0, 1, 6), prevent_cse=False,
+            policy=(jax.checkpoint_policies.checkpoint_dots
+                    if c.remat == "dots" else None))
+    for i, (period, n) in enumerate(c.layer_types):
+        base, start = dict(seen), first
+
+        def body(state, inp, period=period, base=base, start=start):
+            x, m, carry = state
+            lps, rep = inp
+            for j, kind in enumerate(period):
+                full = base.get("full", 0) - 1
+                if "full" in period[:j + 1]:
+                    full = full + rep + 1
+                at = {"layer": start + rep * len(period) + j,
+                      "index": base[kind] + rep, "full": full}
+                x, m, carry = layer_fn(c, kind, lps[kind], x, m, carry,
+                                       mixer, at)
+            return (x, m, carry), None
+
+        (x, m, carry), _ = jax.lax.scan(
+            body, (x, m, carry),
+            (params["layers"][f"seg{i}"], jnp.arange(n, dtype=jnp.int32)),
+            unroll=c.scan_unroll)
+        for kind in period:
+            seen[kind] += n
+        first += n * len(period)
+    return x, carry
+
+
+def _dense_mixer(c: ModelConfig, attn_mask, kind: str,
+                 lp: Dict[str, jax.Array], h: jax.Array, m: jax.Array, kv,
+                 at: Dict[str, Any]):
+    """A ``layer_types`` layer's first sublayer over whole sequences, h
+    (B, S, D) its normed input: the mixer from a zero state, attention
+    over the sequence's own k and v with a plain mask (a "window" layer's
+    also ``t - s < layer_window``), a "cross" layer over ``kv``, the last
+    "full" layer's k and v. -> (its output, the memory, kv')."""
+    if kind == "mamba":
+        u_in, z = _mamba1_in(c, lp, h)
+        with jax.named_scope("ssm.conv"):
+            conv = ssm_ops.conv_dense(u_in, lp["ssm_conv_w"],
+                                      lp["ssm_conv_b"])
+            u, dt, a, b, cc = _mamba1_inputs(c, lp, conv, h.dtype)
+        with jax.named_scope("ssm.scan"):
+            y = ssm_ops.scan1_dense(u, dt, a, b, cc, lp["ssm_D"])
+        return _mamba1_out(c, lp, y, z) + (kv,)
+    if kind == "gmu":
+        return _gmu(lp, h, m), m, kv
+    q = _diff_queries(c, lp, h)
+    if kind == "cross":
+        k, v = kv
+    else:
+        k, v = _diff_kv(c, lp, h)
+        if kind == "full":
+            kv = (k, v)
+    out = attention(q, k, v, q_offset=0, kv_mask=attn_mask, causal=True,
+                    window=c.layer_window if kind == "window" else None,
+                    scale=1.0 / float(c.head_dim) ** 0.5)
+    return _diff_out(c, lp, out, at["layer"]), m, kv
+
+
+def _forward_pattern(params: Params, c: ModelConfig, x: jax.Array,
+                     attn_mask) -> jax.Array:
+    """The no-cache forward of a ``layer_types`` configuration over whole
+    sequences x (B, S, D) (``_dense_mixer``). The trainer's and the tests'
+    path."""
+    shape = x.shape[:2] + (c.cache_kv_heads, c.cache_head_dim)
+    kv = (jnp.zeros(shape, x.dtype), jnp.zeros(shape, x.dtype))
+    return _pattern_scan(c, params, x, kv,
+                         functools.partial(_dense_mixer, c, attn_mask),
+                         remat=bool(c.remat))[0]
+
+
 def _rope_tables(c: ModelConfig, positions: jax.Array):
     """cos / sin for ``positions``, made for the whole head, or under
     latent attention for the decoupled rotary part alone. YaRN scaling
@@ -1102,6 +1427,16 @@ def forward(
 def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
                   mesh=None, fresh_cache=False):
     b, s = tokens.shape
+    if c.pattern:
+        if cache is not None or mesh is not None:
+            raise LayerPatternUnsupported(
+                "forward(cache=...) over the slot KVCache"
+                if cache is not None else "forward(mesh=...)", c.name)
+        x = _forward_pattern(params, c, params["embed"][tokens], attn_mask)
+        x = _norm(c, x, params, "final_norm")
+        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+        return (logits.astype(jnp.float32), None,
+                jnp.zeros((), jnp.float32))
     if c.hc_mult and (cache is not None or mesh is not None):
         raise ResidualStreamUnsupported(
             "forward(cache=...) over the slot KVCache" if cache is not None
@@ -1689,12 +2024,193 @@ def reads_pool_in_place(c: ModelConfig, use_kernel: Optional[bool]) -> bool:
     if use_kernel is not None:
         return bool(use_kernel)
     from ..ops.paged_attention import on_tpu
-    return on_tpu() and (c.mla or c.head_dim % 128 == 0)
+    return on_tpu() and (c.mla or c.cache_head_dim % 128 == 0)
+
+
+def ring_tables(rows: int, table_width: int, ring_blocks: int) -> jax.Array:
+    """The block table of the "window" layers' rings, a constant of the
+    shapes: row r's logical block b is ring block ``r ring_blocks + b %
+    ring_blocks`` of the leaf read as ``rows x ring_blocks`` blocks
+    (``rollout.paged_kv.StateRows.win_k``)."""
+    return (jnp.arange(rows, dtype=jnp.int32)[:, None] * ring_blocks
+            + jnp.arange(table_width, dtype=jnp.int32)[None, :]
+            % ring_blocks)
+
+
+def _write_rows(leaf: jax.Array, layer, block: jax.Array, off: jax.Array,
+                val: jax.Array) -> jax.Array:
+    """Each entry's cache row val (T, Hkv, D) into a payload leaf
+    ``(L, NB, BS x f, Hkv / f, D)`` (the head axis folded,
+    ``rollout.paged_kv.stored_kv_heads``) at ``(layer, block[t], off[t])``;
+    a block past the leaf's is dropped. One update a (token, head), as
+    ``_paged_attend``'s."""
+    t, hkv, d = val.shape
+    stored = leaf.shape[3]
+    fold = hkv // stored
+    rows = off[:, None] * fold + jnp.arange(fold)              # (T, f)
+    return leaf.at[layer, block[:, None, None], rows[:, :, None],
+                   jnp.arange(stored)].set(
+        val.reshape(t, fold, stored, d).astype(leaf.dtype), mode="drop")
+
+
+def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
+                           positions, write_block, write_off, use_kernel):
+    """``_forward_paged_impl`` for a ``layer_types`` configuration: the
+    same ``_pattern_scan`` as the no-cache forward, over the pool's leaves.
+
+    * "full" layers write and read the block-addressed ``k``/``v`` through
+      the step's ``tables`` like any attention layer (PR 38's group items
+      apply); the "cross" layers read the last "full" layer's blocks with
+      that step's plan and tables and write nothing.
+    * "window" layers write position p at slot ``p % capacity`` of their
+      row's ring (``pool.rows.win_k`` / ``win_v``) and read the trailing
+      ``layer_window`` positions through ``ring_tables``, the kernel by a
+      window plan (``ops.paged_attention.plan_rows(window=)``): the ring
+      holds a window and a step's entries, so the writes of a step never
+      reach what its queries read, and a chunk that crosses the window's
+      edge is whole prefill.
+    * "mamba" layers read and write their rows' state and conv window
+      (``ops.ssm.conv_flat``, ``scan1_flat``) once a run.
+
+    Entries that keep no write (padding, dropped writes) advance no state
+    and write no ring slot. -> the tuple ``_forward_paged_impl`` returns."""
+    t = tokens.shape[0]
+    if pool.k_scale is not None or pool.rows is None:
+        raise LayerPatternUnsupported(
+            "a quantized pool / a pool without row-addressed state in "
+            "forward_paged", c.name)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens][:, None, :]                 # (T, 1, D)
+    hkv = c.cache_kv_heads
+    fold = hkv // pool.k.shape[3]
+    bs = pool.k.shape[2] // fold
+    state, window, win_k, win_v = pool.rows
+    rows_r = tables.shape[0]
+    ring_blocks = win_k.shape[2] // (bs * fold)
+    cap = ring_blocks * bs
+    if t > cap - c.layer_window + 1:
+        raise ValueError(
+            f"{c.name}: a step of {t} entries over rings of {cap} "
+            f"positions (window {c.layer_window}): the pool was made for "
+            f"fewer step_tokens")
+    keep = write_block < pool.k.shape[1]
+    scale = 1.0 / float(c.head_dim) ** 0.5
+    kernel = reads_pool_in_place(c, use_kernel)
+    # the rings as blocks: (Lw, rows x ring_blocks, BS x f, Hkv / f, D)
+    as_blocks = lambda a: a.reshape(
+        (a.shape[0], a.shape[1] * ring_blocks, bs * fold) + a.shape[3:])
+    ring_shape = win_k.shape
+    win_k, win_v = as_blocks(win_k), as_blocks(win_v)
+    ring_tbl = ring_tables(rows_r, tables.shape[1], ring_blocks)
+    ring_block = jnp.where(
+        keep, seq_row * ring_blocks + (positions // bs) % ring_blocks,
+        win_k.shape[1])
+    row_plan = win_plan = shared = None
+    if kernel:
+        from ..ops.paged_attention import (group_tile, paged_attention_rows,
+                                           plan_rows, query_tile)
+        with jax.named_scope("attn.row_plan"):
+            row_plan = plan_rows(
+                seq_row, positions, block_size=bs,
+                table_width=tables.shape[1], q_tile=query_tile(c.num_heads),
+                tables=tables, group_tile=group_tile(c.num_heads))
+            win_plan = plan_rows(
+                seq_row, positions, block_size=bs,
+                table_width=tables.shape[1], q_tile=query_tile(c.num_heads),
+                window=c.layer_window)
+            shared = jnp.stack([row_plan.kv_blocks_saved,
+                                row_plan.group_items])
+    with jax.named_scope("ssm.run_plan"):
+        run_plan = ssm_ops.plan_runs(seq_row, positions, keep,
+                                     num_rows=rows_r)
+
+    def attend(q, k_leaf, v_leaf, layer, tbl, plan, span):
+        """q (T, 1, Hq, D) over one layer of folded leaves -> the same
+        shape; ``span`` > 0: the trailing positions a query reads."""
+        if plan is not None:
+            with jax.named_scope("attn.scores"):
+                return paged_attention_rows(
+                    q[:, 0], k_leaf, v_leaf, layer, tbl, positions, plan,
+                    scale=scale, kv_heads=hkv)[:, None]
+        with jax.named_scope("attn.kv_gather"):
+            mine = tbl[seq_row]                                 # (T, MB)
+            seq = lambda leaf: leaf[layer, mine].reshape(
+                t, mine.shape[1] * bs, hkv, leaf.shape[-1]).astype(q.dtype)
+            k_seq, v_seq = seq(k_leaf), seq(v_leaf)
+        with jax.named_scope("attn.scores"):
+            kv_pos = jnp.arange(k_seq.shape[1])[None, :]
+            valid = kv_pos <= positions[:, None]
+            if span:
+                valid &= kv_pos > positions[:, None] - span
+            return attention(q, k_seq, v_seq, kv_mask=valid, causal=False,
+                             scale=scale)
+
+    def mixer(kind, lp, h, m, leaves, at):
+        k_leaf, v_leaf, state, window, win_k, win_v = leaves
+        if kind == "mamba":
+            u_in, z = _mamba1_in(c, lp, h)
+            with jax.named_scope("ssm.conv"):
+                conv, window = ssm_ops.conv_flat(
+                    u_in[:, 0], lp["ssm_conv_w"], lp["ssm_conv_b"], window,
+                    at["index"], seq_row, run_plan)
+                u, dt, a, b, cc = _mamba1_inputs(c, lp, conv[:, None],
+                                                 h.dtype)
+            with jax.named_scope("ssm.scan"):
+                y, state = ssm_ops.scan1_flat(
+                    u[:, 0], dt[:, 0], a, b[:, 0], cc[:, 0], lp["ssm_D"],
+                    state, at["index"], seq_row, run_plan)
+            out, m = _mamba1_out(c, lp, y[:, None], z)
+        elif kind == "gmu":
+            out = _gmu(lp, h, m)
+        else:
+            with jax.named_scope("attn.qkv"):
+                q = _diff_queries(c, lp, h)
+                if kind != "cross":
+                    k, v = _diff_kv(c, lp, h)
+            if kind == "full":
+                with jax.named_scope("attn.kv_write"):
+                    k_leaf = _write_rows(k_leaf, at["index"], write_block,
+                                         write_off, k[:, 0])
+                    v_leaf = _write_rows(v_leaf, at["index"], write_block,
+                                         write_off, v[:, 0])
+            if kind == "window":
+                with jax.named_scope("attn.kv_write"):
+                    win_k = _write_rows(win_k, at["index"], ring_block,
+                                        positions % bs, k[:, 0])
+                    win_v = _write_rows(win_v, at["index"], ring_block,
+                                        positions % bs, v[:, 0])
+                got = attend(q, win_k, win_v, at["index"], ring_tbl,
+                             win_plan, c.layer_window)
+            else:
+                got = attend(q, k_leaf, v_leaf,
+                             at["index" if kind == "full" else "full"],
+                             tables, row_plan, 0)
+            out = _diff_out(c, lp, got, at["layer"])
+        return out, m, (k_leaf, v_leaf, state, window, win_k, win_v)
+
+    x, leaves = _pattern_scan(
+        c, params, x, (pool.k, pool.v, state, window, win_k, win_v), mixer)
+    k_leaf, v_leaf, state, window, win_k, win_v = leaves
+    pool = pool._replace(k=k_leaf, v=v_leaf, rows=type(pool.rows)(
+        state, window, win_k.reshape(ring_shape), win_v.reshape(ring_shape)))
+    with jax.named_scope("lm_head"):
+        x = _norm(c, x, params, "final_norm")
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["embed"])[:, 0].astype(jnp.float32)
+    return logits, pool, None, None, shared
 
 
 def _forward_paged_impl(params, c, tokens, *, pool, tables,
                         seq_row, positions, write_block, write_off,
                         use_kernel, adapters=None, adapter_ids=None):
+    if c.pattern:
+        if adapters is not None:
+            raise LayerPatternUnsupported("adapter banks in forward_paged",
+                                          c.name)
+        return _forward_paged_pattern(
+            params, c, tokens, pool=pool, tables=tables, seq_row=seq_row,
+            positions=positions, write_block=write_block,
+            write_off=write_off, use_kernel=use_kernel)
     with jax.named_scope("embed"):
         # (T, 1, D), or the stream (T, 1, hc_mult, D)
         x = _stream_open(c, _times(params["embed"][tokens][:, None, :],
@@ -1735,7 +2251,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         # of the state-space mixer (padding and dropped writes advance
         # nothing). The row-addressed leaves ride the carry behind the
         # block-addressed ones.
-        rows = tuple(pool.rows)
+        rows = (pool.rows.ssm, pool.rows.conv)
         with jax.named_scope("ssm.run_plan"):
             run_plan = ssm_ops.plan_runs(
                 seq_row, positions, write_block < pool.k.shape[1],

@@ -57,6 +57,7 @@ def attention(
                                              # True = valid
     causal: bool = True,
     window: Optional[int] = None,            # sliding-window width
+    scale: Optional[float] = None,           # None: 1 / sqrt(D)
 ) -> jnp.ndarray:
     """Grouped-query causal attention. Returns (B, Sq, Hq, D).
 
@@ -78,7 +79,8 @@ def attention(
     rep = hq // hkv
     qg = q.reshape(b, sq, hkv, rep, d)
 
-    scale = 1.0 / jnp.sqrt(jnp.asarray(d, dtype=jnp.float32))
+    if scale is None:
+        scale = 1.0 / jnp.sqrt(jnp.asarray(d, dtype=jnp.float32))
     exact = q.dtype == jnp.float32
     if exact:
         scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg.astype(jnp.float32),

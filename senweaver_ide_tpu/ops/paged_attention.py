@@ -152,8 +152,20 @@ def group_tile(num_q_heads: int) -> int:
 
 def blocks_per_chunk(block_size: int, num_kv_heads: int) -> int:
     """Pool blocks a compute step streams: as many as fill the score
-    tile's columns."""
-    return max(1, TILE_COLS // (block_size * num_kv_heads))
+    tile's columns. Where ONE block is most of a tile and not all of it
+    (10 kv heads x 32 positions = 320 of 512 columns), three: alone it
+    would pay a compute step's floor for 60% of a tile. On a v5e at 40
+    query heads over 10 kv heads x 128, blocks of 32, 48 decode rows at
+    contexts ~2.5k (my chip run, PR 39; ms a call by blocks a step, 1 / 2
+    / 3 / 4): a group's rows over forked tables 1.29 / 0.98 / 0.89 /
+    0.88, a 512-position window over rings 0.74 / 0.64 / 0.59 / 0.62,
+    with a 145-entry chunk beside 3.80 / 2.71 / 2.44 / 2.37 and 1.28 /
+    1.04 / 0.95 / 0.99. Every block of 64, 256 or 512 columns (the heads
+    a preset had before) divides the tile and streams as it did."""
+    cols = block_size * num_kv_heads
+    if cols < TILE_COLS < 2 * cols:
+        return 3
+    return max(1, TILE_COLS // cols)
 
 
 def on_tpu() -> bool:
@@ -173,7 +185,7 @@ _FRESH, _GROUP = -1, -2
                    data_fields=["row", "q0", "count", "first", "blocks",
                                 "slot", "num_items", "kv_blocks_saved",
                                 "group_items"],
-                   meta_fields=["q_tile", "group_tile"])
+                   meta_fields=["q_tile", "group_tile", "window"])
 @dataclasses.dataclass(frozen=True)
 class RowPlan:
     """The flat batch cut into the kernel's items, int32 vectors one
@@ -191,7 +203,11 @@ class RowPlan:
     ``kv_blocks_saved`` and ``group_items`` (scalars) count, over the
     group items, blocks x (members - 1) and 1. ``q_tile`` and
     ``group_tile`` (static) are the most queries an item and the most
-    members a group item hold: the kernel sizes its tiles by them."""
+    members a group item hold: the kernel sizes its tiles by them.
+    ``window`` (static, 0: none): the plan of a window layer, whose items
+    start at the first block that any of their queries' trailing
+    ``window`` positions lie in, and whose queries the kernel masks to
+    those positions."""
     row: jax.Array
     q0: jax.Array
     count: jax.Array
@@ -203,12 +219,13 @@ class RowPlan:
     group_items: jax.Array
     q_tile: int
     group_tile: int = 0
+    window: int = 0
 
 
 def plan_rows(seq_row: jax.Array, positions: jax.Array, *,
               block_size: int, table_width: int, q_tile: int,
               tables: Optional[jax.Array] = None,
-              group_tile: int = 0) -> RowPlan:
+              group_tile: int = 0, window: int = 0) -> RowPlan:
     """Find the segments of a flat batch on the device and cut them into
     items of up to ``q_tile`` queries (``query_tile`` of the model's
     heads; see the module docstring). A boundary is where
@@ -232,7 +249,12 @@ def plan_rows(seq_row: jax.Array, positions: jax.Array, *,
     which no two such rows share a block plans the items it did without
     ``tables``. A dozen small ops over (rows x rows) and one over (rows
     x rows x table width), once a step (the fused step lowers them for
-    every shape it compiles)."""
+    every shape it compiles).
+
+    ``window`` > 0 plans a window layer: query t reads positions
+    ``(positions[t] - window, positions[t]]`` alone, so an item starts at
+    the block its FIRST query's window starts in (a segment's positions
+    rise). Such rows are rings that share nothing: no ``tables``."""
     t = seq_row.shape[0]
     seq_row = seq_row.astype(jnp.int32)
     positions = positions.astype(jnp.int32)
@@ -254,6 +276,11 @@ def plan_rows(seq_row: jax.Array, positions: jax.Array, *,
     # every entry's own item (the one it opens, if it opens one), how many
     # items it gives and where its own goes
     count, first, blocks = hi - idx + 1, jnp.zeros_like(idx), live(last)
+    if window:
+        if tables is not None:
+            raise ValueError("a window plan takes no tables: its rows are "
+                             "rings, which share no block")
+        first = jnp.maximum(positions - window + 1, 0) // block_size
     slot = jnp.full_like(idx, _FRESH)
     gives = opens.astype(jnp.int32)
     grouping = tables is not None and group_tile >= 2
@@ -324,7 +351,8 @@ def plan_rows(seq_row: jax.Array, positions: jax.Array, *,
     return RowPlan(row=row, q0=q0, count=count, first=first, blocks=blocks,
                    slot=slot, num_items=jnp.sum(gives)[None],
                    kv_blocks_saved=saved, group_items=group_items,
-                   q_tile=q_tile, group_tile=group_tile if grouping else 0)
+                   q_tile=q_tile, group_tile=group_tile if grouping else 0,
+                   window=window)
 
 
 def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
@@ -333,7 +361,7 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
                  q_ref, *refs,
                  scale: float, block_size: int, hkv: int, rep: int,
                  hq_pad: int, q_tile: int, group_tile: int, chunk: int,
-                 exact: bool, leaves: int):
+                 exact: bool, leaves: int, window: int = 0):
     """The whole flat batch in one program; see the module docstring.
 
     ``refs`` are the pool's ``leaves`` payload leaves in HBM (k and v, or
@@ -458,8 +486,13 @@ def _rows_kernel(layer_ref, tables_ref, row_ref, q0_ref, count_ref,
         col = jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1)
         head = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) % hq_pad
         own = (col % hkv) == (head // rep)          # (rows, n_cols)
-        seen = (col // hkv + (first_ref[item] + c * chunk) * block_size
-                <= q_pos)
+        col_pos = col // hkv + (first_ref[item] + c * chunk) * block_size
+        seen = col_pos <= q_pos
+        if window:
+            # a window layer: a query reads its trailing ``window``
+            # positions alone (the plan starts the item at the first block
+            # any of its queries reads)
+            seen = jnp.logical_and(seen, col_pos > q_pos - window)
         k, *v = (buf.at[slot].reshape(n_cols, buf.shape[-1])[...]
                  for buf in bufs)
         # a latent row's leading columns are its value: the window of the
@@ -535,15 +568,26 @@ def paged_attention_rows(
     positions: jax.Array,      # (T,) int32 — each query's own position
     plan: RowPlan,             # plan_rows(seq_row, positions, ...)
     *,
+    scale: Optional[float] = None,   # None: 1 / sqrt(D)
+    kv_heads: Optional[int] = None,  # None: the leaves' own head axis
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Attention of a flat paged batch over its rows' blocks, read in
     place (module docstring). Query t sees positions ``<= positions[t]``
-    of its row. Returns ``(T, Hq, D)``."""
+    of its row, and under a window plan (``plan.window``) only its
+    trailing ``window`` of them. Returns ``(T, Hq, D)``.
+
+    ``kv_heads``: the leaves are stored FOLDED,
+    ``(L, NB, BS * f, Hkv / f, D)`` for the same bytes in the same order
+    as ``(L, NB, BS, Hkv, D)`` (``rollout.paged_kv.stored_kv_heads``: a
+    head axis of 10 would be padded to 16 sublanes in HBM, and Mosaic
+    cannot cut a block out of that), and ``kv_heads`` is the true
+    ``Hkv``. The kernel reads a block as ``BS * Hkv`` rows either way."""
     d = q.shape[-1]
     return _attend_rows(q, (k_leaf, v_leaf), layer, tables, positions, plan,
-                        scale=1.0 / (d ** 0.5), value_dim=d,
-                        name="paged_attention_rows", interpret=interpret)
+                        scale=scale or 1.0 / (d ** 0.5), value_dim=d,
+                        name="paged_attention_rows", interpret=interpret,
+                        kv_heads=kv_heads)
 
 
 def paged_latent_attention_rows(
@@ -571,12 +615,14 @@ def paged_latent_attention_rows(
 
 
 def _attend_rows(q, leaves, layer, tables, positions, plan, *, scale,
-                 value_dim, name, interpret):
+                 value_dim, name, interpret, kv_heads=None):
     """The one program behind ``paged_attention_rows`` (``leaves`` k and
     v) and ``paged_latent_attention_rows`` (one leaf, the value a row's
     leading ``value_dim`` columns)."""
     t, hq, d = q.shape
     _, _, bs, hkv, _ = leaves[0].shape
+    if kv_heads:
+        bs, hkv = bs * hkv // kv_heads, kv_heads
     rep = hq // hkv
     exact = q.dtype == jnp.float32
     hq_pad = _head_rows(hq)
@@ -595,8 +641,8 @@ def _attend_rows(q, leaves, layer, tables, positions, plan, *, scale,
     kernel = functools.partial(
         _rows_kernel, scale=scale, block_size=bs, hkv=hkv,
         rep=rep, hq_pad=hq_pad, q_tile=q_tile, group_tile=group,
-        chunk=chunk, exact=exact, leaves=len(leaves))
-    if hkv == 1:
+        chunk=chunk, exact=exact, leaves=len(leaves), window=plan.window)
+    if leaves[0].shape[3] == 1:
         # a lone kv head is no axis of a block: Mosaic cannot cut a
         # packed (block_size, 1, D) window out of the pool
         leaves = tuple(leaf.reshape(leaf.shape[:3] + (d,))

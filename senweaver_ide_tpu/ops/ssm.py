@@ -1,6 +1,7 @@
-"""The Mamba-2 state-space mixer's two sequence operators: the causal
-depthwise conv with a carried window, and the selective scan in its
-state-space-duality form. Plain XLA.
+"""The state-space mixers' sequence operators: the causal depthwise conv
+with a carried window, Mamba-2's selective scan in its
+state-space-duality form, and Mamba-1's (``scan1_dense``, ``scan1_flat``,
+further down) as a scan over a run's entries. Plain XLA.
 
 One set of equations (ISSUE 32, "The equations"; heads ``h`` of group
 ``g(h)`` read that group's ``B`` and ``C``):
@@ -283,6 +284,109 @@ def scan_flat(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     ssm, z_long = _advance_long(ssm, layer, plan, seq_row, cum, dt, x, b, c)
     y = y + z_long + jnp.where(plan.keep[:, None, None], z_row[seq_row], 0.0)
     return y + d[:, None] * x.astype(jnp.float32), ssm
+
+
+# -- Mamba-1: a decay a (channel, state) pair ------------------------------
+#
+#     S_t = exp(dt_t (x) 1 * A) * S_{t-1} + (dt_t * u_t) (x) B_t     S_{-1} = 0
+#     y_t = S_t C_t + D * u_t
+#
+# with A (I, N): every one of a channel's N state values decays at its own
+# rate, so the duality form over a step's entries (one scalar decay a head
+# a token) does not exist, and a run is a scan over its entries. The same
+# ``RunPlan`` and the same rules as above (position 0 starts from zero; an
+# entry not kept advances nothing; a run continues its row's stored state;
+# a decay is ``exp`` of a float32 product). The state is held (N, I): the
+# channels on the lanes, no padding of a 16-wide axis to 128.
+
+# entries of a long run that one trip of its loop advances, unrolled
+_RUN_BLOCK = 8
+
+
+def _step1(state, dt_t, u_t, b_t, c_t, a_t):
+    """One token of Mamba-1. state (..., N, I) f32; dt_t, u_t (..., I) f32;
+    b_t, c_t (..., N) f32; a_t (N, I) f32 -> (state', y_t (..., I))."""
+    state = (jnp.exp(dt_t[..., None, :] * a_t) * state
+             + (dt_t * u_t)[..., None, :] * b_t[..., :, None])
+    return state, jnp.sum(state * c_t[..., :, None], axis=-2)
+
+
+def scan1_dense(u: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+                c: jax.Array, d: jax.Array) -> jax.Array:
+    """Mamba-1's selective scan over whole sequences from a zero state,
+    one position at a time. u (B, S, I); dt (B, S, I) f32, after the
+    softplus; a (I, N) f32, negative; b, c (B, S, N); d (I,). -> y
+    (B, S, I) f32."""
+    f32 = lambda v: jnp.swapaxes(v.astype(jnp.float32), 0, 1)
+    a_t = a.astype(jnp.float32).T
+
+    def token(state, inp):
+        u_t, dt_t, b_t, c_t = inp
+        return _step1(state, dt_t, u_t, b_t, c_t, a_t)
+
+    s0 = jnp.zeros((u.shape[0],) + a_t.shape, jnp.float32)
+    _, y = jax.lax.scan(token, s0, (f32(u), f32(dt), f32(b), f32(c)))
+    return jnp.swapaxes(y, 0, 1) + d.astype(jnp.float32) * u.astype(
+        jnp.float32)
+
+
+def scan1_flat(u: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
+               c: jax.Array, d: jax.Array, ssm: jax.Array, layer: jax.Array,
+               seq_row: jax.Array, plan: RunPlan):
+    """``scan1_dense`` for the flat batch over the rows' stored states. u
+    (T, I); dt (T, I) f32, after the softplus; a (I, N) f32; b, c (T, N);
+    d (I,); ssm (L, rows, N, I) f32. -> (y (T, I) f32, ssm').
+
+    Every row whose run is ONE entry (a decode row) advances in one
+    elementwise pass over the rows' states. Each longer run (a prefill
+    chunk) takes one trip of a loop: its row's state is read once, carried
+    through the run's entries ``_RUN_BLOCK`` at a time, and written back
+    once. A run's kept entries are contiguous in the batch (``plan_runs``).
+    """
+    t = u.shape[0]
+    r = plan.row_len.shape[0]
+    a_t = a.astype(jnp.float32).T                            # (N, I)
+    uf, bf, cf = (v.astype(jnp.float32) for v in (u, b, c))
+    # the decode rows
+    e = plan.row_last
+    one = plan.row_len == 1
+    s = ssm[layer, :r]                                       # (R, N, I)
+    s0 = jnp.where(plan.row_fresh[:, None, None], 0.0, s)
+    s1, y_row = _step1(s0, dt[e], uf[e], bf[e], cf[e], a_t)
+    ssm = ssm.at[layer, :r].set(jnp.where(one[:, None, None], s1, s))
+    y = jnp.where((plan.keep & one[seq_row])[:, None], y_row[seq_row], 0.0)
+
+    # the prefill chunks
+    pad = lambda v: jnp.pad(v, ((0, _RUN_BLOCK), (0, 0)))
+    dt_p, u_p, b_p, c_p = pad(dt), pad(uf), pad(bf), pad(cf)
+
+    def one_run(i, carry):
+        ssm, y = carry
+        row = plan.long_rows[i]
+        n = plan.row_len[row]
+        start = plan.row_last[row] - n + 1
+        st = jnp.where(plan.row_fresh[row], 0.0, ssm[layer, row])
+
+        def block(k, carry):
+            st, y = carry
+            at = start + k * _RUN_BLOCK
+            cut = lambda v: jax.lax.dynamic_slice_in_dim(v, at, _RUN_BLOCK)
+            dts, us, bs, cs, old = (cut(dt_p), cut(u_p), cut(b_p), cut(c_p),
+                                    cut(y))
+            out = []
+            for j in range(_RUN_BLOCK):
+                live = k * _RUN_BLOCK + j < n
+                nxt, y_j = _step1(st, dts[j], us[j], bs[j], cs[j], a_t)
+                st = jnp.where(live, nxt, st)
+                out.append(jnp.where(live, y_j, old[j]))
+            return st, jax.lax.dynamic_update_slice_in_dim(
+                y, jnp.stack(out), at, 0)
+
+        st, y = jax.lax.fori_loop(0, -(-n // _RUN_BLOCK), block, (st, y))
+        return ssm.at[layer, row].set(st), y
+
+    ssm, y = jax.lax.fori_loop(0, plan.n_long, one_run, (ssm, pad(y)))
+    return y[:t] + d.astype(jnp.float32) * uf, ssm
 
 
 def gated_norm(y: jax.Array, z: jax.Array, weight: jax.Array, groups: int,

@@ -52,7 +52,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import (LatentCacheUnsupported, ModelConfig,
+from ..models.config import (LatentCacheUnsupported,
+                             LayerPatternUnsupported, ModelConfig,
                              RecurrentStateUnsupported,
                              ResidualStreamUnsupported)
 from ..obs import get_registry
@@ -133,6 +134,9 @@ class AdapterPool:
         if config.hc_mult:
             raise ResidualStreamUnsupported("the multi-LoRA adapter pool",
                                             config.name)
+        if config.pattern:
+            raise LayerPatternUnsupported("the multi-LoRA adapter pool",
+                                          config.name)
         if config.ssm:
             raise RecurrentStateUnsupported("the multi-LoRA adapter pool",
                                             config.name)

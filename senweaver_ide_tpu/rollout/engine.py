@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import (LatentCacheUnsupported, ModelConfig,
+from ..models.config import (LatentCacheUnsupported,
+                             LayerPatternUnsupported, ModelConfig,
                              RecurrentStateUnsupported,
                              ResidualStreamUnsupported)
 from ..models.transformer import (KVCache, Params, forward, forward_paged,
@@ -47,7 +48,8 @@ from ..ops.sampling import sample_token, sampled_logprob
 from .kv_pressure import (HostPrefix, PrefixCandidate, dequantize_host,
                           pick_victim, should_tier)
 from .paged_kv import (BlockAllocator, BlockPayload, BlocksExhausted,
-                       PagedKVPool, copy_blocks, copy_state_rows,
+                       PagedKVPool, cache_kinds, copy_blocks,
+                       copy_state_rows,
                        gather_blocks, gather_blocks_quant, init_paged_pool,
                        install_blocks, install_blocks_quant, kv_row_bytes,
                        pool_bytes_per_block, resolve_block_size,
@@ -765,7 +767,29 @@ class RolloutEngine:
                      "pool")):
                 if asked:
                     raise ResidualStreamUnsupported(mechanism, config.name)
-        if config.ssm:
+        if config.pattern:
+            ec = engine_config or EngineConfig()
+            # Layers of unlike kinds serve from the paged pool's
+            # descriptors alone (block-addressed KV for the full-attention
+            # layers, rings and state by row), on one chip: what has no
+            # form for them is refused by name. Never the slots fallback,
+            # which would hold every layer's cache at full length.
+            for asked, mechanism in (
+                    (ec.kv_layout == "slots", "the slot KVCache layout "
+                     "(EngineConfig.kv_layout='slots')"),
+                    (config.kv_quant, "the slot int8 cache (kv_quant)"),
+                    (ec.kv_dtype != "bf16"
+                     or ec.kv_dtype_per_layer is not None,
+                     "the quantized KV ladder (EngineConfig.kv_dtype "
+                     "int8/fp8)"),
+                    (self._ring, "the sliding-window ring cache of the "
+                     "slot layout (sliding_window)"),
+                    (mesh is not None, "a mesh (mesh=...)"),
+                    (adapter_pool is not None, "the multi-LoRA adapter "
+                     "pool")):
+                if asked:
+                    raise LayerPatternUnsupported(mechanism, config.name)
+        elif config.ssm:
             ec = engine_config or EngineConfig()
             # Recurrent state lives in the paged pool's row-addressed
             # leaves on one chip alone: a layout that has no place for it
@@ -890,11 +914,15 @@ class RolloutEngine:
             # follower has copied it; with none free a group degrades to
             # unshared prefills.
             n_snap = max(2, num_slots // 6) if config.ssm else 0
+            st = self.engine_config.step_tokens
+            self._step_tokens = max(
+                num_slots, int(st) if st else max(4 * num_slots, 64))
             self.pool = self._on_device(lambda: init_paged_pool(
                 config, nb, bs,
                 kv_dtype=self.engine_config.kv_dtype,
                 kv_dtype_per_layer=self.engine_config.kv_dtype_per_layer,
-                state_rows=num_slots + n_snap if config.ssm else 0))
+                state_rows=num_slots + n_snap if config.ssm else 0,
+                step_tokens=self._step_tokens))
             self._state_snap_free: List[int] = list(  # guarded-by: _lock
                 range(num_slots + n_snap - 1, num_slots - 1, -1))
             # (src, dst) row copies asked for since the last fused step:
@@ -912,6 +940,22 @@ class RolloutEngine:
                     "Device bytes of the pool's row-addressed recurrent "
                     "state (engine rows and snapshot rows, all layers)."
                 ).set(self.pool.rows.nbytes)
+            # What the pool holds by kind of cache (block-addressed KV,
+            # window rings, state, conv windows), once: a reader of the
+            # gauges has the bytes of each descriptor.
+            kind_bytes = get_registry().gauge(
+                "senweaver_kv_cache_kind_bytes",
+                "Device bytes of the pool by kind of cache "
+                "(paged_kv.cache_kinds): kv, window, ssm, conv.",
+                labelnames=("kind",))
+            self.cache_kind_bytes = {
+                k.kind: k.nbytes(nb, bs, num_slots + n_snap)
+                for k in cache_kinds(
+                    config, bs, self._step_tokens,
+                    self.engine_config.kv_dtype,
+                    self.engine_config.kv_dtype_per_layer)}
+            for kind, n_bytes in self.cache_kind_bytes.items():
+                kind_bytes.set(n_bytes, kind=kind)
             self._alloc = BlockAllocator(
                 nb, bs, registry=get_registry(),
                 bytes_per_block=pool_bytes_per_block(self.pool))
@@ -941,9 +985,6 @@ class RolloutEngine:
             self._cur_tok_dev = self._on_device(
                 lambda: jnp.zeros((num_slots,), jnp.int32))
             self._prefill_jobs: Dict[int, _PrefillJob] = {}  # guarded-by: _lock
-            st = self.engine_config.step_tokens
-            self._step_tokens = max(
-                num_slots, int(st) if st else max(4 * num_slots, 64))
             # None: forward_paged chooses by what it sees (the platform
             # and the pool's leaves); True / False force it, for tests
             self._use_paged_kernel = self.engine_config.paged_kernel
@@ -1211,6 +1252,9 @@ class RolloutEngine:
         (``num_blocks``; default sized like the target's) whose
         gauges publish under ``senweaver_spec_draft_kv_*``."""
         from .spec_controller import FixedDepth, SpecController
+        if self.config.pattern or draft_config.pattern:
+            raise LayerPatternUnsupported("fused draft/verify speculation",
+                                          self.config.name)
         if self.config.ssm or draft_config.ssm:
             # a rejected draft cannot roll a state back
             raise RecurrentStateUnsupported("fused draft/verify speculation",
@@ -2247,7 +2291,10 @@ class RolloutEngine:
 
     def _refuse_state(self, mechanism: str) -> None:
         """What has no state-snapshot counterpart yet is refused by name
-        for a model with recurrent state, never fallen back from."""
+        for a model with recurrent state, never fallen back from; a
+        layer pattern's rings and unlike layers have none either."""
+        if self.config.pattern:
+            raise LayerPatternUnsupported(mechanism, self.config.name)
         if self.config.ssm:
             raise RecurrentStateUnsupported(mechanism, self.config.name)
 
@@ -3643,6 +3690,8 @@ class RolloutEngine:
                     st.set_attr("ssm_rows", len(decode_rows)
                                 + sum(1 for j in job_rows if j[5]))
                     st.set_attr("ssm_state_copies", n_copies)
+                if self.config.pattern:
+                    self._note_pattern_step(st, pos_l[:used], n_copies)
             t_launch = get_profiler().begin_step("engine.fused_step")
             if st is not None and t_launch and (prev is not None
                                                 or self._fetched_at):
@@ -3673,6 +3722,28 @@ class RolloutEngine:
                     or self._state_needs_values(fly)):
                 self._collect(span, fly, emitted)
             return emitted
+
+    def _note_pattern_step(self, st, positions, n_copies: int) -> None:
+        # guarded-by: caller
+        """A layer pattern's attrs of one fused step on its span: columns
+        of cache (a token's k and v in one layer) the step's used entries
+        attend, by the kind of layer that reads them — a window layer
+        their trailing window, the full layer the context, the cross
+        layers the full layer's context again each — and the rings a
+        fork's row copies moved (a state-row copy carries the row's ring
+        in every window layer)."""
+        c = self.config
+        ctx = np.asarray(positions, np.int64) + 1
+        full = int(ctx.sum())
+        cols = {"window": (int(np.minimum(ctx, c.layer_window).sum())
+                           * c.kind_layers("window")),
+                "full": full * c.kind_layers("full"),
+                "cross": full * c.kind_layers("cross")}
+        for kind, n in cols.items():
+            st.set_attr("kv_columns_" + kind, n)
+        st.set_attr("kv_columns", sum(cols.values()))
+        st.set_attr("window_row_copies",
+                    n_copies * c.kind_layers("window"))
 
     def _schedule_traced(self, span) -> None:
         # guarded-by: caller

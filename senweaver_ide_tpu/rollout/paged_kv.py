@@ -60,7 +60,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..models.config import LatentCacheUnsupported
+from ..models.config import LatentCacheUnsupported, LayerPatternUnsupported
 from ..models.transformer import (ModelConfig, dequantize_pool_kv,
                                   quantize_pool_kv)
 from ..obs.runtime_profile import ProfiledFunction
@@ -155,7 +155,8 @@ class StateRows(NamedTuple):
     ``gather_blocks*``) never touch it, and a later kind of per-row or
     per-window state can sit beside it the same way.
 
-    ``ssm`` ``(L, rows, H, P, N)`` float32: a state-space mixer's state;
+    ``ssm`` ``(L, rows, H, P, N)`` float32: a state-space mixer's state
+    (Mamba-1's: ``(L, rows, N, I)``, the channels on the lanes);
     ``conv`` ``(L, rows, K-1, C)``: the last K-1 inputs of its conv, oldest
     first (``ops/ssm.py``)."""
 
@@ -171,6 +172,33 @@ class StateRows(NamedTuple):
         """Device bytes of all rows, all layers: held whole, whatever the
         rows do."""
         return sum(int(a.size) * jnp.dtype(a.dtype).itemsize for a in self)
+
+
+class RingRows(NamedTuple):
+    """:class:`StateRows` of a layer pattern (``ModelConfig.layer_types``)
+    with "window" layers: the mixers' ``ssm`` (Mamba-1's:
+    ``(Lm, rows, N, I)``, the channels on the lanes) and ``conv``, and
+    beside them, addressed by the same rows and copied by the same
+    :func:`copy_state_rows`, the window layers' rings.
+
+    ``win_k`` / ``win_v`` ``(Lw, rows, capacity x f, Hkv / f, D)``: one
+    ring a row a layer: position p's k and v lie at ring slot
+    ``p % capacity``, ``capacity`` = the window plus a step's entries
+    rounded up to whole blocks, so that a step's writes never reach a
+    position one of its queries still reads (:func:`window_capacity`). A
+    ring is a row's like the state: copied whole at a fork, never shared.
+    The attention kernels read it as ``capacity / block_size`` blocks a row
+    through a table that is a constant of the shapes
+    (``models.transformer.ring_tables``); the head axis is stored folded as
+    the block leaves' is (:func:`stored_kv_heads`)."""
+
+    ssm: jnp.ndarray
+    conv: jnp.ndarray
+    win_k: jnp.ndarray
+    win_v: jnp.ndarray
+
+    num_rows = StateRows.num_rows
+    nbytes = StateRows.nbytes
 
 
 class PagedKVPool(NamedTuple):
@@ -189,7 +217,8 @@ class PagedKVPool(NamedTuple):
     None-derived properties are static under jit.
 
     ``rows`` holds what is addressed by row and not by block
-    (:class:`StateRows`); None for a model whose only state is KV."""
+    (:class:`StateRows`, or :class:`RingRows` where window layers' rings
+    sit beside the state); None for a model whose only state is KV."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -197,7 +226,7 @@ class PagedKVPool(NamedTuple):
     v_scale: Optional[jnp.ndarray] = None
     k_hi: Optional[jnp.ndarray] = None
     v_hi: Optional[jnp.ndarray] = None
-    rows: Optional[StateRows] = None
+    rows: Optional[Any] = None
 
     @property
     def num_blocks(self) -> int:
@@ -243,12 +272,107 @@ class BlockPayload(NamedTuple):
     v_hi: Any = None
 
 
-def init_state_rows(config: ModelConfig, num_rows: int) -> StateRows:
+def stored_kv_heads(kv_heads: int) -> int:
+    """The head axis a payload leaf is STORED with. XLA:TPU tiles a
+    leaf's last two axes, and a head axis that is neither a multiple of 8
+    nor 1, 2 or 4 is padded to the next 8 in HBM (10 heads: 16 sublanes,
+    1.6 x the bytes, and Mosaic cannot cut a block out of the padded
+    axis). Such a leaf folds ``f = kv_heads / stored`` heads into the
+    position axis: ``(L, NB, BS x f, stored, D)``, the same bytes in the
+    same order as ``(L, NB, BS, kv_heads, D)``, tiled compactly
+    (``ops.paged_attention.paged_attention_rows``'s ``kv_heads``). Every
+    head count a preset had before the first folded one (1, 2, 4, 8, 16,
+    32) is stored as it is."""
+    if kv_heads % 8 == 0:
+        return kv_heads
+    return next(h for h in (4, 2, 1) if kv_heads % h == 0)
+
+
+def window_capacity(config: ModelConfig, block_size: int,
+                    step_tokens: int) -> int:
+    """Positions a "window" layer's ring holds a row: ``layer_window`` plus
+    the most entries one row can have in a step, in whole blocks. A step
+    writes its entries and then attends: entry i of a chunk at positions
+    ``p .. p + n - 1`` overwrites position ``p + i - capacity``, and the
+    chunk's first query still reads from ``p - window + 1``: safe while
+    ``capacity >= window + n - 1``. It does not grow with ``max_len``."""
+    return -(-(config.layer_window + step_tokens) // block_size) * block_size
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheKind:
+    """What one kind of layer holds in the pool (:func:`cache_kinds`)."""
+    kind: str            # "kv" | "window" | "ssm" | "conv"
+    addressed: str       # "block": by the allocator's tables; "row"
+    layers: int
+    unit_bytes: int      # bytes a layer of a token (block-addressed, and
+                         # the rings) or of a row (state)
+    units: int           # tokens a row holds in the rings; else 1
+
+    def nbytes(self, num_blocks: int, block_size: int, rows: int) -> int:
+        """Device bytes of this kind in a pool of that geometry."""
+        n = (num_blocks * block_size if self.addressed == "block"
+             else rows * self.units)
+        return self.layers * n * self.unit_bytes
+
+
+def cache_kinds(config: ModelConfig, block_size: int = 16,
+                step_tokens: int = 0, kv_dtype: str = "bf16",
+                kv_dtype_per_layer=None) -> List[CacheKind]:
+    """A descriptor a kind of cache ``config``'s layers hold, in the order
+    of the pool's leaves: the block-addressed KV of its attention layers
+    (a layer pattern: its "full" layers alone; bytes without a quantized
+    rung's scales), then what is row-addressed — the rings of its
+    "window" layers, the state and the conv window of its mixers. A layer
+    that appears in none holds nothing ("gmu", "cross"). The pool's
+    constructors, :func:`kv_row_bytes` and the engine's gauges read
+    these."""
+    c = config
+    item = jnp.dtype(c.dtype).itemsize
+    payload, n_hi = resolve_kv_dtypes(c.attn_layers, kv_dtype,
+                                      kv_dtype_per_layer)
+    leaf = (c.latent_row_dim if c.mla
+            else c.cache_kv_heads * c.cache_head_dim)
+    kv_item = jnp.dtype(payload or c.dtype).itemsize
+    out = [CacheKind("kv", "block", c.attn_layers,
+                     leaf * kv_item * (1 if c.mla else 2), 1)]
+    if c.pattern and c.kind_layers("window"):
+        out.append(CacheKind(
+            "window", "row", c.kind_layers("window"), 2 * leaf * item,
+            window_capacity(c, block_size, step_tokens)))
+    if c.ssm:
+        mixers = c.kind_layers("mamba") if c.pattern else c.num_layers
+        out.append(CacheKind(
+            "ssm", "row", mixers, 4 * c.mamba_d_state * (
+                c.mamba_d_ssm if c.mamba_dt_rank
+                else c.mamba_n_heads * c.mamba_d_head), 1))
+        out.append(CacheKind(
+            "conv", "row", mixers,
+            (c.mamba_d_conv - 1) * c.ssm_conv_dim * item, 1))
+    return out
+
+
+def init_state_rows(config: ModelConfig, num_rows: int,
+                    block_size: int = 16, step_tokens: int = 0):
     """Zeroed row-addressed state for ``config``'s state-space mixer:
     the state in float32 (it is a sum over thousands of steps), the conv's
     window in the serving dtype (it holds projection outputs as they
-    are)."""
+    are). A layer pattern's leaves lead with the layers of their own kind
+    ("mamba", "window"), and its rings are sized by ``block_size`` and
+    ``step_tokens`` (:func:`window_capacity`)."""
     c = config
+    if c.pattern:
+        mixers, stored = c.kind_layers("mamba"), stored_kv_heads(
+            c.cache_kv_heads)
+        ring = (c.kind_layers("window"), num_rows,
+                window_capacity(c, block_size, step_tokens)
+                * (c.cache_kv_heads // stored), stored, c.cache_head_dim)
+        return RingRows(
+            ssm=jnp.zeros((mixers, num_rows, c.mamba_d_state,
+                           c.mamba_d_ssm), jnp.float32),
+            conv=jnp.zeros((mixers, num_rows, c.mamba_d_conv - 1,
+                            c.ssm_conv_dim), c.dtype),
+            win_k=jnp.zeros(ring, c.dtype), win_v=jnp.zeros(ring, c.dtype))
     return StateRows(
         ssm=jnp.zeros((c.num_layers, num_rows, c.mamba_n_heads,
                        c.mamba_d_head, c.mamba_d_state), jnp.float32),
@@ -259,7 +383,8 @@ def init_state_rows(config: ModelConfig, num_rows: int) -> StateRows:
 def init_paged_pool(config: ModelConfig, num_blocks: int,
                     block_size: int, kv_dtype: str = "bf16",
                     kv_dtype_per_layer=None,
-                    state_rows: int = 0) -> PagedKVPool:
+                    state_rows: int = 0,
+                    step_tokens: int = 0) -> PagedKVPool:
     """Zeroed pool sized for ``config``. ``kv_dtype`` selects the
     serving precision ladder rung; ``kv_dtype_per_layer`` optionally
     keeps a bf16 prefix of layers full-width (see
@@ -279,7 +404,19 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
 
     A configuration with recurrent state (``config.ssm``) gets
     ``state_rows`` rows of it beside the blocks (``PagedKVPool.rows``);
-    every other configuration's pool is what it was."""
+    every other configuration's pool is what it was.
+
+    A layer pattern (``config.layer_types``) holds block-addressed KV for
+    its "full" layers alone, the head axis stored folded
+    (:func:`stored_kv_heads`), and beside its mixers' state the rings of
+    its "window" layers, sized by ``step_tokens``
+    (:func:`window_capacity`); the quantized ladder has no form for it
+    yet."""
+    if config.pattern and (kv_dtype != "bf16"
+                           or kv_dtype_per_layer is not None):
+        raise LayerPatternUnsupported(
+            "the quantized KV ladder (EngineConfig.kv_dtype int8/fp8)",
+            config.name)
     if config.ssm:
         if state_rows <= 0:
             raise ValueError(
@@ -288,8 +425,13 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
         return init_paged_pool(
             dataclasses.replace(config, mamba_d_ssm=0), num_blocks,
             block_size, kv_dtype, kv_dtype_per_layer)._replace(
-                rows=init_state_rows(config, state_rows))
-    hkv, dh = config.num_kv_heads, config.head_dim
+                rows=init_state_rows(config, state_rows, block_size,
+                                     step_tokens))
+    hkv, dh = config.cache_kv_heads, config.cache_head_dim
+    if config.pattern:
+        # the one forward that writes and reads a folded head axis
+        fold = hkv // stored_kv_heads(hkv)
+        block_size, hkv = block_size * fold, hkv // fold
     # attention layers: two a layer in a shortcut block
     num_layers = config.attn_layers
     payload, n_hi = resolve_kv_dtypes(num_layers, kv_dtype,
@@ -344,12 +486,9 @@ def kv_row_bytes(config: ModelConfig, kv_dtype: str = "bf16",
     """Bytes one token takes in one payload leaf of one layer, AS STORED:
     ``Hkv x head_dim`` of the pool's dtype (a quantized rung stores one
     byte a value), or the latent pool's one padded row."""
-    payload, _ = resolve_kv_dtypes(config.attn_layers, kv_dtype,
-                                   kv_dtype_per_layer)
-    if config.mla:
-        return config.latent_row_dim * jnp.dtype(config.dtype).itemsize
-    return (config.num_kv_heads * config.head_dim
-            * jnp.dtype(payload or config.dtype).itemsize)
+    kv = cache_kinds(config, kv_dtype=kv_dtype,
+                     kv_dtype_per_layer=kv_dtype_per_layer)[0]
+    return kv.unit_bytes // (1 if config.mla else 2)
 
 
 def resolve_block_size(row_bytes: int, max_len: int) -> int:

@@ -37,7 +37,8 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import (LatentCacheUnsupported, ModelConfig,
+from ..models.config import (LatentCacheUnsupported,
+                             LayerPatternUnsupported, ModelConfig,
                              RecurrentStateUnsupported,
                              ResidualStreamUnsupported)
 from ..models.quantize import _quantize_matrix, is_quantized
@@ -66,6 +67,11 @@ def init_lora(config: ModelConfig, key: jax.Array, *, rank: int = 16,
             config.name)
     if config.hc_mult:
         raise ResidualStreamUnsupported("LoRA adapters", config.name)
+    if config.pattern:
+        raise LayerPatternUnsupported(
+            "LoRA adapters (init_lora: the layers' leaves are by segment "
+            "and kind, and the trainer has no backward of the Mamba-1 scan "
+            "over runs)", config.name)
     if config.ssm:
         raise RecurrentStateUnsupported(
             "LoRA adapters (init_lora: the mixer's projections are no "

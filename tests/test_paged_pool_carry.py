@@ -33,7 +33,8 @@ from senweaver_ide_tpu.rollout.sampler import SampleParams
 from senweaver_ide_tpu.rollout import engine as eng
 from senweaver_ide_tpu.rollout.paged_kv import (PagedKVPool, init_paged_pool,
                                                 kv_row_bytes,
-                                                resolve_block_size)
+                                                resolve_block_size,
+                                                stored_kv_heads)
 
 NUM_BLOCKS, BLOCK_SIZE, ROWS, TABLE_WIDTH = 12, 4, 3, 4
 
@@ -447,6 +448,64 @@ def test_hybrid_step_compiled_for_v5e_copies_no_pool_and_no_state(
                         and "copy-start" not in line), line
 
 
+@pytest.mark.parametrize("entries", [48, 192])
+def test_layer_pattern_step_compiled_for_v5e_copies_no_pool_ring_or_state(
+        one_v5e, entries, monkeypatch):
+    """``_paged_fused_step`` at Phi-4-mini-flash-reasoning's widths (all
+    32 layers: three scans of unlike layers; the phi4 cell's shapes: 48
+    rows of 4096 tokens in blocks of 32, 56 state rows, rings of 704
+    positions), compiled for the v5e. The ten cache rows a token are stored
+    folded 5 x 2, which XLA:TPU tiles ``T(2,128)`` without padding (an axis
+    of 10 it pads to 16 and Mosaic cannot cut a block out of it); the full
+    layer's blocks and the window layers' rings both go through
+    ``paged_attention_rows``; the rings are read as blocks by a reshape that
+    moves nothing; no leaf of the pool is copied, and nothing of a gather's
+    size is left."""
+    from senweaver_ide_tpu.ops import paged_attention
+    monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
+    c = _benchmark_config("phi-4-mini-flash-reasoning")
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: tf.init_params(c, jax.random.PRNGKey(0))))
+    bs = resolve_block_size(kv_row_bytes(c), 4096)
+    assert bs == 32
+    rows, width = 48, 4096 // bs
+    pool = on_chip(jax.eval_shape(lambda: init_paged_pool(
+        c, 52 * width, bs, state_rows=56, step_tokens=192)))
+    blocks, ring = "1,6656,160,2,128", "8,56,3520,2,128"
+    assert ",".join(map(str, pool.k.shape)) == blocks
+    assert ",".join(map(str, pool.rows.win_k.shape)) == ring
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_v5e)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = eng._paged_fused_step.lower(
+            params, c, i32(6, entries), i32(rows, width), pool,
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_v5e),
+            i32(rows), SampleParams(temperature=1.0), None).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = compiled.as_text()
+    # the logits and little else: no second pool, ring or state
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < 2 * entries * c.vocab_size * 4)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "%paged_attention_rows." in line]
+    assert len(calls) >= 3, "full, window and cross layers run the kernel"
+    assert f"bf16[{blocks}]{{4,3,2,1,0:T(2,128)(2,1)}}" in text
+    assert f"bf16[{entries * width},{bs},10,128]" not in text
+    for shape in (f"bf16[{blocks}]", f"bf16[{ring}]",
+                  "bf16[8,1232,160,2,128]", "f32[9,56,16,5120]",
+                  "f32[48,16,5120]"):
+        for line in text.splitlines():
+            if f"= {shape}" in line:
+                assert (" copy(" not in line
+                        and "copy-start" not in line), line
+
+
 def _preset_head_shapes():
     """Every (Hq, Hkv, Dh) a full-size dense preset has, once, under the
     first preset's name."""
@@ -474,21 +533,27 @@ def test_every_preset_takes_a_path_that_compiles_for_v5e(one_v5e, model,
     from senweaver_ide_tpu.ops import paged_attention as pa
     monkeypatch.setattr(pa, "on_tpu", lambda: True)
     c = get_config(model)
-    hq, hkv, d = c.num_heads, c.num_kv_heads, c.head_dim
+    # what a token's cache row is: a differential-attention model's 40/20
+    # heads of 64 are 10 rows of 128 (``ModelConfig.cache_kv_heads``), and
+    # a head axis of 10 is stored folded 5 x 2 (``stored_kv_heads``)
+    hq, hkv, d = c.num_heads, c.cache_kv_heads, c.cache_head_dim
     if d % 128:
         assert not tf.reads_pool_in_place(c, None)
         return
     assert tf.reads_pool_in_place(c, None)
     rows, layers = 48, 2
+    fold = hkv // stored_kv_heads(hkv)
     struct = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                        sharding=one_v5e)
 
     def attend(q, k_leaf, v_leaf, tables, seq_row, positions):
-        plan = pa.plan_rows(seq_row, positions, block_size=k_leaf.shape[2],
+        plan = pa.plan_rows(seq_row, positions,
+                            block_size=k_leaf.shape[2] // fold,
                             table_width=tables.shape[1],
                             q_tile=pa.query_tile(hq))
         return pa.paged_attention_rows(q, k_leaf, v_leaf, jnp.int32(1),
-                                       tables, positions, plan)
+                                       tables, positions, plan,
+                                       kv_heads=hkv if fold > 1 else None)
 
     cache = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -498,7 +563,8 @@ def test_every_preset_takes_a_path_that_compiles_for_v5e(one_v5e, model,
                                                          1024))
                  for e in (48, 192)}):
             width = 1024 // bs
-            leaf = struct((layers, 13 * width, bs, hkv, d), jnp.bfloat16)
+            leaf = struct((layers, 13 * width, bs * fold, hkv // fold, d),
+                          jnp.bfloat16)
             text = jax.jit(attend).lower(
                 struct((entries, hq, d), jnp.bfloat16), leaf, leaf,
                 struct((rows, width), jnp.int32),
