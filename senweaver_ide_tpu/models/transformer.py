@@ -1930,14 +1930,24 @@ def forward_paged(
     with_moe_stats: bool = False,  # static: also return MoEStats
     with_mhc_stats: bool = False,  # static: also return the Sinkhorn error
     with_attn_stats: bool = False,  # static: also return the shared reads
+    logit_entries: Optional[jax.Array] = None,  # (S,) int32 — the entries
+                                  # whose logits are wanted (None = all)
 ):
     """Run the model over a paged KV pool: every entry of the flat
     ``(T,)`` token batch is one (sequence, position) pair — a decode
     step or one token of a chunked-prefill segment — reading KV through
     the ``(row, logical_block) -> physical_block`` table. Returns
     ``(logits (T, V) fp32, pool')``. Token t's logits predict its next
-    token, so the engine samples from the rows it flagged (decode
-    entries and final prompt tokens) and ignores the rest.
+    token, and only some entries' are ever read (decode entries and final
+    prompt tokens): ``logit_entries``, an int32 vector of static length
+    ``S``, names them, and the stream's rows at those entries are
+    gathered BEFORE the final norm and the head, so the vocabulary is
+    paid ``S`` times and the result is ``(logits (S, V) fp32, pool')``,
+    row ``i`` what entry ``logit_entries[i]`` would have read in the
+    every-entry form (an index out of range is clamped: a row whose
+    logits are nobody's). Every layer still runs over all ``T`` entries:
+    each writes its cache row. Without the argument every entry pays the
+    head (the draft paths, which read each entry's argmax or none).
 
     ``pool`` is the whole ``PagedKVPool`` pytree (accepted duck-typed
     to avoid a models → rollout import cycle). Its leaves travel in the
@@ -1994,13 +2004,13 @@ def forward_paged(
                 tables=tables, seq_row=seq_row, positions=positions,
                 write_block=write_block, write_off=write_off,
                 use_kernel=use_kernel, adapters=adapters,
-                adapter_ids=adapter_ids)
+                adapter_ids=adapter_ids, logit_entries=logit_entries)
     else:
         out = _forward_paged_impl(
             params, c, tokens, pool=pool, tables=tables,
             seq_row=seq_row, positions=positions, write_block=write_block,
             write_off=write_off, use_kernel=use_kernel, adapters=adapters,
-            adapter_ids=adapter_ids)
+            adapter_ids=adapter_ids, logit_entries=logit_entries)
     logits, pool, moe, err, shared = out
     if shared is None and with_attn_stats:
         shared = jnp.zeros((2,), jnp.int32)
@@ -2054,7 +2064,8 @@ def _write_rows(leaf: jax.Array, layer, block: jax.Array, off: jax.Array,
 
 
 def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
-                           positions, write_block, write_off, use_kernel):
+                           positions, write_block, write_off, use_kernel,
+                           logit_entries=None):
     """``_forward_paged_impl`` for a ``layer_types`` configuration: the
     same ``_pattern_scan`` as the no-cache forward, over the pool's leaves.
 
@@ -2194,15 +2205,25 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
     pool = pool._replace(k=k_leaf, v=v_leaf, rows=type(pool.rows)(
         state, window, win_k.reshape(ring_shape), win_v.reshape(ring_shape)))
     with jax.named_scope("lm_head"):
-        x = _norm(c, x, params, "final_norm")
+        x = _norm(c, _logit_rows(x, logit_entries), params, "final_norm")
         logits = jnp.einsum("bsd,vd->bsv", x,
                             params["embed"])[:, 0].astype(jnp.float32)
     return logits, pool, None, None, shared
 
 
+def _logit_rows(x: jax.Array, entries: Optional[jax.Array]) -> jax.Array:
+    """The stream's rows ``(T, 1, ...)`` at the entries whose logits are
+    wanted (``forward_paged``'s ``logit_entries``; an index past the last
+    entry is clamped), or all of them."""
+    if entries is None:
+        return x
+    return jnp.take(x, entries, axis=0, mode="clip")
+
+
 def _forward_paged_impl(params, c, tokens, *, pool, tables,
                         seq_row, positions, write_block, write_off,
-                        use_kernel, adapters=None, adapter_ids=None):
+                        use_kernel, adapters=None, adapter_ids=None,
+                        logit_entries=None):
     if c.pattern:
         if adapters is not None:
             raise LayerPatternUnsupported("adapter banks in forward_paged",
@@ -2210,7 +2231,8 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         return _forward_paged_pattern(
             params, c, tokens, pool=pool, tables=tables, seq_row=seq_row,
             positions=positions, write_block=write_block,
-            write_off=write_off, use_kernel=use_kernel)
+            write_off=write_off, use_kernel=use_kernel,
+            logit_entries=logit_entries)
     with jax.named_scope("embed"):
         # (T, 1, D), or the stream (T, 1, hc_mult, D)
         x = _stream_open(c, _times(params["embed"][tokens][:, None, :],
@@ -2349,8 +2371,8 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         upd["rows"] = type(pool.rows)(*leaves[-2:])
 
     with jax.named_scope("lm_head"):
-        x = rms_norm(_stream_close(c, x), params["final_norm"],
-                     c.rms_norm_eps)
+        x = rms_norm(_stream_close(c, _logit_rows(x, logit_entries)),
+                     params["final_norm"], c.rms_norm_eps)
         head = params.get("lm_head")
         if head is None:  # tied embeddings
             if "tied_head_q8" in params:

@@ -246,15 +246,27 @@ def _pool_decode_step(params: Params, config: ModelConfig, cur_tok: jax.Array,
 FEED_TAKE, FEED_PUT = 1, 2
 
 
+def _head_entries(entries: int, rows: int, all_logits: bool) -> int:
+    """How many of a fused step's ``entries`` pay the final norm, the
+    head and the sampler: the ``rows`` that can hold a sampler where the
+    step is wider than that, every entry where it is not or where each
+    entry's argmax is read (``all_logits``: a plan with verify entries).
+    ``_paged_fused_step`` builds its program by it and the host counts
+    by it (``engine.step`` attr ``head_entries``)."""
+    return entries if all_logits or entries <= rows else rows
+
+
 @functools.partial(jax.jit,
-                   static_argnames=("config", "sample", "use_kernel"),
+                   static_argnames=("config", "sample", "use_kernel",
+                                    "all_logits"),
                    donate_argnames=("pool",))
 def _paged_fused_step(params: Params, config: ModelConfig,
                       plan: jax.Array, tables: jax.Array,
                       pool: PagedKVPool,
                       key: jax.Array, cur: jax.Array, sample: SampleParams,
                       use_kernel: Optional[bool],
-                      adapters=None, adapter_ids=None):
+                      adapters=None, adapter_ids=None,
+                      all_logits: bool = False):
     """One fused paged step over a flat token batch: decode rows and
     exact-size chunked-prefill segments share the same forward under a
     static token budget (``plan.shape[1]``). ``plan`` is the host's
@@ -275,10 +287,22 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     and one with ``FEED_PUT`` leaves its sample there (decode rows,
     the last entry of a completing prefill). So a step can be launched
     before the last one's tokens are home, and it is the same program
-    when it is not. Sampling happens in-jit for EVERY row; the host
-    keeps only the rows it marked as samplers (decode rows, the final
-    token of a completing prefill), so ONE batched device_get per step
-    covers first tokens and decode tokens alike. With an adapter pool
+    when it is not. Sampling happens in-jit, over the entries somebody
+    reads: the host keeps only its samplers (decode rows, the final
+    token of a completing prefill), which are exactly the entries with
+    ``FEED_PUT``, at most one a row. A step wider than the rows
+    (``T > num_slots``: prefill chunks ride it) finds each row's such
+    entry on the device, from the plan it already has, and the final
+    norm, the head, the sampler and the log-prob run over those
+    ``num_slots`` entries alone (``forward_paged``'s ``logit_entries``;
+    a row with none reads a clamped entry and its sample is dropped);
+    the samples and log-probs are scattered back to their entries'
+    places in ``(T,)`` outputs, so the host reads them as it always
+    did. A step as wide as the rows gathers nothing, and
+    ``all_logits=True`` (static; a plan with verify entries, whose every
+    entry's argmax is read) keeps every entry's head at any width. ONE
+    batched device_get per step covers first tokens and decode tokens
+    alike. With an adapter pool
     attached, ``adapters`` (fixed-shape rank-ladder banks) and
     ``adapter_ids`` (per-rung (T,) slot vectors, null slot 0 for base
     rows) ride every call, so tenant churn reuses the same compiled
@@ -291,17 +315,33 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     key, step_key = jax.random.split(key)
     tokens, seq_row, positions, write_block, write_off, feed = plan
     tokens = jnp.where((feed & FEED_TAKE) > 0, cur[seq_row], tokens)
+    t, rows = tokens.shape[0], cur.shape[0]
+    samplers = None
+    if _head_entries(t, rows, all_logits) < t:
+        # each row's sampler: its one entry that puts, else ``t`` (out of
+        # range: the head reads a clamped entry, the scatters below drop
+        # its sample)
+        samplers = jnp.full((rows,), t, jnp.int32).at[
+            jnp.where((feed & FEED_PUT) > 0, seq_row, rows)].set(
+                jnp.arange(t, dtype=jnp.int32), mode="drop")
     logits, pool, *stats = forward_paged(
         params, config, tokens, pool=pool,
         tables=tables, seq_row=seq_row, positions=positions,
         write_block=write_block, write_off=write_off,
         use_kernel=use_kernel, adapters=adapters, adapter_ids=adapter_ids,
         with_moe_stats=config.num_experts > 0,
-        with_mhc_stats=config.hc_mult > 0, with_attn_stats=True)
+        with_mhc_stats=config.hc_mult > 0, with_attn_stats=True,
+        logit_entries=samplers)
     shared = stats.pop()
     next_tok = sample_token(logits, step_key, temperature=sample.temperature,
                             top_k=sample.top_k, top_p=sample.top_p)
     logp = sampled_logprob(logits, next_tok)
+    if samplers is not None:
+        # row r's sample and log-prob go back to their entry's place
+        next_tok = jnp.zeros((t,), next_tok.dtype).at[samplers].set(
+            next_tok, mode="drop")
+        logp = jnp.zeros((t,), logp.dtype).at[samplers].set(
+            logp, mode="drop")
     # a row has at most one entry that puts: the others' index is out of
     # range and the scatter drops them
     cur = cur.at[jnp.where((feed & FEED_PUT) > 0, seq_row, cur.shape[0])
@@ -1157,6 +1197,14 @@ class RolloutEngine:
         # the last assembled plan's share of that counter, for the
         # engine.step span's attr kv_blocks
         self._kv_blocks_step = 0                # guarded-by: _lock
+        self._head_entries_total = reg.counter(
+            "senweaver_engine_head_entries_total",
+            "Entries of the fused steps that paid the final norm, the "
+            "head and the sampler: a step wider than the rows (prefill "
+            "chunks ride it) pays them for one entry a row, a step as wide "
+            "as the rows or one with verify entries for every entry.")
+        # likewise, for the attr head_entries
+        self._head_entries_step = 0             # guarded-by: _lock
         # Is span tracing on? Asked once at the top of a step and true
         # only inside it: the step's span sites and the request.* spans
         # of phases that end in the step read it instead of asking again.
@@ -3586,6 +3634,9 @@ class RolloutEngine:
         # the tail padding is one more run, of row 0's first block
         self._kv_blocks_step = kv_blocks + (n_real < t)
         self._kv_blocks_total.inc(self._kv_blocks_step)
+        self._head_entries_step = _head_entries(t, self.num_slots,
+                                                bool(spec_rows))
+        self._head_entries_total.inc(self._head_entries_step)
         while len(toks_l) < t:
             toks_l.append(0)
             rows_l.append(0)
@@ -3674,6 +3725,7 @@ class RolloutEngine:
             if st is not None:
                 st.set_attr("entries", len(toks_l))
                 st.set_attr("used", used)
+                st.set_attr("head_entries", self._head_entries_step)
                 st.set_attr("decode_rows", len(decode_rows))
                 st.set_attr("prefill_tokens", sum(j[3] for j in job_rows))
                 st.set_attr("table_width", int(tables.shape[1]))
@@ -3701,8 +3753,8 @@ class RolloutEngine:
                 # none). None of it where the last step still runs.
                 st.set_attr("unqueued_ms", 0.0 if prev is not None else
                             (t_launch - self._fetched_at) * 1_000.0)
-            toks, logps = self._launch_paged(span, vectors, tables,
-                                             adapters, adapter_ids)
+            toks, logps = self._launch_paged(span, vectors, tables, adapters,
+                                             adapter_ids, bool(spec_rows))
             with span("engine.advance"):
                 samplers = self._advance_paged(decode_rows, job_rows)
                 self._publish_fragmentation()
@@ -3780,7 +3832,8 @@ class RolloutEngine:
         return (not self._free_slots()
                 or any(not r.paused for r in self._queue))
 
-    def _launch_paged(self, span, vectors, tables, adapters, adapter_ids):
+    def _launch_paged(self, span, vectors, tables, adapters, adapter_ids,
+                      all_logits):
         # guarded-by: caller
         """Enqueue the fused step on the plan's ``(6, T)`` array and ask
         for its tokens' copy to the host: ONE program for the runtime,
@@ -3791,19 +3844,21 @@ class RolloutEngine:
         wrapper's ``.dispatch`` child) is the wrapper's bookkeeping and
         the two transfer requests. On the v5e host a new shape's
         lowering time follows the summed frame sizes from ``step()``
-        down to this call (PERF.md §6, PR 24, 31 and 36): the unused
-        locals below keep this frame and ``_step_paged``'s at the size
-        the warm-up was last measured at on the chip
-        (``tests/test_engine_launch_path.py`` pins the sum; ROADMAP
-        D10)."""
-        b0 = b1 = None                               # frame ballast
+        down to this call (PERF.md §6, PR 24, 31 and 36): this frame and
+        ``_step_paged``'s keep the size the warm-up was last measured at
+        on the chip (``tests/test_engine_launch_path.py`` pins the sum;
+        ROADMAP D10). The two unused locals that made it up since PR 36
+        went to ``all_logits`` and its place on the stack (PR 40): the
+        next local here or there moves the pin, with a measured warm
+        ``setup_s`` beside it."""
         with span("engine.launch") as sp:
             (next_tok, logp, self.pool, self._key,
              self._cur_tok_dev) = _paged_fused_step(
                 self.params, self.config, vectors, tables, self.pool,
                 self._key, self._cur_tok_dev, self.sample,
                 self._use_paged_kernel,
-                adapters=adapters, adapter_ids=adapter_ids)
+                adapters=adapters, adapter_ids=adapter_ids,
+                all_logits=all_logits)
             next_tok.copy_to_host_async()
             logp.copy_to_host_async()
             if sp is not None:

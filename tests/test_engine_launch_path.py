@@ -113,16 +113,21 @@ def test_a_steps_tokens_are_sampled_with_the_second_half_of_the_split(
         model, monkeypatch):
     """One step by hand, in the parent's form: the host splits, the step
     key samples. The engine's first step gives the same tokens and
-    log-probs, so a seed's tokens are what they were."""
+    log-probs. That step is a WIDE one (a 5-token prompt in 16 entries on
+    4 rows), so since PR 40 the hand's sampler runs over the rows'
+    sampler entries as the step's does (one a row, the clamped last entry
+    for a row with none): the noise is drawn at ``(num_slots, V)``, and
+    the pin moved with it (``tests/test_head_entries.py`` holds the
+    logits to the every-entry head's)."""
     eng, ref = make_engine(model), make_engine(model)
     for e in (eng, ref):
         e.submit(PROMPTS[1], max_new_tokens=4)
     seen = {}
     launch = engine_mod.RolloutEngine._launch_paged
 
-    def spy(self, span, vectors, tables, adapters, adapter_ids):
+    def spy(self, span, vectors, tables, *rest):
         seen["plan"], seen["tables"] = vectors, tables
-        out = launch(self, span, vectors, tables, adapters, adapter_ids)
+        out = launch(self, span, vectors, tables, *rest)
         seen["out"] = [np.asarray(a) for a in out]
         return out
 
@@ -132,17 +137,26 @@ def test_a_steps_tokens_are_sampled_with_the_second_half_of_the_split(
     next_key, step_key = jax.random.split(jax.random.PRNGKey(SEED))
     tokens, seq_row, positions, write_block, write_off, feed = seen["plan"]
     assert not (feed & engine_mod.FEED_TAKE).any()      # host-known tokens
+    n = tokens.shape[0]
+    assert n > eng.num_slots
+    (put,) = np.flatnonzero(feed & engine_mod.FEED_PUT)
+    samplers = np.full((eng.num_slots,), n, np.int32)
+    samplers[seq_row[put]] = put
     logits, _pool, *_ = engine_mod.forward_paged(
         params, config, tokens, pool=ref.pool, tables=seen["tables"],
         seq_row=seq_row, positions=positions, write_block=write_block,
         write_off=write_off, use_kernel=ref._use_paged_kernel)
+    logits = logits[np.minimum(samplers, n - 1)]
     tok = engine_mod.sample_token(logits, step_key, temperature=1.0,
                                   top_k=0, top_p=1.0)
     logp = engine_mod.sampled_logprob(logits, tok)
-    n = tokens.shape[0]
-    assert np.array_equal(seen["out"][0][:n], np.asarray(tok))
-    np.testing.assert_allclose(seen["out"][1][:n], np.asarray(logp),
+    row = seq_row[put]
+    assert seen["out"][0][put] == int(tok[row])
+    np.testing.assert_allclose(seen["out"][1][put], float(logp[row]),
                                rtol=1e-5, atol=1e-6)
+    rest = np.delete(np.arange(n), put)
+    assert not seen["out"][0][rest].any() and not seen["out"][1][rest].any()
+    assert eng.result(0) == [int(tok[row])]
     assert np.array_equal(np.asarray(eng._key), np.asarray(next_key))
 
 

@@ -425,7 +425,10 @@ def test_step_reports_its_share_of_the_routing_in_the_one_fetch(model):
 # (zeros on this gather path) joined on behind the step's tokens
 # (9bc362fd790508c7, d7ca6bc275e8c59f, ae41dbfc9a84c662, cef23a44eb88e94f
 # before; ``forward_paged``'s own jaxprs, tests/test_falcon_h1.py, are
-# what they were).
+# what they were). Since PR 40 a step wider than its rows (12 entries on 3
+# here) pays the head for its samplers alone: the pin is its every-entry
+# form, ``all_logits=True``, which is the program the parent ran
+# (tests/test_head_entries.py: a narrow step is that form too).
 PARENT_STEP = {"tiny-test": "8c64e81a4b54a550",
                "tiny-glm-moe-test": "55e468c6bcc5911d",
                "tiny-xing-mhc-test": "2affdb048e6dc77d",
@@ -440,7 +443,8 @@ def _step_digest(name):
     tables = jnp.asarray(np.arange(3 * 6).reshape(3, 6) % 16, jnp.int32)
     text = jax.make_jaxpr(
         lambda p, pool, key, cur: engine_mod._paged_fused_step._fn(
-            p, c, plan, tables, pool, key, cur, SAMPLED, False))(
+            p, c, plan, tables, pool, key, cur, SAMPLED, False,
+            all_logits=True))(
                 params, pool, jax.random.PRNGKey(1),
                 jnp.zeros((3,), jnp.int32))
     return hashlib.sha256(str(text).encode()).hexdigest()[:16]

@@ -361,12 +361,16 @@ def test_kernel_step_compiled_for_v5e_copies_no_pool(
     text = compiled.as_text()
     leaf = ",".join(map(str, pool.k.shape))
     layer_bytes = pool.k.size * pool.k.dtype.itemsize // pool.k.shape[0]
-    # the sampler's (entries, vocabulary) f32 logits are the step's
-    # largest temporary; a layer's slice of one leaf is 27 MB at qwen's
-    # widths
-    logits_bytes = entries * c.vocab_size * 4
+    # the sampler's (head entries, vocabulary) f32 logits are the step's
+    # largest temporary, and a step wider than the rows pays the head for
+    # the rows' samplers alone (PR 40: no value is (192, vocabulary)); a
+    # layer's slice of one leaf is 27 MB at qwen's widths
+    logits_bytes = min(entries, rows) * c.vocab_size * 4
     assert (compiled.memory_analysis().temp_size_in_bytes
             < logits_bytes + layer_bytes / 2)
+    if entries > rows:
+        assert f"[{entries},{c.vocab_size}]" not in text
+        assert f"[{rows},{c.vocab_size}]" in text
     name = "paged_latent_attention_rows" if latent else "paged_attention_rows"
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and f"%{name}." in line]
@@ -435,6 +439,8 @@ def test_hybrid_step_compiled_for_v5e_copies_no_pool_and_no_state(
     slab_bytes = rows * 32 * 128 * 256 * 4
     assert (compiled.memory_analysis().temp_size_in_bytes
             < entries * c.vocab_size * 4 + slab_bytes / 2)
+    if entries > rows:      # the head runs over the rows' samplers (PR 40)
+        assert f"[{entries},{c.vocab_size}]" not in text
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "%paged_attention_rows." in line]
     assert calls, "the kernel is not in the compiled step"
@@ -492,6 +498,8 @@ def test_layer_pattern_step_compiled_for_v5e_copies_no_pool_ring_or_state(
     # the logits and little else: no second pool, ring or state
     assert (compiled.memory_analysis().temp_size_in_bytes
             < 2 * entries * c.vocab_size * 4)
+    if entries > rows:      # the head runs over the rows' samplers (PR 40)
+        assert f"[{entries},{c.vocab_size}]" not in text
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "%paged_attention_rows." in line]
     assert len(calls) >= 3, "full, window and cross layers run the kernel"
