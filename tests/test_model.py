@@ -1,5 +1,7 @@
 """Model runtime tests: forward, KV-cache parity, sampling, sharded mesh."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,7 +10,7 @@ import pytest
 from senweaver_ide_tpu.models import (count_params, forward, get_config,
                                       init_kv_cache, init_params, tiny_test)
 from senweaver_ide_tpu.ops import (apply_rope, apply_top_k, apply_top_p,
-                                   rope_cos_sin, sample_token)
+                                   rope_cos_sin, sample_token, sampling)
 from senweaver_ide_tpu.parallel import (MeshConfig, data_sharding, make_mesh,
                                         param_specs, shard_params)
 from senweaver_ide_tpu.rollout import SampleParams, generate, generate_scan
@@ -128,6 +130,96 @@ def test_top_p_cutoff_matches_exact():
     # Nucleus wider than the cutoff clips to exactly the cutoff.
     clipped = np.asarray(apply_top_p(logits, 0.95, cutoff=64)) > -1e29
     assert (clipped.sum(axis=-1) == 64).all()
+
+
+def _top_k_widths(fn, *args):
+    """Last-axis widths of every ``top_k`` / ``sort`` operand in fn's
+    jaxpr, nested jaxprs included."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name in ("top_k", "sort"):
+                found.append((eqn.primitive.name,
+                              eqn.invars[0].aval.shape[-1]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _top_p_rows(kind, vocab):
+    rng = np.random.default_rng(vocab)
+    rows = rng.standard_normal((3, vocab)) * 3
+    if kind == "bf16":          # thousands of equal neighbours
+        return jnp.asarray(rows, jnp.bfloat16).astype(jnp.float32) / 0.8
+    if kind == "one_hot":
+        return jnp.zeros((3, vocab), jnp.float32).at[:, vocab // 3].set(40.0)
+    if kind == "flat":          # every probability equal
+        return jnp.full((3, vocab), 0.25, jnp.float32)
+    assert kind == "top_k_masked"
+    return apply_top_k(jnp.asarray(rows, jnp.float32), 40)
+
+
+# (vocabulary, cutoff, groups, the widths ``lax.top_k`` sees): the default
+# groups at real vocabularies (50,257 is divided by no group: the padding;
+# 32,000 skips the cut by 128 and takes the one by 16; 1,000 keeps the
+# single ``lax.top_k``), and the helper forced to cut at 1,000: once, once
+# with padding, and twice.
+_TOP_P_SHAPES = [(151936, 128, None, [1187, 1024, 2048]),
+                 (50257, 128, None, [393, 1024, 2048]),
+                 (32000, 128, None, [2000, 2048]),
+                 (1000, 128, None, [1000]),
+                 (1000, 16, (8,), [125, 128]),
+                 (1000, 16, (7,), [143, 112]),
+                 (1000, 4, (50, 5), [20, 40, 20])]
+
+
+@pytest.mark.parametrize("p", [0.3, 0.8, 0.95])
+@pytest.mark.parametrize("kind", ["bf16", "one_hot", "flat", "top_k_masked"])
+@pytest.mark.parametrize("vocab,cutoff,groups,widths", _TOP_P_SHAPES)
+def test_top_p_two_stage_matches_single_top_k(monkeypatch, vocab, cutoff,
+                                              groups, widths, kind, p):
+    """The staged selection returns ``lax.top_k``'s values bit for bit,
+    so the nucleus mask and a key's tokens are those of the nucleus cut
+    with ONE ``lax.top_k`` over the whole vocabulary."""
+    if groups is not None:
+        monkeypatch.setattr(
+            sampling, "_top_values",
+            functools.partial(sampling._top_values, groups=groups))
+    logits = _top_p_rows(kind, vocab)
+    probs = jax.nn.softmax(logits, axis=-1)
+    key = jax.random.PRNGKey(vocab)
+
+    def cut():
+        return (sampling._top_values(probs, cutoff),
+                apply_top_p(logits, p, cutoff=cutoff),
+                sample_token(logits, key, top_p=p, top_p_cutoff=cutoff))
+
+    assert _top_k_widths(lambda x: sampling._top_values(x, cutoff),
+                         probs) == [("top_k", w) for w in widths]
+    staged = cut()
+    monkeypatch.setattr(sampling, "_top_values",
+                        lambda x, k: jax.lax.top_k(x, k)[0])
+    for got, want in zip(staged, cut()):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("top_p", [0.95, 1.0])
+def test_sampler_program_sorts_no_vocabulary(top_p):
+    """The program a chat step samples with (48 rows of Qwen's 151,936
+    columns, top-p 0.95) holds no ``top_k`` or ``sort`` over the
+    vocabulary; without top-p (the rollout cells) it holds no ``top_k``
+    at all."""
+    logits = jax.ShapeDtypeStruct((48, 151936), jnp.float32)
+    found = _top_k_widths(
+        lambda x, key: sample_token(x, key, temperature=0.8, top_p=top_p),
+        logits, jax.random.PRNGKey(0))
+    if top_p < 1.0:
+        assert found and all(width < 151936 // 8 for _, width in found)
+    else:
+        assert found == []
 
 
 def test_top_p_zero_is_disabled():
