@@ -43,12 +43,10 @@ import numpy as np
 from senweaver_ide_tpu.models import (forward, get_config, init_params)
 from senweaver_ide_tpu.models.transformer import (count_params,
                                                   dequantize_pool_kv,
-                                                  init_kv_cache,
                                                   quantize_pool_kv)
 from senweaver_ide_tpu.obs.runtime_profile import get_profiler
 from senweaver_ide_tpu.ops.attention import attention
 from senweaver_ide_tpu.ops.flash_attention import flash_attention
-from senweaver_ide_tpu.ops.flash_decode import flash_decode
 from senweaver_ide_tpu.ops.paged_attention import (
     paged_attention_rows, paged_flash_decode, paged_latent_attention_rows,
     plan_rows, query_tile)
@@ -345,7 +343,7 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
     ``s`` positions."""
     hq, hkv, d = config.num_heads, config.num_kv_heads, config.head_dim
     dtype = jnp.float32 if interpret else jnp.bfloat16
-    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 7)
     out = {}
 
     def timed(name, fn, *args):
@@ -395,22 +393,6 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
         check(rel <= KERNEL_TOL, f"flash_attention d{name} off by {rel} "
                                  f"of its largest entry")
 
-    # flash_decode: one query per sequence, ragged fill levels
-    b = 8
-    q1 = jax.random.normal(ks[4], (b, 1, hq, d), dtype)
-    kc = jax.random.normal(ks[5], (b, s, hkv, d), dtype)
-    vc = jax.random.normal(ks[6], (b, s, hkv, d), dtype)
-    lengths = jnp.asarray(
-        np.linspace(1, s, b).round().astype(np.int32))
-    fd_jit = jax.jit(functools.partial(flash_decode, interpret=interpret))
-    if not interpret:
-        lowered_with_kernel(fd_jit.lower(q1, kc, vc, lengths), "_fd_kernel")
-    valid = jnp.arange(s)[None, :] < lengths[:, None]
-    err = max_err(timed("flash_decode", fd_jit, q1, kc, vc, lengths),
-                  attention(q1, kc, vc, kv_mask=valid, causal=False))
-    out["flash_decode_max_err"] = err
-    check(err <= KERNEL_TOL, f"flash_decode off by {err}")
-
     # paged_flash_decode at blocks of 16: one query a row through
     # paged_attention_rows, and the quantized pools with fused dequant
     bs = 16
@@ -420,9 +402,9 @@ def kernel_checks(config, s: int, interpret: bool, seed: int) -> dict:
     tables = jnp.asarray(np.stack([rng.permutation(nb)[:mb]
                                    for _ in range(t)]).astype(np.int32))
     plens = jnp.asarray(np.linspace(1, s, t).round().astype(np.int32))
-    qp = jax.random.normal(ks[7], (t, hq, d), dtype)
-    k_pool = kc[:2].reshape(nb, bs, hkv, d)
-    v_pool = vc[:2].reshape(nb, bs, hkv, d)
+    qp = jax.random.normal(ks[4], (t, hq, d), dtype)
+    k_pool = jax.random.normal(ks[5], (nb, bs, hkv, d), dtype)
+    v_pool = jax.random.normal(ks[6], (nb, bs, hkv, d), dtype)
     pvalid = jnp.arange(s)[None, :] < plens[:, None]
 
     def gathered(kp, vp):
@@ -613,20 +595,6 @@ def paged_rows_checks(hq: int, hkv: int, d: int, tag: str, interpret: bool,
     return out
 
 
-def flash_config_checks(params, config, sz: Sizes, interpret: bool) -> None:
-    """``decode_attn_impl="flash"`` gives way to einsum without a word
-    when the cache is not tileable (transformer.py ``flash_ok``). At the
-    shape used here it must hold: the kernel's name is in the lowered
-    decode step."""
-    if interpret:
-        return      # an interpreted kernel leaves no custom call to find
-    cache = init_kv_cache(config, sz.num_slots, sz.kernel_seq)
-    step = jax.jit(lambda p, t, c: forward(p, config, t, cache=c)[0])
-    lowered_with_kernel(
-        step.lower(params, jnp.zeros((sz.num_slots, 1), jnp.int32), cache),
-        "_fd_kernel")
-
-
 def fused_step_lowering(engine):
     """The engine's own jitted step, lowered with the engine's own state
     and flags (nothing is run or donated)."""
@@ -767,9 +735,19 @@ def main() -> None:
         trajs = trajectories(rows)
 
     with report.phase("agree") as rec:
-        greedy = RolloutEngine(params, config, num_slots=sz.num_slots,
-                               max_len=sz.max_len, seed=args.seed,
-                               sample=SampleParams(temperature=0.0))
+        # On the chip the engine must choose the kernel by itself
+        # (paged_kernel=None: an unquantized dense pool on a TPU is read
+        # by paged_attention_rows, as in every engine here). The
+        # rehearsal forces it, so the interpreted kernel rides the fused
+        # step too.
+        greedy = RolloutEngine(
+            params, config, num_slots=sz.num_slots, max_len=sz.max_len,
+            seed=args.seed, sample=SampleParams(temperature=0.0),
+            engine_config=EngineConfig(
+                paged_kernel=True if interpret else None))
+        if not interpret:
+            lowered_with_kernel(fused_step_lowering(greedy),
+                                "paged_attention_rows")
         rec.update(greedy_agreement(
             greedy, scorer, params,
             draw_prompts(rng, sz.score_rows, sz.prompt_lo,
@@ -813,34 +791,11 @@ def main() -> None:
         # the engine now answers from the PUBLISHED weights
         rec["agreement"] = agreement(scorer, served, got[:sz.score_rows],
                                      sz.logp_tol)
-        del engine, state
+        del engine, state, served, params
 
     with report.phase("kernels") as rec:
         rec.update(kernel_checks(config, sz.kernel_seq, interpret,
                                  args.seed))
-
-    with report.phase("flash_engine") as rec:
-        flash_config = dataclasses.replace(config, decode_attn_impl="flash")
-        flash_config_checks(served, flash_config, sz, interpret)
-        # On the chip the engine must choose the kernel by itself
-        # (paged_kernel=None: an unquantized dense pool on a TPU is read
-        # by paged_attention_rows, as in every engine above). The
-        # rehearsal forces it, so the interpreted kernel rides the fused
-        # step too.
-        flash = RolloutEngine(
-            served, flash_config, num_slots=sz.num_slots,
-            max_len=sz.max_len, seed=args.seed,
-            sample=SampleParams(temperature=0.0),
-            engine_config=EngineConfig(
-                paged_kernel=True if interpret else None))
-        if not interpret:
-            lowered_with_kernel(fused_step_lowering(flash),
-                                "paged_attention_rows")
-        rec.update(greedy_agreement(
-            flash, scorer, served,
-            draw_prompts(rng, sz.score_rows, sz.prompt_lo,
-                         sz.score_prompt_hi, vocab), sz, vocab))
-        del flash, served, params
 
     if jax.device_count() >= 4:
         gc.collect()

@@ -17,85 +17,186 @@ from typing import Optional, Tuple, Union
 import jax.numpy as jnp
 
 
-class LatentCacheUnsupported(NotImplementedError):
-    """A mechanism that has no form yet for a latent-attention (MLA)
-    configuration, whose cache row is one ``[c_kv | k_rope]`` vector a token
-    and not (k, v) by kv-head. Raised where the mechanism is asked for —
-    engine construction, ``init_kv_cache``, ``enable_speculation`` — and
-    never replaced by a silent fallback. ``mechanism`` names it."""
+class FormUnsupported(NotImplementedError):
+    """A mechanism has no form yet for one form of model: raised where the
+    mechanism is asked for (``refuse``), never replaced by a silent
+    fallback. ``mechanism`` names it. A subclass is one form: ``clause``
+    ends the message, ``has`` tells whether a configuration is of it."""
+
+    clause = ""
+    has = staticmethod(lambda c: False)
 
     def __init__(self, mechanism: str, config_name: str):
-        super().__init__(
-            f"{mechanism} is not implemented for the latent-attention "
-            f"configuration {config_name!r}: its paged cache holds one "
-            f"latent vector a token, not (k, v) by kv-head")
+        super().__init__(f"{mechanism} is not implemented for "
+                         + self.clause.format(name=config_name))
         self.mechanism = mechanism
 
 
-class ResidualStreamUnsupported(NotImplementedError):
-    """A mechanism that has no form yet for a configuration whose residual
-    path is ``hc_mult`` streams wide (manifold-constrained
-    hyper-connections, ``models.transformer._residual``). Raised where the
-    mechanism is asked for, never replaced by the plain residual add.
-    ``mechanism`` names it."""
-
-    def __init__(self, mechanism: str, config_name: str):
-        super().__init__(
-            f"{mechanism} is not implemented for the multi-stream residual "
-            f"(hc_mult > 0) of configuration {config_name!r}")
-        self.mechanism = mechanism
+class LatentCacheUnsupported(FormUnsupported):
+    """Latent attention (MLA): the cache row is one ``[c_kv | k_rope]``
+    vector a token."""
+    clause = ("the latent-attention configuration {name!r}: its paged "
+              "cache holds one latent vector a token, not (k, v) by kv-head")
+    has = staticmethod(lambda c: c.mla)
 
 
-class RecurrentStateUnsupported(NotImplementedError):
-    """A mechanism that has no form yet for a configuration whose layers
-    hold recurrent state (a state-space mixer, ``mamba_d_ssm > 0``): one
-    fixed-size array a row, overwritten every token, that has no position
-    to re-read and cannot be shared by refcount, only copied
-    (``rollout.paged_kv.StateRows``). Raised where the mechanism is asked
-    for, never replaced by a path that would drop or reuse the state.
-    ``mechanism`` names it."""
-
-    def __init__(self, mechanism: str, config_name: str):
-        super().__init__(
-            f"{mechanism} is not implemented for the recurrent state "
-            f"(mamba_d_ssm > 0) of configuration {config_name!r}: a row's "
-            f"state is overwritten every token and has no snapshot there")
-        self.mechanism = mechanism
+class ResidualStreamUnsupported(FormUnsupported):
+    """A residual path ``hc_mult`` streams wide (``transformer._residual``):
+    never the plain residual add."""
+    clause = ("the multi-stream residual (hc_mult > 0) of configuration "
+              "{name!r}")
+    has = staticmethod(lambda c: c.hc_mult > 0)
 
 
-class ExpertShareUnsupported(NotImplementedError):
-    """A mechanism that has no form yet for a configuration whose expert
-    layer holds a SHARE of the experts its router addresses
-    (``moe_routed_experts`` > ``num_experts``), has identity experts
-    (``moe_zero_experts``) or sits on a shortcut beside a double block
-    (``shortcut_moe``). Raised where the mechanism is asked for, never
-    replaced by a layer that would drop the absent experts' pairs for
-    capacity or compute the identity experts as banks. ``mechanism``
-    names it."""
-
-    def __init__(self, mechanism: str, config_name: str):
-        super().__init__(
-            f"{mechanism} is not implemented for the expert share / "
-            f"identity experts / shortcut block of configuration "
-            f"{config_name!r}")
-        self.mechanism = mechanism
+class RecurrentStateUnsupported(FormUnsupported):
+    """Recurrent state in every block (``paged_kv.StateRows``): it has no
+    position to re-read and is copied, never shared by refcount. A layer
+    pattern's mixers are ``LayerPatternUnsupported``'s."""
+    clause = ("the recurrent state (mamba_d_ssm > 0) of configuration "
+              "{name!r}: a row's state is overwritten every token and has "
+              "no snapshot there")
+    has = staticmethod(lambda c: c.ssm and not c.pattern)
 
 
-class LayerPatternUnsupported(NotImplementedError):
-    """A mechanism that has no form yet for a configuration whose layers
-    are of unlike kinds in a fixed pattern (``layer_types``: state-space
-    mixers, window and full attention, gated memory units, cross
-    attention), each kind with a cache of its own or none. Raised where
-    the mechanism is asked for, never replaced by a path that would treat
-    the layers as one kind or hold a window layer's cache at full length.
-    ``mechanism`` names it."""
+class ExpertShareUnsupported(FormUnsupported):
+    """An expert layer that holds a share of the experts its router
+    addresses, has identity experts, or sits on a shortcut beside a double
+    block: never a layer that would drop the absent experts' pairs."""
+    clause = ("the expert share / identity experts / shortcut block of "
+              "configuration {name!r}")
+    has = staticmethod(lambda c: c.expert_share or c.shortcut_moe)
 
-    def __init__(self, mechanism: str, config_name: str):
-        super().__init__(
-            f"{mechanism} is not implemented for the layer pattern "
-            f"(layer_types) of configuration {config_name!r}: its layers "
-            f"are of unlike kinds, each with a cache of its own or none")
-        self.mechanism = mechanism
+
+class LayerPatternUnsupported(FormUnsupported):
+    """Layers of unlike kinds in a fixed pattern (``layer_types``), each
+    kind with a cache of its own or none: never a path that would treat
+    them as one kind or hold a window layer's cache at full length."""
+    clause = ("the layer pattern (layer_types) of configuration {name!r}: "
+              "its layers are of unlike kinds, each with a cache of its own "
+              "or none")
+    has = staticmethod(lambda c: c.pattern)
+
+
+_Latent, _Streams, _State, _Share, _Pattern = (
+    LatentCacheUnsupported, ResidualStreamUnsupported,
+    RecurrentStateUnsupported, ExpertShareUnsupported,
+    LayerPatternUnsupported)
+_SLOTS = "the slot KVCache layout (EngineConfig.kv_layout='slots')"
+_LADDER = "the quantized KV ladder (EngineConfig.kv_dtype int8/fp8)"
+_POOL = "the multi-LoRA adapter pool"
+
+
+def _row(text: str, *forms) -> dict:
+    """One text for ``forms``, asked in that order."""
+    return dict.fromkeys(forms, text)
+
+
+# Which form of model lacks which mechanism: mechanism -> {form: what the
+# error calls the mechanism}, the forms in the order the mechanism's entry
+# point asks them (a configuration of two forms raises the first; a text
+# given after a ``_row`` replaces that form's and keeps its place). Every
+# cell raises (``refuse``); a pair that is not here is not refused.
+# docs/serving.md shows this table as a matrix.
+UNSUPPORTED = {
+    # RolloutEngine(...): these forms serve from the paged pool on one chip
+    # alone (a latent pool; the streams through forward_paged; state and
+    # rings in row-addressed leaves). What the engine would answer with the
+    # slot layout, which has no place for their caches and would hold a
+    # window layer's at full length, is refused at construction instead.
+    "RolloutEngine(kv_layout='slots')": {
+        **_row(_SLOTS, _Latent, _Streams, _Pattern, _State),
+        _Streams: "the slot KVCache path"},
+    "RolloutEngine(config.kv_quant)": {
+        **_row("the slot int8 cache (kv_quant)", _Latent, _Streams,
+               _Pattern, _State),
+        _Streams: "the slot KVCache path"},
+    "RolloutEngine(kv_dtype=)": _row(_LADDER, _Pattern),
+    "RolloutEngine(config.sliding_window)": {
+        **_row("the sliding-window ring cache", _Latent, _Streams,
+               _Pattern, _State),
+        _Streams: "the slot KVCache path",
+        _Pattern: "the sliding-window ring cache of the slot layout "
+                  "(sliding_window)"},
+    "RolloutEngine(mesh=)": {
+        **_row("a mesh (mesh=...)", _Latent, _Streams, _Pattern, _State),
+        _Latent: "tensor-parallel KV sharding (mesh=...)"},
+    "RolloutEngine(adapter_pool=)": _row(_POOL, _Latent, _Streams, _Pattern,
+                                         _State),
+    # a rejected draft cannot roll a state back; the draft's form counts
+    "enable_speculation": _row("fused draft/verify speculation", _Pattern,
+                               _State, _Latent),
+    # a prefix's, a branch's, a checkpoint's blocks are shared, grafted or
+    # shipped by block: state and rings have no snapshot counterpart yet
+    "register_prefix": {
+        **_row("registered prefixes (register_prefix: a prefix's blocks are "
+               "grafted, its state has no snapshot)", _Latent, _Pattern,
+               _State),
+        _Latent: "registered prefixes (their prefill runs over the slot "
+                 "KVCache)"},
+    "export_prefix": _row("prefix export (export_prefix)", _Pattern, _State),
+    "import_prefix": _row(
+        "prefix import (import_prefix: the peer's KV comes without the state "
+        "behind it)", _Pattern, _State),
+    "fork_request": _row(
+        "fork_request (a branch shares KV blocks by refcount; the state has "
+        "no fork yet)", _Pattern, _State),
+    "checkpoint_request": _row(
+        "request checkpoints and migration (checkpoint_request: a "
+        "DecodeCheckpoint holds KV blocks, no state)", _Pattern, _State),
+    "restore_request": _row(
+        "request checkpoints and migration (restore_request)", _Pattern,
+        _State),
+    "init_kv_cache": _row("the slot KVCache layout", _Latent, _Pattern,
+                          _State),
+    "forward(cache=)": _row("forward(cache=...) over the slot KVCache",
+                            _Pattern, _Streams, _State, _Latent),
+    # the share over an 'ep' axis needs its exchange; parallel/expert.py
+    # drops pairs for capacity and knows no identity expert
+    "forward(mesh=)": _row("forward(mesh=...)", _Pattern, _Streams, _State,
+                           _Share),
+    "forward(attn_impl=)": _row(
+        "attn_impl={c.attn_impl!r} / sliding_window={c.sliding_window} in "
+        "the no-cache forward", _Latent),
+    "forward_paged(adapters=)": {
+        **_row("adapter banks in forward_paged", _Pattern, _State, _Latent,
+               _Streams),
+        _Latent: "adapter banks / a quantized pool in forward_paged"},
+    "forward_paged(pool=)": {
+        _Pattern: "a quantized pool / a pool without row-addressed state "
+                  "in forward_paged",
+        _Latent: "adapter banks / a quantized pool in forward_paged"},
+    "init_paged_pool(kv_dtype=)": _row(_LADDER, _Pattern, _Latent),
+    "init_lora": {
+        _Latent: "LoRA on the latent projections (targets are "
+                 "wq/wk/wv/wo)",
+        _Streams: "LoRA adapters",
+        _Pattern: "LoRA adapters (init_lora: the layers' leaves are by "
+                  "segment and kind, and the trainer has no backward of "
+                  "the Mamba-1 scan over runs)",
+        _State: "LoRA adapters (init_lora: the mixer's projections are no "
+                "target, and the trainer has no backward of the chunked "
+                "scan)"},
+    "AdapterPool": {
+        **_row(_POOL, _Latent, _Streams, _Pattern, _State),
+        _Latent: _POOL + " (its targets are wq/wk/wv/wo)"},
+    # the checkpoint's names for the maps' and the mixer's leaves and for a
+    # double block's sublayers are not known here, nor which of its heads
+    # form a differential set, nor which experts a chip's share of a
+    # checkpoint would be cut from
+    "load_hf_params": _row("the HF loader", _Streams, _Pattern, _State,
+                           _Share),
+    "export_hf_params": _row("the HF exporter", _Streams, _Pattern, _State,
+                             _Share),
+}
+
+
+def refuse(config, mechanism: str, also=None) -> None:
+    """Raise for the first form of ``UNSUPPORTED[mechanism]`` that
+    ``config`` is of (or ``also``, a second configuration the mechanism
+    would run: a draft); return if it is of none."""
+    for form, text in UNSUPPORTED[mechanism].items():
+        if form.has(config) or (also is not None and form.has(also)):
+            raise form(text.format(c=config), config.name)
 
 
 # The kinds of layer a ``layer_types`` pattern may name, each ``x + mix(
@@ -187,20 +288,7 @@ class ModelConfig:
     #               with an sp axis and S divisible by its size.
     #   "ulysses" — Ulysses all-to-all head/sequence swap over 'sp'; head
     #               counts must divide by the sp axis size.
-    # The KV-cache (decode) path has its own selection below.
     attn_impl: str = "einsum"
-    # Attention implementation for the KV-cache single-token decode path:
-    #   "einsum" — ops/attention.py over the whole cache (materializes
-    #              the (B, Hkv, rep, 1, Smax) fp32 scores per step).
-    #   "flash"  — ops/flash_decode.py: streamed KV blocks with online
-    #              softmax and per-slot length skipping; interpret-mode
-    #              on non-TPU backends. Applies only when s == 1 and no
-    #              extra attention mask is in play (prefill keeps einsum).
-    decode_attn_impl: str = "einsum"
-    # lax.scan unroll factor for the layer loop. Decode steps are tiny
-    # programs; TPU loop overhead per scan iteration is material at
-    # sq=1, and unrolling trades compile time for it. 1 = no unroll.
-    scan_unroll: int = 1
     # Rematerialize layer activations in the no-cache (training) path:
     # jax.checkpoint around each scanned layer, so backward recomputes
     # activations instead of saving L layers of them — the HBM-for-FLOPs

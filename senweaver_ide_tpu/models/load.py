@@ -31,9 +31,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from .config import (ExpertShareUnsupported, LayerPatternUnsupported,
-                     ModelConfig,
-                     RecurrentStateUnsupported, ResidualStreamUnsupported)
+from .config import ModelConfig, refuse
 from .transformer import Params
 
 __all__ = ["load_hf_params", "export_hf_params", "available_hf_keys"]
@@ -98,19 +96,7 @@ def load_hf_params(model_dir: str, config: ModelConfig, *,
     import jax.numpy as jnp
 
     c = config
-    if c.hc_mult:
-        # the checkpoint's names for the maps' leaves are not known here
-        raise ResidualStreamUnsupported("the HF loader", c.name)
-    if c.pattern:
-        # nor which of the checkpoint's heads form a differential set
-        raise LayerPatternUnsupported("the HF loader", c.name)
-    if c.ssm:
-        # nor are its names for the mixer's leaves
-        raise RecurrentStateUnsupported("the HF loader", c.name)
-    if c.shortcut_moe or c.expert_share:
-        # nor for a double block's sublayers, or which experts a chip's
-        # share of a checkpoint would be cut from
-        raise ExpertShareUnsupported("the HF loader", c.name)
+    refuse(c, "load_hf_params")
     dtype = dtype or c.dtype
     raw = _load_raw(model_dir)
     D, F, L, V = c.hidden_size, c.intermediate_size, c.num_layers, c.vocab_size
@@ -204,14 +190,7 @@ def export_hf_params(params: Params, config: ModelConfig,
 
     from .quantize import is_quantized
 
-    if config.hc_mult:
-        raise ResidualStreamUnsupported("the HF exporter", config.name)
-    if config.pattern:
-        raise LayerPatternUnsupported("the HF exporter", config.name)
-    if config.ssm:
-        raise RecurrentStateUnsupported("the HF exporter", config.name)
-    if config.shortcut_moe or config.expert_share:
-        raise ExpertShareUnsupported("the HF exporter", config.name)
+    refuse(config, "export_hf_params")
     if is_quantized(params):
         # transposing the +/-127 codes without their scales would write a
         # garbage checkpoint that loads cleanly elsewhere

@@ -24,6 +24,7 @@ Architectures covered: Qwen2.5-Coder (GQA + QKV bias, tied embeddings at
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -37,11 +38,7 @@ from ..ops.attention import NEG_INF, attention
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
 from ..ops import ssm as ssm_ops
-from .config import (LAYER_KINDS, ExpertShareUnsupported,
-                     LatentCacheUnsupported, LayerPatternUnsupported,
-                     ModelConfig,
-                     RecurrentStateUnsupported, ResidualStreamUnsupported,
-                     YarnScaling)
+from .config import LAYER_KINDS, ModelConfig, YarnScaling, refuse
 from .moe import BANKS, MoEStats, expert_ffn
 
 Params = Dict[str, Any]
@@ -72,8 +69,7 @@ def ring_capacity(config: ModelConfig, max_len: int) -> int:
     positions (ring buffer, written at pos % capacity) — THE memory
     benefit of SWA: a mistral-7b 32k-context decode holds 4096 cache
     slots, not 32768. Rounded up to a multiple of 8 for TPU lane
-    tiling; when the window itself is flash-tileable the capacity
-    equals it exactly, keeping the flash-decode path eligible."""
+    tiling."""
     if config.sliding_window is None:
         return max_len
     return min(max_len, -(-config.sliding_window // 8) * 8)
@@ -93,13 +89,7 @@ def _is_ring(c: ModelConfig, cap: int) -> bool:
 
 def init_kv_cache(config: ModelConfig, batch: int, max_len: int,
                   dtype=None, *, quantized: Optional[bool] = None) -> KVCache:
-    if config.mla:
-        raise LatentCacheUnsupported("the slot KVCache layout", config.name)
-    if config.pattern:
-        raise LayerPatternUnsupported("the slot KVCache layout", config.name)
-    if config.ssm:
-        raise RecurrentStateUnsupported("the slot KVCache layout",
-                                        config.name)
+    refuse(config, "init_kv_cache")
     quantized = config.kv_quant if quantized is None else quantized
     max_len = ring_capacity(config, max_len)
     shape = (config.num_layers, batch, max_len, config.num_kv_heads,
@@ -652,25 +642,14 @@ def _self_attention(c: ModelConfig, q, k, v, kv_mask, mesh):
                      f"einsum|flash|ring|ulysses")
 
 
-def _cache_attention(c: ModelConfig, q, k_full, v_full, length, kv_mask,
-                     flash_decode_ok: bool):
-    """Cache-path attention dispatch: einsum over the whole cache, or the
-    streamed flash-decode kernel when the step shape allows it.
+def _cache_attention(c: ModelConfig, q, k_full, v_full, length, kv_mask):
+    """Cache-path attention: einsum over the whole cache.
 
     Ring caches (SWA): ``kv_mask`` arrives as the full per-query
     (B, Sq, cap) validity mask — fill, causality, and window are all
     baked in by ``_forward_impl`` in ring coordinates, so the positional
     causal/window mask here must be OFF (ring index != absolute
-    position). Flash-decode stays valid on a ring whose capacity equals
-    the window: live entries are exactly indices < min(length+1, cap)
-    and online softmax is order-invariant."""
-    if flash_decode_ok:
-        from ..ops.flash_decode import flash_decode
-        smax = k_full.shape[1]
-        blk = 128 if smax % 128 == 0 else smax
-        # post-write valid count: the current token's k/v is in the cache
-        valid_count = jnp.minimum(length + 1, smax)
-        return flash_decode(q, k_full, v_full, valid_count, block_kv=blk)
+    position)."""
     if _is_ring(c, k_full.shape[1]):
         return attention(q, k_full, v_full, kv_mask=kv_mask, causal=False)
     return attention(q, k_full, v_full, q_offset=length, kv_mask=kv_mask,
@@ -889,6 +868,56 @@ def _stream_close(c: ModelConfig, x: jax.Array) -> jax.Array:
     return x.astype(jnp.float32).sum(-2).astype(x.dtype)
 
 
+def _precision(c: ModelConfig):
+    """The forward's matmul precision as a context: the platform default
+    where the configuration names none."""
+    if c.matmul_precision is None:
+        return contextlib.nullcontext()
+    return jax.default_matmul_precision(c.matmul_precision)
+
+
+def _embed(c: ModelConfig, params: Params, tokens: jax.Array,
+           flat: bool = False) -> jax.Array:
+    """Tokens -> the residual stream the layers carry. ``flat``: the paged
+    forward's batch of T entries, carried as (T, 1, ...)."""
+    x = params["embed"][tokens]      # gather; sharded vocab → XLA collective
+    if flat:
+        x = x[:, None, :]
+    return _stream_open(c, _times(x, c.embedding_multiplier))
+
+
+def _logit_rows(x: jax.Array, entries: Optional[jax.Array]) -> jax.Array:
+    """The stream's rows ``(T, 1, ...)`` at the entries whose logits are
+    wanted (``forward_paged``'s ``logit_entries``; an index past the last
+    entry is clamped), or all of them."""
+    if entries is None:
+        return x
+    return jnp.take(x, entries, axis=0, mode="clip")
+
+
+def _lm_head(c: ModelConfig, params: Params, x: jax.Array,
+             logit_entries: Optional[jax.Array] = None,
+             flat: bool = False) -> jax.Array:
+    """The stream after the last layer -> float32 logits: the stream
+    closed, the final norm, the head (the embedding where it is tied; its
+    int8 shadow ``tied_head_q8`` where ``models/quantize.py`` made one:
+    the head matmul streams half the bytes and ``_dense`` applies the
+    per-vocab-row scale as the shared fused epilogue), the multiplier.
+    ``logit_entries``: the rows of a ``flat`` stream whose logits are
+    wanted (``_logit_rows``); ``flat`` logits are (T, V)."""
+    x = _norm(c, _stream_close(c, _logit_rows(x, logit_entries)), params,
+              "final_norm")
+    if "lm_head" in params:
+        logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
+    elif "tied_head_q8" in params:
+        logits = _dense(x, params, "tied_head_q8", "bsd,vd->bsv")
+    else:
+        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+    if flat:
+        logits = logits[:, 0]
+    return _times(logits.astype(jnp.float32), c.lm_head_multiplier)
+
+
 def _worst(a, b):
     """The larger of two Sinkhorn errors, either of which may be None."""
     if a is None or b is None:
@@ -899,7 +928,7 @@ def _worst(a, b):
 def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
            cos: jax.Array, sin: jax.Array,
            cache_kv: Optional[Tuple[jax.Array, jax.Array, jax.Array]],
-           kv_mask, mesh=None, flash_decode_ok: bool = False):
+           kv_mask, mesh=None):
     """One transformer block. x: (B, S, D), or the residual stream
     (B, S, hc_mult, D) of a multi-stream configuration (``_residual``).
 
@@ -914,8 +943,7 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     if c.shortcut_moe:
         x, kv_out, aux, _ = _shortcut_block(
             c, lp, x, lambda lp_i, i, x_in, kv: _attend(
-                c, lp_i, x_in, cos, sin, cache_kv, kv_mask, mesh,
-                flash_decode_ok), None)
+                c, lp_i, x_in, cos, sin, cache_kv, kv_mask, mesh), None)
         return x, kv_out, aux
     if c.ssm:
         # two mixers on one normed input (``_mixers``); the scan is causal,
@@ -924,20 +952,19 @@ def _layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
             c, lp, x, "attn", lambda x_in: _mixers(
                 c, lp, x_in,
                 lambda h: _attend(c, lp, None, cos, sin, cache_kv, kv_mask,
-                                  mesh, flash_decode_ok, h=h),
+                                  mesh, h=h),
                 lambda h: (_ssm_mix(c, lp, h), None)))
     else:
         x, kv_out, _ = _residual(
             c, lp, x, "attn", lambda x_in: _attend(
-                c, lp, x_in, cos, sin, cache_kv, kv_mask, mesh,
-                flash_decode_ok))
+                c, lp, x_in, cos, sin, cache_kv, kv_mask, mesh))
     x, aux, _, _ = _mlp(c, lp, x)
     return x, kv_out, aux
 
 
 def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
             cos: jax.Array, sin: jax.Array, cache_kv, kv_mask, mesh,
-            flash_decode_ok: bool, h: Optional[jax.Array] = None):
+            h: Optional[jax.Array] = None):
     """``_layer``'s attention sublayer, norm to output projection: x
     (B, S, D), what the residual path hands it -> (attention's output
     (B, S, D), the cache pair ``_layer`` returns). With ``h`` the caller
@@ -947,9 +974,7 @@ def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
     b, s, _ = h.shape
     if c.mla:       # no cache here: _forward_impl refuses one
         if c.attn_impl != "einsum" or c.sliding_window is not None:
-            raise LatentCacheUnsupported(
-                f"attn_impl={c.attn_impl!r} / sliding_window="
-                f"{c.sliding_window} in the no-cache forward", c.name)
+            refuse(c, "forward(attn_impl=)")
         out = _mla_self_attention(c, lp, h, cos, sin, kv_mask)
         return _dense(out, lp, "wo", "bse,ed->bsd"), (None, None)
     q, k, v = _qkv(c, lp, h, cos, sin)
@@ -1007,7 +1032,7 @@ def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
             out = _cache_attention(c, q,
                                    _dequantize_kv(k_cache, k_scale, h.dtype),
                                    _dequantize_kv(v_cache, v_scale, h.dtype),
-                                   length, kv_mask, flash_decode_ok)
+                                   length, kv_mask)
         kv_out = (k_cache, v_cache, k_scale, v_scale)
     elif cache_kv is not None:
         k_cache, v_cache, length = cache_kv
@@ -1044,8 +1069,7 @@ def _attend(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
             v_cache = v_cache.at[slot, pos].set(v.astype(v_cache.dtype),
                                                 mode="drop")
         if out is None:
-            out = _cache_attention(c, q, k_cache, v_cache, length, kv_mask,
-                                   flash_decode_ok)
+            out = _cache_attention(c, q, k_cache, v_cache, length, kv_mask)
         kv_out = (k_cache, v_cache)
     else:
         out = _self_attention(c, q, k, v, kv_mask, mesh)
@@ -1309,8 +1333,7 @@ def _pattern_scan(c: ModelConfig, params: Params, x: jax.Array, carry,
 
         (x, m, carry), _ = jax.lax.scan(
             body, (x, m, carry),
-            (params["layers"][f"seg{i}"], jnp.arange(n, dtype=jnp.int32)),
-            unroll=c.scan_unroll)
+            (params["layers"][f"seg{i}"], jnp.arange(n, dtype=jnp.int32)))
         for kind in period:
             seen[kind] += n
         first += n * len(period)
@@ -1409,16 +1432,10 @@ def forward(
     must see it in the objective or it is free to collapse).
     """
     c = config
-    if c.matmul_precision is not None:
-        with jax.default_matmul_precision(c.matmul_precision):
-            out = _forward_impl(params, c, tokens, cache=cache,
-                                positions=positions, attn_mask=attn_mask,
-                                mesh=mesh, fresh_cache=fresh_cache)
-    else:
-        out = _forward_impl(params, c, tokens, cache=cache,
-                            positions=positions, attn_mask=attn_mask,
-                            mesh=mesh, fresh_cache=fresh_cache)
-    logits, new_cache, aux = out
+    with _precision(c):
+        logits, new_cache, aux = _forward_impl(
+            params, c, tokens, cache=cache, positions=positions,
+            attn_mask=attn_mask, mesh=mesh, fresh_cache=fresh_cache)
     if with_aux:
         return logits, new_cache, aux
     return logits, new_cache
@@ -1427,31 +1444,15 @@ def forward(
 def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
                   mesh=None, fresh_cache=False):
     b, s = tokens.shape
+    if cache is not None:
+        refuse(c, "forward(cache=)")
+    if mesh is not None:
+        refuse(c, "forward(mesh=)")
     if c.pattern:
-        if cache is not None or mesh is not None:
-            raise LayerPatternUnsupported(
-                "forward(cache=...) over the slot KVCache"
-                if cache is not None else "forward(mesh=...)", c.name)
-        x = _forward_pattern(params, c, params["embed"][tokens], attn_mask)
-        x = _norm(c, x, params, "final_norm")
-        logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-        return (logits.astype(jnp.float32), None,
-                jnp.zeros((), jnp.float32))
-    if c.hc_mult and (cache is not None or mesh is not None):
-        raise ResidualStreamUnsupported(
-            "forward(cache=...) over the slot KVCache" if cache is not None
-            else "forward(mesh=...)", c.name)
-    if c.ssm and (cache is not None or mesh is not None):
-        raise RecurrentStateUnsupported(
-            "forward(cache=...) over the slot KVCache" if cache is not None
-            else "forward(mesh=...)", c.name)
-    if (c.expert_share or c.shortcut_moe) and mesh is not None:
-        # the share over an 'ep' axis needs its exchange; parallel/expert.py
-        # drops pairs for capacity and knows no identity expert
-        raise ExpertShareUnsupported("forward(mesh=...)", c.name)
-    # gather; sharded vocab → XLA collective
-    x = _stream_open(c, _times(params["embed"][tokens],
-                               c.embedding_multiplier))
+        x = _forward_pattern(params, c, _embed(c, params, tokens),
+                             attn_mask)
+        return _lm_head(c, params, x), None, jnp.zeros((), jnp.float32)
+    x = _embed(c, params, tokens)
 
     if positions is None:
         base = cache.length if cache is not None else jnp.zeros((), jnp.int32)
@@ -1461,9 +1462,6 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
         positions = jnp.broadcast_to(positions, (b, s))
     cos, sin = _rope_tables(c, positions)
 
-    if cache is not None and c.mla:
-        raise LatentCacheUnsupported(
-            "forward(cache=...) over the slot KVCache", c.name)
     if cache is None:
         def one_layer(x, lp, cos, sin):
             x, _, layer_aux = _layer(c, lp, x, cos, sin, None, attn_mask,
@@ -1492,8 +1490,7 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
 
         carry = (x, jnp.zeros((), jnp.float32))
         for stack in _layer_stacks(params):
-            carry, _ = jax.lax.scan(body, carry, stack,
-                                    unroll=c.scan_unroll)
+            carry, _ = jax.lax.scan(body, carry, stack)
         x, aux_total = carry
         new_cache = None
     else:
@@ -1563,45 +1560,19 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
             valid = jnp.broadcast_to(kv_pos < bound, (b, max_len))
             if attn_mask is not None:
                 valid = valid & attn_mask
-        # Flash-decode applies only when the validity mask is exactly
-        # "pos < valid_count" (single new token, no extra mask) and the
-        # cache splits into proper KV blocks: either 128-aligned (the
-        # streamed multi-block grid) or small enough that one whole-cache
-        # block still fits VMEM comfortably. An unaligned LARGE cache
-        # would degenerate to block_kv = max_len — no per-slot skipping
-        # and a VMEM-busting block — so it falls back to einsum instead.
-        # SWA eligibility: a RING cache qualifies exactly when capacity
-        # == window (live entries are indices < min(length+1, cap), all
-        # inside the window, and online softmax is order-invariant; cap >
-        # window would leave stale slots the length model can't mask). An
-        # ABSOLUTE short cache (cap < aligned window) qualifies when cap
-        # ≤ window: every position it can hold is within any query's
-        # window, so the plain "pos < length+1" model is already exact.
-        tileable = (max_len % 128 == 0
-                    or (max_len % 8 == 0 and max_len <= 512))
-        if c.sliding_window is None:
-            swa_flash = True
-        elif _is_ring(c, max_len):
-            swa_flash = max_len == c.sliding_window
-        else:
-            swa_flash = max_len <= c.sliding_window
-        flash_ok = (c.decode_attn_impl == "flash" and s == 1
-                    and attn_mask is None and tileable and swa_flash)
-
         if cache.quantized:
             def body_q(carry, inputs):
                 x, aux = carry
                 lp, k_c, v_c, k_s, v_s = inputs
                 x, kv_out, layer_aux = _layer(
                     c, lp, x, cos, sin,
-                    (k_c, v_c, cache.length, k_s, v_s), valid,
-                    flash_decode_ok=flash_ok)
+                    (k_c, v_c, cache.length, k_s, v_s), valid)
                 return (x, aux + layer_aux), kv_out
 
             (x, aux_total), (k_upd, v_upd, ks_upd, vs_upd) = jax.lax.scan(
                 body_q, (x, jnp.zeros((), jnp.float32)),
                 (params["layers"], cache.k, cache.v, cache.k_scale,
-                 cache.v_scale), unroll=c.scan_unroll)
+                 cache.v_scale))
             new_cache = KVCache(k=k_upd, v=v_upd, length=cache.length + s,
                                 k_scale=ks_upd, v_scale=vs_upd)
         else:
@@ -1610,28 +1581,15 @@ def _forward_impl(params, c, tokens, *, cache, positions, attn_mask,
                 lp, k_cache, v_cache = inputs
                 x, (k_cache, v_cache), layer_aux = _layer(
                     c, lp, x, cos, sin, (k_cache, v_cache, cache.length),
-                    valid, flash_decode_ok=flash_ok)
+                    valid)
                 return (x, aux + layer_aux), (k_cache, v_cache)
 
             (x, aux_total), (k_upd, v_upd) = jax.lax.scan(
                 body, (x, jnp.zeros((), jnp.float32)),
-                (params["layers"], cache.k, cache.v), unroll=c.scan_unroll)
+                (params["layers"], cache.k, cache.v))
             new_cache = KVCache(k=k_upd, v=v_upd, length=cache.length + s)
 
-    x = rms_norm(_stream_close(c, x), params["final_norm"], c.rms_norm_eps)
-    head = params.get("lm_head")
-    if head is None:  # tied embeddings
-        if "tied_head_q8" in params:
-            # int8 shadow of the embed table (models/quantize.py): the
-            # head matmul streams half the bytes; _dense applies the
-            # per-vocab-row scale as the shared fused epilogue
-            logits = _dense(x, params, "tied_head_q8", "bsd,vd->bsv")
-        else:
-            logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    else:
-        logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
-    return (_times(logits.astype(jnp.float32), c.lm_head_multiplier),
-            new_cache, aux_total)
+    return _lm_head(c, params, x), new_cache, aux_total
 
 
 def _paged_layer(c: ModelConfig, lp: Dict[str, jax.Array], x: jax.Array,
@@ -1997,21 +1955,12 @@ def forward_paged(
     them together (``kv_blocks_saved``), and the group items that did
     (``attn_group_items``); zeros where the gather runs."""
     c = config
-    if c.matmul_precision is not None:
-        with jax.default_matmul_precision(c.matmul_precision):
-            out = _forward_paged_impl(
-                params, c, tokens, pool=pool,
-                tables=tables, seq_row=seq_row, positions=positions,
-                write_block=write_block, write_off=write_off,
-                use_kernel=use_kernel, adapters=adapters,
-                adapter_ids=adapter_ids, logit_entries=logit_entries)
-    else:
-        out = _forward_paged_impl(
+    with _precision(c):
+        logits, pool, moe, err, shared = _forward_paged_impl(
             params, c, tokens, pool=pool, tables=tables,
             seq_row=seq_row, positions=positions, write_block=write_block,
             write_off=write_off, use_kernel=use_kernel, adapters=adapters,
             adapter_ids=adapter_ids, logit_entries=logit_entries)
-    logits, pool, moe, err, shared = out
     if shared is None and with_attn_stats:
         shared = jnp.zeros((2,), jnp.int32)
     return ((logits, pool) + ((moe,) if with_moe_stats else ())
@@ -2086,12 +2035,10 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
     Entries that keep no write (padding, dropped writes) advance no state
     and write no ring slot. -> the tuple ``_forward_paged_impl`` returns."""
     t = tokens.shape[0]
-    if pool.k_scale is not None or pool.rows is None:
-        raise LayerPatternUnsupported(
-            "a quantized pool / a pool without row-addressed state in "
-            "forward_paged", c.name)
+    if pool.rows is None:
+        refuse(c, "forward_paged(pool=)")
     with jax.named_scope("embed"):
-        x = params["embed"][tokens][:, None, :]                 # (T, 1, D)
+        x = _embed(c, params, tokens, flat=True)                # (T, 1, D)
     hkv = c.cache_kv_heads
     fold = hkv // pool.k.shape[3]
     bs = pool.k.shape[2] // fold
@@ -2205,29 +2152,19 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
     pool = pool._replace(k=k_leaf, v=v_leaf, rows=type(pool.rows)(
         state, window, win_k.reshape(ring_shape), win_v.reshape(ring_shape)))
     with jax.named_scope("lm_head"):
-        x = _norm(c, _logit_rows(x, logit_entries), params, "final_norm")
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"])[:, 0].astype(jnp.float32)
+        logits = _lm_head(c, params, x, logit_entries, flat=True)
     return logits, pool, None, None, shared
-
-
-def _logit_rows(x: jax.Array, entries: Optional[jax.Array]) -> jax.Array:
-    """The stream's rows ``(T, 1, ...)`` at the entries whose logits are
-    wanted (``forward_paged``'s ``logit_entries``; an index past the last
-    entry is clamped), or all of them."""
-    if entries is None:
-        return x
-    return jnp.take(x, entries, axis=0, mode="clip")
 
 
 def _forward_paged_impl(params, c, tokens, *, pool, tables,
                         seq_row, positions, write_block, write_off,
                         use_kernel, adapters=None, adapter_ids=None,
                         logit_entries=None):
+    if adapters is not None:
+        refuse(c, "forward_paged(adapters=)")
+    if pool.k_scale is not None:
+        refuse(c, "forward_paged(pool=)")
     if c.pattern:
-        if adapters is not None:
-            raise LayerPatternUnsupported("adapter banks in forward_paged",
-                                          c.name)
         return _forward_paged_pattern(
             params, c, tokens, pool=pool, tables=tables, seq_row=seq_row,
             positions=positions, write_block=write_block,
@@ -2235,18 +2172,8 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
             logit_entries=logit_entries)
     with jax.named_scope("embed"):
         # (T, 1, D), or the stream (T, 1, hc_mult, D)
-        x = _stream_open(c, _times(params["embed"][tokens][:, None, :],
-                                   c.embedding_multiplier))
+        x = _embed(c, params, tokens, flat=True)
         cos, sin = _rope_tables(c, positions[:, None])
-    if c.ssm and adapters is not None:
-        raise RecurrentStateUnsupported("adapter banks in forward_paged",
-                                        c.name)
-    if c.mla and (adapters is not None or pool.k_scale is not None):
-        raise LatentCacheUnsupported(
-            "adapter banks / a quantized pool in forward_paged", c.name)
-    if c.hc_mult and adapters is not None:
-        raise ResidualStreamUnsupported("adapter banks in forward_paged",
-                                        c.name)
     # STATIC under jit: derived from pytree structure (None-ness and
     # shapes), so the precision ladder never adds a trace argument.
     n_hi = 0 if pool.k_hi is None else pool.k_hi.shape[0]
@@ -2327,8 +2254,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
 
         index = jnp.arange(first, first + n, dtype=jnp.int32)
         (x, leaves, counts, worst), _ = jax.lax.scan(
-            body, (x, leaves, counts, worst), (layers, ad, index),
-            unroll=c.scan_unroll)
+            body, (x, leaves, counts, worst), (layers, ad, index))
         return x, leaves, counts, worst
 
     layers, lo_ad, err = params["layers"], adapters, None
@@ -2371,18 +2297,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
         upd["rows"] = type(pool.rows)(*leaves[-2:])
 
     with jax.named_scope("lm_head"):
-        x = rms_norm(_stream_close(c, _logit_rows(x, logit_entries)),
-                     params["final_norm"], c.rms_norm_eps)
-        head = params.get("lm_head")
-        if head is None:  # tied embeddings
-            if "tied_head_q8" in params:
-                logits = _dense(x, params, "tied_head_q8", "bsd,vd->bsv")
-            else:
-                logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-        else:
-            logits = _dense(x, params, "lm_head", "bsd,dv->bsv")
-        logits = _times(logits[:, 0].astype(jnp.float32),
-                        c.lm_head_multiplier)
+        logits = _lm_head(c, params, x, logit_entries, flat=True)
     return logits, pool._replace(**upd), moe, err, shared
 
 

@@ -99,7 +99,7 @@ the BlockSpec index maps, the scales riding their own specs through the
 same indirection and the rescale fused after the payload's upcast.
 
 ``lengths[t]`` counts valid positions including the freshly-written
-current token (write-then-attend, same contract as flash_decode).
+current token (write-then-attend).
 """
 
 from __future__ import annotations
@@ -770,7 +770,7 @@ def paged_flash_decode(
     """Block-table cache attention with one query a table row. Returns
     (T, Hq, D). The KV block size IS the kernel block size — the pool
     was allocated block-aligned, so there is never a pad-copy path here
-    (the flash_decode ``Smax % block_kv`` failure mode cannot arise by
+    (an ``Smax % block_kv`` remainder cannot arise by
     construction). Unquantized, it is ``paged_attention_rows`` with
     every entry a row of its own; passing ``k_scale``/``v_scale``
     selects the dequant-fused kernel for quantized pools. Note Mosaic's
@@ -798,7 +798,7 @@ def paged_flash_decode(
         interpret = not on_tpu()
 
     # (T, Hq, D) → (T, Hkv*rep_pad, D): flattened (kv-head, group) pairs
-    # on the sublane axis, same layout as flash_decode.
+    # on the sublane axis.
     qg = q.reshape(t, hkv, rep, d)
     if rep_pad != rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_pad - rep), (0, 0)))

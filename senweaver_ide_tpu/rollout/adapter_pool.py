@@ -52,10 +52,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import (LatentCacheUnsupported,
-                             LayerPatternUnsupported, ModelConfig,
-                             RecurrentStateUnsupported,
-                             ResidualStreamUnsupported)
+from ..models.config import ModelConfig, refuse
 from ..obs import get_registry
 
 # (in_dim, out_dim) per supported target. Attention-only by design:
@@ -127,19 +124,7 @@ class AdapterPool:
 
     def __init__(self, config: ModelConfig,
                  pool_config: Optional[AdapterPoolConfig] = None):
-        if config.mla:
-            raise LatentCacheUnsupported(
-                "the multi-LoRA adapter pool (its targets are wq/wk/wv/wo)",
-                config.name)
-        if config.hc_mult:
-            raise ResidualStreamUnsupported("the multi-LoRA adapter pool",
-                                            config.name)
-        if config.pattern:
-            raise LayerPatternUnsupported("the multi-LoRA adapter pool",
-                                          config.name)
-        if config.ssm:
-            raise RecurrentStateUnsupported("the multi-LoRA adapter pool",
-                                            config.name)
+        refuse(config, "AdapterPool")
         self.config = config
         self.pool_config = pool_config or AdapterPoolConfig()
         pc = self.pool_config

@@ -34,10 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.config import (LatentCacheUnsupported,
-                             LayerPatternUnsupported, ModelConfig,
-                             RecurrentStateUnsupported,
-                             ResidualStreamUnsupported)
+from ..models.config import ModelConfig, refuse
 from ..models.transformer import (KVCache, Params, forward, forward_paged,
                                   init_kv_cache, reads_pool_in_place)
 from ..obs import get_registry, get_tracer
@@ -509,9 +506,6 @@ class EngineConfig:
     # and restore on demand via the install scatter; False degrades to
     # evict-only (the preempt-heavy PR-10 ladder, kept for benching).
     host_tier: bool = True
-    # An unshared prefix must have been grafted this many times before
-    # it is worth the host round-trip; colder entries are dropped.
-    tier_min_uses: int = 2
     # Preemption-starvation cap: a request preempted this many times
     # becomes non-preemptible (it either finishes or, when even a
     # whole-pool allocation cannot fit it, truncate-finishes) —
@@ -776,74 +770,20 @@ class RolloutEngine:
         # ring pools (chunked prefill), the pool size on absolute ones.
         self.context_bound = (config.max_seq_len
                               if self._ring else max_len)
-        if config.mla:
-            ec = engine_config or EngineConfig()
-            # Latent attention serves from the paged latent pool alone.
-            # What has no latent form is refused here, by name, instead
-            # of falling back to a layout that cannot hold the cache.
-            for asked, mechanism in (
-                    (ec.kv_layout == "slots", "the slot KVCache layout "
-                     "(EngineConfig.kv_layout='slots')"),
-                    (config.kv_quant, "the slot int8 cache (kv_quant)"),
-                    (self._ring, "the sliding-window ring cache"),
-                    (mesh is not None, "tensor-parallel KV sharding "
-                     "(mesh=...)"),
-                    (adapter_pool is not None, "the multi-LoRA adapter "
-                     "pool"),
-                    (config.decode_attn_impl == "flash",
-                     "the Pallas flash-decode kernel over the slot cache "
-                     "(decode_attn_impl='flash')")):
-                if asked:
-                    raise LatentCacheUnsupported(mechanism, config.name)
-        if config.hc_mult:
-            ec = engine_config or EngineConfig()
-            # The multi-stream residual runs through forward_paged on one
-            # chip alone; the same refusals, for a model of any attention.
-            for asked, mechanism in (
-                    (ec.kv_layout == "slots" or config.kv_quant
-                     or self._ring, "the slot KVCache path"),
-                    (mesh is not None, "a mesh (mesh=...)"),
-                    (adapter_pool is not None, "the multi-LoRA adapter "
-                     "pool")):
-                if asked:
-                    raise ResidualStreamUnsupported(mechanism, config.name)
-        if config.pattern:
-            ec = engine_config or EngineConfig()
-            # Layers of unlike kinds serve from the paged pool's
-            # descriptors alone (block-addressed KV for the full-attention
-            # layers, rings and state by row), on one chip: what has no
-            # form for them is refused by name. Never the slots fallback,
-            # which would hold every layer's cache at full length.
-            for asked, mechanism in (
-                    (ec.kv_layout == "slots", "the slot KVCache layout "
-                     "(EngineConfig.kv_layout='slots')"),
-                    (config.kv_quant, "the slot int8 cache (kv_quant)"),
-                    (ec.kv_dtype != "bf16"
-                     or ec.kv_dtype_per_layer is not None,
-                     "the quantized KV ladder (EngineConfig.kv_dtype "
-                     "int8/fp8)"),
-                    (self._ring, "the sliding-window ring cache of the "
-                     "slot layout (sliding_window)"),
-                    (mesh is not None, "a mesh (mesh=...)"),
-                    (adapter_pool is not None, "the multi-LoRA adapter "
-                     "pool")):
-                if asked:
-                    raise LayerPatternUnsupported(mechanism, config.name)
-        elif config.ssm:
-            ec = engine_config or EngineConfig()
-            # Recurrent state lives in the paged pool's row-addressed
-            # leaves on one chip alone: a layout that has no place for it
-            # is refused, never fallen back to.
-            for asked, mechanism in (
-                    (ec.kv_layout == "slots", "the slot KVCache layout "
-                     "(EngineConfig.kv_layout='slots')"),
-                    (config.kv_quant, "the slot int8 cache (kv_quant)"),
-                    (self._ring, "the sliding-window ring cache"),
-                    (mesh is not None, "a mesh (mesh=...)"),
-                    (adapter_pool is not None, "the multi-LoRA adapter "
-                     "pool")):
-                if asked:
-                    raise RecurrentStateUnsupported(mechanism, config.name)
+        # What this form of model has no form for is refused by name
+        # (models.config.UNSUPPORTED), never fallen back from to the slot
+        # layout below.
+        ec = engine_config or EngineConfig()
+        for asked, mechanism in (
+                (ec.kv_layout == "slots", "RolloutEngine(kv_layout='slots')"),
+                (config.kv_quant, "RolloutEngine(config.kv_quant)"),
+                (ec.kv_dtype != "bf16" or ec.kv_dtype_per_layer is not None,
+                 "RolloutEngine(kv_dtype=)"),
+                (self._ring, "RolloutEngine(config.sliding_window)"),
+                (mesh is not None, "RolloutEngine(mesh=)"),
+                (adapter_pool is not None, "RolloutEngine(adapter_pool=)")):
+            if asked:
+                refuse(config, mechanism)
         self.sample = sample
         self.eos_id = eos_id
         # Optional tensor-parallel serving: params take the Megatron
@@ -867,7 +807,7 @@ class RolloutEngine:
         self._key = self._on_device(lambda: jax.random.PRNGKey(seed))
         # KV layout: paged block pool by default; the layouts the pool
         # has no equivalent for yet fall back to the slot cache.
-        self.engine_config = engine_config or EngineConfig()
+        self.engine_config = ec
         if self.engine_config.block_size is None:
             self.engine_config = dataclasses.replace(
                 self.engine_config, block_size=resolve_block_size(
@@ -1300,16 +1240,7 @@ class RolloutEngine:
         (``num_blocks``; default sized like the target's) whose
         gauges publish under ``senweaver_spec_draft_kv_*``."""
         from .spec_controller import FixedDepth, SpecController
-        if self.config.pattern or draft_config.pattern:
-            raise LayerPatternUnsupported("fused draft/verify speculation",
-                                          self.config.name)
-        if self.config.ssm or draft_config.ssm:
-            # a rejected draft cannot roll a state back
-            raise RecurrentStateUnsupported("fused draft/verify speculation",
-                                            self.config.name)
-        if self.config.mla or draft_config.mla:
-            raise LatentCacheUnsupported("fused draft/verify speculation",
-                                         self.config.name)
+        refuse(self.config, "enable_speculation", also=draft_config)
         if self.kv_layout != "paged":
             raise ValueError(
                 "fused speculation needs the paged KV layout (engine "
@@ -1663,8 +1594,7 @@ class RolloutEngine:
         for requests that are done, paused, or still prefilling."""
         if self.kv_layout != "paged":
             raise ValueError("fork_request requires the paged KV layout")
-        self._refuse_state("fork_request (a branch shares KV blocks by "
-                           "refcount; the state has no fork yet)")
+        refuse(self.config, "fork_request")
         with self._lock:
             self._drain()
             parent = self._requests.get(rid)
@@ -1986,13 +1916,7 @@ class RolloutEngine:
         buffer; ``update_params`` invalidates all prefixes (their KV
         belongs to the old policy) and auto_prefix clients re-register.
         """
-        if self.config.mla:
-            raise LatentCacheUnsupported(
-                "registered prefixes (their prefill runs over the slot "
-                "KVCache)", self.config.name)
-        self._refuse_state("registered prefixes (register_prefix: a "
-                           "prefix's blocks are grafted, its state has no "
-                           "snapshot)")
+        refuse(self.config, "register_prefix")
         with self._lock:
             if not tokens:
                 raise ValueError("empty prefix")
@@ -2056,7 +1980,7 @@ class RolloutEngine:
         are immutable and the jitted paths donate only the POOL cache,
         never a prefix buffer. Raises KeyError if the prefix was evicted
         or invalidated (callers re-register, same as submit())."""
-        self._refuse_state("prefix export (export_prefix)")
+        refuse(self.config, "export_prefix")
         with self._lock:
             self._drain()      # the gather reads the pool
             if prefix_id not in self._prefixes:
@@ -2105,8 +2029,7 @@ class RolloutEngine:
         buffer would be silent garbage). ``last_logits`` is the donor's
         final-token logits; without it, a zero-suffix submit recomputes
         the last position (one-token prefill) on first use."""
-        self._refuse_state("prefix import (import_prefix: the peer's KV "
-                           "comes without the state behind it)")
+        refuse(self.config, "import_prefix")
         with self._lock:
             if not tokens:
                 raise ValueError("empty prefix")
@@ -2263,9 +2186,7 @@ class RolloutEngine:
         request is left PAUSED so its state cannot advance between
         snapshot and the coordinator's release/resume). The freeze +
         snapshot happen atomically under the engine lock."""
-        self._refuse_state("request checkpoints and migration "
-                           "(checkpoint_request: a DecodeCheckpoint holds "
-                           "KV blocks, no state)")
+        refuse(self.config, "checkpoint_request")
         from .migration import checkpoint_from_engine
         with self._lock:
             self._drain()
@@ -2277,8 +2198,7 @@ class RolloutEngine:
         layout exist, otherwise a front-of-queue requeue that resumes
         through the preemption-recompute replay. Either way the
         resumed output is token-exact versus never migrating."""
-        self._refuse_state("request checkpoints and migration "
-                           "(restore_request)")
+        refuse(self.config, "restore_request")
         from .migration import restore_into_engine
         with self._lock:
             self._drain()
@@ -2336,15 +2256,6 @@ class RolloutEngine:
             return out
 
     # -- internals ----------------------------------------------------------
-
-    def _refuse_state(self, mechanism: str) -> None:
-        """What has no state-snapshot counterpart yet is refused by name
-        for a model with recurrent state, never fallen back from; a
-        layer pattern's rings and unlike layers have none either."""
-        if self.config.pattern:
-            raise LayerPatternUnsupported(mechanism, self.config.name)
-        if self.config.ssm:
-            raise RecurrentStateUnsupported(mechanism, self.config.name)
 
     def _flush_state_copies(self, span) -> int:
         # guarded-by: caller
@@ -3029,9 +2940,7 @@ class RolloutEngine:
                              self._prefix_use_seq)
         if victim is None:
             return False
-        cfg = self.engine_config
-        if should_tier(victim, host_tier=cfg.host_tier,
-                       tier_min_uses=cfg.tier_min_uses):
+        if should_tier(victim, host_tier=self.engine_config.host_tier):
             try:
                 self._swap_out_prefix(victim.pid)
                 return True

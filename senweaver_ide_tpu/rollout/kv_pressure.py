@@ -71,18 +71,22 @@ def pick_victim(candidates: Sequence[PrefixCandidate],
     return min(candidates, key=lambda c: victim_key(c, now_seq))
 
 
-def should_tier(cand: PrefixCandidate, *, host_tier: bool,
-                tier_min_uses: int) -> bool:
+# An unshared prefix must have been grafted this many times before it is
+# worth the host round-trip; colder entries are dropped.
+TIER_MIN_USES = 2
+
+
+def should_tier(cand: PrefixCandidate, *, host_tier: bool) -> bool:
     """Tier (swap to host) instead of evicting (drop + re-prefill)?
 
     Shared prefixes are always worth keeping — every consumer's prefill
     rides on them. Unshared ones must have proven reuse
-    (``use_count >= tier_min_uses``) to pay for the host round-trip.
+    (``use_count >= TIER_MIN_USES``) to pay for the host round-trip.
     With the host tier disabled the answer is always no: the engine
     degrades to the PR-10 behaviour (evict, then preempt)."""
     if not host_tier:
         return False
-    return cand.shared or cand.use_count >= tier_min_uses
+    return cand.shared or cand.use_count >= TIER_MIN_USES
 
 
 class HostPrefix(NamedTuple):

@@ -60,7 +60,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from ..models.config import LatentCacheUnsupported, LayerPatternUnsupported
+from ..models.config import refuse
 from ..models.transformer import (ModelConfig, dequantize_pool_kv,
                                   quantize_pool_kv)
 from ..obs.runtime_profile import ProfiledFunction
@@ -414,9 +414,7 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
     yet."""
     if config.pattern and (kv_dtype != "bf16"
                            or kv_dtype_per_layer is not None):
-        raise LayerPatternUnsupported(
-            "the quantized KV ladder (EngineConfig.kv_dtype int8/fp8)",
-            config.name)
+        refuse(config, "init_paged_pool(kv_dtype=)")
     if config.ssm:
         if state_rows <= 0:
             raise ValueError(
@@ -438,9 +436,7 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
                                       kv_dtype_per_layer)
     if config.mla:
         if payload is not None:
-            raise LatentCacheUnsupported(
-                "the quantized KV ladder (EngineConfig.kv_dtype int8/fp8)",
-                config.name)
+            refuse(config, "init_paged_pool(kv_dtype=)")
         shape = (num_layers, num_blocks, block_size, 1)
         return PagedKVPool(
             k=jnp.zeros(shape + (config.latent_row_dim,), dtype=config.dtype),

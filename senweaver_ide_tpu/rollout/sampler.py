@@ -2,12 +2,9 @@
 
 Replaces the reference's remote-API streaming path
 (``electron-main/llmMessage/sendLLMMessage.impl.ts``) for local policy
-rollouts. Two decode drivers share the same jitted step:
-
-- :func:`generate` — host loop calling the jitted step; supports per-sequence
-  early stop and streaming callbacks (the agent loop uses this).
-- :func:`generate_scan` — fully device-resident ``lax.scan`` decode for
-  benchmarking and batch rollouts (no host roundtrip per token).
+rollouts. :func:`generate` is a host loop calling the jitted step; it
+supports per-sequence early stop and streaming callbacks (the agent loop
+uses this, and the engine's tests compare against it).
 
 The KV cache is static-shape and sharded per
 ``parallel.sharding.KV_CACHE_SPEC``; continuous batching slots in by treating
@@ -122,53 +119,3 @@ def generate(
         if on_token is not None:
             on_token(i, tok)
     return jnp.stack(out, axis=1)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("config", "max_new_tokens", "sample",
-                                    "eos_id"))
-def generate_scan(
-    params: Params,
-    config: ModelConfig,
-    prompt: jax.Array,
-    cache: KVCache,
-    key: jax.Array,
-    *,
-    max_new_tokens: int = 128,
-    sample: SampleParams = SampleParams(),
-    eos_id: int = -1,
-) -> Tuple[jax.Array, KVCache]:
-    """Fully-jitted decode: prefill + scan over max_new_tokens steps.
-
-    Device-resident; the benchmark path. ``cache`` must be freshly
-    initialized (nothing prefilled). eos handling keeps shapes static by
-    overwriting post-eos tokens with eos_id; ring (SWA) caches prefill
-    prompts longer than their capacity in capacity-sized chunks.
-    """
-    cap = cache.k.shape[2]
-    s_prompt = prompt.shape[1]
-    if s_prompt > cap:
-        logits = None
-        for lo in range(0, s_prompt, cap):
-            logits, cache = forward(params, config, prompt[:, lo:lo + cap],
-                                    cache=cache, fresh_cache=(lo == 0))
-    else:
-        logits, cache = forward(params, config, prompt, cache=cache,
-                                fresh_cache=True)
-    tok0 = sample_token(logits[:, -1, :], key,
-                        temperature=sample.temperature,
-                        top_k=sample.top_k, top_p=sample.top_p)
-    b = prompt.shape[0]
-    done0 = tok0 == eos_id
-
-    def body(carry, step_key):
-        tok, cache, done = carry
-        next_tok, _, cache = decode_step(params, config, tok[:, None], cache,
-                                         step_key, sample)
-        next_tok = jnp.where(done, eos_id, next_tok)
-        done = done | (next_tok == eos_id)
-        return (next_tok, cache, done), next_tok
-
-    keys = jax.random.split(key, max_new_tokens - 1)
-    (_, cache, _), toks = jax.lax.scan(body, (tok0, cache, done0), keys)
-    return jnp.concatenate([tok0[:, None], toks.T], axis=1), cache

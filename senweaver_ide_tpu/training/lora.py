@@ -37,10 +37,7 @@ from typing import Dict, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..models.config import (LatentCacheUnsupported,
-                             LayerPatternUnsupported, ModelConfig,
-                             RecurrentStateUnsupported,
-                             ResidualStreamUnsupported)
+from ..models.config import ModelConfig, refuse
 from ..models.quantize import _quantize_matrix, is_quantized
 
 # (in_dim, out_dim) resolvers per supported target matrix.
@@ -61,22 +58,7 @@ def init_lora(config: ModelConfig, key: jax.Array, *, rank: int = 16,
               alpha: float = None, targets: Sequence[str] = DEFAULT_TARGETS,
               ) -> Dict:
     """Adapter pytree; zero function delta at init (B = 0)."""
-    if config.mla:
-        raise LatentCacheUnsupported(
-            "LoRA on the latent projections (targets are wq/wk/wv/wo)",
-            config.name)
-    if config.hc_mult:
-        raise ResidualStreamUnsupported("LoRA adapters", config.name)
-    if config.pattern:
-        raise LayerPatternUnsupported(
-            "LoRA adapters (init_lora: the layers' leaves are by segment "
-            "and kind, and the trainer has no backward of the Mamba-1 scan "
-            "over runs)", config.name)
-    if config.ssm:
-        raise RecurrentStateUnsupported(
-            "LoRA adapters (init_lora: the mixer's projections are no "
-            "target, and the trainer has no backward of the chunked scan)",
-            config.name)
+    refuse(config, "init_lora")
     if config.num_experts > 0:
         bad = {"w_gate", "w_up", "w_down"} & set(targets)
         if bad:

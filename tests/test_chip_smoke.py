@@ -28,7 +28,7 @@ def test_chip_smoke_tiny_rehearses_every_phase_on_cpu():
     assert isinstance(result["device"]["kind"], str)
     assert set(report["report"]["phases"]) == {
         "init", "serve", "agree", "train", "publish", "kernels",
-        "flash_engine", "fsdp4_train", "fleet4_serve"}
+        "fsdp4_train", "fleet4_serve"}
 
 
 def test_chip_smoke_needs_a_tpu_unless_tiny():

@@ -453,8 +453,6 @@ UNSUPPORTED = {
             kv_dtype_per_layer=("bf16", "int8", "int8"))), "quantized KV"),
     "adapter pool": (lambda m: _construct(m, adapter_pool=object()),
                      "adapter pool"),
-    "flash decode": (lambda m: _construct(m, config=dataclasses.replace(
-        m[1], decode_attn_impl="flash")), "Pallas"),
     "tensor parallel": (lambda m: _construct(m, mesh=object()),
                         "tensor-parallel"),
     "sliding window": (lambda m: _construct(m, config=dataclasses.replace(

@@ -127,11 +127,11 @@ def test_torn_swap_falls_back_to_eviction_leak_free(model, monkeypatch):
     """A chaos kill inside the swap-out readback must not strand pool
     blocks or host state: the evictor falls through to plain eviction,
     the pressured request still completes, and the pool drains clean."""
-    eng = make(model, num_slots=1, num_blocks=6, tier_min_uses=1)
+    eng = make(model, num_slots=1, num_blocks=6)
     pid = eng.register_prefix(COLD)
-    r0 = eng.submit(COLD + [1], max_new_tokens=2, prefix_id=pid)
-    out0 = eng.run()
-    assert len(out0[r0]) == 2                # warm use_count: tier-worthy
+    for tail in (1, 2):                      # grafted twice: tier-worthy
+        r0 = eng.submit(COLD + [tail], max_new_tokens=2, prefix_id=pid)
+        assert len(eng.run()[r0]) == 2
 
     def boom(pool, ids):
         raise ChaosError("injected gather kill mid-swap")

@@ -73,22 +73,6 @@ def test_decode_parity_quantized_vs_full(setup):
     assert float(cos) > 0.995, float(cos)
 
 
-def test_generate_scan_with_quantized_cache(setup):
-    config, params = setup
-    from senweaver_ide_tpu.rollout.sampler import (SampleParams,
-                                                   generate_scan)
-    prompt = jnp.ones((2, 8), jnp.int32)
-    cache = init_kv_cache(config, 2, 24, quantized=True)
-    toks, out_cache = generate_scan(
-        params, config, prompt, cache, jax.random.PRNGKey(0),
-        max_new_tokens=8, sample=SampleParams(0.8, 0, 0.0))
-    assert toks.shape == (2, 8)
-    assert out_cache.k.dtype == jnp.int8
-    # prefill (8) + 7 decode writes; the final sampled token is returned
-    # but never written back
-    assert int(out_cache.length) == 15
-
-
 def test_per_slot_scatter_writes_scales(setup):
     """Continuous-batching path: (B,) lengths scatter values + scales at
     per-slot offsets."""

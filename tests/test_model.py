@@ -13,7 +13,7 @@ from senweaver_ide_tpu.ops import (apply_rope, apply_top_k, apply_top_p,
                                    rope_cos_sin, sample_token, sampling)
 from senweaver_ide_tpu.parallel import (MeshConfig, data_sharding, make_mesh,
                                         param_specs, shard_params)
-from senweaver_ide_tpu.rollout import SampleParams, generate, generate_scan
+from senweaver_ide_tpu.rollout import SampleParams, generate
 
 
 @pytest.fixture(scope="module")
@@ -66,18 +66,6 @@ def test_generate_greedy_deterministic(model):
                  sample=SampleParams(temperature=0.0))
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     assert a.shape == (1, 6)
-
-
-def test_generate_scan_matches_host_loop_greedy(model):
-    cfg, params = model
-    toks = jnp.array([[1, 2, 3, 4]], dtype=jnp.int32)
-    host = generate(params, cfg, toks, max_new_tokens=5,
-                    sample=SampleParams(temperature=0.0))
-    cache = init_kv_cache(cfg, 1, 16)
-    dev, _ = generate_scan(params, cfg, toks, cache, jax.random.PRNGKey(0),
-                           max_new_tokens=5,
-                           sample=SampleParams(temperature=0.0))
-    np.testing.assert_array_equal(np.asarray(host), np.asarray(dev))
 
 
 def test_eos_early_stop(model):

@@ -223,26 +223,6 @@ def test_ring_per_slot_lengths_match_scalar(rng):
                                atol=2e-4)
 
 
-def test_ring_flash_decode_matches_einsum(rng):
-    """cap == window makes the ring eligible for flash-decode; both
-    decode impls must agree across a wrap (seq 24, window 16)."""
-    base = dataclasses.replace(tiny_test(), sliding_window=16)
-    flash = dataclasses.replace(base, decode_attn_impl="flash")
-    params = init_params(base, jax.random.PRNGKey(3))
-    toks = jnp.asarray(rng.integers(0, base.vocab_size, (2, 24)), jnp.int32)
-
-    caches = {"einsum": init_kv_cache(base, 2, 64),
-              "flash": init_kv_cache(flash, 2, 64)}
-    assert caches["flash"].k.shape[2] == 16
-    for i in range(24):
-        lg_e, caches["einsum"] = forward(params, base, toks[:, i:i + 1],
-                                         cache=caches["einsum"])
-        lg_f, caches["flash"] = forward(params, flash, toks[:, i:i + 1],
-                                        cache=caches["flash"])
-        np.testing.assert_allclose(np.asarray(lg_e), np.asarray(lg_f),
-                                   atol=3e-4, err_msg=f"step {i}")
-
-
 def test_ring_int8_cache_parity(rng):
     """Quantized ring writes (values AND scales at modular indices)."""
     cfg = dataclasses.replace(tiny_test(), sliding_window=4, kv_quant=True)
@@ -356,26 +336,6 @@ def test_generate_long_prompt_chunks_through_ring(rng):
         want.append(tok)
         seq.append(tok)
     assert [int(t) for t in np.asarray(got[0])] == want
-
-
-def test_generate_scan_long_prompt_chunks(rng):
-    """generate_scan (the jitted bench path) chunk-prefills long prompts
-    identically to the host-loop generate."""
-    from senweaver_ide_tpu.rollout.sampler import (SampleParams, generate,
-                                                   generate_scan)
-
-    cfg = dataclasses.replace(tiny_test(), sliding_window=8)
-    params = init_params(cfg, jax.random.PRNGKey(9))
-    prompt = jnp.asarray(rng.integers(1, 500, (2, 19)), jnp.int32)
-    sp = SampleParams(temperature=0.0)
-
-    host = generate(params, cfg, prompt, max_new_tokens=5, sample=sp,
-                    key=jax.random.PRNGKey(1), max_len=32)
-    cache = init_kv_cache(cfg, 2, 32)
-    dev, _ = generate_scan(params, cfg, prompt, cache,
-                           jax.random.PRNGKey(1), max_new_tokens=5,
-                           sample=sp)
-    np.testing.assert_array_equal(np.asarray(host), np.asarray(dev))
 
 
 def test_short_swa_cache_uses_absolute_mode(rng):

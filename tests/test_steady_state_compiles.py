@@ -134,7 +134,7 @@ def _kv_pressure(config, params):
     swap-out / restore and preemption replay all ride the fused step,
     and the shared prefix survives them."""
     def run():
-        eng, pid, out = _pressured(config, params, tier_min_uses=1)
+        eng, pid, out = _pressured(config, params)
         eng.release_prefix(pid)
         eng._alloc.check_leaks()
         return out

@@ -161,41 +161,13 @@ def test_export_hf_rejects_int8(tmp_path):
         export_hf_params(quantize_weights_int8(params), c, str(tmp_path))
 
 
-def test_int8_weights_with_flash_decode():
-    """The two serving accelerators compose: int8 weight matmuls with
-    the flash-decode cache kernel (interpret mode on CPU)."""
-    c, params, toks = _setup()
-    c = dataclasses.replace(c, decode_attn_impl="flash")
-    qp = quantize_weights_int8(params)
-    cache = init_kv_cache(c, 2, 128)      # 128-aligned: flash engages
-    logits, cache = forward(qp, c, toks[:, :16], cache=cache,
-                            fresh_cache=True)
-    outs = [logits[:, -1]]
-    for i in range(16, 24):
-        step, cache = forward(qp, c, toks[:, i:i + 1], cache=cache)
-        outs.append(step[:, -1])
-    einsum_cfg = dataclasses.replace(c, decode_attn_impl="einsum")
-    cache2 = init_kv_cache(einsum_cfg, 2, 128)
-    logits2, cache2 = forward(qp, einsum_cfg, toks[:, :16], cache=cache2,
-                              fresh_cache=True)
-    outs2 = [logits2[:, -1]]
-    for i in range(16, 24):
-        step2, cache2 = forward(qp, einsum_cfg, toks[:, i:i + 1],
-                                cache=cache2)
-        outs2.append(step2[:, -1])
-    np.testing.assert_allclose(np.asarray(jnp.stack(outs, 1)),
-                               np.asarray(jnp.stack(outs2, 1)),
-                               atol=3e-4, rtol=3e-4)
-
-
 def test_all_serving_levers_compose():
     """The max-memory-efficiency serving config: sliding-window RING
-    cache + int8 KV quantization + int8 weights + flash decode, through
-    the engine (the one-16GB-chip 7B posture, every lever at once)."""
+    cache + int8 KV quantization + int8 weights, through the engine (the
+    one-16GB-chip 7B posture, every lever at once)."""
     from senweaver_ide_tpu.rollout import RolloutEngine
     c = dataclasses.replace(get_config("tiny-test"), sliding_window=128,
-                            kv_quant=True, decode_attn_impl="flash",
-                            max_seq_len=512)
+                            kv_quant=True, max_seq_len=512)
     params = quantize_weights_int8(init_params(c, jax.random.PRNGKey(0)))
     engine = RolloutEngine(params, c, num_slots=2, max_len=128,
                            eos_id=None, seed=0)
