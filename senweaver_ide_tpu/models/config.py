@@ -205,12 +205,30 @@ def refuse(config, mechanism: str, also=None) -> None:
 #             scan's output as the memory ``m`` of the layers after it
 #   "window"  self-attention over the trailing ``layer_window`` positions;
 #             holds a row-addressed ring of that many positions and a step
-#   "full"    causal self-attention; holds block-addressed KV
+#   "full"    causal self-attention (differential under ``diff_attn``, else
+#             plain GQA, its output gated under ``attn_out_gate``); holds
+#             block-addressed KV
 #   "gmu"     a gated memory unit: a gate of the layer's input on ``m``;
 #             holds nothing
 #   "cross"   attention of the layer's queries over the last "full"
 #             layer's KV; holds nothing
-LAYER_KINDS = ("mamba", "window", "full", "gmu", "cross")
+#   "kda"     a gated delta-rule mixer with a decay a key channel
+#             (``ops/delta_rule.py``); holds a row-addressed matrix state a
+#             head and the conv window of its q, k and v
+# The second sublayer is the dense SwiGLU, or the expert layer where the
+# configuration has experts (``models.transformer._ffn``).
+LAYER_KINDS = ("mamba", "window", "full", "gmu", "cross", "kda")
+
+
+def pattern_keys(period) -> Tuple[str, ...]:
+    """The names of a period's layers in ``params["layers"]["seg<i>"]``:
+    the kind, with its place in the period behind it where the period
+    names the kind more than once (``("full", "kda", "kda")`` ->
+    ``("full", "kda1", "kda2")``): each layer of a period has leaves of its
+    own, stacked ``repeats`` deep, so no leaf has two readers in the scan's
+    body."""
+    return tuple(kind if period.count(kind) == 1 else f"{kind}{j}"
+                 for j, kind in enumerate(period))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -410,7 +428,7 @@ class ModelConfig:
     # decoder): segments ``(period, repeats)``, a period a tuple of
     # ``LAYER_KINDS``, run in order; ``num_layers`` is their sum. Each
     # segment is ONE scan over its repeats whose body runs the period's
-    # layers (``params["layers"]["seg<i>"]["<kind><j>"]``, stacked
+    # layers (``params["layers"]["seg<i>"][k]``, k of ``pattern_keys``, stacked
     # ``repeats`` deep). Such a model has no rotary or other positional
     # term: causality, the window and the recurrence are its only sense of
     # order. Empty: every layer is of one kind, as above.
@@ -431,6 +449,22 @@ class ModelConfig:
     # ``num_kv_heads / 2`` rows of ``2 head_dim`` a token: [k1 | k2] and
     # [v1 | v2] of a pair of kv heads.
     diff_attn: bool = False
+    # A plain (not differential) attention layer's output is multiplied by
+    # ``sigmoid(h W_g)``, h the layer's normed input, elementwise over the
+    # heads' outputs, before ``W_o`` (``layer_types`` "full").
+    attn_out_gate: bool = False
+    # The gated delta-rule mixer (``layer_types`` "kda"): ``kda_num_heads``
+    # heads of ``kda_head_dim`` for q, k and v alike, each through a causal
+    # depthwise conv of ``kda_conv`` taps; the decay a key channel and the
+    # output gate through bottlenecks of rank ``kda_rank``; ``beta`` in
+    # (0, 2) where ``kda_neg_eigval`` (the state's transition may then have
+    # negative eigenvalues), else in (0, 1). Its state is float32
+    # ``(heads, head_dim, head_dim)`` a row a layer.
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_rank: int = 0
+    kda_neg_eigval: bool = False
 
     @property
     def pattern(self) -> bool:
@@ -457,9 +491,14 @@ class ModelConfig:
 
     @property
     def ssm(self) -> bool:
-        """The blocks hold a state-space mixer, and the cache a recurrent
-        state a row beside the KV blocks."""
-        return self.mamba_d_ssm > 0
+        """The blocks hold a state-space or delta-rule mixer, and the cache
+        a recurrent state a row beside the KV blocks."""
+        return self.mamba_d_ssm > 0 or self.kind_layers("kda") > 0
+
+    @property
+    def kda_dim(self) -> int:
+        """Width of each of a "kda" layer's q, k and v."""
+        return self.kda_num_heads * self.kda_head_dim
 
     @property
     def ssm_conv_dim(self) -> int:
@@ -833,6 +872,118 @@ def tiny_phi4flash_test() -> ModelConfig:
         norm="layer", diff_attn=True)
 
 
+# Solar-Open2's published ``config.json`` keys (``model_type: solar_open2``)
+# that ``solar_open2_config`` maps; any other key is something the program
+# would have to model and does not.
+_SOLAR_OPEN2_KEYS = frozenset((
+    "partial_rotary_factor", "linear_attn_config", "hidden_size",
+    "num_hidden_layers", "num_attention_heads", "head_dim",
+    "num_key_value_heads", "vocab_size", "intermediate_size",
+    "moe_intermediate_size", "rms_norm_eps", "rope_theta",
+    "tie_word_embeddings", "max_position_embeddings",
+    "first_k_dense_replace", "use_rope", "gqa_interval", "gqa_layers",
+    "use_gqa_gate", "kda_use_full_proj", "kda_allow_neg_eigval",
+    "n_routed_experts", "n_shared_experts", "norm_topk_prob",
+    "routed_scaling_factor", "num_experts_per_tok"))
+
+
+def solar_open2_config(published: dict, *, name: str, first_expert: int = 0,
+                       routed_experts: Optional[int] = None,
+                       dtype=jnp.bfloat16,
+                       matmul_precision: Optional[str] = None
+                       ) -> ModelConfig:
+    """Solar-Open2's published keys -> ``ModelConfig``: periods of one
+    gated NoPE GQA layer (``layer_types`` "full") and ``gqa_interval``
+    gated delta-rule layers ("kda"), every layer's second sublayer the
+    expert layer (a sigmoid router with a correction bias, the chosen
+    scores normalised; ``n_shared_experts`` shared), an untied head, no
+    positional term. ``n_routed_experts`` is the experts HELD, those from
+    ``first_expert`` of the ``routed_experts`` the router addresses (None:
+    all are held). The two bottlenecks' rank is the mixer's head size (the
+    key does not give it). A key this does not map, or a value with no
+    form here (rotary attention, a leading dense layer, full-rank decay
+    projections, a tied head, chosen scores left unnormalised, ``gqa_layers``
+    that are not every ``gqa_interval + 1``-th layer), raises: a silent
+    default under a real model's name would be a guess."""
+    p = published
+    lin = p.get("linear_attn_config") or {}
+    period = p.get("gqa_interval", 0) + 1
+    layers = p.get("num_hidden_layers", 0)
+    wrong = sorted(set(p) - _SOLAR_OPEN2_KEYS) + sorted(
+        "linear_attn_config." + k for k in set(lin) - {
+            "short_conv_kernel_size", "head_dim", "num_heads",
+            "num_kv_heads"}) + [
+        k for k, ok in (
+            ("use_rope", p.get("use_rope") is False),
+            ("partial_rotary_factor", p.get("partial_rotary_factor") == 1),
+            ("first_k_dense_replace", p.get("first_k_dense_replace") == 0),
+            ("kda_use_full_proj", p.get("kda_use_full_proj") is False),
+            ("tie_word_embeddings", p.get("tie_word_embeddings") is False),
+            ("norm_topk_prob", p.get("norm_topk_prob") is True),
+            ("linear_attn_config.num_kv_heads",
+             lin.get("num_kv_heads") in (None, lin.get("num_heads"))),
+            ("num_hidden_layers", layers > 0 and layers % period == 0),
+            ("gqa_layers",
+             [l for l in p.get("gqa_layers", ()) if l < layers]
+             == list(range(0, layers, period))))
+        if not ok]
+    if wrong:
+        raise ValueError(f"{name}: {wrong} as set are not mapped by "
+                         f"solar_open2_config")
+    return ModelConfig(
+        name=name, vocab_size=p["vocab_size"], hidden_size=p["hidden_size"],
+        intermediate_size=p["intermediate_size"], num_layers=layers,
+        num_heads=p["num_attention_heads"],
+        num_kv_heads=p["num_key_value_heads"], head_dim=p["head_dim"],
+        max_seq_len=p["max_position_embeddings"],
+        rope_theta=float(p["rope_theta"]),
+        rms_norm_eps=float(p["rms_norm_eps"]), dtype=dtype,
+        matmul_precision=matmul_precision,
+        num_experts=p["n_routed_experts"],
+        num_experts_per_tok=p["num_experts_per_tok"],
+        moe_intermediate_size=p["moe_intermediate_size"],
+        num_shared_experts=p["n_shared_experts"],
+        router_type="sigmoid_bias",
+        routed_scaling_factor=float(p["routed_scaling_factor"]),
+        moe_routed_experts=routed_experts or p["n_routed_experts"],
+        moe_first_expert=first_expert,
+        layer_types=((("full",) + ("kda",) * (period - 1),
+                      layers // period),),
+        attn_out_gate=bool(p["use_gqa_gate"]),
+        kda_num_heads=lin["num_heads"], kda_head_dim=lin["head_dim"],
+        kda_conv=lin["short_conv_kernel_size"], kda_rank=lin["head_dim"],
+        kda_neg_eigval=bool(p["kda_allow_neg_eigval"]))
+
+
+# Solar-Open2's published keys at test size: two periods of (GQA, KDA, KDA,
+# KDA), 4/2 attention heads x 16, a mixer of 4 heads x 8 (its width 32 is
+# not the hidden size on purpose), a 16-wide router top-4 and one shared
+# expert.
+TINY_SOLAR_OPEN2_KEYS = {
+    "partial_rotary_factor": 1,
+    "linear_attn_config": {"short_conv_kernel_size": 4, "head_dim": 8,
+                           "num_heads": 4, "num_kv_heads": None},
+    "hidden_size": 64, "num_hidden_layers": 8, "num_attention_heads": 4,
+    "head_dim": 16, "num_key_value_heads": 2, "vocab_size": 512,
+    "intermediate_size": 160, "moe_intermediate_size": 32,
+    "rms_norm_eps": 1e-5, "rope_theta": 10000,
+    "tie_word_embeddings": False, "max_position_embeddings": 128,
+    "first_k_dense_replace": 0, "use_rope": False, "gqa_interval": 3,
+    "gqa_layers": [0, 4], "use_gqa_gate": True, "kda_use_full_proj": False,
+    "kda_allow_neg_eigval": True, "n_routed_experts": 8,
+    "n_shared_experts": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "num_experts_per_tok": 4}
+
+
+def tiny_solar_open2_test() -> ModelConfig:
+    """Solar-Open2's layers at test size through the arch map of its
+    published keys, this chip's share of the experts 8 of 16, from the
+    4th."""
+    return solar_open2_config(
+        TINY_SOLAR_OPEN2_KEYS, name="tiny-solar-open2-test", first_expert=4,
+        routed_experts=16, dtype=jnp.float32, matmul_precision="highest")
+
+
 def tiny_test() -> ModelConfig:
     """Small config for unit tests and CPU-mesh dry runs."""
     return ModelConfig(
@@ -926,6 +1077,7 @@ PRESETS = {
     "tiny-longcat-flash-test": tiny_longcat_flash_test,
     "phi-4-mini-flash-reasoning": phi4_mini_flash,
     "tiny-phi4flash-test": tiny_phi4flash_test,
+    "tiny-solar-open2-test": tiny_solar_open2_test,
     "small-test": small_test,
 }
 

@@ -37,8 +37,9 @@ import numpy as np
 from ..ops.attention import NEG_INF, attention
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
-from ..ops import ssm as ssm_ops
-from .config import LAYER_KINDS, ModelConfig, YarnScaling, refuse
+from ..ops import delta_rule, ssm as ssm_ops
+from .config import (LAYER_KINDS, ModelConfig, YarnScaling, pattern_keys,
+                     refuse)
 from .moe import BANKS, MoEStats, expert_ffn
 
 Params = Dict[str, Any]
@@ -156,6 +157,40 @@ def dequantize_pool_kv(q: jnp.ndarray, scale: jnp.ndarray,
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
+def _init_expert_leaves(c: ModelConfig, L: int, dense: Callable,
+                        ks: jax.Array, at: Tuple[int, ...]
+                        ) -> Dict[str, jax.Array]:
+    """An expert layer's leaves, stacked ``L`` deep: the router as wide as
+    it addresses, the banks of the experts held, a bias router's correction
+    bias, the shared expert. ``dense(key, shape, fan_in)`` draws a matrix;
+    ``at``: which of the keys ``ks`` draws the router, the three banks and
+    the shared expert."""
+    D, E, Fe = c.hidden_size, c.num_experts, c.expert_size
+    if c.moe_first_expert + E > c.routed_experts:
+        raise ValueError(
+            f"{c.name}: experts [{c.moe_first_expert}, "
+            f"{c.moe_first_expert + E}) held of {c.routed_experts} routed")
+    out = {"router": dense(ks[at[0]], (L, D, c.router_width), D),
+           "w_gate": dense(ks[at[1]], (L, E, D, Fe), D),
+           "w_up": dense(ks[at[2]], (L, E, D, Fe), D),
+           "w_down": dense(ks[at[3]], (L, E, Fe, D), Fe)}
+    if c.router_type in ("sigmoid_bias", "softmax_bias"):
+        # The per-expert correction bias added to the scores for the
+        # CHOICE only. A trained model's bias evens the load; training
+        # starts it at 0. Named ``*_norm`` because, like a norm's gain,
+        # a seeded-weights filler has to leave it a constant: drawn at
+        # random it would decide the choice in place of the scores.
+        out["router_bias_norm"] = jnp.zeros((L, c.router_width),
+                                            jnp.float32)
+    if c.num_shared_experts:
+        Fs = c.num_shared_experts * Fe
+        kk = jax.random.split(ks[at[4]], 3)
+        out["ws_gate"] = dense(kk[0], (L, D, Fs), D)
+        out["ws_up"] = dense(kk[1], (L, D, Fs), D)
+        out["ws_down"] = dense(kk[2], (L, Fs, D), Fs)
+    return out
+
+
 def _init_layer_stack(c: ModelConfig, key: jax.Array, L: int,
                       expert: bool) -> Dict[str, jax.Array]:
     """One stack of ``L`` layers of the same structure, every leaf with a
@@ -186,33 +221,9 @@ def _init_layer_stack(c: ModelConfig, key: jax.Array, L: int,
         raise ValueError(
             f"{c.name}: a shortcut block is two latent-attention sublayers, "
             f"two dense FFNs and routed experts, on the plain residual")
-    if expert and c.moe_first_expert + c.num_experts > c.routed_experts:
-        raise ValueError(
-            f"{c.name}: experts [{c.moe_first_expert}, "
-            f"{c.moe_first_expert + c.num_experts}) held of "
-            f"{c.routed_experts} routed")
 
     def experts():
-        E, Fe = c.num_experts, c.expert_size
-        out = {"router": dense(ks[7], (L, D, c.router_width), D),
-               "w_gate": dense(ks[4], (L, E, D, Fe), D),
-               "w_up": dense(ks[5], (L, E, D, Fe), D),
-               "w_down": dense(ks[6], (L, E, Fe, D), Fe)}
-        if c.router_type in ("sigmoid_bias", "softmax_bias"):
-            # The per-expert correction bias added to the scores for the
-            # CHOICE only. A trained model's bias evens the load; training
-            # starts it at 0. Named ``*_norm`` because, like a norm's gain,
-            # a seeded-weights filler has to leave it a constant: drawn at
-            # random it would decide the choice in place of the scores.
-            out["router_bias_norm"] = jnp.zeros((L, c.router_width),
-                                                jnp.float32)
-        if c.num_shared_experts:
-            Fs = c.num_shared_experts * Fe
-            kk = jax.random.split(ks[1], 3)
-            out["ws_gate"] = dense(kk[0], (L, D, Fs), D)
-            out["ws_up"] = dense(kk[1], (L, D, Fs), D)
-            out["ws_down"] = dense(kk[2], (L, Fs, D), Fs)
-        return out
+        return _init_expert_leaves(c, L, dense, ks, (7, 4, 5, 6, 1))
 
     if c.shortcut_moe:
         plain = dataclasses.replace(c, shortcut_moe=False)
@@ -322,21 +333,22 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     k_embed, k_layers, k_head = jax.random.split(key, 3)
     D = c.hidden_size
     if c.pattern:
-        return _init_pattern_params(c, k_embed, k_layers)
-    n_dense = c.first_dense_layers if c.num_experts > 0 else 0
-    if not 0 <= n_dense < c.num_layers:
-        raise ValueError(f"{c.name}: first_dense_layers {n_dense} of "
-                         f"{c.num_layers} layers")
-    params: Params = {
-        "embed": (jax.random.normal(k_embed, (c.vocab_size, D), c.dtype)
-                  * jnp.asarray(0.02, c.dtype)),
-        "layers": _init_layer_stack(c, k_layers, c.num_layers - n_dense,
-                                    expert=c.num_experts > 0),
-        "final_norm": jnp.ones((D,), c.dtype),
-    }
-    if n_dense:
-        params["dense_layers"] = _init_layer_stack(
-            c, jax.random.fold_in(k_layers, 1), n_dense, expert=False)
+        params = _init_pattern_params(c, k_embed, k_layers)
+    else:
+        n_dense = c.first_dense_layers if c.num_experts > 0 else 0
+        if not 0 <= n_dense < c.num_layers:
+            raise ValueError(f"{c.name}: first_dense_layers {n_dense} of "
+                             f"{c.num_layers} layers")
+        params = {
+            "embed": (jax.random.normal(k_embed, (c.vocab_size, D), c.dtype)
+                      * jnp.asarray(0.02, c.dtype)),
+            "layers": _init_layer_stack(c, k_layers, c.num_layers - n_dense,
+                                        expert=c.num_experts > 0),
+            "final_norm": jnp.ones((D,), c.dtype),
+        }
+        if n_dense:
+            params["dense_layers"] = _init_layer_stack(
+                c, jax.random.fold_in(k_layers, 1), n_dense, expert=False)
     if not c.tie_word_embeddings:
         params["lm_head"] = (
             jax.random.normal(k_head, (D, c.vocab_size), c.dtype)
@@ -347,30 +359,52 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
 def _init_pattern_params(c: ModelConfig, k_embed: jax.Array,
                          k_layers: jax.Array) -> Params:
     """``init_params`` for a configuration of unlike layers
-    (``c.layer_types``): ``params["layers"]["seg<i>"][<kind>]`` holds the
-    leaves of that kind's layers in segment i, stacked ``repeats`` deep,
-    and each kind has only its own: every kind the two norms (with biases
-    under LayerNorm) and the SwiGLU MLP; "mamba" the mixer's seven
-    (``_mamba1_mix``), at Mamba-1's own start (A = -(1..N), a step size
-    log-uniform on [1e-3, 1e-1] behind the softplus, D 1); "window" and
-    "full" the q/k/v/o projections, the four lambda vectors as the columns
-    of one ``(head_dim, 4)`` leaf (normal * 0.1) and the sub-norm's gain;
+    (``c.layer_types``): ``params["layers"]["seg<i>"][key]`` holds the
+    leaves of one layer of segment i's period (``pattern_keys``: the kind,
+    numbered where the period names it more than once), stacked
+    ``repeats`` deep, and each kind has only its own: every kind the two
+    norms (with biases under LayerNorm) and the second sublayer, a SwiGLU
+    MLP or where the configuration has experts the expert layer
+    (``_init_expert_leaves``); "mamba" the mixer's seven (``_mamba1_mix``),
+    at Mamba-1's own start (A = -(1..N), a step size log-uniform on [1e-3,
+    1e-1] behind the softplus, D 1); "window" and "full" the q/k/v/o
+    projections and, under differential attention, the four lambda vectors
+    as the columns of one ``(head_dim, 4)`` leaf (normal * 0.1) and the
+    sub-norm's gain, else under ``attn_out_gate`` the gate's projection;
     "cross" a query and an output projection with its own lambda and
-    sub-norm; "gmu" two matrices. ``final_norm_bias`` is ``(1, D)``: a
-    seeded-weights filler that reads a leaf's fan-in off its second-to-last
-    axis (``benchmark/weights.py``) then has one to read."""
+    sub-norm; "gmu" two matrices; "kda" the delta-rule mixer's
+    (``_kda_project`` .. ``_kda_out``): ``kda_in`` = [W_q | W_k | W_v], the
+    conv's taps over those channels (no bias), the decay's and the output
+    gate's bottlenecks, ``kda_beta``, the output norm's gain a head, and in
+    float32 whatever the serving dtype ``kda_A_log`` (A uniform on [1, 16])
+    and ``kda_dt_bias`` (a step log-uniform on [1e-3, 1e-1] behind the
+    softplus). ``final_norm_bias`` is ``(1, D)``, as the decay's two
+    vectors are ``(L, 1, .)``: a seeded-weights filler that reads a leaf's
+    fan-in off its second-to-last axis (``benchmark/weights.py``) then has
+    one to read."""
     D, F, I = c.hidden_size, c.intermediate_size, c.mamba_d_ssm
+    kinds = {kind for period, _ in c.layer_types for kind in period}
     if (sum(len(p) * n for p, n in c.layer_types) != c.num_layers
-            or any(len(set(p)) != len(p) or set(p) - set(LAYER_KINDS)
-                   for p, _ in c.layer_types)):
+            or kinds - set(LAYER_KINDS)):
         raise ValueError(f"{c.name}: layer_types {c.layer_types} is not "
-                         f"{c.num_layers} layers of distinct kinds a period, "
-                         f"each one of {LAYER_KINDS}")
-    if not (c.diff_attn and c.tie_word_embeddings and not c.hc_mult
-            and not c.num_experts and not c.mla):
+                         f"{c.num_layers} layers, each one of {LAYER_KINDS}")
+    if c.hc_mult or c.mla or c.shortcut_moe or c.first_dense_layers or (
+            c.num_experts and c.router_type == "softmax"):
         raise ValueError(
-            f"{c.name}: a layer pattern is differential attention over a "
-            f"tied embedding on the plain residual, with dense MLPs")
+            f"{c.name}: a layer pattern runs on the plain residual, without "
+            f"latent attention, a shortcut block or leading dense layers, "
+            f"and its experts under a bias router (no aux loss is carried)")
+    if not c.diff_attn and kinds & {"window", "cross"}:
+        raise ValueError(
+            f"{c.name}: \"window\" and \"cross\" layers are differential "
+            f"attention (diff_attn); plain attention is a \"full\" layer")
+    if "kda" in kinds and ("mamba" in kinds or not (
+            c.kda_num_heads and c.kda_head_dim and c.kda_rank
+            and c.kda_conv > 1)):
+        raise ValueError(f"{c.name}: a \"kda\" layer needs kda_num_heads, "
+                         f"kda_head_dim, kda_rank and kda_conv > 1, and no "
+                         f"\"mamba\" layer beside it (the pool has one "
+                         f"state leaf)")
 
     def dense(key, shape, fan_in):
         scale = jnp.asarray(1.0 / float(fan_in) ** 0.5, c.dtype)
@@ -388,9 +422,12 @@ def _init_pattern_params(c: ModelConfig, k_embed: jax.Array,
 
     def one_kind(kind, key, L):
         ks = jax.random.split(key, 12)
-        lp = {"w_gate": dense(ks[0], (L, D, F), D),
-              "w_up": dense(ks[1], (L, D, F), D),
-              "w_down": dense(ks[2], (L, F, D), F)}
+        if c.num_experts:
+            lp = _init_expert_leaves(c, L, dense, ks, (10, 0, 1, 2, 11))
+        else:
+            lp = {"w_gate": dense(ks[0], (L, D, F), D),
+                  "w_up": dense(ks[1], (L, D, F), D),
+                  "w_down": dense(ks[2], (L, F, D), F)}
         norm(lp, "attn_norm", L)
         norm(lp, "mlp_norm", L)
         if kind == "mamba":
@@ -417,11 +454,36 @@ def _init_pattern_params(c: ModelConfig, k_embed: jax.Array,
                       wk=dense(ks[4], (L, D, c.kv_dim), D),
                       wv=dense(ks[5], (L, D, c.kv_dim), D),
                       wo=dense(ks[6], (L, c.q_dim, D), c.q_dim))
-            diff(lp, ks[7], L)
+            if c.diff_attn:
+                diff(lp, ks[7], L)
+            elif c.attn_out_gate:
+                lp["w_attn_gate"] = dense(ks[7], (L, D, c.q_dim), D)
         elif kind == "cross":
             lp.update(wq=dense(ks[3], (L, D, c.q_dim), D),
                       wo=dense(ks[6], (L, c.q_dim, D), c.q_dim))
             diff(lp, ks[7], L)
+        elif kind == "kda":
+            H, W, R, K = c.kda_num_heads, c.kda_dim, c.kda_rank, c.kda_conv
+            kk = jax.random.split(ks[3], 6)
+            bound = 1.0 / float(K) ** 0.5
+            dt = jnp.exp(jax.random.uniform(
+                ks[7], (L, 1, W), jnp.float32, math.log(1e-3),
+                math.log(1e-1)))
+            lp.update(
+                kda_in=dense(kk[0], (L, D, 3 * W), D),
+                kda_conv_w=jax.random.uniform(ks[4], (L, K, 3 * W), c.dtype,
+                                              -bound, bound),
+                kda_f_down=dense(kk[1], (L, D, R), D),
+                kda_f_up=dense(kk[2], (L, R, W), R),
+                kda_dt_bias=dt + jnp.log(-jnp.expm1(-dt)),
+                kda_A_log=jnp.log(jax.random.uniform(
+                    ks[5], (L, 1, H), jnp.float32, 1.0, 16.0)),
+                kda_beta=dense(kk[3], (L, D, H), D),
+                kda_g_down=dense(kk[4], (L, D, R), D),
+                kda_g_up=dense(kk[5], (L, R, W), R),
+                kda_g_bias=jnp.zeros((L, 1, W), c.dtype),
+                kda_o_norm=jnp.ones((L, c.kda_head_dim), c.dtype),
+                kda_out=dense(ks[6], (L, W, D), W))
         else:       # "gmu"
             lp.update(gmu_in=dense(ks[3], (L, D, I), D),
                       gmu_out=dense(ks[4], (L, I, D), I))
@@ -431,9 +493,10 @@ def _init_pattern_params(c: ModelConfig, k_embed: jax.Array,
         "embed": (jax.random.normal(k_embed, (c.vocab_size, D), c.dtype)
                   * jnp.asarray(0.02, c.dtype)),
         "layers": {
-            f"seg{i}": {kind: one_kind(
+            f"seg{i}": {name: one_kind(
                 kind, jax.random.fold_in(k_layers, 16 * i + j), n)
-                for j, kind in enumerate(period)}
+                for j, (name, kind) in enumerate(zip(pattern_keys(period),
+                                                     period))}
             for i, (period, n) in enumerate(c.layer_types)},
         "final_norm": jnp.ones((D,), c.dtype)}
     if c.norm == "layer":
@@ -1269,17 +1332,108 @@ def _diff_out(c: ModelConfig, lp: Dict[str, jax.Array], out: jax.Array,
                       "bse,ed->bsd")
 
 
+def _plain_qkv(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array):
+    """Plain GQA with no positional term, h (B, S, D) -> q (B, S, Hq, d),
+    k, v (B, S, Hkv, d)."""
+    b, s, _ = h.shape
+    return (_dense(h, lp, "wq", "bsd,de->bse").reshape(
+                b, s, c.num_heads, c.head_dim),
+            _dense(h, lp, "wk", "bsd,de->bse").reshape(
+                b, s, c.num_kv_heads, c.head_dim),
+            _dense(h, lp, "wv", "bsd,de->bse").reshape(
+                b, s, c.num_kv_heads, c.head_dim))
+
+
+def _gated_out(c: ModelConfig, lp: Dict[str, jax.Array], out: jax.Array,
+               h: jax.Array) -> jax.Array:
+    """Plain attention's output (B, S, Hq, d) -> the sublayer's (B, S, D):
+    under ``attn_out_gate`` times ``sigmoid(h W_g)`` elementwise, h the
+    layer's normed input, the gate in float32; then ``W_o``."""
+    b, s = out.shape[:2]
+    o = out.reshape(b, s, c.q_dim)
+    with jax.named_scope("attn.out"):
+        if c.attn_out_gate:
+            gate = jax.nn.sigmoid(_dense(h, lp, "w_attn_gate",
+                                         "bsd,de->bse").astype(jnp.float32))
+            o = (o.astype(jnp.float32) * gate).astype(h.dtype)
+        return _dense(o, lp, "wo", "bse,ed->bsd")
+
+
+# the divisor of q's and k's normalisation: ||x||^2 + this
+_KDA_L2_EPS = 1e-6
+
+
+def _kda_project(c: ModelConfig, lp: Dict[str, jax.Array],
+                 h: jax.Array) -> jax.Array:
+    """The delta-rule mixer's input projection of the layer's normed input
+    h (B, S, D): ``[q | k | v] = h [W_q | W_k | W_v]`` (B, S, 3 W), the
+    conv's input."""
+    return _dense(h, lp, "kda_in", "bsd,de->bse")
+
+
+def _kda_qkv(c: ModelConfig, conv_out: jax.Array):
+    """The conv's output (B, S, 3 W) f32 -> what the recurrence reads, in
+    float32: ``q = silu(.) / ||.|| / sqrt(d)``, ``k = silu(.) / ||.||``,
+    ``v = silu(.)``, each (B, S, H, d)."""
+    b, s, _ = conv_out.shape
+    a = jax.nn.silu(conv_out).reshape(b, s, 3, c.kda_num_heads,
+                                      c.kda_head_dim)
+    unit = lambda x: x * jax.lax.rsqrt(
+        jnp.sum(x * x, axis=-1, keepdims=True) + _KDA_L2_EPS)
+    return (unit(a[:, :, 0]) * (1.0 / float(c.kda_head_dim) ** 0.5),
+            unit(a[:, :, 1]), a[:, :, 2])
+
+
+def _kda_gates(c: ModelConfig, lp: Dict[str, jax.Array], h: jax.Array):
+    """h (B, S, D) -> (g (B, S, H, d) f32, the log decay a key channel:
+    ``-exp(A_log) softplus(W_up (W_down h) + dt_bias)``; beta (B, S, H)
+    f32: ``sigmoid(h W_beta)``, times 2 under ``kda_neg_eigval``)."""
+    b, s, _ = h.shape
+    f = _dense(_dense(h, lp, "kda_f_down", "bsd,dr->bsr"), lp, "kda_f_up",
+               "bsr,re->bse").astype(jnp.float32) + lp["kda_dt_bias"][0]
+    g = (-jnp.exp(lp["kda_A_log"][0].astype(jnp.float32))[:, None]
+         * jax.nn.softplus(f).reshape(b, s, c.kda_num_heads, c.kda_head_dim))
+    beta = jax.nn.sigmoid(_dense(h, lp, "kda_beta",
+                                 "bsd,dh->bsh").astype(jnp.float32))
+    return g, beta * (2.0 if c.kda_neg_eigval else 1.0)
+
+
+def _kda_out(c: ModelConfig, lp: Dict[str, jax.Array], o: jax.Array,
+             h: jax.Array) -> jax.Array:
+    """The readout o (B, S, H, d) f32 -> the mixer's output (B, S, D):
+    ``W_o [RMSNorm_head(o) * sigmoid(W_up (W_down h) + b)]``, one gain of d
+    shared by the heads, norm and gate in float32."""
+    b, s = o.shape[:2]
+    with jax.named_scope("kda.out"):
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                              + c.rms_norm_eps)
+        o = (o * lp["kda_o_norm"].astype(jnp.float32)).reshape(
+            b, s, c.kda_dim)
+        gate = jax.nn.sigmoid(
+            _dense(_dense(h, lp, "kda_g_down", "bsd,dr->bsr"), lp,
+                   "kda_g_up", "bsr,re->bse").astype(jnp.float32)
+            + lp["kda_g_bias"][0].astype(jnp.float32))
+        return _dense((o * gate).astype(h.dtype), lp, "kda_out",
+                      "bse,ed->bsd")
+
+
 def _pattern_layer(c: ModelConfig, kind: str, lp: Dict[str, jax.Array],
                    x: jax.Array, m: jax.Array, carry, mixer: Callable,
                    at: Dict[str, Any]):
     """One layer of a ``layer_types`` configuration, for the no-cache and
-    the paged forward alike: ``x + mix(Norm(x))`` then ``x + MLP(Norm(
-    x))``, both through ``_residual``. ``mixer(kind, lp, h, m, carry, at)``
-    is the layer's first sublayer on its normed input -> (its output, the
-    memory, carry'); ``at`` says where the layer is (``_pattern_scan``).
+    the paged forward alike: ``x + mix(Norm(x))`` then ``x + FFN(Norm(
+    x))``, both through ``_residual``; the FFN is the dense SwiGLU or the
+    expert layer (``_ffn``). ``mixer(kind, lp, h, m, carry, at)`` is the
+    layer's first sublayer on its normed input -> (its output, the memory,
+    carry'); ``at`` says where the layer is (``_pattern_scan``), which
+    entries an expert layer's stats count (``count``) and, where ``lp``
+    holds the whole stack's expert banks, the layer's place in them
+    (``rep``). -> (x, m, carry', the expert layer's ``MoEStats`` or None).
     The scope names (``ssm.mamba``, ``attn.window``, ``attn.full``, ``gmu``,
-    ``attn.cross``, ``mlp``) are docs/observability.md's."""
-    scope = {"mamba": "ssm.mamba", "gmu": "gmu"}.get(kind, "attn." + kind)
+    ``attn.cross``, ``kda``, ``mlp``, ``moe``) are docs/observability.md's.
+    """
+    scope = {"mamba": "ssm.mamba", "gmu": "gmu", "kda": "kda"}.get(
+        kind, "attn." + kind)
 
     def mix(x_in):
         out, m2, carry2 = mixer(kind, lp, _norm(c, x_in, lp, "attn_norm"),
@@ -1288,16 +1442,16 @@ def _pattern_layer(c: ModelConfig, kind: str, lp: Dict[str, jax.Array],
 
     with jax.named_scope(scope):
         x, (m, carry), _ = _residual(c, lp, x, "attn", mix)
-    with jax.named_scope("mlp"):
-        x, _, _ = _residual(
-            c, lp, x, "mlp", lambda x_in: (_swiglu(
-                _norm(c, x_in, lp, "mlp_norm"), lp, "w_gate", "w_up",
-                "w_down"), None))
-    return x, m, carry
+    with jax.named_scope("moe" if "router" in lp else "mlp"):
+        x, (_, stats), _ = _residual(
+            c, lp, x, "mlp", lambda x_in: _ffn(
+                c, lp, _norm(c, x_in, lp, "mlp_norm"), at.get("count"),
+                at.get("rep")))
+    return x, m, carry, stats
 
 
 def _pattern_scan(c: ModelConfig, params: Params, x: jax.Array, carry,
-                  mixer: Callable, remat: bool = False):
+                  mixer: Callable, remat: bool = False, count=None):
     """The layers of a ``layer_types`` configuration: one scan a segment,
     whose body runs the period's unlike layers in order, with the memory
     ``m`` (the last "mamba" layer's scan output, read by the "gmu" layers)
@@ -1305,10 +1459,15 @@ def _pattern_scan(c: ModelConfig, params: Params, x: jax.Array, carry,
     in the scan's carry. ``at`` tells a layer its number in the model
     (``layer``), among the layers of its kind (``index``, which addresses
     that kind's cache leaves), and the ``index`` of the last "full" layer
-    before it (``full``, whose KV a "cross" layer reads). -> (x, carry')."""
+    before it (``full``, whose KV a "cross" layer reads). An expert
+    configuration's banks stay whole outside the scan's xs (each layer's
+    grouped products address its experts inside them, ``moe._grouped``),
+    and its layers' ``MoEStats`` over the entries ``count`` marks are
+    merged in the carry. -> (x, carry', ``MoEStats`` or None)."""
     m = jnp.zeros(x.shape[:-1] + (c.mamba_d_ssm,), x.dtype)
     seen = {kind: 0 for period, _ in c.layer_types for kind in period}
     first = 0
+    acc = MoEStats.zeros(c) if c.num_experts else None
     layer_fn = _pattern_layer
     if remat:
         layer_fn = jax.checkpoint(
@@ -1317,27 +1476,47 @@ def _pattern_scan(c: ModelConfig, params: Params, x: jax.Array, carry,
                     if c.remat == "dots" else None))
     for i, (period, n) in enumerate(c.layer_types):
         base, start = dict(seen), first
+        names = pattern_keys(period)
+        seg = params["layers"][f"seg{i}"]
+        banks = {name: {k: v for k, v in seg[name].items() if k in BANKS}
+                 for name in names} if acc is not None else None
+        if banks is not None:
+            seg = {name: {k: v for k, v in seg[name].items()
+                          if k not in BANKS} for name in names}
 
-        def body(state, inp, period=period, base=base, start=start):
-            x, m, carry = state
+        def body(state, inp, period=period, base=base, start=start,
+                 names=names, banks=banks):
+            x, m, carry, acc = state
             lps, rep = inp
-            for j, kind in enumerate(period):
+            for j, (name, kind) in enumerate(zip(names, period)):
                 full = base.get("full", 0) - 1
                 if "full" in period[:j + 1]:
                     full = full + rep + 1
+                # "index": the layer among those of its kind, of which the
+                # period may name more than one
+                each = period.count(kind)
                 at = {"layer": start + rep * len(period) + j,
-                      "index": base[kind] + rep, "full": full}
-                x, m, carry = layer_fn(c, kind, lps[kind], x, m, carry,
-                                       mixer, at)
-            return (x, m, carry), None
+                      "index": base[kind] + (
+                          rep if each == 1
+                          else rep * each + period[:j].count(kind)),
+                      "full": full}
+                lp = lps[name]
+                if banks is not None:
+                    lp = {**lp, **banks[name]}
+                    at.update(count=count, rep=rep)
+                x, m, carry, stats = layer_fn(c, kind, lp, x, m, carry,
+                                              mixer, at)
+                if stats is not None:
+                    acc = acc.merge(stats)
+            return (x, m, carry, acc), None
 
-        (x, m, carry), _ = jax.lax.scan(
-            body, (x, m, carry),
-            (params["layers"][f"seg{i}"], jnp.arange(n, dtype=jnp.int32)))
+        (x, m, carry, acc), _ = jax.lax.scan(
+            body, (x, m, carry, acc),
+            (seg, jnp.arange(n, dtype=jnp.int32)))
         for kind in period:
             seen[kind] += n
         first += n * len(period)
-    return x, carry
+    return x, carry, acc
 
 
 def _dense_mixer(c: ModelConfig, attn_mask, kind: str,
@@ -1359,6 +1538,20 @@ def _dense_mixer(c: ModelConfig, attn_mask, kind: str,
         return _mamba1_out(c, lp, y, z) + (kv,)
     if kind == "gmu":
         return _gmu(lp, h, m), m, kv
+    if kind == "kda":
+        with jax.named_scope("kda.conv"):
+            q, k, v = _kda_qkv(c, ssm_ops.conv_dense(
+                _kda_project(c, lp, h), lp["kda_conv_w"], None))
+        with jax.named_scope("kda.gates"):
+            g, beta = _kda_gates(c, lp, h)
+        with jax.named_scope("kda.chunk"):
+            o = delta_rule.kda_dense(q, k, v, g, beta)
+        return _kda_out(c, lp, o, h), m, kv
+    if not c.diff_attn:
+        q, k, v = _plain_qkv(c, lp, h)
+        out = attention(q, k, v, q_offset=0, kv_mask=attn_mask, causal=True,
+                        scale=1.0 / float(c.head_dim) ** 0.5)
+        return _gated_out(c, lp, out, h), m, (k, v)
     q = _diff_queries(c, lp, h)
     if kind == "cross":
         k, v = kv
@@ -1888,6 +2081,7 @@ def forward_paged(
     with_moe_stats: bool = False,  # static: also return MoEStats
     with_mhc_stats: bool = False,  # static: also return the Sinkhorn error
     with_attn_stats: bool = False,  # static: also return the shared reads
+    with_kda_stats: bool = False,  # static: also return the delta rule's
     logit_entries: Optional[jax.Array] = None,  # (S,) int32 — the entries
                                   # whose logits are wanted (None = all)
 ):
@@ -1953,10 +2147,15 @@ def forward_paged(
     (``ops.paged_attention.plan_rows``): the block reads the step did
     not make because rows that hold the same physical blocks attended
     them together (``kv_blocks_saved``), and the group items that did
-    (``attn_group_items``); zeros where the gather runs."""
+    (``attn_group_items``); zeros where the gather runs.
+
+    ``with_kda_stats=True`` (a configuration with "kda" layers) returns one
+    more value, last: ``(entries that went through the chunked form, int32;
+    the largest |o| any delta-rule layer read out before its head norm,
+    float32)``."""
     c = config
     with _precision(c):
-        logits, pool, moe, err, shared = _forward_paged_impl(
+        logits, pool, moe, err, shared, kda = _forward_paged_impl(
             params, c, tokens, pool=pool, tables=tables,
             seq_row=seq_row, positions=positions, write_block=write_block,
             write_off=write_off, use_kernel=use_kernel, adapters=adapters,
@@ -1965,7 +2164,8 @@ def forward_paged(
         shared = jnp.zeros((2,), jnp.int32)
     return ((logits, pool) + ((moe,) if with_moe_stats else ())
             + ((err,) if with_mhc_stats else ())
-            + ((shared,) if with_attn_stats else ()))
+            + ((shared,) if with_attn_stats else ())
+            + ((kda,) if with_kda_stats else ()))
 
 
 def reads_pool_in_place(c: ModelConfig, use_kernel: Optional[bool]) -> bool:
@@ -2028,9 +2228,11 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
       window plan (``ops.paged_attention.plan_rows(window=)``): the ring
       holds a window and a step's entries, so the writes of a step never
       reach what its queries read, and a chunk that crosses the window's
-      edge is whole prefill.
-    * "mamba" layers read and write their rows' state and conv window
-      (``ops.ssm.conv_flat``, ``scan1_flat``) once a run.
+      edge is whole prefill. A pattern without them has no rings
+      (``pool.rows`` is a ``StateRows``).
+    * "mamba" and "kda" layers read and write their rows' state and conv
+      window (``ops.ssm.conv_flat``, ``scan1_flat``,
+      ``ops.delta_rule.kda_flat``) once a run.
 
     Entries that keep no write (padding, dropped writes) advance no state
     and write no ring slot. -> the tuple ``_forward_paged_impl`` returns."""
@@ -2042,27 +2244,31 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
     hkv = c.cache_kv_heads
     fold = hkv // pool.k.shape[3]
     bs = pool.k.shape[2] // fold
-    state, window, win_k, win_v = pool.rows
+    state, window = pool.rows.ssm, pool.rows.conv
+    rings = c.kind_layers("window") > 0
     rows_r = tables.shape[0]
-    ring_blocks = win_k.shape[2] // (bs * fold)
-    cap = ring_blocks * bs
-    if t > cap - c.layer_window + 1:
-        raise ValueError(
-            f"{c.name}: a step of {t} entries over rings of {cap} "
-            f"positions (window {c.layer_window}): the pool was made for "
-            f"fewer step_tokens")
     keep = write_block < pool.k.shape[1]
     scale = 1.0 / float(c.head_dim) ** 0.5
     kernel = reads_pool_in_place(c, use_kernel)
-    # the rings as blocks: (Lw, rows x ring_blocks, BS x f, Hkv / f, D)
-    as_blocks = lambda a: a.reshape(
-        (a.shape[0], a.shape[1] * ring_blocks, bs * fold) + a.shape[3:])
-    ring_shape = win_k.shape
-    win_k, win_v = as_blocks(win_k), as_blocks(win_v)
-    ring_tbl = ring_tables(rows_r, tables.shape[1], ring_blocks)
-    ring_block = jnp.where(
-        keep, seq_row * ring_blocks + (positions // bs) % ring_blocks,
-        win_k.shape[1])
+    win_k = win_v = ring_tbl = ring_block = None
+    if rings:
+        win_k, win_v = pool.rows.win_k, pool.rows.win_v
+        ring_blocks = win_k.shape[2] // (bs * fold)
+        cap = ring_blocks * bs
+        if t > cap - c.layer_window + 1:
+            raise ValueError(
+                f"{c.name}: a step of {t} entries over rings of {cap} "
+                f"positions (window {c.layer_window}): the pool was made "
+                f"for fewer step_tokens")
+        # the rings as blocks: (Lw, rows x ring_blocks, BS x f, Hkv / f, D)
+        as_blocks = lambda a: a.reshape(
+            (a.shape[0], a.shape[1] * ring_blocks, bs * fold) + a.shape[3:])
+        ring_shape = win_k.shape
+        win_k, win_v = as_blocks(win_k), as_blocks(win_v)
+        ring_tbl = ring_tables(rows_r, tables.shape[1], ring_blocks)
+        ring_block = jnp.where(
+            keep, seq_row * ring_blocks + (positions // bs) % ring_blocks,
+            win_k.shape[1])
     row_plan = win_plan = shared = None
     if kernel:
         from ..ops.paged_attention import (group_tile, paged_attention_rows,
@@ -2072,15 +2278,19 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
                 seq_row, positions, block_size=bs,
                 table_width=tables.shape[1], q_tile=query_tile(c.num_heads),
                 tables=tables, group_tile=group_tile(c.num_heads))
-            win_plan = plan_rows(
-                seq_row, positions, block_size=bs,
-                table_width=tables.shape[1], q_tile=query_tile(c.num_heads),
-                window=c.layer_window)
+            if rings:
+                win_plan = plan_rows(
+                    seq_row, positions, block_size=bs,
+                    table_width=tables.shape[1],
+                    q_tile=query_tile(c.num_heads), window=c.layer_window)
             shared = jnp.stack([row_plan.kv_blocks_saved,
                                 row_plan.group_items])
     with jax.named_scope("ssm.run_plan"):
         run_plan = ssm_ops.plan_runs(seq_row, positions, keep,
                                      num_rows=rows_r)
+    # a delta-rule configuration's step also says how many entries its
+    # chunked form took and the largest readout any layer computed
+    kda = (jnp.zeros((), jnp.float32) if c.kind_layers("kda") else None)
 
     def attend(q, k_leaf, v_leaf, layer, tbl, plan, span):
         """q (T, 1, Hq, D) over one layer of folded leaves -> the same
@@ -2104,7 +2314,7 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
                              scale=scale)
 
     def mixer(kind, lp, h, m, leaves, at):
-        k_leaf, v_leaf, state, window, win_k, win_v = leaves
+        k_leaf, v_leaf, state, window, win_k, win_v, kda = leaves
         if kind == "mamba":
             u_in, z = _mamba1_in(c, lp, h)
             with jax.named_scope("ssm.conv"):
@@ -2120,6 +2330,29 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
             out, m = _mamba1_out(c, lp, y[:, None], z)
         elif kind == "gmu":
             out = _gmu(lp, h, m)
+        elif kind == "kda":
+            with jax.named_scope("kda.conv"):
+                conv, window = ssm_ops.conv_flat(
+                    _kda_project(c, lp, h)[:, 0], lp["kda_conv_w"], None,
+                    window, at["index"], seq_row, run_plan)
+                q, k, v = _kda_qkv(c, conv[:, None])
+            with jax.named_scope("kda.gates"):
+                g, beta = _kda_gates(c, lp, h)
+            o, state = delta_rule.kda_flat(
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
+                at["index"], seq_row, run_plan)
+            kda = jnp.maximum(kda, jnp.max(jnp.abs(o)))
+            out = _kda_out(c, lp, o[:, None], h)
+        elif not c.diff_attn:
+            with jax.named_scope("attn.qkv"):
+                q, k, v = _plain_qkv(c, lp, h)
+            with jax.named_scope("attn.kv_write"):
+                k_leaf = _write_rows(k_leaf, at["index"], write_block,
+                                     write_off, k[:, 0])
+                v_leaf = _write_rows(v_leaf, at["index"], write_block,
+                                     write_off, v[:, 0])
+            out = _gated_out(c, lp, attend(q, k_leaf, v_leaf, at["index"],
+                                           tables, row_plan, 0), h)
         else:
             with jax.named_scope("attn.qkv"):
                 q = _diff_queries(c, lp, h)
@@ -2144,16 +2377,21 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
                              at["index" if kind == "full" else "full"],
                              tables, row_plan, 0)
             out = _diff_out(c, lp, got, at["layer"])
-        return out, m, (k_leaf, v_leaf, state, window, win_k, win_v)
+        return out, m, (k_leaf, v_leaf, state, window, win_k, win_v, kda)
 
-    x, leaves = _pattern_scan(
-        c, params, x, (pool.k, pool.v, state, window, win_k, win_v), mixer)
-    k_leaf, v_leaf, state, window, win_k, win_v = leaves
+    x, leaves, moe = _pattern_scan(
+        c, params, x, (pool.k, pool.v, state, window, win_k, win_v, kda),
+        mixer, count=keep)
+    k_leaf, v_leaf, state, window, win_k, win_v, kda = leaves
     pool = pool._replace(k=k_leaf, v=v_leaf, rows=type(pool.rows)(
-        state, window, win_k.reshape(ring_shape), win_v.reshape(ring_shape)))
+        state, window, *((win_k.reshape(ring_shape),
+                          win_v.reshape(ring_shape)) if rings else ())))
+    if kda is not None:
+        kda = (jnp.sum(jnp.where(run_plan.row_len >= 2, run_plan.row_len, 0)
+                       ).astype(jnp.int32), kda)
     with jax.named_scope("lm_head"):
         logits = _lm_head(c, params, x, logit_entries, flat=True)
-    return logits, pool, None, None, shared
+    return logits, pool, moe, None, shared, kda
 
 
 def _forward_paged_impl(params, c, tokens, *, pool, tables,
@@ -2298,7 +2536,7 @@ def _forward_paged_impl(params, c, tokens, *, pool, tables,
 
     with jax.named_scope("lm_head"):
         logits = _lm_head(c, params, x, logit_entries, flat=True)
-    return logits, pool._replace(**upd), moe, err, shared
+    return logits, pool._replace(**upd), moe, err, shared, None
 
 
 def count_params(params: Params) -> int:

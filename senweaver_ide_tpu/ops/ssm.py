@@ -45,7 +45,7 @@ entries, and writes the row back (``_advance_long``).
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -133,14 +133,17 @@ def _advance(cum: jax.Array, cum_last: jax.Array, dt: jax.Array,
 
 # -- dense: whole sequences from a zero state ------------------------------
 
-def conv_dense(xbc: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+def conv_dense(xbc: jax.Array, w: jax.Array,
+               b: Optional[jax.Array]) -> jax.Array:
     """Causal depthwise conv over (B, S, C), zeros before position 0:
     ``out_t = sum_j w[j] in_{t-(K-1)+j} + b``, summed in float32. w (K, C),
-    b (C,). -> (B, S, C) f32."""
+    b (C,) or None: no bias. -> (B, S, C) f32."""
     k, s = w.shape[0], xbc.shape[1]
-    w, b = w.astype(jnp.float32), b.astype(jnp.float32)
+    w = w.astype(jnp.float32)
+    bias = None if b is None else b.astype(jnp.float32)
     padded = jnp.pad(xbc.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    return sum(w[j] * padded[:, j:j + s] for j in range(k)) + b
+    out = sum(w[j] * padded[:, j:j + s] for j in range(k))
+    return out if bias is None else out + bias
 
 
 def scan_dense(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
@@ -182,8 +185,8 @@ def scan_dense(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 
 # -- flat: the fused step's entries over row-addressed state ---------------
 
-def conv_flat(xbc: jax.Array, w: jax.Array, b: jax.Array, conv: jax.Array,
-              layer: jax.Array, seq_row: jax.Array,
+def conv_flat(xbc: jax.Array, w: jax.Array, b: Optional[jax.Array],
+              conv: jax.Array, layer: jax.Array, seq_row: jax.Array,
               plan: RunPlan) -> Tuple[jax.Array, jax.Array]:
     """``conv_dense`` for the flat batch: xbc (T, C) the entries' conv
     inputs, ``conv`` (L, rows, K-1, C) each row's last K-1 inputs, oldest
@@ -195,8 +198,11 @@ def conv_flat(xbc: jax.Array, w: jax.Array, b: jax.Array, conv: jax.Array,
     r = plan.row_len.shape[0]
     win = conv[layer, :r]                                    # (R, K-1, C)
     mine = jnp.where(plan.fresh[:, None, None], 0, win[seq_row])
-    w, b = w.astype(jnp.float32), b.astype(jnp.float32)
-    out = w[k - 1] * xbc.astype(jnp.float32) + b
+    w = w.astype(jnp.float32)
+    bias = None if b is None else b.astype(jnp.float32)
+    out = w[k - 1] * xbc.astype(jnp.float32)
+    if bias is not None:
+        out = out + bias
     for back in range(1, k):
         earlier = jnp.pad(xbc, ((back, 0), (0, 0)))[:t]
         slot = jnp.clip(k - 1 - back + plan.local, 0, k - 2)

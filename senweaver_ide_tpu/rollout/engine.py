@@ -321,6 +321,7 @@ def _paged_fused_step(params: Params, config: ModelConfig,
         samplers = jnp.full((rows,), t, jnp.int32).at[
             jnp.where((feed & FEED_PUT) > 0, seq_row, rows)].set(
                 jnp.arange(t, dtype=jnp.int32), mode="drop")
+    has_kda = config.kind_layers("kda") > 0
     logits, pool, *stats = forward_paged(
         params, config, tokens, pool=pool,
         tables=tables, seq_row=seq_row, positions=positions,
@@ -328,7 +329,8 @@ def _paged_fused_step(params: Params, config: ModelConfig,
         use_kernel=use_kernel, adapters=adapters, adapter_ids=adapter_ids,
         with_moe_stats=config.num_experts > 0,
         with_mhc_stats=config.hc_mult > 0, with_attn_stats=True,
-        logit_entries=samplers)
+        with_kda_stats=has_kda, logit_entries=samplers)
+    kda = stats.pop() if has_kda else None
     shared = stats.pop()
     next_tok = sample_token(logits, step_key, temperature=sample.temperature,
                             top_k=sample.top_k, top_p=sample.top_p)
@@ -364,6 +366,14 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     # the rows' tables (``forward_paged``: block reads not made, group
     # items), the same way (``_collect`` cuts them off again).
     next_tok = jnp.concatenate([next_tok, shared.astype(next_tok.dtype)])
+    if kda is not None:
+        # A delta-rule model's step also says what its mixers did, behind
+        # everything else: the entries its chunked form took behind the
+        # tokens, the largest readout behind the log-probs
+        # (``_note_kda_step`` reads and cuts them).
+        next_tok = jnp.concatenate(
+            [next_tok, kda[0][None].astype(next_tok.dtype)])
+        logp = jnp.concatenate([logp, kda[1][None].astype(logp.dtype)])
     return next_tok, logp, pool, key, cur
 
 
@@ -1014,6 +1024,21 @@ class RolloutEngine:
                             "Picks that fell on an expert this chip "
                             "holds: the pairs its grouped products "
                             "computed."))
+            # A delta-rule model's fused step reports its mixers the same
+            # way, last behind the step's tokens and log-probs.
+            self._kda_meters = None
+            if config.kind_layers("kda"):
+                self._kda_meters = (
+                    get_registry().counter(
+                        "senweaver_kda_chunk_entries_total",
+                        "Entries of the fused steps that went through the "
+                        "delta rule's chunked form (prefill runs of two or "
+                        "more entries)."),
+                    get_registry().gauge(
+                        "senweaver_kda_readout_absmax",
+                        "Largest |o| any delta-rule layer read out of its "
+                        "state, before the head norm, in the last fused "
+                        "step."))
             # A multi-stream model's fused step reports its residual
             # maps the same way, behind the step's log-probs.
             self._mhc_gauge = None
@@ -3816,6 +3841,8 @@ class RolloutEngine:
         # launch was unqueued, where the launch came after it
         self._fetched_at = get_profiler().end_step(
             "engine.fused_step", fly.t_launch, self._fetched_at)
+        if self._kda_meters is not None:
+            toks, logps = self._note_kda_step(fly.span, toks, logps)
         # the step's last two: the block reads its attention did not make
         # because rows that hold the same blocks attended them together
         toks, (saved, group_items) = toks[:-2], toks[-2:]
@@ -3866,6 +3893,20 @@ class RolloutEngine:
                             * self.config.num_expert_layers)
                 st.set_attr("zero_picks", share[0])
                 st.set_attr("local_pairs", share[1])
+
+    def _note_kda_step(self, st, toks, logps):
+        # guarded-by: caller
+        """A delta-rule model's step: publish what arrived last behind the
+        step's tokens (the entries its chunked form took) and log-probs
+        (the largest readout), and cut both off."""
+        entries, absmax = int(toks[-1]), float(logps[-1])
+        counter, gauge = self._kda_meters
+        counter.inc(entries)
+        gauge.set(absmax)
+        if st is not None:
+            st.set_attr("kda_chunk_entries", entries)
+            st.set_attr("kda_readout_absmax", absmax)
+        return toks[:-1], logps[:-1]
 
     def _note_mhc_step(self, st, logps) -> None:
         # guarded-by: caller

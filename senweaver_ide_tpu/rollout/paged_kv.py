@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import threading
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 
@@ -156,9 +157,13 @@ class StateRows(NamedTuple):
     per-window state can sit beside it the same way.
 
     ``ssm`` ``(L, rows, H, P, N)`` float32: a state-space mixer's state
-    (Mamba-1's: ``(L, rows, N, I)``, the channels on the lanes);
-    ``conv`` ``(L, rows, K-1, C)``: the last K-1 inputs of its conv, oldest
-    first (``ops/ssm.py``)."""
+    (Mamba-1's: ``(L, rows, N, I)``, the channels on the lanes; a
+    delta-rule layer's: a matrix a head, ``(L, rows, H, K, V)``,
+    ``ops/delta_rule.py``); ``conv`` ``(L, rows, K-1, C)``: the last K-1
+    inputs of its conv, oldest first (``ops/ssm.py``). L counts the layers
+    that hold such state: all of them, or a layer pattern's layers of that
+    kind (:func:`_state_leaves`). A pattern without "window" layers is
+    served by this class as it is; one with them by :class:`RingRows`."""
 
     ssm: jnp.ndarray
     conv: jnp.ndarray
@@ -340,44 +345,55 @@ def cache_kinds(config: ModelConfig, block_size: int = 16,
         out.append(CacheKind(
             "window", "row", c.kind_layers("window"), 2 * leaf * item,
             window_capacity(c, block_size, step_tokens)))
-    if c.ssm:
-        mixers = c.kind_layers("mamba") if c.pattern else c.num_layers
-        out.append(CacheKind(
-            "ssm", "row", mixers, 4 * c.mamba_d_state * (
-                c.mamba_d_ssm if c.mamba_dt_rank
-                else c.mamba_n_heads * c.mamba_d_head), 1))
-        out.append(CacheKind(
-            "conv", "row", mixers,
-            (c.mamba_d_conv - 1) * c.ssm_conv_dim * item, 1))
+    out += [CacheKind(kind, "row", shape[0], math.prod(shape[2:])
+                      * jnp.dtype(dtype).itemsize, 1)
+            for kind, (shape, dtype) in _state_leaves(c, 1).items()]
     return out
+
+
+def _state_leaves(config: ModelConfig, num_rows: int) -> dict:
+    """``{"ssm": (shape, dtype), "conv": (shape, dtype)}`` of ``config``'s
+    row-addressed mixer state, ``(layers of the kind, rows, ...)``: the
+    state in float32 (it is a sum over thousands of steps), the conv's
+    window in the serving dtype (it holds projection outputs as they
+    are). Mamba-2 in every block ``(H, P, N)``; a pattern's Mamba-1 layers
+    ``(N, I)``, the channels on the lanes; its delta-rule layers a matrix a
+    head ``(H, K, V)`` and the window of [q | k | v]. Empty where the
+    configuration has no such mixer."""
+    c = config
+    if not c.ssm:
+        return {}
+    if c.kind_layers("kda"):
+        mixers = c.kind_layers("kda")
+        state = (c.kda_num_heads, c.kda_head_dim, c.kda_head_dim)
+        window = (c.kda_conv - 1, 3 * c.kda_dim)
+    else:
+        mixers = c.kind_layers("mamba") if c.pattern else c.num_layers
+        state = ((c.mamba_d_state, c.mamba_d_ssm) if c.mamba_dt_rank
+                 else (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state))
+        window = (c.mamba_d_conv - 1, c.ssm_conv_dim)
+    return {"ssm": ((mixers, num_rows) + state, jnp.float32),
+            "conv": ((mixers, num_rows) + window, c.dtype)}
 
 
 def init_state_rows(config: ModelConfig, num_rows: int,
                     block_size: int = 16, step_tokens: int = 0):
-    """Zeroed row-addressed state for ``config``'s state-space mixer:
-    the state in float32 (it is a sum over thousands of steps), the conv's
-    window in the serving dtype (it holds projection outputs as they
-    are). A layer pattern's leaves lead with the layers of their own kind
-    ("mamba", "window"), and its rings are sized by ``block_size`` and
-    ``step_tokens`` (:func:`window_capacity`)."""
+    """Zeroed row-addressed state for ``config``'s mixers
+    (:func:`_state_leaves`): a :class:`StateRows`, or where a layer pattern
+    has "window" layers a :class:`RingRows` with their rings beside it,
+    sized by ``block_size`` and ``step_tokens`` (:func:`window_capacity`).
+    A pattern's leaves lead with the layers of their own kind."""
     c = config
-    if c.pattern:
-        mixers, stored = c.kind_layers("mamba"), stored_kv_heads(
-            c.cache_kv_heads)
-        ring = (c.kind_layers("window"), num_rows,
-                window_capacity(c, block_size, step_tokens)
-                * (c.cache_kv_heads // stored), stored, c.cache_head_dim)
-        return RingRows(
-            ssm=jnp.zeros((mixers, num_rows, c.mamba_d_state,
-                           c.mamba_d_ssm), jnp.float32),
-            conv=jnp.zeros((mixers, num_rows, c.mamba_d_conv - 1,
-                            c.ssm_conv_dim), c.dtype),
-            win_k=jnp.zeros(ring, c.dtype), win_v=jnp.zeros(ring, c.dtype))
-    return StateRows(
-        ssm=jnp.zeros((c.num_layers, num_rows, c.mamba_n_heads,
-                       c.mamba_d_head, c.mamba_d_state), jnp.float32),
-        conv=jnp.zeros((c.num_layers, num_rows, c.mamba_d_conv - 1,
-                        c.ssm_conv_dim), c.dtype))
+    leaves = {name: jnp.zeros(shape, dtype) for name, (shape, dtype)
+              in _state_leaves(c, num_rows).items()}
+    if not (c.pattern and c.kind_layers("window")):
+        return StateRows(**leaves)
+    stored = stored_kv_heads(c.cache_kv_heads)
+    ring = (c.kind_layers("window"), num_rows,
+            window_capacity(c, block_size, step_tokens)
+            * (c.cache_kv_heads // stored), stored, c.cache_head_dim)
+    return RingRows(win_k=jnp.zeros(ring, c.dtype),
+                    win_v=jnp.zeros(ring, c.dtype), **leaves)
 
 
 def init_paged_pool(config: ModelConfig, num_blocks: int,
@@ -403,8 +419,9 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
     moves no byte for it. The quantized ladder has no latent form yet.
 
     A configuration with recurrent state (``config.ssm``) gets
-    ``state_rows`` rows of it beside the blocks (``PagedKVPool.rows``);
-    every other configuration's pool is what it was.
+    ``state_rows`` rows of it beside the blocks (``PagedKVPool.rows``,
+    :func:`init_state_rows`); every other configuration's pool is what it
+    was.
 
     A layer pattern (``config.layer_types``) holds block-addressed KV for
     its "full" layers alone, the head axis stored folded
@@ -415,16 +432,21 @@ def init_paged_pool(config: ModelConfig, num_blocks: int,
     if config.pattern and (kv_dtype != "bf16"
                            or kv_dtype_per_layer is not None):
         refuse(config, "init_paged_pool(kv_dtype=)")
-    if config.ssm:
-        if state_rows <= 0:
-            raise ValueError(
-                f"{config.name}: a pool for a model with recurrent state "
-                f"needs state_rows > 0")
-        return init_paged_pool(
-            dataclasses.replace(config, mamba_d_ssm=0), num_blocks,
-            block_size, kv_dtype, kv_dtype_per_layer)._replace(
-                rows=init_state_rows(config, state_rows, block_size,
-                                     step_tokens))
+    if config.ssm and state_rows <= 0:
+        raise ValueError(
+            f"{config.name}: a pool for a model with recurrent state "
+            f"needs state_rows > 0")
+    pool = _init_block_pool(config, num_blocks, block_size, kv_dtype,
+                            kv_dtype_per_layer)
+    if not config.ssm:
+        return pool
+    return pool._replace(rows=init_state_rows(config, state_rows, block_size,
+                                              step_tokens))
+
+
+def _init_block_pool(config: ModelConfig, num_blocks: int, block_size: int,
+                     kv_dtype: str, kv_dtype_per_layer) -> PagedKVPool:
+    """``init_paged_pool``'s block-addressed leaves."""
     hkv, dh = config.cache_kv_heads, config.cache_head_dim
     if config.pattern:
         # the one forward that writes and reads a folded head axis

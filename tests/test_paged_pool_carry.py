@@ -514,6 +514,59 @@ def test_layer_pattern_step_compiled_for_v5e_copies_no_pool_ring_or_state(
                         and "copy-start" not in line), line
 
 
+@pytest.mark.parametrize("entries", [192])    # decode pass and chunk loop
+def test_delta_rule_step_compiled_for_v5e_copies_no_pool_and_no_state(
+        one_v5e, entries, monkeypatch):
+    """``_paged_fused_step`` at Solar-Open2-250B's widths (the cell's one
+    period: a gated GQA layer 64/8 x 128 and three delta-rule layers over
+    40 held experts; 48 rows of 4096 tokens in blocks of 32, 56 state rows
+    of 64 x 128 x 128 float32), compiled for the v5e: the GQA layer runs
+    ``paged_attention_rows``, no leaf of the pool is copied, the decode
+    rows' pass leaves no temporary of a 48-row slab of states (201 MB), and
+    a chunk's ``(2C, C, H, K)`` decays (268 MB) are consumed where they are
+    made."""
+    from senweaver_ide_tpu.ops import paged_attention
+    monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
+    c = _benchmark_config("solar-open2-250b")
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e),
+        tree)
+    params = on_chip(jax.eval_shape(
+        lambda: tf.init_params(c, jax.random.PRNGKey(0))))
+    bs = resolve_block_size(kv_row_bytes(c), 4096)
+    assert bs == 32
+    rows, width = 48, 4096 // bs
+    pool = on_chip(jax.eval_shape(lambda: init_paged_pool(
+        c, 52 * width, bs, state_rows=56, step_tokens=192)))
+    blocks, state = "1,6656,32,8,128", "3,56,64,128,128"
+    assert ",".join(map(str, pool.k.shape)) == blocks
+    assert ",".join(map(str, pool.rows.ssm.shape)) == state
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_v5e)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        compiled = eng._paged_fused_step.lower(
+            params, c, i32(6, entries), i32(rows, width), pool,
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_v5e),
+            i32(rows), SampleParams(temperature=1.0), None).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
+    assert any("tpu_custom_call" in line and "%paged_attention_rows." in line
+               for line in text.splitlines())
+    assert f"bf16[{entries * width},{bs},8,128]" not in text
+    # (the 25 MB of conv windows are prefetched whole into fast memory,
+    # ``copy-start`` to ``S(1)``: not a second pool, and not listed)
+    for shape in (f"bf16[{blocks}]", f"f32[{state}]",
+                  "f32[48,64,128,128]"):
+        for line in text.splitlines():
+            if f"= {shape}" in line:
+                assert (" copy(" not in line
+                        and "copy-start" not in line), line
+
+
 def _preset_head_shapes():
     """Every (Hq, Hkv, Dh) a full-size dense preset has, once, under the
     first preset's name."""
