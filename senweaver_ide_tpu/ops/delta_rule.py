@@ -37,9 +37,11 @@ Two entry points, as ``ops/ssm.py`` has them. *Dense* (``kda_dense``): whole
 rules: an entry at position 0 starts from a zero state whatever the row
 held; an entry that is not kept advances nothing; a run that continues a
 row starts from the row's stored state. Every decode row (a run of one
-entry) advances in one pass over the rows' states (``_advance_single``);
-each longer run takes one trip of a loop that reads its row's state once,
-carries it through the run's chunks, and writes it back once.
+entry) advances in one pass over the rows' states (``_advance_single``; on
+a TPU ``ops.state_step.state_step_delta``, the same step with a head's
+state in VMEM: read once, written once); each longer run takes one trip of
+a loop that reads its row's state once, carries it through the run's
+chunks, and writes it back once.
 """
 
 from __future__ import annotations
@@ -49,6 +51,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from . import state_step
 from .ssm import RunPlan
 
 _HI = jax.lax.Precision.HIGHEST
@@ -168,7 +171,13 @@ def kda_flat(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     t = q.shape[0]
     q, k, v, g, beta = _f32(q, k, v, g, beta)
     with jax.named_scope("kda.step"):
-        state, o_row = _advance_single(state, layer, plan, q, k, v, g, beta)
+        if state_step.one_pass(state):
+            state, o_row = state_step.state_step_delta(
+                state, layer, plan.row_last, plan.row_len, plan.row_fresh,
+                q, k, v, g, beta)
+        else:
+            state, o_row = _advance_single(state, layer, plan, q, k, v, g,
+                                           beta)
     one = plan.row_len == 1
     o = jnp.where((plan.keep & one[seq_row])[:, None, None], o_row[seq_row],
                   0.0)

@@ -37,7 +37,9 @@ What the flat form holds to (``tests/test_falcon_h1.py``):
 A row's state is read and written ONCE a run, never gathered once an
 entry. Inside a run the quadratic (duality) form over the step's entries,
 masked to pairs of one run; a run of one entry (a decode row) advances its
-state in one elementwise pass over all rows (``_advance_single``); each
+state in one elementwise pass over all rows (``_advance_single``; on a TPU
+``ops.state_step.state_step_mamba2``, which reads the output out of the
+same visit that writes the state); each
 longer run (a prefill chunk) takes one trip of a loop that reads its row's
 state, serves the run's readout and update as two products over the
 entries, and writes the row back (``_advance_long``).
@@ -49,6 +51,8 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from . import state_step
 
 _HI = jax.lax.Precision.HIGHEST
 
@@ -286,7 +290,12 @@ def scan_flat(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     # terms alone, so a long batch costs a decay no precision
     cum = jnp.einsum("ij,jh->ih", plan.cum_w, da, precision=_HI)
     y = _intra(plan.same, cum, dt, x, b, c)
-    ssm, z_row = _advance_single(ssm, layer, plan, da, dt, x, b, c)
+    if state_step.one_pass(ssm):
+        ssm, z_row = state_step.state_step_mamba2(
+            ssm, layer, plan.row_last, plan.row_len, plan.row_fresh, da, dt,
+            x, b, c)
+    else:
+        ssm, z_row = _advance_single(ssm, layer, plan, da, dt, x, b, c)
     ssm, z_long = _advance_long(ssm, layer, plan, seq_row, cum, dt, x, b, c)
     y = y + z_long + jnp.where(plan.keep[:, None, None], z_row[seq_row], 0.0)
     return y + d[:, None] * x.astype(jnp.float32), ssm

@@ -41,6 +41,7 @@ from ..obs import get_registry, get_tracer
 from ..obs.runtime_profile import (ProfiledFunction, get_profiler,
                                    profiled_device_get)
 from ..obs.tracing import noop_span
+from ..ops import state_step
 from ..ops.sampling import sample_token, sampled_logprob
 from .kv_pressure import (HostPrefix, PrefixCandidate, dequantize_host,
                           pick_victim, should_tier)
@@ -930,6 +931,17 @@ class RolloutEngine:
                     "Device bytes of the pool's row-addressed recurrent "
                     "state (engine rows and snapshot rows, all layers)."
                 ).set(self.pool.rows.nbytes)
+                # the decode rows that went through the one-pass kernel
+                # (ops/state_step.py): counted where a step program traced
+                # here holds the kernel over this leaf, which the ops
+                # layer alone decides, at trace time
+                self._state_shape = self.pool.rows.ssm.shape
+                self._state_one_pass_total = reg.counter(
+                    "senweaver_state_rows_one_pass_total",
+                    "Decode rows whose recurrent state the fused steps "
+                    "advanced in one pass (ops/state_step.py's kernel: "
+                    "read once, written once); 0 where the plain pass "
+                    "ran.")
             # What the pool holds by kind of cache (block-addressed KV,
             # window rings, state, conv windows), once: a reader of the
             # gauges has the bytes of each descriptor.
@@ -3675,6 +3687,9 @@ class RolloutEngine:
                     # rows whose recurrent state the step reads and writes
                     st.set_attr("ssm_rows", len(decode_rows)
                                 + sum(1 for j in job_rows if j[5]))
+                    st.set_attr("state_rows_one_pass", len(decode_rows)
+                                if state_step.traced(self._state_shape)
+                                else 0)
                     st.set_attr("ssm_state_copies", n_copies)
                 if self.config.pattern:
                     self._note_pattern_step(st, pos_l[:used], n_copies)
@@ -3946,6 +3961,8 @@ class RolloutEngine:
         samplers, ``(entry, request, is its first token)`` in entry
         order, for :meth:`_deliver`."""
         samplers = []
+        if self.config.ssm and state_step.traced(self._state_shape):
+            self._state_one_pass_total.inc(len(decode_rows))
         for idx, row, req in decode_rows:
             samplers.append((idx, req, False))
             req.inflight += 1

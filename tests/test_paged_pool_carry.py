@@ -444,6 +444,10 @@ def test_hybrid_step_compiled_for_v5e_copies_no_pool_and_no_state(
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "%paged_attention_rows." in line]
     assert calls, "the kernel is not in the compiled step"
+    # the decode rows' states in one pass (ops/state_step.py), once a
+    # layer in the scan's body: the leaf aliased, no copy of it below
+    assert any("tpu_custom_call" in line and "%state_step_mamba2" in line
+               for line in text.splitlines())
     assert f"bf16[{entries * width},{bs},4,128]" not in text
     for shape in ("f32[2,56,32,128,256]", "f32[48,32,128,256]",
                   "f32[1,48,32,128,256]",
@@ -522,7 +526,8 @@ def test_delta_rule_step_compiled_for_v5e_copies_no_pool_and_no_state(
     40 held experts; 48 rows of 4096 tokens in blocks of 32, 56 state rows
     of 64 x 128 x 128 float32), compiled for the v5e: the GQA layer runs
     ``paged_attention_rows``, no leaf of the pool is copied, the decode
-    rows' pass leaves no temporary of a 48-row slab of states (201 MB), and
+    rows' pass (``state_step_delta``, the state leaf aliased) leaves no
+    temporary of a 48-row slab of states (201 MB), and
     a chunk's ``(2C, C, H, K)`` decays (268 MB) are consumed where they are
     made."""
     from senweaver_ide_tpu.ops import paged_attention
@@ -556,6 +561,10 @@ def test_delta_rule_step_compiled_for_v5e_copies_no_pool_and_no_state(
     assert compiled.memory_analysis().temp_size_in_bytes < 128 << 20
     assert any("tpu_custom_call" in line and "%paged_attention_rows." in line
                for line in text.splitlines())
+    # the three delta-rule layers' decode pass through the one-pass kernel
+    # (ops/state_step.py): the leaf aliased in and out, no copy beside it
+    assert sum("tpu_custom_call" in line and "%state_step_delta" in line
+               for line in text.splitlines()) == 3
     assert f"bf16[{entries * width},{bs},8,128]" not in text
     # (the 25 MB of conv windows are prefetched whole into fast memory,
     # ``copy-start`` to ``S(1)``: not a second pool, and not listed)
