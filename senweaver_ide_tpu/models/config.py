@@ -218,6 +218,10 @@ def refuse(config, mechanism: str, also=None) -> None:
 # The second sublayer is the dense SwiGLU, or the expert layer where the
 # configuration has experts (``models.transformer._ffn``).
 LAYER_KINDS = ("mamba", "window", "full", "gmu", "cross", "kda")
+# The kinds that hold nothing: such a layer writes no state, ring or KV, so a
+# token's pass through it leaves nothing behind for a later token but what it
+# adds to the token's own stream (``ModelConfig.readers_from``).
+STATELESS_KINDS = frozenset(("gmu", "cross"))
 
 
 def pattern_keys(period) -> Tuple[str, ...]:
@@ -471,9 +475,23 @@ class ModelConfig:
         """The layers are of unlike kinds (``layer_types``)."""
         return bool(self.layer_types)
 
-    def kind_layers(self, kind: str) -> int:
-        """Layers of ``kind`` in the pattern."""
-        return sum(period.count(kind) * n for period, n in self.layer_types)
+    def kind_layers(self, kind: str, first: int = 0) -> int:
+        """Layers of ``kind`` in the pattern, from segment ``first`` on."""
+        return sum(period.count(kind) * n
+                   for period, n in self.layer_types[first:])
+
+    @property
+    def readers_from(self) -> int:
+        """The first of the pattern's trailing segments all of whose kinds
+        hold nothing (``STATELESS_KINDS``: SambaY's cross-decoder), or the
+        number of segments where the last one writes: from that segment on
+        a token's pass has one product, its logits, and the paged forward
+        runs it for the entries whose logits somebody reads alone
+        (``models.transformer._forward_paged_pattern``)."""
+        cut = len(self.layer_types)
+        while cut and STATELESS_KINDS.issuperset(self.layer_types[cut - 1][0]):
+            cut -= 1
+        return cut
 
     @property
     def cache_kv_heads(self) -> int:

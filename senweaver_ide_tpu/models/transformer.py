@@ -38,8 +38,8 @@ from ..ops.attention import NEG_INF, attention
 from ..ops.norms import layer_norm, rms_norm
 from ..ops.rotary import apply_rope, rope_cos_sin
 from ..ops import delta_rule, ssm as ssm_ops
-from .config import (LAYER_KINDS, ModelConfig, YarnScaling, pattern_keys,
-                     refuse)
+from .config import (LAYER_KINDS, STATELESS_KINDS, ModelConfig, YarnScaling,
+                     pattern_keys, refuse)
 from .moe import BANKS, MoEStats, expert_ffn
 
 Params = Dict[str, Any]
@@ -1451,7 +1451,9 @@ def _pattern_layer(c: ModelConfig, kind: str, lp: Dict[str, jax.Array],
 
 
 def _pattern_scan(c: ModelConfig, params: Params, x: jax.Array, carry,
-                  mixer: Callable, remat: bool = False, count=None):
+                  mixer: Callable, remat: bool = False, count=None, *,
+                  segments: Optional[Tuple[int, int]] = None, m=None,
+                  acc=None):
     """The layers of a ``layer_types`` configuration: one scan a segment,
     whose body runs the period's unlike layers in order, with the memory
     ``m`` (the last "mamba" layer's scan output, read by the "gmu" layers)
@@ -1463,19 +1465,31 @@ def _pattern_scan(c: ModelConfig, params: Params, x: jax.Array, carry,
     configuration's banks stay whole outside the scan's xs (each layer's
     grouped products address its experts inside them, ``moe._grouped``),
     and its layers' ``MoEStats`` over the entries ``count`` marks are
-    merged in the carry. -> (x, carry', ``MoEStats`` or None)."""
-    m = jnp.zeros(x.shape[:-1] + (c.mamba_d_ssm,), x.dtype)
+    merged in the carry. ``segments=(i, j)`` runs segments ``[i, j)``
+    alone, taking up the ``m`` and the merged stats ``acc`` that the
+    segments before them returned (``x`` may then hold other entries than
+    it did there: ``_forward_paged_pattern``); ``at`` counts the layers
+    before ``i`` either way. -> (x, m, carry', ``MoEStats`` or None)."""
+    lo, hi = segments or (0, len(c.layer_types))
+    if m is None:
+        m = jnp.zeros(x.shape[:-1] + (c.mamba_d_ssm,), x.dtype)
     seen = {kind: 0 for period, _ in c.layer_types for kind in period}
     first = 0
-    acc = MoEStats.zeros(c) if c.num_experts else None
+    if acc is None and c.num_experts:
+        acc = MoEStats.zeros(c)
     layer_fn = _pattern_layer
     if remat:
         layer_fn = jax.checkpoint(
             _pattern_layer, static_argnums=(0, 1, 6), prevent_cse=False,
             policy=(jax.checkpoint_policies.checkpoint_dots
                     if c.remat == "dots" else None))
-    for i, (period, n) in enumerate(c.layer_types):
+    for i, (period, n) in enumerate(c.layer_types[:hi]):
         base, start = dict(seen), first
+        for kind in period:
+            seen[kind] += n
+        first += n * len(period)
+        if i < lo:
+            continue
         names = pattern_keys(period)
         seg = params["layers"][f"seg{i}"]
         banks = {name: {k: v for k, v in seg[name].items() if k in BANKS}
@@ -1513,10 +1527,7 @@ def _pattern_scan(c: ModelConfig, params: Params, x: jax.Array, carry,
         (x, m, carry, acc), _ = jax.lax.scan(
             body, (x, m, carry, acc),
             (seg, jnp.arange(n, dtype=jnp.int32)))
-        for kind in period:
-            seen[kind] += n
-        first += n * len(period)
-    return x, carry, acc
+    return x, m, carry, acc
 
 
 def _dense_mixer(c: ModelConfig, attn_mask, kind: str,
@@ -2096,10 +2107,16 @@ def forward_paged(
     gathered BEFORE the final norm and the head, so the vocabulary is
     paid ``S`` times and the result is ``(logits (S, V) fp32, pool')``,
     row ``i`` what entry ``logit_entries[i]`` would have read in the
-    every-entry form (an index out of range is clamped: a row whose
-    logits are nobody's). Every layer still runs over all ``T`` entries:
-    each writes its cache row. Without the argument every entry pays the
-    head (the draft paths, which read each entry's argmax or none).
+    every-entry form (an index out of range names no entry: a row whose
+    logits are nobody's). Every layer that writes a cache row still runs
+    over all ``T`` entries; a layer pattern's trailing segments whose
+    kinds hold nothing (``models.config.STATELESS_KINDS``: a SambaY
+    decoder's gated memory units and cross layers, whose one product for
+    a prompt token is the logits nobody reads) run over the ``S``
+    gathered entries, the gather moved up behind the last segment that
+    writes (``_forward_paged_pattern``). Without the argument every entry
+    runs every layer and pays the head (the draft paths, which read each
+    entry's argmax or none).
 
     ``pool`` is the whole ``PagedKVPool`` pytree (accepted duck-typed
     to avoid a models → rollout import cycle). Its leaves travel in the
@@ -2235,7 +2252,19 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
       ``ops.delta_rule.kda_flat``) once a run.
 
     Entries that keep no write (padding, dropped writes) advance no state
-    and write no ring slot. -> the tuple ``_forward_paged_impl`` returns."""
+    and write no ring slot.
+
+    With ``logit_entries`` the pattern's trailing segments whose kinds hold
+    nothing (``ModelConfig.readers_from``: SambaY's cross-decoder) run over
+    those ``S`` entries alone: the stream, the memory, ``seq_row`` and
+    ``positions`` are gathered there behind the last segment that writes,
+    the "cross" layers attend by a plan of the gathered entries (one a row:
+    single-query items, a group's decode rows still one group item over
+    their shared blocks), and the head takes the stream as it comes. An
+    index past the last entry is nobody's: it runs as padding does, ``(row
+    0, position 0)``, one block. A pattern that ends in a kind that writes
+    gathers before the head, as ``_forward_paged_impl`` does.
+    -> the tuple ``_forward_paged_impl`` returns."""
     t = tokens.shape[0]
     if pool.rows is None:
         refuse(c, "forward_paged(pool=)")
@@ -2269,22 +2298,38 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
         ring_block = jnp.where(
             keep, seq_row * ring_blocks + (positions // bs) % ring_blocks,
             win_k.shape[1])
-    row_plan = win_plan = shared = None
-    if kernel:
-        from ..ops.paged_attention import (group_tile, paged_attention_rows,
-                                           plan_rows, query_tile)
+
+    def plan_of(rows, pos, window=0):
+        """The kernel's plan of the entries at ``(rows, pos)`` over the
+        block tables, or with ``window`` over the rings; None where the
+        gather runs."""
+        if not kernel:
+            return None
+        from ..ops.paged_attention import group_tile, plan_rows, query_tile
+        blocks = {} if window else dict(
+            tables=tables, group_tile=group_tile(c.num_heads))
         with jax.named_scope("attn.row_plan"):
-            row_plan = plan_rows(
-                seq_row, positions, block_size=bs,
-                table_width=tables.shape[1], q_tile=query_tile(c.num_heads),
-                tables=tables, group_tile=group_tile(c.num_heads))
-            if rings:
-                win_plan = plan_rows(
-                    seq_row, positions, block_size=bs,
-                    table_width=tables.shape[1],
-                    q_tile=query_tile(c.num_heads), window=c.layer_window)
-            shared = jnp.stack([row_plan.kv_blocks_saved,
-                                row_plan.group_items])
+            return plan_rows(
+                rows, pos, block_size=bs, table_width=tables.shape[1],
+                q_tile=query_tile(c.num_heads), window=window, **blocks)
+
+    # a layer's entries: their rows, their positions, the plan of them
+    step = (seq_row, positions, plan_of(seq_row, positions))
+    ring = (seq_row, positions,
+            plan_of(seq_row, positions, c.layer_window) if rings else None)
+    shared = (jnp.stack([step[2].kv_blocks_saved, step[2].group_items])
+              if kernel else None)
+    # the segments from ``cut`` on hold nothing: where the step names the
+    # entries whose logits are read they run over those (``read``), an
+    # index that names none as padding does
+    cut = c.readers_from if logit_entries is not None else len(c.layer_types)
+    read = None
+    if cut < len(c.layer_types):
+        named = logit_entries < t
+        at_entry = lambda a, none: jnp.where(
+            named, jnp.take(a, logit_entries, mode="clip"), none)
+        read = (at_entry(seq_row, 0), at_entry(positions, 0))
+        read = (*read, plan_of(*read))
     with jax.named_scope("ssm.run_plan"):
         run_plan = ssm_ops.plan_runs(seq_row, positions, keep,
                                      num_rows=rows_r)
@@ -2292,10 +2337,13 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
     # chunked form took and the largest readout any layer computed
     kda = (jnp.zeros((), jnp.float32) if c.kind_layers("kda") else None)
 
-    def attend(q, k_leaf, v_leaf, layer, tbl, plan, span):
-        """q (T, 1, Hq, D) over one layer of folded leaves -> the same
-        shape; ``span`` > 0: the trailing positions a query reads."""
+    def attend(q, k_leaf, v_leaf, layer, tbl, span, ent):
+        """q (T, 1, Hq, D), the queries of the entries ``ent``, over one
+        layer of folded leaves -> the same shape; ``span`` > 0: the
+        trailing positions a query reads."""
+        seq_row, positions, plan = ent
         if plan is not None:
+            from ..ops.paged_attention import paged_attention_rows
             with jax.named_scope("attn.scores"):
                 return paged_attention_rows(
                     q[:, 0], k_leaf, v_leaf, layer, tbl, positions, plan,
@@ -2303,7 +2351,8 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
         with jax.named_scope("attn.kv_gather"):
             mine = tbl[seq_row]                                 # (T, MB)
             seq = lambda leaf: leaf[layer, mine].reshape(
-                t, mine.shape[1] * bs, hkv, leaf.shape[-1]).astype(q.dtype)
+                q.shape[0], mine.shape[1] * bs, hkv,
+                leaf.shape[-1]).astype(q.dtype)
             k_seq, v_seq = seq(k_leaf), seq(v_leaf)
         with jax.named_scope("attn.scores"):
             kv_pos = jnp.arange(k_seq.shape[1])[None, :]
@@ -2313,8 +2362,13 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
             return attention(q, k_seq, v_seq, kv_mask=valid, causal=False,
                              scale=scale)
 
-    def mixer(kind, lp, h, m, leaves, at):
+    def mixer(kind, lp, h, m, leaves, at, ent=step):
+        """``ent``: h's entries where they are not the step's (``read``:
+        no kind that writes runs there)."""
         k_leaf, v_leaf, state, window, win_k, win_v, kda = leaves
+        if ent is read and kind not in STATELESS_KINDS:
+            raise ValueError(f"{c.name}: a {kind!r} layer writes its cache "
+                             f"for every entry, not for the sampled ones")
         if kind == "mamba":
             u_in, z = _mamba1_in(c, lp, h)
             with jax.named_scope("ssm.conv"):
@@ -2352,7 +2406,7 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
                 v_leaf = _write_rows(v_leaf, at["index"], write_block,
                                      write_off, v[:, 0])
             out = _gated_out(c, lp, attend(q, k_leaf, v_leaf, at["index"],
-                                           tables, row_plan, 0), h)
+                                           tables, 0, ent), h)
         else:
             with jax.named_scope("attn.qkv"):
                 q = _diff_queries(c, lp, h)
@@ -2371,17 +2425,28 @@ def _forward_paged_pattern(params, c, tokens, *, pool, tables, seq_row,
                     win_v = _write_rows(win_v, at["index"], ring_block,
                                         positions % bs, v[:, 0])
                 got = attend(q, win_k, win_v, at["index"], ring_tbl,
-                             win_plan, c.layer_window)
+                             c.layer_window, ring)
             else:
                 got = attend(q, k_leaf, v_leaf,
                              at["index" if kind == "full" else "full"],
-                             tables, row_plan, 0)
+                             tables, 0, ent)
             out = _diff_out(c, lp, got, at["layer"])
         return out, m, (k_leaf, v_leaf, state, window, win_k, win_v, kda)
 
-    x, leaves, moe = _pattern_scan(
+    x, m, leaves, moe = _pattern_scan(
         c, params, x, (pool.k, pool.v, state, window, win_k, win_v, kda),
-        mixer, count=keep)
+        mixer, count=keep, segments=(0, cut))
+    if read is not None:
+        # behind the last layer that writes, the entries somebody samples
+        # go on alone
+        with jax.named_scope("lm_head"):
+            x, m = _logit_rows(x, logit_entries), _logit_rows(m,
+                                                              logit_entries)
+        x, _, leaves, moe = _pattern_scan(
+            c, params, x, leaves, functools.partial(mixer, ent=read),
+            count=at_entry(keep, False),
+            segments=(cut, len(c.layer_types)), m=m, acc=moe)
+        logit_entries = None
     k_leaf, v_leaf, state, window, win_k, win_v, kda = leaves
     pool = pool._replace(k=k_leaf, v=v_leaf, rows=type(pool.rows)(
         state, window, *((win_k.reshape(ring_shape),

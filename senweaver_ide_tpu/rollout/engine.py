@@ -293,7 +293,9 @@ def _paged_fused_step(params: Params, config: ModelConfig,
     entry on the device, from the plan it already has, and the final
     norm, the head, the sampler and the log-prob run over those
     ``num_slots`` entries alone (``forward_paged``'s ``logit_entries``;
-    a row with none reads a clamped entry and its sample is dropped);
+    a row with none reads an entry that is nobody's and its sample is
+    dropped; a layer pattern's trailing layers that write no cache run
+    over those entries too);
     the samples and log-probs are scattered back to their entries'
     places in ``(T,)`` outputs, so the host reads them as it always
     did. A step as wide as the rows gathers nothing, and
@@ -3692,7 +3694,7 @@ class RolloutEngine:
                                 else 0)
                     st.set_attr("ssm_state_copies", n_copies)
                 if self.config.pattern:
-                    self._note_pattern_step(st, pos_l[:used], n_copies)
+                    self._note_pattern_step(st, vectors, used, n_copies)
             t_launch = get_profiler().begin_step("engine.fused_step")
             if st is not None and t_launch and (prev is not None
                                                 or self._fetched_at):
@@ -3724,25 +3726,38 @@ class RolloutEngine:
                 self._collect(span, fly, emitted)
             return emitted
 
-    def _note_pattern_step(self, st, positions, n_copies: int) -> None:
+    def _note_pattern_step(self, st, plan, used: int, n_copies: int) -> None:
         # guarded-by: caller
-        """A layer pattern's attrs of one fused step on its span: columns
-        of cache (a token's k and v in one layer) the step's used entries
-        attend, by the kind of layer that reads them — a window layer
-        their trailing window, the full layer the context, the cross
+        """A layer pattern's attrs of one fused step on its span, from the
+        ``used`` leading entries of its ``plan`` (the ``(6, T)`` vectors):
+        columns of cache (a token's k and v in one layer) the step's
+        entries attend, by the kind of layer that reads them — a window
+        layer their trailing window, the full layer the context, the cross
         layers the full layer's context again each — and the rings a
         fork's row copies moved (a state-row copy carries the row's ring
-        in every window layer)."""
+        in every window layer). The pattern's trailing segments that hold
+        nothing (``ModelConfig.readers_from``) run over the entries the
+        head runs over (``cross_entries``): in a step that gathers its
+        samplers (``_head_entries``) their cross layers attend the
+        samplers' contexts alone."""
         c = self.config
-        ctx = np.asarray(positions, np.int64) + 1
+        ctx = plan[2, :used].astype(np.int64) + 1
         full = int(ctx.sum())
+        tail = c.readers_from < len(c.layer_types)
+        tail_cross = c.kind_layers("cross", c.readers_from)
+        gathers = tail and self._head_entries_step < plan.shape[1]
+        read = (int(ctx[(plan[5, :used] & FEED_PUT) > 0].sum()) if gathers
+                else full)
         cols = {"window": (int(np.minimum(ctx, c.layer_window).sum())
                            * c.kind_layers("window")),
                 "full": full * c.kind_layers("full"),
-                "cross": full * c.kind_layers("cross")}
+                "cross": (full * (c.kind_layers("cross") - tail_cross)
+                          + read * tail_cross)}
         for kind, n in cols.items():
             st.set_attr("kv_columns_" + kind, n)
         st.set_attr("kv_columns", sum(cols.values()))
+        st.set_attr("cross_entries", self._head_entries_step if tail
+                    else plan.shape[1])
         st.set_attr("window_row_copies",
                     n_copies * c.kind_layers("window"))
 

@@ -6,8 +6,11 @@ on the device and ``forward_paged`` gathers the stream's rows there BEFORE
 the final norm and the head, so a wide step pays the vocabulary
 ``num_slots`` times, not ``T`` times. A step as wide as the rows gathers
 nothing (the program it was), and a plan with verify entries keeps every
-entry's head. Counted and compared here on the CPU; what it is worth in
-time only the chip says (PERF.md §6, PR 40)."""
+entry's head. A layer pattern whose trailing segments hold nothing (a
+SambaY decoder's cross-decoder) runs those over the gathered entries too
+(PR 46): the gather moves up behind the last layer that writes. Counted
+and compared here on the CPU; what it is worth in time only the chip says
+(PERF.md §6, PR 40 and 46)."""
 
 import dataclasses
 
@@ -18,8 +21,10 @@ import pytest
 
 from senweaver_ide_tpu import obs
 from senweaver_ide_tpu.models import init_params, tiny_test
-from senweaver_ide_tpu.models.config import (tiny_falcon_h1_test,
+from senweaver_ide_tpu.models.config import (ModelConfig, sambay_layer_types,
+                                             tiny_falcon_h1_test,
                                              tiny_phi4flash_test,
+                                             tiny_solar_open2_test,
                                              tiny_xing_mhc_test)
 from senweaver_ide_tpu.models.transformer import forward_paged
 from senweaver_ide_tpu.obs.runtime_profile import get_profiler
@@ -62,15 +67,17 @@ def make_engine(model):
                                    step_tokens=WIDE))
 
 
-def wide_plan(num_blocks):
+def wide_plan(num_blocks, chunk=7):
     """A 16-entry plan over four rows by hand: two decode rows, a prefill
-    that completes (its last entry puts), one that does not, and padding.
-    Row r's table is blocks ``4 r .. 4 r + 3``. -> (plan (6, 16), tables
-    (4, 4), the samplers' entries by row: 16 = none)."""
+    that completes (its last entry puts), one of ``chunk`` entries that
+    does not, and padding (none at ``chunk`` 10: the last entry is then a
+    prompt token of row 3). Row r's table is blocks ``4 r .. 4 r + 3``.
+    -> (plan (6, 16), tables (4, 4), the samplers' entries by row: 16 =
+    none)."""
     entries = ([(7, 0, 5, FEED_PUT), (9, 1, 9, FEED_PUT)]
                + [(3 + j, 2, j, FEED_PUT if j == 3 else 0)
                   for j in range(4)]
-               + [(11 + j, 3, j, 0) for j in range(7)])
+               + [(11 + j, 3, j, 0) for j in range(chunk)])
     plan = np.zeros((6, WIDE), np.int32)
     plan[3] = num_blocks                    # padding: the write is dropped
     for i, (tok, row, pos, feed) in enumerate(entries):
@@ -80,17 +87,23 @@ def wide_plan(num_blocks):
     return plan, tables, np.asarray([0, 1, 5, WIDE], np.int32)
 
 
-def paged_logits(model, eng, plan, tables, entries):
+def paged_step(model, pool, plan, tables, entries, kernel=False):
+    """``forward_paged`` on a ``(6, T)`` plan -> (logits, pool')."""
     params, config = model
-    tokens, seq_row, positions, write_block, write_off, _feed = plan
 
-    def run(params, pool, entries):
+    def run(params, pool, plan, tables, entries):
+        tokens, seq_row, positions, write_block, write_off, _feed = plan
         return forward_paged(
             params, config, tokens, pool=pool, tables=tables,
             seq_row=seq_row, positions=positions, write_block=write_block,
-            write_off=write_off, use_kernel=False, logit_entries=entries)[0]
+            write_off=write_off, use_kernel=kernel, logit_entries=entries)
 
-    return np.asarray(jax.jit(run)(params, eng.pool, entries))
+    return jax.jit(run)(params, pool, jnp.asarray(plan), jnp.asarray(tables),
+                        entries)
+
+
+def paged_logits(model, eng, plan, tables, entries):
+    return np.asarray(paged_step(model, eng.pool, plan, tables, entries)[0])
 
 
 # ---- (1) the gathered head is the every-entry head at the samplers --------
@@ -141,6 +154,118 @@ def test_the_wide_step_samples_its_samplers_and_scatters_them_back(model):
     # MoEStats' counts, the attention plan's two, the Sinkhorn error
     assert np.array_equal(toks[WIDE:], toks_all[WIDE:])
     np.testing.assert_allclose(logp[WIDE:], logp_all[WIDE:], rtol=1e-5)
+
+
+# ---- (1b) a pattern's trailing layers that write nothing -----------------
+
+PATTERNS = {"sambay": tiny_phi4flash_test, "delta-rule": tiny_solar_open2_test}
+
+
+def context_plan(num_blocks):
+    """What the decode rows of ``wide_plan`` attend: rows 0 and 1 prefill
+    positions 0..4 and 0..8 (14 entries, 2 of padding)."""
+    plan = np.zeros((6, WIDE), np.int32)
+    plan[3] = num_blocks
+    runs = [(0, p) for p in range(5)] + [(1, p) for p in range(9)]
+    for i, (row, pos) in enumerate(runs):
+        plan[:, i] = (20 + i, row, pos, 4 * row + pos // BLOCK, pos % BLOCK,
+                      0)
+    return plan
+
+
+@pytest.mark.parametrize("pattern, kernel, chunk", [
+    ("sambay", False, 7), ("sambay", False, 10), ("sambay", True, 7),
+    ("sambay", True, 10), ("delta-rule", False, 7), ("delta-rule", True, 10)])
+def test_a_patterns_gathered_step_serves_and_writes_what_every_entry_does(
+        pattern, kernel, chunk):
+    """Through the gather and through the kernels (interpreted), on a pool
+    that holds the decode rows' contexts: the gathered form's logits are
+    the every-entry form's rows at each sampler, and every leaf of the
+    pool — k, v, the mixers' states, the conv windows, the rings — is the
+    every-entry form's, whether the pattern ends in layers that hold
+    nothing (SambaY: they run over the 4 gathered entries) or in layers
+    that write (the delta rule: nothing is cut). A row with no sampler is
+    nobody's: at ``chunk`` 10 its index clamps onto a prompt token at
+    position 9, and it must not read that token's context."""
+    config = PATTERNS[pattern]()
+    model = (init_params(config, jax.random.PRNGKey(0)), config)
+    eng = make_engine(model)
+    nb = eng._alloc.num_blocks
+    plan, tables, samplers = wide_plan(nb, chunk)
+    _, pool = paged_step(model, eng.pool, context_plan(nb), tables, None,
+                           kernel)
+    every, pool_every = paged_step(model, pool, plan, tables, None, kernel)
+    got, pool_got = paged_step(model, pool, plan, tables,
+                                 jnp.asarray(samplers), kernel)
+    put = samplers < WIDE
+    assert got.shape == (ROWS, config.vocab_size)
+    np.testing.assert_allclose(np.asarray(got)[put],
+                               np.asarray(every)[samplers[put]],
+                               rtol=1e-5, atol=1e-6)
+    assert np.isfinite(np.asarray(got)).all()
+    leaves, leaves_every = (jax.tree_util.tree_leaves(p)
+                            for p in (pool_got, pool_every))
+    assert len(leaves) == len(leaves_every) >= 4
+    for a, b in zip(leaves, leaves_every):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-6)
+    # the step wrote something: the pool is not the one it was given
+    assert any(float(np.abs(np.asarray(a) - np.asarray(b)).max()) > 0
+               for a, b in zip(leaves, jax.tree_util.tree_leaves(pool)))
+
+
+def test_a_row_with_no_sampler_runs_as_padding_does():
+    """SambaY, ``chunk`` 10: row 3's index is out of range and clamps onto
+    the plan's last entry, a prompt token of row 3 at position 9. The cut
+    program hands the cross layers ``(row 0, position 0)`` for it, so its
+    logits are NOT that entry's: the every-entry form's differ there and
+    nowhere else."""
+    config = tiny_phi4flash_test()
+    model = (init_params(config, jax.random.PRNGKey(0)), config)
+    eng = make_engine(model)
+    nb = eng._alloc.num_blocks
+    plan, tables, samplers = wide_plan(nb, 10)
+    _, pool = paged_step(model, eng.pool, context_plan(nb), tables, None,
+                           False)
+    every, _ = paged_step(model, pool, plan, tables, None, False)
+    got, _ = paged_step(model, pool, plan, tables, jnp.asarray(samplers),
+                          False)
+    gap = np.abs(np.asarray(got) - np.asarray(every)[
+        np.minimum(samplers, WIDE - 1)]).max(-1)
+    assert (gap[:3] < 1e-5).all() and gap[3] > 1e-3
+
+
+SOLAR_PERIOD = tiny_solar_open2_test().layer_types
+
+
+@pytest.mark.parametrize("layer_types, cut", [
+    (sambay_layer_types(32), 2), (sambay_layer_types(8), 2),
+    # a pattern that ends in a kind that writes is never cut
+    (SOLAR_PERIOD, len(SOLAR_PERIOD)),
+    (((("mamba", "full"), 1), (("mamba", "cross"), 3)), 2),
+    (((("mamba", "full"), 1), (("gmu", "cross"), 2), (("mamba", "cross"), 1)),
+     3),
+    # segments that hold nothing count from the LAST one that writes
+    (((("mamba", "full"), 1), (("gmu",), 2), (("cross", "gmu"), 1)), 1),
+    ((), 0)])
+def test_only_trailing_segments_that_hold_nothing_are_cut(layer_types, cut):
+    c = dataclasses.replace(tiny_phi4flash_test(), layer_types=layer_types)
+    assert c.readers_from == cut
+
+
+def test_a_segment_that_writes_is_refused_over_gathered_entries(monkeypatch):
+    """Were the rule ever to name a segment that writes (here: forced to
+    cut at SambaY's middle pair, a mixer and the full layer), the forward
+    raises while it traces: no program that skips a cache row exists."""
+    config = tiny_phi4flash_test()
+    model = (init_params(config, jax.random.PRNGKey(0)), config)
+    eng = make_engine(model)
+    plan, tables, samplers = wide_plan(eng._alloc.num_blocks)
+    monkeypatch.setattr(ModelConfig, "readers_from",
+                        property(lambda self: 1))
+    with pytest.raises(ValueError, match="writes its cache"):
+        paged_step(model, eng.pool, plan, tables, jnp.asarray(samplers),
+                     False)
 
 
 # ---- (2) the engine, greedy, through chunked prefill ----------------------
@@ -211,17 +336,21 @@ def test_a_wide_steps_samples_are_draws_from_each_samplers_nucleus():
 
 # ---- (3) what the program is ---------------------------------------------
 
-def step_jaxpr(model, width, all_logits):
+def step_closed_jaxpr(model, width, all_logits):
     params, config = model
     eng = make_engine(model)
     plan = jnp.zeros((6, width), jnp.int32)
     tables = jnp.zeros((ROWS, 4), jnp.int32)
-    return str(jax.make_jaxpr(
+    return jax.make_jaxpr(
         lambda p, pool, key, cur: engine_mod._paged_fused_step._fn(
             p, config, plan, tables, pool, key, cur, SAMPLED, False,
             all_logits=all_logits))(
                 params, eng.pool, jax.random.PRNGKey(1),
-                jnp.zeros((ROWS,), jnp.int32)))
+                jnp.zeros((ROWS,), jnp.int32))
+
+
+def step_jaxpr(model, width, all_logits):
+    return str(step_closed_jaxpr(model, width, all_logits))
 
 
 def test_a_narrow_step_takes_no_gather_and_a_wide_one_pays_rows(model):
@@ -234,6 +363,42 @@ def test_a_narrow_step_takes_no_gather_and_a_wide_one_pays_rows(model):
     assert f"[{WIDE},{vocab}]" in every and f"[{ROWS},{vocab}]" not in every
     assert f"[{ROWS},{vocab}]" in wide and f"[{WIDE},{vocab}]" not in wide
     assert f"[{WIDE},1,{vocab}]" not in wide
+
+
+def stream_rows(config, width, all_logits):
+    """The leading axis of the stream ``(rows, 1, hidden)`` in the carry of
+    each of the step's layer scans, in program order."""
+    jaxpr = step_closed_jaxpr(
+        (init_params(config, jax.random.PRNGKey(0)), config), width,
+        all_logits)
+    found = []
+
+    def walk(jpr):
+        for eqn in jpr.eqns:
+            if eqn.primitive.name == "scan":
+                found.extend(
+                    v.aval.shape[0] for v in eqn.invars
+                    if getattr(v.aval, "shape", ())[1:] == (
+                        1, config.hidden_size))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
+def test_a_sambay_wide_step_holds_its_cross_decoder_at_the_rows():
+    """The tiny SambaY preset's three scans (window pairs, the middle pair,
+    the cross pair): in a wide step the last one carries the stream of the
+    4 rows' samplers, the two that write all 16 entries; the every-entry
+    program and the narrow step carry one width throughout. A pattern that
+    ends in a kind that writes (the delta rule's one scan) keeps all 16."""
+    c = tiny_phi4flash_test()
+    assert stream_rows(c, WIDE, False) == [WIDE, WIDE, ROWS]
+    assert stream_rows(c, WIDE, True) == [WIDE] * 3
+    assert stream_rows(c, ROWS, False) == [ROWS] * 3
+    solar = tiny_solar_open2_test()
+    assert set(stream_rows(solar, WIDE, False)) == {WIDE}
 
 
 @pytest.mark.parametrize("entries, rows, all_logits, paid", [

@@ -18,6 +18,7 @@ leaves in the layer scan's carry. Two things hold it there:
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -507,6 +508,12 @@ def test_layer_pattern_step_compiled_for_v5e_copies_no_pool_ring_or_state(
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and "%paged_attention_rows." in line]
     assert len(calls) >= 3, "full, window and cross layers run the kernel"
+    # a call's result: the entries + a tile of 10 queries, 40 heads in 48
+    # rows. The layers that write attend every entry; the cross layers, a
+    # wide step's 48 gathered samplers alone (PR 46)
+    widths = sorted({int(n) for line in calls for n in re.findall(
+        r"= bf16\[(\d+),48,128\]", line)})
+    assert widths == sorted({entries + 10, rows + 10}), widths
     assert f"bf16[{blocks}]{{4,3,2,1,0:T(2,128)(2,1)}}" in text
     assert f"bf16[{entries * width},{bs},10,128]" not in text
     for shape in (f"bf16[{blocks}]", f"bf16[{ring}]",

@@ -43,6 +43,7 @@ from senweaver_ide_tpu.ops import ssm as ssm_ops
 from senweaver_ide_tpu.rollout import (AdapterPool, EngineConfig,
                                        RolloutEngine)
 from senweaver_ide_tpu.rollout import paged_kv
+from senweaver_ide_tpu.rollout.engine import FEED_PUT
 from senweaver_ide_tpu.rollout.paged_kv import (cache_kinds, init_paged_pool,
                                                 stored_kv_heads,
                                                 window_capacity)
@@ -612,10 +613,18 @@ def test_the_step_reports_its_columns_and_copies(model):
     assert first["kv_columns_full"] == sum(range(1, 17))
     assert first["kv_columns_window"] == 2 * sum(min(p, 8)
                                                  for p in range(1, 17))
-    assert first["kv_columns_cross"] == first["kv_columns_full"]
-    assert first["kv_columns"] == (first["kv_columns_full"]
-                                   + first["kv_columns_window"]
-                                   + first["kv_columns_cross"])
+    # a wide step with no sampler: nothing reaches the cross layer, which
+    # runs over the 8 rows' (empty) places all the same (PR 46)
+    assert (first["entries"], first["head_entries"]) == (16, 8)
+    assert first["kv_columns_cross"] == 0 and first["cross_entries"] == 8
+    assert all(s["kv_columns"] == (s["kv_columns_full"]
+                                   + s["kv_columns_window"]
+                                   + s["kv_columns_cross"]) for s in steps)
+    # a step as wide as the rows gathers nothing: every entry's context
+    narrow = [s for s in steps if s["entries"] == 8]
+    assert narrow and all(
+        s["kv_columns_cross"] == s["kv_columns_full"] > 0
+        and s["cross_entries"] == 8 for s in narrow)
     # a fork copies a row of every window layer's ring with the state
     assert max(s["ssm_state_copies"] for s in steps) >= 1
     assert all(s["window_row_copies"] == 2 * s["ssm_state_copies"]
@@ -623,6 +632,63 @@ def test_the_step_reports_its_columns_and_copies(model):
     assert eng.cache_kind_bytes == {
         k.kind: k.nbytes(eng._alloc.num_blocks, 8, 8 + 2)
         for k in cache_kinds(model[1], 8, 16)}
+
+
+def test_the_cross_columns_count_the_entries_that_reach_the_cross_layers(
+        model, monkeypatch):
+    """Every step of a run in which prefill chunks ride beside decode rows,
+    against the plan the step was launched with: in a step that gathers
+    its samplers (wider than the rows) ``kv_columns_cross`` is the contexts
+    of the entries that put — decode rows, a completing prefill's last —
+    times the cross layers, in any other step every used entry's; and
+    ``cross_entries`` is the entries the head ran over."""
+    obs.enable()
+    eng = make_engine(model)
+    plans = []
+    note = type(eng)._note_pattern_step
+
+    def spy(self, st, plan, used, n_copies):
+        plans.append((plan[:, :used].copy(), plan.shape[1]))
+        return note(self, st, plan, used, n_copies)
+
+    monkeypatch.setattr(type(eng), "_note_pattern_step", spy)
+    rids = eng.submit_group(PROMPT, 4, max_new_tokens=8)
+    for _ in range(4):
+        eng.step()
+    rids.append(eng.submit(PROMPT[:21], max_new_tokens=4))
+    drain(eng, rids)
+    steps = [s.attrs for s in obs.get_tracer().spans()
+             if s.name == "engine.step" and "kv_columns" in s.attrs]
+    assert len(steps) == len(plans)
+    kinds = set()
+    for attrs, (plan, entries) in zip(steps, plans):
+        ctx = plan[2].astype(np.int64) + 1
+        puts = (plan[5] & FEED_PUT) > 0
+        wide = entries > eng.num_slots
+        want = int(ctx[puts].sum()) if wide else int(ctx.sum())
+        assert attrs["kv_columns_cross"] == want       # one cross layer
+        assert attrs["kv_columns_full"] == int(ctx.sum())
+        assert attrs["cross_entries"] == attrs["head_entries"] == (
+            eng.num_slots if wide else entries)
+        kinds.add((wide, bool(puts.any()), bool((~puts).any())))
+    # wide steps with and without samplers, chunks beside decode rows
+    assert {(True, False, True), (True, True, True),
+            (False, True, False)} <= kinds
+
+
+def test_a_pattern_that_ends_in_a_kind_that_writes_pays_every_entry():
+    """The delta-rule pattern cuts nothing: its ``cross_entries`` is the
+    step's ``entries`` at both widths."""
+    from senweaver_ide_tpu.models.config import tiny_solar_open2_test
+    config = tiny_solar_open2_test()
+    obs.enable()
+    eng = make_engine((init_params(config, jax.random.PRNGKey(0)), config))
+    drain(eng, [eng.submit(PROMPT, max_new_tokens=3)])
+    steps = [s.attrs for s in obs.get_tracer().spans()
+             if s.name == "engine.step" and "cross_entries" in s.attrs]
+    assert {s["entries"] for s in steps} == {8, 16}
+    assert all(s["cross_entries"] == s["entries"] for s in steps)
+    assert all(s["kv_columns_cross"] == 0 for s in steps)
 
 
 # ---- (6) what has no form yet is refused by name ---------------------------

@@ -683,10 +683,16 @@ def test_a_pattern_refuses_the_forms_it_has_no_equations_for():
 # sha256 of ``_paged_fused_step``'s lowered text at the tiny presets (6
 # rows; 6 and 24 entries), printed by the PARENT of PR 43 (commit dfe1db6):
 # the pattern scan, the state pool and the expert layer changed around
-# these models, and their programs did not.
+# these models, and their programs did not. PR 46 changed ONE on purpose,
+# SambaY's wide step (its cross-decoder runs over the rows' samplers:
+# "3b6de128f064f399" at the parent, 02f1966), and added this file's own
+# preset as its parent printed it: a pattern that ends in a kind that writes
+# is cut nowhere, so the seam in ``_pattern_scan`` lowers what was lowered.
 PARENT_STEPS = {
     ("tiny-phi4flash-test", 6): "6c3e4be0ad33fcfe",
-    ("tiny-phi4flash-test", 24): "3b6de128f064f399",
+    ("tiny-phi4flash-test", 24): "f4f7359f2d50f3ed",
+    ("tiny-solar-open2-test", 6): "8d057e45ef7b8344",
+    ("tiny-solar-open2-test", 24): "3678e35f54cace5d",
     ("tiny-falcon-h1-test", 6): "44284bac802734dd",
     ("tiny-falcon-h1-test", 24): "a304c513da631233",
     ("tiny-longcat-flash-test", 6): "c7bb384666103916",
